@@ -167,7 +167,7 @@ def resolve_impl(impl_raw, log=None) -> tuple[Optional[CodecKernel], str]:
           "use rabit_codec_impl=auto", load_error())
     if log is not None and not _warned:
         _warned = True
-        log.warning("codec kernels unavailable (%s); falling back to "
-                    "the numpy wire path (rabit_codec_impl=auto)",
-                    load_error())
+        log.warn("codec kernels unavailable (%s); falling back to "
+                 "the numpy wire path (rabit_codec_impl=auto)",
+                 load_error())
     return None, "numpy-fallback"

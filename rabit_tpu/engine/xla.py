@@ -24,12 +24,16 @@ checkpoint/recover contract covers it at iteration granularity, which is
 how the reference's apps use the API anyway (checkpoint per iteration,
 reference: rabit-learn/kmeans/kmeans.cc:121-157).
 
-Bootstrap: the inner engine's tracker rendezvous assigns the rank; rank 0
-then picks a JAX coordinator address and broadcasts it over the control
-plane; every process calls ``jax.distributed.initialize`` with its
-tracker rank as the process id, so control-plane ranks and mesh positions
-agree by construction.  If JAX is already multi-process (TPU pod launched
-through its own orchestration), the engine adopts JAX's identity instead.
+Bootstrap: the inner engine's tracker rendezvous assigns the rank; the
+tracker hosts a JAX coordination service and every process joins it as a
+client with its tracker rank as the node id.  On the CPU backend that id
+becomes ``jax.process_index()``; on a TPU the process index is the chip
+runtime's own (the launcher gives each child one chip — doc/scaling.md
+"One process per chip"), so the ranks exchange their process indices
+through the coordination service and the process mesh is ordered by
+rank either way (``_build_proc_mesh``).  If JAX is already
+multi-process (TPU pod launched through its own orchestration), the
+engine adopts JAX's identity instead.
 """
 from __future__ import annotations
 
@@ -66,23 +70,14 @@ def _is_runtime_failure(e: BaseException) -> bool:
     ``ValueError``, XLA ones as ``RuntimeError`` subclasses) so a
     programming error that merely *mentions* a marker word is not
     silently swallowed into the degraded path."""
-    try:
-        import jax.errors
+    import jax.errors
 
-        if isinstance(e, (jax.errors.JaxRuntimeError, OSError)):
-            return True
-    except (ImportError, AttributeError):  # pragma: no cover
-        pass
+    if isinstance(e, (jax.errors.JaxRuntimeError, OSError)):
+        return True
     if not isinstance(e, (ValueError, RuntimeError, OSError)):
         return False
     msg = str(e).lower()
     return any(m in msg for m in _TRANSPORT_MARKERS)
-
-
-def _free_port() -> int:
-    from rabit_tpu.utils.net import free_port
-
-    return free_port()
 
 
 class XLAEngine(Engine):
@@ -107,8 +102,6 @@ class XLAEngine(Engine):
         self._reform_enabled = True
         self._device_epoch = 0
         self._init_timeout = 300
-        self._custom_client = False
-        self._svc_tracker_hosted = False
         # Device-plane allreduce lowering: "psum" (XLA's own ICI
         # collective, the default) or "pallas_ring" (the credit-flow
         # remote-DMA ring in ops/ring_allreduce.py) for payloads at or
@@ -315,39 +308,19 @@ class XLAEngine(Engine):
             return
         # Only meaningful on CPU backends (tests, DCN-only hosts); inert
         # on TPU.  Must be set before backend initialization.
-        impl = params.get("rabit_jax_cpu_collectives", "gloo")
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", impl)
-        except Exception as e:  # noqa: BLE001 — config retired/renamed
-            self._obs_log.debug("jax_cpu_collectives_implementation "
-                                "unavailable: %s", e)
+        jax.config.update("jax_cpu_collectives_implementation",
+                          params.get("rabit_jax_cpu_collectives", "gloo"))
         # Fault tolerance lives in the host-side robust protocol, so a
         # peer death must surface as a failed collective (-> degrade to
         # host transport), NOT as the coordination service fatally
         # terminating the survivors.
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-        except Exception as e:  # noqa: BLE001 — older jax, no flag
-            self._obs_log.debug("jax_enable_recoverability unavailable: "
-                                "%s", e)
-        if self._private_bindings_ok():
-            # Every rank resolves the SAME tracker-hosted service by key:
-            # the init-time coordinator exchange runs entirely over the
-            # tracker, so version-span 0 contains no engine-internal
-            # collectives and a worker relaunched before the first
-            # checkpoint replays a span aligned with the survivors'.
-            coord = self._request_tracker_service("init")
-            self._svc_tracker_hosted = bool(coord)
-        else:
-            coord = ""
-        if not coord:
-            # Legacy fallback (no private client bindings, or a tracker
-            # that cannot host): rank 0 hosts, address distributed over
-            # the host plane.  This puts one broadcast into span 0; on
-            # such installs rank-0 death is unrecoverable anyway (the
-            # round-2 contract), so the narrower replay alignment is
-            # accepted there.
-            coord = self._broadcast_fresh_coordinator()
+        jax.config.update("jax_enable_recoverability", True)
+        # Every rank resolves the SAME tracker-hosted service by key:
+        # the init-time coordinator exchange runs entirely over the
+        # tracker, so version-span 0 contains no engine-internal
+        # collectives and a worker relaunched before the first
+        # checkpoint replays a span aligned with the survivors'.
+        coord = self._request_tracker_service("init")
         if os.environ.get("RABIT_XLA_DIE_FORMATION", "") == str(self._rank):
             # Fault-injection hook (XLA death matrix): die INSIDE the
             # formation window — tracker round + coordinator resolution
@@ -446,74 +419,19 @@ class XLAEngine(Engine):
                 f"tracker jaxsvc request failed ({type(e).__name__}: {e})")
             return ""
 
-    @staticmethod
-    def _private_bindings_ok() -> bool:
-        """True when jaxlib exposes the client constructor (with the
-        kwargs we need) for joining an EXTERNAL coordination service.
-        Probed BEFORE choosing the coordinator host: without the
-        bindings, the public-API fallback makes rank 0 host the service
-        itself, so the coordinator address must then be rank-0-local —
-        a tracker-hosted address would have rank 0 binding a port that
-        is already the tracker's (or on the wrong machine entirely).
-
-        The probe is a feature TRY-CALL: construct (never connect) a
-        client with the kwargs the recoverable recipe needs.  nanobind
-        rejects unknown kwargs with TypeError before any side effect,
-        construction performs no network IO (``connect()`` is a separate
-        call), and ``shutdown_on_destruction=False`` keeps the immediate
-        drop RPC-free.  ``inspect.signature`` is useless here (nanobind
-        reports ``(*args, **kwargs)``) and doc-grep broke on docstring
-        wording churn."""
-        try:
-            from jax._src import distributed as _jd  # noqa: F401
-            from jax._src.lib import _jax as jaxlib_ext
-
-            fn = jaxlib_ext.get_distributed_runtime_client
-        except (ImportError, AttributeError):
-            return False
-        try:
-            client = fn("127.0.0.1:1", 0, init_timeout=1,
-                        shutdown_on_destruction=False, recoverable=True)
-            del client
-            return True
-        except TypeError:
-            # unknown kwarg / changed arity — the recipe is unavailable
-            return False
-        except Exception:  # noqa: BLE001
-            # kwargs were ACCEPTED; construction failed for environmental
-            # reasons — report available and let the real call surface it
-            return True
-
     def _broadcast_fresh_coordinator(self) -> str:
-        """Rank 0 obtains a coordinator endpoint — preferring a
-        TRACKER-HOSTED coordination service, so the service's lifetime is
-        decoupled from every worker's (any worker death, rank 0
-        included, is then a recoverable peer failure) — and everyone
-        learns it over the host control plane.  The payload carries a
-        T|/L| marker so all members agree on where the service lives."""
-        if self._rank == 0:
-            if self._private_bindings_ok():
-                coord = self._request_tracker_service()
-            else:
-                coord = ""
-                self._log_stderr(
-                    "jaxlib private distributed-client bindings "
-                    "unavailable — FALLING BACK to rank-0-hosted "
-                    "coordination service; rank-0 death will NOT be "
-                    "recoverable")
-            payload = (f"T|{coord}" if coord else
-                       f"L|{self._coordinator_host()}:{_free_port()}"
-                       ).encode()
-        else:
-            payload = None
-        marker, _, coord = self._inner.broadcast(
-            payload, root=0).decode().partition("|")
-        self._svc_tracker_hosted = marker == "T"
-        return coord
+        """Rank 0 obtains a fresh TRACKER-HOSTED coordination service —
+        its lifetime is decoupled from every worker's, so any worker
+        death, rank 0 included, is a recoverable peer failure — and
+        everyone learns its address over the host control plane ("" if
+        the tracker could not host one)."""
+        payload = (self._request_tracker_service().encode()
+                   if self._rank == 0 else None)
+        return self._inner.broadcast(payload, root=0).decode()
 
     def _connect_distributed(self, coord: str,
                              init_timeout: int | None = None) -> None:
-        """Join the JAX coordination service at ``coord``.
+        """Join the tracker-hosted JAX coordination service at ``coord``.
 
         Built on the jaxlib distributed-runtime bindings directly
         because every rank here is a CLIENT — the service itself runs in
@@ -524,88 +442,42 @@ class XLAEngine(Engine):
         reference survives any single death the same way,
         reference: src/allreduce_robust.cc:426-453);
         ``shutdown_on_destruction=False`` keeps a dropped client's
-        destructor from RPC-ing a dead service.  Falls back to the
-        public API (rank 0 hosting, round-2 behavior) if the private
-        bindings move."""
-        import jax
+        destructor from RPC-ing a dead service.  One installation: these
+        are the jaxlib 0.9 signatures, called as they are."""
+        from jax._src import distributed as jdist
+        from jax._src.lib import _jax as jaxlib_ext
 
-        try:
-            from jax._src import distributed as jdist
-            from jax._src.lib import _jax as jaxlib_ext
-
-            state = jdist.global_state
-            check(state.client is None,
-                  "XLA engine: JAX distributed client already exists")
-            if (self._rank == 0 and not self._svc_tracker_hosted
-                    and state.service is None):
-                bind = "[::]:" + coord.rsplit(":", 1)[1]
-                # long barrier deadline for the same reason as the
-                # tracker-hosted service: a formation-window death must
-                # surface as the clients' local timeouts, not a
-                # service-pushed fatal (client.h:80)
-                try:
-                    state.service = \
-                        jaxlib_ext.get_distributed_runtime_service(
-                            bind, self._world,
-                            cluster_register_timeout=24 * 3600)
-                except TypeError:  # older jaxlib without the kwarg
-                    state.service = \
-                        jaxlib_ext.get_distributed_runtime_service(
-                            bind, self._world)
-            client = jaxlib_ext.get_distributed_runtime_client(
-                coord, self._rank,
-                init_timeout=init_timeout or self._init_timeout,
-                use_compression=True,
-                shutdown_on_destruction=False,
-                recoverable=True)
-            client.connect()
-            self._log_stderr(f"rank {self._rank} joined coordination "
-                             f"service {coord}")
-            state.client = client
-            state.coordinator_address = coord
-            state.num_processes = self._world
-            state.process_id = self._rank
-            self._custom_client = True
-        except (ImportError, AttributeError, TypeError) as e:
-            # Private bindings changed shape — use the public API (rank 0
-            # hosts the service; its death is then fatal to survivors,
-            # the round-2 contract).
-            self._log_stderr(
-                f"private distributed-client path failed "
-                f"({type(e).__name__}: {e}) — FALLING BACK to public "
-                "jax.distributed.initialize; rank-0 death will NOT be "
-                "recoverable")
-            self._svc_tracker_hosted = False
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coord,
-                    num_processes=self._world,
-                    process_id=self._rank,
-                    initialization_timeout=(init_timeout
-                                            or self._init_timeout),
-                )
-            except TypeError:  # older jax without the kwarg
-                jax.distributed.initialize(
-                    coordinator_address=coord,
-                    num_processes=self._world,
-                    process_id=self._rank,
-                )
-            self._custom_client = False
+        if not coord:
+            # an OSError: _is_runtime_failure degrades instead of aborting
+            raise ConnectionError(
+                "the tracker could not host a JAX coordination service")
+        state = jdist.global_state
+        check(state.client is None,
+              "XLA engine: JAX distributed client already exists")
+        client = jaxlib_ext.get_distributed_runtime_client(
+            coord, self._rank,
+            init_timeout=init_timeout or self._init_timeout,
+            use_compression=True,
+            shutdown_on_destruction=False,
+            recoverable=True)
+        client.connect()
+        self._log_stderr(f"rank {self._rank} joined coordination "
+                         f"service {coord}")
+        state.client = client
+        state.coordinator_address = coord
+        state.num_processes = self._world
+        state.process_id = self._rank
 
     def _drop_distributed_state(self) -> None:
         """Reset jax.distributed bookkeeping WITHOUT the disconnect RPC
-        (the coordination service is known dead — rank 0's incarnation
-        that owned it is gone; an RPC would block and, under the default
-        callback, fatally terminate this process)."""
-        try:
-            from jax._src import distributed as jdist
+        (for a group that never formed or whose members are gone: an
+        RPC would block and, under the default callback, fatally
+        terminate this process)."""
+        from jax._src import distributed as jdist
 
-            state = jdist.global_state
-            state.client = None
-            state.service = None
-            state.coordinator_address = None
-        except (ImportError, AttributeError):  # pragma: no cover
-            pass
+        state = jdist.global_state
+        state.client = None
+        state.coordinator_address = None
         self._we_initialized_jax = False
 
     def _shutdown_distributed_ordered(self) -> None:
@@ -660,19 +532,19 @@ class XLAEngine(Engine):
         survivor into the reform).  Protocol, all ranks symmetric:
 
         1. host-plane MAX-allreduce of per-rank state flags
-           (bit0 degraded, bit1 member-of-current-JAX-group, bit2
-           member's group used a tracker-hosted service);
+           (bit0 degraded, bit1 member-of-current-JAX-group);
         2. if nobody is degraded -> done (one small host op per
            checkpoint);
-        3. tear down the old group — ordered disconnect when the old
-           coordination service is still alive (tracker-hosted, or its
-           rank-0 owner survived), raw state drop when it died;
+        3. tear down the old group — ordered disconnect from its
+           tracker-hosted coordination service (which outlives every
+           worker) when anyone is still a member, raw state drop
+           otherwise;
         4. destroy device backends (compiled executables and device
            arrays of the old epoch die with them);
-        5. rank 0 obtains a fresh coordination service (tracker-hosted
-           when possible) and broadcasts it over the host plane;
-           everyone re-initializes, rebuilds the process mesh, clears
-           the collective cache, bumps device_epoch.
+        5. rank 0 obtains a fresh tracker-hosted coordination service
+           and broadcasts it over the host plane; everyone
+           re-initializes, rebuilds the process mesh, clears the
+           collective cache, bumps device_epoch.
 
         A failed re-formation (e.g. another death mid-reform) leaves
         every reachable rank degraded; the next checkpoint retries with
@@ -685,11 +557,8 @@ class XLAEngine(Engine):
         import jax.extend  # jax.extend is not imported by bare `import jax`
 
         flags = np.zeros(self._world, np.uint8)
-        mine = (1 if self._degraded else 0) | (
-            2 if self._we_initialized_jax else 0) | (
-            4 if self._we_initialized_jax and self._svc_tracker_hosted
-            else 0)
-        flags[self._rank] = mine
+        flags[self._rank] = (1 if self._degraded else 0) | (
+            2 if self._we_initialized_jax else 0)
         self._inner.allreduce(flags, ReduceOp.MAX)
         if not (flags & 1).any():
             return
@@ -697,12 +566,11 @@ class XLAEngine(Engine):
         # structure (and its control-plane op sequence) is identical on
         # members and relaunched incarnations alike
         members_exist = bool((flags & 2).any())
-        service_alive = bool((flags & 4).any()) or bool(flags[0] & 2)
         self._log_stderr(
             f"re-forming device plane (degraded ranks: "
-            f"{[int(r) for r in np.flatnonzero(flags & 1)]}, old service "
-            f"{'alive' if members_exist and service_alive else 'dead'})")
-        if members_exist and service_alive:
+            f"{[int(r) for r in np.flatnonzero(flags & 1)]}, old group "
+            f"{'has members' if members_exist else 'is gone'})")
+        if members_exist:
             # ordered disconnect; ranks that were never members of the
             # old group (relaunched incarnations) drop their (empty)
             # state but MUST still join both barriers — every rank's
@@ -768,36 +636,54 @@ class XLAEngine(Engine):
         self._log_stderr(
             f"device plane re-formed (epoch {self._device_epoch})")
 
-    def _coordinator_host(self) -> str:
-        """Interface the other hosts can reach this process on: the one
-        that routes to the tracker (works for any inner engine)."""
-        from rabit_tpu.utils.net import routable_ip
+    def _build_proc_mesh(self, aligned: bool = False) -> None:
+        """One device per process, ordered by control-plane rank: mesh
+        position ``r`` holds rank ``r``'s device, or allgather rows and
+        broadcast roots would be misattributed.
 
-        return routable_ip(self._tracker_addr)
+        On the CPU backend ``jax.process_index()`` IS the rank (the node
+        id this process joined the coordination service with).  On a TPU
+        it is the chip runtime's own numbering, fixed by which chip the
+        process was given and not by any task id the launcher sets (of
+        the four v5e chips of one host, the process that sees chip 0 is
+        process 3).  So every rank publishes its process index in the
+        coordination service's key-value store — not a host-plane op, so
+        version span 0 stays free of engine-internal collectives — and
+        the mesh is ordered by what all of them read back.
 
-    def _build_proc_mesh(self) -> None:
-        """One device per process, ordered by control-plane rank."""
+        ``aligned`` (mixed mode) additionally requires the two
+        numberings to be identical; the verdict rests on the shared
+        table, so every rank reaches the same one."""
         import jax
+        from jax._src import distributed as jdist
         from jax.sharding import Mesh
 
         check(jax.process_count() == self._world,
               "XLA engine: JAX world (%d) != tracker world (%d)",
               jax.process_count(), self._world)
-        # Mesh positions are ordered by process_index while engine.rank is
-        # the control-plane rank — the two must be the same numbering, or
-        # allgather rows / broadcast roots would be misattributed.
-        check(jax.process_index() == self._rank,
-              "XLA engine: jax.process_index() (%d) != control-plane rank "
-              "(%d); launch so that process ids match tracker ranks",
-              jax.process_index(), self._rank)
         per_proc: dict[int, jax.Device] = {}
         for d in jax.devices():
             per_proc.setdefault(d.process_index, d)
         check(len(per_proc) == self._world,
               "XLA engine: %d processes own devices, expected %d",
               len(per_proc), self._world)
-        devs = [per_proc[p] for p in sorted(per_proc)]
-        self._proc_mesh = Mesh(np.array(devs), (PROC_AXIS,))
+        kv = jdist.global_state.client
+        key = "rabit_tpu/process_index_of_rank/%d"
+        kv.key_value_set(key % self._rank, str(jax.process_index()),
+                         allow_overwrite=True)
+        proc_of_rank = [
+            int(kv.blocking_key_value_get(key % r,
+                                          self._init_timeout * 1000))
+            for r in range(self._world)]
+        check(sorted(proc_of_rank) == sorted(per_proc),
+              "XLA engine: ranks report process indices %s, devices "
+              "belong to %s", proc_of_rank, sorted(per_proc))
+        check(not aligned or proc_of_rank == list(range(self._world)),
+              "XLA engine: jax.process_index() of ranks 0..%d is %s (this "
+              "rank: %d); launch so that process ids match tracker ranks",
+              self._world - 1, proc_of_rank, jax.process_index())
+        self._proc_mesh = Mesh(
+            np.array([per_proc[p] for p in proc_of_rank]), (PROC_AXIS,))
 
     def _build_proc_mesh_mixed(self) -> None:
         """Mesh build for MIXED mode (tracker + adopted external JAX).
@@ -851,7 +737,7 @@ class XLAEngine(Engine):
             return
         err: Exception | None = None
         try:
-            self._build_proc_mesh()
+            self._build_proc_mesh(aligned=True)
         except Exception as e:  # noqa: BLE001 — consensus decides below
             err = e
         from jax.experimental import multihost_utils
@@ -1155,10 +1041,11 @@ class XLAEngine(Engine):
             return False
         import jax
 
-        if jax.default_backend() != "tpu" and jax.process_count() > 1:
-            return False
+        from rabit_tpu.ops import on_tpu
         from rabit_tpu.ops.ring_allreduce import supported_ops
 
+        if not on_tpu() and jax.process_count() > 1:
+            return False
         if op not in supported_ops():
             return False
         nbytes = int(np.prod(shape, dtype=np.int64)) * \

@@ -24,7 +24,7 @@ import numpy as np
 
 import rabit_tpu
 from rabit_tpu.learn import histogram
-from rabit_tpu.ops import MAX, SUM
+from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils.checks import check
 
 
@@ -173,7 +173,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     # the (f, n) layout; transpose once, reuse every node/level/round
     import jax
     bins_t = (jax.numpy.asarray(bins).T
-              if jax.default_backend() == "tpu" else None)
+              if on_tpu() else None)
 
     epoch = rabit_tpu.device_epoch()
     for round_idx in range(version, num_round):

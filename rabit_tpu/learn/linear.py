@@ -20,6 +20,7 @@ import rabit_tpu
 from rabit_tpu.learn.data import SparseMat, load_libsvm
 from rabit_tpu.learn.lbfgs import LBFGSSolver, ObjFunction
 from rabit_tpu.ops import MAX
+from rabit_tpu.utils import compile_cache
 from rabit_tpu.utils.checks import check
 from rabit_tpu.utils.serial import Base64InStream, Base64OutStream
 
@@ -338,6 +339,7 @@ def main(argv: list[str]) -> int:
             rabit_tpu.tracker_print("Usage: <data_in> param=val")
         rabit_tpu.finalize()
         return 0
+    compile_cache.enable()
     obj = LinearObjFunction()
     if argv[1] == "stdin":
         obj.load_data(argv[1])

@@ -21,7 +21,24 @@ from rabit_tpu.ops.reduce_ops import (
     apply_op_pairwise,
 )
 
+
+
+def on_tpu() -> bool:
+    """THE place that decides "the chip's compiler, or not".
+
+    Every Pallas kernel's ``interpret=None`` default, every learner's
+    ``use_pallas=None`` default and the sparse staging tier ask this one
+    function, so a run that must be on the chip (``chip_smoke.py``,
+    ``bench.py``) asserts it once up front and knows that no path below
+    silently took the interpreter or the XLA formulation instead.
+    Tests steer explicitly (``interpret=True`` / ``use_pallas=...``)."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 __all__ = [
+    "on_tpu",
     "ReduceOp",
     "MAX",
     "MIN",

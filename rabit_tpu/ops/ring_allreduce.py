@@ -32,10 +32,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from rabit_tpu.ops import on_tpu
 from rabit_tpu.ops.reduce_ops import ReduceOp
 
 _LOGICAL = pltpu.DeviceIdType.LOGICAL
 _NSLOTS = 2
+_LANES = 128
 
 _COMBINE = {
     ReduceOp.SUM: jnp.add,
@@ -44,9 +46,14 @@ _COMBINE = {
     ReduceOp.PROD: jnp.multiply,
 }
 
-# Budget for on-chip buffers: x + out + comm slots must fit VMEM with
-# headroom (~16 MB/core).  Larger payloads are segmented by the wrapper.
+# Budget for the two full-payload VMEM buffers (x + out); the comm slots
+# add 2/ndev of one of them on top.  Larger payloads are segmented by the
+# wrapper.  The kernel asks Mosaic for exactly what it uses plus
+# headroom (``vmem_limit_bytes``) rather than relying on the scoped
+# default (16 MiB on v5e), so the check against the chip's limit is the
+# compiler's own.
 _VMEM_BUDGET_BYTES = 8 << 20
+_VMEM_HEADROOM_BYTES = 4 << 20
 
 
 def supported_ops():
@@ -59,8 +66,13 @@ def _ring_kernel(x_ref, out_ref, comm_ref, send_sem, recv_sem, cap_sem,
                  *, ndev: int, combine, axis_name: str):
     """One full allreduce: reduce-scatter then all-gather on a ring.
 
-    Refs: ``x_ref``/``out_ref`` are (ndev, chunk) in VMEM; ``comm_ref``
-    is the (_NSLOTS, chunk) landing pad written by the left neighbour.
+    Refs: ``x_ref``/``out_ref`` are (ndev, rows, 128) in VMEM;
+    ``comm_ref`` is the (_NSLOTS, rows, 128) landing pad written by the
+    left neighbour.  The per-hop chunk is selected on the LEADING,
+    untiled dimension: Mosaic tiles the last two dimensions (sublanes x
+    lanes), so a one-row slice of a 2-D (ndev, chunk) buffer is refused
+    on the chip ("Slice shape along dimension 0 must be aligned to
+    tiling") although the interpreter accepts it.
     """
     my_id = lax.axis_index(axis_name)
     right = lax.rem(my_id + 1, ndev)
@@ -99,8 +111,8 @@ def _ring_kernel(x_ref, out_ref, comm_ref, send_sem, recv_sem, cap_sem,
             pltpu.semaphore_wait(cap_sem.at[slot], 1)
 
         rdma = pltpu.make_async_remote_copy(
-            src_ref=out_ref.at[pl.ds(send_idx, 1)],
-            dst_ref=comm_ref.at[pl.ds(slot, 1)],
+            src_ref=out_ref.at[send_idx],
+            dst_ref=comm_ref.at[slot],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[slot],
             device_id=right,
@@ -109,10 +121,9 @@ def _ring_kernel(x_ref, out_ref, comm_ref, send_sem, recv_sem, cap_sem,
         rdma.start()
         rdma.wait()
 
-        incoming = comm_ref[pl.ds(slot, 1), :]
-        current = out_ref[pl.ds(recv_idx, 1), :]
-        out_ref[pl.ds(recv_idx, 1), :] = jnp.where(
-            is_rs, combine(current, incoming), incoming)
+        incoming = comm_ref[slot]
+        out_ref[recv_idx] = jnp.where(
+            is_rs, combine(out_ref[recv_idx], incoming), incoming)
 
         # ack to the sender (my left neighbour): slot drained
         pltpu.semaphore_signal(cap_sem.at[slot], inc=1, device_id=left,
@@ -131,27 +142,28 @@ def _ring_kernel(x_ref, out_ref, comm_ref, send_sem, recv_sem, cap_sem,
     lax.fori_loop(0, min(_NSLOTS, 2 * nphase), drain, 0)
 
 
-def _segment_allreduce(seg, axis_name, ndev, chunk, op, interpret,
-                       collective_id):
-    combine = _COMBINE[op]
-    kern = functools.partial(_ring_kernel, ndev=ndev, combine=combine,
+def _segment_allreduce(seg, axis_name, op, interpret, collective_id):
+    """Ring-allreduce one (ndev, rows, 128) segment resident in VMEM."""
+    ndev, rows, _ = seg.shape
+    kern = functools.partial(_ring_kernel, ndev=ndev, combine=_COMBINE[op],
                              axis_name=axis_name)
-    out = pl.pallas_call(
+    vmem_bytes = (2 * ndev + _NSLOTS) * rows * _LANES * seg.dtype.itemsize
+    return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((ndev, chunk), seg.dtype),
+        out_shape=jax.ShapeDtypeStruct(seg.shape, seg.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((_NSLOTS, chunk), seg.dtype),
+            pltpu.VMEM((_NSLOTS, rows, _LANES), seg.dtype),
             pltpu.SemaphoreType.DMA((_NSLOTS,)),
             pltpu.SemaphoreType.DMA((_NSLOTS,)),
             pltpu.SemaphoreType.REGULAR((_NSLOTS,)),
         ],
         compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=collective_id),
+            has_side_effects=True, collective_id=collective_id,
+            vmem_limit_bytes=vmem_bytes + _VMEM_HEADROOM_BYTES),
         interpret=pltpu.InterpretParams() if interpret else False,
     )(seg)
-    return out
 
 
 def ring_allreduce_pallas(x: jax.Array, axis_name: str,
@@ -161,9 +173,10 @@ def ring_allreduce_pallas(x: jax.Array, axis_name: str,
     """Allreduce ``x`` (same shape on every device) along ``axis_name``.
 
     Call inside ``shard_map``.  Pads the flattened payload to
-    ``ndev × chunk`` with 128-aligned chunks, runs the ring kernel per
-    VMEM-sized segment, and restores the original shape.  ``interpret``
-    defaults to auto (True off-TPU so tests run on the CPU mesh).
+    ``ndev`` chunks of whole (sublane, 128-lane) tiles, runs the ring
+    kernel per VMEM-sized segment, and restores the original shape.
+    ``interpret`` defaults to :func:`rabit_tpu.ops.on_tpu` (interpreted
+    off-TPU so tests run on the CPU mesh).
     """
     if op not in _COMBINE:
         raise ValueError(f"ring_allreduce_pallas: unsupported op {op}")
@@ -171,27 +184,28 @@ def ring_allreduce_pallas(x: jax.Array, axis_name: str,
     if ndev == 1:
         return x
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
 
     flat = x.reshape(-1)
     size = flat.shape[0]
-    chunk = max(128, -(-size // ndev))
-    chunk = -(-chunk // 128) * 128
+    itemsize = flat.dtype.itemsize
+    # one chunk per device, in whole tiles: 8 sublanes of 32-bit words,
+    # 16 of 16-bit, 32 of 8-bit — so no hop moves a partial tile
+    sublanes = 8 * max(1, 4 // itemsize)
+    rows = -(-size // (ndev * _LANES))
+    rows = -(-rows // sublanes) * sublanes
 
-    # segment so (x + out + slots) stays inside the VMEM budget
-    bytes_per = ndev * chunk * flat.dtype.itemsize
-    nseg = max(1, -(-2 * bytes_per // _VMEM_BUDGET_BYTES))
-    seg_chunk = -(-chunk // (128 * nseg)) * 128
-    nseg = -(-chunk // seg_chunk)
+    # segment so (x + out) stays inside the VMEM budget
+    nseg = max(1, -(-2 * ndev * rows * _LANES * itemsize
+                    // _VMEM_BUDGET_BYTES))
+    seg_rows = -(-rows // (sublanes * nseg)) * sublanes
+    nseg = -(-rows // seg_rows)
 
-    padded = jnp.zeros((ndev * nseg * seg_chunk,), flat.dtype
+    padded = jnp.zeros((ndev * nseg * seg_rows * _LANES,), flat.dtype
                        ).at[:size].set(flat)
-    segs = padded.reshape(ndev, nseg, seg_chunk)
-
-    outs = []
-    for s in range(nseg):
-        outs.append(_segment_allreduce(
-            segs[:, s, :], axis_name, ndev, seg_chunk, op, interpret,
-            collective_id))
+    segs = padded.reshape(ndev, nseg, seg_rows, _LANES)
+    outs = [_segment_allreduce(segs[:, s], axis_name, op, interpret,
+                               collective_id)
+            for s in range(nseg)]
     out = jnp.stack(outs, axis=1).reshape(-1)[:size]
     return out.reshape(x.shape)

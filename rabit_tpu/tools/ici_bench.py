@@ -33,7 +33,7 @@ def bench_impl(impl: str, ndev: int, size: int, reps: int) -> float:
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from rabit_tpu.ops import ReduceOp
+    from rabit_tpu.ops import ReduceOp, on_tpu
 
     avail = len(jax.devices())
     if ndev > avail:
@@ -42,7 +42,7 @@ def bench_impl(impl: str, ndev: int, size: int, reps: int) -> float:
             "visible (on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={ndev})")
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("x",))
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
 
     def one(x):
         if impl == "psum":

@@ -98,15 +98,10 @@ def main(argv: list[str]) -> int:
     nrep = int(argv[2]) if len(argv) > 2 else 100
     device = len(argv) > 3 and argv[3] == "device"
     checkpoint_every = int(os.environ.get("RABIT_SPEED_CHECKPOINT", "0"))
-    if device and os.environ.get("RABIT_JAX_CPU"):
-        # Multi-process device runs on a machine whose accelerator can't
-        # host several JAX processes (e.g. one shared chip): pin the CPU
-        # backend BEFORE any jax use — env alone is not honoured when a
-        # platform plugin pins the default (see tests/conftest.py).
-        import jax
+    if device:
+        from rabit_tpu.utils import compile_cache
 
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", 1)
+        compile_cache.enable()
     rabit_tpu.init()
     results = run(ndata, nrep, device, checkpoint_every)
     if rabit_tpu.get_rank() == 0:

@@ -12,6 +12,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import random
 import signal
@@ -26,6 +27,69 @@ from rabit_tpu.tracker.tracker import Tracker
 # reference uses exit(-2) == 254 (src/allreduce_mock.h:165-171,
 # tracker/rabit_demo.py:28-40); we keep the same convention.
 RESTART_EXIT_CODE = 254
+
+
+# One process per chip on ONE host: libtpu's process bounds by world
+# size, the layout jax's own multi-process TPU harness uses.  Only the
+# shape this repository has run on chips is listed (four v5e, PR 21).
+_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
+def local_chips() -> int:
+    """TPU chips this host exposes to its processes, counted from the
+    device nodes — no JAX, no libtpu: a launcher that opened the chip
+    would hold what its children need."""
+    return len(glob.glob("/dev/vfio/[0-9]*")
+               + glob.glob("/dev/accel[0-9]*"))
+
+
+def chip_envs(n_workers: int) -> list[dict[str, str]]:
+    """Per-child environment that gives each of ``n_workers`` local
+    children exactly ONE chip (doc/scaling.md "One process per chip").
+
+    A chip belongs to one process at a time, and a child that inherits
+    the host's whole-slice environment tries to open every chip, so N
+    children collide.  libtpu's per-process visibility and
+    process-bounds variables split the host instead: child ``i`` sees
+    chip ``i`` as task ``i`` of an N-process slice, and the runtimes
+    find each other on the listed local ports.  Which
+    ``jax.process_index()`` a child ends up with is the chip runtime's
+    business (by chip position, not by task id); the XLA engine orders
+    its process mesh by tracker rank whatever it is.
+
+    Returns empty dicts where the host has no chips to give (CPU test
+    runs) — and says so on stderr when it has chips but not one per
+    worker, because those children then share or miss the chip."""
+    chips = local_chips()
+    if chips == 0:
+        return [{}] * n_workers
+    if n_workers > chips or n_workers not in _PROCESS_BOUNDS:
+        sys.stderr.write(
+            f"[launch] host exposes {chips} TPU chip(s); {n_workers} "
+            "workers cannot each own one (supported: "
+            f"{sorted(_PROCESS_BOUNDS)} <= chips) — children are NOT "
+            "pinned to chips\n")
+        return [{}] * n_workers
+    from rabit_tpu.utils.net import free_port
+
+    ports = [free_port() for _ in range(n_workers)]
+    addrs = ",".join(f"localhost:{p}" for p in ports)
+    bounds = _PROCESS_BOUNDS[n_workers]
+    return [{
+        "TPU_VISIBLE_CHIPS": str(i),
+        "CLOUD_TPU_TASK_ID": str(i),
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": addrs,
+        "TPU_PROCESS_PORT": str(ports[i]),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+        # the older aliases of the same settings, kept consistent so a
+        # host image that exports them whole-slice cannot contradict
+        "TPU_WORKER_ID": str(i),
+        "TPU_HOST_BOUNDS": bounds,
+        "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_WORKER_HOSTNAMES": ",".join(["localhost"] * n_workers),
+    } for i in range(n_workers)]
 
 
 def is_dead_exit(code: int, remote: bool = False) -> bool:
@@ -265,6 +329,7 @@ def launch(n_workers: int, cmd: list[str], max_trials: int = 10,
     on_dead = make_dead_killer(live, started, lock, watchdog_killed,
                                heartbeat_sec, "launch_local")
 
+    chips = chip_envs(n_workers)
     tracker = Tracker(n_workers, watchdog_sec=watchdog_sec,
                       on_stall=on_stall if watchdog_sec else None,
                       obs_dir=obs_dir,
@@ -283,6 +348,7 @@ def launch(n_workers: int, cmd: list[str], max_trials: int = 10,
             env.update(extra_env or {})
             env.update(tracker.worker_env(task_id=str(worker_id),
                                           job=job))
+            env.update(chips[worker_id])
             env["RABIT_NUM_TRIAL"] = str(trial)
             # Total restarts of any cause.  Distinct from RABIT_NUM_TRIAL,
             # which counts only kill-point deaths so deterministic mock

@@ -97,7 +97,7 @@ def launch_pod(cmd: list[str], hosts: list[str] | None = None,
     import time
     import uuid
 
-    from rabit_tpu.tracker.launch_local import (is_dead_exit,
+    from rabit_tpu.tracker.launch_local import (chip_envs, is_dead_exit,
                                                 is_watchdog_exit,
                                                 make_dead_killer,
                                                 make_stall_killer,
@@ -154,6 +154,9 @@ def launch_pod(cmd: list[str], hosts: list[str] | None = None,
                                kill_fn=_kill_worker)
 
     elastic = min_workers is not None or max_workers is not None
+    # --local children share this host's chips, one each; over ssh each
+    # worker owns its whole host and the platform's own environment
+    chips = chip_envs(world) if not hosts else [{}] * world
     tracker = Tracker(world, host=tracker_host
                       or (routable_ip() if hosts else "127.0.0.1"),
                       watchdog_sec=watchdog_sec,
@@ -192,6 +195,7 @@ def launch_pod(cmd: list[str], hosts: list[str] | None = None,
             return subprocess.Popen(full)
         penv = dict(os.environ)
         penv.update(env)
+        penv.update(chips[i])
         return subprocess.Popen(cmd, env=penv)
 
     def run_one(i: int) -> None:

@@ -2142,11 +2142,12 @@ class Tracker:
         the SAME wildcard namespace the service will use (IPv6 any,
         falling back to IPv4 any on IPv6-less hosts), and the residual
         probe-close -> service-bind race is handled by retrying with a
-        fresh port instead of failing the job over to the
-        rank-0-hosted path."""
+        fresh port.  Importing jaxlib here initialises no backend: the
+        tracker (and the launcher that hosts it) never touches a chip,
+        which its children need whole."""
         try:
             from jax._src.lib import _jax as jaxlib_ext
-        except Exception as e:  # noqa: BLE001
+        except ImportError as e:  # a tracker-only host without jax
             log("tracker: cannot host jax coordination service: %s", e)
             return 0
         last: Exception | None = None
@@ -2175,13 +2176,9 @@ class Tracker:
                 # start), never as the service's barrier deadline,
                 # which is pushed to registered clients as a FATAL
                 # error (client.h:80 terminates them).
-                try:
-                    svc = jaxlib_ext.get_distributed_runtime_service(
-                        f"{bind_host}:{port}", world,
-                        cluster_register_timeout=24 * 3600)
-                except TypeError:  # older jaxlib without the kwarg
-                    svc = jaxlib_ext.get_distributed_runtime_service(
-                        f"{bind_host}:{port}", world)
+                svc = jaxlib_ext.get_distributed_runtime_service(
+                    f"{bind_host}:{port}", world,
+                    cluster_register_timeout=24 * 3600)
             except Exception as e:  # noqa: BLE001 — port race: retry
                 last = e
                 continue

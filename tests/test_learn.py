@@ -169,6 +169,29 @@ def test_kmeans_checkpoint_resume(empty_engine):
         resumed.centroids, full.centroids, rtol=1e-5, atol=1e-6)
 
 
+def test_kmeans_chained_resume_counts_chains(empty_engine):
+    """The chained path commits one version per CHAIN, so a resume
+    continues at version * device_chain iterations — not at `version`
+    iterations, which re-ran most of the committed work and committed
+    one version too many."""
+    import rabit_tpu
+    from rabit_tpu.learn import kmeans
+
+    data, _ = _blob_data()
+    full = kmeans.run(data, num_cluster=3, max_iter=6, row_block=64,
+                      device_chain=3)
+    assert rabit_tpu.version_number() == 2
+    rabit_tpu.finalize()
+    rabit_tpu.init(rabit_engine="empty")
+    kmeans.run(data, num_cluster=3, max_iter=3, row_block=64,
+               device_chain=3)
+    resumed = kmeans.run(data, num_cluster=3, max_iter=6, row_block=64,
+                         device_chain=3)
+    assert rabit_tpu.version_number() == 2
+    np.testing.assert_allclose(
+        resumed.centroids, full.centroids, rtol=1e-5, atol=1e-6)
+
+
 def test_kmeans_stats_against_numpy(empty_engine):
     from rabit_tpu.learn import kmeans
 

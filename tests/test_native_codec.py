@@ -115,14 +115,19 @@ def test_auto_fallback_warns_exactly_once(monkeypatch):
     monkeypatch.setattr(kernel_mod, "_kernel", None)
     monkeypatch.setattr(kernel_mod, "_load_error", "no lib (simulated)")
     monkeypatch.setattr(kernel_mod, "_warned", False)
+    from rabit_tpu.obs.log import Logger
+
     warnings = []
 
-    class Log:
-        def warning(self, msg, *a):
-            warnings.append(msg % a if a else msg)
+    class Log(Logger):
+        # the engines' real logger class: a method it does not have
+        # (this path once called ``log.warning``) must fail here, not
+        # at the first engine init on a box without the built library
+        def _emit(self, level, fmt, *a):
+            warnings.append(fmt % a if a else fmt)
 
     for _ in range(3):
-        k, label = kernel_mod.resolve_impl("auto", log=Log())
+        k, label = kernel_mod.resolve_impl("auto", log=Log("test"))
         assert k is None and label == "numpy-fallback"
     assert len(warnings) == 1, warnings
     assert "numpy" in warnings[0]

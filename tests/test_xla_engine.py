@@ -234,39 +234,6 @@ def test_xla_world8_rank0_then_another_consecutive_checkpoints(request):
     assert code == 0
 
 
-def test_private_bindings_probe(monkeypatch):
-    """The jaxlib-capability probe is a try-call, not a doc-grep: it
-    must track what the binding actually ACCEPTS, surviving docstring
-    wording churn and stripped docstrings (python -OO)."""
-    from jax._src.lib import _jax as jaxlib_ext
-
-    from rabit_tpu.engine.xla import XLAEngine
-
-    class _Client:
-        pass
-
-    def accepts(addr, node_id, *, init_timeout,
-                shutdown_on_destruction, recoverable):
-        return _Client()
-
-    def rejects(addr, node_id, *, init_timeout):  # no recoverable kwargs
-        return _Client()
-
-    def env_error(addr, node_id, *, init_timeout,
-                  shutdown_on_destruction, recoverable):
-        raise RuntimeError("address unreachable")  # kwargs were accepted
-
-    monkeypatch.setattr(
-        jaxlib_ext, "get_distributed_runtime_client", accepts)
-    assert XLAEngine._private_bindings_ok() is True
-    monkeypatch.setattr(
-        jaxlib_ext, "get_distributed_runtime_client", rejects)
-    assert XLAEngine._private_bindings_ok() is False
-    monkeypatch.setattr(
-        jaxlib_ext, "get_distributed_runtime_client", env_error)
-    assert XLAEngine._private_bindings_ok() is True
-
-
 def test_xla_death_inside_group_formation(request):
     """The window the design admits is awkward: a worker finishes the
     tracker round but dies BEFORE the JAX group forms.  Survivors must
