@@ -573,7 +573,7 @@ def open_device(want_chips: int):
          bytes_limit=stats.get("bytes_limit"),
          compile_cache_dir=compile_cache.enable(),
          compile_cache_env=os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-    return devs, compile_cache.CompileClock()
+    return devs, compile_cache.count_compiles()
 
 
 def run_phase(name: str, clock, fn, *args) -> None:

@@ -19,6 +19,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from rabit_tpu import engine as _engine_mod
+from rabit_tpu.obs import program
 from rabit_tpu.ops import ReduceOp, SUM
 from rabit_tpu.utils.checks import check
 from rabit_tpu.utils.serial import deserialize_model, serialize_model
@@ -40,6 +41,8 @@ def init(args: Optional[list[str]] = None, **params: Any) -> None:
     """
     import os
 
+    from rabit_tpu.utils import compile_cache
+
     merged: dict[str, Any] = {}
     for key, val in os.environ.items():
         if key.startswith("RABIT_"):
@@ -49,7 +52,12 @@ def init(args: Optional[list[str]] = None, **params: Any) -> None:
             k, v = a.split("=", 1)
             merged[k] = v
     merged.update(params)
-    _engine_mod.init(merged)
+    with program.span("init"):
+        # before the engine for a process that already has JAX, after
+        # it for one whose engine brought JAX in
+        compile_cache.count_compiles()
+        _engine_mod.init(merged)
+        compile_cache.count_compiles()
 
 
 def finalize() -> None:
@@ -104,21 +112,23 @@ def allreduce(
     deterministic across ranks — like ``fuse`` on the async face.
     """
     eng = _engine_mod.get_engine()
-    if isinstance(data, np.ndarray):
-        check(data.flags.c_contiguous, "allreduce: array must be C-contiguous")
-        return eng.allreduce(data, op, prepare_fun, codec)
-    try:
-        import jax
-    except ImportError:  # pragma: no cover
-        jax = None
-    if jax is not None and isinstance(data, jax.Array):
-        return eng.allreduce(data, op, prepare_fun, codec)
-    # scalars / lists: round-trip through numpy
-    arr = np.asarray(data)
-    scalar = arr.ndim == 0
-    arr = np.atleast_1d(arr).copy()
-    out = eng.allreduce(arr, op, prepare_fun, codec)
-    return out[0] if scalar else out
+    with program.span("allreduce"):
+        if isinstance(data, np.ndarray):
+            check(data.flags.c_contiguous,
+                  "allreduce: array must be C-contiguous")
+            return eng.allreduce(data, op, prepare_fun, codec)
+        try:
+            import jax
+        except ImportError:  # pragma: no cover
+            jax = None
+        if jax is not None and isinstance(data, jax.Array):
+            return eng.allreduce(data, op, prepare_fun, codec)
+        # scalars / lists: round-trip through numpy
+        arr = np.asarray(data)
+        scalar = arr.ndim == 0
+        arr = np.atleast_1d(arr).copy()
+        out = eng.allreduce(arr, op, prepare_fun, codec)
+        return out[0] if scalar else out
 
 
 def allreduce_async(
@@ -244,24 +254,27 @@ def load_checkpoint(with_local: bool = False, into_global: Any = None,
     (mirroring the reference's LoadCheckPoint(ISerializable*) contract).
     """
     eng = _engine_mod.get_engine()
-    version, g, l = eng.load_checkpoint()
-    gobj = (deserialize_model(g, into_global)
-            if (g is not None and version > 0) else None)
-    if with_local:
-        lobj = (deserialize_model(l, into_local)
-                if (l is not None and version > 0) else None)
-        return version, gobj, lobj
-    return version, gobj
+    with program.span("load_checkpoint"):
+        version, g, l = eng.load_checkpoint()
+        gobj = (deserialize_model(g, into_global)
+                if (g is not None and version > 0) else None)
+        if with_local:
+            lobj = (deserialize_model(l, into_local)
+                    if (l is not None and version > 0) else None)
+            return version, gobj, lobj
+        return version, gobj
 
 
 def checkpoint(global_model: Any, local_model: Any = None) -> None:
     """Commit a checkpoint of the model(s); bumps the version
     (reference: rabit::CheckPoint, src/allreduce_robust.cc:242-295)."""
     eng = _engine_mod.get_engine()
-    eng.checkpoint(
-        serialize_model(global_model),
-        serialize_model(local_model) if local_model is not None else None,
-    )
+    with program.span("commit", version=eng.version_number + 1):
+        with program.span("commit.serialize"):
+            g = serialize_model(global_model)
+            l = (serialize_model(local_model)
+                 if local_model is not None else None)
+        eng.checkpoint(g, l)
 
 
 def lazy_checkpoint(global_model: Any) -> None:

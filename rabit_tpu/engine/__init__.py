@@ -14,6 +14,7 @@ kill points), ``xla`` (JAX/XLA collectives over the device mesh) and
 from __future__ import annotations
 
 from rabit_tpu.engine.interface import Engine
+from rabit_tpu.obs import program
 from rabit_tpu.utils.checks import check
 
 _engine: Engine | None = None
@@ -66,6 +67,7 @@ def init(params: dict | None = None) -> Engine:
     eng = _make_engine(name, params)
     eng.init(params)
     _engine = eng
+    program.attach(eng)     # program spans follow the engine's rabit_obs
     return eng
 
 
@@ -115,3 +117,4 @@ def finalize() -> None:
     if _engine is not None:
         _engine.shutdown()
         _engine = None
+        program.detach()

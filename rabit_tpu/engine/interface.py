@@ -22,6 +22,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from rabit_tpu.obs import program
 from rabit_tpu.ops import ReduceOp
 
 
@@ -283,6 +284,24 @@ class Engine(ABC):
         buffer of :class:`rabit_tpu.obs.EventTrace`.  Empty for
         uninstrumented engines or when telemetry is disabled."""
         return []
+
+    @property
+    def path_stats(self) -> dict:
+        """What the path through the program did in this process, flat
+        and JSON-serialisable (``dict[str, int | float]``): every
+        program span as ``<name>.n`` / ``<name>.total_s`` /
+        ``<name>.max_s`` and every counter under its own name
+        (:mod:`rabit_tpu.obs.program`; always on), merged with the
+        engine's own counters where it keeps some (the XLA engine's
+        ``device_ops`` / ``host_ops``)."""
+        return program.stats()
+
+    def event_trace(self):
+        """The engine's LIVE :class:`rabit_tpu.obs.EventTrace`, or
+        ``None`` when telemetry is off (the twin of :meth:`metrics`)."""
+        if not getattr(self, "_obs_on", False):
+            return None
+        return getattr(self, "_trace", None)
 
     def metrics(self):
         """The engine's LIVE :class:`rabit_tpu.obs.Metrics` registry,

@@ -168,7 +168,8 @@ class NativeEngine(Engine):
             obs.ship_summary(
                 self.tracker_print, self._log, type(self).__name__,
                 rank, world, self.stats(),
-                [e for e in self._trace.events() if e.get("name") != "op"])
+                [e for e in self._trace.events()
+                 if e.get("name") not in ("op", "span")])
         if self._obs_dir:
             obs.dump_events(self._log, self._obs_dir, rank,
                             self._trace.events())

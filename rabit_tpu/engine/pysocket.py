@@ -1373,7 +1373,7 @@ class PySocketEngine(Engine):
                 self.tracker_print, self._log, type(self).__name__,
                 self._rank, self._world, self._metrics.snapshot(),
                 [e for e in self._trace.events()
-                 if e.get("name") not in ("op", "sched")],
+                 if e.get("name") not in ("op", "sched", "span")],
                 job=self._job_id)
         if self._obs_dir:
             obs.dump_events(self._log, self._obs_dir, self._rank,

@@ -77,6 +77,7 @@ from rabit_tpu import obs
 from rabit_tpu import sched as sched_mod
 from rabit_tpu.engine.pysocket import (LinkError, PySocketEngine,
                                        WorldChangedError)
+from rabit_tpu.obs import program
 from rabit_tpu.ops import ReduceOp
 from rabit_tpu.tracker import protocol as P
 from rabit_tpu.utils.checks import RabitError, check, error
@@ -1087,32 +1088,34 @@ class PyRobustEngine(PySocketEngine):
             self._lazy_global = None
 
     def _commit_checkpoint(self) -> None:
-        if self._pending_lazy is not None:
-            self._lazy_global = self._pending_lazy
-            self._pending_lazy = None
-            self._global = b""
-        else:
-            self._global = self._pending_global
-            self._lazy_global = None
-        self._has_checkpoint = True
-        self._version += 1
-        if self._has_pending_local:
-            self._local_store[self._rank] = (self._version,
-                                             self._pending_local)
-            self._local = self._pending_local  # world-of-1 load path
-        self._cache.clear()
-        self._seq = 0
-        if self._obs_on:
-            self._metrics.counter("checkpoint.commits").inc()
-            # Live-plane gauge: the streamed frames carry it, so a
-            # /metrics scrape shows each rank's committed progress
-            # mid-run (the cmd=epoch poll only reports in elastic mode).
-            self._metrics.gauge("ckpt.committed_version").set(
-                self._version)
-            self._trace.emit("checkpoint", phase="commit", rank=self._rank,
-                             version=self._version)
-        if self._is_ckpt_writer():
-            self._persist_checkpoint()
+        with program.span("commit.apply"):
+            if self._pending_lazy is not None:
+                self._lazy_global = self._pending_lazy
+                self._pending_lazy = None
+                self._global = b""
+            else:
+                self._global = self._pending_global
+                self._lazy_global = None
+            self._has_checkpoint = True
+            self._version += 1
+            if self._has_pending_local:
+                self._local_store[self._rank] = (self._version,
+                                                 self._pending_local)
+                self._local = self._pending_local  # world-of-1 load path
+            self._cache.clear()
+            self._seq = 0
+            if self._obs_on:
+                self._metrics.counter("checkpoint.commits").inc()
+                # Live-plane gauge: the streamed frames carry it, so a
+                # /metrics scrape shows each rank's committed progress
+                # mid-run (the cmd=epoch poll only reports in elastic
+                # mode).
+                self._metrics.gauge("ckpt.committed_version").set(
+                    self._version)
+                self._trace.emit("checkpoint", phase="commit",
+                                 rank=self._rank, version=self._version)
+            if self._is_ckpt_writer():
+                self._persist_checkpoint()
 
     def _is_ckpt_writer(self) -> bool:
         return (self._ckpt_store is not None
@@ -1174,7 +1177,8 @@ class PyRobustEngine(PySocketEngine):
             return
         flag = K_CHECKPOINT | (K_LOCAL_CHK if self._has_pending_local else 0)
         version_before = self._version
-        self._recover_exec(flag, want_result=False)
+        with program.span("commit.barrier"):
+            self._recover_exec(flag, want_result=False)
         if self._version == version_before:  # not committed via catch-up
             if self._has_pending_local:
                 # Every rank exits the barrier on the same consensus
@@ -1193,7 +1197,8 @@ class PyRobustEngine(PySocketEngine):
         if (self._elastic or self._adapt) \
                 and self._poll_rescale_pending():
             ack |= K_RESCALE
-        self._recover_exec(ack, want_result=False)
+        with program.span("commit.ack"):
+            self._recover_exec(ack, want_result=False)
         if (self._elastic or self._adapt) \
                 and (self._last_agreed & K_RESCALE):
             # Some rank's poll saw a pending epoch; the OR-merged ack

@@ -39,13 +39,13 @@ from __future__ import annotations
 
 import os
 import socket as pysocket
-import time
 from typing import Callable, Optional
 
 import numpy as np
 
 from rabit_tpu import obs
 from rabit_tpu.engine.interface import CollectiveHandle, Engine
+from rabit_tpu.obs import program
 from rabit_tpu.ops import ReduceOp
 from rabit_tpu.utils.checks import check
 
@@ -112,9 +112,9 @@ class XLAEngine(Engine):
         self._pallas_min_bytes = 1 << 20
         # observable path counters (tests assert post-reform collectives
         # ride the device mesh again, not the degraded host path).
-        # Named path_stats because Engine.stats() is the telemetry
-        # snapshot method.
-        self.path_stats = {"device_ops": 0, "host_ops": 0}
+        # Reported in path_stats beside the program spans (so named
+        # because Engine.stats() is the telemetry snapshot method).
+        self._path_counts = {"device_ops": 0, "host_ops": 0}
         # Telemetry (rabit_tpu.obs): resolved in init().
         self._obs_on = False
         self._obs_dir: Optional[str] = None
@@ -246,16 +246,20 @@ class XLAEngine(Engine):
                     # checkpoint boundary re-forms the group.
                     self._degraded = True
                 else:
-                    try:
-                        self._init_jax_distributed(params)
-                    except Exception as e:  # noqa: BLE001
-                        if not _is_runtime_failure(e):
-                            raise
-                        self._log_stderr(
-                            "device group formation failed "
-                            f"({type(e).__name__}: {e}); starting degraded")
-                        self._drop_distributed_state()
-                        self._degraded = True
+                    with program.span("init.group"):
+                        try:
+                            self._init_jax_distributed(params)
+                        except Exception as e:  # noqa: BLE001
+                            if not _is_runtime_failure(e):
+                                raise
+                            self._log_stderr(
+                                "device group formation failed "
+                                f"({type(e).__name__}: {e}); starting "
+                                "degraded")
+                            self._drop_distributed_state()
+                            self._degraded = True
+                        if not (self._degraded or self._adopted_jax):
+                            self._build_proc_mesh()
         else:
             # No tracker: adopt whatever world JAX already lives in
             # (single process, or a pod slice launched by its own runtime).
@@ -267,7 +271,8 @@ class XLAEngine(Engine):
             self._world = jax.process_count()
             self._adopted_jax = self._world > 1
             self._no_host_transport = self._world > 1
-        if self._world > 1 and not self._degraded:
+        if (self._world > 1 and not self._degraded
+                and self._proc_mesh is None):
             if self._adopted_jax and not self._no_host_transport:
                 self._build_proc_mesh_mixed()
             else:
@@ -553,15 +558,21 @@ class XLAEngine(Engine):
         if (self._world <= 1 or self._adopted_jax or self._inner is None
                 or not self._reform_enabled):
             return
-        import jax
-        import jax.extend  # jax.extend is not imported by bare `import jax`
-
         flags = np.zeros(self._world, np.uint8)
         flags[self._rank] = (1 if self._degraded else 0) | (
             2 if self._we_initialized_jax else 0)
-        self._inner.allreduce(flags, ReduceOp.MAX)
+        with program.span("commit.reform_flags"):
+            self._inner.allreduce(flags, ReduceOp.MAX)
         if not (flags & 1).any():
             return
+        with program.span("commit.reform"):
+            self._reform(flags)
+
+    def _reform(self, flags) -> None:
+        """Steps 3-5 of :meth:`_maybe_reform`: some rank is degraded."""
+        import jax
+        import jax.extend  # jax.extend is not imported by bare `import jax`
+
         # every rank derives these from the SHARED flags, so the branch
         # structure (and its control-plane op sequence) is identical on
         # members and relaunched incarnations alike
@@ -847,10 +858,15 @@ class XLAEngine(Engine):
         if not self._obs_on or self._metrics is None:
             return {}  # disabled telemetry reports nothing (interface.py)
         self._metrics.gauge("xla.device_ops").set(
-            self.path_stats["device_ops"])
-        self._metrics.gauge("xla.host_ops").set(self.path_stats["host_ops"])
+            self._path_counts["device_ops"])
+        self._metrics.gauge("xla.host_ops").set(
+            self._path_counts["host_ops"])
         self._metrics.gauge("xla.device_epoch").set(self._device_epoch)
         return self._metrics.snapshot()
+
+    @property
+    def path_stats(self) -> dict:
+        return {**program.stats(), **self._path_counts}
 
     def events(self) -> list[dict]:
         own = self._trace.events() if self._trace is not None else []
@@ -986,7 +1002,7 @@ class XLAEngine(Engine):
             out = self._inner.allreduce(host.copy(), op)
         else:
             out = self._inner.allgather(host)
-        self.path_stats["host_ops"] += 1
+        self._path_counts["host_ops"] += 1
         if self._obs_on:
             self._metrics.counter("op.host_degraded.count").inc()
             self._metrics.counter("op.host_degraded.bytes").inc(host.nbytes)
@@ -1002,22 +1018,23 @@ class XLAEngine(Engine):
             check(arr.is_fully_replicated,
                   "XLA engine: global input arrays must be fully replicated")
             arr = arr.addressable_shards[0].data
-        local = jax.device_put(arr, jax.local_devices()[0])[None]
-        global_shape = (self._world,) + tuple(arr.shape)
-        garr = jax.make_array_from_single_device_arrays(
-            global_shape,
-            NamedSharding(self._proc_mesh, P(PROC_AXIS)),
-            [local],
-        )
+        with program.span(kind + ".stage"):
+            local = jax.device_put(arr, jax.local_devices()[0])[None]
+            global_shape = (self._world,) + tuple(arr.shape)
+            garr = jax.make_array_from_single_device_arrays(
+                global_shape,
+                NamedSharding(self._proc_mesh, P(PROC_AXIS)),
+                [local],
+            )
         fn = self._collective_fn(kind, tuple(arr.shape),
                                  np.dtype(arr.dtype).name, ReduceOp(op))
-        t0 = time.perf_counter() if self._obs_on else 0.0
-        out = fn(garr)
-        self.path_stats["device_ops"] += 1
+        # dispatch time only: device collectives are asynchronous and
+        # blocking here to time them would serialize the data plane
+        with program.span(kind + ".dispatch") as dispatch:
+            out = fn(garr)
+        self._path_counts["device_ops"] += 1
         if self._obs_on:
-            # dispatch time only: device collectives are asynchronous and
-            # blocking here to time them would serialize the data plane
-            dt = time.perf_counter() - t0
+            dt = dispatch.seconds
             self._metrics.counter(f"op.device_{kind}.count").inc()
             self._metrics.counter(f"op.device_{kind}.bytes").inc(arr.nbytes)
             self._metrics.histogram(
@@ -1056,6 +1073,10 @@ class XLAEngine(Engine):
         key = (kind, shape, dtype_name, op)
         fn = self._reduce_cache.get(key)
         if fn is None:
+            # a program built here compiles at its first call: inside
+            # a learner's loop that is a stall
+            program.count(kind + ".programs_built")
+            import jax
             from jax import lax
             from jax.sharding import PartitionSpec as P
 
@@ -1068,14 +1089,19 @@ class XLAEngine(Engine):
                 from rabit_tpu.ops.ring_allreduce import \
                     ring_allreduce_pallas
 
-                body = lambda s: ring_allreduce_pallas(  # noqa: E731
-                    s[0], PROC_AXIS, op)
+                def body(s):
+                    with jax.named_scope("engine/allreduce"):
+                        return ring_allreduce_pallas(s[0], PROC_AXIS, op)
+
                 out_spec = P(*([None] * nd))
                 # pallas outputs carry no varying-across-mesh annotation;
                 # the static replication check cannot see through them
                 check_vma = False
             elif kind == "allreduce":
-                body = lambda s: C.allreduce(s[0], PROC_AXIS, op)  # noqa: E731
+                def body(s):
+                    with jax.named_scope("engine/allreduce"):
+                        return C.allreduce(s[0], PROC_AXIS, op)
+
                 out_spec = P(*([None] * nd))
             else:
                 # allgather: (world, *shape) replicated everywhere.
@@ -1087,14 +1113,17 @@ class XLAEngine(Engine):
 
                 world = self._world
 
-                def body(s, world=world):  # noqa: E731
-                    buf = jnp.zeros((world,) + tuple(s[0].shape),
-                                    s[0].dtype)
-                    buf = lax.dynamic_update_index_in_dim(
-                        buf, s[0], lax.axis_index(PROC_AXIS), 0)
-                    return lax.psum(buf, PROC_AXIS)
+                def body(s, world=world):
+                    with jax.named_scope("engine/allgather"):
+                        buf = jnp.zeros((world,) + tuple(s[0].shape),
+                                        s[0].dtype)
+                        buf = lax.dynamic_update_index_in_dim(
+                            buf, s[0], lax.axis_index(PROC_AXIS), 0)
+                        return lax.psum(buf, PROC_AXIS)
 
                 out_spec = P(*([None] * (nd + 1)))
+            # the program's name in a device trace
+            body.__name__ = f"engine_{kind}_{op.name.lower()}"
             fn = C.shard_collective(
                 self._proc_mesh, body,
                 in_specs=(P(PROC_AXIS, *([None] * nd)),),

@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from rabit_tpu.obs import program
 from rabit_tpu.utils.checks import check
 
 
@@ -58,6 +59,10 @@ class SparseMat:
         nrow is padded up to a multiple of it (padded rows get label 0 and
         all-padding features) so the data splits into equal static blocks.
         """
+        with program.span("stage.to_ell"):
+            return self._to_ell(pad_index, row_block)
+
+    def _to_ell(self, pad_index, row_block):
         if pad_index is None:
             pad_index = self.feat_dim
         nrow = self.num_row

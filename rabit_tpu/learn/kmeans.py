@@ -19,6 +19,7 @@ import numpy as np
 
 import rabit_tpu
 from rabit_tpu.learn.data import SparseMat, load_libsvm, save_matrix_txt
+from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils import compile_cache
 from rabit_tpu.utils.checks import check
@@ -124,7 +125,7 @@ def _densify_fn(block: int, d: int, nnz: int):
         import jax.numpy as jnp
 
         @jax.jit
-        def run(idx, val, valid):
+        def kmeans_densify(idx, val, valid):
             def body(_, blk):
                 i, v, vld = blk
                 dense = _ell_densify(i, v, d)
@@ -135,8 +136,7 @@ def _densify_fn(block: int, d: int, nnz: int):
             _, out = jax.lax.scan(body, None, (idx, val, valid))
             return out                     # (nb, block, d+1)
 
-        _STEP_CACHE[key] = run
-        fn = run
+        fn = _STEP_CACHE[key] = kmeans_densify
     return fn
 
 
@@ -177,7 +177,7 @@ def _stage_dense16(idx, val, valid, feat_dim: int, row_block: int,
         fn = _STEP_CACHE.get(key)
         if fn is None:
             @functools.partial(jax.jit, donate_argnums=(0,))
-            def fn(x, ci, cv, start):
+            def kmeans_stage_dense16(x, ci, cv, start):
                 def body(_, blk):
                     bi, bv = blk
                     dense = _ell_densify(bi, bv, feat_dim)[:, :feat_dim]
@@ -190,7 +190,7 @@ def _stage_dense16(idx, val, valid, feat_dim: int, row_block: int,
                 return lax.dynamic_update_slice(
                     x, dense.reshape(rows, dp), (start, 0))
 
-            _STEP_CACHE[key] = fn
+            fn = _STEP_CACHE[key] = kmeans_stage_dense16
         return fn
 
     x = jnp.zeros((n16, dp), cdt)
@@ -268,14 +268,14 @@ def _dense_stats_fn(k: int, d: int, block: int):
             return {"cnorm": stats["cnorm"], "acc": new}, None
 
         @jax.jit
-        def run(centroids, dense_blocks):
-            init = {"cnorm": _normalize_rows(centroids),
-                    "acc": jnp.zeros((k, d + 1), jnp.float32)}
-            out, _ = jax.lax.scan(body, init, dense_blocks)
-            return out["acc"]
+        def kmeans_dense_stats(centroids, dense_blocks):
+            with jax.named_scope("kmeans/assign_stats"):
+                init = {"cnorm": _normalize_rows(centroids),
+                        "acc": jnp.zeros((k, d + 1), jnp.float32)}
+                out, _ = jax.lax.scan(body, init, dense_blocks)
+                return out["acc"]
 
-        _STEP_CACHE[key] = run
-        fn = run
+        fn = _STEP_CACHE[key] = kmeans_dense_stats
     return fn
 
 
@@ -298,15 +298,17 @@ def _stats_fn(k: int, d: int, block: int, nnz: int):
         return {"cnorm": stats["cnorm"], "acc": new}, None
 
     @jax.jit
-    def run(centroids, idx_blocks, val_blocks, valid_blocks):
-        init = {"cnorm": _normalize_rows(centroids),
-                "acc": jnp.zeros((k, d + 1), jnp.float32)}
-        out, _ = jax.lax.scan(
-            body, init, (idx_blocks, val_blocks, valid_blocks))
-        return out["acc"]
+    def kmeans_ell_scan_stats(centroids, idx_blocks, val_blocks,
+                              valid_blocks):
+        with jax.named_scope("kmeans/assign_stats"):
+            init = {"cnorm": _normalize_rows(centroids),
+                    "acc": jnp.zeros((k, d + 1), jnp.float32)}
+            out, _ = jax.lax.scan(
+                body, init, (idx_blocks, val_blocks, valid_blocks))
+            return out["acc"]
 
-    _STEP_CACHE[key] = run
-    return run
+    _STEP_CACHE[key] = kmeans_ell_scan_stats
+    return kmeans_ell_scan_stats
 
 
 def centroid_update(cent, stats):
@@ -348,27 +350,30 @@ def _device_loop_fn(iters: int, use_pallas: bool, block: int | None,
 
         def one_iter(cent, xv):
             x, valid = xv
-            if use_pallas:
-                from rabit_tpu.ops.kmeans_kernel import kmeans_stats_fused
-                stats = kmeans_stats_fused(cent, x, valid, block=block)
-            else:
-                onehot = _dense_assign(
-                    _normalize_rows(cent).astype(cdt), x, valid)
-                sums = jax.lax.dot_general(
-                    onehot.astype(cdt), x, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                counts = jnp.sum(onehot, axis=0)
-                stats = jnp.concatenate([sums, counts[:, None]], axis=1)
-            return centroid_update(cent, stats)
+            with jax.named_scope("kmeans/assign_stats"):
+                if use_pallas:
+                    from rabit_tpu.ops.kmeans_kernel import \
+                        kmeans_stats_fused
+                    stats = kmeans_stats_fused(cent, x, valid, block=block)
+                else:
+                    onehot = _dense_assign(
+                        _normalize_rows(cent).astype(cdt), x, valid)
+                    sums = jax.lax.dot_general(
+                        onehot.astype(cdt), x, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    counts = jnp.sum(onehot, axis=0)
+                    stats = jnp.concatenate(
+                        [sums, counts[:, None]], axis=1)
+            with jax.named_scope("kmeans/update"):
+                return centroid_update(cent, stats)
 
         @jax.jit
-        def run(cent, x, valid):
+        def kmeans_chain(cent, x, valid):
             x = x.astype(cdt)  # one cast, reused across the chain
             return jax.lax.fori_loop(
                 0, iters, lambda _, c: one_iter(c, (x, valid)), cent)
 
-        _STEP_CACHE[key] = run
-        fn = run
+        fn = _STEP_CACHE[key] = kmeans_chain
     return fn
 
 
@@ -411,6 +416,14 @@ def prepare_shard(idx, val, valid, feat_dim: int,
     (doc/benchmarks.md "ELL densify bound", superseded in round 4);
     elsewhere the block-scan densify pass is used.
     """
+    # as it returns: the transfers it enqueued may still be in flight
+    with program.span("stage.put"):
+        return _prepare_shard(idx, val, valid, feat_dim, row_block,
+                              budget, compute_dtype)
+
+
+def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
+                   budget: int, compute_dtype: str):
     import jax
 
     import jax.numpy as jnp
@@ -497,12 +510,14 @@ def _dense16_stats_fn(k: int, d: int, dp: int):
         from rabit_tpu.ops.kmeans_kernel import kmeans_stats_fused
 
         @jax.jit
-        def fn(centroids, x, valid):
-            cent_p = jnp.pad(centroids, ((0, 0), (0, dp - d)))
-            stats = kmeans_stats_fused(cent_p, x, valid)   # (k, dp+1)
-            return jnp.concatenate([stats[:, :d], stats[:, -1:]], axis=1)
+        def kmeans_stats(centroids, x, valid):
+            with jax.named_scope("kmeans/assign_stats"):
+                cent_p = jnp.pad(centroids, ((0, 0), (0, dp - d)))
+                stats = kmeans_stats_fused(cent_p, x, valid)  # (k, dp+1)
+                return jnp.concatenate(
+                    [stats[:, :d], stats[:, -1:]], axis=1)
 
-        _STEP_CACHE[key] = fn
+        fn = _STEP_CACHE[key] = kmeans_stats
     return fn
 
 
@@ -519,22 +534,24 @@ def _ell_chain_fn(iters: int, k: int, d: int, d_pad: int, nnz: int):
         from rabit_tpu.ops.kmeans_kernel import kmeans_ell_stats_fused
 
         def one_iter(cent, idx_g, val_g, valid):
-            cent_p = jnp.pad(cent, ((0, 0), (0, d_pad - d)))
-            stats = kmeans_ell_stats_fused(
-                cent_p, idx_g, val_g, valid, d_pad, nnz=nnz,
-                group=_ELL_FUSED_GROUP, hi=_ELL_FUSED_HI,
-                block=_ELL_FUSED_BLOCK)
-            stats = jnp.concatenate([stats[:, :d], stats[:, -1:]], axis=1)
-            return centroid_update(cent, stats)
+            with jax.named_scope("kmeans/assign_stats"):
+                cent_p = jnp.pad(cent, ((0, 0), (0, d_pad - d)))
+                stats = kmeans_ell_stats_fused(
+                    cent_p, idx_g, val_g, valid, d_pad, nnz=nnz,
+                    group=_ELL_FUSED_GROUP, hi=_ELL_FUSED_HI,
+                    block=_ELL_FUSED_BLOCK)
+                stats = jnp.concatenate(
+                    [stats[:, :d], stats[:, -1:]], axis=1)
+            with jax.named_scope("kmeans/update"):
+                return centroid_update(cent, stats)
 
         @jax.jit
-        def run(cent, idx_g, val_g, valid):
+        def kmeans_ell_chain(cent, idx_g, val_g, valid):
             return jax.lax.fori_loop(
                 0, iters, lambda _, c: one_iter(c, idx_g, val_g, valid),
                 cent)
 
-        _STEP_CACHE[key] = run
-        fn = run
+        fn = _STEP_CACHE[key] = kmeans_ell_chain
     return fn
 
 
@@ -606,7 +623,8 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     path: that many iterations run as one XLA program between
     checkpoints (resume granularity coarsens to the chain length: one
     committed version per chain, so a resumed run must pass the same
-    ``device_chain``).
+    ``device_chain``).  The host fetches and commits a chain's result
+    while the next chain already runs.
 
     ``hash_dim`` (power of two) clusters in SIGNED-HASHED feature space
     instead of the original one: every downstream stage — init,
@@ -654,7 +672,8 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     idx, val, _labels, valid = data.to_ell(
         pad_index=feat_dim, row_block=row_block)
     # clamp out-of-range features (another shard defined feat_dim)
-    idx = np.minimum(idx, feat_dim).astype(np.int32)
+    with program.span("stage.clamp"):
+        idx = np.minimum(idx, feat_dim).astype(np.int32)
     # dataset lives on device across iterations; only the (k, d+1) stats
     # matrix crosses the host boundary for the fault-tolerant allreduce
     shard = prepare_shard(idx, val, valid, feat_dim, row_block,
@@ -687,20 +706,40 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
             # the shard is staged at the lane-padded width; iterate in
             # that space (zero columns are inert) and slice on fetch
             cent = jnp.pad(cent, ((0, 0), (0, x.shape[1] - feat_dim)))
-        while it < max_iter:
-            chain = min(device_chain, max_iter - it)
-            if shard[0] == "dense":
-                cent = device_iterations(cent, x, vcol, chain)
-            elif shard[0] == "dense16":
-                cent = device_iterations(cent, x, vcol, chain,
-                                         compute_dtype=compute_dtype,
-                                         block=_DENSE16_ROW_TILE)
-            else:
+
+        def enqueue(cent, chain):
+            with program.span("learn.dispatch"):
+                if shard[0] == "dense":
+                    return device_iterations(cent, x, vcol, chain)
+                if shard[0] == "dense16":
+                    return device_iterations(
+                        cent, x, vcol, chain, compute_dtype=compute_dtype,
+                        block=_DENSE16_ROW_TILE)
                 fn = _ell_chain_fn(chain, k, feat_dim, d_pad, nnz_p)
-                cent = fn(cent, idx_g, val_g, dvalid)
+                return fn(cent, idx_g, val_g, dvalid)
+
+        # The chain after this one is enqueued before this one's result
+        # is fetched and committed: it needs only the centroids on the
+        # device, so the device never waits for the host's fetch, commit
+        # and dispatch, and the rate does not follow the host's load.
+        chain = min(device_chain, max_iter - it)
+        queued = None
+        while chain:
+            version += 1
             it += chain
-            model.centroids = np.asarray(cent)[:, :feat_dim]
-            rabit_tpu.checkpoint(model)
+            ahead = min(device_chain, max_iter - it)
+            with program.span("learn.step", version=version):
+                cent = enqueue(cent, chain) if queued is None else queued
+                queued = enqueue(cent, ahead) if ahead else None
+                with program.span("learn.fetch"):
+                    fetched = np.asarray(cent)
+                # the iterations of the version the host now holds
+                program.count("learn.iterations", chain)
+                with program.span("learn.update"):
+                    model.centroids = fetched[:, :feat_dim]
+                program.count("learn.versions")
+                rabit_tpu.checkpoint(model)
+            chain = ahead
         if out_model and rabit_tpu.get_rank() == 0:
             save_model(model, out_model)
         return model
@@ -713,7 +752,7 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     device_plane = _engine_mod.is_device_plane()
 
     epoch = rabit_tpu.device_epoch()
-    for _ in range(version, max_iter):
+    for it in range(version, max_iter):
         if rabit_tpu.device_epoch() != epoch:
             # the device plane was re-formed at a checkpoint boundary
             # (failure recovery): arrays of the old epoch died with the
@@ -721,21 +760,33 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
             epoch = rabit_tpu.device_epoch()
             shard = prepare_shard(idx, val, valid, feat_dim, row_block,
                                   compute_dtype=compute_dtype)
-        if device_plane:
-            local = shard_stats_device(model, shard)
-            stats = np.asarray(rabit_tpu.allreduce(local, SUM))
-        else:
-            stats = np.zeros((k, feat_dim + 1), np.float32)
+        with program.span("learn.step", version=it + 1):
+            if device_plane:
+                with program.span("learn.dispatch"):
+                    local = shard_stats_device(model, shard)
+                total = rabit_tpu.allreduce(local, SUM)
+                with program.span("learn.fetch"):
+                    stats = np.asarray(total)
+            else:
+                stats = np.zeros((k, feat_dim + 1), np.float32)
 
-            def lazy_stats(stats=stats, model=model):
-                stats[...] = shard_stats(model, shard)
+                def lazy_stats(stats=stats, model=model):
+                    with program.span("learn.dispatch"):
+                        local = shard_stats_device(model, shard)
+                    with program.span("learn.fetch"):
+                        stats[...] = np.asarray(local)
 
-            stats = rabit_tpu.allreduce(stats, SUM, prepare_fun=lazy_stats)
-        counts = stats[:, -1:]
-        check(bool((counts != 0).all()), "get zero sized cluster")
-        model.centroids = (stats[:, :-1] / counts).astype(np.float32)
-        model.normalize()
-        rabit_tpu.checkpoint(model)
+                stats = rabit_tpu.allreduce(stats, SUM,
+                                            prepare_fun=lazy_stats)
+            program.count("learn.iterations")
+            with program.span("learn.update"):
+                counts = stats[:, -1:]
+                check(bool((counts != 0).all()), "get zero sized cluster")
+                model.centroids = (stats[:, :-1] / counts).astype(
+                    np.float32)
+                model.normalize()
+            program.count("learn.versions")
+            rabit_tpu.checkpoint(model)
 
     if out_model and rabit_tpu.get_rank() == 0:
         save_model(model, out_model)

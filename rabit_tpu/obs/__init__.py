@@ -1,6 +1,6 @@
 """rabit_tpu.obs — the telemetry subsystem.
 
-Three pieces (doc/observability.md):
+The pieces (doc/observability.md):
 
 * :mod:`rabit_tpu.obs.metrics` — counters, gauges and log2-bucket
   latency histograms behind a thread-safe :class:`Metrics` registry;
@@ -16,6 +16,11 @@ Three pieces (doc/observability.md):
 * :mod:`rabit_tpu.obs.span` — cross-rank collective spans, per-op skew
   merging and rolling straggler scores (doc/observability.md "Live
   telemetry");
+* :mod:`rabit_tpu.obs.program` — **program spans and counters**: the
+  device-plane path's layer boundaries timed where the work happens
+  (always-on table = ``Engine.path_stats``; the profiler's trace while
+  a session records; events + histograms when telemetry is on —
+  doc/observability.md "Program spans");
 * :mod:`rabit_tpu.obs.adapt` — the **adaptive controller** closing the
   loop: live span folds re-score the schedule choice online, push
   schedule-switch epochs, demote persistent stragglers out of

@@ -10,8 +10,7 @@ Histograms use **fixed log2 buckets**: a value lands in the bucket of
 its binary exponent (`math.frexp`), so bucket boundaries are powers of
 two and a percentile estimate is accurate within one octave.  On top of
 the buckets a Welford accumulator tracks exact count/sum/mean/std and
-min/max — the same implementation `utils.profiler.Timer` now wraps
-(reference's only aggregation was the speed test's hand-rolled
+min/max (reference's only aggregation was the speed test's hand-rolled
 sum/sum² allreduce, test/speed_test.cc:53-70).
 """
 from __future__ import annotations

@@ -18,6 +18,9 @@ atomically).
 from __future__ import annotations
 
 import os
+import sys
+
+from rabit_tpu.obs import program
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -39,37 +42,64 @@ def enable() -> str:
     return path
 
 
-class CompileClock:
+class _CompileClock:
     """Seconds this process spent getting executables, split by phase.
 
-    ``take()`` returns what accumulated since the previous call:
-    ``seconds`` is the backend's compile time when the cache missed
-    (cold) and the cache read time when it hit (warm); ``misses`` /
-    ``hits`` count the programs of each kind.  Fed by ``jax.monitoring``
-    — the numbers are JAX's own, not a wall-clock difference that would
-    fold execution in."""
+    Feeds the program counters ``compile.seconds`` / ``compile.misses``
+    / ``compile.hits`` (:mod:`rabit_tpu.obs.program`; a process's
+    ``Engine.path_stats`` carries them): ``seconds`` is the backend's
+    compile time when the cache missed (cold) and the cache read time
+    when it hit (warm); ``misses`` / ``hits`` count the programs of
+    each kind.  From ``jax.monitoring`` — the numbers are JAX's own,
+    not a wall-clock difference that would fold execution in.
+
+    ``take()`` returns what those counters gained since the previous
+    call.  One clock a process: get it from :func:`count_compiles`."""
+
+    _NAMES = ("seconds", "misses", "hits")
 
     def __init__(self) -> None:
         import jax.monitoring
 
-        self._seconds = 0.0
-        self._requests = 0
-        self._hits = 0
+        self._taken = self._read()
         jax.monitoring.register_event_duration_secs_listener(self._on_secs)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_secs(self, event: str, seconds: float, **_kw) -> None:
+    @staticmethod
+    def _on_secs(event: str, seconds: float, **_kw) -> None:
         if event == _COMPILE_EVENT:
-            self._seconds += seconds
+            program.count("compile.seconds", seconds)
 
-    def _on_event(self, event: str, **_kw) -> None:
+    @staticmethod
+    def _on_event(event: str, **_kw) -> None:
+        # a request is a miss until its hit is reported
         if event == _REQUEST_EVENT:
-            self._requests += 1
+            program.count("compile.misses", 1)
         elif event == _HIT_EVENT:
-            self._hits += 1
+            program.count("compile.misses", -1)
+            program.count("compile.hits", 1)
+
+    def _read(self) -> list:
+        stats = program.stats()
+        return [stats.get("compile." + name, 0) for name in self._NAMES]
 
     def take(self) -> dict:
-        out = {"seconds": round(self._seconds, 3),
-               "misses": self._requests - self._hits, "hits": self._hits}
-        self._seconds, self._requests, self._hits = 0.0, 0, 0
-        return out
+        now = self._read()
+        seconds, misses, hits = (b - a for a, b in zip(self._taken, now))
+        self._taken = now
+        return {"seconds": round(seconds, 3), "misses": misses,
+                "hits": hits}
+
+
+_CLOCK: _CompileClock | None = None
+
+
+def count_compiles() -> _CompileClock | None:
+    """This process's one compile clock, registered on the first call
+    made once JAX is imported (``rabit_tpu.init`` calls; JAX is not
+    imported for the clock's sake: a process without it compiles
+    nothing).  None until then."""
+    global _CLOCK
+    if _CLOCK is None and "jax" in sys.modules:
+        _CLOCK = _CompileClock()
+    return _CLOCK

@@ -2,15 +2,14 @@
 
 Fast unit coverage for the metrics registry (counters / gauges /
 log2-bucket histograms), the bounded event trace (eviction, JSONL and
-Chrome-trace round trips), the structured logger gating, and the
-Timer-over-Histogram fold — plus distributed gates: a 4-rank fixed-op
+Chrome-trace round trips) and the structured logger gating — plus
+distributed gates: a 4-rank fixed-op
 job must report identical op counts and byte totals on every rank
 (pysocket and pyrobust), and a soak round with an injected kill must
 produce a tracker-aggregated report with per-op latency percentiles and
 the documented recovery timeline, renderable by tools/obs_report.py.
 """
 import json
-import math
 import sys
 import threading
 
@@ -164,25 +163,6 @@ def test_obs_configure_defaults(monkeypatch):
     cfg = obs.configure({"rabit_obs_dir": "/tmp/x", "rabit_obs_events": 16})
     assert cfg.enabled and cfg.obs_dir == "/tmp/x"
     assert cfg.trace_capacity == 16
-
-
-# -------------------------------------------------------- Timer fold-in
-def test_timer_welford_std_max():
-    from rabit_tpu.utils.profiler import Timer
-
-    t = Timer()
-    # drive the shared Histogram directly: Timer must expose its
-    # aggregation, not a parallel implementation
-    for v in (0.1, 0.2, 0.3):
-        t.histogram.observe(v)
-    assert t.count == 3
-    assert t.total == pytest.approx(0.6)
-    assert t.mean == pytest.approx(0.2)
-    assert t.std == pytest.approx(math.sqrt(np.var([0.1, 0.2, 0.3])))
-    assert t.max == pytest.approx(0.3)
-    with t:
-        pass
-    assert t.count == 4
 
 
 def test_engine_stats_default_empty(empty_engine):

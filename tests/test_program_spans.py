@@ -1,0 +1,507 @@
+"""Program spans and counters (rabit_tpu/obs/program.py): the table's
+arithmetic, ``Engine.path_stats``, the spans a k-means run and a robust
+commit leave, and the three sinks — the table (always), the profiler's
+trace (while a session records), the engine's telemetry (``rabit_obs``).
+All on the CPU; a time read here is never a device metric."""
+import json
+import os
+import statistics
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import rabit_tpu
+from rabit_tpu import engine as engine_mod
+from rabit_tpu import obs
+from rabit_tpu.obs import program
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+STAGE = {"stage.to_ell", "stage.clamp", "stage.put"}
+LOOP = {"learn.step", "learn.dispatch", "learn.fetch", "learn.update"}
+# what kmeans.run leaves on engine `empty`: its two calls before the
+# loop, staging, the loop, the commit as far as `empty` has layers
+KMEANS_SPANS = (STAGE | LOOP
+                | {"load_checkpoint", "allreduce", "commit",
+                   "commit.serialize"})
+
+
+@pytest.fixture
+def table():
+    program.reset()
+    yield program
+    program.reset()
+
+
+def span_names(stats: dict) -> set:
+    return {k[:-2] for k in stats if k.endswith(".n")}
+
+
+# ------------------------------------------------------------ the table
+def test_span_accumulates_n_total_and_max(table):
+    for seconds in (0.002, 0.02, 0.002):
+        with program.span("a"):
+            time.sleep(seconds)
+    s = program.stats()
+    assert s["a.n"] == 3
+    assert 0.024 <= s["a.total_s"] < 0.2
+    assert 0.02 <= s["a.max_s"] < s["a.total_s"]
+
+
+def test_nested_spans_are_both_counted_and_the_child_fits(table):
+    with program.span("outer") as outer:
+        with program.span("outer.inner") as inner:
+            time.sleep(0.005)
+    s = program.stats()
+    assert s["outer.n"] == s["outer.inner.n"] == 1
+    assert 0.005 <= s["outer.inner.total_s"] <= s["outer.total_s"]
+    assert inner.seconds == s["outer.inner.total_s"]
+    assert outer.seconds == s["outer.total_s"]
+
+
+def test_an_exception_closes_the_span_and_passes_through(table):
+    with pytest.raises(KeyError):
+        with program.span("boom"):
+            with program.span("boom.child"):
+                raise KeyError("x")
+    s = program.stats()
+    assert s["boom.n"] == 1 and s["boom.child.n"] == 1
+    with program.span("boom"):          # and the table still works
+        pass
+    assert program.stats()["boom.n"] == 2
+
+
+def test_counters_share_the_table_and_keep_ints(table):
+    program.count("c.bytes", 100)
+    program.count("c.bytes", 28)
+    program.count("c.calls")
+    program.count("c.seconds", 0.25)
+    s = program.stats()
+    assert s["c.bytes"] == 128 and isinstance(s["c.bytes"], int)
+    assert s["c.calls"] == 1 and s["c.seconds"] == 0.25
+
+
+def test_stats_is_flat_json_and_reset_empties_it(table):
+    with program.span("x", version=3, anything="goes"):
+        program.count("x.k", 2)
+    s = program.stats()
+    assert set(s) == {"x.n", "x.total_s", "x.max_s", "x.k"}
+    assert all(isinstance(v, (int, float)) for v in s.values())
+    assert json.loads(json.dumps(s)) == s
+    program.reset()
+    assert program.stats() == {}
+
+
+def test_span_off_cost_is_microseconds(table):
+    """No profiler session, telemetry off: a span is a few dictionary
+    operations.  A loose ceiling (the median of 10,000, under 10 us), so
+    that a loaded box does not fail it; the x4 loop opens 12 a version
+    of 22 ms."""
+    samples = []
+    for _ in range(10000):
+        t0 = time.perf_counter()
+        with program.span("cost"):
+            pass
+        samples.append(time.perf_counter() - t0)
+    assert statistics.median(samples) < 10e-6
+    assert program.stats()["cost.n"] == 10000
+
+
+# ----------------------------------------------------------- path_stats
+def test_path_stats_of_engine_empty(table, empty_engine):
+    rabit_tpu.allreduce(np.ones(4, np.float32))
+    rabit_tpu.checkpoint({"a": 1})
+    s = engine_mod.get_engine().path_stats
+    assert json.loads(json.dumps(s)) == s
+    assert all(isinstance(v, (int, float)) for v in s.values())
+    assert "host_ops" not in s and "device_ops" not in s
+    assert s["allreduce.n"] == 1
+    assert s["commit.n"] == s["commit.serialize.n"] == 1
+
+
+def test_path_stats_of_engine_xla_keeps_its_own_counters(table):
+    if rabit_tpu.initialized():
+        rabit_tpu.finalize()
+    rabit_tpu.init(rabit_engine="xla")
+    try:
+        rabit_tpu.checkpoint({"a": 1})
+        s = engine_mod.get_engine().path_stats
+        assert s["device_ops"] == 0 and s["host_ops"] == 0
+        assert s["init.n"] == 1 and s["commit.n"] == 1
+        assert json.loads(json.dumps(s)) == s
+        assert all(isinstance(v, (int, float)) for v in s.values())
+        # telemetry is off: nothing of the spans is exported
+        eng = engine_mod.get_engine()
+        assert eng.stats() == {} and eng.events() == []
+    finally:
+        rabit_tpu.finalize()
+
+
+# ------------------------------------------------------ the learner loop
+def blobs(n=2048, nnz=8, d=64, k=4, seed=0):
+    """Rows in k well-separated clusters, every row ``nnz`` entries."""
+    from rabit_tpu.learn.data import SparseMat
+
+    rng = np.random.default_rng(seed)
+    band = d // k
+    cluster = np.arange(n) % k
+    findex = (cluster[:, None] * band
+              + rng.integers(0, band, (n, nnz))).astype(np.int32)
+    fvalue = (1.0 + rng.random((n, nnz))).astype(np.float32)
+    return SparseMat(indptr=np.arange(0, n * nnz + 1, nnz),
+                     findex=findex.reshape(-1), fvalue=fvalue.reshape(-1),
+                     labels=np.zeros(n, np.float32), feat_dim=d)
+
+
+@pytest.mark.parametrize("chain,per_version,children", [
+    (4, 4, ("learn.dispatch", "learn.fetch", "learn.update", "commit")),
+    # on a host engine the stats are computed lazily inside the
+    # allreduce, which then holds the dispatch and the fetch
+    (0, 1, ("allreduce", "learn.update", "commit")),
+])
+def test_kmeans_run_leaves_the_spans_of_its_layers(
+        table, empty_engine, chain, per_version, children):
+    from rabit_tpu.learn import kmeans
+
+    program.reset()                     # the fixture's `init` span
+    kmeans.run(blobs(), 4, 8, device_chain=chain)
+    s = engine_mod.get_engine().path_stats
+    assert span_names(s) == KMEANS_SPANS
+    versions = rabit_tpu.version_number()
+    assert s["learn.versions"] == s["learn.step.n"] == versions
+    assert s["learn.iterations"] == per_version * versions == 8
+    assert s["commit.n"] == versions
+    for name in STAGE:
+        assert s[name + ".n"] == 1
+    # every counter of the table has a reader (PERF.md section 3)
+    assert {k for k in s if "." in k and k.split(".")[-1] not in
+            ("n", "total_s", "max_s")} <= {
+        "learn.iterations", "learn.versions", "allreduce.programs_built",
+        "compile.seconds", "compile.misses", "compile.hits"}
+    # the loop's 1 + the feature-width agreement before it
+    assert s["allreduce.n"] == (1 if chain else 1 + versions)
+    covered = sum(s[c + ".total_s"] for c in children)
+    assert covered >= 0.9 * s["learn.step.total_s"]
+    assert covered <= s["learn.step.total_s"]
+
+
+@pytest.mark.parametrize("max_iter,stop_at,order", [
+    # 4 + 4 + 2 iterations: a chain is enqueued before the one ahead of
+    # it is fetched and committed, and nothing after the last
+    (10, None, ["enqueue 4", "enqueue 4", "commit 1", "enqueue 2",
+                "commit 2", "commit 3"]),
+    # a caller that leaves from inside a commit (the benchmark's window
+    # does) finds whole versions counted, the queued chain not among them
+    (100, 2, ["enqueue 4", "enqueue 4", "commit 1", "enqueue 4",
+              "commit 2"]),
+])
+def test_chained_loop_enqueues_a_chain_ahead_of_its_fetch(
+        table, empty_engine, monkeypatch, max_iter, stop_at, order):
+    from rabit_tpu.learn import kmeans
+
+    seen = []
+    iterate, commit = kmeans.device_iterations, rabit_tpu.checkpoint
+
+    def device_iterations(cent, x, valid, iters, **kw):
+        seen.append(f"enqueue {iters}")
+        return iterate(cent, x, valid, iters, **kw)
+
+    def checkpoint(model):
+        commit(model)
+        seen.append(f"commit {rabit_tpu.version_number()}")
+        if rabit_tpu.version_number() == stop_at:
+            raise KeyboardInterrupt
+
+    monkeypatch.setattr(kmeans, "device_iterations", device_iterations)
+    monkeypatch.setattr(rabit_tpu, "checkpoint", checkpoint)
+    data = blobs()
+    if stop_at:
+        with pytest.raises(KeyboardInterrupt):
+            kmeans.run(data, 4, max_iter, device_chain=4)
+    else:
+        chained = kmeans.run(data, 4, max_iter, device_chain=4)
+    assert seen == order
+    s = program.stats()
+    versions = rabit_tpu.version_number()
+    assert s["learn.versions"] == s["learn.fetch.n"] == versions
+    done = [int(e.split()[1]) for e in order if e.startswith("enqueue")]
+    assert s["learn.iterations"] == sum(done[:versions])
+    assert s["learn.dispatch.n"] == len(done)
+    if not stop_at:
+        # the same centroids as the loop that goes through the host
+        # after every iteration
+        rabit_tpu.finalize()
+        rabit_tpu.init(rabit_engine="empty")
+        plain = kmeans.run(data, 4, max_iter)
+        np.testing.assert_allclose(chained.centroids, plain.centroids,
+                                   rtol=1e-4, atol=1e-5)
+
+
+# -------------------------------------------------- the robust commit
+def test_world2_pyrobust_commit_has_its_rounds_once_a_commit(tmp_path):
+    """Two processes under the tracker, telemetry on: every commit is
+    one barrier round, one apply and one acknowledgement, in the table
+    and, with parent and version, in ``Engine.events()``."""
+    from rabit_tpu.tracker.launch_local import launch
+
+    commits = 3
+    code = launch(2, [sys.executable, "tests/workers/span_worker.py",
+                      str(commits)],
+                  extra_env={"RABIT_ENGINE": "pyrobust", "RABIT_OBS": "1",
+                             "SPAN_OUT": str(tmp_path)})
+    assert code == 0
+    for rank in range(2):
+        out = json.loads((tmp_path / f"rank{rank}.json").read_text())
+        s = out["path_stats"]
+        for name in ("commit", "commit.serialize", "commit.barrier",
+                     "commit.apply", "commit.ack"):
+            assert s[name + ".n"] == commits, name
+        inside = sum(s[f"commit.{c}.total_s"] for c in
+                     ("serialize", "barrier", "apply", "ack"))
+        assert 0.5 * s["commit.total_s"] <= inside <= s["commit.total_s"]
+        rounds = [e for e in out["spans"]
+                  if e["kind"] in ("commit.barrier", "commit.ack")]
+        assert len(rounds) == 2 * commits
+        assert all(e["parent"] == "commit" and e["rank"] == rank
+                   for e in rounds)
+        assert sorted(e["version"] for e in rounds) == sorted(
+            2 * list(range(1, commits + 1)))
+
+
+# ----------------------------------------------- sink 3: rabit_obs on
+def test_with_rabit_obs_the_spans_are_events_and_histograms(table):
+    from rabit_tpu.learn import kmeans
+
+    if rabit_tpu.initialized():
+        rabit_tpu.finalize()
+    rabit_tpu.init(rabit_engine="xla", rabit_obs="1")
+    try:
+        eng = engine_mod.get_engine()
+        kmeans.run(blobs(), 4, 8, device_chain=4)
+        events = [e for e in eng.events() if e["name"] == "span"]
+        table_now = eng.path_stats
+        by_kind = {}
+        for e in events:
+            by_kind.setdefault(e["kind"], []).append(e)
+        # `init` closed around the engine's own init, before the
+        # engine had telemetry to attach: the table alone has it
+        assert set(by_kind) == KMEANS_SPANS and table_now["init.n"] == 1
+        for kind, evs in by_kind.items():
+            assert len(evs) == table_now[kind + ".n"], kind
+            assert all(e["dur"] >= 0 and e["rank"] == 0 for e in evs)
+        steps = by_kind["learn.step"]
+        assert [e["version"] for e in steps] == [1, 2]
+        assert all("parent" not in e for e in steps)
+        for kind in ("learn.dispatch", "learn.fetch", "learn.update",
+                     "commit"):
+            assert [e["parent"] for e in by_kind[kind]] == ["learn.step"] * 2
+        # a child inherits the version of the unit of work it is in
+        assert [e["version"] for e in by_kind["learn.fetch"]] == [1, 2]
+        assert [(e["parent"], e["version"])
+                for e in by_kind["commit.serialize"]] == [("commit", 1),
+                                                          ("commit", 2)]
+        hist = eng.stats()["histograms"]
+        for kind in by_kind:
+            assert hist[f"span.{kind}.seconds"]["count"] == len(by_kind[kind])
+        # the merged timeline stays renderable
+        assert len(obs.chrome_trace(events)) == len(events)
+    finally:
+        rabit_tpu.finalize()
+    # the next engine of the process has telemetry off: nothing follows
+    rabit_tpu.init(rabit_engine="xla")
+    try:
+        rabit_tpu.checkpoint({"a": 1})
+        assert engine_mod.get_engine().events() == []
+    finally:
+        rabit_tpu.finalize()
+
+
+class StubEngine:
+    """What ``program.attach`` needs of an engine with telemetry on."""
+    rank = 0
+
+    def __init__(self):
+        self._m, self._t = obs.Metrics(), obs.EventTrace(64)
+
+    def metrics(self):
+        return self._m
+
+    def event_trace(self):
+        return self._t
+
+
+def test_a_span_open_across_detach_leaves_no_stale_nesting(table):
+    """``finalize`` inside a span, then the next engine of the process:
+    the span that was open is exported nowhere and is nobody's parent."""
+    first, second = StubEngine(), StubEngine()
+    program.attach(first)
+    with program.span("old.outer", version=1):
+        program.detach()
+    program.attach(second)
+    try:
+        with program.span("new.work"):
+            pass
+    finally:
+        program.detach()
+    assert program.stats()["old.outer.n"] == 1
+    assert first.event_trace().events() == []
+    (event,) = second.event_trace().events()
+    assert event["kind"] == "new.work"
+    assert "parent" not in event and "version" not in event
+
+
+def test_a_span_entered_before_attach_is_in_the_table_alone(table):
+    sink = StubEngine()
+    with program.span("early"):
+        program.attach(sink)
+        try:
+            with program.span("late"):
+                pass
+        finally:
+            program.detach()
+    assert {e["kind"] for e in sink.event_trace().events()} == {"late"}
+    assert program.stats()["early.n"] == program.stats()["late.n"] == 1
+
+
+def test_a_span_on_another_thread_has_its_own_nesting(table):
+    """Nesting is per thread: a span opened on a helper thread is not
+    the child of what the main thread has open."""
+    import threading
+
+    stub = StubEngine()
+    program.attach(stub)
+    try:
+        with program.span("main.work", version=9):
+            t = threading.Thread(target=lambda: program.span(
+                "helper.work").__enter__().__exit__(None, None, None))
+            t.start()
+            t.join(10)
+            assert not t.is_alive()
+            with program.span("main.child"):
+                pass
+    finally:
+        program.detach()
+    events = {e["kind"]: e for e in stub.event_trace().events()}
+    assert "parent" not in events["helper.work"]
+    assert "version" not in events["helper.work"]
+    assert events["main.child"]["parent"] == "main.work"
+    assert events["main.child"]["version"] == 9
+
+
+# -------------------------------------- sink 2: the profiler's own trace
+def test_under_a_profiler_session_the_spans_are_in_the_xplane(
+        table, empty_engine, tmp_path):
+    import jax
+
+    sys.path.insert(0, ROOT)
+    from perfbench import trace_reduce
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    with jax.profiler.trace(str(tmp_path)):
+        with program.span("learn.step", version=5):
+            rabit_tpu.checkpoint({"a": 1})
+    profile = jax.profiler.ProfileData.from_file(
+        trace_reduce.find_xplane(str(tmp_path)))
+    spans = trace_reduce.host_spans(profile, program.PREFIX)
+    assert {"learn.step", "commit", "commit.serialize"} <= set(spans)
+    (a, b), = spans["learn.step"]
+    (ca, cb), = spans["commit"]
+    assert a <= ca and cb <= b          # on one clock, nested
+    versions = [dict(e.stats).get("version")
+                for plane in profile.planes for line in plane.lines
+                for e in line.events
+                if e.name == program.PREFIX + "learn.step"]
+    assert versions == [5]
+
+
+# ------------------------------------------------------- compile counters
+def test_one_compile_clock_a_process_feeds_the_counters(table):
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.utils import compile_cache
+
+    clock = compile_cache.count_compiles()
+    assert clock is not None and compile_cache.count_compiles() is clock
+    clock.take()
+    jax.jit(lambda x: x * 3.25 + 1.5)(jnp.arange(7.0)).block_until_ready()
+    s = program.stats()
+    assert s["compile.seconds"] > 0
+    taken = clock.take()
+    assert taken["seconds"] == pytest.approx(s["compile.seconds"], abs=2e-3)
+    assert taken["misses"] == s.get("compile.misses", 0)
+    assert taken["hits"] == s.get("compile.hits", 0)
+    assert clock.take() == {"seconds": 0.0, "misses": 0, "hits": 0}
+
+
+# ------------------------------------------------------ tools/span_trace
+def test_span_trace_gives_idle_time_to_the_innermost_span():
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    sys.path.insert(0, ROOT)
+    import span_trace
+    from perfbench.trace_reduce import _overlap
+
+    thread = [("learn.step", 0, 100), ("learn.dispatch", 5, 20),
+              ("commit", 60, 95), ("commit.barrier", 62, 80),
+              ("commit.ack", 80, 94),
+              # the step the end of the trace cut off: no parent event
+              ("learn.dispatch", 105, 120)]
+    own = span_trace.self_intervals([thread, [("other", 0, 10)]])
+    assert own["learn.step"] == [[0, 5], [20, 60], [95, 100]]
+    assert own["commit"] == [[60, 62], [94, 95]]
+    assert own["learn.dispatch"] == [[5, 20], [105, 120]]
+    assert own["commit.barrier"] == [[62, 80]]
+    idle = [[10, 30], [70, 110]]
+    got = {name: _overlap(idle, cover) for name, cover in own.items()}
+    assert got == {"learn.step": 15, "learn.dispatch": 15, "commit": 1,
+                   "commit.barrier": 10, "commit.ack": 14, "other": 0}
+    # every idle instant under a span of this thread is counted once
+    assert sum(got.values()) == 55
+
+
+def test_span_cost_compares_segments_with_their_neighbours():
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    sys.path.insert(0, ROOT)
+    import span_trace
+
+    # versions of 1 s that drift by 1 ms each; 0.25 s more with the
+    # spans on; the version after a switch 5 s longer
+    every, first, t, stamps = 4, 2, 0.0, [0.0]
+    for i in range(first + 1, 12 * every):
+        t += (1.0 + 1e-3 * i + (0.25 if (i // every) % 2 == 0 else 0.0)
+              + (5.0 if i % every == 0 else 0.0))
+        stamps.append(t)
+    cost = span_trace.span_cost(stamps, first, every)
+    assert cost["segments_compared"] == 9        # 11 whole, 9 inside
+    assert cost["on_minus_off_s"] == pytest.approx(0.25, abs=1e-9)
+    assert cost["on_minus_off_q1_s"] == pytest.approx(0.25, abs=1e-9)
+    assert cost["version_s_on"] > cost["version_s_off"]
+
+
+def test_alternating_switches_the_spans_off_and_on(table):
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import span_trace
+
+    class Clock:
+        stamps = []
+
+        def __call__(self):
+            self.stamps.append(0.0)
+
+    on = (program.span, program.count)
+    commit = span_trace.alternating(Clock(), 2, program, on)
+    try:
+        seen = []
+        for _ in range(8):
+            with program.span("v"):
+                program.count("v.k")
+            seen.append(program.stats().get("v.n", 0))
+            commit()
+    finally:
+        program.span, program.count = on
+    # commits 0-1 on, 2-3 off, 4-5 on, 6-7 off
+    assert seen == [1, 2, 2, 2, 3, 4, 4, 4]
+    assert program.stats()["v.k"] == 4
+

@@ -63,8 +63,14 @@ def main() -> None:
         assert isinstance(out, jax.Array)
         np.testing.assert_array_equal(np.asarray(out), want)
     ops = len(SIZES)
-    assert eng.path_stats == {"device_ops": ops, "host_ops": 0}, \
-        eng.path_stats
+
+    def path_ops():
+        return {k: eng.path_stats[k] for k in ("device_ops", "host_ops")}
+
+    assert path_ops() == {"device_ops": ops, "host_ops": 0}, path_ops()
+    # the program's own spans saw the same calls (obs/program.py)
+    assert eng.path_stats["allreduce.dispatch.n"] == ops, eng.path_stats
+    assert eng.path_stats["allreduce.programs_built"] == ops
     # under RABIT_DEVICE_IMPL=pallas_ring the two large payloads rode
     # the remote-DMA kernel (multi-process meshes only do on a TPU)
     ring = [eng._use_pallas_ring((n // 4,), "float32", rabit_tpu.SUM)
@@ -77,8 +83,7 @@ def main() -> None:
     g = np.asarray(rabit_tpu.allgather(
         jnp.array([rank, 2 * rank], dtype=jnp.int32)))
     assert g.tolist() == [[r, 2 * r] for r in range(world)], g
-    assert eng.path_stats == {"device_ops": ops + 2, "host_ops": 0}, \
-        eng.path_stats
+    assert path_ops() == {"device_ops": ops + 2, "host_ops": 0}, path_ops()
 
     # control plane: object broadcast from every root, checkpoint trio
     for root in range(world):
@@ -93,7 +98,7 @@ def main() -> None:
 
     rabit_tpu.tracker_print(
         f"check_xla_chip rank {rank}/{world} OK path_stats="
-        f"{eng.path_stats} device_impl={eng._device_impl} ring={ring}")
+        f"{path_ops()} device_impl={eng._device_impl} ring={ring}")
     rabit_tpu.finalize()
 
 
