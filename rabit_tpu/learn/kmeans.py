@@ -626,6 +626,17 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     ``device_chain``).  The host fetches and commits a chain's result
     while the next chain already runs.
 
+    The distributed loop on the device plane (XLA engine, several ranks)
+    likewise runs a step ahead of its commit: once version v's centroids
+    are updated, the stats program of v+1 (local, no collective) is
+    enqueued, and only then is v committed, so the commit's host rounds
+    run under the kernel.  Every version is still committed before the
+    next version's allreduce is issued, and a resumed job recomputes at
+    most the one uncommitted version, as before.  A result queued across
+    a re-formation of the device plane (``rabit_tpu.device_epoch``
+    moved inside the commit) is dropped unread and dispatched afresh on
+    the re-staged shard.  Host engines keep the lazy path.
+
     ``hash_dim`` (power of two) clusters in SIGNED-HASHED feature space
     instead of the original one: every downstream stage — init,
     staging, stats, checkpoints, the saved model — then lives at that
@@ -751,19 +762,33 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
 
     device_plane = _engine_mod.is_device_plane()
 
+    # On the device plane the stats program of the next version is
+    # enqueued before this version is committed: it needs the updated
+    # centroids and nothing of the commit, whose host rounds then run
+    # under the kernel.  It is local and collective-free; the next
+    # version's allreduce is still issued after this commit returns.
     epoch = rabit_tpu.device_epoch()
+    queued = None
     for it in range(version, max_iter):
         if rabit_tpu.device_epoch() != epoch:
             # the device plane was re-formed at a checkpoint boundary
             # (failure recovery): arrays of the old epoch died with the
             # backends — re-upload the shard, then continue at full speed
             epoch = rabit_tpu.device_epoch()
+            if queued is not None:
+                # so did the result queued ahead: dropped unread
+                queued = None
+                program.count("learn.ahead_discarded")
             shard = prepare_shard(idx, val, valid, feat_dim, row_block,
                                   compute_dtype=compute_dtype)
         with program.span("learn.step", version=it + 1):
             if device_plane:
-                with program.span("learn.dispatch"):
-                    local = shard_stats_device(model, shard)
+                if queued is None:
+                    with program.span("learn.dispatch"):
+                        local = shard_stats_device(model, shard)
+                else:
+                    local, queued = queued, None
+                    program.count("learn.ahead")
                 total = rabit_tpu.allreduce(local, SUM)
                 with program.span("learn.fetch"):
                     stats = np.asarray(total)
@@ -786,6 +811,11 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                     np.float32)
                 model.normalize()
             program.count("learn.versions")
+            if device_plane and it + 1 < max_iter:
+                # `learn.update` bound a new centroid array, which
+                # nothing writes from here on (the commit reads it)
+                with program.span("learn.dispatch"):
+                    queued = shard_stats_device(model, shard)
             rabit_tpu.checkpoint(model)
 
     if out_model and rabit_tpu.get_rank() == 0:

@@ -178,7 +178,8 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
     # every counter of the table has a reader (PERF.md section 3)
     assert {k for k in s if "." in k and k.split(".")[-1] not in
             ("n", "total_s", "max_s")} <= {
-        "learn.iterations", "learn.versions", "allreduce.programs_built",
+        "learn.iterations", "learn.versions", "learn.ahead",
+        "learn.ahead_discarded", "allreduce.programs_built",
         "compile.seconds", "compile.misses", "compile.hits"}
     # the loop's 1 + the feature-width agreement before it
     assert s["allreduce.n"] == (1 if chain else 1 + versions)
@@ -237,6 +238,172 @@ def test_chained_loop_enqueues_a_chain_ahead_of_its_fetch(
         plain = kmeans.run(data, 4, max_iter)
         np.testing.assert_allclose(chained.centroids, plain.centroids,
                                    rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------- the distributed loop, a commit ahead
+class DevicePlane:
+    """The per-iteration loop's device-plane arm switched on in one
+    process on engine `empty`: ``is_device_plane`` says yes, and the
+    three calls the order is made of — ``shard_stats_device``,
+    ``rabit_tpu.allreduce`` of its result, ``rabit_tpu.checkpoint`` —
+    and ``prepare_shard`` write what they did into ``seen`` (the shards
+    staged also into ``staged``).  A stats
+    result travels in a ``Queued`` that only the allreduce opens, so a
+    result that was dropped unread shows as a dispatch no allreduce
+    follows.  ``in_commit[v]`` runs inside commit v, after the real one.
+    """
+
+    class Queued:
+        def __init__(self, version, array):
+            self.version, self.array = version, array
+
+    def __init__(self, monkeypatch, base=0, in_commit=None, budget=None):
+        from rabit_tpu.learn import kmeans
+
+        self.seen, self.staged, self.epoch = [], [], 0
+        in_commit = in_commit or {}
+        stats, reduce, commit, stage = (
+            kmeans.shard_stats_device, rabit_tpu.allreduce,
+            rabit_tpu.checkpoint, kmeans.prepare_shard)
+
+        def shard_stats_device(model, shard):
+            # the version whose stats these are: one past the versions
+            # this run() has updated the centroids of
+            version = base + program.stats().get("learn.versions", 0) + 1
+            self.seen.append(f"dispatch {version}")
+            return self.Queued(version, stats(model, shard))
+
+        def allreduce(data, *a, **kw):
+            if not isinstance(data, self.Queued):
+                return reduce(data, *a, **kw)   # the feature-width one
+            self.seen.append(f"allreduce {data.version}")
+            return data.array                   # world 1: the sum is it
+
+        def checkpoint(model):
+            commit(model)
+            version = rabit_tpu.version_number()
+            self.seen.append(f"commit {version}")
+            in_commit.get(version, lambda: None)()
+
+        def prepare_shard(*a, **kw):
+            self.seen.append("stage")
+            if budget is not None:
+                kw["budget"] = budget
+            self.staged.append(stage(*a, **kw))
+            return self.staged[-1]
+
+        monkeypatch.setattr(engine_mod, "is_device_plane", lambda: True)
+        monkeypatch.setattr(kmeans, "shard_stats_device", shard_stats_device)
+        monkeypatch.setattr(kmeans, "prepare_shard", prepare_shard)
+        monkeypatch.setattr(rabit_tpu, "allreduce", allreduce)
+        monkeypatch.setattr(rabit_tpu, "checkpoint", checkpoint)
+        monkeypatch.setattr(rabit_tpu, "device_epoch", lambda: self.epoch)
+
+    def move_epoch(self):
+        self.epoch += 1
+
+
+def leave():
+    raise KeyboardInterrupt
+
+
+def version_order(first, last, final):
+    """Versions ``first``..``last`` with the stats program of each
+    enqueued before the commit of the one before; ``final``: the job
+    ends at ``last`` (nothing is enqueued for a version after it)."""
+    order = ["stage", f"dispatch {first}"]
+    for v in range(first, last + 1):
+        order.append(f"allreduce {v}")
+        if v < last or not final:
+            order.append(f"dispatch {v + 1}")
+        order.append(f"commit {v}")
+    return order
+
+
+@pytest.mark.parametrize("case", ["full", "resume", "leave", "epoch"])
+def test_distributed_loop_enqueues_the_stats_a_commit_ahead(
+        table, empty_engine, monkeypatch, case):
+    from rabit_tpu.learn import kmeans
+
+    data = blobs()
+    if case == "resume":
+        kmeans.run(data, 4, 2)          # versions 1 and 2, the host arm
+        program.reset()
+    plane = DevicePlane(
+        monkeypatch, base=2 if case == "resume" else 0,
+        in_commit={"leave": {2: leave},
+                   "epoch": {2: lambda: plane.move_epoch()}}.get(case))
+    if case == "leave":
+        # a caller that leaves from inside a commit (the benchmark's
+        # window does): whole versions counted, the queued program
+        # abandoned
+        with pytest.raises(KeyboardInterrupt):
+            kmeans.run(data, 4, 100)
+        want, versions, ahead = version_order(1, 2, final=False), 2, 1
+    elif case == "epoch":
+        # the device plane re-formed inside commit 2: the result queued
+        # for version 3 died with the old epoch's arrays and is dropped
+        # unread, the shard staged anew, version 3 dispatched in place
+        kmeans.run(data, 4, 5)
+        want = version_order(1, 2, final=False) + version_order(3, 5, True)
+        versions, ahead = 5, 3          # versions 2, 4 and 5
+    else:
+        # the first version after load_checkpoint is dispatched in
+        # place, a fresh start or a resume; nothing after the last
+        first = 3 if case == "resume" else 1
+        kmeans.run(data, 4, 5)
+        want = version_order(first, 5, final=True)
+        versions, ahead = 5 - first + 1, 5 - first
+    assert plane.seen == want
+    # the next version's collective never before this version's commit
+    reduced = [int(e.split()[1]) for e in plane.seen
+               if e.startswith("allreduce")]
+    for v in reduced[1:]:
+        assert (plane.seen.index(f"commit {v - 1}")
+                < plane.seen.index(f"allreduce {v}"))
+    s = program.stats()
+    assert s["learn.versions"] == s["learn.iterations"] == versions
+    assert s["learn.step.n"] == s["learn.fetch.n"] == versions
+    assert s.get("learn.ahead", 0) == ahead
+    assert s.get("learn.ahead_discarded", 0) == (1 if case == "epoch" else 0)
+    dispatched = sum(e.startswith("dispatch") for e in plane.seen)
+    assert s["learn.dispatch.n"] == dispatched
+    assert dispatched == sum(
+        e.startswith("allreduce") for e in plane.seen) + (
+        case in ("leave", "epoch"))
+    assert rabit_tpu.version_number() == (2 if case == "leave" else 5)
+
+
+@pytest.mark.parametrize("tier,dtype,budget", [
+    ("dense", "float32", None),         # densified float32 blocks
+    ("dense16", "bfloat16", 0),         # half-width rows
+    ("ell", "float32", 0),              # blocked ELL, the scan path
+])
+@pytest.mark.parametrize("epoch_moves", [False, True], ids=["", "reform"])
+def test_distributed_loop_a_commit_ahead_gives_the_host_arms_centroids(
+        table, empty_engine, monkeypatch, tier, dtype, budget, epoch_moves):
+    """The same iterations whichever arm runs them and whether or not a
+    queued result is dropped on the way: on the CPU a jitted call may
+    read its numpy operand in place, so centroids written after the
+    dispatch ahead would show here."""
+    from rabit_tpu.learn import kmeans
+
+    data = blobs()
+    with monkeypatch.context() as patched:
+        plane = DevicePlane(
+            patched, budget=budget, in_commit={
+                3: lambda: plane.move_epoch()} if epoch_moves else None)
+        ahead = kmeans.run(data, 4, 6, compute_dtype=dtype)
+    assert [s[0] for s in plane.staged] == [tier] * (2 if epoch_moves else 1)
+    assert program.stats()["learn.ahead"] == (4 if epoch_moves else 5)
+    rabit_tpu.finalize()
+    rabit_tpu.init(rabit_engine="empty")
+    stage = kmeans.prepare_shard
+    if budget is not None:
+        monkeypatch.setattr(kmeans, "prepare_shard", lambda *a, **kw: stage(
+            *a, **{**kw, "budget": budget}))
+    plain = kmeans.run(data, 4, 6, compute_dtype=dtype)
+    np.testing.assert_array_equal(ahead.centroids, plain.centroids)
 
 
 # -------------------------------------------------- the robust commit

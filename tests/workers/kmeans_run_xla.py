@@ -17,6 +17,7 @@ jax.config.update("jax_num_cpu_devices", 1)
 import numpy as np
 
 import rabit_tpu
+from rabit_tpu import engine
 from rabit_tpu.learn import kmeans, load_libsvm
 
 
@@ -49,6 +50,13 @@ def main() -> int:
     data = load_libsvm(pattern, rank=rank)
     model = kmeans.run(data, num_cluster=k, max_iter=max_iter,
                        row_block=32)
+    # the loop ran its stats programs a commit ahead (in a relaunched
+    # life too: max_iter leaves it versions enough), and a survivor of
+    # a re-formation dropped the result it had queued in the old epoch
+    stats = engine.get_engine().path_stats
+    assert stats.get("learn.ahead", 0) >= 1, stats
+    if die and trial == 0 and rabit_tpu.device_epoch() > 0:
+        assert stats.get("learn.ahead_discarded", 0) >= 1, stats
 
     # all ranks must agree on the final model
     gathered = rabit_tpu.allgather(model.centroids.reshape(-1))
