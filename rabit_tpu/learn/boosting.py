@@ -8,12 +8,13 @@ module closes that loop with a compact binned GBDT so the histogram
 path is exercised end-to-end as a real app: logistic or squared loss,
 level-wise trees, split gain from second-order statistics.
 
-TPU-native notes: features are quantile-binned once (int32 on device);
-per-node histograms come from the MXU one-hot contraction in
-:mod:`rabit_tpu.learn.histogram` with node membership folded into the
-grad/hess operand (static shapes — no gather/partition per node).  The
-only cross-rank traffic per level is one histogram allreduce per node,
-the XGBoost wire pattern.  Fault tolerance: one checkpoint per boosting
+TPU-native notes: features are quantile-binned once, on the device,
+and stay there with every other per-row quantity; per-node histograms
+come from the MXU one-hot contraction in :mod:`rabit_tpu.learn.histogram`
+with node membership folded into the grad/hess operand inside the
+kernel (static shapes: 2^depth node slots a level).  The only
+cross-rank traffic per level is one histogram allreduce, the XGBoost
+wire pattern.  Fault tolerance: one checkpoint per boosting
 round, the reference's per-iteration commit structure.
 """
 from __future__ import annotations
@@ -23,7 +24,9 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import rabit_tpu
+from rabit_tpu import engine as _engine_mod
 from rabit_tpu.learn import histogram
+from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils.checks import check
 
@@ -108,6 +111,329 @@ def _grad_hess(margin: np.ndarray, labels: np.ndarray, loss: str):
     return (margin - labels).astype(np.float32), np.ones_like(margin)
 
 
+# rows the quantile cuts are taken from (XGBoost's sketch is approximate
+# too): a shard up to this size gives every row
+CUT_SAMPLE_ROWS = 1 << 20
+
+
+def cut_sample(values: np.ndarray) -> np.ndarray:
+    """The strided sample of a shard that defines its cuts."""
+    return values[::max(1, values.shape[0] // CUT_SAMPLE_ROWS)][
+        :CUT_SAMPLE_ROWS]
+
+
+def _keep_rows(seed: int, round_idx: int, n: int, subsample: float):
+    """This round's row sample, seeded by ``(seed, round, rank)``."""
+    rng = np.random.default_rng((seed, round_idx, rabit_tpu.get_rank()))
+    return rng.random(n) < subsample
+
+
+def _route(tree: list[TreeNode], slots: list[int], leaves: list[int]):
+    """How the rows of one level move on.  ``slots[s]`` is the tree node
+    in slot ``s`` of the level (-1: none).  Returns the table whose row
+    ``s`` is ``(feature, bin_threshold, default_left, leaf)`` and the
+    next level's slots: the rows of a split node go to slot ``2s`` or
+    ``2s + 1``; those of a node that stays a leaf take its code
+    ``leaf`` < 0, which is ``-1 - (its place in leaves)``, for good."""
+    tab = np.zeros((len(slots), 4), np.int32)
+    nxt = [-1] * (2 * len(slots))
+    for s, nid in enumerate(slots):
+        if nid < 0:
+            continue
+        node = tree[nid]
+        if node.feature < 0:
+            leaves.append(nid)
+            tab[s, 3] = -len(leaves)
+        else:
+            tab[s] = (node.feature, node.bin_threshold,
+                      getattr(node, "default_left", True), 0)
+            nxt[2 * s], nxt[2 * s + 1] = node.left, node.right
+    return tab, nxt
+
+
+def _leaf_values(tree, slots, leaves, max_depth: int) -> np.ndarray:
+    """Leaf weight by row code once every level is routed: a row still
+    in slot ``s`` reads entry ``s``, a row with leaf code ``c`` entry
+    ``2^max_depth - c - 1``."""
+    width = 1 << max_depth
+    vals = np.zeros(2 * width, np.float32)
+    for s, nid in enumerate(slots):
+        if nid >= 0:
+            check(tree[nid].feature < 0,
+                  "boosting: a tree is deeper than max_depth=%d", max_depth)
+            vals[s] = tree[nid].value
+    for k, nid in enumerate(leaves):
+        vals[width + k] = tree[nid].value
+    return vals
+
+
+def _replay(shard, trees, max_depth: int) -> None:
+    """Add committed trees to the shard's margin (a resume)."""
+    for tree in trees:
+        slots, leaves = [0], []
+        for _depth in range(max_depth):
+            if all(nid < 0 for nid in slots):
+                break
+            tab, slots = _route(tree, slots, leaves)
+            shard.partition(tab)
+        shard.leaf(_leaf_values(tree, slots, leaves, max_depth))
+
+
+class _HostShard:
+    """One rank's rows as numpy arrays: the arm of the host engines and
+    of a process without an accelerator."""
+
+    def __init__(self, values, labels, model, max_depth, nbin, subsample,
+                 seed, use_pallas, compute_dtype):
+        self.n = values.shape[0]
+        self.labels, self.model = labels, model
+        self.max_depth, self.subsample, self.seed = max_depth, subsample, seed
+        self.kw = {"use_pallas": use_pallas, "compute_dtype": compute_dtype}
+        with program.span("stage.bin"):
+            self.bins = apply_cuts(values, model.cuts)
+        self.any_nan = bool(np.isnan(values).any())
+        self.max_bin = int(self.bins.max(initial=0))
+
+    def start(self, nslot: int) -> None:
+        self.nslot = nslot
+        self.margin = self.model.margin(self.bins)
+        self.node = np.zeros(self.n, np.int32)
+
+    def grad_hess(self, round_idx: int) -> None:
+        self.grad, self.hess = _grad_hess(self.margin, self.labels,
+                                          self.model.loss)
+        if self.subsample < 1.0:
+            # zeroed grad/hess = row contributes nothing anywhere this
+            # round while every shape stays static for the fused kernels
+            keep = _keep_rows(self.seed, round_idx, self.n, self.subsample)
+            self.grad = np.where(keep, self.grad, 0.0).astype(np.float32)
+            self.hess = np.where(keep, self.hess, 0.0).astype(np.float32)
+
+    def level(self, slots):
+        """The histograms of the slots that hold a node, and which."""
+        order = [s for s, nid in enumerate(slots) if nid >= 0]
+        return histogram.build_level_local(
+            self.bins, self.grad, self.hess, self.node, order, self.nslot,
+            **self.kw), order
+
+    def partition(self, tab: np.ndarray) -> None:
+        node = self.node
+        live = node >= 0
+        feat, thr, dleft, leaf = tab[np.where(live, node, 0)].T
+        b = self.bins[np.arange(self.n), feat]
+        left = np.where(b == self.model.cuts.shape[1] + 1, dleft != 0,
+                        b <= thr)
+        self.node = np.where(live, np.where(leaf < 0, leaf,
+                                            2 * node + 1 - left),
+                             node).astype(np.int32)
+
+    def leaf(self, vals: np.ndarray) -> None:
+        width = 1 << self.max_depth
+        self.margin += self.model.learning_rate * vals[
+            np.where(self.node >= 0, self.node, width - self.node - 1)]
+        self.node = np.zeros(self.n, np.int32)
+
+
+_PROGRAMS: dict = {}
+
+
+def _lookup(table, idx, width: int):
+    """``table[idx]`` for a 1-D table of a few dozen entries, as a chain
+    of selects (one fused pass; a gather of n scalars is the slow way
+    on the chip)."""
+    import jax.numpy as jnp
+
+    if width > 256:
+        return jnp.take(table, idx, axis=0)
+    out = jnp.zeros(idx.shape, table.dtype)
+    for k in range(width):
+        out = jnp.where(idx == k, table[k], out)
+    return out
+
+
+class _DeviceShard:
+    """One rank's rows on the device for the whole job: staged bins,
+    labels, margin, (grad, hess) and the node of every row.  A round is
+    a fixed set of programs, compiled before the first; the host sees
+    histograms and sends back tables of a level's width."""
+
+    def __init__(self, values, labels, model, max_depth, nbin, subsample,
+                 seed, use_pallas, compute_dtype):
+        import jax
+
+        self.n, self.f = values.shape
+        self.model, self.max_depth = model, max_depth
+        self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
+        self.subsample, self.seed = subsample, seed
+        self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
+        self.bins_t, seen = histogram.stage_bins(values, model.cuts, nbin)
+        with program.span("stage.put"):
+            self.labels = jax.device_put(np.asarray(labels, np.float32))
+        self.any_nan, self.max_bin = (int(v) for v in np.asarray(seen))
+
+    def start(self, nslot: int) -> None:
+        import jax.numpy as jnp
+
+        self.nslot = nslot
+        with program.span("stage.compile"):
+            self.prog = self._programs()
+        self.margin = jnp.full((self.n,), self.model.base_score, jnp.float32)
+        self.node = jnp.zeros((self.n,), jnp.int32)
+        with program.span("stage.margin"):
+            _replay(self, self.model.trees, self.max_depth)
+
+    def _programs(self) -> dict:
+        """The job's programs, compiled for its shapes: ``grad``,
+        ``level`` (one a depth: 2^depth node slots, empty slots carry
+        no weight, so a tree that stops early runs the same programs),
+        ``partition`` and ``leaf``."""
+        import jax
+        import jax.numpy as jnp
+
+        from rabit_tpu.ops import histogram_kernel as hk
+
+        n, f, nslot, depth = self.n, self.f, self.nslot, self.max_depth
+        loss, rate = self.model.loss, self.model.learning_rate
+        sampled = self.subsample < 1.0
+        missing_bin = self.model.cuts.shape[1] + 1
+        use_pallas, cdt = self.use_pallas, self.compute_dtype
+        key = (n, f, self.bins_t.shape[0], nslot, depth, loss, rate, sampled,
+               missing_bin, use_pallas, cdt, hk.hist_fused_multi,
+               jax.default_backend())
+        if key in _PROGRAMS:
+            return _PROGRAMS[key]
+        half, width = self.half, 1 << depth
+
+        def gbdt_grad(margin, labels, *keep):
+            with jax.named_scope("gbdt/grad"):
+                if loss == "logistic":
+                    p = 1.0 / (1.0 + jnp.exp(-margin))
+                    g, h = p - labels, p * (1 - p)
+                else:
+                    g, h = margin - labels, jnp.ones_like(margin)
+                gh = jnp.stack([g, h])
+                return jnp.where(keep[0], gh, 0.0) if keep else gh
+
+        def level_of(nslots: int):
+            def gbdt_level(bins_t, gh, node):
+                with jax.named_scope("gbdt/level"):
+                    return histogram.level_hist(
+                        bins_t, gh, node, nslots, f, nslot,
+                        use_pallas=use_pallas, compute_dtype=cdt)
+            return gbdt_level
+
+        def gbdt_partition(bins_t, node, tab):
+            with jax.named_scope("gbdt/partition"):
+                feat, thr, dleft, leaf = (
+                    _lookup(tab[:, c], node, half) for c in range(4))
+                # the bin of each row's own split feature: one pass over
+                # the staged array, no gather and no slice of it
+                rows_of = jnp.arange(bins_t.shape[0], dtype=jnp.int32)
+                b = jnp.sum(jnp.where(feat[None, :] == rows_of[:, None],
+                                      bins_t, 0), axis=0)
+                left = jnp.where(b == missing_bin, dleft != 0, b <= thr)
+                child = 2 * node + 1 - left.astype(jnp.int32)
+                return jnp.where(node < 0, node,
+                                 jnp.where(leaf < 0, leaf, child))
+
+        def gbdt_leaf(margin, node, vals):
+            with jax.named_scope("gbdt/leaf"):
+                code = jnp.where(node >= 0, node, width - node - 1)
+                return (margin + rate * _lookup(vals, code, 2 * width),
+                        jnp.zeros_like(node))
+
+        sds = jax.ShapeDtypeStruct
+        rows_f, rows_i = sds((n,), jnp.float32), sds((n,), jnp.int32)
+        bins, gh = sds(self.bins_t.shape, jnp.int32), sds((2, n), jnp.float32)
+
+        def build(fn, *shapes, donate=()):
+            return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
+
+        keep = (sds((n,), jnp.bool_),) if sampled else ()
+        prog = {
+            "grad": build(gbdt_grad, rows_f, rows_f, *keep),
+            "level": [build(level_of(1 << d), bins, gh, rows_i)
+                      for d in range(depth)],
+            "partition": build(gbdt_partition, bins, rows_i,
+                               sds((half, 4), jnp.int32), donate=(1,)),
+            "leaf": build(gbdt_leaf, rows_f, rows_i,
+                          sds((2 * width,), jnp.float32), donate=(0, 1)),
+        }
+        _PROGRAMS[key] = prog
+        return prog
+
+    def grad_hess(self, round_idx: int) -> None:
+        keep = ()
+        if self.subsample < 1.0:
+            # the one host array of length n a round: the sample is
+            # numpy's, so that both arms draw the same rows
+            import jax
+
+            keep = (jax.device_put(_keep_rows(
+                self.seed, round_idx, self.n, self.subsample)),)
+        self.gh = self.prog["grad"](self.margin, self.labels, *keep)
+
+    def level(self, slots):
+        depth = len(slots).bit_length() - 1
+        return (self.prog["level"][depth](self.bins_t, self.gh, self.node),
+                range(len(slots)))
+
+    def partition(self, tab: np.ndarray) -> None:
+        tab = np.concatenate(
+            [tab, np.zeros((self.half - len(tab), 4), np.int32)])
+        self.node = self.prog["partition"](self.bins_t, self.node, tab)
+
+    def leaf(self, vals: np.ndarray) -> None:
+        self.margin, self.node = self.prog["leaf"](self.margin, self.node,
+                                                   vals)
+
+
+def _reduce_level(local) -> np.ndarray:
+    """One ``rabit_tpu.allreduce`` of the level's histograms (also at
+    world 1) and their copy to the host, where the splits are chosen.
+    On the device plane the payload stays a device array so the
+    reduction rides ICI; host engines take the fault-tolerant numpy
+    path."""
+    shape = local.shape
+    if _engine_mod.is_device_plane():
+        out = rabit_tpu.allreduce(local.reshape(-1), SUM)
+        with program.span("gbdt.level.fetch"):
+            return np.asarray(out).reshape(shape)
+    with program.span("gbdt.level.fetch"):
+        local = histogram._writable(local)
+    return rabit_tpu.allreduce(local.reshape(-1), SUM).reshape(shape)
+
+
+def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
+           reg_lambda: float, min_child_weight: float,
+           has_missing: bool) -> bool:
+    """Choose ``node``'s split on its reduced histogram, or leave it a
+    leaf.  A split gives both children the weight their side's sums
+    give; a child that is split in turn gets its own."""
+    if has_missing:
+        gain, default_left = histogram.split_gain_missing(hist, reg_lambda)
+    else:
+        gain = histogram.split_gain(hist, reg_lambda)
+    j, t = np.unravel_index(int(gain.argmax()), gain.shape)
+    dl = bool(default_left[j, t]) if has_missing else True
+    # both sides from the chosen feature's own bins, in float64
+    g_tot, h_tot = hist[j].sum(axis=0, dtype=np.float64)
+    gl, hl = hist[j, :t + 1].sum(axis=0, dtype=np.float64)
+    if has_missing and dl:
+        gl, hl = gl + hist[j, -1, 0], hl + hist[j, -1, 1]
+    gr, hr = g_tot - gl, h_tot - hl
+    if (gain[j, t] <= 1e-12 or hl < min_child_weight
+            or hr < min_child_weight):
+        node.value = float(-g_tot / (h_tot + reg_lambda))
+        return False
+    node.feature, node.bin_threshold = int(j), int(t)
+    node.default_left, node.value = dl, 0.0
+    node.left, node.right = len(tree), len(tree) + 1
+    tree.append(TreeNode(value=float(-gl / (hl + reg_lambda))))
+    tree.append(TreeNode(value=float(-gr / (hr + reg_lambda))))
+    return True
+
+
 def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
           max_depth: int = 3, nbin: int = 32, learning_rate: float = 0.3,
           reg_lambda: float = 1.0, loss: str = "logistic",
@@ -117,9 +443,21 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
           compute_dtype: str | None = None) -> BoostedModel:
     """Train a distributed booster on this rank's row shard.
 
-    Deterministic across ranks: cuts come from rank 0, every split
-    decision is taken on the allreduced histogram.  Resumes from the
-    last committed round after a failure (checkpoint per round).
+    Deterministic across ranks: cuts come from rank 0 (the quantiles of
+    :func:`cut_sample` of its shard), every split decision is taken on
+    the allreduced histogram.  Resumes from the last committed round
+    after a failure (checkpoint per round).
+
+    On an accelerator, and under the XLA engine's device plane, the
+    rows live on the device for the whole job (``_DeviceShard``): they
+    are binned there, a level is one fused histogram program over
+    2^depth node slots, one ``rabit_tpu.allreduce``, the fetch of the
+    slots' histograms, the gain scan on the host and one program that
+    moves every row to its child; depth-limit leaf weights come from
+    the last level's histogram and the margin update is one lookup by
+    row.  The host touches no array of length n inside the loop.
+    Elsewhere the same loop runs on numpy arrays (``_HostShard``) and
+    builds the same trees.
 
     ``subsample < 1`` draws a fresh per-round row sample (stochastic
     gradient boosting): sampled-out rows contribute no gradient mass to
@@ -140,121 +478,98 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     """
     check(0.0 < subsample <= 1.0, "subsample must be in (0, 1], got %s",
           subsample)
-    n, f = values.shape
     version, restored = rabit_tpu.load_checkpoint()
-    nan_handle = None
     if version == 0:
         # rank 0's shard defines the cuts; other ranks just receive them
-        cuts = rabit_tpu.broadcast(
-            histogram.quantile_cuts(values, nbin)
-            if rabit_tpu.get_rank() == 0 else None, 0)
-        # missing handling is GLOBAL: any rank with NaNs means every
-        # rank must carry the extra histogram slot and the missing-aware
-        # gain.  Decided HERE (round 0) and checkpointed in the model —
-        # a resume must not repeat the collective (replay alignment).
-        # Issued async with fuse=False (a lone op waiting in a bucket
-        # would not start until wait()): the MAX vote rides the wire
-        # while this rank runs the big apply_cuts binning pass below.
-        nan_handle = rabit_tpu.allreduce_async(
-            np.array([np.isnan(values).any()], np.int32), MAX, fuse=False)
-        base = 0.0
-        model = BoostedModel(cuts=cuts, base_score=base,
+        with program.span("stage.cuts"):
+            cuts = rabit_tpu.broadcast(
+                histogram.quantile_cuts(cut_sample(values), nbin)
+                if rabit_tpu.get_rank() == 0 else None, 0)
+        model = BoostedModel(cuts=cuts, base_score=0.0,
                              learning_rate=learning_rate, loss=loss,
                              has_missing=False)
     else:
         model = restored
-    bins = apply_cuts(values, model.cuts)
-    if nan_handle is not None:
-        model.has_missing = bool(nan_handle.wait()[0])
+        rabit_tpu.tracker_print(
+            "[%d] restart iter=%d" % (rabit_tpu.get_rank(), version))
+    device_arm = on_tpu() or _engine_mod.is_device_plane()
+
+    def stage():
+        shard = (_DeviceShard if device_arm else _HostShard)(
+            values, labels, model, max_depth, nbin, subsample, seed,
+            use_pallas, compute_dtype)
+        if version == 0 and not model.trees:
+            # missing handling is GLOBAL: any rank with NaNs means every
+            # rank must carry the extra histogram slot and the
+            # missing-aware gain.  Decided HERE (round 0) and
+            # checkpointed in the model — a resume must not repeat the
+            # collective (replay alignment).
+            model.has_missing = bool(rabit_tpu.allreduce(
+                np.array([shard.any_nan], np.int32), MAX)[0])
+        missing_bin = model.cuts.shape[1] + 1
+        nslot = missing_bin + (1 if getattr(model, "has_missing", False)
+                               else 0)
+        check(shard.max_bin < nslot,
+              "boosting: a feature value is NaN (bin %d) but the model's "
+              "has_missing is False, so its histograms have only %d "
+              "slots: has_missing is decided once, at round 0, over "
+              "every rank's shard, and this shard does not match it",
+              shard.max_bin, nslot)
+        shard.start(nslot)
+        return shard
+
+    shard = stage()
     has_missing = getattr(model, "has_missing", False)
-    missing_bin = model.cuts.shape[1] + 1
-    margin = model.margin(bins)  # recomputed once on (re)start
-    # resident transposed bins: the fused level-histogram kernel streams
-    # the (f, n) layout; transpose once, reuse every node/level/round
-    import jax
-    bins_t = (jax.numpy.asarray(bins).T
-              if on_tpu() else None)
-
     epoch = rabit_tpu.device_epoch()
+    ready = -1                      # the round whose (grad, hess) is made
     for round_idx in range(version, num_round):
-        if bins_t is not None and rabit_tpu.device_epoch() != epoch:
-            # device plane re-formed after a failure: old-epoch arrays
-            # died with the backends — re-upload the resident bins
-            epoch = rabit_tpu.device_epoch()
-            bins_t = jax.numpy.asarray(bins).T
-        grad, hess = _grad_hess(margin, labels, model.loss)
-        if subsample < 1.0:
-            # zeroed grad/hess = row contributes nothing anywhere this
-            # round (histograms, depth-limit leaves) while every shape
-            # stays static for the fused kernels
-            rng = np.random.default_rng(
-                (seed, round_idx, rabit_tpu.get_rank()))
-            keep = rng.random(n) < subsample
-            grad = np.where(keep, grad, 0.0).astype(np.float32)
-            hess = np.where(keep, hess, 0.0).astype(np.float32)
-
-        tree: list[TreeNode] = [TreeNode()]
-        node_of_row = np.zeros(n, np.int32)
-        frontier = [0]
-        for depth in range(max_depth):
-            next_frontier: list[int] = []
-            # every live node's histogram in one fused bins pass and
-            # ONE allreduce for the level (the per-node XGBoost wire
-            # pattern, batched)
-            hists = histogram.build_level_allreduce(
-                bins, grad, hess, node_of_row, frontier,
-                missing_bin + 1 if has_missing else missing_bin,
-                bins_t=bins_t,
-                use_pallas=use_pallas, compute_dtype=compute_dtype)
-            for pos, nid in enumerate(frontier):
-                hist = hists[pos]
-                g_tot = hist[:, :, 0].sum(axis=1)[0]
-                h_tot = hist[:, :, 1].sum(axis=1)[0]
-                leaf_value = -g_tot / (h_tot + reg_lambda)
-                if has_missing:
-                    gain, default_left = histogram.split_gain_missing(
-                        hist, reg_lambda)
-                else:
-                    gain = histogram.split_gain(hist, reg_lambda)
-                    default_left = None
-                j, t = np.unravel_index(int(gain.argmax()), gain.shape)
-                dl = bool(default_left[j, t]) if has_missing else True
-                hl = hist[j, :t + 1, 1].sum()
-                if has_missing and dl:
-                    hl += hist[j, -1, 1]
-                hr = h_tot - hl
-                if (gain[j, t] <= 1e-12 or hl < min_child_weight
-                        or hr < min_child_weight):
-                    tree[nid].value = float(leaf_value)
-                    continue
-                node = tree[nid]
-                node.feature = int(j)
-                node.bin_threshold = int(t)
-                node.default_left = dl
-                node.left = len(tree)
-                tree.append(TreeNode())
-                node.right = len(tree)
-                tree.append(TreeNode())
-                rows = node_of_row == nid
-                b = bins[:, j]
-                go_left = np.where(b == missing_bin, dl, b <= t)
-                node_of_row[rows & go_left] = node.left
-                node_of_row[rows & ~go_left] = node.right
-                next_frontier += [node.left, node.right]
-            frontier = next_frontier
-            if not frontier:
-                break
-        # frontier nodes at max depth become leaves: one batched
-        # allreduce of all their (g, h) sums (not one per leaf)
-        if frontier:
-            gh = np.empty((len(frontier), 2), np.float64)
-            for i, nid in enumerate(frontier):
-                mask = node_of_row == nid
-                gh[i] = (grad[mask].sum(), hess[mask].sum())
-            gh = rabit_tpu.allreduce(gh.reshape(-1), SUM).reshape(-1, 2)
-            for i, nid in enumerate(frontier):
-                tree[nid].value = float(-gh[i, 0] / (gh[i, 1] + reg_lambda))
-        model.trees.append(tree)
-        margin += model.learning_rate * model._tree_margin(tree, bins)
-        rabit_tpu.checkpoint(model)
+        with program.span("learn.step", version=round_idx + 1):
+            if device_arm and rabit_tpu.device_epoch() != epoch:
+                # device plane re-formed after a failure: old-epoch
+                # arrays died with the backends — stage the shard again
+                epoch = rabit_tpu.device_epoch()
+                shard, ready = stage(), -1
+            if ready != round_idx:
+                with program.span("gbdt.grad"):
+                    shard.grad_hess(round_idx)
+            tree: list[TreeNode] = [TreeNode()]
+            slots, leaves = [0], []
+            for depth in range(max_depth):
+                if all(nid < 0 for nid in slots):
+                    break
+                with program.span("gbdt.level", depth=depth):
+                    # every node slot's histogram in one fused bins
+                    # pass and ONE allreduce for the level (the
+                    # per-node XGBoost wire pattern, batched)
+                    with program.span("learn.dispatch"):
+                        local, order = shard.level(slots)
+                    hists = _reduce_level(local)
+                    with program.span("gbdt.split"):
+                        live = [(pos, slots[s]) for pos, s in enumerate(order)
+                                if slots[s] >= 0]
+                        split = sum(_split(
+                            tree[nid], tree, hists[pos], reg_lambda,
+                            min_child_weight, has_missing)
+                            for pos, nid in live)
+                        tab, slots = _route(tree, slots, leaves)
+                    with program.span("gbdt.partition"):
+                        shard.partition(tab)
+                program.count("gbdt.levels")
+                program.count("gbdt.channels", 2 * len(order))
+                program.count("gbdt.channels_live", 2 * len(live))
+                program.count("gbdt.nodes_split", split)
+            # the nodes at the depth limit are leaves, with the weights
+            # their parents' histograms gave them
+            with program.span("gbdt.leaf"):
+                shard.leaf(_leaf_values(tree, slots, leaves, max_depth))
+            model.trees.append(tree)
+            if round_idx + 1 < num_round:
+                # the next round's first program is enqueued before the
+                # commit, whose host rounds then run beside it
+                with program.span("gbdt.grad"):
+                    shard.grad_hess(round_idx + 1)
+                ready = round_idx + 1
+            program.count("learn.iterations")
+            program.count("learn.versions")
+            rabit_tpu.checkpoint(model)
     return model

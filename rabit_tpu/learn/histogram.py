@@ -8,14 +8,17 @@ statistics (the pattern BASELINE.md lists under "configs to reproduce";
 the reference itself only ships the collective, the histogram is the
 app's job — same split here).
 
-TPU-native design: binned features live on device as an (n, f) int32
-array; the builder is a single jitted program that scans (row-block,
-feature-block) tiles, expanding bins to a one-hot against a bin iota and
-contracting with the (grad, hess) pair on the MXU — compiler-friendly
+TPU-native design: binned features live on device as int32, for a
+boosting job staged once as the transposed, feature-padded ``(fpad, n)``
+array the fused kernel streams (:func:`stage_bins`, binned on the device
+from the float values); the builder expands bins to one-hots and
+contracts them with the (grad, hess) pair on the MXU — compiler-friendly
 fixed shapes, no scatter (TPU scatters serialize; the one-hot contraction
-keeps the FLOPs on the matrix unit).  The cross-worker step is one
-framework allreduce of the flat (f * nbin * 2) histogram, exactly the
-XGBoost wire pattern.
+keeps the FLOPs on the matrix unit).  A tree level builds every node
+slot's histogram in one pass (:func:`level_hist`), node membership folded
+into the weights inside the kernel.  The cross-worker step is one
+framework allreduce of the flat histogram, exactly the XGBoost wire
+pattern.
 """
 from __future__ import annotations
 
@@ -76,6 +79,135 @@ def apply_cuts(values: np.ndarray, cuts: np.ndarray) -> np.ndarray:
     return bins
 
 
+STAGE_CHUNK_ROWS = 1 << 20
+
+
+def staged_features(f: int, nbin: int) -> int:
+    """Feature rows of the staged bins array: ``f`` rounded up to the
+    fused kernel's feature group, so that a call pads nothing."""
+    from rabit_tpu.ops.histogram_kernel import plan
+
+    _hi, _lo, fpg, ngroups = plan(nbin, f)
+    return fpg * ngroups
+
+
+def _bin_program(n: int, c: int, f: int, fpad: int, ncut: int):
+    """Compiled ``gbdt_bin``: bins ``c`` rows of float values and writes
+    them into columns ``[lo, lo + c)`` of the staged array in place."""
+    key = ("bin", n, c, f, fpad, ncut)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        def gbdt_bin(bins_t, seen, vals, cuts_t, lo):
+            with jax.named_scope("gbdt/bin"):
+                v = vals.T                                   # (f, c)
+                # cuts <= value, counted: searchsorted(side="right");
+                # a NaN is below every cut and takes the missing bin
+                b = jnp.sum(cuts_t[:, :, None] <= v[None, :, :], axis=0,
+                            dtype=jnp.int32)
+                nan = jnp.isnan(v)
+                b = jnp.where(nan, ncut + 1, b)
+                b = jnp.pad(b, ((0, fpad - f), (0, 0)))
+                seen = jnp.maximum(seen, jnp.stack(
+                    [jnp.any(nan).astype(jnp.int32), jnp.max(b)]))
+                return jax.lax.dynamic_update_slice(
+                    bins_t, b, (jnp.int32(0), lo)), seen
+
+        sds = jax.ShapeDtypeStruct
+        fn = jax.jit(gbdt_bin, donate_argnums=(0, 1)).lower(
+            sds((fpad, n), jnp.int32), sds((2,), jnp.int32),
+            sds((c, f), jnp.float32), sds((ncut, f), jnp.float32),
+            sds((), jnp.int32)).compile()
+        _CACHE[key] = fn
+    return fn
+
+
+def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
+    """Bin a shard on the device and keep it there: returns the
+    ``(fpad, n)`` int32 array :func:`level_hist` streams (features
+    padded to the kernel's group with bin 0, whose histograms are
+    dropped) and a (2,) int32 device array ``[any NaN, largest bin]``.
+
+    Equal to :func:`apply_cuts` bit for bit, NaN included.  The float
+    values cross to the device a chunk of rows at a time and only the
+    bins stay; nothing of length n is made on the host."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.obs import program
+
+    n, f = values.shape
+    fpad, ncut = staged_features(f, nbin), cuts.shape[1]
+    cuts_t = jnp.asarray(np.ascontiguousarray(cuts.T, np.float32))
+    bins_t = jnp.zeros((fpad, n), jnp.int32)
+    seen = jnp.zeros((2,), jnp.int32)
+    chunk = min(n, STAGE_CHUNK_ROWS)
+    for lo in range(0, n, chunk):
+        c = min(chunk, n - lo)
+        with program.span("stage.compile"):
+            fn = _bin_program(n, c, f, fpad, ncut)
+        with program.span("stage.put"):
+            vals = jax.device_put(
+                np.ascontiguousarray(values[lo:lo + c], np.float32))
+        with program.span("stage.bin"):
+            bins_t, seen = fn(bins_t, seen, vals, cuts_t, np.int32(lo))
+    return bins_t, seen
+
+
+def _level_xla(bins_t, gh, node, nslots: int, nbin: int, block: int = 4096):
+    """The level's histograms without the kernel, in float32: the exact
+    path (``use_pallas=False``).  A scan over row blocks, each block's
+    one-hots contracted with the node-masked weights."""
+    import jax
+    import jax.numpy as jnp
+
+    fpad, n = bins_t.shape
+    block = min(block, n)
+    npad = -(-n // block) * block
+    nb = npad // block
+    bt = jnp.pad(bins_t, ((0, 0), (0, npad - n))).reshape(fpad, nb, block)
+    w = jnp.pad(gh, ((0, 0), (0, npad - n))).reshape(2, nb, block)
+    nd = jnp.pad(node, (0, npad - n), constant_values=-1).reshape(nb, block)
+    bin_iota = jnp.arange(nbin, dtype=jnp.int32)
+    slot_iota = jnp.arange(nslots, dtype=jnp.int32)
+
+    def body(acc, xs):
+        b, wb, ndb = xs
+        oh = (b[:, :, None] == bin_iota).astype(jnp.float32)
+        ws = ((ndb[:, None] == slot_iota)[:, :, None]
+              * wb.T[:, None, :]).astype(jnp.float32)      # (block, S, 2)
+        return acc + jnp.einsum("frk,rsc->sfkc", oh, ws,
+                                precision=jax.lax.Precision.HIGHEST), None
+
+    acc, _ = jax.lax.scan(
+        body, jnp.zeros((nslots, fpad, nbin, 2), jnp.float32),
+        (bt.transpose(1, 0, 2), w.transpose(1, 0, 2), nd))
+    return acc
+
+
+def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
+               use_pallas: bool | None = None, compute_dtype=None):
+    """``(nslots, f, nbin, 2)`` histograms of one tree level, traceable:
+    slot ``s`` holds the (grad, hess) sums of the rows whose ``node`` is
+    ``s``; a slot with no row reads zeros, a row at no slot (node < 0)
+    is in no histogram.  ``bins_t`` is the staged ``(fpad, n)`` array,
+    ``gh`` the ``(2, n)`` float32 weights.  The fused kernel folds the
+    node masks into the weights a row block at a time (12 bytes a row
+    read, no ``(2 * nslots, n)`` matrix in HBM)."""
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    if not use_pallas:
+        return _level_xla(bins_t, gh, node, nslots, nbin)[:, :f]
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    kw = {} if compute_dtype is None else {"compute_dtype": compute_dtype}
+    out = hk.hist_fused_multi(bins_t, gh, nbin, node_of_row=node,
+                              nslots=nslots, **kw)   # (2 * nslots, fpad, nbin)
+    return out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
+
+
 def split_gain_missing(hist: np.ndarray, reg_lambda: float = 1.0):
     """Sparsity-aware split gain: the LAST bin of ``hist`` (f, nbin, 2)
     holds the missing-value rows.  For every (feature, cut) the gain is
@@ -83,13 +215,14 @@ def split_gain_missing(hist: np.ndarray, reg_lambda: float = 1.0):
     ``(gain, default_left)`` where gain is the better of the two and
     default_left says which direction won (XGBoost's learned default
     direction, one bool per candidate split)."""
+    # float64 throughout: see split_gain
+    hist = np.asarray(hist, np.float64)
     g, h = hist[:, :-1, 0], hist[:, :-1, 1]
     gm = hist[:, -1:, 0]
     hm = hist[:, -1:, 1]
-    gl = np.cumsum(g, axis=1)[:, :-1]
-    hl = np.cumsum(h, axis=1)[:, :-1]
-    gt = g.sum(axis=1, keepdims=True) + gm
-    ht = h.sum(axis=1, keepdims=True) + hm
+    gc, hc = np.cumsum(g, axis=1), np.cumsum(h, axis=1)
+    gl, hl = gc[:, :-1], hc[:, :-1]
+    gt, ht = gc[:, -1:] + gm, hc[:, -1:] + hm
     parent = gt * gt / (ht + reg_lambda)
 
     def score(gl_, hl_):
@@ -196,9 +329,10 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
     TPU this routes every node through ONE fused-kernel bins pass
     (measured ~25x over per-node XLA passes at 8 nodes,
     doc/benchmarks.md):
-    :func:`rabit_tpu.ops.histogram_kernel.hist_fused_multi` with a
-    (2m, n) weight matrix — node masks folded into grad/hess channels,
-    chunked when a level exceeds the kernel's channel budget.
+    :func:`rabit_tpu.ops.histogram_kernel.hist_fused_multi`, which
+    folds the node masks into the grad/hess channels itself (no (2m, n)
+    weight matrix), chunked when a level exceeds the kernel's channel
+    budget.
     ``bins_t`` optionally supplies the resident transposed (f, n)
     device array so the transpose isn't redone per level.  Off-TPU,
     falls back to the XLA builder per node.
@@ -207,7 +341,6 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
 
     if use_pallas is None:
         use_pallas = on_tpu()
-    nid = jnp.asarray(np.asarray(node_ids, np.int32))
     nor = jnp.asarray(np.asarray(node_of_row, np.int32))
     g = jnp.asarray(grad)
     h = jnp.asarray(hess)
@@ -216,19 +349,20 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
         from rabit_tpu.ops import histogram_kernel as hk
         if bins_t is None:
             bins_t = jnp.asarray(bins).T
-        kw = {} if compute_dtype is None else {"compute_dtype": compute_dtype}
+        f = bins_t.shape[0]
+        # node id -> position in node_ids, -1 for a row of another node
+        ids = np.asarray(node_ids, np.int64)
+        lut = np.full(int(ids.max(initial=0)) + 2, -1, np.int32)
+        lut[ids] = np.arange(m, dtype=np.int32)
+        slot = jnp.asarray(lut)[jnp.clip(nor, -1, len(lut) - 1)]
+        gh = jnp.stack([g, h]).astype(jnp.float32)
         # chunk derived from the kernel's VMEM accumulator budget (2
         # channels per node: grad + hess), not a fixed constant — wide
         # features shrink it so deep levels still compile
-        chunk = max(1, hk.max_channels(nbin, bins.shape[1]) // 2)
-        outs = []
-        for lo_i in range(0, m, chunk):
-            nids = nid[lo_i:lo_i + chunk]
-            mc = len(nids)
-            mask = (nor[None, :] == nids[:, None]).astype(g.dtype)
-            w = jnp.concatenate([mask * g[None, :], mask * h[None, :]])
-            out = hk.hist_fused_multi(bins_t, w, nbin, **kw)  # (2mc, f, nbin)
-            outs.append(jnp.stack([out[:mc], out[mc:]], axis=-1))
+        chunk = max(1, hk.max_channels(nbin, f) // 2)
+        outs = [level_hist(bins_t, gh, slot - lo, min(chunk, m - lo), f,
+                           nbin, use_pallas=True, compute_dtype=compute_dtype)
+                for lo in range(0, m, chunk)]
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     g_np, h_np, nor_np = np.asarray(g), np.asarray(h), np.asarray(nor)
     parts = [build_local(bins, g_np * (nor_np == v), h_np * (nor_np == v),
@@ -306,12 +440,14 @@ def build_allreduce_async(bins, grad, hess, nbin: int, fuse: bool = False,
 def split_gain(hist: np.ndarray, reg_lambda: float = 1.0) -> np.ndarray:
     """Per (feature, cut) split gain from a (f, nbin, 2) histogram —
     the standard XGBoost structure score, vectorized over all cuts."""
-    g = hist[:, :, 0]
-    h = hist[:, :, 1]
-    gl = np.cumsum(g, axis=1)[:, :-1]
-    hl = np.cumsum(h, axis=1)[:, :-1]
-    gt = g.sum(axis=1, keepdims=True)
-    ht = h.sum(axis=1, keepdims=True)
+    # float64, and the totals are the cumulative sums' own last entries:
+    # in float32 a node of millions of rows has sums with an ulp of 0.5,
+    # a total summed in another order than the prefix can then leave an
+    # empty right side at hr = -1, and hr + lambda = 0 is an infinite gain
+    hist = np.asarray(hist, np.float64)
+    gc, hc = np.cumsum(hist[:, :, 0], axis=1), np.cumsum(hist[:, :, 1], axis=1)
+    gl, hl = gc[:, :-1], hc[:, :-1]
+    gt, ht = gc[:, -1:], hc[:, -1:]
     gr, hr = gt - gl, ht - hl
     parent = gt * gt / (ht + reg_lambda)
     return (gl * gl / (hl + reg_lambda)

@@ -100,8 +100,16 @@ def plan(nbin: int, f: int):
     return hi, lo, fpg, ngroups
 
 
-def _hist_kernel(bins_t_ref, w_ref, out_ref, *,
-                 hi: int, lo: int, fpg: int, ngroups: int, nw: int):
+def _hist_kernel(bins_t_ref, w_ref, *rest,
+                 hi: int, lo: int, fpg: int, ngroups: int, nslots: int):
+    """One row block: the one-hots of every feature group against every
+    weight channel, added into the VMEM-resident output.
+
+    With ``nslots`` > 0 a node operand follows the weights and channel
+    ``s * nw + c`` is weight row ``c`` of the rows whose node is ``s``
+    (everything else weighs 0): the level's node masks are made here,
+    a row block at a time, and never exist in HBM."""
+    node_ref, out_ref = rest if nslots else (None, rest[0])
     i = pl.program_id(0)
     block = w_ref.shape[1]
     w = w_ref[:]                                   # (nw, block) compute dtype
@@ -110,8 +118,17 @@ def _hist_kernel(bins_t_ref, w_ref, out_ref, *,
     cdt = w.dtype
     prec = (lax.Precision.HIGHEST if cdt == jnp.float32
             else lax.Precision.DEFAULT)
+    rows = [w[c:c + 1, :] for c in range(w.shape[0])]
+    if nslots:
+        node = node_ref[:]                         # (1, block) int32
+        zero = jnp.zeros((), cdt)
+        rows = [jnp.where(node == s, r, zero)
+                for s in range(nslots) for r in rows]
 
-    groups = []
+    @pl.when(i == 0)
+    def _():
+        out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
     for grp in range(ngroups):
         bt = bins_t_ref[grp * fpg:(grp + 1) * fpg, :]        # (fpg, block)
         bh = lax.shift_right_logical(bt, lo_shift)
@@ -124,33 +141,22 @@ def _hist_kernel(bins_t_ref, w_ref, out_ref, *,
         lo_iota = lax.broadcasted_iota(jnp.int32, (fpg, lo, block), 1)
         b = (bl[:, None, :] == lo_iota).astype(cdt)
         b = b.reshape(fpg * lo, block)                       # (N, block)
-        cs = []
-        for c in range(nw):
+        for c, row in enumerate(rows):
             # MXU-native NT matmul: contract over the row dimension
-            cs.append(lax.dot_general(
-                a * w[c:c + 1, :], b, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=prec))
-        groups.append(jnp.stack(cs))          # (nw, fpg*hi, fpg*lo)
-    contrib = jnp.stack(groups)               # (ngroups, nw, ...)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[:] = contrib
-
-    @pl.when(i != 0)
-    def _():
-        out_ref[:] = out_ref[:] + contrib
+            out_ref[grp, c] += lax.dot_general(
+                a * row, b, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("nbin", "block", "interpret", "compute_dtype",
-                     "plan_override"))
-def _hist_multi(bins_t, weights, nbin: int, block: int,
+                     "plan_override", "nslots"))
+def _hist_multi(bins_t, weights, node, nbin: int, block: int,
                 interpret: bool, compute_dtype,
-                plan_override=None) -> jax.Array:
+                plan_override=None, nslots: int = 0) -> jax.Array:
     f, n = bins_t.shape
-    nw = weights.shape[0]
+    nw = weights.shape[0] * max(nslots, 1)
     if plan_override is None:
         hi, lo, fpg, ngroups = plan(nbin, f)
     else:
@@ -179,23 +185,33 @@ def _hist_multi(bins_t, weights, nbin: int, block: int,
     npad = _round_up(n, block)
     cdt = jnp.dtype(compute_dtype)
 
+    # no copy for input staged at (fpad, n) int32 with n a multiple of
+    # the block: zero-width pads and same-type casts return their input
     bt = jnp.pad(bins_t.astype(jnp.int32),
                  ((0, fpad - f), (0, npad - n)))
-    w = jnp.pad(weights.astype(cdt), ((0, 0), (0, npad - n)))
+    operands = [bt, jnp.pad(weights.astype(cdt), ((0, 0), (0, npad - n)))]
+    in_specs = [
+        pl.BlockSpec((fpad, block), lambda i: (0, i),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((weights.shape[0], block), lambda i: (0, i),
+                     memory_space=pltpu.VMEM),
+    ]
+    if nslots:
+        # padded rows sit at node -1: in no slot
+        operands.append(jnp.pad(
+            node.astype(jnp.int32).reshape(1, n), ((0, 0), (0, npad - n)),
+            constant_values=-1))
+        in_specs.append(pl.BlockSpec((1, block), lambda i: (0, i),
+                                     memory_space=pltpu.VMEM))
 
     params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
         vmem_limit_bytes=_VMEM_LIMIT_BYTES)
     raw = pl.pallas_call(
         functools.partial(_hist_kernel, hi=hi, lo=lo, fpg=fpg,
-                          ngroups=ngroups, nw=nw),
+                          ngroups=ngroups, nslots=nslots),
         grid=(npad // block,),
-        in_specs=[
-            pl.BlockSpec((fpad, block), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((nw, block), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (ngroups, nw, fpg * hi, fpg * lo), lambda i: (0, 0, 0, 0),
             memory_space=pltpu.VMEM),
@@ -203,7 +219,7 @@ def _hist_multi(bins_t, weights, nbin: int, block: int,
             (ngroups, nw, fpg * hi, fpg * lo), jnp.float32),
         compiler_params=params,
         interpret=interpret,
-    )(bt, w)
+    )(*operands)
 
     # diagonal-block extraction (tiny, plain XLA): feature j of group g,
     # channel c lives at raw[g, c, j*hi:(j+1)*hi, j*lo:(j+1)*lo]
@@ -223,7 +239,8 @@ def default_block(n: int) -> int:
 def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                      interpret: bool | None = None,
                      compute_dtype=jnp.bfloat16,
-                     plan_override: tuple | None = None) -> jax.Array:
+                     plan_override: tuple | None = None,
+                     node_of_row=None, nslots: int = 0) -> jax.Array:
     """(nw, f, nbin) histograms of ``nw`` weight channels in one pass.
 
     ``bins_t`` is the TRANSPOSED (f, n) int32 bins array (the layout
@@ -232,20 +249,28 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
     is (nw, n); each row gets its own (f, nbin) histogram.  Extra
     channels share the single bins read, so per-level node histograms
     cost one HBM pass instead of one per node.
+
+    A tree level passes ``node_of_row`` (n,) int32 and ``nslots``: the
+    result is then ``(nslots * nw, f, nbin)``, slot-major, channel
+    ``s * nw + c`` being weight row ``c`` over the rows at node ``s``
+    (a row at any other node, -1 say, is in no histogram).  The masks
+    are made inside the kernel from 4 bytes a row; no (channels, n)
+    weight matrix is written to HBM.
     """
     if interpret is None:
         interpret = not on_tpu()
     f, n = bins_t.shape
-    nw = weights.shape[0]
+    nw = weights.shape[0] * max(nslots, 1)
     if not 1 <= nw <= _MAX_CHANNELS:
         raise ValueError(f"nw={nw} out of range [1, {_MAX_CHANNELS}]")
     if block is None:
         block = default_block(n)
     block = min(block, _round_up(n, 128))
     return _hist_multi(jnp.asarray(bins_t), jnp.asarray(weights),
+                       None if not nslots else jnp.asarray(node_of_row),
                        nbin, block, interpret,
                        jnp.dtype(compute_dtype).name,
-                       plan_override=plan_override)
+                       plan_override=plan_override, nslots=nslots)
 
 
 def hist_fused(bins, grad, hess, nbin: int, block: int | None = None,

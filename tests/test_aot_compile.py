@@ -79,6 +79,23 @@ def _hist_level(topo):
                                ((16, 1 << 18), jnp.float32))).compile()
 
 
+def _hist_level_staged(topo):
+    from rabit_tpu.ops.histogram_kernel import hist_fused_multi
+
+    # the benchmark cell's widest level: 32 node slots x (grad, hess)
+    # folded in inside the kernel, 28 features staged as (32, n) int32,
+    # 256 bins, one chip's 33.6M rows; the bins are not copied (the
+    # temporaries are the bf16 grad and hess)
+    n = 32 << 20
+    fn = jax.jit(functools.partial(hist_fused_multi, nbin=256, nslots=32,
+                                   interpret=False))
+    (bins, gh, node) = _one_chip(topo, ((32, n), jnp.int32),
+                                 ((2, n), jnp.float32), ((n,), jnp.int32))
+    compiled = fn.lower(bins, gh, node_of_row=node).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= n * 2 * 2 + (1 << 20)
+    return compiled
+
+
 def _kmeans_ell_chain(topo):
     from rabit_tpu.learn import kmeans
 
@@ -157,14 +174,15 @@ def _ring(nbytes, topo):
 
 
 @pytest.mark.parametrize("build", [
-    _kmeans_dense, _hist_level, _kmeans_ell_chain, _dense16_loop,
+    _kmeans_dense, _hist_level, _hist_level_staged, _kmeans_ell_chain,
+    _dense16_loop,
     _mesh_kmeans_step,
     # latency-sized, one VMEM segment, and past the segmentation
     # threshold (_VMEM_BUDGET_BYTES): the three fail at the parent commit
     functools.partial(_ring, 64 << 10), functools.partial(_ring, 4 << 20),
     functools.partial(_ring, 64 << 20),
 ], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-8x64x256x262k",
-        "kmeans_ell_chain-d512-4M", "dense16_loop-24M", "mesh_kmeans_step",
+        "hist_fused_multi-32slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])
 def test_compiles_for_v5e(topo, build):
     assert "tpu_custom_call" in build(topo).as_text()

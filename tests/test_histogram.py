@@ -146,3 +146,99 @@ def test_split_gain_prefers_clean_split():
     gain = histogram.split_gain(hist)
     assert gain.shape == (1, nbin - 1)
     assert gain.argmax() == 3  # the boundary between the clusters
+
+
+# ----------------------------------------------------------------------
+# the staged layout and the level builder of the device arm
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("n,chunk,nan", [(1000, 1 << 20, False),
+                                         (1000, 1 << 20, True),
+                                         (1000, 384, True)],
+                         ids=["dense", "nan", "nan-chunks-and-a-tail"])
+def test_stage_bins_equals_apply_cuts_bit_for_bit(monkeypatch, n, chunk, nan):
+    rng = np.random.default_rng(11)
+    f, nbin = 5, 16
+    vals = rng.standard_normal((n, f)).astype(np.float32)
+    vals[::7, 2] = vals[3, 2]                    # ties, on a cut or not
+    if nan:
+        vals[rng.random((n, f)) < 0.1] = np.nan
+    cuts = histogram.quantile_cuts(vals, nbin)
+    monkeypatch.setattr(histogram, "STAGE_CHUNK_ROWS", chunk)
+    bins_t, seen = histogram.stage_bins(vals, cuts, nbin)
+    want = histogram.apply_cuts(vals, cuts)
+    fpad = histogram.staged_features(f, nbin)
+    assert bins_t.shape == (fpad, n) and str(bins_t.dtype) == "int32"
+    np.testing.assert_array_equal(np.asarray(bins_t)[:f].T, want)
+    assert not np.asarray(bins_t)[f:].any()
+    assert list(np.asarray(seen)) == [int(nan), int(want.max())]
+
+
+@pytest.mark.parametrize("kw", [{"use_pallas": False},
+                                {"use_pallas": True,
+                                 "compute_dtype": "float32"}],
+                         ids=["xla", "kernel"])
+def test_level_hist_padded_slots_read_zero(kw):
+    """A level always has 2^depth slots: a slot with no row reads
+    zeros, a row at no slot is in no histogram, the live slots read the
+    per-node histograms."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(12)
+    n, f, nbin, slots = 700, 3, 8, 8
+    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
+    grad = rng.standard_normal(n).astype(np.float32)
+    hess = rng.random(n).astype(np.float32)
+    node = rng.choice([-3, -1, 0, 3, 5], n).astype(np.int32)
+    fpad = histogram.staged_features(f, nbin)
+    bins_t = jnp.zeros((fpad, n), jnp.int32).at[:f].set(bins.T)
+    got = np.asarray(histogram.level_hist(
+        bins_t, jnp.stack([grad, hess]), jnp.asarray(node), slots, f, nbin,
+        **kw))
+    assert got.shape == (slots, f, nbin, 2)
+    for s in range(slots):
+        rows = node == s
+        want = _np_hist(bins[rows], grad[rows], hess[rows], nbin)
+        if s not in (0, 3, 5):
+            assert not got[s].any()
+        np.testing.assert_allclose(got[s], want, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("f,padded", [(32, False), (28, True)],
+                         ids=["staged", "not-staged"])
+def test_staged_input_makes_the_kernels_pad_a_noop(f, padded):
+    """(fpad, n) int32 input with n a multiple of the block reaches the
+    kernel as it is: the compiled program holds no pad of the bins."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops.histogram_kernel import hist_fused_multi
+
+    n, nbin = 512, 16                  # 16 bins: 32 features a group
+    fn = jax.jit(functools.partial(hist_fused_multi, nbin=nbin, nslots=2,
+                                   interpret=True))
+    text = fn.lower(jax.ShapeDtypeStruct((f, n), jnp.int32),
+                    jax.ShapeDtypeStruct((2, n), jnp.float32),
+                    node_of_row=jax.ShapeDtypeStruct((n,), jnp.int32)
+                    ).compile().as_text()
+    pads = [ln for ln in text.splitlines()
+            if " pad(" in ln and "s32[32,512]" in ln.split(" pad(")[0]]
+    assert bool(pads) == padded, pads
+
+
+def test_split_gain_of_a_node_of_millions_of_rows():
+    """Found on the chip (PR 26): in float32 a total summed in another
+    order than the prefix sums left an empty right side at hr = -1, and
+    hr + lambda = 0 made that candidate's gain infinite."""
+    nbin = 256
+    hist = np.zeros((2, nbin, 2), np.float32)
+    rng = np.random.default_rng(13)
+    hist[:, :162, 1] = 29000.0 + rng.random((2, 162)).astype(np.float32)
+    hist[:, :162, 0] = rng.standard_normal((2, 162)) * 1e3
+    gain = histogram.split_gain(hist, 1.0)
+    assert np.isfinite(gain).all()
+    assert abs(gain[:, 161:]).max() < 1e-6     # nothing on the right
+    gain_m, _left = histogram.split_gain_missing(
+        np.concatenate([hist, hist[:, :1]], axis=1), 1.0)
+    assert np.isfinite(gain_m).all()
