@@ -12,10 +12,13 @@ TPU-native notes: features are quantile-binned once, on the device,
 and stay there with every other per-row quantity; per-node histograms
 come from the MXU one-hot contraction in :mod:`rabit_tpu.learn.histogram`
 with node membership folded into the grad/hess operand inside the
-kernel (static shapes: 2^depth node slots a level).  The only
-cross-rank traffic per level is one histogram allreduce, the XGBoost
-wire pattern.  Fault tolerance: one checkpoint per boosting
-round, the reference's per-iteration commit structure.
+kernel.  Below the root a level builds one child of every node split
+the level above (static shapes: 2^(depth-1) build slots) and has the
+sibling as parent minus built, on the reduced histograms: XGBoost's
+histogram subtraction.  The only cross-rank traffic per level is one
+allreduce of the built histograms, the XGBoost wire pattern.  Fault
+tolerance: one checkpoint per boosting round, the reference's
+per-iteration commit structure.
 """
 from __future__ import annotations
 
@@ -209,9 +212,10 @@ class _HostShard:
             self.grad = np.where(keep, self.grad, 0.0).astype(np.float32)
             self.hess = np.where(keep, self.hess, 0.0).astype(np.float32)
 
-    def level(self, slots):
-        """The histograms of the slots that hold a node, and which."""
-        order = [s for s, nid in enumerate(slots) if nid >= 0]
+    def level(self, build):
+        """The histograms of the level slots ``build`` names (-1: none),
+        and which."""
+        order = [s for s in build if s >= 0]
         return histogram.build_level_local(
             self.bins, self.grad, self.hess, self.node, order, self.nslot,
             **self.kw), order
@@ -284,9 +288,10 @@ class _DeviceShard:
 
     def _programs(self) -> dict:
         """The job's programs, compiled for its shapes: ``grad``,
-        ``level`` (one a depth: 2^depth node slots, empty slots carry
-        no weight, so a tree that stops early runs the same programs),
-        ``partition`` and ``leaf``."""
+        ``level`` (by its number of build slots: 1 at the root, then one
+        a node of the level above; an empty slot holds no row, so a tree
+        that stops early runs the same programs), ``partition`` and
+        ``leaf``."""
         import jax
         import jax.numpy as jnp
 
@@ -315,10 +320,18 @@ class _DeviceShard:
                 return jnp.where(keep[0], gh, 0.0) if keep else gh
 
         def level_of(nslots: int):
-            def gbdt_level(bins_t, gh, node):
+            def gbdt_level(bins_t, gh, node, takes):
                 with jax.named_scope("gbdt/level"):
+                    # build slot p takes the rows of level slot takes[p],
+                    # a child of the node in slot p of the level above
+                    # (the root: slot 0 of both); a row of the sibling,
+                    # of an unsplit node or of a leaf is in no slot
+                    above = node >> 1
+                    slot = jnp.where(
+                        (node >= 0) & (node == _lookup(takes, above, nslots)),
+                        above, -1)
                     return histogram.level_hist(
-                        bins_t, gh, node, nslots, f, nslot,
+                        bins_t, gh, slot, nslots, f, nslot,
                         use_pallas=use_pallas, compute_dtype=cdt)
             return gbdt_level
 
@@ -352,8 +365,9 @@ class _DeviceShard:
         keep = (sds((n,), jnp.bool_),) if sampled else ()
         prog = {
             "grad": build(gbdt_grad, rows_f, rows_f, *keep),
-            "level": [build(level_of(1 << d), bins, gh, rows_i)
-                      for d in range(depth)],
+            "level": {p: build(level_of(p), bins, gh, rows_i,
+                               sds((p,), jnp.int32))
+                      for p in [1] + [1 << d for d in range(1, depth - 1)]},
             "partition": build(gbdt_partition, bins, rows_i,
                                sds((half, 4), jnp.int32), donate=(1,)),
             "leaf": build(gbdt_leaf, rows_f, rows_i,
@@ -373,10 +387,10 @@ class _DeviceShard:
                 self.seed, round_idx, self.n, self.subsample)),)
         self.gh = self.prog["grad"](self.margin, self.labels, *keep)
 
-    def level(self, slots):
-        depth = len(slots).bit_length() - 1
-        return (self.prog["level"][depth](self.bins_t, self.gh, self.node),
-                range(len(slots)))
+    def level(self, build):
+        return (self.prog["level"][len(build)](
+            self.bins_t, self.gh, self.node, np.asarray(build, np.int32)),
+            build)
 
     def partition(self, tab: np.ndarray) -> None:
         tab = np.concatenate(
@@ -406,14 +420,16 @@ def _reduce_level(local) -> np.ndarray:
 
 def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
            reg_lambda: float, min_child_weight: float,
-           has_missing: bool) -> bool:
+           has_missing: bool) -> int | None:
     """Choose ``node``'s split on its reduced histogram, or leave it a
-    leaf.  A split gives both children the weight their side's sums
-    give; a child that is split in turn gets its own."""
-    if has_missing:
-        gain, default_left = histogram.split_gain_missing(hist, reg_lambda)
-    else:
-        gain = histogram.split_gain(hist, reg_lambda)
+    leaf (None).  A split gives both children the weight their side's
+    sums give; a child that is split in turn gets its own.  Returns the
+    child whose histogram the next level builds, 0 left or 1 right: the
+    one with the smaller hessian sum (ties: left), so that the other,
+    derived as parent minus built, is the larger and inherits the
+    parent's accumulation error at most doubled in relative size."""
+    gain, default_left = histogram.split_candidates(
+        hist, reg_lambda, min_child_weight, has_missing)
     j, t = np.unravel_index(int(gain.argmax()), gain.shape)
     dl = bool(default_left[j, t]) if has_missing else True
     # both sides from the chosen feature's own bins, in float64
@@ -422,16 +438,15 @@ def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
     if has_missing and dl:
         gl, hl = gl + hist[j, -1, 0], hl + hist[j, -1, 1]
     gr, hr = g_tot - gl, h_tot - hl
-    if (gain[j, t] <= 1e-12 or hl < min_child_weight
-            or hr < min_child_weight):
+    if gain[j, t] <= 1e-12:
         node.value = float(-g_tot / (h_tot + reg_lambda))
-        return False
+        return None
     node.feature, node.bin_threshold = int(j), int(t)
     node.default_left, node.value = dl, 0.0
     node.left, node.right = len(tree), len(tree) + 1
     tree.append(TreeNode(value=float(-gl / (hl + reg_lambda))))
     tree.append(TreeNode(value=float(-gr / (hr + reg_lambda))))
-    return True
+    return int(hr < hl)
 
 
 def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
@@ -450,12 +465,14 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
 
     On an accelerator, and under the XLA engine's device plane, the
     rows live on the device for the whole job (``_DeviceShard``): they
-    are binned there, a level is one fused histogram program over
-    2^depth node slots, one ``rabit_tpu.allreduce``, the fetch of the
-    slots' histograms, the gain scan on the host and one program that
-    moves every row to its child; depth-limit leaf weights come from
-    the last level's histogram and the margin update is one lookup by
-    row.  The host touches no array of length n inside the loop.
+    are binned there, a level is one fused histogram program over one
+    child of every node split the level above (the root at depth 0),
+    one ``rabit_tpu.allreduce``, the fetch of those histograms, the
+    siblings' as parent minus built, the gain scan on the host and one
+    program that moves every row to its child; depth-limit leaf weights
+    come from the last level's histograms and the margin update is one
+    lookup by row.  The host touches no array of length n inside the
+    loop.
     Elsewhere the same loop runs on numpy arrays (``_HostShard``) and
     builds the same trees.
 
@@ -534,30 +551,49 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                     shard.grad_hess(round_idx)
             tree: list[TreeNode] = [TreeNode()]
             slots, leaves = [0], []
+            # the level slot built for each slot of the level above (the
+            # root for itself; -1: none), and that level's reduced
+            # histograms by slot
+            build, above = [0], {}
             for depth in range(max_depth):
                 if all(nid < 0 for nid in slots):
                     break
                 with program.span("gbdt.level", depth=depth):
-                    # every node slot's histogram in one fused bins
-                    # pass and ONE allreduce for the level (the
-                    # per-node XGBoost wire pattern, batched)
+                    # the built slots' histograms in one fused bins pass
+                    # and ONE allreduce for the level (the per-node
+                    # XGBoost wire pattern, batched); every rank holds
+                    # the same reduced histograms, so builds the same
                     with program.span("learn.dispatch"):
-                        local, order = shard.level(slots)
-                    hists = _reduce_level(local)
+                        local, order = shard.level(build)
+                    built = _reduce_level(local)
                     with program.span("gbdt.split"):
-                        live = [(pos, slots[s]) for pos, s in enumerate(order)
-                                if slots[s] >= 0]
-                        split = sum(_split(
-                            tree[nid], tree, hists[pos], reg_lambda,
-                            min_child_weight, has_missing)
-                            for pos, nid in live)
+                        hists = {}
+                        for pos, s in enumerate(order):
+                            if s < 0:
+                                continue
+                            hists[s] = np.asarray(built[pos], np.float64)
+                            if depth:
+                                # the sum over ranks is linear: this IS
+                                # the sibling's reduced histogram
+                                hists[s ^ 1] = above[s >> 1] - hists[s]
+                        build = [-1] * len(slots)
+                        for s in sorted(hists):
+                            side = _split(
+                                tree[slots[s]], tree, hists[s], reg_lambda,
+                                min_child_weight, has_missing)
+                            if side is not None:
+                                build[s] = 2 * s + side
                         tab, slots = _route(tree, slots, leaves)
+                        above = hists
                     with program.span("gbdt.partition"):
                         shard.partition(tab)
+                live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
                 program.count("gbdt.channels", 2 * len(order))
-                program.count("gbdt.channels_live", 2 * len(live))
-                program.count("gbdt.nodes_split", split)
+                program.count("gbdt.channels_live", 2 * live)
+                program.count("gbdt.hists_derived", len(hists) - live)
+                program.count("gbdt.nodes_split",
+                              sum(s >= 0 for s in build))
             # the nodes at the depth limit are leaves, with the weights
             # their parents' histograms gave them
             with program.span("gbdt.leaf"):

@@ -14,9 +14,10 @@ array the fused kernel streams (:func:`stage_bins`, binned on the device
 from the float values); the builder expands bins to one-hots and
 contracts them with the (grad, hess) pair on the MXU — compiler-friendly
 fixed shapes, no scatter (TPU scatters serialize; the one-hot contraction
-keeps the FLOPs on the matrix unit).  A tree level builds every node
-slot's histogram in one pass (:func:`level_hist`), node membership folded
-into the weights inside the kernel.  The cross-worker step is one
+keeps the FLOPs on the matrix unit).  A tree level builds its node
+slots' histograms in one pass (:func:`level_hist`), node membership folded
+into the weights inside the kernel; the booster gives it one child of
+every split node and subtracts for the sibling.  The cross-worker step is one
 framework allreduce of the flat histogram, exactly the XGBoost wire
 pattern.
 """
@@ -208,6 +209,52 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     return out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
 
 
+def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
+                     min_child_weight: float | None = None,
+                     has_missing: bool = False):
+    """``(gain, default_left)`` of every (feature, cut) of a (f, nbin, 2)
+    histogram: the XGBoost structure score, left = bins 0..cut.  With
+    ``has_missing`` the LAST bin holds the missing-value rows, the gain
+    is the better of sending them left and right and ``default_left``
+    says which won (None without).
+
+    A candidate that leaves a side's hessian sum under
+    ``min_child_weight`` is not eligible and reads -inf (XGBoost's
+    ``EnumerateSplit`` scores no other): a histogram obtained as parent
+    minus sibling reads a few ulp of either sign where no row fell, an
+    empty side near ``-reg_lambda`` is then an unbounded gain, and the
+    argmax must not see it.  ``None`` scores every candidate."""
+    # float64, and the totals are the cumulative sums' own last entries:
+    # in float32 a node of millions of rows has sums with an ulp of 0.5,
+    # a total summed in another order than the prefix can then leave an
+    # empty right side at hr = -1, and hr + lambda = 0 is an infinite gain
+    hist = np.asarray(hist, np.float64)
+    reg = hist[:, :-1] if has_missing else hist
+    gc, hc = np.cumsum(reg[:, :, 0], axis=1), np.cumsum(reg[:, :, 1], axis=1)
+    gl, hl = gc[:, :-1], hc[:, :-1]
+    gt, ht = gc[:, -1:], hc[:, -1:]
+    if has_missing:
+        gm, hm = hist[:, -1:, 0], hist[:, -1:, 1]
+        gt, ht = gt + gm, ht + hm
+    parent = gt * gt / (ht + reg_lambda)
+
+    def score(gl_, hl_):
+        gr_, hr_ = gt - gl_, ht - hl_
+        with np.errstate(divide="ignore", invalid="ignore"):
+            gain = (gl_ * gl_ / (hl_ + reg_lambda)
+                    + gr_ * gr_ / (hr_ + reg_lambda) - parent)
+        if min_child_weight is None:
+            return gain
+        return np.where((hl_ >= min_child_weight) & (hr_ >= min_child_weight),
+                        gain, -np.inf)
+
+    gain_right = score(gl, hl)             # missing rows, if any, go right
+    if not has_missing:
+        return gain_right, None
+    gain_left = score(gl + gm, hl + hm)    # missing rows go left
+    return np.maximum(gain_left, gain_right), gain_left >= gain_right
+
+
 def split_gain_missing(hist: np.ndarray, reg_lambda: float = 1.0):
     """Sparsity-aware split gain: the LAST bin of ``hist`` (f, nbin, 2)
     holds the missing-value rows.  For every (feature, cut) the gain is
@@ -215,24 +262,7 @@ def split_gain_missing(hist: np.ndarray, reg_lambda: float = 1.0):
     ``(gain, default_left)`` where gain is the better of the two and
     default_left says which direction won (XGBoost's learned default
     direction, one bool per candidate split)."""
-    # float64 throughout: see split_gain
-    hist = np.asarray(hist, np.float64)
-    g, h = hist[:, :-1, 0], hist[:, :-1, 1]
-    gm = hist[:, -1:, 0]
-    hm = hist[:, -1:, 1]
-    gc, hc = np.cumsum(g, axis=1), np.cumsum(h, axis=1)
-    gl, hl = gc[:, :-1], hc[:, :-1]
-    gt, ht = gc[:, -1:] + gm, hc[:, -1:] + hm
-    parent = gt * gt / (ht + reg_lambda)
-
-    def score(gl_, hl_):
-        gr_, hr_ = gt - gl_, ht - hl_
-        return (gl_ * gl_ / (hl_ + reg_lambda)
-                + gr_ * gr_ / (hr_ + reg_lambda) - parent)
-
-    gain_left = score(gl + gm, hl + hm)    # missing goes left
-    gain_right = score(gl, hl)             # missing goes right
-    return np.maximum(gain_left, gain_right), gain_left >= gain_right
+    return split_candidates(hist, reg_lambda, has_missing=True)
 
 
 def quantize(values: np.ndarray, nbin: int):
@@ -440,15 +470,4 @@ def build_allreduce_async(bins, grad, hess, nbin: int, fuse: bool = False,
 def split_gain(hist: np.ndarray, reg_lambda: float = 1.0) -> np.ndarray:
     """Per (feature, cut) split gain from a (f, nbin, 2) histogram —
     the standard XGBoost structure score, vectorized over all cuts."""
-    # float64, and the totals are the cumulative sums' own last entries:
-    # in float32 a node of millions of rows has sums with an ulp of 0.5,
-    # a total summed in another order than the prefix can then leave an
-    # empty right side at hr = -1, and hr + lambda = 0 is an infinite gain
-    hist = np.asarray(hist, np.float64)
-    gc, hc = np.cumsum(hist[:, :, 0], axis=1), np.cumsum(hist[:, :, 1], axis=1)
-    gl, hl = gc[:, :-1], hc[:, :-1]
-    gt, ht = gc[:, -1:], hc[:, -1:]
-    gr, hr = gt - gl, ht - hl
-    parent = gt * gt / (ht + reg_lambda)
-    return (gl * gl / (hl + reg_lambda)
-            + gr * gr / (hr + reg_lambda) - parent)
+    return split_candidates(hist, reg_lambda)[0]

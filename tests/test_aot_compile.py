@@ -79,15 +79,16 @@ def _hist_level(topo):
                                ((16, 1 << 18), jnp.float32))).compile()
 
 
-def _hist_level_staged(topo):
+def _hist_level_staged(nslots, topo):
     from rabit_tpu.ops.histogram_kernel import hist_fused_multi
 
-    # the benchmark cell's widest level: 32 node slots x (grad, hess)
-    # folded in inside the kernel, 28 features staged as (32, n) int32,
-    # 256 bins, one chip's 33.6M rows; the bins are not copied (the
-    # temporaries are the bf16 grad and hess)
+    # a level of the benchmark cell: node slots x (grad, hess) folded in
+    # inside the kernel (16 is its widest since a level builds one child
+    # of every split node; 32 is every node of depth 5), 28 features
+    # staged as (32, n) int32, 256 bins, one chip's 33.6M rows; the bins
+    # are not copied (the temporaries are the bf16 grad and hess)
     n = 32 << 20
-    fn = jax.jit(functools.partial(hist_fused_multi, nbin=256, nslots=32,
+    fn = jax.jit(functools.partial(hist_fused_multi, nbin=256, nslots=nslots,
                                    interpret=False))
     (bins, gh, node) = _one_chip(topo, ((32, n), jnp.int32),
                                  ((2, n), jnp.float32), ((n,), jnp.int32))
@@ -174,7 +175,8 @@ def _ring(nbytes, topo):
 
 
 @pytest.mark.parametrize("build", [
-    _kmeans_dense, _hist_level, _hist_level_staged, _kmeans_ell_chain,
+    _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
+    functools.partial(_hist_level_staged, 16), _kmeans_ell_chain,
     _dense16_loop,
     _mesh_kmeans_step,
     # latency-sized, one VMEM segment, and past the segmentation
@@ -182,7 +184,8 @@ def _ring(nbytes, topo):
     functools.partial(_ring, 64 << 10), functools.partial(_ring, 4 << 20),
     functools.partial(_ring, 64 << 20),
 ], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-8x64x256x262k",
-        "hist_fused_multi-32slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M", "mesh_kmeans_step",
+        "hist_fused_multi-32slots-28x256x33.6M",
+        "hist_fused_multi-16slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])
 def test_compiles_for_v5e(topo, build):
     assert "tpu_custom_call" in build(topo).as_text()

@@ -343,3 +343,206 @@ def test_no_host_array_of_length_n_inside_the_loop(arm, monkeypatch, which,
     boosting.train(X, y, num_round=3, max_depth=3, nbin=8, use_pallas=False)
     assert state["in_step"]
     assert (state["largest"] < n) == clean, state
+
+
+# ----------------------------------------------------------------------
+# histogram subtraction: a level builds one child of every split node
+# and has the sibling as parent minus built
+# ----------------------------------------------------------------------
+def _spy_on_loop(monkeypatch):
+    """Records what ``train``'s level loop asks and uses: every
+    ``shard.level(build)`` call as ``(build, local, order)`` and every
+    ``_split`` call as ``(round, node id, histogram, child to build)``."""
+    levels, splits, rounds = [], [], [-1]
+    split = boosting._split
+
+    def seen_split(node, tree, hist, *a):
+        if len(tree) == 1:
+            rounds[0] += 1
+        nid = next(i for i, other in enumerate(tree) if other is node)
+        side = split(node, tree, hist, *a)
+        splits.append((rounds[0], nid, np.array(hist), side))
+        return side
+
+    def seen_level(level):
+        def wrapper(self, build):
+            local, order = level(self, build)
+            levels.append((list(build), np.asarray(local), list(order)))
+            return local, order
+        return wrapper
+
+    monkeypatch.setattr(boosting, "_split", seen_split)
+    for cls in (boosting._HostShard, boosting._DeviceShard):
+        monkeypatch.setattr(cls, "level", seen_level(cls.level))
+    return levels, splits
+
+
+def _node_of_rows(tree, bins, missing_bin):
+    """The rows that pass through each node of a tree, id -> mask, and
+    each node's depth."""
+    rows, depth = {0: np.ones(bins.shape[0], bool)}, {0: 0}
+    for nid, node in enumerate(tree):
+        if node.feature < 0:
+            continue
+        b = bins[:, node.feature]
+        left = np.where(b == missing_bin, node.default_left,
+                        b <= node.bin_threshold)
+        rows[node.left] = rows[nid] & left
+        rows[node.right] = rows[nid] & ~left
+        depth[node.left] = depth[node.right] = depth[nid] + 1
+    return rows, depth
+
+
+@pytest.mark.parametrize("subsample", [1.0, 0.5], ids=["all", "half"])
+@pytest.mark.parametrize("missing", [False, True], ids=["dense", "nan"])
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_every_histogram_the_loop_used_equals_a_pass_over_the_nodes_rows(
+        arm, monkeypatch, which, missing, subsample):
+    """Built or derived, the histogram a node was split on is that of
+    its own rows.  The direct pass is the float32 XLA builder; a derived
+    histogram carries a float32 rounding of each ancestor's cell, so the
+    tolerance is a few ulp of the root's largest cell."""
+    from rabit_tpu.learn import histogram
+
+    X, y = _tabular(missing=missing)
+    kw = dict(num_round=2, max_depth=4, nbin=16, subsample=subsample, seed=3,
+              use_pallas=False)
+    other = "device" if which == "host" else "host"
+    arm(other)
+    twin = boosting.train(X, y, **kw)
+    arm(which)
+    _levels, splits = _spy_on_loop(monkeypatch)
+    model = boosting.train(X, y, **kw)
+    assert _structure(model) == _structure(twin)
+
+    bins = boosting.apply_cuts(X, model.cuts)
+    missing_bin = model.cuts.shape[1] + 1
+    nslot = missing_bin + int(model.has_missing)
+    assert model.has_missing == missing
+    checked = 0
+    for r, tree in enumerate(model.trees):
+        before = boosting.BoostedModel(
+            cuts=model.cuts, trees=model.trees[:r],
+            learning_rate=model.learning_rate)
+        grad, hess = boosting._grad_hess(before.margin(bins), y, "logistic")
+        if subsample < 1.0:
+            keep = boosting._keep_rows(3, r, len(y), subsample)
+            grad, hess = grad * keep, hess * keep
+        rows, depth = _node_of_rows(tree, bins, missing_bin)
+        used = {nid: hist for rnd, nid, hist, _ in splits if rnd == r}
+        # every node above the depth limit was scanned, once
+        assert sorted(used) == sorted(nid for nid in rows if depth[nid] < 4)
+        tol = 8 * np.finfo(np.float32).eps * np.abs(used[0]).max()
+        for nid, hist in used.items():
+            want = np.asarray(histogram.build_level_local(
+                bins, grad, hess, rows[nid].astype(np.int32), [1], nslot,
+                use_pallas=False))[0]
+            np.testing.assert_allclose(hist, want, rtol=0, atol=tol)
+            checked += 1
+    assert checked >= 2 * 15
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_unsplit_parent_gives_no_built_slot_and_no_derived_histogram(
+        arm, monkeypatch, which):
+    """A tree that stops early: the children of a node that stayed a
+    leaf are neither built nor derived, and its rows are in no slot."""
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=2000)
+    arm(which)
+    levels, splits = _spy_on_loop(monkeypatch)
+    before = program.stats()
+    model = boosting.train(X, y, num_round=1, max_depth=4, nbin=16,
+                           min_child_weight=60.0, use_pallas=False)
+    counted = {k: program.stats().get(k, 0) - before.get(k, 0)
+               for k in ("gbdt.hists_derived", "gbdt.nodes_split",
+                         "gbdt.channels", "gbdt.channels_live")}
+    tree = model.trees[0]
+    assert len(tree) < 31 and 2 <= len(levels) <= 4
+    scanned = iter(splits)
+    split_above = [True]            # the root stands for its own parent
+    for depth, (build, local, order) in enumerate(levels):
+        # one build slot a node of the level above, taken iff it split
+        assert [s >= 0 for s in build] == split_above
+        assert all(s >> 1 == p for p, s in enumerate(build) if s >= 0)
+        for pos, s in enumerate(order):
+            if s < 0:
+                assert not local[pos].any()     # no row in the slot
+        split_above = [False] * (1 << depth)
+        for s in sorted({t for s in build if s >= 0
+                         for t in ((s, s ^ 1) if depth else (s,))}):
+            split_above[s] = next(scanned)[3] is not None
+    assert next(scanned, None) is None
+    assert any(s < 0 for build, _, _ in levels for s in build)
+    built = sum(s >= 0 for build, _, _ in levels for s in build)
+    assert counted["gbdt.hists_derived"] == built - 1
+    assert counted["gbdt.channels_live"] == 2 * built
+    assert counted["gbdt.nodes_split"] == sum(n.feature >= 0 for n in tree)
+    if which == "device":
+        assert counted["gbdt.channels"] == 2 * sum(
+            len(build) for build, _, _ in levels)
+
+
+@pytest.mark.parametrize("has_missing", [False, True], ids=["dense", "nan"])
+def test_empty_side_of_a_derived_histogram_does_not_win_the_argmax(
+        has_missing):
+    """Parent minus built leaves a few ulp of either sign where no row
+    fell.  Feature 0's last bin is such a cell, its hessian reading
+    -lambda: the cut before it has ``hr + lambda = 0`` and an unbounded
+    gain, which took the argmax and then failed ``min_child_weight``,
+    so that the node stayed a leaf beside feature 1's good split."""
+    from rabit_tpu.learn import histogram
+
+    lam, nbin = 1.0, 4 + int(has_missing)
+    hist = np.zeros((2, nbin, 2))
+    hist[0, :3] = [(-3.0, 10.0), (1.0, 10.0), (2.0, 11.0)]
+    hist[0, 3] = (1e-7, -lam)
+    hist[1, :4] = [(-6.0, 8.0), (-5.0, 7.0), (6.0, 8.0), (5.0 + 1e-7, 7.0)]
+    unmasked = (histogram.split_gain_missing(hist, lam)[0] if has_missing
+                else histogram.split_gain(hist, lam))
+    assert not unmasked[0, 2] < np.inf           # the trap
+    gain, _ = histogram.split_candidates(hist, lam, 1e-3, has_missing)
+    assert gain[0, 2] == -np.inf and np.isfinite(gain[1]).all()
+    tree = [boosting.TreeNode()]
+    side = boosting._split(tree[0], tree, hist, lam, 1e-3, has_missing)
+    assert (tree[0].feature, tree[0].bin_threshold) == (1, 1)
+    assert side == 0 and len(tree) == 3          # hl == hr: left is built
+    # a node with no eligible candidate at all stays a leaf
+    tree = [boosting.TreeNode()]
+    assert boosting._split(tree[0], tree, hist, lam, 16.0,
+                           has_missing) is None and len(tree) == 1
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_counters_of_one_full_depth_3_round(arm, which):
+    """Built slots 1 + 1 + 2, each but the root's with a derived
+    sibling; two channels a built slot; 1 + 2 + 4 nodes split."""
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=2000)
+    arm(which)
+    before = program.stats()
+    model = boosting.train(X, y, num_round=1, max_depth=3, nbin=16,
+                           use_pallas=False)
+    assert len(model.trees[0]) == 15
+    after = program.stats()
+    assert [after.get(k, 0) - before.get(k, 0) for k in (
+        "gbdt.hists_derived", "gbdt.channels", "gbdt.channels_live",
+        "gbdt.nodes_split")] == [3, 8, 8, 7]
+
+
+def test_built_child_is_the_lighter_and_the_same_on_every_rank(tmp_path):
+    """World 4, NaN features and a row sample: every rank builds the
+    same child of every split node, the one whose reduced hessian sum
+    is the smaller (the worker checks both)."""
+    from rabit_tpu.tracker.launch_local import launch
+
+    X, y = _missing_xor_data(n=800, frac=0.2)
+    np.save(tmp_path / "X.npy", X)
+    np.save(tmp_path / "y.npy", y)
+    code = launch(4, [sys.executable, "tests/workers/boosting_dist.py",
+                      str(tmp_path)],
+                  extra_env={"BOOST_SUBSAMPLE": "0.8", "BOOST_MIN_ACC": "0.5",
+                             "BOOST_CHECK_BUILT": "1"})
+    assert code == 0

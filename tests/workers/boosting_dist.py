@@ -21,6 +21,55 @@ import rabit_tpu
 from rabit_tpu.learn import boosting
 
 
+def watch_built():
+    """Record which level slots every ``shard.level`` call builds and
+    the hessian sum of every histogram ``_split`` scans, by round."""
+    builds, weights = [], []
+    shard, split = boosting._HostShard, boosting._split
+    grad_hess, level = shard.grad_hess, shard.level
+
+    def seen_grad_hess(self, round_idx):
+        builds.append([])
+        weights.append({})
+        return grad_hess(self, round_idx)
+
+    def seen_level(self, build):
+        builds[-1].append(list(build))
+        return level(self, build)
+
+    def seen_split(node, tree, hist, *a):
+        nid = next(i for i, other in enumerate(tree) if other is node)
+        weights[-1][nid] = float(hist[0, :, 1].sum())
+        return split(node, tree, hist, *a)
+
+    shard.grad_hess, shard.level = seen_grad_hess, seen_level
+    boosting._split = seen_split
+    return builds, weights
+
+
+def check_built(model, builds, weights) -> None:
+    """Each built slot holds the child with the smaller reduced hessian
+    sum, and every rank built the same slots."""
+    assert len(builds) == len(model.trees), (len(builds), len(model.trees))
+    pairs = 0
+    for tree, levels, weight in zip(model.trees, builds, weights):
+        slots = [0]
+        for depth, build in enumerate(levels):
+            for s in build:
+                if depth and s >= 0:
+                    assert weight[slots[s]] <= weight[slots[s ^ 1]], (
+                        depth, s, weight[slots[s]], weight[slots[s ^ 1]])
+                    pairs += 1
+            slots = [c for nid in slots for c in (
+                (tree[nid].left, tree[nid].right)
+                if nid >= 0 and tree[nid].feature >= 0 else (-1, -1))]
+    assert pairs >= len(model.trees)
+    mine = np.array([s for levels in builds for build in levels
+                     for s in build + [-2]], np.float64)
+    for theirs in rabit_tpu.allgather(mine):
+        np.testing.assert_array_equal(theirs, mine)
+
+
 def main() -> int:
     data_dir = sys.argv[1]
     rabit_tpu.init()
@@ -33,8 +82,11 @@ def main() -> int:
 
     subsample = float(os.environ.get("BOOST_SUBSAMPLE", "1.0"))
     min_acc = float(os.environ.get("BOOST_MIN_ACC", "0.9"))
+    watched = watch_built() if os.environ.get("BOOST_CHECK_BUILT") else None
     model = boosting.train(Xs, ys, num_round=15, max_depth=3, nbin=16,
                            subsample=subsample)
+    if watched:
+        check_built(model, *watched)
 
     # identical predictions everywhere (same model on every rank);
     # with missing values this also pins the learned default directions
