@@ -121,7 +121,7 @@ def run_collectives(args) -> None:
     def one_pass(td: str, tag: str, groups: str | None,
                  extra_env: dict | None = None,
                  sizes: str | None = None,
-                 tune: bool = False, nworkers: int = 4,
+                 tune: bool = False,
                  pipe_depths: str | None = None,
                  repeat: int | None = None,
                  trace_ab: bool = False,
@@ -151,7 +151,7 @@ def run_collectives(args) -> None:
                 os.environ.pop("RABIT_TRACKER_GROUPS", None)
             env = {"RABIT_ENGINE": "pysocket"}
             env.update(extra_env or {})
-            code = launch(nworkers, cmd, extra_env=env)
+            code = launch(4, cmd, extra_env=env)
         finally:
             if saved is None:
                 os.environ.pop("RABIT_TRACKER_GROUPS", None)
@@ -165,9 +165,9 @@ def run_collectives(args) -> None:
 
     with tempfile.TemporaryDirectory() as td:
         # Only passes that explicitly opt in persist tuner rows: the
-        # flat world-4 pass (the flagship cache) and the shm transport
-        # pass (its allreduce@shm rows) — never the pod/obs/tcp_t
-        # passes, whose topologies or world sizes would pollute it.
+        # flat world-4 pass (the flagship cache) and the codec passes
+        # — never the pod/obs passes, whose topologies would pollute
+        # it.
         flat = one_pass(td, "flat", None, tune=True)
         pod = one_pass(td, "pod", "0,0,1,1")
         # Obs-overhead row: the SAME headline stream with the full live
@@ -192,20 +192,6 @@ def run_collectives(args) -> None:
             extra_env={"RABIT_OBS": "1", "RABIT_OBS_FLUSH_SEC": "0.5",
                        "RABIT_TRACE_SAMPLE": str(DEFAULT_TRACE_SAMPLE)},
             trace_ab=True, repeat=5)
-        # Transport dimension (doc/benchmarks.md "shm vs tcp"): a
-        # same-host world over loopback TCP vs the shm ring transport,
-        # on the small-payload ladder where a serving workload lives.
-        # World 2 on purpose: it measures the LINK (one hop, no
-        # scheduler fan-in) and stays stable on oversubscribed CI boxes
-        # where 4 ranks on 2 cores turn the comparison into scheduler
-        # noise.  The shm pass also persists its winners under
-        # --tune-dir, keyed allreduce@shm so auto picks never bleed
-        # across transports (sched/tuner.py table_kind).
-        tsizes = "1KB,4KB,16KB,64KB,256KB"
-        tcp_t = one_pass(td, "tcp", None, sizes=tsizes, nworkers=2)
-        shm_t = one_pass(td, "shm", None, sizes=tsizes,
-                         extra_env={"RABIT_TRANSPORT": "shm"},
-                         tune=True, nworkers=2)
         # Codec dimension (doc/performance.md "Quantized wire codecs"):
         # world 4 on the bandwidth-bound 256KB-4MB ladder, full-width
         # vs bf16 vs block-scaled int8 — ALL measured under the same
@@ -414,32 +400,6 @@ def run_collectives(args) -> None:
         json.dump(pipeline_summary, f, indent=2, sort_keys=True)
     log(f"bench: wrote pipeline rows to {args.pipeline_json}")
 
-    # -- shm-vs-tcp rows (the `static` column is the real dispatch) --
-    transport_rows = {}
-    for size in tcp_t["sizes"]:
-        base = tcp_t["sizes"][size].get("static")
-        shm = shm_t["sizes"].get(size, {}).get("static")
-        if base and shm:
-            transport_rows[size] = {
-                "tcp_MBps": base, "shm_MBps": shm,
-                "speedup": round(shm / base, 3)}
-    small = [r["speedup"] for s, r in transport_rows.items()
-             if int(s) <= (64 << 10)]
-    transport_summary = {
-        "metric": "shm_vs_tcp_small_payload_speedup",
-        "value": round(min(small), 3) if small else 0.0,
-        "best": round(max(small), 3) if small else 0.0,
-        "unit": "x",
-        "world": tcp_t["world"],
-        "regime": "<=64KB, same-host world 2, static dispatch",
-        "sizes": transport_rows,
-        "stream_shm_MBps": shm_t["stream"]["blocking_MBps"],
-        "stream_tcp_MBps": tcp_t["stream"]["blocking_MBps"],
-    }
-    with open(args.transport_json, "w") as f:
-        json.dump(transport_summary, f, indent=2, sort_keys=True)
-    log(f"bench: wrote transport rows to {args.transport_json}")
-
     def overhead_pct(off: float, on: float) -> float:
         return round(100.0 * (1.0 - on / off), 2) if off else 0.0
 
@@ -491,10 +451,6 @@ def run_collectives(args) -> None:
         "stream": f"{stream['ops']} x {stream['payload_bytes']} B sum",
         "sched_speedup_flat": best_flat,
         "sched_speedup_pod": best_pod,
-        # worst-case shm-over-tcp speedup in the <=64KB regime (the
-        # BENCH_transport.json headline; >1.0 means shm wins everywhere
-        # in the small-payload band)
-        "transport_speedup_small": transport_summary["value"],
         # best int8-wire-over-f32 speedup on the bandwidth-bound
         # >=256KB ring/halving/bucketed rows (the BENCH_codec.json
         # headline — raw bandwidth bought by the quantized wire)
@@ -527,7 +483,6 @@ def run_collectives(args) -> None:
               "pod": {"groups": pod.get("groups"),
                       "per_size_MBps": pod["sizes"],
                       "sched_gains": pod_gains},
-              "transport": transport_summary,
               "codec": codec_summary,
               "pipeline": pipeline_summary}
     if args.json:
@@ -679,14 +634,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--tune-dir", default=None,
                     help="collectives suite: persist the measured "
                          "per-size schedule winners as the "
-                         "rabit_sched=auto tuning cache here (the shm "
-                         "transport pass adds allreduce@shm rows; the "
+                         "rabit_sched=auto tuning cache here (the "
                          "codec passes add allreduce+bf16 / "
                          "allreduce+int8 rows)")
-    ap.add_argument("--transport-json", default="BENCH_transport.json",
-                    metavar="OUT.json",
-                    help="collectives suite: where the shm-vs-tcp "
-                         "small-payload rows land")
     ap.add_argument("--codec-json", default="BENCH_codec.json",
                     metavar="OUT.json",
                     help="collectives suite: where the quantized-wire "

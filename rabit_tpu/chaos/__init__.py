@@ -25,23 +25,18 @@ Kinds: ``refuse`` (ECONNREFUSED), ``cto`` (connect timeout), ``reset``
 (bounded sleep), ``eintr`` (interrupted syscall), ``flip`` (one wire
 bit XOR'd in a transferred byte) and ``corrupt`` (one transferred byte
 overwritten) — the corruption kinds integrity framing
-(``rabit_wire_integrity``) exists to catch — plus the shm-transport
-kinds ``torn`` (a half-completed-looking ring write: permanent
-corruption that must escalate to shm→tcp failover) and ``doorbell``
-(one swallowed ring wakeup: the reader's bounded poll must absorb it).
-Sites: ``tracker`` and ``connect`` (connect-stage kinds), ``accept``,
-``io`` (established TCP links; the default for
-reset/partial/stall/eintr/flip/corrupt) and ``shm`` (ring
-touchpoints: torn/doorbell/flip/corrupt/stall — both transports are
-tortured by the same seeds).  The ``accept`` site admits only
+(``rabit_wire_integrity``) exists to catch.
+Sites: ``tracker`` and ``connect`` (connect-stage kinds), ``accept``
+and ``io`` (established worker-worker links; the default for
+reset/partial/stall/eintr/flip/corrupt).  The ``accept`` site admits only
 ``stall`` — an accept has no retry path to absorb a refusal (the
 dialing peer owns the retry).  Control-plane link sites (sharded
 tracker, doc/fault_tolerance.md "Sharded tracker"): ``hello`` (the
 worker→tracker registration exchange), ``hb`` (the heartbeat channel)
 and ``scrape`` (the shard→aggregator obs scrape) admit only
 ``reset``/``stall``, must be named explicitly (no kind defaults to
-them), and are direction-filtered like the shm kinds — each fires on
-the side whose detector the pairing gates read.  The replicated
+them), and are direction-filtered — each fires on the side whose
+detector the pairing gates read.  The replicated
 directory adds ``dir_register`` / ``dir_poll`` / ``dir_resolve``
 (same reset/stall vocabulary, consulted in ``DirectoryClient`` where
 the bounded-retry / ride-the-cache detectors live).  The serving wire
@@ -71,16 +66,16 @@ from rabit_tpu.chaos.plan import (CONNECT_KINDS, CONNECT_SITES,
                                   DEFAULT_BUDGET, DEFAULT_PARTIAL_MAX,
                                   DEFAULT_STALL_MS, DIRECTORY_SITES,
                                   IO_KINDS, KIND_CORRUPT,
-                                  KIND_CTO, KIND_DOORBELL, KIND_EINTR,
+                                  KIND_CTO, KIND_EINTR,
                                   KIND_FLIP, KIND_PARTIAL, KIND_REFUSE,
-                                  KIND_RESET, KIND_STALL, KIND_TORN, KINDS,
-                                  SERVE_SITES, SHM_KINDS, SITE_ACCEPT,
+                                  KIND_RESET, KIND_STALL, KINDS,
+                                  SERVE_SITES, SITE_ACCEPT,
                                   SITE_CONNECT,
                                   SITE_DIR_POLL, SITE_DIR_REGISTER,
                                   SITE_DIR_RESOLVE,
                                   SITE_HB, SITE_HELLO, SITE_IO, SITE_SCRAPE,
                                   SITE_SERVE_REPLY, SITE_SERVE_REQ,
-                                  SITE_SHM, SITE_TRACKER, SITES,
+                                  SITE_TRACKER, SITES,
                                   TRACKER_LINK_KINDS, TRACKER_LINK_SITES,
                                   ChaosPlan, ChaosRule, parse_plan)
 from rabit_tpu.chaos.sock import ChaosSocket
@@ -105,12 +100,11 @@ def configure(params: dict, identity: str,
 
 __all__ = [
     "ChaosPlan", "ChaosRule", "ChaosSocket", "configure", "parse_plan",
-    "KINDS", "SITES", "CONNECT_KINDS", "IO_KINDS", "SHM_KINDS",
-    "CONNECT_SITES",
+    "KINDS", "SITES", "CONNECT_KINDS", "IO_KINDS", "CONNECT_SITES",
     "KIND_REFUSE", "KIND_CTO", "KIND_RESET", "KIND_PARTIAL", "KIND_STALL",
-    "KIND_EINTR", "KIND_FLIP", "KIND_CORRUPT", "KIND_TORN",
-    "KIND_DOORBELL", "SITE_TRACKER", "SITE_CONNECT", "SITE_ACCEPT",
-    "SITE_IO", "SITE_SHM", "SITE_HELLO", "SITE_HB", "SITE_SCRAPE",
+    "KIND_EINTR", "KIND_FLIP", "KIND_CORRUPT",
+    "SITE_TRACKER", "SITE_CONNECT", "SITE_ACCEPT",
+    "SITE_IO", "SITE_HELLO", "SITE_HB", "SITE_SCRAPE",
     "SITE_DIR_REGISTER", "SITE_DIR_POLL", "SITE_DIR_RESOLVE",
     "SITE_SERVE_REQ", "SITE_SERVE_REPLY", "SERVE_SITES",
     "TRACKER_LINK_KINDS", "TRACKER_LINK_SITES", "DIRECTORY_SITES",

@@ -32,43 +32,30 @@ KIND_PARTIAL = "partial"  # established link: short read/write split
 KIND_STALL = "stall"      # bounded latency stall (silent slow peer)
 KIND_EINTR = "eintr"      # signal-interrupted syscall (EINTR)
 # Wire CORRUPTION kinds (the faults integrity framing exists to catch —
-# doc/fault_tolerance.md "Transports, integrity & failover").  Applied
+# doc/fault_tolerance.md "Links & integrity").  Applied
 # at the receive boundary of a transfer, so an injection always lands
 # in the byte stream the peer actually produced (a send-side flip could
 # fall in the unsent remainder of a partial write and never reach the
 # wire, breaking the injected↔detected pairing the gates assert).
 KIND_FLIP = "flip"        # one bit XOR'd in one transferred byte
 KIND_CORRUPT = "corrupt"  # one transferred byte overwritten
-# Shm-transport-specific kinds (the failure modes a ring buffer adds):
-KIND_TORN = "torn"        # write-side: a half-completed-looking ring
-#                           write (several trailing bytes damaged) —
-#                           PERMANENT corruption: detection must
-#                           escalate to failover, never a silent pass
-KIND_DOORBELL = "doorbell"  # write-side: one swallowed wakeup byte —
-#                             the reader's bounded poll slices must
-#                             absorb it (latency, never a hang)
 
 CONNECT_KINDS = (KIND_REFUSE, KIND_CTO, KIND_STALL)
 IO_KINDS = (KIND_RESET, KIND_PARTIAL, KIND_STALL, KIND_EINTR,
             KIND_FLIP, KIND_CORRUPT)
-SHM_KINDS = (KIND_TORN, KIND_DOORBELL, KIND_FLIP, KIND_CORRUPT,
-             KIND_STALL)
 KINDS = (KIND_REFUSE, KIND_CTO, KIND_RESET, KIND_PARTIAL, KIND_STALL,
-         KIND_EINTR, KIND_FLIP, KIND_CORRUPT, KIND_TORN, KIND_DOORBELL)
+         KIND_EINTR, KIND_FLIP, KIND_CORRUPT)
 
 # Injection sites.  Connect-stage sites see only CONNECT_KINDS; the
-# "io" site (established worker-worker TCP links) sees IO_KINDS; the
-# "shm" site (shared-memory ring touchpoints) sees SHM_KINDS — both
-# transports are tortured by the same seeded schedules.
+# "io" site (established worker-worker links) sees IO_KINDS.
 SITE_TRACKER = "tracker"       # tracker command connects
 SITE_CONNECT = "connect"       # peer link dials during rendezvous
 SITE_ACCEPT = "accept"         # peer link accepts during rendezvous
 SITE_IO = "io"                 # established link send/recv
-SITE_SHM = "shm"               # shm ring writes/reads + doorbells
 # Control-plane link sites (the sharded tracker's fault surface —
-# doc/fault_tolerance.md "Sharded tracker").  Direction-filtered like
-# the shm kinds: each site is consulted only on the side named here, so
-# an injection always lands where its detector lives.
+# doc/fault_tolerance.md "Sharded tracker").  Direction-filtered: each
+# site is consulted only on the side named here, so an injection always
+# lands where its detector lives.
 SITE_HELLO = "hello"           # worker→tracker registration exchange
 SITE_HB = "hb"                 # worker→tracker heartbeat channel
 SITE_SCRAPE = "scrape"         # shard→aggregator obs scrape
@@ -96,7 +83,7 @@ TRACKER_LINK_SITES = (SITE_HELLO, SITE_HB, SITE_SCRAPE)
 # budgets must absorb it).  Connect-stage kinds already have their own
 # site (tracker), and corruption is the data plane's problem.
 TRACKER_LINK_KINDS = (KIND_RESET, KIND_STALL)
-SITES = (CONNECT_SITES + (SITE_IO, SITE_SHM) + TRACKER_LINK_SITES
+SITES = (CONNECT_SITES + (SITE_IO,) + TRACKER_LINK_SITES
          + DIRECTORY_SITES + SERVE_SITES)
 
 # Kinds without an explicit @site apply here.
@@ -107,10 +94,8 @@ _DEFAULT_SITES = {
     KIND_PARTIAL: (SITE_IO,),
     KIND_STALL: (SITE_IO,),
     KIND_EINTR: (SITE_IO,),
-    KIND_FLIP: (SITE_IO, SITE_SHM),
-    KIND_CORRUPT: (SITE_IO, SITE_SHM),
-    KIND_TORN: (SITE_SHM,),
-    KIND_DOORBELL: (SITE_SHM,),
+    KIND_FLIP: (SITE_IO,),
+    KIND_CORRUPT: (SITE_IO,),
 }
 
 DEFAULT_BUDGET = 256      # total injections per process life
@@ -184,11 +169,10 @@ class ChaosPlan:
         """One injection decision at ``site``; returns the fired kind or
         None.  Rules are evaluated in spec order; the first that fires
         wins (at most one fault per touchpoint).  ``kinds`` restricts
-        which rules this touchpoint can draw (the shm transport's
-        write and read touchpoints serve disjoint fault kinds — a
-        write-side ``torn`` must never fire at a read, where it would
-        degrade to a transient); per-rule consult counters keep the
-        schedule deterministic either way."""
+        which rules this touchpoint can draw (a link's send and
+        receive touchpoints serve different fault kinds — see
+        :meth:`io`); per-rule consult counters keep the schedule
+        deterministic either way."""
         if not self.active or self.injected >= self.budget:
             return None
         for rule in self._rules:
@@ -242,20 +226,6 @@ class ChaosPlan:
             return None
         return kind
 
-    def shm(self, kinds: Optional[tuple[str, ...]] = None
-            ) -> Optional[str]:
-        """Consult at one shm ring touchpoint (a completed ring write
-        on the producer side, a frame decode on the consumer side —
-        each passes the kinds it can apply, so write faults stay
-        permanent and read faults stay transient).  Same contract as
-        :meth:`io`: stalls served here, other kinds returned for the
-        ShmLink to apply."""
-        kind = self._consult(SITE_SHM, kinds)
-        if kind == KIND_STALL:
-            time.sleep(self.stall_ms / 1000.0)
-            return None
-        return kind
-
     def link(self, site: str,
              kinds: Optional[tuple[str, ...]] = None) -> Optional[str]:
         """Consult at one control-plane link touchpoint (the hello
@@ -277,7 +247,7 @@ class ChaosPlan:
 
     def mutate(self, mv, kind: str) -> None:
         """Deterministically damage ``mv`` in place for a fired
-        flip/corrupt/torn injection.  Position and bit ride the same
+        flip/corrupt injection.  Position and bit ride the same
         hash family as the schedule itself (keyed by a dedicated
         mutation counter), so a replayed seed reproduces the identical
         damage whenever the transfer sizes line up.  XOR damage is
@@ -292,11 +262,8 @@ class ChaosPlan:
         pos = h % n
         if kind == KIND_FLIP:
             mv[pos] ^= 1 << ((h >> 8) & 7)
-        elif kind == KIND_CORRUPT:
+        else:  # corrupt
             mv[pos] ^= ((h >> 8) & 0xFF) or 0xA5
-        else:  # torn: damage from pos to the end (a memcpy cut short)
-            for i in range(pos, n):
-                mv[i] ^= 0xFF
 
     def summary(self) -> dict:
         """Per-rule fire counts (for logs and reproduce lines)."""
@@ -353,8 +320,6 @@ def parse_plan(spec: str, identity: str,
                   "%s)", site, "/".join(SITES))
             if site == SITE_IO:
                 allowed: tuple[str, ...] = IO_KINDS
-            elif site == SITE_SHM:
-                allowed = SHM_KINDS
             elif site == SITE_ACCEPT:
                 # An accept has no retry path to absorb a refusal (the
                 # dialing PEER owns the retry), so only stalls make a

@@ -5,7 +5,7 @@ reduction path and the transport frame layer: it decides how an
 eligible allreduce payload is represented on every link, independent of
 WHICH schedule moves the bytes (tree/ring/halving/swing/hier), of
 bucket fusion, of the async pump, of pyrobust replay and of the
-transport underneath (tcp/shm, with or without integrity framing).
+link underneath (with or without integrity framing).
 ``rabit_wire_codec`` selects one per job (doc/performance.md
 "Quantized wire codecs"); the classic full-width wire stays the
 default, and the PR-3 bf16 cast is now simply the first codec

@@ -270,13 +270,11 @@ class PySocketEngine(Engine):
         # every touchpoint gates on that single check.
         self._chaos: Optional[chaos_mod.ChaosPlan] = None
         self._sock_buf = 0          # rabit_sock_buf (0 = kernel default)
-        # Pluggable transports (rabit_tpu/transport/): the factory owns
-        # link construction + feature negotiation + shm failover
-        # denial; built for real in init() once the knobs resolve.
+        # The link layer (rabit_tpu/transport/): the factory owns
+        # link construction + integrity negotiation; built for real in
+        # init() once the knobs resolve.
         self._lf = tr.LinkFactory(tr.TransportConfig(),
                                   timeout=self._timeout)
-        self._transport_label = "tcp"   # tuning-cache key dimension
-        self._obs_transport = "tcp"     # LIVE label streamed to obs
         # Wire codec (rabit_wire_codec): the ONE lossy wire-format
         # seam — None is the classic full-width wire, Bf16Codec is the
         # historical rabit_wire_dtype=bf16 cast, the block-scaled
@@ -624,40 +622,25 @@ class PySocketEngine(Engine):
         # every socket touchpoint from the first rendezvous on.
         self._chaos = chaos_mod.configure(params, identity=self._task_id,
                                           on_inject=self._chaos_inject)
-        # Pluggable transports + integrity framing (doc/parameters.md
-        # "Transports"; doc/fault_tolerance.md "Transports, integrity &
-        # failover").  All defaults keep the wire byte-identical; every
-        # feature is negotiated per link at rendezvous.
-        raw = _param_or_env("rabit_transport")
-        transport = (str(raw).strip().lower()
-                     if raw not in (None, "") else "tcp")
+        # Integrity framing (doc/parameters.md "Transports";
+        # doc/fault_tolerance.md "Links & integrity").  The default
+        # keeps the wire byte-identical; framing is negotiated per
+        # link at rendezvous.
         raw = _param_or_env("rabit_wire_integrity")
         integrity = (str(raw).strip().lower()
                      if raw not in (None, "") else "off")
-        ring_bytes = _size_or_zero(
-            _param_or_env("rabit_shm_ring_bytes"), 1 << 20) or (1 << 20)
-        raw = _param_or_env("rabit_transport_failover")
-        failover = str(raw).strip().lower() not in ("0", "false", "off") \
-            if raw not in (None, "") else True
-        raw = _param_or_env("rabit_shm_retries")
-        shm_retries = int(raw) if raw not in (None, "") else 3
-        raw = _param_or_env("rabit_shm_dir")
-        shm_dir = str(raw) if raw not in (None, "") else None
         # Egress pacing (bench/test knob, doc/parameters.md): emulate a
         # constrained cross-host link budget on loopback so bandwidth-
         # regime measurements (wire codecs, schedule crossovers) run in
         # the regime they target.  0 (the default) = unpaced.
         raw = _param_or_env("rabit_link_mbps")
         link_mbps = float(raw) if raw not in (None, "") else 0.0
-        cfg = tr.TransportConfig(
-            transport=transport, integrity=integrity,
-            shm_ring_bytes=ring_bytes, failover=failover,
-            shm_retries=shm_retries, shm_dir=shm_dir,
-            link_mbps=link_mbps)
+        cfg = tr.TransportConfig(integrity=integrity,
+                                 link_mbps=link_mbps)
         self._lf = tr.LinkFactory(
             cfg, timeout=self._timeout, sock_buf=self._sock_buf,
-            chaos=self._chaos, wrap=self._wrap_link,
-            events=_TransportEvents(self), log=self._log)
+            wrap=self._wrap_link, events=_TransportEvents(self),
+            log=self._log)
         self._rendezvous(P.CMD_START)
         self._start_heartbeat()
 
@@ -849,15 +832,8 @@ class PySocketEngine(Engine):
         self._sched_live = live
         self._demoted = demoted
         os.environ["RABIT_TPU_LOG_TAG"] = f"rank{self._rank}"
-        # The link factory negotiates per-peer transports from the same
-        # handout every rank received (host groups name the same-host
-        # shm candidates), so both ends of every link agree; the label
-        # keys auto-tuner lookups so shm and tcp measurements never
-        # answer for each other.
-        self._lf.set_topology(self._rank, self._groups)
-        self._transport_label = self._lf.cfg.mode_label(self._groups)
+        self._lf.rank = self._rank   # the link hello carries it
         self._reconnect_links(topo)
-        self._obs_transport = self._live_transport_label()
 
     def _register(self, cmd: str, my_host: str,
                   my_port: int) -> P.TopologyReply:
@@ -1025,13 +1001,10 @@ class PySocketEngine(Engine):
 
         Each established socket is handed to the transport factory,
         which runs the link handshake (classic bytes under default
-        config), negotiates shm/integrity features where configured,
-        and applies the shared socket setup (rabit_sock_buf,
-        TCP_NODELAY, timeout) on EVERY TCP link creation path — first
-        wiring, recovery re-dials and shm→tcp failover alike.  This is
-        the seam the live failover rides: a peer in the factory's
-        denied set (its shm link failed mid-job) renegotiates here as
-        plain TCP.
+        config), negotiates integrity framing where configured, and
+        applies the shared socket setup (rabit_sock_buf, TCP_NODELAY,
+        timeout) on EVERY link creation path — first wiring and
+        recovery re-dials alike.
         """
         for peer_rank, host, port in topo.connect:
             s = self._dial_retry((host, port), chaos_mod.SITE_CONNECT)
@@ -1210,17 +1183,10 @@ class PySocketEngine(Engine):
         obs.note_drops(self._metrics, self._trace)
         payload = {"rank": self._rank, "world": self._world,
                    "engine": type(self).__name__, "epoch": self._epoch,
-                   # The wire the measurements RODE (not just the one
-                   # configured): the controller's online TuningCache
-                   # merges key on it, so schedule verdicts learned
-                   # over shm never answer a tcp job — and a rank whose
-                   # shm lanes fell over (or fell back) to tcp stops
-                   # filing tcp-measured verdicts under allreduce@shm.
-                   "transport": self._obs_transport,
                    # The wire codec (replicated config): keys the
-                   # controller's online TuningCache merges like the
-                   # transport, so schedule verdicts measured over a
-                   # quantized wire never answer a full-width job.
+                   # controller's online TuningCache merges, so
+                   # schedule verdicts measured over a quantized wire
+                   # never answer a full-width job.
                    "codec": self._codec_label,
                    # Which implementation runs the codec hop math
                    # (native / numpy / numpy-fallback): purely
@@ -1498,61 +1464,19 @@ class PySocketEngine(Engine):
     # ------------------------------------------------------------------
     # link IO helpers (delegating to rabit_tpu/transport)
     # ------------------------------------------------------------------
-    def _live_transport_label(self) -> str:
-        """The wire label streamed with obs frames: the replicated
-        ``mode_label`` (which keys DISPATCH tuner picks and must stay a
-        collective decision), degraded to the truth this rank can see.
-        A rank that was nominated same-host peers yet holds no live shm
-        link — universal fallback (unwritable shm dir, attach refusals)
-        or mid-job failover denial — reports ``tcp``, so the
-        controller's online TuningCache merges never file tcp-measured
-        verdicts under the ``@shm`` rows.  A rank with no same-group
-        link peer defers to the world label: its measurements ride the
-        same collectives as the shm-paired ranks'."""
-        if self._transport_label != "shm":
-            return self._transport_label
-        if any(lk.kind == "shm" for lk in self._links.values()):
-            return "shm"
-        if any(self._lf.same_group(peer) for peer in self._links):
-            return "tcp"
-        return "shm"
-
     def _note_link_error(self, exc: LinkError) -> None:
-        """Failure attribution for the LIVE FAILOVER path: a LinkError
-        raised inside a shm link (health probe, ring fault, integrity
-        escalation) marks that peer transport-denied, so the recover
-        rendezvous this same exception is about to trigger re-dials the
-        link as plain TCP — mid-job, visible in the
-        ``transport.failover.*`` counters and the tracker timeline,
-        never a hang.  TCP failures change nothing here (there is no
-        transport below TCP to fall to; recovery handles them as
-        always).
-
-        Every LinkError — any transport — additionally lands in the
-        flight recorder and (with ``rabit_trace_dir`` set) persists it:
-        a surviving rank's record names the peer it was blocked on at
-        the moment the world broke, which is exactly the evidence
-        ``tools/postmortem.py`` votes the first-dead rank from."""
-        link = getattr(exc, "link", None)
-        peer = getattr(link, "peer", None)
+        """Every LinkError lands in the flight recorder and (with
+        ``rabit_trace_dir`` set) persists it: a surviving rank's record
+        names the peer it was blocked on at the moment the world broke,
+        which is exactly the evidence ``tools/postmortem.py`` votes the
+        first-dead rank from.  Recovery itself handles the error as
+        always."""
+        peer = getattr(getattr(exc, "link", None), "peer", None)
         if self._flight is not None:
             self._flight.note("link_error", rank=self._rank, peer=peer,
                               error=type(exc).__name__,
                               detail=str(exc)[:160])
             self.flight_persist("link_error", peer=peer)
-        if link is None or link.kind != "shm":
-            return
-        if not self._lf.deny(link.peer):
-            return
-        self._log.warn("transport: shm link to rank %d failed (%s: %s); "
-                       "failing over to tcp at the next rendezvous",
-                       link.peer, type(exc).__name__, exc)
-        if self._obs_on:
-            self._metrics.counter("transport.failover").inc()
-            self._metrics.counter("transport.failover.shm_to_tcp").inc()
-            self._trace.emit("transport", phase="failover",
-                             rank=self._rank, peer=link.peer,
-                             error=type(exc).__name__)
 
     def _send(self, rank: int, data: bytes | memoryview) -> None:
         try:
@@ -1698,10 +1622,9 @@ class PySocketEngine(Engine):
         hop shares: open (pump_begin may raise on a dead link), flush
         + restore on success, ABORT on any exception (framed backlog
         dropped — recovery rewires the links from scratch), and
-        LinkError attribution through :meth:`_note_link_error` so a
-        failing shm link still earns its tcp failover.  One copy of
-        the discipline, used by :meth:`_hop_pipelined` and the fused
-        segmented ring."""
+        LinkError attribution through :meth:`_note_link_error`.  One
+        copy of the discipline, used by :meth:`_hop_pipelined` and the
+        fused segmented ring."""
         pipe = None
         try:
             try:
@@ -2130,7 +2053,6 @@ class PySocketEngine(Engine):
             return self._static_schedule(nbytes)
         if name == "auto":
             pick = (self._tuner.pick("allreduce", logical, self._world,
-                                     self._transport_label,
                                      codec=pick_codec)
                     if self._tuner is not None else None)
             s = sched_mod.SCHEDULES.get(pick) if pick else None

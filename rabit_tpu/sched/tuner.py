@@ -49,18 +49,15 @@ class TuningCache:
 
     # ------------------------------------------------------------- build
     @staticmethod
-    def table_kind(kind: str, transport: str = "tcp",
-                   codec: str = "none") -> str:
-        """Cache-table key for an op kind on a transport and wire
-        codec.  ``tcp``/``none`` keep the bare kind (every pre-existing
-        cache keeps working); any other transport gets its own
-        ``kind@transport`` rows and any other codec its own
-        ``kind+codec`` rows, so a winner measured over shm rings never
-        answers a TCP world and a winner measured over a quantized wire
-        (whose per-payload wire bytes — hence crossovers — genuinely
-        differ) never answers a full-width job, or vice versa."""
-        if transport not in ("", "tcp", None):
-            kind = f"{kind}@{transport}"
+    def table_kind(kind: str, codec: str = "none") -> str:
+        """Cache-table key for an op kind on a wire codec.  ``none``
+        keeps the bare kind (every pre-existing cache keeps working);
+        any other codec gets its own ``kind+codec`` rows, so a winner
+        measured over a quantized wire (whose per-payload wire bytes —
+        hence crossovers — genuinely differ) never answers a full-width
+        job, or vice versa.  (A cache written by an older release may
+        hold ``kind@<transport>`` rows: no lookup forms that key any
+        more, so they load and answer nothing.)"""
         if codec not in ("", "none", None):
             kind = f"{kind}+{codec}"
         return kind
@@ -69,23 +66,21 @@ class TuningCache:
     def from_bench(cls, per_size_mbps: dict, world: int, *,
                    host: str = "", candidates=None,
                    extra_meta: dict | None = None,
-                   transport: str = "tcp",
                    codec: str = "none") -> "TuningCache":
         """Build from the per-size MB/s table the collectives bench
         emits (``{"<bytes>": {"tree": MBps, "ring": ..., ...}}``).
         ``candidates`` restricts which columns may win (the bench also
-        measures non-schedule paths like ``bucketed``); ``transport``
-        and ``codec`` key the rows to the wire they were measured on."""
+        measures non-schedule paths like ``bucketed``); ``codec`` keys
+        the rows to the wire format they were measured on."""
         best: dict[str, str] = {}
         for size, row in per_size_mbps.items():
             cand = {k: float(v) for k, v in row.items()
                     if candidates is None or k in candidates}
             if cand:
                 best[str(int(size))] = max(cand, key=cand.get)
-        meta = {"host": host, "world": int(world),
-                "transport": transport, "codec": codec}
+        meta = {"host": host, "world": int(world), "codec": codec}
         meta.update(extra_meta or {})
-        return cls({cls.table_kind("allreduce", transport, codec):
+        return cls({cls.table_kind("allreduce", codec):
                     {str(int(world)): best}}, meta)
 
     # --------------------------------------------------------------- io
@@ -134,20 +129,18 @@ class TuningCache:
 
     # ---------------------------------------------------------- online
     def merge_online(self, kind: str, world: int, nbytes: int,
-                     name: str, transport: str = "tcp",
-                     codec: str = "none") -> None:
+                     name: str, codec: str = "none") -> None:
         """Fold one LIVE measurement verdict into the table: the
         adaptive controller decided ``name`` wins ``(kind, world,
         payload bucket)`` from rolling span data (doc/performance.md
         "Online adaptation").  Widens the cache's world coverage — a
         bench'd cache learns worlds the bench never ran — and the next
         ``rabit_sched=auto`` job at this world starts on the learned
-        schedule instead of re-discovering it.  ``transport`` and
-        ``codec`` key the rows (:meth:`table_kind`): verdicts measured
-        over shm rings must never answer a tcp world, nor quantized-
-        wire verdicts a full-width job, or vice versa."""
+        schedule instead of re-discovering it.  ``codec`` keys the rows
+        (:meth:`table_kind`): quantized-wire verdicts must never answer
+        a full-width job, or vice versa."""
         rows = self.table.setdefault(
-            self.table_kind(kind, transport, codec), {}).setdefault(
+            self.table_kind(kind, codec), {}).setdefault(
             str(int(world)), {})
         rows[str(int(nbytes))] = str(name)
         self._world_fallback.clear()  # coverage changed: re-derive
@@ -156,20 +149,18 @@ class TuningCache:
 
     # ------------------------------------------------------------- query
     def pick(self, kind: str, nbytes: int, world: int,
-             transport: str = "tcp", codec: str = "none"
-             ) -> Optional[str]:
+             codec: str = "none") -> Optional[str]:
         """Winning schedule name for the nearest benchmarked payload
         size (log-space distance), or None.  An exact world match wins;
         a world the cache never saw falls back to the NEAREST bench'd
         world in log space (noted once per world in the structured log)
         instead of silently dropping to static — peer patterns scale
         smoothly enough in log(world) that a neighboring world's winner
-        beats no information at all.  ``transport`` and ``codec`` scope
-        the lookup to rows measured on the same wire format
-        (:meth:`table_kind`) — a shm or int8 world with no matching
-        rows misses to static rather than borrowing full-width TCP
-        numbers whose crossovers don't apply."""
-        kind = self.table_kind(kind, transport, codec)
+        beats no information at all.  ``codec`` scopes the lookup to
+        rows measured on the same wire format (:meth:`table_kind`) — an
+        int8 world with no matching rows misses to static rather than
+        borrowing full-width numbers whose crossovers don't apply."""
+        kind = self.table_kind(kind, codec)
         table = self.table.get(kind)
         if not table:
             return None

@@ -330,7 +330,6 @@ def main() -> None:
             "host": host,
             "world": world,
             "groups": list(eng._groups),
-            "transport": getattr(eng, "_transport_label", "tcp"),
             "codec": getattr(eng, "_codec_label", "none"),
             "pipeline_depth": getattr(eng, "_pipe_depth", 1),
             "engine": type(eng).__name__,
@@ -343,30 +342,26 @@ def main() -> None:
             with open(args.out, "w") as f:
                 json.dump(data, f, indent=2)
         if args.tune_dir:
-            # The transport AND wire codec this world measured on key
-            # the cache rows (allreduce vs allreduce@shm vs
-            # allreduce+int8 — sched/tuner.py table_kind): schedule
-            # crossovers genuinely differ between loopback TCP and shm
-            # rings, and between full-width and quantized wires whose
-            # per-payload bytes differ 2-4x — auto picks must never
-            # bleed across either dimension.
-            transport = getattr(eng, "_transport_label", "tcp")
+            # The wire codec this world measured on keys the cache
+            # rows (allreduce vs allreduce+int8 — sched/tuner.py
+            # table_kind): schedule crossovers genuinely differ between
+            # full-width and quantized wires whose per-payload bytes
+            # differ 2-4x — auto picks must never bleed across.
             codec = getattr(eng, "_codec_label", "none")
             cache = sched_mod.TuningCache.from_bench(
                 sizes, world, host=host,
-                candidates=set(sched_names), transport=transport,
-                codec=codec,
+                candidates=set(sched_names), codec=codec,
                 extra_meta={"bench": "collectives",
                             "sizes": sorted(int(s) for s in sizes),
                             "pipeline_depth": getattr(eng, "_pipe_depth",
                                                       1)})
             prior = sched_mod.TuningCache.load(args.tune_dir)
             if prior is not None:
-                # Merge-don't-clobber, per (kind, world): a tcp pass, a
-                # shm pass and runs at other world sizes all land in
-                # ONE cache file — this run's rows win only for the
-                # exact (kind, world) cells it actually measured, so a
-                # world-2 transport pass can never erase the flagship
+                # Merge-don't-clobber, per (kind, world): a full-width
+                # pass, a codec pass and runs at other world sizes all
+                # land in ONE cache file — this run's rows win only for
+                # the exact (kind, world) cells it actually measured,
+                # so a world-2 pass can never erase the flagship
                 # world-4 rows the nearest-world fallback serves.
                 merged = {k: dict(w) for k, w in prior.table.items()}
                 for kind, worlds in cache.table.items():
@@ -374,7 +369,7 @@ def main() -> None:
                 cache.table = merged
             path = cache.save(args.tune_dir)
             print(f"collectives_bench: wrote tuning cache to {path} "
-                  f"(transport={transport}, codec={codec})",
+                  f"(codec={codec})",
                   file=sys.stderr, flush=True)
     rabit_tpu.finalize()
 

@@ -38,12 +38,6 @@ Usage:
         # is then re-run as a FRESH job at that world size from the
         # same committed blob and the models compared bit-for-bit at
         # the next boundary; mix in --chaos for wire faults on top
-    python -m rabit_tpu.tools.soak --transport shm [--chaos]
-        # the shm-transport gate: shared-memory rings + integrity
-        # framing under seeded corruption (one guaranteed torn ring
-        # write per rank -> detect -> live shm->tcp failover), final
-        # model bit-exact vs an uninterrupted tcp reference; mix in
-        # --chaos for the full wire fault mix on top
     python -m rabit_tpu.tools.soak --adapt [--chaos]
         # the closed-loop gate: a world-4 pyrobust job with rank 0
         # deliberately slowed runs under a tracker with the adaptive
@@ -624,118 +618,6 @@ def run_elastic(args, rng: random.Random, round_obs_dir) -> int:
             print(f"[soak] round {r}: rescales bit-identical to fixed-"
                   f"world references at v{v1}/v{v2}/final", flush=True)
         print(f"[soak] {args.rounds} elastic rounds passed", flush=True)
-        return 0
-    finally:
-        shutil.rmtree(base, ignore_errors=True)
-
-
-def run_transport(args, rng: random.Random, round_obs_dir) -> int:
-    """The shm-transport gate (``--transport shm``): a same-host world
-    runs the bit-exactness worker over shared-memory rings with
-    integrity framing armed and a seeded corruption schedule — a
-    guaranteed ``torn`` ring write per rank (permanent damage: must be
-    DETECTED and then survived by a live shm→tcp failover mid-job),
-    transient ``flip``s on both transports (absorbed by the bounded
-    re-read / the robust op retry), and with ``--chaos`` the full wire
-    fault mix on top.  The final model of every rank must be
-    bit-identical to an uninterrupted loopback-TCP reference run —
-    zero silent corruption — and the failover must be visible in the
-    ``transport.failover.*`` counters and the merged tracker timeline.
-    """
-    import json
-    import shutil
-    import tempfile
-
-    from rabit_tpu.tracker.launch_local import launch
-
-    worker_path = args.worker_path or str(
-        _REPO_ROOT / "tests" / "workers" / "cold_restart.py")
-    base = pathlib.Path(tempfile.mkdtemp(prefix="rabit_shm_soak_"))
-    try:
-        ref_dir = base / "ref"
-        code = launch(
-            args.world, [sys.executable, worker_path,
-                         str(args.ndata), str(args.niter)],
-            extra_env={"RABIT_ENGINE": "pyrobust",
-                       "RABIT_OUT_DIR": str(ref_dir)})
-        if code != 0:
-            print(f"[soak] FAILED: uninterrupted tcp reference run "
-                  f"exited {code}", flush=True)
-            return 1
-        for r in range(args.rounds):
-            rdir = base / f"round{r}"
-            obs_dir = round_obs_dir(r) or str(rdir / "obs")
-            if args.chaos:
-                plan = gen_chaos(rng, "pyrobust")
-            else:
-                plan = (f"{rng.randrange(1 << 30)}:"
-                        f"refuse@connect=0.1*4")
-            # The transport-specific teeth: one guaranteed permanent
-            # torn ring write per rank (the failover trigger), plus
-            # transient read-side flips on shm and framed-TCP links.
-            plan += (";torn@shm=1.0*1;flip@shm=0.05*20;"
-                     "flip@io=0.01*20;corrupt@io=0.01*10")
-            env = {"RABIT_ENGINE": "pyrobust",
-                   "RABIT_TRANSPORT": "shm",
-                   "RABIT_WIRE_INTEGRITY": "crc32c",
-                   "RABIT_OUT_DIR": str(rdir / "out"),
-                   "RABIT_CHAOS": plan}
-            if "RABIT_TIMEOUT_SEC" not in os.environ:
-                env["RABIT_TIMEOUT_SEC"] = "20"
-            if "RABIT_BACKOFF_BASE_MS" not in os.environ:
-                env["RABIT_BACKOFF_BASE_MS"] = "20"
-            print(f"[soak] round {r}: transport=shm world={args.world} "
-                  f"chaos={plan}", flush=True)
-            code = launch(
-                args.world, [sys.executable, worker_path,
-                             str(args.ndata), str(args.niter)],
-                extra_env=env, obs_dir=obs_dir)
-            if code != 0:
-                print(f"[soak] FAILED (exit {code}) — reproduce with "
-                      f"RABIT_TRANSPORT=shm RABIT_WIRE_INTEGRITY=crc32c "
-                      f"RABIT_CHAOS='{plan}'", flush=True)
-                return 1
-            for rank in range(args.world):
-                ref = (ref_dir / f"final.{rank}").read_bytes()
-                got = (rdir / "out" / f"final.{rank}").read_bytes()
-                if ref != got:
-                    print(f"[soak] FAILED: rank {rank} final model is "
-                          f"NOT bit-identical to the tcp reference "
-                          f"(silent corruption?)", flush=True)
-                    return 1
-            rep = json.loads(
-                (pathlib.Path(obs_dir) / "obs_report.json").read_text())
-            agg = rep["aggregate"]
-            tl = rep["recovery_timeline"]
-
-            def metric(name: str) -> float:
-                return agg.get(name, {}).get("max", 0)
-
-            if metric("transport.links.shm") < 1:
-                print("[soak] FAILED: no shm link was ever negotiated "
-                      "— the gate ran vacuously on tcp", flush=True)
-                return 1
-            if metric("chaos.injected.torn") < 1 \
-                    or metric("integrity.detected") < 1:
-                print("[soak] FAILED: seeded corruption was injected "
-                      "but never detected (silent corruption window)",
-                      flush=True)
-                return 1
-            if metric("transport.failover.shm_to_tcp") < 1:
-                print("[soak] FAILED: the torn shm link never failed "
-                      "over to tcp", flush=True)
-                return 1
-            if not any(e["name"] == "transport"
-                       and e.get("phase") == "failover" for e in tl):
-                print("[soak] FAILED: failover happened but is not on "
-                      "the tracker timeline", flush=True)
-                return 1
-            print(f"[soak] round {r}: detected={metric('integrity.detected'):.0f} "
-                  f"failovers={metric('transport.failover'):.0f} "
-                  f"final model bit-identical to the tcp reference",
-                  flush=True)
-        print(f"[soak] {args.rounds} shm-transport rounds passed",
-              flush=True)
         return 0
     finally:
         shutil.rmtree(base, ignore_errors=True)
@@ -2836,17 +2718,6 @@ def main(argv: list[str] | None = None) -> int:
                          "commit boundary — migrated_out == "
                          "migrated_in, bit-exact finals, balanced "
                          "fleet books")
-    ap.add_argument("--transport", default="tcp",
-                    choices=["tcp", "shm"],
-                    help="shm: the transport gate — a same-host world "
-                         "over shared-memory rings with integrity "
-                         "framing and seeded corruption (guaranteed "
-                         "torn ring write per rank -> detection -> "
-                         "live shm->tcp failover), final model "
-                         "bit-exact vs an uninterrupted tcp reference; "
-                         "mixable with --chaos for the full wire fault "
-                         "mix on top (doc/fault_tolerance.md "
-                         "'Transports, integrity & failover')")
     ap.add_argument("--adapt", action="store_true",
                     help="closed-loop adaptive gate: a world-4 job "
                          "with a deliberately slowed rank under a "
@@ -2917,7 +2788,7 @@ def main(argv: list[str] | None = None) -> int:
         args.ndata = 5000
     if (args.chaos and args.engine == "mock" and not args.cold_restart
             and not args.elastic and not args.tenants
-            and not args.adapt and args.transport != "shm"):
+            and not args.adapt):
         ap.error("--chaos drives the Python engines only; pass "
                  "--engine pyrobust (recovery mix) or pysocket "
                  "(survivable mix)")
@@ -2946,19 +2817,9 @@ def main(argv: list[str] | None = None) -> int:
             ap.error("--adapt is its own scenario (cold_restart worker "
                      "with a slowed rank); it only combines with "
                      "--chaos (or rides --tenants)")
-    if args.transport == "shm":
-        if args.engine not in ("mock", "pyrobust"):
-            ap.error("--transport shm drives the pure-Python robust "
-                     "engine; pass --engine pyrobust (or leave the "
-                     "default)")
-        if args.cold_restart or args.elastic or args.adapt \
-                or args.tenants or args.worker != "model_recover":
-            ap.error("--transport shm is its own scenario "
-                     "(cold_restart worker, bit-exact vs a tcp "
-                     "reference); it only combines with --chaos")
     if args.postmortem:
         if (args.cold_restart or args.elastic or args.adapt
-                or args.tenants or args.transport == "shm"
+                or args.tenants
                 or args.serve or args.chaos
                 or args.worker != "model_recover"):
             ap.error("--postmortem is its own scenario (a seeded "
@@ -2970,7 +2831,7 @@ def main(argv: list[str] | None = None) -> int:
             ap.error("--serve drives the pure-Python robust engine; "
                      "pass --engine pyrobust (or leave the default)")
         if (args.cold_restart or args.elastic or args.adapt
-                or args.tenants or args.transport == "shm"
+                or args.tenants
                 or args.chaos or args.qos
                 or args.worker != "model_recover"):
             ap.error("--serve is its own scenario (serving fleet + "
@@ -2981,7 +2842,7 @@ def main(argv: list[str] | None = None) -> int:
             ap.error("--qos drives the pure-Python robust engine; "
                      "pass --engine pyrobust (or leave the default)")
         if (args.cold_restart or args.elastic or args.adapt
-                or args.tenants or args.transport == "shm"
+                or args.tenants
                 or args.chaos or args.postmortem
                 or args.worker != "model_recover"):
             ap.error("--qos is its own scenario (serving fleet with a "
@@ -3041,8 +2902,6 @@ def main(argv: list[str] | None = None) -> int:
         return run_shards(args, rng, round_obs_dir)
     if args.tenants:
         return run_tenants(args, rng, round_obs_dir)
-    if args.transport == "shm":
-        return run_transport(args, rng, round_obs_dir)
     if args.adapt:
         return run_adapt(args, rng, round_obs_dir)
     if args.elastic:

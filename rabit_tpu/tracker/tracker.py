@@ -200,10 +200,9 @@ class JobState:
         self._traces = obs.TraceAssembler()
         self._straggling: set[int] = set()
         self._obs_frames_bad = 0
-        # The job's wire transport and wire codec as reported in its
-        # streamed frames (uniform across ranks): both key the
-        # controller's online tuner merges (sched/tuner.py table_kind).
-        self._transport = "tcp"
+        # The job's wire codec as reported in its streamed frames
+        # (uniform across ranks): keys the controller's online tuner
+        # merges (sched/tuner.py table_kind).
         self._codec = "none"
         # Adaptive control plane (obs/adapt.py, tracker --adapt): the
         # per-job controller folds the merged spans into schedule
@@ -647,15 +646,9 @@ class JobState:
                 self._tag(), task_id, e)
             return
         self.last_activity = time.monotonic()
-        # The job's transport label (uniform across ranks — replicated
-        # config + handout): scopes the controller's online tuner
-        # merges so shm-measured winners never answer a tcp world.
-        transport = payload.get("transport")
-        if isinstance(transport, str) and transport:
-            self._transport = transport
-        # The wire codec label rides the same frames (also replicated
-        # config): winners measured over a quantized wire never answer
-        # a full-width job, mirroring the transport scoping.
+        # The wire codec label (uniform across ranks — replicated
+        # config) scopes the controller's online tuner merges: winners
+        # measured over a quantized wire never answer a full-width job.
         codec = payload.get("codec")
         if isinstance(codec, str) and codec:
             self._codec = codec
@@ -829,7 +822,6 @@ class JobState:
             merge = getattr(tracker, "_tune_merge", None)
             if merge is not None:  # bare test objects lack the cache
                 merge("allreduce", self.n_workers, act.bucket, act.sched,
-                      getattr(self, "_transport", "tcp"),
                       getattr(self, "_codec", "none"))
 
     def _push_sched_epoch(self) -> None:
@@ -2683,21 +2675,18 @@ class Tracker:
                         job._tag(), type(e).__name__, e)
 
     def _tune_merge(self, kind: str, world: int, nbytes: int,
-                    name: str, transport: str = "tcp",
-                    codec: str = "none") -> None:
+                    name: str, codec: str = "none") -> None:
         """Fold one controller verdict into the shared TuningCache and
         atomically re-persist it (tracker --tune-dir), so the NEXT
         ``rabit_sched=auto`` job starts on the learned schedule.
-        ``transport`` and ``codec`` (from the job's streamed frames)
-        key the rows — a winner measured over shm rings never answers a
-        tcp world, nor an int8-wire winner a full-width job.
+        ``codec`` (from the job's streamed frames) keys the rows — an
+        int8-wire winner never answers a full-width job.
         Best-effort: a full disk degrades warm starts, never the
         running job."""
         if self._tuning_cache is None:
             return
         with self._tune_lock:
             self._tuning_cache.merge_online(kind, world, nbytes, name,
-                                            transport=transport,
                                             codec=codec)
             if self._tune_dir:
                 try:

@@ -10,12 +10,10 @@ send/recv, vectored writes, timeouts, health — while the engine keeps
 everything above the byte stream (op framing, reduction math, seqno/
 replay, recovery).
 
-Two implementations ship: :class:`rabit_tpu.transport.tcp.TcpLink`
-(the existing TCP path, byte-identical on the wire, chaos interposition
-at the same syscall seam) and :class:`rabit_tpu.transport.shm.ShmLink`
-(same-host shared-memory ring buffers with the TCP connection retained
-as doorbell + liveness channel).  Both optionally speak **integrity
-framing** (``rabit_wire_integrity``): every write is wrapped in a
+One implementation ships: :class:`rabit_tpu.transport.tcp.TcpLink`
+(the classic TCP path, byte-identical on the wire, chaos interposition
+at the syscall seam).  It optionally speaks **integrity framing**
+(``rabit_wire_integrity``): every write is wrapped in a
 ``u32 length | payload | u32 crc`` frame so a flipped wire bit is
 *detected* — surfacing as a typed :class:`IntegrityError` (a
 :class:`LinkError`, so the pyrobust recovery path treats it like any
@@ -50,13 +48,6 @@ SENDMSG_MAX_PARTS = 64
 #: frame layout and detection strength are identical, and peers agree on
 #: the mode through the link handshake either way.
 INTEGRITY_MODES = ("off", "crc32", "crc32c")
-TRANSPORT_MODES = ("tcp", "shm", "auto")
-
-#: smallest usable shm ring: enforced on the local config AND on the
-#: NEGOTIATED size (a skewed or garbled peer offer below this takes the
-#: clean tcp-fallback path — a degenerate ring whose every write
-#: returns 0 would stall to the link timeout instead).
-SHM_RING_MIN = 4096
 
 
 class LinkError(ConnectionError):
@@ -65,7 +56,7 @@ class LinkError(ConnectionError):
     Raised by every transport on IO failure; the robust engine's
     recovery path catches exactly this.  Instances raised inside a
     :class:`Link` carry the link as ``err.link`` so the engine can
-    attribute the failure (e.g. shm→tcp failover bookkeeping)."""
+    attribute the failure (flight-recorder note)."""
 
     link: Optional["Link"] = None
 
@@ -74,13 +65,11 @@ class IntegrityError(LinkError):
     """Integrity framing detected wire corruption on a link.
 
     A frame's CRC trailer (or a structurally impossible frame length)
-    did not match its payload after the transport's bounded re-read
-    budget.  This IS a :class:`LinkError` on purpose: the pyrobust
-    recovery path escalates it exactly like a peer death — the op
-    retries from pristine buffers — and the engine's failover hook
-    additionally tears a corrupted shm link down and re-dials it as
-    TCP.  Without a robust layer it reaches the caller typed, never as
-    silently wrong numbers."""
+    did not match its payload.  This IS a :class:`LinkError` on
+    purpose: the pyrobust recovery path escalates it exactly like a
+    peer death — the op retries from pristine buffers.  Without a
+    robust layer it reaches the caller typed, never as silently wrong
+    numbers."""
 
 
 class Events:
@@ -101,59 +90,24 @@ NULL_EVENTS = Events()
 class TransportConfig:
     """Resolved transport knobs (doc/parameters.md "Transports").
 
-    ``transport``: ``tcp`` (default — byte-identical classic wire),
-    ``shm``/``auto`` (offer shared-memory rings to same-host-group
-    peers, TCP cross-host; ``shm`` logs when it has to fall back).
-    ``integrity``: ``off`` | ``crc32`` | ``crc32c`` frame trailers.
-    ``shm_ring_bytes``: per-direction ring capacity.  ``failover``:
-    tear a failing shm link down and re-dial as TCP at the next
-    rendezvous.  ``shm_retries``: bounded re-reads of a CRC-failed shm
-    frame before escalating (catches a torn-but-completing write).
+    ``integrity``: ``off`` (default — byte-identical classic wire) |
+    ``crc32`` | ``crc32c`` frame trailers.
     ``link_mbps``: egress pacing per TCP link (:class:`LinkPacer`;
     0 = unpaced — the default and the only production setting).
     """
 
-    def __init__(self, transport: str = "tcp", integrity: str = "off",
-                 shm_ring_bytes: int = 1 << 20, failover: bool = True,
-                 shm_retries: int = 3,
-                 shm_dir: Optional[str] = None,
+    def __init__(self, integrity: str = "off",
                  link_mbps: float = 0.0) -> None:
-        check(transport in TRANSPORT_MODES,
-              "rabit_transport must be one of %s, got %r",
-              "/".join(TRANSPORT_MODES), transport)
         check(integrity in INTEGRITY_MODES,
               "rabit_wire_integrity must be one of %s, got %r",
               "/".join(INTEGRITY_MODES), integrity)
-        check(shm_ring_bytes >= SHM_RING_MIN,
-              "rabit_shm_ring_bytes must be >= %d, got %r",
-              SHM_RING_MIN, shm_ring_bytes)
-        check(shm_retries >= 0, "rabit_shm_retries must be >= 0")
         check(link_mbps >= 0, "rabit_link_mbps must be >= 0")
         self.link_mbps = float(link_mbps)
-        self.transport = transport
         self.integrity = integrity
-        self.shm_ring_bytes = int(shm_ring_bytes)
-        self.failover = bool(failover)
-        self.shm_retries = int(shm_retries)
-        self.shm_dir = shm_dir
 
     @property
     def wants_integrity(self) -> bool:
         return self.integrity != "off"
-
-    @property
-    def wants_shm(self) -> bool:
-        return self.transport in ("shm", "auto")
-
-    def mode_label(self, groups: list[int]) -> str:
-        """The transport label for tuning-cache keys: ``shm`` when shm
-        is configured AND the topology has same-group peers to use it
-        on, else ``tcp``.  Replicated inputs only (config + handout),
-        so every rank computes the same label — schedule choice stays a
-        collective decision."""
-        if self.wants_shm and len(groups) != len(set(groups)):
-            return "shm"
-        return "tcp"
 
 
 class LinkPacer:
@@ -218,8 +172,8 @@ def wait_readable_writable(rlist, wlist, timeout: Optional[float]
     host process routinely exceed FD_SETSIZE, and the transport layer
     must degrade to a LinkError, never a ValueError crash (same
     rationale as the tracker's serve loop).  Not an epoll selector
-    either: shm waits call this once per 2 ms slice, and a poll object
-    costs no kernel fd and no per-call register/close syscalls.
+    either: a poll object costs no kernel fd and no per-call
+    register/close syscalls.
     Returns (readable, writable)."""
     poller = select.poll()
     by_fd: dict = {}
@@ -257,9 +211,9 @@ def wait_readable_writable(rlist, wlist, timeout: Optional[float]
 def setup_stream_socket(sock: socket.socket, timeout: Optional[float],
                         sock_buf: int) -> socket.socket:
     """The ONE socket-setup helper every TCP link creation path runs —
-    first wiring, recovery re-dials after a chaos reset, and shm→tcp
-    failover re-dials alike — so ``rabit_sock_buf`` and the latency
-    options can never silently miss a re-created link.  TCP_NODELAY
+    first wiring and recovery re-dials after a chaos reset alike — so
+    ``rabit_sock_buf`` and the latency options can never silently miss
+    a re-created link.  TCP_NODELAY
     (small consensus words must not wait on Nagle), the engine's link
     IO timeout, and SO_SNDBUF/SO_RCVBUF when ``rabit_sock_buf`` asks
     (both directions; the kernel doubles the value for bookkeeping).
@@ -307,9 +261,9 @@ class Link:
       multi-link pumps (:mod:`rabit_tpu.transport.pump`) multiplex
       over.  ``rx_pending()`` must be True only when ``poll_recv``
       WILL make progress without new wire bytes, or the pump would
-      busy-spin; ``needs_poll()`` marks transports whose readiness a
-      plain ``select`` cannot fully see (shm rings), bounding the
-      pump's wait slices.
+      busy-spin; ``needs_poll()`` marks a link whose readiness a
+      plain ``poll`` cannot fully see (a paced-out TcpLink), bounding
+      the pump's wait slices.
     """
 
     kind = "?"
@@ -367,25 +321,13 @@ class Link:
     def needs_poll(self) -> bool:
         return False
 
-    def drain_wakeups(self) -> None:
-        """Consume queued doorbell bytes (shm); no-op elsewhere."""
-
-    def arm_wait(self, rx: bool) -> None:
-        """Advertise an imminent blocking wait for data (``rx``) or
-        space (``not rx``) so the peer knows a wakeup is wanted (shm
-        waiter flags); no-op elsewhere.  Callers must re-check
-        readiness after arming and ``disarm_wait`` afterwards."""
-
-    def disarm_wait(self, rx: bool) -> None:
-        pass
-
     def fileno(self) -> int:
         raise NotImplementedError
 
     # -- lifecycle -----------------------------------------------------
     def healthy(self) -> bool:
         """Cheap liveness probe: False once the peer is known dead or
-        the channel is structurally broken (closed fd, bad ring magic).
+        the channel is structurally broken (closed fd).
         Never blocks."""
         return True
 

@@ -12,9 +12,7 @@ Detection, not correction: a mismatched trailer increments the
 ``integrity.detected`` counter and raises
 :class:`~rabit_tpu.transport.base.IntegrityError` (TCP consumes the
 stream, so there is nothing left to re-read; the pyrobust layer retries
-the whole op from pristine buffers).  The shm transport can do better —
-its ring supports re-reading an unconsumed frame — and implements the
-bounded re-read retry in :mod:`rabit_tpu.transport.shm`.
+the whole op from pristine buffers).
 
 The checksum is the stdlib's C-accelerated CRC-32 (``zlib.crc32``) for
 both negotiated mode names (see ``INTEGRITY_MODES`` in base.py).
@@ -72,10 +70,9 @@ def encode_frames(bufs: list, frame_max: int = FRAME_MAX) -> list:
 
 
 class PlainBuffer:
-    """Verified-plaintext staging shared by the framed receive paths
-    (the TCP deframer below and the shm ring's verify-then-consume
-    reader): ``push()`` verified payload in, ``take()`` serves the
-    engine's reads in whatever sizes it asks."""
+    """Verified-plaintext staging of the framed receive path (the
+    deframer below): ``push()`` verified payload in, ``take()`` serves
+    the engine's reads in whatever sizes it asks."""
 
     def __init__(self) -> None:
         self._buf = bytearray()

@@ -3,13 +3,11 @@
 The engine's deadlock-sensitive concurrent IO patterns — full-duplex
 ring exchange and the tree's multi-child drain — used to be select
 loops hardwired to sockets.  They live here now, written against the
-:class:`~rabit_tpu.transport.base.Link` pump interface so a ring step
-between two shm peers, two TCP peers, or one of each runs the same
-loop: poll every involved link, and only when NOTHING progressed wait
-on the links' fds.  Shm links bound the wait to a short slice
-(``needs_poll``): their ring state is not fully visible to ``select``,
-so the pump re-polls at millisecond granularity as the lost-wakeup
-safety net while the doorbell fd provides the common-case wakeup.
+:class:`~rabit_tpu.transport.base.Link` pump interface: poll every
+involved link, and only when NOTHING progressed wait on the links'
+fds.  A paced-out link bounds the wait to a short slice
+(``needs_poll``): the kernel calls it writable while its token bucket
+says wait, so the pump re-polls at millisecond granularity.
 
 Byte streams are unchanged from the inline loops: payload is consumed
 in arrival order per link, send windows are whatever the kernel (or
@@ -23,14 +21,18 @@ from typing import Optional
 
 from rabit_tpu.transport.base import (Link, LinkError, flatten_parts,
                                       wait_readable_writable)
-from rabit_tpu.transport.shm import WAIT_SLICE_SEC
+
+#: wait slice for a link whose readiness ``poll`` cannot fully see
+#: (``Link.needs_poll``: a paced-out TcpLink is write-ready to the
+#: kernel but must wait for its bucket to refill).
+WAIT_SLICE_SEC = 0.002
 
 
 def _timeout_error(links: list[Link], msg: str) -> LinkError:
     """Build the idle-timeout error, health-probing the stalled links
-    first: a link that is structurally dead (ctrl EOF, lost ring
-    magic) gets the blame — with ``err.link`` attribution, so the
-    engine's failover hook fires — instead of an anonymous timeout."""
+    first: a link that is structurally dead (closed fd) gets the
+    blame — with ``err.link`` attribution for the engine's
+    flight-recorder note — instead of an anonymous timeout."""
     for link in links:
         try:
             ok = link.healthy()
@@ -51,47 +53,28 @@ def _wait(rlinks: list[Link], wlinks: list[Link],
     now = time.monotonic()
     if deadline is not None and now >= deadline:
         raise _timeout_error(rlinks + wlinks, timeout_msg)
-    bounded = any(link.needs_poll() for link in rlinks) \
-        or any(link.needs_poll() for link in wlinks)
-    # Shm write-waits watch the doorbell fd for READABLE wakeup bytes
-    # (the reader signals freed space on the same channel).
-    rlist = list(rlinks) + [lk for lk in wlinks
-                            if lk.needs_poll() and lk not in rlinks]
+    if not rlinks and not wlinks:
+        return
+    if any(link.rx_pending() for link in rlinks):
+        return
+    # A paced-out link is writable to the kernel all the while: sleep
+    # a bounded slice instead of watching its fd for POLLOUT.
     wlist = [lk for lk in wlinks if not lk.needs_poll()]
+    paced = len(wlist) < len(wlinks)
     wait_sec = None if deadline is None else max(deadline - now, 0.0)
-    if bounded:
+    if paced:
         wait_sec = WAIT_SLICE_SEC if wait_sec is None \
             else min(wait_sec, WAIT_SLICE_SEC)
-    if not rlist and not wlist:
-        return
-    # Waiter flags first, readiness re-check second (the shm sleep
-    # protocol: the peer rings only for an advertised sleeper, and it
-    # may have acted between our poll and the arm).
-    for link in rlinks:
-        link.arm_wait(rx=True)
-    for link in wlinks:
-        link.arm_wait(rx=False)
     try:
-        for link in rlinks:
-            if link.rx_pending():
-                return
-        try:
-            readable, writable = wait_readable_writable(rlist, wlist,
-                                                        wait_sec)
-        except (OSError, ValueError) as e:
-            raise LinkError(f"{timeout_msg.split(':')[0]}: wait "
-                            f"failed: {e}") from e
-        if deadline is not None and not bounded \
-                and not readable and not writable:
-            # select blocked the full remaining idle budget, no event
-            raise _timeout_error(rlinks + wlinks, timeout_msg)
-        for link in rlist:
-            link.drain_wakeups()
-    finally:
-        for link in rlinks:
-            link.disarm_wait(rx=True)
-        for link in wlinks:
-            link.disarm_wait(rx=False)
+        readable, writable = wait_readable_writable(rlinks, wlist,
+                                                    wait_sec)
+    except (OSError, ValueError) as e:
+        raise LinkError(f"{timeout_msg.split(':')[0]}: wait "
+                        f"failed: {e}") from e
+    if deadline is not None and not paced \
+            and not readable and not writable:
+        # poll blocked the full remaining idle budget, no event
+        raise _timeout_error(rlinks + wlinks, timeout_msg)
 
 
 def _end_all(begun: list[Link], suppress: bool) -> None:

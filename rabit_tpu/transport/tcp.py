@@ -232,7 +232,7 @@ class TcpLink(Link):
     def needs_poll(self) -> bool:
         # A paced-out link is write-ready to select (the kernel buffer
         # has room) but must not be re-polled hot: bound the pump's
-        # wait to the shm-style slice until the bucket refills.
+        # wait to pump.WAIT_SLICE_SEC until the bucket refills.
         return self._pacer is not None and not self._pacer.ready()
 
     def fileno(self) -> int:

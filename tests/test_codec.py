@@ -245,17 +245,14 @@ def test_eligibility_is_replicated_config():
 
 # ------------------------------------------------------ tuner dimension
 def test_tuning_cache_codec_dimension(tmp_path):
-    """Codec-keyed rows are isolated per codec AND per transport —
-    picks never bleed across wire formats (mirrors the transport
-    dimension's isolation contract)."""
+    """Codec-keyed rows are isolated per codec — picks never bleed
+    across wire formats."""
     from rabit_tpu.sched.tuner import TuningCache
 
     assert TuningCache.table_kind("allreduce") == "allreduce"
-    assert TuningCache.table_kind("allreduce", "shm") == "allreduce@shm"
-    assert TuningCache.table_kind("allreduce", "tcp", "int8") \
+    assert TuningCache.table_kind("allreduce", "none") == "allreduce"
+    assert TuningCache.table_kind("allreduce", "int8") \
         == "allreduce+int8"
-    assert TuningCache.table_kind("allreduce", "shm", "int8") \
-        == "allreduce@shm+int8"
     f32 = TuningCache.from_bench({"4096": {"tree": 100.0, "ring": 10.0}},
                                  4, candidates={"tree", "ring"})
     q = TuningCache.from_bench({"4096": {"tree": 10.0, "ring": 100.0}},
@@ -268,7 +265,6 @@ def test_tuning_cache_codec_dimension(tmp_path):
     assert cache.pick("allreduce", 4096, 4, codec="none") == "tree"
     assert cache.pick("allreduce", 4096, 4, codec="int8") == "ring"
     assert cache.pick("allreduce", 4096, 4, codec="int4") is None
-    assert cache.pick("allreduce", 4096, 4, "shm", "int8") is None
     cache.merge_online("allreduce", 6, 8192, "swing", codec="int4")
     assert cache.pick("allreduce", 8192, 6, codec="int4") == "swing"
     # The none-codec pick at world 6 must NOT see int4's world-6 row:

@@ -1,29 +1,25 @@
-"""Pluggable transports: shm rings, integrity framing, live failover.
+"""The host link layer: one link kind (TCP), optional integrity framing.
 
-Five layers under test (doc/fault_tolerance.md "Transports, integrity
-& failover"):
+Layers under test (doc/fault_tolerance.md "Links & integrity"):
 
-* the primitives — ShmRing wrap-around/peek semantics, the frame
-  codec's encode/decode round trip and corruption detection, the
-  transport-keyed tuning-cache rows;
-* link pairs in one process — framed shm round trips, write-side
-  ``torn`` damage escalating as a typed IntegrityError, read-side
-  ``flip`` damage transparently absorbed by the bounded re-read;
+* the primitives — the frame codec's encode/decode round trip and
+  corruption detection, the pump's wait, the tuning cache ignoring
+  rows a pre-PR-28 release keyed by transport;
+* link pairs in one process — a framed TcpLink round trip above
+  ``FRAME_MAX``, the pump's abort path, injected↔detected pairing;
 * the negotiation handshake — default config stays on the classic
-  byte-identical wire, features activate only in the offer
+  byte-identical wire, framing activates only in the offer
   intersection (mixed-config worlds interoperate in both directions),
-  same-host-group peers upgrade to shm, cross-group stay tcp;
-* the chaos contract — flip/corrupt/torn/doorbell ride the same
-  seeded deterministic schedules as every other kind, and with framing
-  on EVERY injected corruption pairs with an ``integrity.detected``
-  count (zero silent corruption);
-* end to end — the transport parity matrix (worlds 2/4/5, shm and
-  mixed same-host/cross-host topologies, every schedule, the
-  zero/1/odd-size payload ladder), kill-point replay over shm under
-  pyrobust, and a mid-job torn ring failing over to tcp with the
-  failover on the obs counters — plus the engine-hygiene lint over
-  rabit_tpu/transport/.  The randomized gate is
-  ``tools/soak.py --transport shm [--chaos]`` (slow-marked here).
+  and the ``shm:<bytes>`` token an older release may still offer is
+  ignored;
+* the chaos contract — flip/corrupt ride the same seeded deterministic
+  schedules as every other kind, and with framing on EVERY injected
+  corruption pairs with an ``integrity.detected`` count (zero silent
+  corruption);
+* end to end — the framed parity matrix (worlds 2/4/5, flat and
+  grouped topologies, every schedule, the zero/1/odd-size payload
+  ladder) and kill-point replay over framed links under pyrobust —
+  plus the engine-hygiene lint over rabit_tpu/transport/.
 """
 import ast
 import json
@@ -63,52 +59,6 @@ class _Counters:
 
     def event(self, name, **fields):
         self.events.append((name, fields))
-
-
-# ---------------------------------------------------------------- rings
-def test_shm_ring_roundtrip_and_wraparound(tmp_path):
-    from rabit_tpu.transport.shm import ShmRing
-
-    ring, path = ShmRing.create(str(tmp_path), 64)
-    peer = ShmRing.attach(path)
-    os.unlink(path)
-    rng = np.random.default_rng(7)
-    sent = bytearray()
-    got = bytearray()
-    # Push ~10 ring capacities through in ragged chunks so the cursors
-    # wrap many times and every copy path splits at the boundary.
-    payload = rng.integers(0, 256, 640, dtype=np.uint8).tobytes()
-    off = 0
-    while off < len(payload) or len(got) < len(payload):
-        if off < len(payload):
-            n = ring.write(memoryview(payload)[off:off + 37])
-            sent += payload[off:off + n]
-            off += n
-        buf = bytearray(29)
-        n = peer.read(memoryview(buf))
-        got += buf[:n]
-    assert bytes(got) == payload
-    assert ring.avail() == 0 and ring.space() == 64
-
-
-def test_shm_ring_peek_does_not_consume(tmp_path):
-    from rabit_tpu.transport.shm import ShmRing
-
-    ring, path = ShmRing.create(str(tmp_path), 32)
-    peer = ShmRing.attach(path)
-    os.unlink(path)
-    ring.write(memoryview(b"abcdefgh"))
-    first = bytearray(4)
-    peer.peek(0, memoryview(first))
-    again = bytearray(4)
-    peer.peek(0, memoryview(again))
-    assert bytes(first) == bytes(again) == b"abcd"
-    assert peer.avail() == 8  # nothing consumed
-    mid = bytearray(3)
-    peer.peek(2, memoryview(mid))
-    assert bytes(mid) == b"cde"
-    peer.advance(8)
-    assert peer.avail() == 0
 
 
 # --------------------------------------------------------------- frames
@@ -159,38 +109,45 @@ def test_frame_codec_detects_each_corruption():
 
 
 # ----------------------------------------------------- tuning-cache key
-def test_tuning_cache_transport_keyed_rows():
-    from rabit_tpu.sched import TuningCache
+def test_tuning_cache_ignores_legacy_transport_rows(tmp_path):
+    """A cache file written before PR 28 may hold ``allreduce@shm``
+    rows (the transport key dimension that went with the shm link): it
+    still loads, and those rows answer no lookup."""
+    from rabit_tpu.sched.tuner import (CACHE_FILENAME, SCHEMA_VERSION,
+                                       TuningCache)
 
-    tcp = TuningCache.from_bench({"4096": {"tree": 100.0, "ring": 10.0}},
-                                 4, transport="tcp")
-    shm = TuningCache.from_bench({"4096": {"tree": 10.0, "ring": 100.0}},
-                                 4, transport="shm")
-    merged = dict(tcp.table)
-    merged.update(shm.table)
-    cache = TuningCache(merged)
+    rows = {"4": {"4096": "ring"}}
+    (tmp_path / CACHE_FILENAME).write_text(json.dumps({
+        "schema": SCHEMA_VERSION,
+        "meta": {"host": "old", "world": 4, "transport": "shm"},
+        "table": {"allreduce": {"4": {"4096": "tree"}},
+                  "allreduce@shm": rows,
+                  "allreduce@shm+int8": rows}}))
+    cache = TuningCache.load(str(tmp_path))
+    assert cache is not None
     assert cache.pick("allreduce", 4096, 4) == "tree"
-    assert cache.pick("allreduce", 4096, 4, "tcp") == "tree"
-    assert cache.pick("allreduce", 4096, 4, "shm") == "ring"
-    # no bleed: a transport with no rows misses to None (static), it
-    # never borrows the other transport's winner
-    only_tcp = TuningCache(dict(tcp.table))
-    assert only_tcp.pick("allreduce", 4096, 4, "shm") is None
+    for nbytes in (64, 4096, 1 << 20):
+        for world in (2, 4, 6):   # exact and nearest-world lookups
+            assert cache.pick("allreduce", nbytes, world) != "ring"
+            assert cache.pick("allreduce", nbytes, world,
+                              codec="int8") is None
+    # ...and an online merge lands beside them, never on them
+    cache.merge_online("allreduce", 4, 4096, "halving", codec="int8")
+    assert cache.pick("allreduce", 4096, 4, codec="int8") == "halving"
+    assert cache.table["allreduce@shm+int8"] == rows
 
 
 # ------------------------------------------------------- chaos contract
 def test_chaos_corruption_kinds_grammar_and_determinism():
-    from rabit_tpu.chaos import parse_plan
+    from rabit_tpu.chaos import ChaosSocket, parse_plan
     from rabit_tpu.utils.checks import RabitError
 
-    spec = ("23:flip@io=0.2;corrupt@io=0.1;torn@shm=0.3;"
-            "doorbell@shm=0.2;flip@shm=0.1;budget=200")
+    spec = "23:flip@io=0.2;corrupt@io=0.1;budget=200"
 
     def drive(plan):
         for _ in range(300):
-            plan.io()
-            plan.shm(("torn", "doorbell", "stall"))
-            plan.shm(("flip", "corrupt"))
+            plan.io(ChaosSocket._TX_KINDS)   # a send: draws nothing here
+            plan.io()                        # a receive
         return list(plan.log)
 
     log_a = drive(parse_plan(spec, identity="2"))
@@ -199,11 +156,17 @@ def test_chaos_corruption_kinds_grammar_and_determinism():
     assert drive(parse_plan(spec.replace("23:", "24:", 1),
                             identity="2")) != log_a
     kinds = {k for _, k, _, _ in log_a}
-    assert {"flip", "torn", "doorbell"} <= kinds
-    # shm-only kinds cannot fire at wire sites and vice versa
-    for bad in ("1:torn@io=0.1", "1:doorbell@io=0.1",
-                "1:reset@shm=0.1", "1:flip@connect=0.1",
-                "1:torn@accept=0.1"):
+    assert kinds == {"flip", "corrupt"}
+    # corruption manifests in RECEIVED bytes: a send never draws it
+    tx_only = parse_plan(spec, identity="2")
+    for _ in range(300):
+        tx_only.io(ChaosSocket._TX_KINDS)
+    assert not tx_only.log
+    # corruption kinds fire at the io site only; the shm site and its
+    # kinds went with the shm link and are rejected like any unknown
+    for bad in ("1:flip@connect=0.1", "1:corrupt@accept=0.1",
+                "1:flip@shm=0.1", "1:reset@shm=0.1", "1:torn@io=0.1",
+                "1:torn=0.1", "1:doorbell=0.1"):
         with pytest.raises((RabitError, ValueError)):
             parse_plan(bad, identity="0")
 
@@ -213,7 +176,7 @@ def test_chaos_mutate_is_deterministic_and_never_noop():
 
     a = parse_plan("5:flip@io=1.0", identity="1")
     b = parse_plan("5:flip@io=1.0", identity="1")
-    for kind in ("flip", "corrupt", "torn"):
+    for kind in ("flip", "corrupt"):
         va = bytearray(b"0123456789abcdef")
         vb = bytearray(b"0123456789abcdef")
         a.mutate(va, kind)
@@ -223,30 +186,21 @@ def test_chaos_mutate_is_deterministic_and_never_noop():
 
 
 # ------------------------------------------------------------ link pairs
-def _shm_pair(tmp_path, frames=True, plan_w=None, plan_r=None,
-              ev_w=None, ev_r=None, ring=65536, timeout=10.0,
-              retries=3):
-    from rabit_tpu.transport.base import NULL_EVENTS
-    from rabit_tpu.transport.shm import ShmLink, ShmRing
+def test_tcp_link_framed_roundtrip_threaded():
+    """A payload above FRAME_MAX (so it frames per chunk) through a
+    framed link pair with a small send buffer: writer and reader run
+    concurrently and the stream comes out byte-exact."""
+    from rabit_tpu.transport.base import FRAME_MAX
+    from rabit_tpu.transport.tcp import TcpLink
 
     a, b = socket.socketpair()
-    r1, p1 = ShmRing.create(str(tmp_path), ring)
-    r2, p2 = ShmRing.create(str(tmp_path), ring)
-    w = ShmLink(a, 1, r1, ShmRing.attach(p2), timeout,
-                ev_w or NULL_EVENTS, frames=frames, plan=plan_w,
-                retries=retries)
-    r = ShmLink(b, 0, r2, ShmRing.attach(p1), timeout,
-                ev_r or NULL_EVENTS, frames=frames, plan=plan_r,
-                retries=retries)
-    os.unlink(p1)
-    os.unlink(p2)
-    return w, r
-
-
-def test_shm_link_framed_roundtrip_threaded(tmp_path):
-    w, r = _shm_pair(tmp_path, ring=4096)  # payload >> ring: must wrap
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+    ev = _Counters()
+    w = TcpLink(a, 1, 10.0, frames=True)
+    r = TcpLink(b, 0, 10.0, ev, frames=True)
     rng = np.random.default_rng(3)
-    payload = rng.integers(0, 256, 100_000, dtype=np.uint8).tobytes()
+    payload = rng.integers(0, 256, 2 * FRAME_MAX + 12345,
+                           dtype=np.uint8).tobytes()
     err = []
 
     def writer():
@@ -261,56 +215,7 @@ def test_shm_link_framed_roundtrip_threaded(tmp_path):
     t.join(timeout=30)
     assert not err, err
     assert bytes(out) == payload
-    w.close()
-    r.close()
-
-
-def test_shm_link_torn_write_escalates_typed(tmp_path):
-    from rabit_tpu.chaos import parse_plan
-    from rabit_tpu.transport.base import IntegrityError, LinkError
-
-    ev = _Counters()
-    plan = parse_plan("9:torn@shm=1.0*1", identity="1")
-    w, r = _shm_pair(tmp_path, plan_w=plan, ev_r=ev)
-    w.sendall(b"x" * 512)
-    assert [k for _, k, _, _ in plan.log] == ["torn"]
-    with pytest.raises(IntegrityError) as ei:
-        r.recv_exact(512)
-    assert isinstance(ei.value, LinkError)   # recovery path catches it
-    assert ei.value.link is r                # failover attribution
-    assert ev.counts.get("integrity.detected") == 1
-    w.close()
-    r.close()
-
-
-def test_shm_link_read_flip_recovered_by_reread(tmp_path):
-    from rabit_tpu.chaos import parse_plan
-
-    ev = _Counters()
-    plan = parse_plan("11:flip@shm=1.0*1", identity="0")
-    w, r = _shm_pair(tmp_path, plan_r=plan, ev_r=ev)
-    w.sendall(b"payload under transient read damage")
-    out = r.recv_exact(35)
-    assert bytes(out) == b"payload under transient read damage"
-    assert [k for _, k, _, _ in plan.log] == ["flip"]
-    assert ev.counts.get("integrity.detected") == 1
-    assert ev.counts.get("integrity.retry") == 1  # one re-read sufficed
-    assert ev.counts.get("integrity.recovered") == 1
-    w.close()
-    r.close()
-
-
-def test_shm_link_doorbell_swallow_is_absorbed(tmp_path):
-    from rabit_tpu.chaos import parse_plan
-
-    plan = parse_plan("13:doorbell@shm=1.0*1", identity="1")
-    w, r = _shm_pair(tmp_path, plan_w=plan)
-    t0 = time.monotonic()
-    w.sendall(b"wakeup-less")
-    out = r.recv_exact(11)
-    assert bytes(out) == b"wakeup-less"
-    assert time.monotonic() - t0 < 5  # bounded poll, not the timeout
-    assert [k for _, k, _, _ in plan.log] == ["doorbell"]
+    assert not ev.counts.get("integrity.detected")
     w.close()
     r.close()
 
@@ -352,54 +257,120 @@ def test_wait_readable_writable_poll_semantics():
         wait_readable_writable([a], [], 0.01)
 
 
-def test_accept_refuses_degenerate_rings(tmp_path):
-    """A dialer (version skew / corrupt offer) shipping rings below the
-    floor must be refused at attach: both sides land on tcp instead of
-    a ring that can stall every send to the link timeout."""
-    from rabit_tpu.tracker import protocol as P
-    from rabit_tpu.transport.base import TransportConfig
-    from rabit_tpu.transport.factory import LinkFactory
-    from rabit_tpu.transport.shm import ShmRing
+@pytest.mark.parametrize("case", ["expired", "rx_pending", "paced",
+                                  "idle"])
+def test_pump_wait(case):
+    """What is left of the pump's wait: the deadline, ``rx_pending``,
+    one poll, and the bounded slice for a paced-out link (whose socket
+    the kernel calls writable while its token bucket says wait)."""
+    from rabit_tpu.transport import pump
+    from rabit_tpu.transport.base import LinkError, LinkPacer
+    from rabit_tpu.transport.tcp import TcpLink
 
     a, b = socket.socketpair()
-    lf = LinkFactory(TransportConfig(transport="shm"), timeout=5.0)
-    lf.set_topology(0, [0, 0])
-    tiny_tx, p1 = ShmRing.create(str(tmp_path), 16)
-    tiny_rx, p2 = ShmRing.create(str(tmp_path), 16)
-    answers = []
+    now = time.monotonic()
+    try:
+        if case == "expired":
+            link = TcpLink(a, 1, 5.0)
+            with pytest.raises(LinkError, match="timed out"):
+                pump._wait([link], [], now - 1.0, "x: timed out")
+        elif case == "rx_pending":
+            # verified plaintext already staged: no poll, no sleep
+            from rabit_tpu.transport.framing import encode_frames
 
-    def dialer():
-        P.send_str(a, p1)
-        P.send_str(a, p2)
-        answers.append(P.recv_u32(a))
+            link = TcpLink(a, 1, 5.0, frames=True)
+            b.sendall(b"".join(bytes(p) for p in
+                               encode_frames([memoryview(b"abcdefgh")])))
+            link.pump_begin()
+            got = bytearray(4)
+            assert link.poll_recv(memoryview(got)) == 4
+            assert link.rx_pending()
+            pump._wait([link], [], now + 30.0, "x: timed out")
+            assert time.monotonic() - now < 5.0
+            link.pump_end()
+        elif case == "paced":
+            pacer = LinkPacer(1.0)
+            pacer.debit(10 << 20)           # deep in deficit
+            link = TcpLink(a, 1, 5.0, pacer=pacer)
+            assert link.needs_poll()
+            pump._wait([], [link], now + 30.0, "x: timed out")
+            took = time.monotonic() - now
+            assert pump.WAIT_SLICE_SEC <= took < 5.0   # a slice, no raise
+        else:
+            # nothing readable for the whole idle budget: a typed error
+            link = TcpLink(a, 1, 5.0)
+            with pytest.raises(LinkError, match="timed out"):
+                pump._wait([link], [], now + 0.05, "x: timed out")
+    finally:
+        a.close()
+        b.close()
 
-    t = threading.Thread(target=dialer)
+
+def _tcp_pair():
+    """A connected loopback TCP pair (the link hello sets TCP_NODELAY,
+    which a unix socketpair refuses)."""
+    with socket.socket() as lst:
+        lst.bind(("127.0.0.1", 0))
+        lst.listen(1)
+        a = socket.create_connection(lst.getsockname(), timeout=5.0)
+        b, _addr = lst.accept()
+    return a, b
+
+
+def _legacy_peer(sock, rank, offer, got):
+    """What a pre-PR-28 peer that still offers shm rings puts on the
+    wire, as acceptor or dialer: XMAGIC, its rank, its feature string.
+    It enters the ring exchange only when BOTH sides offered ``shm``,
+    which this release never does — so after the hello it speaks plain
+    (or framed) TCP."""
+    from rabit_tpu.tracker import protocol as P
+    from rabit_tpu.transport.factory import XMAGIC
+
+    P.send_u32(sock, XMAGIC)
+    P.send_u32(sock, rank)
+    P.send_str(sock, offer)
+    got.append((P.recv_u32(sock), P.recv_u32(sock),
+                P.recv_str(sock, max_len=256)))
+
+
+@pytest.mark.parametrize("side", ["accept", "dial"])
+@pytest.mark.parametrize("offer,framed", [("crc32c,shm:1048576", True),
+                                          ("shm:1048576", False)])
+def test_legacy_shm_offer_yields_tcp_link(side, offer, framed):
+    """Wire compatibility with the previous release: the ``shm:<bytes>``
+    token of an older peer's offer is ignored like any unknown token —
+    with ``crc32c`` beside it the link comes up framed TCP, alone it
+    comes up classic TCP — and this side's own offer is the parent's
+    integrity-only string."""
+    from rabit_tpu.transport.base import TransportConfig
+    from rabit_tpu.transport.factory import XMAGIC, LinkFactory
+
+    a, b = _tcp_pair()
+    lf = LinkFactory(TransportConfig(integrity="crc32c"), timeout=5.0)
+    lf.rank = 0
+    got = []
+    t = threading.Thread(target=_legacy_peer, args=(b, 1, offer, got))
     t.start()
-    link = lf._accept_shm(b, 1, frames=False)
+    if side == "accept":
+        link, peer = lf.accept(a)
+        assert peer == 1
+    else:
+        link = lf.dial(a, 1)
     t.join(timeout=10)
-    assert link is None             # caller falls through to _tcp_link
-    assert answers == [0]           # dialer told to stay tcp too
-    tiny_tx.close()
-    tiny_rx.close()
-    a.close()
-    b.close()
-
-
-def test_dial_rejects_tiny_negotiated_ring():
-    """A negotiated ring size below the floor (skewed peer offer) takes
-    the documented dialer-abort path, keeping the handshake protocol in
-    sync — the acceptor reads the empty-path abort and stays tcp."""
-    from rabit_tpu.tracker import protocol as P
-    from rabit_tpu.transport.base import TransportConfig
-    from rabit_tpu.transport.factory import LinkFactory
-
-    a, b = socket.socketpair()
-    lf = LinkFactory(TransportConfig(transport="shm"), timeout=5.0)
-    lf.set_topology(0, [0, 0])
-    link = lf._dial_shm(a, 1, {"shm": 16}, frames=False)
-    assert link is None             # caller falls through to _tcp_link
-    assert P.recv_str(b, max_len=4096) == ""   # the protocol abort
-    a.close()
+    assert got == [(XMAGIC, 0, "crc32c")]
+    assert link.kind == "tcp" and link.peer == 1
+    assert bool(link._frames) == framed
+    # and the bytes that follow are what that negotiated: a frame
+    # (u32 length | payload | u32 crc) or the bare payload
+    link.sendall(b"ping")
+    b.settimeout(5.0)
+    wire = b.recv(64)
+    if framed:
+        assert len(wire) == 12 and wire[4:8] == b"ping"
+        assert struct.unpack("<I", wire[:4]) == (4,)
+    else:
+        assert wire == b"ping"
+    link.close()
     b.close()
 
 
@@ -492,7 +463,6 @@ def _link_snapshot(eng):
     # mixed-version world looks like once negotiation is in play)
     ({"rabit_wire_integrity": "crc32c"}, {}),
     ({}, {"rabit_wire_integrity": "crc32c"}),
-    ({"rabit_transport": "shm"}, {}),
 ])
 def test_negotiation_degrades_to_common_subset(side_a, side_b):
     snaps = {}
@@ -507,7 +477,7 @@ def test_negotiation_degrades_to_common_subset(side_a, side_b):
 
 
 def test_negotiation_activates_in_intersection():
-    feats = {"rabit_transport": "shm", "rabit_wire_integrity": "crc32c"}
+    feats = {"rabit_wire_integrity": "crc32c"}
     snaps = {}
 
     def body(eng, rank):
@@ -516,86 +486,32 @@ def test_negotiation_activates_in_intersection():
     engines = _run_world(2, {0: dict(feats), 1: dict(feats)}, body)
     for rank, links in snaps.items():
         ((_peer, kind, framed),) = links
-        assert kind == "shm" and framed, (rank, links)
+        assert kind == "tcp" and framed, (rank, links)
     for eng in engines:
-        assert eng.stats()["counters"].get("transport.links.shm") == 1
-
-
-def test_cross_group_peers_stay_tcp(monkeypatch):
-    """transport=auto upgrades only same-host-group links: a simulated
-    two-host world 4 keeps every cross-group link on tcp."""
-    monkeypatch.setenv("RABIT_TRACKER_GROUPS", "0,0,1,1")
-    snaps = {}
-    groups = {}
-
-    def body(eng, rank):
-        snaps[rank] = _link_snapshot(eng)
-        groups[rank] = list(eng._groups)
-        _allreduce_ok(eng, rank)
-    _run_world(4, {i: {"rabit_transport": "auto"} for i in range(4)},
-               body)
-    checked = 0
-    for rank, links in snaps.items():
-        for peer, kind, _framed in links:
-            same = groups[rank][rank] == groups[rank][peer]
-            assert (kind == "shm") == same, (rank, peer, kind)
-            checked += 1
-    assert checked  # the handout actually wired links
-
-
-def test_shm_failover_to_tcp_mid_job():
-    """A torn ring write mid-job: detected, typed, the link re-dialed
-    as TCP through the recover rendezvous — op results stay exact and
-    the failover is on the counters."""
-    feats = {"rabit_transport": "shm", "rabit_wire_integrity": "crc32c",
-             "rabit_timeout_sec": 15}
-    final = {}
-
-    obs_label = {}
-
-    def body(eng, rank):
-        for _ in range(4):
-            _allreduce_ok(eng, rank)
-        final[rank] = _link_snapshot(eng)
-        obs_label[rank] = eng._obs_transport
-    params = {0: dict(feats), 1: dict(feats)}
-    params[1]["rabit_chaos"] = "31:torn@shm=1.0*1"
-    engines = _run_world(2, params, body, engine="pyrobust")
-    failovers = sum(
-        e.stats()["counters"].get("transport.failover.shm_to_tcp", 0)
-        for e in engines)
-    detected = sum(e.stats()["counters"].get("integrity.detected", 0)
-                   for e in engines)
-    assert failovers >= 1 and detected >= 1
-    for rank, links in final.items():
-        ((_peer, kind, _framed),) = links
-        assert kind == "tcp", f"rank {rank} never failed over to tcp"
-        # The obs-streamed wire label degrades with the links: the
-        # controller must not file tcp-measured verdicts under @shm.
-        assert obs_label[rank] == "tcp", (rank, obs_label)
+        assert eng.stats()["counters"].get("transport.links.tcp") == 1
 
 
 # --------------------------------------------------- end-to-end matrix
 @pytest.mark.parametrize("world", [2, 4, 5])
-@pytest.mark.parametrize("sched", ["tree", "ring", "halving", "hier"])
-def test_parity_matrix_shm(world, sched):
-    """Transport parity: every schedule over a full-shm same-host world
-    serves the zero/1/odd-size exact-arithmetic ladder bit-correctly
-    (sched_parity self-verifies; inapplicable schedules must fall back,
-    not die)."""
+@pytest.mark.parametrize("sched", ["tree", "ring", "halving", "hier",
+                                   "swing", "static"])
+def test_parity_matrix_framed(world, sched):
+    """Link parity: every schedule over a world whose every link is
+    integrity-framed serves the zero/1/odd-size exact-arithmetic ladder
+    bit-correctly (sched_parity self-verifies; inapplicable schedules
+    must fall back, not die)."""
     assert _launch("sched_parity", world,
                    {"RABIT_ENGINE": "pysocket", "RABIT_SCHED": sched,
-                    "RABIT_TRANSPORT": "shm",
+                    "RABIT_WIRE_INTEGRITY": "crc32c",
                     "RABIT_REDUCE_BUFFER": "4KB"}) == 0
 
 
 @pytest.mark.parametrize("world,groups", [(4, "0,0,1,1"),
                                           (5, "0,0,0,1,1")])
 def test_parity_matrix_mixed_transport(world, groups):
-    """Mixed same-host/cross-host worlds: shm intra-group, tcp
-    cross-group, hier exercising both in one op — plus integrity
-    framing on every link."""
-    env = {"RABIT_ENGINE": "pysocket", "RABIT_TRANSPORT": "auto",
+    """Two simulated hosts: hier runs its intra-group and cross-group
+    phases in one op, with integrity framing on every link."""
+    env = {"RABIT_ENGINE": "pysocket",
            "RABIT_WIRE_INTEGRITY": "crc32c",
            "RABIT_TRACKER_GROUPS": groups}
     for sched in ("static", "hier"):
@@ -603,13 +519,12 @@ def test_parity_matrix_mixed_transport(world, groups):
                        {**env, "RABIT_SCHED": sched}) == 0
 
 
-def test_kill_point_replay_over_shm():
-    """The flagship two-deaths replay scenario with the whole data
-    plane on shm rings + integrity framing: cache/replay recovery must
-    serve bit-identical results across the restarts."""
+def test_kill_point_replay_over_framed():
+    """The flagship two-deaths replay scenario with integrity framing
+    on every link: cache/replay recovery must serve bit-identical
+    results across the restarts."""
     assert _launch("model_recover", 4,
                    {"RABIT_ENGINE": "pyrobust",
-                    "RABIT_TRANSPORT": "shm",
                     "RABIT_WIRE_INTEGRITY": "crc32c",
                     "RABIT_MOCK": "0,0,1,0;1,1,1,0",
                     "RABIT_TIMEOUT_SEC": "15"},
@@ -666,20 +581,3 @@ def test_transport_module_hygiene():
                     and node.func.id == "print"):
                 offenders.append(f"{path.name}:{node.lineno} raw print")
     assert not offenders, offenders
-
-
-# ------------------------------------------------------------ soak gate
-@pytest.mark.slow
-def test_transport_soak_gate():
-    """The randomized shm gate: seeded torn/flip corruption over shm
-    rings with integrity framing — zero silent corruption (bit-exact
-    final vs a tcp reference), live shm→tcp failover visible on the
-    counters and timeline — composed with the full --chaos wire mix."""
-    from rabit_tpu.tools.soak import main as soak_main
-
-    assert soak_main(["--transport", "shm", "--world", "4",
-                      "--rounds", "1", "--ndata", "3000",
-                      "--niter", "4"]) == 0
-    assert soak_main(["--transport", "shm", "--chaos", "--world", "4",
-                      "--rounds", "1", "--ndata", "3000",
-                      "--niter", "4", "--seed", "5"]) == 0
