@@ -214,11 +214,13 @@ class _HostShard:
 
     def level(self, build):
         """The histograms of the level slots ``build`` names (-1: none),
-        and which."""
+        which, and the kernel calls that took."""
         order = [s for s in build if s >= 0]
+        calls = histogram.level_calls(len(order), self.bins.shape[1],
+                                      self.nslot, self.kw["use_pallas"])
         return histogram.build_level_local(
             self.bins, self.grad, self.hess, self.node, order, self.nslot,
-            **self.kw), order
+            **self.kw), order, calls
 
     def partition(self, tab: np.ndarray) -> None:
         node = self.node
@@ -290,8 +292,9 @@ class _DeviceShard:
         """The job's programs, compiled for its shapes: ``grad``,
         ``level`` (by its number of build slots: 1 at the root, then one
         a node of the level above; an empty slot holds no row, so a tree
-        that stops early runs the same programs), ``partition`` and
-        ``leaf``."""
+        that stops early runs the same programs; one of more slots than
+        ``histogram.slots_per_call`` holds several kernel calls),
+        ``partition`` and ``leaf``."""
         import jax
         import jax.numpy as jnp
 
@@ -388,9 +391,11 @@ class _DeviceShard:
         self.gh = self.prog["grad"](self.margin, self.labels, *keep)
 
     def level(self, build):
+        calls = histogram.level_calls(len(build), self.bins_t.shape[0],
+                                      self.nslot, self.use_pallas)
         return (self.prog["level"][len(build)](
             self.bins_t, self.gh, self.node, np.asarray(build, np.int32)),
-            build)
+            build, calls)
 
     def partition(self, tab: np.ndarray) -> None:
         tab = np.concatenate(
@@ -564,7 +569,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                     # XGBoost wire pattern, batched); every rank holds
                     # the same reduced histograms, so builds the same
                     with program.span("learn.dispatch"):
-                        local, order = shard.level(build)
+                        local, order, calls = shard.level(build)
                     built = _reduce_level(local)
                     with program.span("gbdt.split"):
                         hists = {}
@@ -589,6 +594,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                         shard.partition(tab)
                 live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
+                program.count("gbdt.levels_chunked", int(calls > 1))
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
                 program.count("gbdt.hists_derived", len(hists) - live)

@@ -188,6 +188,24 @@ def _level_xla(bins_t, gh, node, nslots: int, nbin: int, block: int = 4096):
     return acc
 
 
+def slots_per_call(nbin: int, f: int) -> int:
+    """Level slots one kernel call of :func:`level_hist` builds: half
+    the kernel's widest worthwhile call (a slot is a grad and a hess
+    channel)."""
+    from rabit_tpu.ops.histogram_kernel import max_channels
+
+    return max(1, max_channels(nbin, f) // 2)
+
+
+def level_calls(nslots: int, f: int, nbin: int,
+                use_pallas: bool | None = None) -> int:
+    """Kernel calls :func:`level_hist` makes for a level of ``nslots``:
+    none on the XLA path."""
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    return -(-nslots // slots_per_call(nbin, f)) if use_pallas else 0
+
+
 def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
                use_pallas: bool | None = None, compute_dtype=None):
     """``(nslots, f, nbin, 2)`` histograms of one tree level, traceable:
@@ -196,16 +214,31 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     is in no histogram.  ``bins_t`` is the staged ``(fpad, n)`` array,
     ``gh`` the ``(2, n)`` float32 weights.  The fused kernel folds the
     node masks into the weights a row block at a time (12 bytes a row
-    read, no ``(2 * nslots, n)`` matrix in HBM)."""
+    read, no ``(2 * nslots, n)`` matrix in HBM).
+
+    A level of more slots than :func:`slots_per_call` is built by
+    several kernel calls inside the same program, call ``k`` over the
+    slots from ``k * slots_per_call`` on (a row of another call's slots
+    matches none of this one's), and their results joined: a channel
+    sees the same rows in the same order either way, so the histograms
+    are those of one wide call bit for bit, at the kernel's time a
+    channel of a narrow one."""
     if use_pallas is None:
         use_pallas = on_tpu()
     if not use_pallas:
         return _level_xla(bins_t, gh, node, nslots, nbin)[:, :f]
+    import jax.numpy as jnp
+
     from rabit_tpu.ops import histogram_kernel as hk
 
     kw = {} if compute_dtype is None else {"compute_dtype": compute_dtype}
-    out = hk.hist_fused_multi(bins_t, gh, nbin, node_of_row=node,
-                              nslots=nslots, **kw)   # (2 * nslots, fpad, nbin)
+    per_call = slots_per_call(nbin, bins_t.shape[0])
+    outs = [hk.hist_fused_multi(bins_t, gh, nbin,
+                                node_of_row=node - lo if lo else node,
+                                nslots=min(per_call, nslots - lo), **kw)
+            for lo in range(0, nslots, per_call)]
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    # (2 * nslots, fpad, nbin), slot-major
     return out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
 
 
@@ -361,8 +394,8 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
     doc/benchmarks.md):
     :func:`rabit_tpu.ops.histogram_kernel.hist_fused_multi`, which
     folds the node masks into the grad/hess channels itself (no (2m, n)
-    weight matrix), chunked when a level exceeds the kernel's channel
-    budget.
+    weight matrix), in as many kernel calls as :func:`level_hist`
+    makes of a level that wide.
     ``bins_t`` optionally supplies the resident transposed (f, n)
     device array so the transpose isn't redone per level.  Off-TPU,
     falls back to the XLA builder per node.
@@ -376,24 +409,16 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
     h = jnp.asarray(hess)
     m = len(node_ids)
     if use_pallas:
-        from rabit_tpu.ops import histogram_kernel as hk
         if bins_t is None:
             bins_t = jnp.asarray(bins).T
-        f = bins_t.shape[0]
         # node id -> position in node_ids, -1 for a row of another node
         ids = np.asarray(node_ids, np.int64)
         lut = np.full(int(ids.max(initial=0)) + 2, -1, np.int32)
         lut[ids] = np.arange(m, dtype=np.int32)
         slot = jnp.asarray(lut)[jnp.clip(nor, -1, len(lut) - 1)]
         gh = jnp.stack([g, h]).astype(jnp.float32)
-        # chunk derived from the kernel's VMEM accumulator budget (2
-        # channels per node: grad + hess), not a fixed constant — wide
-        # features shrink it so deep levels still compile
-        chunk = max(1, hk.max_channels(nbin, f) // 2)
-        outs = [level_hist(bins_t, gh, slot - lo, min(chunk, m - lo), f,
-                           nbin, use_pallas=True, compute_dtype=compute_dtype)
-                for lo in range(0, m, chunk)]
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+        return level_hist(bins_t, gh, slot, m, bins_t.shape[0], nbin,
+                          use_pallas=True, compute_dtype=compute_dtype)
     g_np, h_np, nor_np = np.asarray(g), np.asarray(h), np.asarray(nor)
     parts = [build_local(bins, g_np * (nor_np == v), h_np * (nor_np == v),
                          nbin, use_pallas=False)

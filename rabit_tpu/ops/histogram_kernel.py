@@ -59,6 +59,14 @@ from rabit_tpu.ops import on_tpu
 _VMEM_LIMIT_BYTES = 100 << 20
 _DEFAULT_BLOCK = 2048
 _MAX_CHANNELS = 64
+# The widest call that stays on the kernel's own line.  Measured on a
+# v5e at 32 x 33.6M rows, 256 bins (builders' chip runs, PR 27 and
+# PR 30): a call costs 10.3 ms + 22.45 ms a channel at 2, 4, 8, 16, 20,
+# 24 and 28 channels (0.3698 s at 16) and leaves that line at 32
+# (0.9444 s where two calls of 16 cost 0.7397 s) and 64 (1.6315 s, four
+# of 16 1.4794 s).  A tree level's channels are a power of two, so 16
+# splits every wider level into equal calls of one shape.
+_LINE_CHANNELS = 16
 
 
 def _round_up(v: int, m: int) -> int:
@@ -73,14 +81,16 @@ def _next_pow2(v: int) -> int:
 
 
 def max_channels(nbin: int, f: int) -> int:
-    """Largest weight-channel count whose (ngroups, nw, fpg*hi, fpg*lo)
-    f32 VMEM accumulator fits the kernel's budget for this shape —
-    level builders derive their chunk size from this instead of a fixed
-    constant, so wide-feature deep levels chunk harder rather than
-    failing the accumulator bound."""
+    """The widest call worth issuing for this shape, in weight channels:
+    the smaller of what the (ngroups, nw, fpg*hi, fpg*lo) f32 VMEM
+    accumulator's budget holds and the width up to which a call's time
+    is linear in its channels (``_LINE_CHANNELS``: a 32-channel call
+    costs 0.2 s more than two of 16).  ``learn.histogram.level_hist``
+    builds a wider level in calls of this width, so wide-feature deep
+    levels chunk harder rather than failing the accumulator bound."""
     hi, lo, fpg, ngroups = plan(nbin, f)
     per_channel = ngroups * fpg * hi * fpg * lo * 4
-    return max(1, min(_MAX_CHANNELS,
+    return max(1, min(_LINE_CHANNELS,
                       (_VMEM_LIMIT_BYTES // 2) // per_channel))
 
 

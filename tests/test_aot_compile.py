@@ -82,11 +82,10 @@ def _hist_level(topo):
 def _hist_level_staged(nslots, topo):
     from rabit_tpu.ops.histogram_kernel import hist_fused_multi
 
-    # a level of the benchmark cell: node slots x (grad, hess) folded in
-    # inside the kernel (16 is its widest since a level builds one child
-    # of every split node; 32 is every node of depth 5), 28 features
-    # staged as (32, n) int32, 256 bins, one chip's 33.6M rows; the bins
-    # are not copied (the temporaries are the bf16 grad and hess)
+    # the widest direct call, 64 channels: node slots x (grad, hess)
+    # folded in inside the kernel, 28 features staged as (32, n) int32,
+    # 256 bins, one chip's 33.6M rows; the bins are not copied (the
+    # temporaries are the bf16 grad and hess)
     n = 32 << 20
     fn = jax.jit(functools.partial(hist_fused_multi, nbin=256, nslots=nslots,
                                    interpret=False))
@@ -94,6 +93,25 @@ def _hist_level_staged(nslots, topo):
                                  ((2, n), jnp.float32), ((n,), jnp.int32))
     compiled = fn.lower(bins, gh, node_of_row=node).compile()
     assert compiled.memory_analysis().temp_size_in_bytes <= n * 2 * 2 + (1 << 20)
+    return compiled
+
+
+def _hist_level_chunked(nslots, topo):
+    from rabit_tpu.learn import histogram
+
+    # the benchmark cell's widest level as level_hist builds it (16
+    # slots since a level builds one child of every split node): two
+    # calls of 8 slots in one program; beside the bf16 grad and hess the
+    # second call's slot codes are the one temporary of a row's length
+    n = 32 << 20
+    fn = jax.jit(lambda bins, gh, node: histogram.level_hist(
+        bins, gh, node, nslots, 28, 256, use_pallas=True))
+    compiled = fn.lower(*_one_chip(topo, ((32, n), jnp.int32),
+                                   ((2, n), jnp.float32),
+                                   ((n,), jnp.int32))).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= nslots // 8
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= n * 2 * 2 + n * 4 + (2 << 20))
     return compiled
 
 
@@ -176,7 +194,7 @@ def _ring(nbytes, topo):
 
 @pytest.mark.parametrize("build", [
     _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
-    functools.partial(_hist_level_staged, 16), _kmeans_ell_chain,
+    functools.partial(_hist_level_chunked, 16), _kmeans_ell_chain,
     _dense16_loop,
     _mesh_kmeans_step,
     # latency-sized, one VMEM segment, and past the segmentation

@@ -366,9 +366,9 @@ def _spy_on_loop(monkeypatch):
 
     def seen_level(level):
         def wrapper(self, build):
-            local, order = level(self, build)
+            local, order, calls = level(self, build)
             levels.append((list(build), np.asarray(local), list(order)))
-            return local, order
+            return local, order, calls
         return wrapper
 
     monkeypatch.setattr(boosting, "_split", seen_split)
@@ -530,6 +530,46 @@ def test_counters_of_one_full_depth_3_round(arm, which):
     assert [after.get(k, 0) - before.get(k, 0) for k in (
         "gbdt.hists_derived", "gbdt.channels", "gbdt.channels_live",
         "gbdt.nodes_split")] == [3, 8, 8, 7]
+
+
+@pytest.mark.parametrize("which,use_pallas,chunked", [
+    ("device", True, 1), ("device", False, 0), ("host", False, 0)],
+    ids=["device-kernel", "device-xla", "host-xla"])
+def test_chunked_levels_of_one_depth_6_round(arm, which, use_pallas,
+                                                  chunked):
+    """Of a depth-6 tree's six levels the last alone (16 build slots,
+    32 channels) is wider than the kernel's widest worthwhile call, and
+    only an arm that runs the kernel issues calls at all."""
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=2000)
+    arm(which)
+    before = program.stats()
+    model = boosting.train(X, y, num_round=1, max_depth=6, nbin=16,
+                           use_pallas=use_pallas, compute_dtype="float32")
+    assert len(model.trees[0]) > 63                    # reaches depth 6
+    after = program.stats()
+    assert [after.get(k, 0) - before.get(k, 0) for k in (
+        "gbdt.levels", "gbdt.levels_chunked")] == [6, chunked]
+
+
+def test_device_arm_trains_a_forest_of_levels_wider_than_a_kernel_call(arm):
+    """``max_depth`` 8: the deepest level builds 64 slots, 128 channels,
+    eight kernel calls in one program (with one call a level the device
+    arm's programs could not be built: the kernel takes 64 channels).
+    Both arms run the kernel and grow the same forest."""
+    X, y = _tabular()
+    kw = dict(num_round=2, max_depth=8, nbin=16, use_pallas=True,
+              compute_dtype="float32")
+    models = []
+    for which in ("host", "device"):
+        arm(which)
+        models.append(boosting.train(X, y, **kw))
+    host, device = models
+    assert max(len(t) for t in device.trees) > 255     # deeper than 7
+    assert _structure(host) == _structure(device)
+    np.testing.assert_allclose(_weights(device), _weights(host),
+                               rtol=1e-4, atol=1e-5)
 
 
 def test_built_child_is_the_lighter_and_the_same_on_every_rank(tmp_path):

@@ -106,8 +106,8 @@ def test_build_level_local_pallas_matches_fallback():
 
 
 def test_build_level_chunks_past_channel_budget():
-    # 40 nodes -> 80 weight channels > the kernel's 64-channel budget:
-    # the level builder must chunk and concatenate
+    # 40 nodes -> 80 weight channels, past the widest call the kernel
+    # module asks for (16): level_hist builds them in five calls
     rng = np.random.default_rng(7)
     n, f, nbin, m = 300, 2, 8, 40
     bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
@@ -242,3 +242,144 @@ def test_split_gain_of_a_node_of_millions_of_rows():
     gain_m, _left = histogram.split_gain_missing(
         np.concatenate([hist, hist[:, :1]], axis=1), 1.0)
     assert np.isfinite(gain_m).all()
+
+
+# ----------------------------------------------------------------------
+# a level wider than the kernel's widest worthwhile call is built by
+# several calls inside level_hist
+# ----------------------------------------------------------------------
+def _level_case(nslots, n=2500, f=3, nbin=8, seed=21):
+    """Rows in no slot (-1, and slots beyond the level), two slots with
+    no row, and a row count that is no multiple of the kernel's block
+    (2048 here: two blocks, the second padded)."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed + nslots)
+    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
+    gh = np.stack([rng.standard_normal(n), rng.random(n)]).astype(np.float32)
+    node = rng.integers(-1, nslots + 2, n).astype(np.int32)
+    empty = sorted({nslots // 2, nslots - 1})
+    node[np.isin(node, empty)] = -1
+    fpad = histogram.staged_features(f, nbin)
+    bins_t = jnp.zeros((fpad, n), jnp.int32).at[:f].set(bins.T)
+    return bins_t, jnp.asarray(gh), jnp.asarray(node), empty
+
+
+def _pallas_calls(jaxpr) -> int:
+    """``pallas_call`` equations in a jaxpr, nested programs included."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == "pallas_call"
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                total += _pallas_calls(inner)
+    return total
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("nslots", [1, 8, 9, 16, 17, 32, 64])
+def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype):
+    """The calls of a chunked level give every channel the rows of the
+    one wide call in the same order: equal bit for bit (the direct call
+    takes at most 64 channels, so a 64-slot level is held against two),
+    and equal to the float32 XLA level to the operand's rounding."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    f, nbin = 3, 8
+    bins_t, gh, node, empty = _level_case(nslots)
+    got = np.asarray(histogram.level_hist(
+        bins_t, gh, node, nslots, f, nbin, use_pallas=True,
+        compute_dtype=dtype))
+    assert got.shape == (nslots, f, nbin, 2)
+    direct = jnp.concatenate([
+        hk.hist_fused_multi(bins_t, gh, nbin, node_of_row=node - lo,
+                            nslots=min(32, nslots - lo), compute_dtype=dtype)
+        for lo in range(0, nslots, 32)])
+    direct = np.asarray(direct.reshape(nslots, 2, -1, nbin)
+                        .transpose(0, 2, 3, 1)[:, :f])
+    np.testing.assert_array_equal(got, direct)
+    for s in empty:
+        assert not got[s].any()
+    want = np.asarray(histogram.level_hist(bins_t, gh, node, nslots, f, nbin,
+                                           use_pallas=False))
+    # a bin's sum of bf16-rounded weights is off by 2^-9 of its sum of |w|
+    room = 2.0 ** -8 if dtype == "bfloat16" else 1e-5
+    mass = np.asarray(histogram.level_hist(
+        bins_t, jnp.abs(gh), node, nslots, f, nbin, use_pallas=False))
+    assert (np.abs(got - want) <= room * mass + 1e-4).all()
+
+
+@pytest.mark.parametrize("nslots", [1, 8, 9, 16, 32, 64])
+def test_level_lowers_to_one_kernel_call_a_width(nslots):
+    """At or under the kernel's widest worthwhile call a level is one
+    ``pallas_call``; a wider one is ceil(2 * nslots / width) of them in
+    the same program."""
+    import jax
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    f, nbin = 3, 8
+    bins_t, gh, node, _ = _level_case(nslots, n=256)
+    width = hk.max_channels(nbin, bins_t.shape[0])
+    assert width == 16
+    jaxpr = jax.make_jaxpr(lambda b, w, nd: histogram.level_hist(
+        b, w, nd, nslots, f, nbin, use_pallas=True))(bins_t, gh, node)
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert calls == -(-2 * nslots // width)
+    assert calls == histogram.level_calls(nslots, bins_t.shape[0], nbin,
+                                          use_pallas=True)
+    assert histogram.level_calls(nslots, f, nbin, use_pallas=False) == 0
+
+
+@pytest.mark.parametrize("nbin,f,want", [(256, 28, 16), (256, 32, 16),
+                                         (8, 3, 16), (256, 2000, 3)],
+                         ids=["cell", "cell-staged", "tiny", "wide"])
+def test_widest_call_is_the_smaller_of_the_line_and_the_vmem_bound(
+        nbin, f, want):
+    """Wide-feature shapes whose accumulator bound is under the line's
+    width keep that smaller bound; a direct caller may still ask the
+    kernel for its 64 channels."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    assert hk.max_channels(nbin, f) == want
+    assert histogram.slots_per_call(nbin, f) == max(1, want // 2)
+
+
+def test_direct_caller_still_gets_64_channels_and_no_more():
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    bins_t, gh, node, _ = _level_case(32, n=256)
+    out = hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=32)
+    assert out.shape[0] == 64
+    with pytest.raises(ValueError, match="out of range"):
+        hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=33)
+
+
+def test_build_level_local_takes_level_hists_chunks():
+    """A ``node_ids`` list longer than a call's slots, of ids in no
+    order: ``build_level_local`` is ``level_hist`` on the ids' places."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(33)
+    n, f, nbin = 900, 3, 8
+    ids = [int(v) for v in rng.permutation(60)[:21]]
+    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
+    grad = rng.standard_normal(n).astype(np.float32)
+    hess = rng.random(n).astype(np.float32)
+    node = rng.integers(-1, 60, n).astype(np.int32)
+    got = np.asarray(histogram.build_level_local(
+        bins, grad, hess, node, ids, nbin, use_pallas=True))
+    place = np.full(n, -1, np.int32)
+    for pos, v in enumerate(ids):
+        place[node == v] = pos
+    want = np.asarray(histogram.level_hist(
+        jnp.asarray(bins.T), jnp.stack([grad, hess]), jnp.asarray(place),
+        len(ids), f, nbin, use_pallas=True))
+    assert histogram.level_calls(len(ids), f, nbin, use_pallas=True) == 3
+    np.testing.assert_array_equal(got, want)
+    exact = np.asarray(histogram.build_level_local(
+        bins, grad, hess, node, ids, nbin, use_pallas=False))
+    np.testing.assert_allclose(got, exact, rtol=0, atol=0.05)
