@@ -21,7 +21,10 @@ One chip (the default, what the driver runs):
 * the sparse ``ell_fused`` tier through ``kmeans.run`` at d=512;
 * one GBDT level through ``histogram.build_level_local`` at 8 nodes x 64
   features x 256 bins x 262k rows, and a boosting round through
-  ``boosting.train``.
+  ``boosting.train``;
+* both sparse products of the wide linear path (``X w``, ``X^T g``)
+  through ``linear.stage_rows`` at 262k rows x 39 non-zeros over a
+  million weights.
 
 ``--chips 4`` (the builder runs it; four chips cost four times as much):
 
@@ -68,6 +71,7 @@ DENSE_ROWS_MAX = 23 << 20   # ~24M, tools/big_kmeans.py dense (12.3 GB)
 ELL_DIM = 512
 ELL_ROWS = 4 << 20          # 8 GB dense in f32: over the densify budget
 GBDT_ROWS, GBDT_FEATS, GBDT_BINS, GBDT_NODES = 1 << 18, 64, 256, 8
+LBFGS_ROWS, LBFGS_FEATS, LBFGS_NNZ = 1 << 18, 1_000_000, 39
 MESH_ROWS_PER_CHIP = 1 << 20
 ALLREDUCE_BYTES = (64 << 10, 4 << 20, 64 << 20)
 WORKER_PHASE_TIMEOUT_SEC = 300
@@ -403,6 +407,51 @@ def phase_gbdt(seed: int, rows: int) -> dict:
             "seconds": laps.seconds}
 
 
+def phase_lbfgs_products(seed: int, rows: int) -> dict:
+    """Both sparse products of the wide linear path (``X w`` with the
+    loss, ``X^T g``) through ``linear.stage_rows`` and its two programs,
+    against float64 numpy."""
+    import numpy as np
+
+    from rabit_tpu.learn import linear
+
+    laps = Laps()
+    rng = np.random.default_rng(seed + 3)
+    idx = rng.integers(0, LBFGS_FEATS, (rows, LBFGS_NNZ)).astype(np.int32)
+    idx[:, 0] %= 4096                     # hot cells, as hashed logs have
+    val = rng.standard_normal((rows, LBFGS_NNZ)).astype(np.float32) / 6
+    labels = (rng.random(rows) < 0.26).astype(np.float32)
+    w = (rng.standard_normal(LBFGS_FEATS) * 0.3).astype(np.float32)
+    laps.lap("generate")
+    shard = linear.stage_rows(idx, val, labels, LBFGS_FEATS)
+    margins, loss = shard.evaluate(w, np.float32(-0.5))
+    grad, gsum = (np.asarray(a) for a in shard.gradient(margins))
+    loss = float(np.asarray(loss).astype(np.float64).sum())
+    laps.lap("stage_and_products")
+    m = -0.5 + (val.astype(np.float64) * w[idx]).sum(axis=1)
+    want_loss = float(np.where(labels > 0, np.logaddexp(0, -m),
+                               np.logaddexp(0, m)).sum())
+    g = 1.0 / (1.0 + np.exp(-m)) - labels
+    want = np.bincount(idx.reshape(-1), (val * g[:, None]).reshape(-1),
+                       LBFGS_FEATS)
+    laps.lap("reference")
+    err_m = float(np.max(np.abs(np.asarray(margins)[:rows] - m)))
+    check(err_m < 1e-5, f"margins off by {err_m}")
+    # the chip's float32 exp and log1p against float64's: 8e-6 of the
+    # loss on a v5e (PR 31), and as much of g through the sigmoid
+    check(abs(loss - want_loss) < 5e-5 * want_loss,
+          f"loss {loss} against {want_loss}")
+    err_g = rel_err(grad, want)
+    check(err_g < 1e-4, f"X^T g off by {err_g} (relative)")
+    check(abs(float(gsum.sum()) - g.sum()) < 1e-3 * np.abs(g).sum(),
+          "bias gradient")
+    return {"rows": rows, "features": LBFGS_FEATS, "nnz_per_row": LBFGS_NNZ,
+            "slots": shard.nnz_padded, "nnz": shard.nnz,
+            "kernel": "lbfgs_margin, lbfgs_grad (Pallas)",
+            "margin_max_abs_err": err_m, "grad_rel_err": err_g,
+            "seconds": laps.seconds}
+
+
 # ----------------------------------------------------------------------
 # four chips
 # ----------------------------------------------------------------------
@@ -614,6 +663,8 @@ def main(argv: list[str] | None = None) -> int:
                   dense_rows(_dense16_budget(), host_bytes))
         run_phase("kmeans_ell", clock, phase_kmeans_ell, args.seed, ELL_ROWS)
         run_phase("gbdt_histogram", clock, phase_gbdt, args.seed, GBDT_ROWS)
+        run_phase("lbfgs_products", clock, phase_lbfgs_products, args.seed,
+                  LBFGS_ROWS)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}), flush=True)

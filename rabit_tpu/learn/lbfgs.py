@@ -8,12 +8,23 @@ shards, summed with one allreduce, lbfgs.h:244-249) so no rank ever
 materializes another rank's history; the final direction is assembled
 shard-locally and completed with an allreduce (lbfgs.h:283-296).
 
-TPU re-design: shard linear algebra (the Gram products and the direction
-assembly) is batched into single jitted matmuls over the (2m+1, nsub)
-history matrix instead of per-pair host loops — MXU work rather than
-pointer walks.  Cross-rank sums go through the framework allreduce; solver
-state is committed with the (global, local) checkpoint pair exactly like
-the reference (gstate global / history shard local, lbfgs.h:119,192).
+Where the work is: the objective's two passes over the data (``eval``
+and ``calc_grad``) are the device's; everything of the solver itself is
+float64 numpy on the host — the Gram products and the direction
+assembly are one matmul each over the (2m+1, nsub) history matrix
+instead of the reference's per-pair loops (0.9 GFLOP an iteration at a
+million weights and m = 10).  Cross-rank sums go through the framework
+allreduce; solver state is committed with the (global, local)
+checkpoint pair exactly like the reference (gstate global / history
+shard local, lbfgs.h:119,192).
+
+Spans (doc/observability.md "Program spans"): ``lbfgs.init`` at set-up;
+an outer iteration is ``learn.step`` ⊃ ``lbfgs.grad``, ``allreduce``,
+``lbfgs.direction`` (⊃ ``lbfgs.gram``, ``lbfgs.two_loop``,
+``lbfgs.assemble``), ``lbfgs.linesearch`` (⊃ one ``lbfgs.eval`` a
+trial) and ``commit``; the accepted trial's gradient is enqueued before
+the commit (``ObjFunction.start_grad``), so the step after finds it
+under way.
 """
 from __future__ import annotations
 
@@ -23,6 +34,7 @@ from typing import Optional
 import numpy as np
 
 import rabit_tpu
+from rabit_tpu.obs import program
 from rabit_tpu.ops import SUM
 from rabit_tpu.utils.checks import check
 
@@ -47,6 +59,12 @@ class ObjFunction(ABC):
     @abstractmethod
     def init_model(self, weight: np.ndarray) -> None: ...
 
+    def start_grad(self, weight: np.ndarray) -> None:
+        """Begin ``calc_grad(weight)``'s device work without waiting for
+        it, where an objective can.  The solver calls it on the accepted
+        iterate just before it commits; ``calc_grad`` of the same
+        weights then only has to fetch."""
+
     def save_state(self) -> object:
         return None
 
@@ -59,10 +77,11 @@ def _gram(hist: np.ndarray) -> np.ndarray:
 
     The two-loop recursion's curvature ratios need the full float64 the
     solver state carries; a device matmul would silently downcast to f32
-    without x64 mode, so this small (2m+1)² product stays on host.  The
+    without x64 mode, so this (2m+1)² product stays on host.  The
     FLOP-heavy work (the objective's eval/grad) is on device.
     """
-    return hist @ hist.T
+    with program.span("lbfgs.gram"):
+        return hist @ hist.T
 
 
 class LBFGSSolver:
@@ -101,6 +120,7 @@ class LBFGSSolver:
         self.dot_buf: np.ndarray | None = None  # (2m+1, 2m+1) float64
         self.range_begin = 0
         self.range_end = 0
+        self._work: dict[str, np.ndarray] = {}
 
     # ------------------------------------------------------------------
     def set_param(self, name: str, val: str) -> None:
@@ -150,10 +170,25 @@ class LBFGSSolver:
     def _shift(self) -> None:
         self.offset = (self.offset + 1) % self.size_memory
 
+    def _scratch(self, name: str, size: int) -> np.ndarray:
+        """A float64 work vector that lives as long as the solver: an
+        iteration at a million weights made some 25 temporaries of 8 MB,
+        which the allocator maps and faults in anew each time (PERF.md
+        section 6, PR 31).  Nothing committed or returned ever aliases
+        one."""
+        buf = self._work.get(name)
+        if buf is None or buf.shape[0] != size:
+            buf = self._work[name] = np.empty(size, np.float64)
+        return buf
+
     # ------------------------------------------------------------------
     def init(self) -> None:
         """Restore-or-initialize (reference: lbfgs.h:116-152)."""
         check(self.obj is not None, "LBFGSSolver.init: set an objective first")
+        with program.span("lbfgs.init"):
+            self._init()
+
+    def _init(self) -> None:
         version, gstate, hist = rabit_tpu.load_checkpoint(with_local=True)
         if version == 0:
             self.num_dim = self.obj.init_num_dim()
@@ -186,8 +221,8 @@ class LBFGSSolver:
                     % (self.num_dim, self.init_objval, self.size_memory))
         else:
             self._restore_local(hist)
-            if self.silent == 0 and rank == 0:
-                rabit_tpu.tracker_print("restart from version=%d" % version)
+            # the one line every learner prints on a resume
+            rabit_tpu.tracker_print("[%d] restart iter=%d" % (rank, version))
 
     # -- checkpoint payloads (reference: GlobalState/HistoryArray
     #    Load/Save, lbfgs.h:505-528,596-617) --------------------------------
@@ -233,21 +268,28 @@ class LBFGSSolver:
     # ------------------------------------------------------------------
     def update_one_iter(self) -> bool:
         """One outer iteration (reference: UpdateOneIter, lbfgs.h:166-194)."""
-        grad = self.obj.calc_grad(self.weight).astype(np.float64)
+        with program.span("learn.step", version=rabit_tpu.version_number() + 1):
+            return self._update_one_iter()
+
+    def _update_one_iter(self) -> bool:
+        with program.span("lbfgs.grad"):
+            grad = np.asarray(self.obj.calc_grad(self.weight), np.float64)
         # codec=False on every solver collective: the L-BFGS direction
         # math is precision-critical (curvature ratios of near-equal
         # dots), so these ops keep exact full-width bytes even when the
         # job arms a lossy wire codec for its bulk traffic
         # (doc/performance.md "Quantized wire codecs").
         grad = rabit_tpu.allreduce(grad, SUM, codec=False)
-        dir_, vdot = self._find_change_direction(grad)
+        with program.span("lbfgs.direction"):
+            dir_, vdot = self._find_change_direction(grad)
         if vdot >= -1e-15:
             # the (sub)gradient direction vanished: already at the optimum
             # (the reference asserts dotv<0, lbfgs.h:318; converging to an
             # exact stationary point is a stop, not an error, here)
             self.new_objval = self.old_objval
             return True
-        iters, new_weight = self._backtrack_line_search(dir_, vdot)
+        with program.span("lbfgs.linesearch"):
+            iters, new_weight = self._backtrack_line_search(dir_, vdot)
         check(iters < self.max_linesearch_iter, "line search failed")
         self.weight = new_weight
         if self.num_iteration > self.min_lbfgs_iter:
@@ -261,7 +303,15 @@ class LBFGSSolver:
                 % (self.num_iteration, iters, self.new_objval,
                    self.old_objval - self.new_objval))
         self.old_objval = self.new_objval
+        # the next iteration's gradient starts on the device now, so
+        # that the commit runs under it: the host's 0.2 s a commit of
+        # 176 MB, and its jitter, were a tenth of an iteration with
+        # nothing in flight (PERF.md section 6, PR 31)
+        if self.num_iteration < self.max_lbfgs_iter:
+            self.obj.start_grad(self.weight)
         rabit_tpu.checkpoint(self._global_payload(), self._local_payload())
+        program.count("learn.iterations")
+        program.count("learn.versions")
         return False
 
     def run(self) -> None:
@@ -288,13 +338,12 @@ class LBFGSSolver:
         lo, hi = self.range_begin, self.range_end
         nsub = hi - lo
         gsub = grad[lo:hi]
-        dir_ = np.zeros(self.num_dim, np.float64)
         if n != 0:
             # hist[m+n-1] holds the previous gradient shard → turn it into
             # the newest y-vector (lbfgs.h:231)
-            self.hist[self._map(m + n - 1)] = gsub - self._row(m + n - 1)
-            self.hist[self._map(2 * m)] = self._l1_dir(
-                gsub, self.weight[lo:hi])
+            newest = self._row(m + n - 1)
+            np.subtract(gsub, newest, out=newest)
+            self._l1_dir(gsub, self.weight[lo:hi], out=self._row(2 * m))
             # Gram products of all history rows in one matmul, then a
             # single allreduce of the needed entries
             # (reference computes 5n dots pairwise, lbfgs.h:233-249)
@@ -309,42 +358,34 @@ class LBFGSSolver:
             vals = rabit_tpu.allreduce(vals, SUM, codec=False)
             for (i, j), v in zip(idxset, vals):
                 self._set_dot(i, j, v)
-            # two-loop recursion in dot space (lbfgs.h:253-281)
-            alpha = np.zeros(n)
-            delta = np.zeros(2 * m + 1)
-            delta[2 * m] = 1.0
-            for j in range(n - 1, -1, -1):
-                vsum = sum(delta[k] * self._dot(k, j)
-                           for k in range(2 * m + 1))
-                alpha[j] = vsum / self._dot(j, m + j)
-                delta[m + j] -= alpha[j]
-            scale = (self._dot(n - 1, m + n - 1)
-                     / self._dot(m + n - 1, m + n - 1))
-            delta *= scale
-            for j in range(n):
-                vsum = sum(delta[k] * self._dot(k, m + j)
-                           for k in range(2 * m + 1))
-                beta = vsum / self._dot(j, m + j)
-                delta[j] += alpha[j] - beta
+            with program.span("lbfgs.two_loop"):
+                delta = self._two_loop(n)
             # assemble shard direction: one (2m+1)-row matvec
             # (reference: AddScale loop, lbfgs.h:283-291)
-            delta_phys = np.zeros(2 * m + 1)
-            for i in range(2 * m + 1):
-                delta_phys[self._map(i)] = delta[i]
-            dirsub = delta_phys @ self.hist
-            steep = self._row(2 * m)
-            if self.reg_L1 != 0.0:
-                dirsub = np.where(dirsub * steep <= 0.0, 0.0, dirsub)
-            vdot = -float(dirsub @ steep)
-            dir_[lo:hi] = dirsub
+            with program.span("lbfgs.assemble"):
+                delta_phys = np.zeros(2 * m + 1)
+                for i in range(2 * m + 1):
+                    delta_phys[self._map(i)] = delta[i]
+                dirsub = delta_phys @ self.hist
+                steep = self._row(2 * m)
+                if self.reg_L1 != 0.0:
+                    against = self._scratch("against", nsub)
+                    np.multiply(dirsub, steep, out=against)
+                    np.putmask(dirsub, against <= 0.0, 0.0)
+                vdot = -float(dirsub @ steep)
+                # the direction and its slope travel as one vector
+                both = self._scratch("direction", self.num_dim + 1)
+                both[:lo] = 0.0
+                both[lo:hi] = dirsub
+                both[hi:self.num_dim] = 0.0
+                both[self.num_dim] = vdot
             # The direction assembly is the big wire op of the
             # iteration (num_dim + 1 doubles): issue it async with
             # fuse=False (eager dispatch — a lone bucketed op would sit
             # unsent until wait()) and run the history-shift bookkeeping
             # below — pure local state — while it is in flight.
             both_handle = rabit_tpu.allreduce_async(
-                np.concatenate([dir_, [vdot]]), SUM, fuse=False,
-                codec=False)
+                both, SUM, fuse=False, codec=False)
         else:
             dir_ = self._l1_dir(grad, self.weight)
             vdot = -float(dir_ @ dir_)
@@ -363,6 +404,28 @@ class LBFGSSolver:
             dir_, vdot = both[:-1], float(both[-1])
         return dir_, vdot
 
+    def _two_loop(self, n: int) -> np.ndarray:
+        """The two-loop recursion in dot space (lbfgs.h:253-281): the
+        coefficients of the direction over the 2m+1 history rows."""
+        m = self.size_memory
+        alpha = np.zeros(n)
+        delta = np.zeros(2 * m + 1)
+        delta[2 * m] = 1.0
+        for j in range(n - 1, -1, -1):
+            vsum = sum(delta[k] * self._dot(k, j)
+                       for k in range(2 * m + 1))
+            alpha[j] = vsum / self._dot(j, m + j)
+            delta[m + j] -= alpha[j]
+        scale = (self._dot(n - 1, m + n - 1)
+                 / self._dot(m + n - 1, m + n - 1))
+        delta *= scale
+        for j in range(n):
+            vsum = sum(delta[k] * self._dot(k, m + j)
+                       for k in range(2 * m + 1))
+            beta = vsum / self._dot(j, m + j)
+            delta[j] += alpha[j] - beta
+        return delta
+
     def _backtrack_line_search(self, dir_: np.ndarray, vdot: float):
         """Armijo backtracking (reference: BacktrackLineSearch,
         lbfgs.h:314-350); first iteration uses a unit-norm step."""
@@ -379,33 +442,46 @@ class LBFGSSolver:
             iters += 1
             if iters >= self.max_linesearch_iter:
                 break
-            new_weight = self.weight + dir_ * alpha
+            # a trial point is a new array: a committed one is kept
+            new_weight = np.multiply(dir_, alpha)
+            np.add(self.weight, new_weight, out=new_weight)
             if self.reg_L1 != 0.0:
                 # OWL-QN: clamp sign flips (lbfgs.h:391-401)
-                new_weight = np.where(
-                    new_weight * self.weight < 0.0, 0.0, new_weight)
-            new_val = self._eval(new_weight)
+                flipped = self._scratch("product", self.num_dim)
+                np.multiply(new_weight, self.weight, out=flipped)
+                np.putmask(new_weight, flipped < 0.0, 0.0)
+            with program.span("lbfgs.eval"):
+                new_val = self._eval(new_weight)
+            program.count("lbfgs.evals")
             if new_val - self.old_objval <= c1 * vdot * alpha:
                 self.new_objval = new_val
                 break
             alpha *= backoff
         lo, hi = self.range_begin, self.range_end
-        self.hist[self._map(self.num_useful - 1)] = (
-            new_weight[lo:hi] - self.weight[lo:hi])
+        np.subtract(new_weight[lo:hi], self.weight[lo:hi],
+                    out=self._row(self.num_useful - 1))
         self.num_iteration += 1
         return iters, new_weight
 
-    def _l1_dir(self, grad: np.ndarray, weight: np.ndarray) -> np.ndarray:
+    def _l1_dir(self, grad: np.ndarray, weight: np.ndarray,
+                out: np.ndarray | None = None) -> np.ndarray:
         """Steepest descent with L1 subgradient (reference: SetL1Dir,
-        lbfgs.h:352-377)."""
+        lbfgs.h:352-377), into ``out`` where one is given."""
+        if out is None:
+            out = np.empty(grad.shape, np.float64)
+        np.negative(grad, out=out)
         if self.reg_L1 == 0.0:
-            return -grad
+            return out
         r = self.reg_L1
-        pos = -grad - r
-        neg = -grad + r
-        at_zero = np.where(grad < -r, pos, np.where(grad > r, neg, 0.0))
-        return np.where(weight > 0.0, pos,
-                        np.where(weight < 0.0, neg, at_zero))
+        # the penalty pushes down where the weight is positive, or is
+        # zero under a gradient below -r; up the other way; and holds a
+        # zero weight where it is the stronger
+        down = (weight > 0.0) | ((weight == 0.0) & (grad < -r))
+        up = (weight < 0.0) | ((weight == 0.0) & (grad > r))
+        np.subtract(out, r, out=out, where=down)
+        np.add(out, r, out=out, where=up)
+        np.putmask(out, ~(down | up), 0.0)
+        return out
 
     def _eval(self, weight: np.ndarray) -> float:
         """Global objective = allreduced data term + L1 (reference: Eval,
@@ -414,6 +490,7 @@ class LBFGSSolver:
         val = float(rabit_tpu.allreduce(np.array([val]), SUM,
                                         codec=False)[0])
         if self.reg_L1 != 0.0:
-            val += self.reg_L1 * float(np.abs(weight).sum())
+            val += self.reg_L1 * float(np.abs(
+                weight, out=self._scratch("product", len(weight))).sum())
         check(not np.isnan(val), "nan occurs")
         return val

@@ -3,13 +3,17 @@
 Equivalent of reference: rabit-learn/linear/{linear.h,linear.cc}.  The
 objective's Eval/CalcGrad — the FLOP-heavy part the reference spreads over
 OpenMP threads with per-row sparse loops (linear.cc:150-201) — are here
-single jitted XLA programs over the padded-ELL data: margins come from a
-gather + row-sum, gradients from a scatter-add, both fused by XLA.  Model
+two compiled programs over the shard, which stays on the device for the
+whole job (:class:`DeviceShard`): the margins ``X w`` with the loss summed
+a tile of rows, and the gradient ``X^T g``.  On the chip both products are
+the blocked one-hot kernels of ``rabit_tpu/ops/sparse_linear_kernel.py``;
+off it the same staged arrays go through XLA's gather and scatter.  Model
 files keep the reference's two on-disk encodings ("binf" binary and
 "bs64" base64 text for text-only channels, linear.cc:76-122).
 """
 from __future__ import annotations
 
+import functools
 import struct
 import sys
 from typing import BinaryIO
@@ -19,7 +23,9 @@ import numpy as np
 import rabit_tpu
 from rabit_tpu.learn.data import SparseMat, load_libsvm
 from rabit_tpu.learn.lbfgs import LBFGSSolver, ObjFunction
-from rabit_tpu.ops import MAX
+from rabit_tpu.obs import program
+from rabit_tpu.ops import MAX, on_tpu
+from rabit_tpu.ops import sparse_linear_kernel as sk
 from rabit_tpu.utils import compile_cache
 from rabit_tpu.utils.checks import check
 from rabit_tpu.utils.serial import Base64InStream, Base64OutStream
@@ -126,60 +132,139 @@ class LinearModel:
                 check(False, "invalid model file")
 
 
-_EVAL_CACHE: dict = {}
+def _row_loss(loss_type: int, m, labels):
+    import jax.numpy as jnp
+
+    if loss_type == LOSS_LOGISTIC:
+        # stable nlogprob (reference: MarginToLoss, linear.h:72-86)
+        nlogprob = jnp.where(m > 0.0, jnp.log1p(jnp.exp(-m)),
+                             -m + jnp.log1p(jnp.exp(m)))
+        return labels * nlogprob + (1.0 - labels) * (m + nlogprob)
+    return 0.5 * (m - labels) ** 2
 
 
-def _make_kernels(loss_type: int, nblocks: int, block: int, nnz: int,
-                  wlen: int):
-    """Jitted eval/grad over ELL blocks.
+def _is_for(kept, w: np.ndarray, base: np.float32) -> bool:
+    """Whether ``kept`` (``(w, base, what the device holds for them)`` or
+    None) was computed at these weights: by value, so that neither a copy
+    nor a weight changed in place is mistaken."""
+    return (kept is not None and kept[1] == base
+            and np.array_equal(kept[0], w))
 
-    Weights are padded with one zero slot that all ELL padding (and any
-    feature ≥ num_feature, reference: linear.h:94-96) points at, so the
-    gather/scatter needs no masking.
-    """
-    key = (loss_type, nblocks, block, nnz, wlen)
-    fns = _EVAL_CACHE.get(key)
-    if fns is not None:
-        return fns
+
+class DeviceShard:
+    """One rank's rows on the device for the whole job: the bucketed
+    non-zeros (:mod:`rabit_tpu.ops.sparse_linear_kernel`), labels and
+    the valid mask, and the two programs of an iteration, compiled
+    before the first.  ``evaluate`` and ``gradient`` only dispatch."""
+
+    def __init__(self, packed, val, fb, labels, valid, tiles: int,
+                 num_feature: int, nnz: int, loss_type: int) -> None:
+        self.arrays = (packed, val, fb, labels, valid)
+        self.tiles, self.num_feature = tiles, num_feature
+        self.nnz, self.nnz_padded = nnz, int(packed.size)
+        with program.span("stage.compile"):
+            self._evaluate, self._gradient = self._programs(loss_type)
+
+    def _programs(self, loss_type: int):
+        import jax
+        import jax.numpy as jnp
+
+        tiles, nf = self.tiles, self.num_feature
+        # the kernels on the chip; XLA's gather and scatter off it (on a
+        # v5e they take 4.96 s and 4.71 s a pass at a million weights and
+        # 654M non-zeros, the kernels 0.77 s and 0.79 s: PERF.md section 5)
+        product, product_t = (
+            (functools.partial(sk.lbfgs_margin, interpret=False),
+             functools.partial(sk.lbfgs_grad, interpret=False))
+            if on_tpu() else (sk.margins_xla, sk.gradient_xla))
+
+        def lbfgs_margin(packed, val, fb, labels, valid, w, base):
+            with jax.named_scope("lbfgs_margin"):
+                m = product(packed, val, fb, w, tiles=tiles) + base
+                loss = _row_loss(loss_type, m, labels) * valid
+                return m, jnp.sum(loss.reshape(tiles, -1), axis=1)
+
+        def lbfgs_grad(packed, val, fb, labels, valid, m):
+            with jax.named_scope("lbfgs_grad"):
+                pred = jax.nn.sigmoid(m) if loss_type == LOSS_LOGISTIC else m
+                g = (pred - labels) * valid
+                return (product_t(packed, val, fb, g, tiles=tiles,
+                                  num_feature=nf),
+                        jnp.sum(g.reshape(tiles, -1), axis=1))
+
+        rows = jax.ShapeDtypeStruct((tiles * sk.ROW_TILE,), jnp.float32)
+        return (jax.jit(lbfgs_margin).lower(
+                    *self.arrays, jax.ShapeDtypeStruct((nf,), jnp.float32),
+                    jax.ShapeDtypeStruct((), jnp.float32)).compile(),
+                jax.jit(lbfgs_grad).lower(*self.arrays, rows).compile())
+
+    def evaluate(self, w: np.ndarray, base: np.float32):
+        """(margins on the device, the loss summed a tile)."""
+        return self._evaluate(*self.arrays, w, base)
+
+    def gradient(self, margins):
+        """(``X^T g`` for the loss's g at ``margins``, g summed a tile)."""
+        return self._gradient(*self.arrays, margins)
+
+
+def stage_rows(indices: np.ndarray, values: np.ndarray, labels: np.ndarray,
+               num_feature: int, loss_type: int = LOSS_LOGISTIC
+               ) -> DeviceShard:
+    """Padded-ELL rows ``(n, k)`` (a slot of value 0 is padding) to the
+    device, a group of tiles at a time with the next group's host copy
+    in flight, each group bucketed there (``stage.put``,
+    ``stage.bucket``); then the job's programs (``stage.compile``)."""
     import jax
     import jax.numpy as jnp
 
-    def margins(wpad, base, idx, val):
-        # (nb, B, nnz) gather → row-sum; bias wpad[wlen-2] added by caller
-        return base + jnp.sum(wpad[idx] * val, axis=-1)
+    n, k = indices.shape
+    tiles = max(1, -(-n // sk.ROW_TILE))
+    group = min(sk.GROUP_TILES, tiles)
+    groups = -(-tiles // group)
+    tiles = groups * group
+    cap = sk.capacity(k, num_feature)
+    rows = group * sk.ROW_TILE
+    with program.span("stage.compile"):
+        slots = jax.ShapeDtypeStruct((rows * k,), jnp.int32)
+        bucket = sk.bucket_group.lower(
+            slots, jax.ShapeDtypeStruct((rows * k,), jnp.float32),
+            nnz_row=k, num_feature=num_feature).compile()
 
-    @jax.jit
-    def eval_fn(wpad, base, idx, val, labels, valid):
-        m = margins(wpad, base, idx, val)
-        if loss_type == LOSS_LOGISTIC:
-            # stable nlogprob (reference: MarginToLoss, linear.h:72-86)
-            nlogprob = jnp.where(
-                m > 0.0,
-                jnp.log1p(jnp.exp(-m)),
-                -m + jnp.log1p(jnp.exp(m)))
-            loss = labels * nlogprob + (1.0 - labels) * (m + nlogprob)
-        else:
-            loss = 0.5 * (m - labels) ** 2
-        return jnp.sum(loss * valid)
+    def put(i: int):
+        with program.span("stage.put"):
+            lo, hi = i * rows, min(n, (i + 1) * rows)
+            flat = []
+            for a, dtype in ((indices, np.int32), (values, np.float32)):
+                a = np.ascontiguousarray(a[lo:hi], dtype).reshape(-1)
+                if hi - lo < rows:
+                    a = np.concatenate(
+                        [a, np.zeros((rows - (hi - lo)) * k, dtype)])
+                flat.append(jax.device_put(a))
+            return flat
 
-    @jax.jit
-    def grad_fn(wpad, base, idx, val, labels, valid):
-        m = margins(wpad, base, idx, val)
-        if loss_type == LOSS_LOGISTIC:
-            pred = jax.nn.sigmoid(m)
-        else:
-            pred = m
-        g = (pred - labels) * valid          # (nb, B)
-        flat_idx = idx.reshape(-1)
-        flat = (val * g[..., None]).reshape(-1)
-        # 1-D scatter into the weight vector measures on par with a
-        # one-hot contraction here (unlike the 2-D row densify in
-        # kmeans, where one-hot wins 10x) — keep the simple form.
-        gw = jnp.zeros(wlen, jnp.float32).at[flat_idx].add(flat)
-        return gw, jnp.sum(g)
-
-    _EVAL_CACHE[key] = (eval_fn, grad_fn)
-    return _EVAL_CACHE[key]
+    subs = group * cap // sk.SUB
+    packed = jnp.zeros((groups * subs, sk.SUB), jnp.int32)
+    val = jnp.zeros((groups * subs, sk.SUB), jnp.float32)
+    fb = jnp.zeros((groups * subs // sk.SUBS, sk.SUBS), jnp.int32)
+    ahead, counted = put(0), []
+    for i in range(groups):
+        here, ahead = ahead, put(i + 1) if i + 1 < groups else None
+        with program.span("stage.bucket"):
+            p, v, f, real = bucket(*here)
+            packed = sk.place(packed, p, i * subs)
+            val = sk.place(val, v, i * subs)
+            fb = sk.place(fb, f, i * subs // sk.SUBS)
+            counted.append(real)
+    with program.span("stage.put"):
+        pad = tiles * sk.ROW_TILE - n
+        y = jax.device_put(np.concatenate(
+            [np.asarray(labels, np.float32), np.zeros(pad, np.float32)]))
+        valid = jax.device_put(np.concatenate(
+            [np.ones(n, np.float32), np.zeros(pad, np.float32)]))
+    with program.span("stage.bucket"):
+        nnz = sum(int(c) for c in counted)       # waits for the last group
+    return DeviceShard(packed, val, fb, y, valid, tiles, num_feature, nnz,
+                       loss_type)
 
 
 class LinearObjFunction(ObjFunction):
@@ -194,10 +279,13 @@ class LinearObjFunction(ObjFunction):
         self.model_out = "final.model"
         self.name_pred = "pred.txt"
         self.save_base64 = False
-        self.row_block = 1024
         self.lbfgs = LBFGSSolver(self)
         self.dtrain: SparseMat | None = None
-        self._ell = None
+        self._rows = None          # (indices, values, labels), padded ELL
+        self._feat_dim = 0
+        self._shard: DeviceShard | None = None
+        self._margins = None       # (w, base, margins on the device)
+        self._ahead = None         # (w, base, a gradient under way)
 
     # ------------------------------------------------------------------
     def set_param(self, name: str, val: str) -> None:
@@ -217,11 +305,21 @@ class LinearObjFunction(ObjFunction):
             self.name_pred = val
         elif name == "save_base64":
             self.save_base64 = bool(int(val))
-        elif name == "row_block":
-            self.row_block = int(val)
 
     def load_data(self, fname: str) -> None:
         self.dtrain = load_libsvm(fname)
+        self._feat_dim = self.dtrain.feat_dim
+
+    def load_arrays(self, indices: np.ndarray, values: np.ndarray,
+                    labels: np.ndarray, feat_dim: int) -> None:
+        """This rank's shard as padded-ELL arrays ``(n, k)`` (a slot of
+        value 0 is padding), what ``SparseMat.to_ell`` gives for a file;
+        ``feat_dim`` is one past the largest feature index."""
+        check(indices.shape == values.shape and indices.ndim == 2
+              and len(labels) == len(indices),
+              "load_arrays: indices and values (n, k), labels (n,)")
+        self._rows = (indices, values, labels)
+        self._feat_dim = int(feat_dim)
 
     # ------------------------------------------------------------------
     # ObjFunction contract
@@ -229,7 +327,7 @@ class LinearObjFunction(ObjFunction):
         """(reference: InitNumDim, linear.cc:126-133)"""
         if self.model_in == "NULL":
             ndim = int(rabit_tpu.allreduce(
-                np.array([self.dtrain.feat_dim], np.int64), MAX)[0])
+                np.array([self._feat_dim], np.int64), MAX)[0])
             self.model.num_feature = max(ndim, self.model.num_feature)
         return self.model.num_feature + 1
 
@@ -241,6 +339,7 @@ class LinearObjFunction(ObjFunction):
                 self.model.init_base_score()
         else:
             weight[:] = self.model.weight
+        self.prepare()
 
     def save_state(self) -> object:
         return (self.model.base_score, self.model.num_feature,
@@ -249,57 +348,90 @@ class LinearObjFunction(ObjFunction):
     def load_state(self, state: object) -> None:
         (self.model.base_score, self.model.num_feature,
          self.model.loss_type) = state
+        self.prepare()
 
-    def _ell_blocks(self):
-        if self._ell is None:
-            nf = self.model.num_feature
-            idx, val, labels, valid = self.dtrain.to_ell(
-                pad_index=nf + 1, row_block=self.row_block)
-            # any feature ≥ num_feature routes to the zero pad slot
-            idx = np.where(idx >= nf, nf + 1, idx).astype(np.int32)
-            import jax
+    def prepare(self) -> None:
+        """Stage the shard and compile the job's programs, once: when
+        the solver's ``init`` hands over a fresh model or a committed
+        one, so that no iteration pays for it."""
+        if self._shard is not None:
+            return
+        if self._rows is None:
+            idx, val, _labels, _valid = self.dtrain.to_ell()
+            self._rows = (idx, val, self.dtrain.labels)
+        self._shard = stage_rows(*self._rows, self.model.num_feature,
+                                 self.model.loss_type)
 
-            nb = idx.shape[0] // self.row_block
-            # device-resident across all solver iterations
-            self._ell = tuple(jax.device_put(a) for a in (
-                idx.reshape(nb, self.row_block, -1),
-                val.reshape(nb, self.row_block, -1),
-                labels.reshape(nb, self.row_block),
-                valid.reshape(nb, self.row_block),
-            ))
-        return self._ell
+    def _split(self, weight: np.ndarray):
+        """(float32 feature weights, float32 bias + base score): what the
+        device is handed."""
+        nf = self.model.num_feature
+        return (np.asarray(weight[:nf], np.float32),
+                np.float32(self.model.base_score + weight[nf]))
 
-    def _wpad(self, weight: np.ndarray) -> np.ndarray:
-        # [w_0..w_{nf-1}, bias, 0-pad]
-        return np.concatenate(
-            [weight, [0.0]]).astype(np.float32)
+    def _evaluate(self, w: np.ndarray, base: np.float32):
+        """Margins at ``(w, base)`` kept on the device for the gradient
+        that follows an accepted trial; the loss summed a tile."""
+        with program.span("learn.dispatch"):
+            margins, partial = self._shard.evaluate(w, base)
+        self._margins = (w, base, margins)
+        program.count("lbfgs.nnz", self._shard.nnz)
+        program.count("lbfgs.nnz_padded", self._shard.nnz_padded)
+        return partial
 
     def eval(self, weight: np.ndarray) -> float:
         """Shard data loss (+L2 on rank 0 only — added once globally;
-        reference: Eval, linear.cc:150-173)."""
-        idx, val, labels, valid = self._ell_blocks()
-        eval_fn, _ = _make_kernels(
-            self.model.loss_type, *idx.shape, len(weight) + 1)
+        reference: Eval, linear.cc:150-173).  Float32 within a tile of
+        rows on the device, float64 across tiles here."""
+        self.prepare()
+        partial = self._evaluate(*self._split(weight))
+        with program.span("learn.fetch"):
+            sum_val = float(np.asarray(partial).astype(np.float64).sum())
         nf = self.model.num_feature
-        base = np.float32(self.model.base_score + weight[nf])
-        sum_val = float(eval_fn(self._wpad(weight), base, idx, val,
-                                labels, valid))
         if rabit_tpu.get_rank() == 0 and self.reg_L2 != 0.0:
             sum_val += 0.5 * self.reg_L2 * float(weight[:nf] @ weight[:nf])
         check(not np.isnan(sum_val), "nan occurs")
         return sum_val
 
+    def _dispatch_grad(self, w: np.ndarray, base: np.float32):
+        """Enqueue ``X^T g`` at ``(w, base)``.  The margins are those
+        ``eval`` left on the device when it was handed these same
+        weights (the accepted trial of the line search); anything else
+        recomputes them."""
+        program.count("lbfgs.grads")
+        if _is_for(self._margins, w, base):
+            program.count("lbfgs.margin_reused")
+        else:
+            self._evaluate(w, base)
+        with program.span("learn.dispatch"):
+            out = self._shard.gradient(self._margins[2])
+        program.count("lbfgs.nnz", self._shard.nnz)
+        program.count("lbfgs.nnz_padded", self._shard.nnz_padded)
+        return out
+
+    def start_grad(self, weight: np.ndarray) -> None:
+        """The gradient at ``weight`` enqueued, not waited for: what the
+        solver asks for before it commits."""
+        self.prepare()
+        w, base = self._split(weight)
+        self._ahead = (w, base, self._dispatch_grad(w, base))
+
     def calc_grad(self, weight: np.ndarray) -> np.ndarray:
-        """Shard gradient (reference: CalcGrad, linear.cc:174-201)."""
-        idx, val, labels, valid = self._ell_blocks()
-        _, grad_fn = _make_kernels(
-            self.model.loss_type, *idx.shape, len(weight) + 1)
+        """Shard gradient (reference: CalcGrad, linear.cc:174-201): the
+        one ``start_grad`` enqueued for these weights, or a new one."""
+        self.prepare()
+        w, base = self._split(weight)
+        ahead, self._ahead = self._ahead, None
+        if _is_for(ahead, w, base):
+            program.count("learn.ahead")
+            gw, gbias = ahead[2]
+        else:
+            gw, gbias = self._dispatch_grad(w, base)
         nf = self.model.num_feature
-        base = np.float32(self.model.base_score + weight[nf])
-        gw, gbias = grad_fn(self._wpad(weight), base, idx, val,
-                            labels, valid)
-        out = np.asarray(gw, np.float64)[:nf + 1]
-        out[nf] = float(gbias)
+        with program.span("learn.fetch"):
+            out = np.empty(nf + 1, np.float64)
+            out[:nf] = np.asarray(gw)
+            out[nf] = np.asarray(gbias).astype(np.float64).sum()
         if rabit_tpu.get_rank() == 0 and self.reg_L2 != 0.0:
             out[:nf] += self.reg_L2 * weight[:nf]
         return out

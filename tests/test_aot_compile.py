@@ -145,6 +145,25 @@ def _dense16_loop(topo):
     return compiled
 
 
+def _lbfgs_product(which, topo):
+    from rabit_tpu.ops import sparse_linear_kernel as sk
+
+    # the benchmark cell's shard as linear.stage_rows leaves it: 1024
+    # row tiles of 16,384 rows x 39 non-zeros over a million weights,
+    # both kernels with the whole weight (or gradient) table in VMEM
+    nf, tiles = 1_000_000, 1024
+    subs = tiles * sk.capacity(39, nf) // sk.SUB
+    packed, val, fb, w, g = _one_chip(
+        topo, ((subs, sk.SUB), jnp.int32), ((subs, sk.SUB), jnp.float32),
+        ((subs // sk.SUBS, sk.SUBS), jnp.int32), ((nf,), jnp.float32),
+        ((tiles * sk.ROW_TILE,), jnp.float32))
+    if which == "margin":
+        return sk.lbfgs_margin.lower(packed, val, fb, w, tiles=tiles,
+                                     interpret=False).compile()
+    return sk.lbfgs_grad.lower(packed, val, fb, g, tiles=tiles,
+                               num_feature=nf, interpret=False).compile()
+
+
 def _mesh(topo):
     return Mesh(np.array(topo.devices), ("dp",))
 
@@ -196,6 +215,8 @@ def _ring(nbytes, topo):
     _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
     functools.partial(_hist_level_chunked, 16), _kmeans_ell_chain,
     _dense16_loop,
+    functools.partial(_lbfgs_product, "margin"),
+    functools.partial(_lbfgs_product, "grad"),
     _mesh_kmeans_step,
     # latency-sized, one VMEM segment, and past the segmentation
     # threshold (_VMEM_BUDGET_BYTES): the three fail at the parent commit
@@ -203,7 +224,8 @@ def _ring(nbytes, topo):
     functools.partial(_ring, 64 << 20),
 ], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-8x64x256x262k",
         "hist_fused_multi-32slots-28x256x33.6M",
-        "hist_fused_multi-16slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M", "mesh_kmeans_step",
+        "hist_fused_multi-16slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M",
+        "lbfgs_margin-16.8Mx39-1M", "lbfgs_grad-16.8Mx39-1M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])
 def test_compiles_for_v5e(topo, build):
     assert "tpu_custom_call" in build(topo).as_text()

@@ -29,6 +29,7 @@ def as_if_on_chip(monkeypatch):
     import rabit_tpu
     import rabit_tpu.ops.histogram_kernel as hk
     import rabit_tpu.ops.kmeans_kernel as kk
+    import rabit_tpu.ops.sparse_linear_kernel as sk
     from rabit_tpu.learn import kmeans
 
     if rabit_tpu.initialized():
@@ -36,7 +37,8 @@ def as_if_on_chip(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for mod, name in ((kk, "kmeans_stats_fused"),
                       (kk, "kmeans_ell_stats_fused"),
-                      (hk, "hist_fused_multi")):
+                      (hk, "hist_fused_multi"),
+                      (sk, "lbfgs_margin"), (sk, "lbfgs_grad")):
         def interpreted(*a, _orig=getattr(mod, name), **kw):
             kw["interpret"] = True
             return _orig(*a, **kw)
@@ -65,6 +67,7 @@ def as_if_on_chip(monkeypatch):
     ("phase_kmeans_dense16", (0, 1 << 14)),
     ("phase_kmeans_ell", (0, 1 << 14)),
     ("phase_gbdt", (0, 1 << 12)),
+    ("phase_lbfgs_products", (0, 1 << 12)),
     ("phase_mesh_kmeans", (0, 1 << 11)),
     ("phase_mesh_allreduce", (0, (64 << 10, 1 << 20), True)),
 ])

@@ -213,7 +213,10 @@ def test_kmeans_stats_against_numpy(empty_engine):
 
 
 # ------------------------------------------------------------------- L-BFGS
-class _Quadratic:
+from rabit_tpu.learn import lbfgs  # noqa: E402
+
+
+class _Quadratic(lbfgs.ObjFunction):
     """f(w) = 0.5||w - t||^2 — exact minimum known."""
 
     def __init__(self, target):
@@ -269,7 +272,6 @@ def test_lbfgs_logistic_l1_sparsity(empty_engine, tmp_path):
     obj.set_param("reg_L1", "2.0")
     obj.set_param("max_lbfgs_iter", "60")
     obj.set_param("silent", "1")
-    obj.set_param("row_block", "128")
     obj.lbfgs.run()
     w = obj.lbfgs.get_weight()
     # relevant features survive, most irrelevant ones are exactly zero
@@ -298,7 +300,6 @@ def _train_linear(tmp_path, objective, seed=0, n=500, d=10, reg_L2="0.01"):
     obj.set_param("reg_L2", reg_L2)
     obj.set_param("max_lbfgs_iter", "80")
     obj.set_param("silent", "1")
-    obj.set_param("row_block", "128")
     obj.set_param("model_out", str(tmp_path / "final.model"))
     obj.run()
     return obj, X, y, w_true
