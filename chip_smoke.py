@@ -255,8 +255,9 @@ def kernel_in_program(fn, *shapes) -> bool:
 def dense_rows(device_budget: int, host_bytes: int) -> int:
     """Rows for the dense16 phase: as many whole 2^20 blocks as fit the
     library's own dense16 budget on the device (bf16 row + f32 validity)
-    and half of host memory (CSR, its ELL copy and the clamp's
-    temporary are ~3 x 8 bytes per non-zero), capped at the 24M of
+    and half of host memory (3 x 8 bytes per non-zero: the CSR and, for
+    a shard with an index out of range, the clamp's copy, with room to
+    spare), capped at the 24M of
     ``tools/big_kmeans.py dense``."""
     per_row_device = DENSE_DIM * 2 + 4
     per_row_host = NNZ * 8 * 3

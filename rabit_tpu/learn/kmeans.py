@@ -218,6 +218,61 @@ def _stage_dense16(idx, val, valid, feat_dim: int, row_block: int,
     return x, jax.device_put(jnp.asarray(v16))
 
 
+def _stage_slice_fns():
+    """Jitted: a zeroed device array of a shape and type, and a slice of
+    rows written into such an array, donated."""
+    fns = _STEP_CACHE.get("stage_slice")
+    if fns is None:
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        @functools.partial(jax.jit, static_argnums=(0, 1))
+        def kmeans_stage_alloc(shape, dtype):
+            return jnp.zeros(shape, dtype)
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def kmeans_stage_slice(x, c, start):
+            return lax.dynamic_update_slice(x, c, (start, 0))
+
+        fns = _STEP_CACHE["stage_slice"] = (kmeans_stage_alloc,
+                                            kmeans_stage_slice)
+    return fns
+
+
+def _stage_sliced(host, rows: int):
+    """``host`` (n, w) as one device array, handed over ``rows`` rows at
+    a time through the donating writer.
+
+    ``jax.device_put`` of one multi-GB array moves a GB a second on the
+    chip's host, where the same bytes in slices of 134 MB move at 14
+    (4.8 s against 0.3 s for the 4.29 GB of a 33.5M-row shard's
+    indices: PERF.md section 6, PR 32).  A slice is awaited before the
+    next is handed over, so the device holds the array and one slice
+    and never more: the loop would otherwise run ahead of the device by
+    as many slices as it has."""
+    import jax
+
+    alloc, write = _stage_slice_fns()
+    try:
+        x = alloc(host.shape, host.dtype)
+        for start in range(0, host.shape[0], rows):
+            c = jax.device_put(host[start:start + rows])
+            # a host scalar: jnp.int32(start) would load a program of
+            # its own, which stays on the device (34 KB) after this
+            x = write(x, c, np.int32(start))
+            x.block_until_ready()
+            del c
+        return x
+    finally:
+        # run once a staging: a program left loaded holds its 50-80 KB
+        # of the device's memory for as long as the job runs
+        alloc.clear_cache()
+        write.clear_cache()
+
+
 def _ell_densify(idx, val, d: int):
     """Densify a padded-ELL block to (rows, d+1).
 
@@ -404,7 +459,8 @@ def _next_pow2(v: int) -> int:
 def prepare_shard(idx, val, valid, feat_dim: int,
                   row_block: int = DEFAULT_ROW_BLOCK,
                   budget: int = DENSIFY_BUDGET_BYTES,
-                  compute_dtype: str = "float32"):
+                  compute_dtype: str = "float32",
+                  max_index: int | None = None):
     """Stage this rank's shard on device for repeated stats passes.
 
     Small-enough shards are densified once (the scatter is
@@ -415,15 +471,26 @@ def prepare_shard(idx, val, valid, feat_dim: int,
     HBM — measured 4x the scan path's throughput at the 50M-point shape
     (doc/benchmarks.md "ELL densify bound", superseded in round 4);
     elsewhere the block-scan densify pass is used.
+
+    ``idx`` and ``val`` are read, never written, and no host array of
+    their size is made unless the data forces one (slots or rows that
+    the fused ELL kernel needs padded): they may be the caller's own
+    memory.  ``max_index`` is the largest entry of ``idx`` where the
+    caller has already taken it (:func:`run` does, under
+    ``stage.clamp``); the fused ELL tier takes it itself otherwise.
+
+    The span ``stage.put`` closes as this returns: the dense tiers'
+    transfers may still be in flight, the fused ELL tier's slices have
+    landed (:func:`_stage_sliced` awaits each).
     """
-    # as it returns: the transfers it enqueued may still be in flight
     with program.span("stage.put"):
         return _prepare_shard(idx, val, valid, feat_dim, row_block,
-                              budget, compute_dtype)
+                              budget, compute_dtype, max_index)
 
 
 def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
-                   budget: int, compute_dtype: str):
+                   budget: int, compute_dtype: str,
+                   max_index: int | None):
     import jax
 
     import jax.numpy as jnp
@@ -450,6 +517,8 @@ def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
         nnz = idx.shape[1]
         nnz_p = _next_pow2(nnz)
         n_p = -(-n // _ELL_FUSED_BLOCK) * _ELL_FUSED_BLOCK
+        if max_index is None:
+            max_index = int(idx.max(initial=0))
         if nnz_p != nnz or n_p != n:
             idx = np.pad(idx, ((0, n_p - n), (0, nnz_p - nnz)),
                          constant_values=feat_dim)
@@ -459,8 +528,11 @@ def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
         # ZERO value (ELL pads) vanish through the val-weighted one-hot,
         # so only clamped out-of-range features carrying real values
         # force an extra sliced-away feature block (+hi columns = +20%
-        # MACs at d=512) to absorb them.
-        contaminated = bool(np.any(val[idx >= feat_dim]))
+        # MACs at d=512) to absorb them.  The mask (a quarter of the
+        # indices' bytes) is built only where the shard holds such an
+        # index at all; the pads above carry zeros and need no look.
+        contaminated = (max_index >= feat_dim
+                        and bool(np.any(val[idx >= feat_dim])))
         d_base = feat_dim + 1 if contaminated else feat_dim
         d_pad = -(-d_base // _ELL_FUSED_HI) * _ELL_FUSED_HI
         # Stage GROUPED (n/G, G*nnz): a device array with a 32-wide
@@ -469,9 +541,10 @@ def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
         g = _ELL_FUSED_GROUP
         idx_g = np.ascontiguousarray(idx.reshape(n_p // g, g * nnz_p))
         val_g = np.ascontiguousarray(
-            val.reshape(n_p // g, g * nnz_p).astype(np.float32))
+            val.reshape(n_p // g, g * nnz_p).astype(np.float32, copy=False))
+        rows = _STAGE_CHUNK_ROWS // g
         return ("ell_fused", feat_dim,
-                (jax.device_put(idx_g), jax.device_put(val_g),
+                (_stage_sliced(idx_g, rows), _stage_sliced(val_g, rows),
                  jax.device_put(valid), d_pad, nnz_p))
     return ("ell", feat_dim, device_ell(idx, val, valid, row_block))
 
@@ -653,6 +726,14 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     array and every iteration rides the HBM-roofline fused kernel
     (similarity in bf16, accumulation in float32 — the bench.py
     numerics).
+
+    Every call stages the whole shard, under three spans:
+    ``stage.to_ell``; ``stage.clamp``, which times one range check of
+    the indices (their maximum against the model's width) and, only
+    where an index lies past that width and ``stage.clamped`` counts 1,
+    the copy that moves such indices to the pad column; ``stage.put``
+    (:func:`prepare_shard`).  A uniform in-range ``data`` is staged from
+    its own ``findex`` and ``fvalue``, which are only read.
     """
     if hash_dim is not None:
         from rabit_tpu.learn.data import hash_features
@@ -682,13 +763,23 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     k, feat_dim = model.centroids.shape
     idx, val, _labels, valid = data.to_ell(
         pad_index=feat_dim, row_block=row_block)
-    # clamp out-of-range features (another shard defined feat_dim)
+    # Features past the model's width (a restored model narrower than
+    # this shard, a SparseMat that understates its feat_dim) go to the
+    # pad column.  One read of the indices says whether any does: a
+    # uniform shard's idx and val are the caller's own findex and
+    # fvalue, and a copy of them is seconds of fresh pages at data
+    # scale.  From here on nothing writes into either.
     with program.span("stage.clamp"):
-        idx = np.minimum(idx, feat_dim).astype(np.int32)
+        max_index = int(idx.max(initial=0))
+        clamped = max_index > feat_dim
+        if clamped:
+            idx, max_index = np.minimum(idx, feat_dim), feat_dim
+        program.count("stage.clamped", int(clamped))
+        idx = idx.astype(np.int32, copy=False)
     # dataset lives on device across iterations; only the (k, d+1) stats
     # matrix crosses the host boundary for the fault-tolerant allreduce
     shard = prepare_shard(idx, val, valid, feat_dim, row_block,
-                          compute_dtype=compute_dtype)
+                          compute_dtype=compute_dtype, max_index=max_index)
 
     if (device_chain > 1 and not rabit_tpu.is_distributed()
             and shard[0] in ("dense", "dense16", "ell_fused")):
@@ -780,7 +871,8 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                 queued = None
                 program.count("learn.ahead_discarded")
             shard = prepare_shard(idx, val, valid, feat_dim, row_block,
-                                  compute_dtype=compute_dtype)
+                                  compute_dtype=compute_dtype,
+                                  max_index=max_index)
         with program.span("learn.step", version=it + 1):
             if device_plane:
                 if queued is None:

@@ -128,6 +128,21 @@ def _kmeans_ell_chain(topo):
                                ((n,), jnp.float32))).compile()
 
 
+def test_stage_slice_writer_updates_the_shard_in_place_on_v5e(topo):
+    """The fused ELL tier's staging at the sparse cell's shape (33.5M
+    rows grouped (n/4, 128), slices of 2^20 rows): the donated array is
+    the output, so the device holds the array and the slice."""
+    from rabit_tpu.learn import kmeans
+
+    n_g, rows = (32 << 20) // kmeans._ELL_FUSED_GROUP, 1 << 18
+    x, c, start = _one_chip(topo, ((n_g, 128), jnp.int32),
+                            ((rows, 128), jnp.int32), ((), jnp.int32))
+    m = kmeans._stage_slice_fns()[1].lower(x, c, start).compile(
+        ).memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes == n_g * 128 * 4
+    assert m.temp_size_in_bytes <= 1 << 20
+
+
 def _dense16_loop(topo):
     from rabit_tpu.learn import kmeans
 

@@ -180,7 +180,9 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
             ("n", "total_s", "max_s")} <= {
         "learn.iterations", "learn.versions", "learn.ahead",
         "learn.ahead_discarded", "allreduce.programs_built",
-        "compile.seconds", "compile.misses", "compile.hits"}
+        "compile.seconds", "compile.misses", "compile.hits",
+        "stage.clamped"}
+    assert s["stage.clamped"] == 0
     # the loop's 1 + the feature-width agreement before it
     assert s["allreduce.n"] == (1 if chain else 1 + versions)
     covered = sum(s[c + ".total_s"] for c in children)
