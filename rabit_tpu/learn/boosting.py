@@ -42,7 +42,7 @@ class TreeNode:
     left: int = -1
     right: int = -1
     # learned default direction for missing values (XGBoost's
-    # sparsity-aware split; rows whose bin is the missing bin go this way)
+    # sparsity-aware split; rows absent at the feature go this way)
     default_left: bool = True
 
 
@@ -64,7 +64,7 @@ class BoostedModel:
 
     def _tree_margin(self, tree: list[TreeNode], bins: np.ndarray
                      ) -> np.ndarray:
-        missing_bin = self.cuts.shape[1] + 1
+        missing_code = self.cuts.shape[1] + 1
         node = np.zeros(bins.shape[0], np.int32)
         out = np.zeros(bins.shape[0], np.float32)
         live = np.ones(bins.shape[0], bool)
@@ -80,7 +80,7 @@ class BoostedModel:
                     live[rows] = False
                 else:
                     b = bins[rows, n.feature]
-                    go_left = np.where(b == missing_bin,
+                    go_left = np.where(b == missing_code,
                                        getattr(n, "default_left", True),
                                        b <= n.bin_threshold)
                     idx = np.flatnonzero(rows)
@@ -192,13 +192,17 @@ class _HostShard:
         self.labels, self.model = labels, model
         self.max_depth, self.subsample, self.seed = max_depth, subsample, seed
         self.kw = {"use_pallas": use_pallas, "compute_dtype": compute_dtype}
+        self.nbin = nbin
         with program.span("stage.bin"):
             self.bins = apply_cuts(values, model.cuts)
-        self.any_nan = bool(np.isnan(values).any())
+        absent = int(np.count_nonzero(self.bins == nbin))   # the code
+        program.count("gbdt.entries", self.bins.size)
+        program.count("gbdt.entries_missing", absent)
+        self.any_nan = absent > 0
         self.max_bin = int(self.bins.max(initial=0))
 
-    def start(self, nslot: int) -> None:
-        self.nslot = nslot
+    def start(self, has_missing: bool) -> None:
+        self.has_missing = has_missing
         self.margin = self.model.margin(self.bins)
         self.node = np.zeros(self.n, np.int32)
 
@@ -217,10 +221,10 @@ class _HostShard:
         which, and the kernel calls that took."""
         order = [s for s in build if s >= 0]
         calls = histogram.level_calls(len(order), self.bins.shape[1],
-                                      self.nslot, self.kw["use_pallas"])
+                                      self.nbin, self.kw["use_pallas"])
         return histogram.build_level_local(
-            self.bins, self.grad, self.hess, self.node, order, self.nslot,
-            **self.kw), order, calls
+            self.bins, self.grad, self.hess, self.node, order, self.nbin,
+            totals=self.has_missing, **self.kw), order, calls
 
     def partition(self, tab: np.ndarray) -> None:
         node = self.node
@@ -228,7 +232,7 @@ class _HostShard:
         feat, thr, dleft, leaf = tab[np.where(live, node, 0)].T
         b = self.bins[np.arange(self.n), feat]
         left = np.where(b == self.model.cuts.shape[1] + 1, dleft != 0,
-                        b <= thr)
+                        b <= thr)           # absent: the default direction
         self.node = np.where(live, np.where(leaf < 0, leaf,
                                             2 * node + 1 - left),
                              node).astype(np.int32)
@@ -268,7 +272,7 @@ class _DeviceShard:
         import jax
 
         self.n, self.f = values.shape
-        self.model, self.max_depth = model, max_depth
+        self.model, self.max_depth, self.nbin = model, max_depth, nbin
         self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
         self.subsample, self.seed = subsample, seed
         self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
@@ -277,10 +281,10 @@ class _DeviceShard:
             self.labels = jax.device_put(np.asarray(labels, np.float32))
         self.any_nan, self.max_bin = (int(v) for v in np.asarray(seen))
 
-    def start(self, nslot: int) -> None:
+    def start(self, has_missing: bool) -> None:
         import jax.numpy as jnp
 
-        self.nslot = nslot
+        self.has_missing = has_missing
         with program.span("stage.compile"):
             self.prog = self._programs()
         self.margin = jnp.full((self.n,), self.model.base_score, jnp.float32)
@@ -300,13 +304,13 @@ class _DeviceShard:
 
         from rabit_tpu.ops import histogram_kernel as hk
 
-        n, f, nslot, depth = self.n, self.f, self.nslot, self.max_depth
+        n, f, nbin, depth = self.n, self.f, self.nbin, self.max_depth
         loss, rate = self.model.loss, self.model.learning_rate
-        sampled = self.subsample < 1.0
-        missing_bin = self.model.cuts.shape[1] + 1
+        sampled, totals = self.subsample < 1.0, self.has_missing
+        missing_code = self.model.cuts.shape[1] + 1
         use_pallas, cdt = self.use_pallas, self.compute_dtype
-        key = (n, f, self.bins_t.shape[0], nslot, depth, loss, rate, sampled,
-               missing_bin, use_pallas, cdt, hk.hist_fused_multi,
+        key = (n, f, self.bins_t.shape[0], nbin, totals, depth, loss, rate,
+               sampled, missing_code, use_pallas, cdt, hk.hist_fused_multi,
                jax.default_backend())
         if key in _PROGRAMS:
             return _PROGRAMS[key]
@@ -334,8 +338,9 @@ class _DeviceShard:
                         (node >= 0) & (node == _lookup(takes, above, nslots)),
                         above, -1)
                     return histogram.level_hist(
-                        bins_t, gh, slot, nslots, f, nslot,
-                        use_pallas=use_pallas, compute_dtype=cdt)
+                        bins_t, gh, slot, nslots, f, nbin,
+                        use_pallas=use_pallas, compute_dtype=cdt,
+                        totals=totals)
             return gbdt_level
 
         def gbdt_partition(bins_t, node, tab):
@@ -347,7 +352,7 @@ class _DeviceShard:
                 rows_of = jnp.arange(bins_t.shape[0], dtype=jnp.int32)
                 b = jnp.sum(jnp.where(feat[None, :] == rows_of[:, None],
                                       bins_t, 0), axis=0)
-                left = jnp.where(b == missing_bin, dleft != 0, b <= thr)
+                left = jnp.where(b == missing_code, dleft != 0, b <= thr)
                 child = 2 * node + 1 - left.astype(jnp.int32)
                 return jnp.where(node < 0, node,
                                  jnp.where(leaf < 0, leaf, child))
@@ -392,7 +397,7 @@ class _DeviceShard:
 
     def level(self, build):
         calls = histogram.level_calls(len(build), self.bins_t.shape[0],
-                                      self.nslot, self.use_pallas)
+                                      self.nbin, self.use_pallas)
         return (self.prog["level"][len(build)](
             self.bins_t, self.gh, self.node, np.asarray(build, np.int32)),
             build, calls)
@@ -414,6 +419,7 @@ def _reduce_level(local) -> np.ndarray:
     reduction rides ICI; host engines take the fault-tolerant numpy
     path."""
     shape = local.shape
+    program.count("gbdt.hist_bytes_fetched", local.nbytes)
     if _engine_mod.is_device_plane():
         out = rabit_tpu.allreduce(local.reshape(-1), SUM)
         with program.span("gbdt.level.fetch"):
@@ -423,27 +429,76 @@ def _reduce_level(local) -> np.ndarray:
     return rabit_tpu.allreduce(local.reshape(-1), SUM).reshape(shape)
 
 
+def _scan(hist: np.ndarray, reg_lambda: float, min_child_weight: float,
+          has_missing: bool):
+    """``histogram.best_split`` of a node's histogram as the loop holds
+    it: with ``has_missing`` its last feature row is not a feature but
+    holds the node's (grad, hess) totals in its bin 0
+    (``histogram.with_totals``)."""
+    if has_missing:
+        return histogram.best_split(hist[:-1], reg_lambda, min_child_weight,
+                                    hist[-1, 0])
+    return histogram.best_split(hist, reg_lambda, min_child_weight)
+
+
+# a level's slots are scanned on a few threads where a slot's histogram
+# is this large (numpy's loops release the interpreter): at 968 features
+# a scan is 7 ms of float64 arithmetic and a round has 63 of them with
+# nothing in flight on the device; at 28 features it is microseconds
+# and a thread would cost more than it saves
+_SCAN_THREADS = 4
+_SCAN_PARALLEL_BYTES = 1 << 20
+_scan_pool = None
+
+
+def _scan_level(hists, reg_lambda: float, min_child_weight: float,
+                has_missing: bool) -> list:
+    """``_scan`` of every slot of a level, node or not."""
+    global _scan_pool
+
+    def scan(hist):
+        return _scan(hist, reg_lambda, min_child_weight, has_missing)
+
+    if len(hists) < 2 or hists[0].nbytes < _SCAN_PARALLEL_BYTES:
+        return [scan(hist) for hist in hists]
+    if _scan_pool is None:
+        from concurrent.futures import ThreadPoolExecutor
+
+        _scan_pool = ThreadPoolExecutor(_SCAN_THREADS,
+                                        thread_name_prefix="gbdt-scan")
+    return list(_scan_pool.map(scan, hists))
+
+
 def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
            reg_lambda: float, min_child_weight: float,
-           has_missing: bool) -> int | None:
+           has_missing: bool, best=None) -> int | None:
     """Choose ``node``'s split on its reduced histogram, or leave it a
     leaf (None).  A split gives both children the weight their side's
     sums give; a child that is split in turn gets its own.  Returns the
     child whose histogram the next level builds, 0 left or 1 right: the
     one with the smaller hessian sum (ties: left), so that the other,
     derived as parent minus built, is the larger and inherits the
-    parent's accumulation error at most doubled in relative size."""
-    gain, default_left = histogram.split_candidates(
-        hist, reg_lambda, min_child_weight, has_missing)
-    j, t = np.unravel_index(int(gain.argmax()), gain.shape)
-    dl = bool(default_left[j, t]) if has_missing else True
+    parent's accumulation error at most doubled in relative size.
+
+    With ``has_missing`` the histogram's last feature row is not a
+    feature: its bin 0 holds the node's (grad, hess) totals
+    (``histogram.with_totals``), and the rows absent from the chosen
+    feature, the totals less its bins, go the better way.  ``best`` is
+    the node's ``_scan`` where the caller has made it already."""
+    gain, j, t, dl = best or _scan(hist, reg_lambda, min_child_weight,
+                                   has_missing)
+    if has_missing:
+        hist, total = hist[:-1], hist[-1, 0]
     # both sides from the chosen feature's own bins, in float64
     g_tot, h_tot = hist[j].sum(axis=0, dtype=np.float64)
     gl, hl = hist[j, :t + 1].sum(axis=0, dtype=np.float64)
-    if has_missing and dl:
-        gl, hl = gl + hist[j, -1, 0], hl + hist[j, -1, 1]
+    if has_missing:
+        gm, hm = histogram.missing_mass(hist[j:j + 1], total)[0]
+        g_tot, h_tot = g_tot + gm, h_tot + hm
+        if dl:
+            gl, hl = gl + gm, hl + hm
     gr, hr = g_tot - gl, h_tot - hl
-    if gain[j, t] <= 1e-12:
+    if gain <= 1e-12:
         node.value = float(-g_tot / (h_tot + reg_lambda))
         return None
     node.feature, node.bin_threshold = int(j), int(t)
@@ -477,7 +532,11 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     program that moves every row to its child; depth-limit leaf weights
     come from the last level's histograms and the margin update is one
     lookup by row.  The host touches no array of length n inside the
-    loop.
+    loop.  The gain scan covers every slot of the level, node or not:
+    like the device's programs it has the level's static shape, so a
+    round costs the same whatever the tree (where few labels are
+    positive a tree is not full, and which nodes stop early is the
+    seed's to say).
     Elsewhere the same loop runs on numpy arrays (``_HostShard``) and
     builds the same trees.
 
@@ -487,10 +546,12 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     ``(seed, round, rank)``, so a resumed run replays the exact sample
     of the round it died in — replay stays bit-aligned with survivors.
 
-    NaN feature values are missing: they bin into a dedicated slot,
-    every split learns a default direction from the missing rows'
-    gradient mass (``histogram.split_gain_missing``), and prediction
-    routes NaN the same way — XGBoost's sparsity-aware splits.
+    NaN feature values are missing: they are added to no bin (a
+    histogram has ``nbin`` slots with and without them), every split
+    learns a default direction from the absent rows' gradient mass, the
+    node's totals less the feature's bins
+    (``histogram.split_gain_missing``), and prediction routes NaN the
+    same way — XGBoost's sparsity-aware splits.
 
     ``use_pallas``/``compute_dtype`` pin the histogram path: on TPU the
     default is the fused Pallas kernel with bf16-rounded weights
@@ -522,26 +583,25 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
             use_pallas, compute_dtype)
         if version == 0 and not model.trees:
             # missing handling is GLOBAL: any rank with NaNs means every
-            # rank must carry the extra histogram slot and the
-            # missing-aware gain.  Decided HERE (round 0) and
+            # rank must carry the node totals with its histograms and
+            # the missing-aware gain.  Decided HERE (round 0) and
             # checkpointed in the model — a resume must not repeat the
             # collective (replay alignment).
             model.has_missing = bool(rabit_tpu.allreduce(
                 np.array([shard.any_nan], np.int32), MAX)[0])
-        missing_bin = model.cuts.shape[1] + 1
-        nslot = missing_bin + (1 if getattr(model, "has_missing", False)
-                               else 0)
-        check(shard.max_bin < nslot,
-              "boosting: a feature value is NaN (bin %d) but the model's "
-              "has_missing is False, so its histograms have only %d "
-              "slots: has_missing is decided once, at round 0, over "
-              "every rank's shard, and this shard does not match it",
-              shard.max_bin, nslot)
-        shard.start(nslot)
+        has_missing = getattr(model, "has_missing", False)
+        check(shard.max_bin < nbin + has_missing,
+              "boosting: a feature value is NaN (code %d) but the model's "
+              "has_missing is False, so its levels carry no node totals "
+              "to tell the absent rows' mass by: has_missing is decided "
+              "once, at round 0, over every rank's shard, and this shard "
+              "does not match it", shard.max_bin)
+        shard.start(has_missing)
         return shard
 
     shard = stage()
     has_missing = getattr(model, "has_missing", False)
+    level_of: dict = {}             # depth -> the level's histograms
     epoch = rabit_tpu.device_epoch()
     ready = -1                      # the round whose (grad, hess) is made
     for round_idx in range(version, num_round):
@@ -559,7 +619,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
             # the level slot built for each slot of the level above (the
             # root for itself; -1: none), and that level's reduced
             # histograms by slot
-            build, above = [0], {}
+            build, above = [0], None
             for depth in range(max_depth):
                 if all(nid < 0 for nid in slots):
                     break
@@ -572,22 +632,41 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                         local, order, calls = shard.level(build)
                     built = _reduce_level(local)
                     with program.span("gbdt.split"):
-                        hists = {}
+                        # the level in its static shape, a slot an
+                        # entry, in float64; one array a depth for the
+                        # whole job (127 MB of fresh pages a level were
+                        # a tenth of a round at 968 features, and its
+                        # noise), so a slot that holds no node holds
+                        # what an earlier round left there
+                        hists = level_of.get(depth)
+                        if hists is None:
+                            hists = level_of[depth] = np.zeros(
+                                (len(slots),) + built.shape[1:])
                         for pos, s in enumerate(order):
                             if s < 0:
                                 continue
-                            hists[s] = np.asarray(built[pos], np.float64)
+                            hists[s] = built[pos]
                             if depth:
                                 # the sum over ranks is linear: this IS
                                 # the sibling's reduced histogram
-                                hists[s ^ 1] = above[s >> 1] - hists[s]
-                        build = [-1] * len(slots)
-                        for s in sorted(hists):
+                                np.subtract(above[s >> 1], hists[s],
+                                            out=hists[s ^ 1])
+                        # every slot is scanned, node or not: a round's
+                        # host work is then a full tree's whatever the
+                        # tree, as the device's is (static shapes), and
+                        # a job's rounds take the same time
+                        best = _scan_level(hists, reg_lambda,
+                                           min_child_weight, has_missing)
+                        build, default_left = [-1] * len(slots), 0
+                        for s, nid in enumerate(slots):
+                            if nid < 0:
+                                continue
                             side = _split(
-                                tree[slots[s]], tree, hists[s], reg_lambda,
-                                min_child_weight, has_missing)
+                                tree[nid], tree, hists[s], reg_lambda,
+                                min_child_weight, has_missing, best[s])
                             if side is not None:
                                 build[s] = 2 * s + side
+                                default_left += tree[nid].default_left
                         tab, slots = _route(tree, slots, leaves)
                         above = hists
                     with program.span("gbdt.partition"):
@@ -595,11 +674,13 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
                 program.count("gbdt.levels_chunked", int(calls > 1))
+                program.count("gbdt.kernel_calls", calls)
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
-                program.count("gbdt.hists_derived", len(hists) - live)
+                program.count("gbdt.hists_derived", live if depth else 0)
                 program.count("gbdt.nodes_split",
                               sum(s >= 0 for s in build))
+                program.count("gbdt.splits_default_left", default_left)
             # the nodes at the depth limit are leaves, with the weights
             # their parents' histograms gave them
             with program.span("gbdt.leaf"):

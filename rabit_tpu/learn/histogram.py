@@ -44,32 +44,77 @@ DEFAULT_ROW_BLOCK = 8192
 DEFAULT_FEAT_BLOCK = 8
 
 
+# columns sorted together by :func:`quantile_cuts`: a cache line of a
+# row-major float32 sample, so that the transpose reads each line once
+CUT_BATCH_COLS = 16
+_CUT_BLOCK_ROWS = 4096
+
+
 def quantile_cuts(values: np.ndarray, nbin: int) -> np.ndarray:
     """Per-column quantile cut points, shape (f, nbin - 1) — the
     host-side analogue of XGBoost's quantile sketch (per-shard; callers
     needing globally consistent cuts broadcast/allreduce them).
 
     NaN entries are missing values: cuts come from the present entries
-    only (``nanquantile``) — plain ``quantile`` would poison a whole
-    column's cuts to NaN.  An all-NaN column gets zero cuts (every
-    present-at-predict-time value bins to 0; its rows ride the missing
-    bin anyway)."""
-    qs = np.linspace(0, 1, nbin + 1)[1:-1]
-    with np.errstate(all="ignore"):
-        import warnings
+    only.  An all-NaN column gets zero cuts (every present-at-predict-
+    time value bins to 0; its rows are absent anyway).
 
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", RuntimeWarning)
-            cuts = np.nanquantile(values, qs, axis=0).T
-    return np.nan_to_num(cuts, nan=0.0).astype(np.float32)
+    Equal to ``np.nanquantile(values, qs, axis=0)`` bit for bit, which
+    walks the columns one at a time (2 s for 28 columns of 2^20 rows, a
+    minute for a thousand): here a batch of columns is transposed and
+    sorted once (NaNs sort last), the present count a column places the
+    quantiles, and the interpolation is numpy's own arithmetic
+    (``linear``: float32 neighbours, float64 weight), batches on a few
+    threads."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    values = np.asarray(values, np.float32)
+    m, f = values.shape
+    qs = np.linspace(0, 1, nbin + 1)[1:-1]
+    cuts = np.zeros((f, nbin - 1), np.float32)
+    if m == 0:
+        return cuts
+
+    def batch(j0: int) -> None:
+        j1 = min(f, j0 + CUT_BATCH_COLS)
+        cols = np.empty((j1 - j0, m), np.float32)
+        for r in range(0, m, _CUT_BLOCK_ROWS):
+            cols[:, r:r + _CUT_BLOCK_ROWS] = values[
+                r:r + _CUT_BLOCK_ROWS, j0:j1].T
+        cols.sort(axis=1)                                  # NaNs last
+        cnt = m - np.count_nonzero(np.isnan(cols), axis=1)
+        last = np.maximum(cnt - 1, 0)[:, None]
+        virtual = last * qs[None, :]                       # float64
+        # a quantile at or past the last present value takes it whole
+        # (numpy: both neighbours the last entry, weight virtual + 1)
+        above, below = virtual >= last, np.floor(virtual)
+        lo = np.where(above, last, below).astype(np.intp)
+        gamma = virtual - np.where(above, -1.0, below)
+        rows = np.arange(j1 - j0)[:, None]
+        a = cols[rows, lo]
+        b = cols[rows, np.minimum(lo + 1, last)]
+        diff = b - a                                       # float32
+        out = np.where(gamma >= 0.5, b - diff * (1 - gamma),
+                       a + diff * gamma)
+        out[cnt == 0] = 0.0                                # all absent
+        cuts[j0:j1] = out
+
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        list(pool.map(batch, range(0, f, CUT_BATCH_COLS)))
+    return cuts
 
 
 def apply_cuts(values: np.ndarray, cuts: np.ndarray) -> np.ndarray:
     """Bin raw feature values with quantile cuts → int32 in [0, nbin);
-    NaN (missing) values map to the dedicated bin ``nbin`` one past the
-    regular range, so histogram builders can tally missing-row gradient
-    mass per feature and the booster can learn a per-split default
-    direction (XGBoost's sparsity-aware split semantics)."""
+    a NaN (missing) value takes the code ``nbin``, one past the regular
+    range.  The code marks the entry as absent and is no bin: every
+    histogram builder here has ``nbin`` slots and adds such an entry to
+    none of them (XGBoost's layout).  A feature's missing mass at a
+    node is the node's total less the feature's own bins
+    (:func:`missing_mass`), from which the booster learns a per-split
+    default direction (XGBoost's sparsity-aware split semantics); the
+    row move and ``predict`` read the code to send the row that way."""
     n, f = values.shape
     bins = np.empty((n, f), np.int32)
     for j in range(f):
@@ -80,7 +125,12 @@ def apply_cuts(values: np.ndarray, cuts: np.ndarray) -> np.ndarray:
     return bins
 
 
+# a chunk of float rows on its way to the device: at most this many
+# rows and this many bytes, so that a wide shard (968 columns: 4 GB a
+# 2^20 rows, its transpose another) crosses in pieces the device holds
+# beside the bins, and a narrow one keeps its 2^20 rows
 STAGE_CHUNK_ROWS = 1 << 20
+STAGE_CHUNK_BYTES = 1 << 28
 
 
 def staged_features(f: int, nbin: int) -> int:
@@ -101,11 +151,11 @@ def _bin_program(n: int, c: int, f: int, fpad: int, ncut: int):
         import jax
         import jax.numpy as jnp
 
-        def gbdt_bin(bins_t, seen, vals, cuts_t, lo):
+        def gbdt_bin(bins_t, seen, absent, vals, cuts_t, lo):
             with jax.named_scope("gbdt/bin"):
                 v = vals.T                                   # (f, c)
                 # cuts <= value, counted: searchsorted(side="right");
-                # a NaN is below every cut and takes the missing bin
+                # a NaN is below every cut and takes the missing code
                 b = jnp.sum(cuts_t[:, :, None] <= v[None, :, :], axis=0,
                             dtype=jnp.int32)
                 nan = jnp.isnan(v)
@@ -113,14 +163,15 @@ def _bin_program(n: int, c: int, f: int, fpad: int, ncut: int):
                 b = jnp.pad(b, ((0, fpad - f), (0, 0)))
                 seen = jnp.maximum(seen, jnp.stack(
                     [jnp.any(nan).astype(jnp.int32), jnp.max(b)]))
+                absent = absent + jnp.sum(nan, axis=1, dtype=jnp.int32)
                 return jax.lax.dynamic_update_slice(
-                    bins_t, b, (jnp.int32(0), lo)), seen
+                    bins_t, b, (jnp.int32(0), lo)), seen, absent
 
         sds = jax.ShapeDtypeStruct
-        fn = jax.jit(gbdt_bin, donate_argnums=(0, 1)).lower(
+        fn = jax.jit(gbdt_bin, donate_argnums=(0, 1, 2)).lower(
             sds((fpad, n), jnp.int32), sds((2,), jnp.int32),
-            sds((c, f), jnp.float32), sds((ncut, f), jnp.float32),
-            sds((), jnp.int32)).compile()
+            sds((f,), jnp.int32), sds((c, f), jnp.float32),
+            sds((ncut, f), jnp.float32), sds((), jnp.int32)).compile()
         _CACHE[key] = fn
     return fn
 
@@ -132,8 +183,11 @@ def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
     dropped) and a (2,) int32 device array ``[any NaN, largest bin]``.
 
     Equal to :func:`apply_cuts` bit for bit, NaN included.  The float
-    values cross to the device a chunk of rows at a time and only the
-    bins stay; nothing of length n is made on the host."""
+    values cross to the device a chunk of rows at a time (at most
+    ``STAGE_CHUNK_ROWS`` rows and ``STAGE_CHUNK_BYTES`` bytes) and only
+    the bins stay; nothing of length n is made on the host.  Counts the
+    staged entries and the absent ones among them (``gbdt.entries``,
+    ``gbdt.entries_missing``), a feature at a time on the device."""
     import jax
     import jax.numpy as jnp
 
@@ -144,7 +198,8 @@ def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
     cuts_t = jnp.asarray(np.ascontiguousarray(cuts.T, np.float32))
     bins_t = jnp.zeros((fpad, n), jnp.int32)
     seen = jnp.zeros((2,), jnp.int32)
-    chunk = min(n, STAGE_CHUNK_ROWS)
+    absent = jnp.zeros((f,), jnp.int32)
+    chunk = max(1, min(n, STAGE_CHUNK_ROWS, STAGE_CHUNK_BYTES // (4 * f)))
     for lo in range(0, n, chunk):
         c = min(chunk, n - lo)
         with program.span("stage.compile"):
@@ -153,7 +208,11 @@ def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
             vals = jax.device_put(
                 np.ascontiguousarray(values[lo:lo + c], np.float32))
         with program.span("stage.bin"):
-            bins_t, seen = fn(bins_t, seen, vals, cuts_t, np.int32(lo))
+            bins_t, seen, absent = fn(bins_t, seen, absent, vals, cuts_t,
+                                      np.int32(lo))
+    program.count("gbdt.entries", n * f)
+    program.count("gbdt.entries_missing",
+                  int(np.asarray(absent).sum(dtype=np.int64)))
     return bins_t, seen
 
 
@@ -188,6 +247,38 @@ def _level_xla(bins_t, gh, node, nslots: int, nbin: int, block: int = 4096):
     return acc
 
 
+def slot_totals(gh, slot, nslots: int, dtype):
+    """``(nslots, 2)`` float32, traceable: the (grad, hess) sums of the
+    rows at each level slot, the weights rounded to ``dtype`` first as
+    the histogram builder rounds its operand, so that a slot's total
+    and its features' bins are sums of the same numbers and their
+    difference is the mass of the rows absent from the feature."""
+    import jax
+    import jax.numpy as jnp
+
+    # lax.reduce_precision, not a cast there and back: the TPU pipeline
+    # drops an f32 -> bf16 -> f32 round trip as excess precision, and
+    # the totals are then those of other numbers than the bins' (found
+    # on the chip, PR 33: every feature read a missing mass of 2^-9 of
+    # the node)
+    grid = jnp.finfo(jnp.dtype(dtype))
+    w = jax.lax.reduce_precision(gh.astype(jnp.float32), grid.nexp,
+                                 grid.nmant)
+    at = slot[None, :] == jnp.arange(nslots, dtype=jnp.int32)[:, None]
+    return jnp.sum(jnp.where(at[:, None, :], w[None], 0.0), axis=-1)
+
+
+def with_totals(hists, totals):
+    """The level's histograms with one more feature row a slot, whose
+    bin 0 holds the slot's (grad, hess) totals: they ride the level's
+    one allreduce and fetch, and a sibling's come out of the same
+    parent-minus-built subtraction as its bins."""
+    import jax.numpy as jnp
+
+    row = jnp.zeros(hists.shape[:1] + (1,) + hists.shape[2:], hists.dtype)
+    return jnp.concatenate([hists, row.at[:, 0, 0].set(totals)], axis=1)
+
+
 def slots_per_call(nbin: int, f: int) -> int:
     """Level slots one kernel call of :func:`level_hist` builds: half
     the kernel's widest worthwhile call (a slot is a grad and a hess
@@ -207,14 +298,18 @@ def level_calls(nslots: int, f: int, nbin: int,
 
 
 def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
-               use_pallas: bool | None = None, compute_dtype=None):
+               use_pallas: bool | None = None, compute_dtype=None,
+               totals: bool = False):
     """``(nslots, f, nbin, 2)`` histograms of one tree level, traceable:
     slot ``s`` holds the (grad, hess) sums of the rows whose ``node`` is
     ``s``; a slot with no row reads zeros, a row at no slot (node < 0)
-    is in no histogram.  ``bins_t`` is the staged ``(fpad, n)`` array,
-    ``gh`` the ``(2, n)`` float32 weights.  The fused kernel folds the
-    node masks into the weights a row block at a time (12 bytes a row
-    read, no ``(2 * nslots, n)`` matrix in HBM).
+    is in no histogram, and an absent entry (code ``nbin``) is in no
+    bin.  ``bins_t`` is the staged ``(fpad, n)`` array, ``gh`` the
+    ``(2, n)`` float32 weights.  The fused kernel folds the node masks
+    into the weights a row block at a time (12 bytes a row read, no
+    ``(2 * nslots, n)`` matrix in HBM).  With ``totals`` (a job whose
+    rows have absent entries) the result is ``(nslots, f + 1, nbin,
+    2)``: :func:`with_totals` of each slot's :func:`slot_totals`.
 
     A level of more slots than :func:`slots_per_call` is built by
     several kernel calls inside the same program, call ``k`` over the
@@ -223,33 +318,67 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     sees the same rows in the same order either way, so the histograms
     are those of one wide call bit for bit, at the kernel's time a
     channel of a narrow one."""
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if not use_pallas:
-        return _level_xla(bins_t, gh, node, nslots, nbin)[:, :f]
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
 
-    kw = {} if compute_dtype is None else {"compute_dtype": compute_dtype}
-    per_call = slots_per_call(nbin, bins_t.shape[0])
-    outs = [hk.hist_fused_multi(bins_t, gh, nbin,
-                                node_of_row=node - lo if lo else node,
-                                nslots=min(per_call, nslots - lo), **kw)
-            for lo in range(0, nslots, per_call)]
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-    # (2 * nslots, fpad, nbin), slot-major
-    return out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    if use_pallas:
+        cdt = compute_dtype or hk.DEFAULT_COMPUTE_DTYPE
+        per_call = slots_per_call(nbin, bins_t.shape[0])
+        outs = [hk.hist_fused_multi(bins_t, gh, nbin,
+                                    node_of_row=node - lo if lo else node,
+                                    nslots=min(per_call, nslots - lo),
+                                    compute_dtype=cdt)
+                for lo in range(0, nslots, per_call)]
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+        # (2 * nslots, fpad, nbin), slot-major
+        out = out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
+    else:
+        cdt = jnp.float32
+        out = _level_xla(bins_t, gh, node, nslots, nbin)[:, :f]
+    if totals:
+        out = with_totals(out, slot_totals(gh, node, nslots, cdt))
+    return out
+
+
+# a missing mass whose hessian part is within this share of the node's
+# total reads as none: where nobody is absent, total less bins is the
+# float32 accumulation's residue (1e-7 of the total, of either sign),
+# and the direction that residue favours would differ from arm to arm
+MISSING_MASS_FLOOR = 1e-5
+
+
+def missing_mass(hist: np.ndarray, total) -> np.ndarray:
+    """``(f, 2)`` float64: the (grad, hess) sums of a node's rows that
+    are absent from each feature, as the node's ``total`` (2,) less the
+    feature's own bins of its ``(f, nbin, 2)`` histogram.  XGBoost's
+    layout: an absent entry is added to no bin, so its mass is kept
+    nowhere and costs no histogram slot.  A mass under
+    ``MISSING_MASS_FLOOR`` of the node's hessian total is no mass."""
+    return _mass(np.asarray(hist, np.float64).sum(axis=1),
+                 np.asarray(total, np.float64))
+
+
+def _mass(present: np.ndarray, total: np.ndarray) -> np.ndarray:
+    """:func:`missing_mass` from the features' own sums (f, 2)."""
+    mass = total - present
+    mass[np.abs(mass[:, 1]) <= MISSING_MASS_FLOOR * abs(total[1])] = 0.0
+    return mass
 
 
 def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
                      min_child_weight: float | None = None,
-                     has_missing: bool = False):
+                     total=None):
     """``(gain, default_left)`` of every (feature, cut) of a (f, nbin, 2)
     histogram: the XGBoost structure score, left = bins 0..cut.  With
-    ``has_missing`` the LAST bin holds the missing-value rows, the gain
-    is the better of sending them left and right and ``default_left``
-    says which won (None without).
+    ``total``, the node's (grad, hess) sums over all of its rows, a
+    feature's bins may fall short of them by the rows absent from it
+    (:func:`missing_mass`): the gain is then the better of sending
+    those rows left and right and ``default_left`` says which won
+    (ties: left).  Without, every row is in a bin of every feature, the
+    totals are the bins' own and ``default_left`` is None.
 
     A candidate that leaves a side's hessian sum under
     ``min_child_weight`` is not eligible and reads -inf (XGBoost's
@@ -257,45 +386,62 @@ def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
     minus sibling reads a few ulp of either sign where no row fell, an
     empty side near ``-reg_lambda`` is then an unbounded gain, and the
     argmax must not see it.  ``None`` scores every candidate."""
-    # float64, and the totals are the cumulative sums' own last entries:
-    # in float32 a node of millions of rows has sums with an ulp of 0.5,
-    # a total summed in another order than the prefix can then leave an
-    # empty right side at hr = -1, and hr + lambda = 0 is an infinite gain
-    hist = np.asarray(hist, np.float64)
-    reg = hist[:, :-1] if has_missing else hist
-    gc, hc = np.cumsum(reg[:, :, 0], axis=1), np.cumsum(reg[:, :, 1], axis=1)
-    gl, hl = gc[:, :-1], hc[:, :-1]
-    gt, ht = gc[:, -1:], hc[:, -1:]
-    if has_missing:
-        gm, hm = hist[:, -1:, 0], hist[:, -1:, 1]
+    # float64, and without ``total`` the totals are the cumulative sums'
+    # own last entries: in float32 a node of millions of rows has sums
+    # with an ulp of 0.5, a total summed in another order than the
+    # prefix can then leave an empty right side at hr = -1, and
+    # hr + lambda = 0 is an infinite gain
+    sums = np.cumsum(np.asarray(hist, np.float64), axis=1)
+    gl, hl = sums[:, :-1, 0], sums[:, :-1, 1]
+    gt, ht = sums[:, -1:, 0], sums[:, -1:, 1]
+    if total is not None:
+        mass = _mass(sums[:, -1], np.asarray(total, np.float64))
+        gm, hm = mass[:, :1], mass[:, 1:]
         gt, ht = gt + gm, ht + hm
     parent = gt * gt / (ht + reg_lambda)
 
     def score(gl_, hl_):
+        # a dozen passes over a (f, nbin - 1) grid, a node: in place
         gr_, hr_ = gt - gl_, ht - hl_
-        with np.errstate(divide="ignore", invalid="ignore"):
-            gain = (gl_ * gl_ / (hl_ + reg_lambda)
-                    + gr_ * gr_ / (hr_ + reg_lambda) - parent)
-        if min_child_weight is None:
-            return gain
-        return np.where((hl_ >= min_child_weight) & (hr_ >= min_child_weight),
-                        gain, -np.inf)
+        if min_child_weight is not None:
+            barred = (hl_ < min_child_weight) | (hr_ < min_child_weight)
+        gain = gl_ * gl_
+        gain /= hl_ + reg_lambda
+        gr_ *= gr_
+        hr_ += reg_lambda
+        gr_ /= hr_
+        gain += gr_
+        gain -= parent
+        if min_child_weight is not None:
+            gain[barred] = -np.inf
+        return gain
 
-    gain_right = score(gl, hl)             # missing rows, if any, go right
-    if not has_missing:
-        return gain_right, None
-    gain_left = score(gl + gm, hl + hm)    # missing rows go left
+    with np.errstate(divide="ignore", invalid="ignore"):
+        gain_right = score(gl, hl)         # absent rows, if any, go right
+        if total is None:
+            return gain_right, None
+        gain_left = score(gl + gm, hl + hm)    # absent rows go left
     return np.maximum(gain_left, gain_right), gain_left >= gain_right
 
 
-def split_gain_missing(hist: np.ndarray, reg_lambda: float = 1.0):
-    """Sparsity-aware split gain: the LAST bin of ``hist`` (f, nbin, 2)
-    holds the missing-value rows.  For every (feature, cut) the gain is
-    evaluated with the missing mass sent left and sent right; returns
-    ``(gain, default_left)`` where gain is the better of the two and
-    default_left says which direction won (XGBoost's learned default
-    direction, one bool per candidate split)."""
-    return split_candidates(hist, reg_lambda, has_missing=True)
+def best_split(hist: np.ndarray, reg_lambda: float,
+               min_child_weight: float | None, total=None):
+    """``(gain, feature, cut, default_left)`` of the best candidate of
+    :func:`split_candidates` (the first of equals, feature-major)."""
+    gain, left = split_candidates(hist, reg_lambda, min_child_weight, total)
+    j, t = np.unravel_index(int(gain.argmax()), gain.shape)
+    return (float(gain[j, t]), int(j), int(t),
+            True if left is None else bool(left[j, t]))
+
+
+def split_gain_missing(hist: np.ndarray, total, reg_lambda: float = 1.0):
+    """Sparsity-aware split gain of a (f, nbin, 2) histogram and the
+    node's (grad, hess) ``total``.  For every (feature, cut) the gain is
+    evaluated with the feature's missing mass sent left and sent right;
+    returns ``(gain, default_left)`` where gain is the better of the
+    two and default_left says which direction won (XGBoost's learned
+    default direction, one bool per candidate split)."""
+    return split_candidates(hist, reg_lambda, total=total)
 
 
 def quantize(values: np.ndarray, nbin: int):
@@ -384,7 +530,7 @@ def build_local(bins, grad, hess, nbin: int,
 
 def build_level_local(bins, grad, hess, node_of_row, node_ids,
                       nbin: int, bins_t=None, use_pallas: bool | None = None,
-                      compute_dtype=None):
+                      compute_dtype=None, totals: bool = False):
     """(m, f, nbin, 2) per-node histograms for one tree level.
 
     Level-wise boosting needs one histogram per live node; building
@@ -398,7 +544,8 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
     makes of a level that wide.
     ``bins_t`` optionally supplies the resident transposed (f, n)
     device array so the transpose isn't redone per level.  Off-TPU,
-    falls back to the XLA builder per node.
+    falls back to the XLA builder per node.  ``totals`` as
+    :func:`level_hist` has it: (m, f + 1, nbin, 2).
     """
     import jax.numpy as jnp
 
@@ -408,22 +555,25 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
     g = jnp.asarray(grad)
     h = jnp.asarray(hess)
     m = len(node_ids)
+    # node id -> position in node_ids, -1 for a row of another node
+    ids = np.asarray(node_ids, np.int64)
+    lut = np.full(int(ids.max(initial=0)) + 2, -1, np.int32)
+    lut[ids] = np.arange(m, dtype=np.int32)
+    slot = jnp.asarray(lut)[jnp.clip(nor, -1, len(lut) - 1)]
+    gh = jnp.stack([g, h]).astype(jnp.float32)
     if use_pallas:
         if bins_t is None:
             bins_t = jnp.asarray(bins).T
-        # node id -> position in node_ids, -1 for a row of another node
-        ids = np.asarray(node_ids, np.int64)
-        lut = np.full(int(ids.max(initial=0)) + 2, -1, np.int32)
-        lut[ids] = np.arange(m, dtype=np.int32)
-        slot = jnp.asarray(lut)[jnp.clip(nor, -1, len(lut) - 1)]
-        gh = jnp.stack([g, h]).astype(jnp.float32)
         return level_hist(bins_t, gh, slot, m, bins_t.shape[0], nbin,
-                          use_pallas=True, compute_dtype=compute_dtype)
+                          use_pallas=True, compute_dtype=compute_dtype,
+                          totals=totals)
     g_np, h_np, nor_np = np.asarray(g), np.asarray(h), np.asarray(nor)
     parts = [build_local(bins, g_np * (nor_np == v), h_np * (nor_np == v),
                          nbin, use_pallas=False)
              for v in np.asarray(node_ids)]
-    return jnp.stack([jnp.asarray(p) for p in parts])
+    out = jnp.stack([jnp.asarray(p) for p in parts])
+    return with_totals(out, slot_totals(gh, slot, m, jnp.float32)) \
+        if totals else out
 
 
 def build_level_allreduce(bins, grad, hess, node_of_row, node_ids,

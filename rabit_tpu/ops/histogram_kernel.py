@@ -67,6 +67,12 @@ _MAX_CHANNELS = 64
 # of 16 1.4794 s).  A tree level's channels are a power of two, so 16
 # splits every wider level into equal calls of one shape.
 _LINE_CHANNELS = 16
+# feature groups up to which the kernel's body holds a copy a group (28
+# to 64 features: the shapes measured above); past it the body loops
+_UNROLL_GROUPS = 8
+# the weight operand's type where a caller names none (one-hots are
+# exact in it; every sum is accumulated in float32)
+DEFAULT_COMPUTE_DTYPE = jnp.bfloat16
 
 
 def _round_up(v: int, m: int) -> int:
@@ -100,6 +106,14 @@ def plan(nbin: int, f: int):
     ``hi*lo`` is ``nbin`` padded to a power of two with ``hi >= lo``;
     ``fpg = 128 // lo`` features share one matmul so the N dimension
     fills 128 lanes exactly.
+
+    ``nbin`` counts the bins of present values only, with and without
+    missing values in the data: an absent entry carries the code
+    ``nbin`` (``learn.histogram.apply_cuts``), which either matches no
+    one-hot row (256 under the 16 x 16 plan: ``256 >> 4`` is no ``hi``
+    class) or lands in the power-of-two padding that the caller slices
+    off, so it is added to no bin and costs no slot.  A 257th slot
+    would pad to 512 and double every product of the kernel.
     """
     nbp = _next_pow2(max(nbin, 4))
     bits = nbp.bit_length() - 1
@@ -139,8 +153,11 @@ def _hist_kernel(bins_t_ref, w_ref, *rest,
     def _():
         out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    for grp in range(ngroups):
-        bt = bins_t_ref[grp * fpg:(grp + 1) * fpg, :]        # (fpg, block)
+    def group(grp, carry=None):
+        start = grp * fpg
+        if not isinstance(grp, int):
+            start = pl.multiple_of(start, fpg)
+        bt = bins_t_ref[pl.ds(start, fpg), :]                # (fpg, block)
         bh = lax.shift_right_logical(bt, lo_shift)
         bl = lax.bitwise_and(bt, lo_mask)
         # one-hots built once per group in (class, row) layout, shared
@@ -156,6 +173,16 @@ def _hist_kernel(bins_t_ref, w_ref, *rest,
             out_ref[grp, c] += lax.dot_general(
                 a * row, b, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32, precision=prec)
+        return carry
+
+    if ngroups <= _UNROLL_GROUPS:
+        for grp in range(ngroups):
+            group(grp)
+    else:
+        # a wide shard (121 groups at 968 features): one body, looped.
+        # Unrolled, each of a boosting job's 13 kernel instances took
+        # the chip's compiler half a minute and the lowering seconds
+        lax.fori_loop(0, ngroups, group, None)
 
 
 @functools.partial(
@@ -195,10 +222,12 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
     npad = _round_up(n, block)
     cdt = jnp.dtype(compute_dtype)
 
-    # no copy for input staged at (fpad, n) int32 with n a multiple of
-    # the block: zero-width pads and same-type casts return their input
-    bt = jnp.pad(bins_t.astype(jnp.int32),
-                 ((0, fpad - f), (0, npad - n)))
+    # no copy for input staged at (fpad, n) int32: zero-width pads and
+    # same-type casts return their input.  The rows are not padded to
+    # the block: the last block of a ragged n reads past the array, and
+    # whatever it finds there meets a weight of 0 and a node of -1,
+    # which the (small) operands below are padded with
+    bt = jnp.pad(bins_t.astype(jnp.int32), ((0, fpad - f), (0, 0)))
     operands = [bt, jnp.pad(weights.astype(cdt), ((0, 0), (0, npad - n)))]
     in_specs = [
         pl.BlockSpec((fpad, block), lambda i: (0, i),
@@ -248,7 +277,7 @@ def default_block(n: int) -> int:
 
 def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                      interpret: bool | None = None,
-                     compute_dtype=jnp.bfloat16,
+                     compute_dtype=DEFAULT_COMPUTE_DTYPE,
                      plan_override: tuple | None = None,
                      node_of_row=None, nslots: int = 0) -> jax.Array:
     """(nw, f, nbin) histograms of ``nw`` weight channels in one pass.
@@ -285,7 +314,7 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
 
 def hist_fused(bins, grad, hess, nbin: int, block: int | None = None,
                interpret: bool | None = None,
-               compute_dtype=jnp.bfloat16) -> jax.Array:
+               compute_dtype=DEFAULT_COMPUTE_DTYPE) -> jax.Array:
     """(f, nbin, 2) gradient/hessian histogram of binned features.
 
     ``bins`` is (n, f) int32 in [0, nbin); ``grad``/``hess`` are (n,)

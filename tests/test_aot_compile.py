@@ -115,6 +115,27 @@ def _hist_level_chunked(nslots, topo):
     return compiled
 
 
+def _hist_level_wide(topo):
+    from rabit_tpu.learn import histogram
+
+    # the wide boosting cell's widest kernel call: 968 features staged
+    # as (968, n) int32 (121 groups of 8), 256 bins with absent entries
+    # coded 256, 3 slots (6 channels: what the VMEM accumulator holds at
+    # this width) and the slots' totals; 1,183,747 rows are no multiple
+    # of the kernel's row block, and the bins are still not copied
+    n, f = 1183747, 968
+    assert histogram.slots_per_call(256, f) == 3
+    fn = jax.jit(lambda bins, gh, node: histogram.level_hist(
+        bins, gh, node, 3, f, 256, use_pallas=True, totals=True))
+    compiled = fn.lower(*_one_chip(topo, ((f, n), jnp.int32),
+                                   ((2, n), jnp.float32),
+                                   ((n,), jnp.int32))).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes <= 64 << 20, m
+    assert m.output_size_in_bytes == 3 * (f + 1) * 256 * 2 * 4
+    return compiled
+
+
 def _kmeans_ell_chain(topo):
     from rabit_tpu.learn import kmeans
 
@@ -228,8 +249,8 @@ def _ring(nbytes, topo):
 
 @pytest.mark.parametrize("build", [
     _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
-    functools.partial(_hist_level_chunked, 16), _kmeans_ell_chain,
-    _dense16_loop,
+    functools.partial(_hist_level_chunked, 16), _hist_level_wide,
+    _kmeans_ell_chain, _dense16_loop,
     functools.partial(_lbfgs_product, "margin"),
     functools.partial(_lbfgs_product, "grad"),
     _mesh_kmeans_step,
@@ -239,7 +260,9 @@ def _ring(nbytes, topo):
     functools.partial(_ring, 64 << 20),
 ], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-8x64x256x262k",
         "hist_fused_multi-32slots-28x256x33.6M",
-        "hist_fused_multi-16slots-28x256x33.6M", "kmeans_ell_chain-d512-4M", "dense16_loop-24M",
+        "hist_fused_multi-16slots-28x256x33.6M",
+        "hist_fused_multi-3slots-968x256x1.18M-ragged",
+        "kmeans_ell_chain-d512-4M", "dense16_loop-24M",
         "lbfgs_margin-16.8Mx39-1M", "lbfgs_grad-16.8Mx39-1M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])
 def test_compiles_for_v5e(topo, build):

@@ -417,7 +417,6 @@ def test_every_histogram_the_loop_used_equals_a_pass_over_the_nodes_rows(
 
     bins = boosting.apply_cuts(X, model.cuts)
     missing_bin = model.cuts.shape[1] + 1
-    nslot = missing_bin + int(model.has_missing)
     assert model.has_missing == missing
     checked = 0
     for r, tree in enumerate(model.trees):
@@ -434,10 +433,19 @@ def test_every_histogram_the_loop_used_equals_a_pass_over_the_nodes_rows(
         assert sorted(used) == sorted(nid for nid in rows if depth[nid] < 4)
         tol = 8 * np.finfo(np.float32).eps * np.abs(used[0]).max()
         for nid, hist in used.items():
+            # with missing values the node's totals ride as one more
+            # feature row, and an absent entry is in no bin
             want = np.asarray(histogram.build_level_local(
-                bins, grad, hess, rows[nid].astype(np.int32), [1], nslot,
-                use_pallas=False))[0]
+                bins, grad, hess, rows[nid].astype(np.int32), [1], 16,
+                use_pallas=False, totals=missing))[0]
+            assert want.shape == (X.shape[1] + missing, 16, 2)
             np.testing.assert_allclose(hist, want, rtol=0, atol=tol)
+            if missing:
+                np.testing.assert_allclose(
+                    hist[-1, 0], [grad[rows[nid]].sum(dtype=np.float64),
+                                  hess[rows[nid]].sum(dtype=np.float64)],
+                    rtol=0, atol=tol)
+                assert not hist[-1, 1:].any()
             checked += 1
     assert checked >= 2 * 15
 
@@ -494,16 +502,21 @@ def test_empty_side_of_a_derived_histogram_does_not_win_the_argmax(
     so that the node stayed a leaf beside feature 1's good split."""
     from rabit_tpu.learn import histogram
 
-    lam, nbin = 1.0, 4 + int(has_missing)
-    hist = np.zeros((2, nbin, 2))
+    lam = 1.0
+    hist = np.zeros((2, 4, 2))
     hist[0, :3] = [(-3.0, 10.0), (1.0, 10.0), (2.0, 11.0)]
     hist[0, 3] = (1e-7, -lam)
     hist[1, :4] = [(-6.0, 8.0), (-5.0, 7.0), (6.0, 8.0), (5.0 + 1e-7, 7.0)]
-    unmasked = (histogram.split_gain_missing(hist, lam)[0] if has_missing
-                else histogram.split_gain(hist, lam))
+    # nobody is absent: the node's totals are either feature's bins
+    total = hist[0].sum(axis=0) if has_missing else None
+    unmasked = (histogram.split_gain_missing(hist, total, lam)[0]
+                if has_missing else histogram.split_gain(hist, lam))
     assert not unmasked[0, 2] < np.inf           # the trap
-    gain, _ = histogram.split_candidates(hist, lam, 1e-3, has_missing)
+    gain, _ = histogram.split_candidates(hist, lam, 1e-3, total)
     assert gain[0, 2] == -np.inf and np.isfinite(gain[1]).all()
+    if has_missing:                  # as the loop hands it to _split
+        hist = np.concatenate([hist, np.zeros((1, 4, 2))])
+        hist[-1, 0] = total
     tree = [boosting.TreeNode()]
     side = boosting._split(tree[0], tree, hist, lam, 1e-3, has_missing)
     assert (tree[0].feature, tree[0].bin_threshold) == (1, 1)
@@ -586,3 +599,156 @@ def test_built_child_is_the_lighter_and_the_same_on_every_rank(tmp_path):
                   extra_env={"BOOST_SUBSAMPLE": "0.8", "BOOST_MIN_ACC": "0.5",
                              "BOOST_CHECK_BUILT": "1"})
     assert code == 0
+
+
+# ----------------------------------------------------------------------
+# rows absent station by station: the forest is the one the plain
+# reference of the benchmark would grow
+# ----------------------------------------------------------------------
+def _station_rows(n=4000, seed=5):
+    """Nine measurements at three stations; a part skips a station
+    whole.  The label reads two measurements and whether the part went
+    through the rarest station, so the default direction carries
+    signal."""
+    rng = np.random.default_rng(seed)
+    X = rng.uniform(-1, 1, (n, 9)).astype(np.float32)
+    X[:, 4] = np.round(X[:, 4] * 2) / 2               # five levels
+    went = rng.random((n, 3)) < np.array([0.9, 0.5, 0.15])
+    X[~went[:, np.repeat(np.arange(3), 3)]] = np.nan
+    z = np.nan_to_num(X)
+    logit = 2.5 * z[:, 0] * z[:, 3] + 1.5 * z[:, 4] + 2.0 * went[:, 2] - 0.8
+    y = (rng.random(n) < 1 / (1 + np.exp(-logit))).astype(np.float32)
+    return X, y
+
+
+@pytest.mark.parametrize("which,kw", [
+    ("host", {"use_pallas": False}),
+    ("device", {"use_pallas": False}),
+    ("device", {"use_pallas": True, "compute_dtype": "float32"}),
+], ids=["host-arm", "xla-arm", "device-arm-kernel-interpreted"])
+def test_station_wise_absent_rows_commit_the_references_forest(arm, which,
+                                                               kw):
+    """``perfbench/reference/gbdt_missing.py`` (float64 numpy, both
+    default directions scored, independent of ``learn/``) replays every
+    committed tree: each split is its best (feature, cut, direction) to
+    1e-6 of the gain, no leaf above the depth limit would be split, and
+    each leaf weight is its sums' to 1e-5 of the leaf's sum of |g|."""
+    import os
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from perfbench.reference import gbdt_missing as refm
+
+    X, y = _station_rows()
+    arm(which)
+    model = boosting.train(X, y, num_round=3, max_depth=4, nbin=32,
+                           min_child_weight=1.0, **kw)
+    assert model.has_missing
+    np.testing.assert_array_equal(model.cuts, refm.quantile_cuts(X, 32))
+    nodes = max(len(t) for t in model.trees)
+    f_int = np.full((3, nodes, 5), -2, np.int32)
+    f_val = np.zeros((3, nodes), np.float32)
+    for t, tree in enumerate(model.trees):
+        for i, n in enumerate(tree):
+            f_int[t, i] = (n.feature, n.bin_threshold, n.default_left,
+                           n.left, n.right)
+            f_val[t, i] = n.value
+    got = refm.replay(X, y, model.cuts, f_int, f_val, [0, 1, 2], 32, 4, 0.3,
+                      1.0, 1.0, "float32")
+    assert got["splits"] >= 30 and got["leaves"] == got["splits"] + 3
+    assert got["split_regret"] < 1e-6
+    assert got["unsplit_above_limit"] == 0
+    assert got["leaf_sum_rel_err"] < 1e-5
+    # the direction is learned, and goes both ways
+    assert 0 < got["default_left"] < got["splits"]
+    # absent rows go the committed way in predict too
+    bins = boosting.apply_cuts(X, model.cuts)
+    assert (bins == 32).mean() > 0.4
+    p = model.predict(X)
+    assert np.isfinite(p).all() and ((p > 0.5) == (y > 0.5)).mean() > 0.7
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_counters_of_the_missing_path(arm, which):
+    """``gbdt.kernel_calls`` a level (none on the XLA path, one a level
+    of the kernel here), ``gbdt.splits_default_left`` beside
+    ``gbdt.nodes_split``, the entries staged and the absent among them,
+    and the bytes of histograms fetched: a slot is f + 1 feature rows
+    (the node's totals ride as one) of nbin (grad, hess) float32."""
+    from rabit_tpu.obs import program
+
+    X, y = _station_rows(n=1500)
+    for use_pallas, calls in ((False, 0), (True, 3)):
+        arm(which)
+        before = program.stats()
+        model = boosting.train(X, y, num_round=1, max_depth=3, nbin=16,
+                               use_pallas=use_pallas,
+                               compute_dtype="float32")
+        after = program.stats()
+        got = {k: after.get(k, 0) - before.get(k, 0) for k in (
+            "gbdt.kernel_calls", "gbdt.levels", "gbdt.nodes_split",
+            "gbdt.splits_default_left", "gbdt.entries",
+            "gbdt.entries_missing", "gbdt.hist_bytes_fetched")}
+        tree = model.trees[0]
+        assert got["gbdt.levels"] == 3 and got["gbdt.kernel_calls"] == calls
+        assert got["gbdt.nodes_split"] == 7
+        assert got["gbdt.splits_default_left"] == sum(
+            n.default_left for n in tree if n.feature >= 0)
+        assert 0 < got["gbdt.splits_default_left"] < 7
+        assert got["gbdt.entries"] == X.size
+        assert got["gbdt.entries_missing"] == np.count_nonzero(np.isnan(X))
+        # built slots 1 + 1 + 2
+        assert got["gbdt.hist_bytes_fetched"] == 4 * (9 + 1) * 16 * 2 * 4
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_every_slot_of_a_level_is_scanned_whatever_the_tree(arm, monkeypatch,
+                                                            which):
+    """A round's host work is a full tree's, node or not in a slot (as
+    the device's programs build every slot): 1 + 2 + 4 + 8 scans a
+    depth-4 round, also where the tree stops early; and the forest is
+    the one a scan of the nodes alone commits."""
+    X, y = _station_rows(n=1500)
+    kw = dict(num_round=2, max_depth=4, nbin=16, min_child_weight=40.0,
+              use_pallas=False)
+    arm(which)
+    plain = boosting.train(X, y, **kw)
+    assert max(len(t) for t in plain.trees) < 31       # stops early
+    scans, scan = [], boosting._scan
+
+    def seen_scan(hist, lam, mcw, has_missing):
+        scans.append(hist.any())
+        return scan(hist, lam, mcw, has_missing)
+
+    arm(which)
+    monkeypatch.setattr(boosting, "_scan", seen_scan)
+    model = boosting.train(X, y, **kw)
+    assert _structure(model) == _structure(plain)
+    levels = [sum(1 for n in t if n.feature >= 0) > 0 for t in model.trees]
+    assert all(levels) and len(scans) == 2 * (1 + 2 + 4 + 8)
+    assert 0 < sum(1 for live in scans if not live) < len(scans)
+
+
+@pytest.mark.parametrize("has_missing", [False, True], ids=["dense", "nan"])
+def test_a_level_of_large_slots_is_scanned_on_threads_to_the_same_result(
+        monkeypatch, has_missing):
+    """Slots of a megabyte or more (968 features) are scanned on a few
+    threads, smaller ones (28 features) one after the other: the list
+    is the same, slot for slot."""
+    rng = np.random.default_rng(73)
+    hists = rng.random((8, 40 + has_missing, 32, 2))
+    hists[:, :, :, 0] -= 0.5
+    if has_missing:
+        hists[:, -1] = 0
+        hists[:, -1, 0] = 2 * hists[:, 0].sum(axis=1)
+    assert hists[0].nbytes < boosting._SCAN_PARALLEL_BYTES
+    serial = boosting._scan_level(hists, 1.0, 1.0, has_missing)
+    monkeypatch.setattr(boosting, "_SCAN_PARALLEL_BYTES", 0)
+    threaded = boosting._scan_level(hists, 1.0, 1.0, has_missing)
+    assert boosting._scan_pool is not None
+    assert threaded == serial and len(serial) == 8
+    assert serial == [boosting._scan(h, 1.0, 1.0, has_missing)
+                      for h in hists]
+    # one slot is not worth a thread
+    assert boosting._scan_level(hists[:1], 1.0, 1.0, has_missing) \
+        == serial[:1]

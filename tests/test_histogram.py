@@ -239,8 +239,9 @@ def test_split_gain_of_a_node_of_millions_of_rows():
     gain = histogram.split_gain(hist, 1.0)
     assert np.isfinite(gain).all()
     assert abs(gain[:, 161:]).max() < 1e-6     # nothing on the right
-    gain_m, _left = histogram.split_gain_missing(
-        np.concatenate([hist, hist[:, :1]], axis=1), 1.0)
+    # the first bin's rows again, absent from both features
+    total = hist[0].sum(axis=0, dtype=np.float64) + hist[0, 0]
+    gain_m, _left = histogram.split_gain_missing(hist, total, 1.0)
     assert np.isfinite(gain_m).all()
 
 
@@ -383,3 +384,288 @@ def test_build_level_local_takes_level_hists_chunks():
     exact = np.asarray(histogram.build_level_local(
         bins, grad, hess, node, ids, nbin, use_pallas=False))
     np.testing.assert_allclose(got, exact, rtol=0, atol=0.05)
+
+
+# ----------------------------------------------------------------------
+# absent entries take no histogram slot: XGBoost's layout
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("f", [28, 968], ids=["higgs", "bosch"])
+def test_plan_for_256_bins_is_the_same_with_and_without_missing_values(f):
+    """A 257th slot pads to 512 (32 x 16) and doubles every product of
+    the kernel; the missing code 256 needs none under the 16 x 16 plan,
+    whose ``hi`` classes end at 15."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    hi, lo, fpg, ngroups = hk.plan(256, f)
+    assert (hi, lo, fpg, ngroups) == (16, 16, 8, -(-f // 8))
+    assert hk.plan(257, f)[:2] == (32, 16)            # what it would cost
+    assert 256 >> (lo.bit_length() - 1) >= hi         # matches no class
+    assert histogram.staged_features(f, 256) == 8 * ngroups
+
+
+@pytest.mark.parametrize("kw,nbin", [({"use_pallas": False}, 16),
+                                     ({"use_pallas": True}, 16),
+                                     ({"use_pallas": True}, 7),
+                                     ({"use_pallas": True}, 256)],
+                         ids=["xla", "kernel", "kernel-7-bins", "kernel-256"])
+def test_an_absent_entry_adds_to_no_bin(kw, nbin):
+    """Rows whose code is ``nbin`` (absent) are in no bin of that
+    feature, in every builder, and in every bin they have elsewhere."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(41)
+    n, f, nslots = 700, 3, 2
+    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
+    bins[rng.random((n, f)) < 0.3] = nbin
+    bins[:, 2] = rng.integers(0, nbin, n)             # nobody misses it
+    gh = np.stack([rng.standard_normal(n), rng.random(n)]).astype(np.float32)
+    node = rng.integers(-1, nslots, n).astype(np.int32)
+    got = np.asarray(histogram.level_hist(
+        jnp.asarray(bins.T), jnp.asarray(gh), jnp.asarray(node), nslots, f,
+        nbin, compute_dtype="float32", **kw))
+    assert got.shape == (nslots, f, nbin, 2)
+    for s in range(nslots):
+        at = node == s
+        for j in range(f):
+            have = at & (bins[:, j] < nbin)
+            want = _np_hist(bins[have][:, j:j + 1], gh[0, have], gh[1, have],
+                            nbin)[0]
+            np.testing.assert_allclose(got[s, j], want, rtol=0, atol=2e-4)
+    built = np.asarray(histogram.build_local(
+        bins, gh[0], gh[1], nbin, **kw, **(
+            {"compute_dtype": "float32"} if kw["use_pallas"] else {})))
+    have = bins[:, 0] < nbin
+    np.testing.assert_allclose(
+        built[0], _np_hist(bins[have][:, :1], gh[0, have], gh[1, have],
+                           nbin)[0], rtol=0, atol=2e-4)
+
+
+def _tally_with_a_257th_slot(bins, grad, hess, rows, nbin):
+    """The parent's layout: (f, nbin + 1, 2) float64, the last slot the
+    (grad, hess) sums of the rows absent from the feature."""
+    out = np.zeros((bins.shape[1], nbin + 1, 2))
+    for j in range(bins.shape[1]):
+        for c, w in enumerate((grad, hess)):
+            out[j, :, c] = np.bincount(bins[rows, j], w[rows].astype(
+                np.float64), nbin + 1)
+    return out
+
+
+@pytest.mark.parametrize("which", ["node", "sibling-by-subtraction"])
+def test_missing_mass_by_subtraction_equals_the_tally_of_a_257th_slot(which):
+    """Total less bins against a slot that tallies the absent rows, to
+    float64 rounding: on a node, on a sibling had as parent minus built,
+    and on a feature nobody misses (whose mass reads 0)."""
+    rng = np.random.default_rng(43)
+    n, f, nbin = 5000, 4, 16
+    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
+    bins[rng.random((n, f)) < 0.6] = nbin
+    bins[:, 3] = rng.integers(0, nbin, n)             # nobody misses it
+    grad = rng.standard_normal(n).astype(np.float32)
+    hess = rng.random(n).astype(np.float32)
+    parent = rng.random(n) < 0.7
+    built = parent & (rng.random(n) < 0.3)
+    tally = _tally_with_a_257th_slot(bins, grad, hess, parent, nbin)
+    rows = parent
+    if which == "sibling-by-subtraction":
+        tally = tally - _tally_with_a_257th_slot(bins, grad, hess, built,
+                                                 nbin)
+        rows = parent & ~built
+    # the new layout: the bins alone, and the node's totals
+    hist, total = tally[:, :nbin], tally[0].sum(axis=0)
+    mass = histogram.missing_mass(hist, total)
+    scale = np.abs(grad[rows]).sum() + hess[rows].sum()
+    np.testing.assert_allclose(mass, tally[:, nbin], rtol=0,
+                               atol=1e-13 * scale)
+    assert (mass[3] == 0).all() and not tally[3, nbin].any()
+    assert np.abs(mass[:3, 1]).min() > 0.1 * hess[rows].sum()
+    # and the candidates scored on either layout are the same
+    gain, left = histogram.split_candidates(hist, 1.0, 1.0, total)
+    gm, hm = tally[:, nbin:, 0], tally[:, nbin:, 1]
+    gc, hc = np.cumsum(hist[:, :, 0], 1), np.cumsum(hist[:, :, 1], 1)
+    gt, ht = gc[:, -1:] + gm, hc[:, -1:] + hm
+
+    def score(gl, hl):
+        ok = (hl >= 1.0) & (ht - hl >= 1.0)
+        return np.where(ok, gl * gl / (hl + 1.0) + (gt - gl) ** 2 / (
+            ht - hl + 1.0) - gt * gt / (ht + 1.0), -np.inf)
+
+    want_left = score(gc[:, :-1] + gm, hc[:, :-1] + hm)
+    want_right = score(gc[:, :-1], hc[:, :-1])
+    np.testing.assert_allclose(gain, np.maximum(want_left, want_right),
+                               rtol=1e-9, atol=1e-9)
+    decided = np.abs(want_left - want_right) > 1e-9
+    assert decided.any() and (left == (want_left >= want_right))[decided].all()
+    assert {True, False} == set(left[decided].tolist())
+
+
+def test_a_residue_under_the_floor_is_no_missing_mass():
+    """Where nobody is absent, total less bins is the accumulation's
+    residue, of either sign: it is read as 0 so that both arms (and
+    every rank) take the same default direction, left."""
+    hist = np.zeros((2, 4, 2))
+    hist[0] = [(-3.0, 10.0), (1.0, 10.0), (2.0, 11.0), (0.5, 9.0)]
+    hist[1] = [(-6.0, 12.0), (-5.0, 8.0), (6.0, 12.0), (5.5, 8.0)]
+    total = hist[0].sum(axis=0)
+    for residue in (3e-6, -3e-6):
+        off = total + residue * total[1]
+        assert not histogram.missing_mass(hist, off).any()
+        gain, left = histogram.split_candidates(hist, 1.0, 1e-3, off)
+        assert left.all() and np.isfinite(gain).all()
+    real = total + np.array([0.4, 1.0])               # a few rows' worth
+    np.testing.assert_allclose(histogram.missing_mass(hist, real),
+                               [[0.4, 1.0], [0.4, 1.0]], atol=1e-12)
+
+
+@pytest.mark.parametrize("case", ["dense", "holes", "all-absent-column",
+                                  "one-present", "few-levels"])
+def test_cuts_equal_nanquantile_bit_for_bit(case):
+    import warnings
+
+    rng = np.random.default_rng(47)
+    m, f, nbin = 20000, 37, 256
+    vals = rng.standard_normal((m, f)).astype(np.float32)
+    if case != "dense":
+        vals[rng.random((m, f)) < 0.8] = np.nan
+    if case == "all-absent-column":
+        vals[:, 5] = np.nan
+    if case == "one-present":
+        vals[:, 6] = np.nan
+        vals[123, 6] = 2.5
+    if case == "few-levels":
+        vals = np.round(vals * 2) / 2
+    qs = np.linspace(0, 1, nbin + 1)[1:-1]
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        want = np.nan_to_num(np.nanquantile(vals, qs, axis=0).T,
+                             nan=0.0).astype(np.float32)
+    got = histogram.quantile_cuts(vals, nbin)
+    assert got.dtype == np.float32 and got.shape == (f, nbin - 1)
+    np.testing.assert_array_equal(got, want)
+    if case == "all-absent-column":
+        assert not got[5].any()
+    if case == "few-levels":
+        assert (np.diff(got, axis=1) == 0).any()      # duplicate cuts
+    # a batch boundary and a width that is no multiple of the batch
+    np.testing.assert_array_equal(
+        histogram.quantile_cuts(vals[:, :histogram.CUT_BATCH_COLS + 1], 7),
+        np.nan_to_num(np.nanquantile(
+            vals[:, :histogram.CUT_BATCH_COLS + 1],
+            np.linspace(0, 1, 8)[1:-1], axis=0).T, nan=0.0).astype(
+                np.float32) if case != "all-absent-column" else
+        histogram.quantile_cuts(vals[:, :histogram.CUT_BATCH_COLS + 1], 7))
+
+
+def test_stage_bins_at_968_columns_holds_no_chunk_over_its_byte_budget(
+        monkeypatch):
+    """A chunk of float rows is bounded in bytes whatever the width (at
+    2^20 rows a 968-column chunk is 4 GB, its transpose another), a
+    narrow shard keeps its 2^20 rows, and the result is apply_cuts'."""
+    import jax
+
+    from rabit_tpu.obs import program
+
+    rng = np.random.default_rng(53)
+    n, f, nbin = 700, 968, 256
+    vals = rng.standard_normal((n, f)).astype(np.float32)
+    station = np.repeat(np.arange(121), 8)
+    vals[(rng.random((n, 121)) < 0.8)[:, station]] = np.nan
+    cuts = histogram.quantile_cuts(vals, nbin)
+    budget = 150 * f * 4                              # 150 rows a chunk
+    monkeypatch.setattr(histogram, "STAGE_CHUNK_BYTES", budget)
+    put, chunks = jax.device_put, []
+
+    def seen_put(x, *a, **kw):
+        chunks.append(np.asarray(x).nbytes)
+        return put(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", seen_put)
+    before = program.stats()
+    bins_t, seen = histogram.stage_bins(vals, cuts, nbin)
+    after = program.stats()
+    assert len(chunks) == 5 and max(chunks) <= budget
+    want = histogram.apply_cuts(vals, cuts)
+    assert bins_t.shape == (f, n)                     # 121 groups of 8
+    np.testing.assert_array_equal(np.asarray(bins_t).T, want)
+    assert list(np.asarray(seen)) == [1, nbin]
+    assert after["gbdt.entries"] - before.get("gbdt.entries", 0) == n * f
+    assert (after["gbdt.entries_missing"]
+            - before.get("gbdt.entries_missing", 0)
+            == np.count_nonzero(np.isnan(vals)))
+    # the budgets as shipped: HIGGS keeps 2^20 rows, Bosch gets 69k
+    monkeypatch.undo()
+    for width, rows in ((28, 1 << 20), (968, (1 << 28) // (4 * 968))):
+        assert min(histogram.STAGE_CHUNK_ROWS,
+                   histogram.STAGE_CHUNK_BYTES // (4 * width)) == rows
+        assert rows * width * 4 <= histogram.STAGE_CHUNK_BYTES
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_wide_shards_looped_kernel_body_equals_the_unrolled_one(
+        monkeypatch, dtype):
+    """Past ``_UNROLL_GROUPS`` feature groups the kernel's body is one
+    group inside a loop (968 features are 121 groups: unrolled, a
+    boosting job's kernels took the chip's compiler minutes); the sums
+    are those of the unrolled body bit for bit, absent codes and a
+    ragged last row block included."""
+    import jax
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    rng = np.random.default_rng(59)
+    n, f, nbin = 2300, 80, 256                         # 10 groups of 8
+    assert hk.plan(nbin, f)[3] > hk._UNROLL_GROUPS
+    bins = rng.integers(0, nbin + 1, (f, n)).astype(np.int32)
+    gh = rng.standard_normal((2, n)).astype(np.float32)
+    node = rng.integers(-1, 3, n).astype(np.int32)
+    kw = dict(node_of_row=node, nslots=3, compute_dtype=dtype)
+    looped = np.asarray(hk.hist_fused_multi(bins, gh, nbin, **kw))
+    monkeypatch.setattr(hk, "_UNROLL_GROUPS", 1 << 20)
+    jax.clear_caches()
+    unrolled = np.asarray(hk.hist_fused_multi(bins, gh, nbin, **kw))
+    jax.clear_caches()
+    np.testing.assert_array_equal(looped, unrolled)
+    if dtype == "float32":
+        for s in range(3):
+            for j in (0, 41, 79):
+                have = (node == s) & (bins[j] < nbin)
+                want = _np_hist(bins[j][have][:, None], gh[0, have],
+                                gh[1, have], nbin)[0]
+                np.testing.assert_allclose(
+                    looped[2 * s:2 * s + 2, j].T, want, rtol=0, atol=2e-4)
+
+
+def test_slot_totals_are_sums_of_the_weights_as_the_kernel_rounds_them():
+    """The totals ride with bins that hold sums of bf16-rounded weights:
+    they must be sums of the same numbers, or every feature reads a
+    missing mass of 2^-9 of the node (found on the chip, where a cast
+    there and back is dropped as excess precision)."""
+    import jax
+    import jax.numpy as jnp
+    import ml_dtypes
+
+    rng = np.random.default_rng(67)
+    n, nslots = 5000, 3
+    gh = rng.standard_normal((2, n)).astype(np.float32)
+    slot = rng.integers(-1, nslots, n).astype(np.int32)
+    fn = jax.jit(lambda g, s: histogram.slot_totals(g, s, nslots,
+                                                    "bfloat16"))
+    got = np.asarray(fn(jnp.asarray(gh), jnp.asarray(slot)))
+    rounded = gh.astype(ml_dtypes.bfloat16).astype(np.float64)
+    for s in range(nslots):
+        np.testing.assert_allclose(got[s], rounded[:, slot == s].sum(axis=1),
+                                   rtol=0, atol=1e-3)
+    assert "reduce_precision" in str(jax.make_jaxpr(
+        lambda g, s: histogram.slot_totals(g, s, nslots, "bfloat16"))(
+            gh, slot))
+    exact = np.asarray(histogram.slot_totals(
+        jnp.asarray(gh), jnp.asarray(slot), nslots, jnp.float32))
+    np.testing.assert_allclose(
+        exact[1], gh[:, slot == 1].astype(np.float64).sum(axis=1), atol=1e-3)
+    # a kernel level's totals and bins agree to accumulation, not to 2^-9
+    bins = rng.integers(0, 16, (8, n)).astype(np.int32)
+    out = np.asarray(histogram.level_hist(
+        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot), nslots, 8, 16,
+        use_pallas=True, totals=True), np.float64)
+    np.testing.assert_allclose(out[:, :8].sum(axis=2), np.repeat(
+        out[:, 8:, 0], 8, axis=1), rtol=0, atol=2e-4)

@@ -39,7 +39,9 @@ def watch_built():
 
     def seen_split(node, tree, hist, *a):
         nid = next(i for i, other in enumerate(tree) if other is node)
-        weights[-1][nid] = float(hist[0, :, 1].sum())
+        # the last feature row: a feature nobody misses, or, with
+        # missing values, the node's totals in its bin 0
+        weights[-1][nid] = float(hist[-1, :, 1].sum())
         return split(node, tree, hist, *a)
 
     shard.grad_hess, shard.level = seen_grad_hess, seen_level
