@@ -98,18 +98,33 @@ def initialized() -> bool:
     return _engine is not None
 
 
-def is_device_plane() -> bool:
-    """True when the active engine reduces ``jax.Array`` payloads over
-    the device data plane (the XLA engine in a multi-process world) —
-    apps keep such payloads on device instead of converting to numpy."""
-    if _engine is None or not _engine.is_distributed():
-        return False
+def _is_xla_engine() -> bool:
     try:
         from rabit_tpu.engine.xla import XLAEngine
 
         return isinstance(_engine, XLAEngine)
     except ImportError:  # pragma: no cover
         return False
+
+
+def is_device_plane() -> bool:
+    """True when the active engine reduces ``jax.Array`` payloads over
+    the device data plane (the XLA engine in a multi-process world) —
+    apps keep such payloads on device instead of converting to numpy."""
+    if _engine is None or not _engine.is_distributed():
+        return False
+    return _is_xla_engine()
+
+
+def keeps_device_payloads() -> bool:
+    """True when ``allreduce`` of a ``jax.Array`` gives a ``jax.Array``
+    back, so that an app's reduced payload can stay on the device for
+    what it does next: the XLA engine (the device plane, and its world
+    of one) and ``empty``, which hands its argument back.  The host
+    engines take numpy alone."""
+    from rabit_tpu.engine.empty import EmptyEngine
+
+    return isinstance(_engine, EmptyEngine) or _is_xla_engine()
 
 
 def finalize() -> None:

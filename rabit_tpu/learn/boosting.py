@@ -16,7 +16,10 @@ kernel.  Below the root a level builds one child of every node split
 the level above (static shapes: 2^(depth-1) build slots) and has the
 sibling as parent minus built, on the reduced histograms: XGBoost's
 histogram subtraction.  The only cross-rank traffic per level is one
-allreduce of the built histograms, the XGBoost wire pattern.  Fault
+allreduce of the built histograms, the XGBoost wire pattern; where the
+engine reduces device arrays they stay on the device afterwards, which
+ranks each node's features and hands the host a shortlist to decide
+on in float64.  Fault
 tolerance: one checkpoint per boosting round, the reference's
 per-iteration commit structure.
 """
@@ -264,13 +267,20 @@ def _lookup(table, idx, width: int):
 class _DeviceShard:
     """One rank's rows on the device for the whole job: staged bins,
     labels, margin, (grad, hess) and the node of every row.  A round is
-    a fixed set of programs, compiled before the first; the host sees
-    histograms and sends back tables of a level's width."""
+    a fixed set of programs, compiled before the first; the host sends
+    back tables of a level's width.  With ``scan`` None it sees
+    ``level``'s histograms whole.  With ``scan`` the job's
+    ``(reg_lambda, min_child_weight)``, where the engine hands a
+    reduced device array back (``train``), they stay here: ``scan()``
+    assembles the level from the reduced built slots and the level
+    above, ranks every slot's features in float32 and hands over
+    ``histogram.SHORTLIST`` histogram rows a slot."""
 
     def __init__(self, values, labels, model, max_depth, nbin, subsample,
-                 seed, use_pallas, compute_dtype):
+                 seed, use_pallas, compute_dtype, scan=None):
         import jax
 
+        self.scan_by, self.above = scan, None
         self.n, self.f = values.shape
         self.model, self.max_depth, self.nbin = model, max_depth, nbin
         self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
@@ -298,7 +308,8 @@ class _DeviceShard:
         a node of the level above; an empty slot holds no row, so a tree
         that stops early runs the same programs; one of more slots than
         ``histogram.slots_per_call`` holds several kernel calls),
-        ``partition`` and ``leaf``."""
+        ``partition`` and ``leaf``; and, where the level's histograms
+        stay on the device, ``scan`` by the level's number of slots."""
         import jax
         import jax.numpy as jnp
 
@@ -308,10 +319,11 @@ class _DeviceShard:
         loss, rate = self.model.loss, self.model.learning_rate
         sampled, totals = self.subsample < 1.0, self.has_missing
         missing_code = self.model.cuts.shape[1] + 1
-        use_pallas, cdt = self.use_pallas, self.compute_dtype
+        use_pallas, cdt, scan_by = (self.use_pallas, self.compute_dtype,
+                                    self.scan_by)
         key = (n, f, self.bins_t.shape[0], nbin, totals, depth, loss, rate,
                sampled, missing_code, use_pallas, cdt, hk.hist_fused_multi,
-               jax.default_backend())
+               jax.default_backend(), scan_by)
         if key in _PROGRAMS:
             return _PROGRAMS[key]
         half, width = self.half, 1 << depth
@@ -363,6 +375,12 @@ class _DeviceShard:
                 return (margin + rate * _lookup(vals, code, 2 * width),
                         jnp.zeros_like(node))
 
+        def gbdt_scan(built, above=None, build=None):
+            with jax.named_scope("gbdt/scan"):
+                level = histogram.assemble_level(built, above, build)
+                return (level,) + histogram.level_shortlist(
+                    level, f, *scan_by, totals)
+
         sds = jax.ShapeDtypeStruct
         rows_f, rows_i = sds((n,), jnp.float32), sds((n,), jnp.int32)
         bins, gh = sds(self.bins_t.shape, jnp.int32), sds((2, n), jnp.float32)
@@ -381,6 +399,18 @@ class _DeviceShard:
             "leaf": build(gbdt_leaf, rows_f, rows_i,
                           sds((2 * width,), jnp.float32), donate=(0, 1)),
         }
+        if scan_by is not None:
+            def hists(*shape):
+                return sds(shape, jnp.float32)
+
+            # the root's level is what was built; below, a level of 2p
+            # slots comes from its p built slots and the p slots above
+            rows = f + totals
+            prog["scan"] = {1: build(gbdt_scan, hists(1, rows, nbin, 2))}
+            for p in (1 << d for d in range(depth - 1)):
+                prog["scan"][2 * p] = build(
+                    gbdt_scan, hists(p, rows, nbin, 2),
+                    hists(2, p, rows, nbin), sds((p,), jnp.int32))
         _PROGRAMS[key] = prog
         return prog
 
@@ -402,6 +432,19 @@ class _DeviceShard:
             self.bins_t, self.gh, self.node, np.asarray(build, np.int32)),
             build, calls)
 
+    def scan(self, reduced, build, depth: int):
+        """The shortlist of every slot of the level at ``depth`` as
+        device arrays ``(features, rows)`` (``histogram.level_shortlist``),
+        from ``level(build)``'s histograms once reduced.  The assembled
+        level stays here, the parent of the next."""
+        if not reduced.is_fully_addressable:
+            # the device plane's result, replicated over the processes
+            reduced = reduced.addressable_shards[0].data
+        below = (self.above, np.asarray(build, np.int32)) if depth else ()
+        self.above, feats, rows = self.prog["scan"][1 << depth](
+            reduced, *below)
+        return feats, rows
+
     def partition(self, tab: np.ndarray) -> None:
         tab = np.concatenate(
             [tab, np.zeros((self.half - len(tab), 4), np.int32)])
@@ -413,20 +456,50 @@ class _DeviceShard:
 
 
 def _reduce_level(local) -> np.ndarray:
-    """One ``rabit_tpu.allreduce`` of the level's histograms (also at
-    world 1) and their copy to the host, where the splits are chosen.
-    On the device plane the payload stays a device array so the
-    reduction rides ICI; host engines take the fault-tolerant numpy
-    path."""
+    """One ``rabit_tpu.allreduce`` of the level's histograms through a
+    host engine, which takes the fault-tolerant numpy path: their copy
+    to the host, where such an engine needs them anyway and the splits
+    are then chosen on them whole."""
     shape = local.shape
     program.count("gbdt.hist_bytes_fetched", local.nbytes)
-    if _engine_mod.is_device_plane():
-        out = rabit_tpu.allreduce(local.reshape(-1), SUM)
-        with program.span("gbdt.level.fetch"):
-            return np.asarray(out).reshape(shape)
     with program.span("gbdt.level.fetch"):
         local = histogram._writable(local)
     return rabit_tpu.allreduce(local.reshape(-1), SUM).reshape(shape)
+
+
+def _fetch_shortlist(feats, rows):
+    """A level's shortlist (``_DeviceShard.scan``) on the host: the
+    features ``(slots, k)`` and, a slot, the float64 histogram
+    ``(k [+ 1], nbin, 2)`` of those features alone (and the totals
+    row), as ``_scan`` and ``_split`` take a node's."""
+    import jax
+
+    feats, rows = jax.device_get((feats, rows))
+    program.count("gbdt.hist_bytes_fetched", feats.nbytes + rows.nbytes)
+    return feats, np.moveaxis(rows, 0, -1).astype(np.float64, order="C")
+
+
+def _assemble(level_of: dict, depth: int, built: np.ndarray, order,
+              nslots: int) -> np.ndarray:
+    """The level at ``depth`` in its static shape on the host, a slot an
+    entry, in float64, from the reduced ``built`` slots (``order``: the
+    level slot of each, -1 none) and the level above: one array a depth
+    for the whole job, kept in ``level_of`` (127 MB of fresh pages a
+    level were a tenth of a round at 968 features, and its noise), so a
+    slot that holds no node holds what an earlier round left there."""
+    hists = level_of.get(depth)
+    if hists is None:
+        hists = level_of[depth] = np.zeros((nslots,) + built.shape[1:])
+    for pos, s in enumerate(order):
+        if s < 0:
+            continue
+        hists[s] = built[pos]
+        if depth:
+            # the sum over ranks is linear: this IS the sibling's
+            # reduced histogram
+            np.subtract(level_of[depth - 1][s >> 1], hists[s],
+                        out=hists[s ^ 1])
+    return hists
 
 
 def _scan(hist: np.ndarray, reg_lambda: float, min_child_weight: float,
@@ -471,7 +544,7 @@ def _scan_level(hists, reg_lambda: float, min_child_weight: float,
 
 def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
            reg_lambda: float, min_child_weight: float,
-           has_missing: bool, best=None) -> int | None:
+           has_missing: bool, best=None, features=None) -> int | None:
     """Choose ``node``'s split on its reduced histogram, or leave it a
     leaf (None).  A split gives both children the weight their side's
     sums give; a child that is split in turn gets its own.  Returns the
@@ -484,7 +557,9 @@ def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
     feature: its bin 0 holds the node's (grad, hess) totals
     (``histogram.with_totals``), and the rows absent from the chosen
     feature, the totals less its bins, go the better way.  ``best`` is
-    the node's ``_scan`` where the caller has made it already."""
+    the node's ``_scan`` where the caller has made it already, and
+    ``features`` the feature of each of the histogram's rows where they
+    are a shortlist and not every feature in order."""
     gain, j, t, dl = best or _scan(hist, reg_lambda, min_child_weight,
                                    has_missing)
     if has_missing:
@@ -501,7 +576,8 @@ def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
     if gain <= 1e-12:
         node.value = float(-g_tot / (h_tot + reg_lambda))
         return None
-    node.feature, node.bin_threshold = int(j), int(t)
+    node.feature = int(j if features is None else features[j])
+    node.bin_threshold = int(t)
     node.default_left, node.value = dl, 0.0
     node.left, node.right = len(tree), len(tree) + 1
     tree.append(TreeNode(value=float(-gl / (hl + reg_lambda))))
@@ -525,18 +601,32 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
 
     On an accelerator, and under the XLA engine's device plane, the
     rows live on the device for the whole job (``_DeviceShard``): they
-    are binned there, a level is one fused histogram program over one
-    child of every node split the level above (the root at depth 0),
-    one ``rabit_tpu.allreduce``, the fetch of those histograms, the
-    siblings' as parent minus built, the gain scan on the host and one
-    program that moves every row to its child; depth-limit leaf weights
-    come from the last level's histograms and the margin update is one
-    lookup by row.  The host touches no array of length n inside the
-    loop.  The gain scan covers every slot of the level, node or not:
-    like the device's programs it has the level's static shape, so a
-    round costs the same whatever the tree (where few labels are
-    positive a tree is not full, and which nodes stop early is the
-    seed's to say).
+    are binned there, and a level is one fused histogram program over
+    one child of every node split the level above (the root at depth
+    0), one ``rabit_tpu.allreduce`` of those histograms as the device
+    array they are (also at world 1), one small program (``gbdt_scan``)
+    that assembles the level there (built slots as they are, siblings
+    as parent minus built, a float32 difference) and ranks every slot's
+    features by their best gain in float32, the fetch of each slot's
+    shortlist (``histogram.SHORTLIST`` features, their histogram rows
+    and the totals row: kilobytes where the level is megabytes), the
+    float64 ``_split`` on those rows and one program that moves every
+    row to its child.  Cut, default direction, gain, the stopping rule,
+    child weights and the side to build next are therefore float64 sums
+    of fetched bins; float32 only chooses which rows the host looks at.
+    The assembled level stays on the device as the next level's parent.
+    Depth-limit leaf weights come from the last level's rows and the
+    margin update is one lookup by row.  The host touches no array of
+    length n inside the loop.  Both the device's ranking and the host's
+    decision cover every slot of the level, node or not: they have the
+    level's static shape, so a round costs the same whatever the tree
+    (where few labels are positive a tree is not full, and which nodes
+    stop is the seed's to say).
+    That holds wherever the engine hands a reduced device array back
+    (``engine.keeps_device_payloads``, read off the engine: no option).
+    Under a distributed host engine the built histograms cross the
+    host for the allreduce anyway: they are fetched whole, the siblings
+    come by a float64 subtraction and numpy scans every slot whole.
     Elsewhere the same loop runs on numpy arrays (``_HostShard``) and
     builds the same trees.
 
@@ -576,11 +666,18 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
         rabit_tpu.tracker_print(
             "[%d] restart iter=%d" % (rabit_tpu.get_rank(), version))
     device_arm = on_tpu() or _engine_mod.is_device_plane()
+    # where the engine hands a reduced device array back, a level's
+    # histograms never cross to the host
+    device_scan = device_arm and _engine_mod.keeps_device_payloads()
 
     def stage():
-        shard = (_DeviceShard if device_arm else _HostShard)(
-            values, labels, model, max_depth, nbin, subsample, seed,
-            use_pallas, compute_dtype)
+        args = (values, labels, model, max_depth, nbin, subsample, seed,
+                use_pallas, compute_dtype)
+        if device_arm:
+            shard = _DeviceShard(*args, scan=(
+                reg_lambda, min_child_weight) if device_scan else None)
+        else:
+            shard = _HostShard(*args)
         if version == 0 and not model.trees:
             # missing handling is GLOBAL: any rank with NaNs means every
             # rank must carry the node totals with its histograms and
@@ -601,7 +698,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
 
     shard = stage()
     has_missing = getattr(model, "has_missing", False)
-    level_of: dict = {}             # depth -> the level's histograms
+    level_of: dict = {}     # depth -> the level's histograms on the host
     epoch = rabit_tpu.device_epoch()
     ready = -1                      # the round whose (grad, hess) is made
     for round_idx in range(version, num_round):
@@ -617,9 +714,8 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
             tree: list[TreeNode] = [TreeNode()]
             slots, leaves = [0], []
             # the level slot built for each slot of the level above (the
-            # root for itself; -1: none), and that level's reduced
-            # histograms by slot
-            build, above = [0], None
+            # root for itself; -1: none)
+            build = [0]
             for depth in range(max_depth):
                 if all(nid < 0 for nid in slots):
                     break
@@ -630,27 +726,21 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                     # the same reduced histograms, so builds the same
                     with program.span("learn.dispatch"):
                         local, order, calls = shard.level(build)
-                    built = _reduce_level(local)
+                    if device_scan:
+                        # reduced where they are, and ranked there: the
+                        # host decides on the few rows a slot it fetches
+                        reduced = rabit_tpu.allreduce(local, SUM)
+                        with program.span("learn.dispatch"):
+                            short = shard.scan(reduced, order, depth)
+                        with program.span("gbdt.level.fetch"):
+                            feats, hists = _fetch_shortlist(*short)
+                    else:
+                        built = _reduce_level(local)
+                        feats = [None] * len(slots)
                     with program.span("gbdt.split"):
-                        # the level in its static shape, a slot an
-                        # entry, in float64; one array a depth for the
-                        # whole job (127 MB of fresh pages a level were
-                        # a tenth of a round at 968 features, and its
-                        # noise), so a slot that holds no node holds
-                        # what an earlier round left there
-                        hists = level_of.get(depth)
-                        if hists is None:
-                            hists = level_of[depth] = np.zeros(
-                                (len(slots),) + built.shape[1:])
-                        for pos, s in enumerate(order):
-                            if s < 0:
-                                continue
-                            hists[s] = built[pos]
-                            if depth:
-                                # the sum over ranks is linear: this IS
-                                # the sibling's reduced histogram
-                                np.subtract(above[s >> 1], hists[s],
-                                            out=hists[s ^ 1])
+                        if not device_scan:
+                            hists = _assemble(level_of, depth, built, order,
+                                              len(slots))
                         # every slot is scanned, node or not: a round's
                         # host work is then a full tree's whatever the
                         # tree, as the device's is (static shapes), and
@@ -663,16 +753,17 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                                 continue
                             side = _split(
                                 tree[nid], tree, hists[s], reg_lambda,
-                                min_child_weight, has_missing, best[s])
+                                min_child_weight, has_missing, best[s],
+                                feats[s])
                             if side is not None:
                                 build[s] = 2 * s + side
                                 default_left += tree[nid].default_left
                         tab, slots = _route(tree, slots, leaves)
-                        above = hists
                     with program.span("gbdt.partition"):
                         shard.partition(tab)
                 live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
+                program.count("gbdt.levels_device_scan", int(device_scan))
                 program.count("gbdt.levels_chunked", int(calls > 1))
                 program.count("gbdt.kernel_calls", calls)
                 program.count("gbdt.channels", 2 * len(order))

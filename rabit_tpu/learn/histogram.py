@@ -434,6 +434,106 @@ def best_split(hist: np.ndarray, reg_lambda: float,
             True if left is None else bool(left[j, t]))
 
 
+# features a slot's shortlist holds (:func:`level_shortlist`): the host
+# decides in float64 among these, so the float32 ranking has to be wrong
+# about this many features at once to lose the best; 8 rows of 256 bins
+# are 16 KB a slot
+SHORTLIST = 8
+
+
+def split_candidates_device(g, h, reg_lambda: float = 1.0,
+                            min_child_weight: float | None = None,
+                            total=None):
+    """:func:`split_candidates` in float32, traceable: what ranks a
+    slot's features on the device.  It chooses which rows the host
+    looks at and decides nothing.  The grad and the hess bins come apart
+    (``(..., f, nbin)`` each, the bins on the lanes) and ``total`` as
+    ``(gt, ht)`` of shape ``(...)``; returned are the gain and
+    ``default_left`` (None without ``total``) of every (feature, cut),
+    **the last bin included**, which is no cut and reads -inf.  The
+    rules are the host's: the totals are the cumulative sums' own last
+    entries plus the missing mass, a mass under ``MISSING_MASS_FLOOR``
+    of the node is none, a side under ``min_child_weight`` is barred,
+    ties go left."""
+    import jax.numpy as jnp
+
+    sg, sh = jnp.cumsum(g, axis=-1), jnp.cumsum(h, axis=-1)
+    gt, ht = sg[..., -1:], sh[..., -1:]
+    if total is not None:
+        tg, th = (t[..., None, None] for t in total)
+        gm, hm = tg - gt, th - ht
+        none = jnp.abs(hm) <= MISSING_MASS_FLOOR * jnp.abs(th)
+        gm, hm = jnp.where(none, 0.0, gm), jnp.where(none, 0.0, hm)
+        gt, ht = gt + gm, ht + hm
+    parent = gt * gt / (ht + reg_lambda)
+    no_cut = jnp.arange(g.shape[-1]) == g.shape[-1] - 1
+
+    def score(gl, hl):
+        gr, hr = gt - gl, ht - hl
+        gain = gl * gl / (hl + reg_lambda) + gr * gr / (hr + reg_lambda) \
+            - parent
+        barred = no_cut
+        if min_child_weight is not None:
+            barred = barred | (hl < min_child_weight) \
+                | (hr < min_child_weight)
+        return jnp.where(barred, -jnp.inf, gain)
+
+    right = score(sg, sh)                  # absent rows, if any, go right
+    if total is None:
+        return right, None
+    left = score(sg + gm, sh + hm)
+    return jnp.maximum(left, right), left >= right
+
+
+def assemble_level(built, above, build):
+    """A level's histograms from what it built, traceable, in the
+    layout they keep on the device, ``(2, slots, rows, nbin)`` (grad
+    and hess apart, the bins on the lanes).  ``built`` is the level
+    program's reduced ``(p, rows, nbin, 2)``; at the root (``above``
+    None) it is the level.  Below, ``above`` is the level above so
+    assembled and ``build[pos]`` the child of its slot ``pos`` that
+    was built (-1: none, the slot was not split): the built child as it
+    is, its sibling as parent minus built (the sum over ranks is
+    linear: this IS the sibling's reduced histogram, to a float32
+    rounding of the parent's cell), and zeros in both children of a
+    slot that was not split."""
+    import jax.numpy as jnp
+
+    b = jnp.moveaxis(built, -1, 0)
+    if above is None:
+        return b
+    sibling = above - b
+    live = (build >= 0)[None, :, None, None]
+    right = ((build & 1) == 1)[None, :, None, None]
+    pair = jnp.stack([jnp.where(right, sibling, b),
+                      jnp.where(right, b, sibling)], axis=2)
+    return jnp.where(live[:, :, None], pair, 0.0).reshape(
+        (2, 2 * b.shape[1]) + b.shape[2:])
+
+
+def level_shortlist(level, f: int, reg_lambda, min_child_weight,
+                    totals: bool, k: int = SHORTLIST):
+    """For **every** slot of an assembled level (:func:`assemble_level`;
+    node or not: a static shape), traceable: the ``min(k, f)`` features
+    of highest best gain by :func:`split_candidates_device` (of equals
+    the first), in feature order, as ``(slots, k)`` int32, and their
+    histogram rows ``(2, slots, k, nbin)``; with ``totals`` the slot's
+    totals row (row ``f`` of the level) rides as one more."""
+    import jax
+    import jax.numpy as jnp
+
+    total = (level[0, :, f, 0], level[1, :, f, 0]) if totals else None
+    gain, _left = split_candidates_device(
+        level[0, :, :f], level[1, :, :f], reg_lambda, min_child_weight, total)
+    _best, feats = jax.lax.top_k(jnp.max(gain, axis=-1), min(k, f))
+    feats = jnp.sort(feats, axis=-1).astype(jnp.int32)
+    take = feats
+    if totals:
+        take = jnp.concatenate(
+            [feats, jnp.full(feats.shape[:1] + (1,), f, jnp.int32)], axis=1)
+    return feats, jnp.take_along_axis(level, take[None, :, :, None], axis=2)
+
+
 def split_gain_missing(hist: np.ndarray, total, reg_lambda: float = 1.0):
     """Sparsity-aware split gain of a (f, nbin, 2) histogram and the
     node's (grad, hess) ``total``.  For every (feature, cut) the gain is

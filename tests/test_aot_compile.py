@@ -164,6 +164,33 @@ def test_stage_slice_writer_updates_the_shard_in_place_on_v5e(topo):
     assert m.temp_size_in_bytes <= 1 << 20
 
 
+def test_wide_level_scan_holds_a_level_and_hands_over_kilobytes_on_v5e(topo):
+    """The wide boosting cell's deepest ``gbdt/scan`` as ``boosting.
+    _DeviceShard`` builds it: 16 built slots of 968 features and the
+    totals row, the 16 slots above, a level of 32.  Its arguments are
+    the two levels as they are (no padded copy of the built slots'
+    minor dimension of 2), it returns the level for the next depth and
+    a shortlist a slot, and works in about the level's own size."""
+    from rabit_tpu.learn import histogram
+
+    f, nbin, p = 968, 256, 16
+
+    def gbdt_scan(built, above, build):
+        level = histogram.assemble_level(built, above, build)
+        return (level,) + histogram.level_shortlist(level, f, 1.0, 1.0, True)
+
+    m = jax.jit(gbdt_scan).lower(*_one_chip(
+        topo, ((p, f + 1, nbin, 2), jnp.float32),
+        ((2, p, f + 1, nbin), jnp.float32), ((p,), jnp.int32))).compile(
+        ).memory_analysis()
+    level = 2 * p * (f + 1) * nbin * 2 * 4
+    k = histogram.SHORTLIST
+    assert m.argument_size_in_bytes <= level + (1 << 20)
+    assert level <= m.output_size_in_bytes <= level + (1 << 20)
+    assert m.output_size_in_bytes - level >= 2 * p * (k + 1) * nbin * 2 * 4
+    assert m.temp_size_in_bytes <= 2 * level, m
+
+
 def _dense16_loop(topo):
     from rabit_tpu.learn import kmeans
 

@@ -674,7 +674,9 @@ def test_counters_of_the_missing_path(arm, which):
     of the kernel here), ``gbdt.splits_default_left`` beside
     ``gbdt.nodes_split``, the entries staged and the absent among them,
     and the bytes of histograms fetched: a slot is f + 1 feature rows
-    (the node's totals ride as one) of nbin (grad, hess) float32."""
+    (the node's totals ride as one) of nbin (grad, hess) float32, and on
+    the device arm its shortlist's rows and no other."""
+    from rabit_tpu.learn import histogram
     from rabit_tpu.obs import program
 
     X, y = _station_rows(n=1500)
@@ -697,8 +699,12 @@ def test_counters_of_the_missing_path(arm, which):
         assert 0 < got["gbdt.splits_default_left"] < 7
         assert got["gbdt.entries"] == X.size
         assert got["gbdt.entries_missing"] == np.count_nonzero(np.isnan(X))
-        # built slots 1 + 1 + 2
-        assert got["gbdt.hist_bytes_fetched"] == 4 * (9 + 1) * 16 * 2 * 4
+        if which == "host":         # built slots 1 + 1 + 2, whole
+            assert got["gbdt.hist_bytes_fetched"] == 4 * (9 + 1) * 16 * 2 * 4
+        else:   # every slot, 1 + 2 + 4: its shortlist, the totals row
+            k = histogram.SHORTLIST          # and the features' numbers
+            assert got["gbdt.hist_bytes_fetched"] == 7 * (
+                (k + 1) * 16 * 2 * 4 + k * 4)
 
 
 @pytest.mark.parametrize("which", ["host", "device"])
@@ -752,3 +758,112 @@ def test_a_level_of_large_slots_is_scanned_on_threads_to_the_same_result(
     # one slot is not worth a thread
     assert boosting._scan_level(hists[:1], 1.0, 1.0, has_missing) \
         == serial[:1]
+
+
+# ----------------------------------------------------------------------
+# a level's histograms stay on the device: it ranks every slot's
+# features, the host decides in float64 on the shortlist it fetches
+# ----------------------------------------------------------------------
+def _wide(n=600, f=400, seed=9, missing=False):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, f)).astype(np.float32)
+    y = ((X[:, 3] * X[:, 211] + 0.5 * X[:, 399]
+          + 0.3 * rng.standard_normal(n)) > 0).astype(np.float32)
+    if missing:
+        X[rng.random((n, f)) < 0.3] = np.nan
+    return X, y
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["dense", "nan"])
+def test_device_arm_fetches_a_shortlist_and_the_host_arm_whole_levels(
+        arm, missing):
+    """At 400 features the device arm fetches under a twentieth of the
+    histogram bytes the host arm moves (what the device arm fetched
+    before its level stayed on the device: the built slots whole), and
+    counts every level as scanned on the device; the host arm counts
+    none; both commit one forest."""
+    from rabit_tpu.obs import program
+
+    X, y = _wide(missing=missing)
+    got, models = {}, {}
+    for which in ("host", "device"):
+        arm(which)
+        before = program.stats()
+        models[which] = boosting.train(X, y, num_round=2, max_depth=3,
+                                       nbin=8, use_pallas=False)
+        after = program.stats()
+        got[which] = {k: after.get(k, 0) - before.get(k, 0) for k in (
+            "gbdt.hist_bytes_fetched", "gbdt.levels",
+            "gbdt.levels_device_scan")}
+    assert _structure(models["host"]) == _structure(models["device"])
+    assert {n.feature for t in models["device"].trees for n in t} > {-1, 3}
+    assert got["host"]["gbdt.levels"] == got["device"]["gbdt.levels"] == 6
+    assert got["host"]["gbdt.levels_device_scan"] == 0
+    assert got["device"]["gbdt.levels_device_scan"] == 6
+    # two rounds of built slots 1 + 1 + 2, whole
+    assert got["host"]["gbdt.hist_bytes_fetched"] == 2 * 4 * (
+        400 + missing) * 8 * 2 * 4
+    assert 0 < 20 * got["device"]["gbdt.hist_bytes_fetched"] \
+        < got["host"]["gbdt.hist_bytes_fetched"]
+
+
+def test_device_scan_follows_what_the_engine_does_with_a_device_array(
+        arm, monkeypatch):
+    """The condition is read off the engine, no option: where
+    ``allreduce`` would not hand a device array back (a host engine),
+    the device arm's levels cross to the host whole, as before, and the
+    forest is the same."""
+    from rabit_tpu import engine
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=1200)
+    kw = dict(num_round=2, max_depth=3, nbin=16, use_pallas=False)
+    arm("device")
+    assert engine.keeps_device_payloads()
+    on_device = boosting.train(X, y, **kw)
+    arm("device")
+    monkeypatch.setattr(engine, "keeps_device_payloads", lambda: False)
+    before = program.stats()
+    through_host = boosting.train(X, y, **kw)
+    after = program.stats()
+    assert after.get("gbdt.levels_device_scan", 0) \
+        == before.get("gbdt.levels_device_scan", 0)
+    assert after["gbdt.levels"] - before["gbdt.levels"] == 6
+    assert _structure(through_host) == _structure(on_device)
+    np.testing.assert_allclose(_weights(on_device), _weights(through_host),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_allreduce_sees_the_built_slots_once_a_level_before_any_scoring(
+        arm, monkeypatch):
+    """World 1, device arm: ``rabit_tpu.allreduce`` is still called once
+    a level, on the device array of the built slots' histograms, and
+    the scan program runs on what it returned."""
+    import jax
+
+    import rabit_tpu
+
+    X, y = _tabular(n=1200, missing=True)
+    arm("device")
+    events, reduce_, scan = [], rabit_tpu.allreduce, boosting._DeviceShard.scan
+
+    def seen_allreduce(data, op=None, *a, **kw):
+        if isinstance(data, jax.Array):
+            events.append(("allreduce", data.shape))
+            data = data + 0        # another array: the scan must take it
+            events.append(("handed", id(data)))
+            return data
+        return reduce_(data, op, *a, **kw)
+
+    def seen_scan(self, reduced, build, depth):
+        events.append(("scan", id(reduced), depth))
+        return scan(self, reduced, build, depth)
+
+    monkeypatch.setattr(rabit_tpu, "allreduce", seen_allreduce)
+    monkeypatch.setattr(boosting._DeviceShard, "scan", seen_scan)
+    boosting.train(X, y, num_round=1, max_depth=3, nbin=16, use_pallas=False)
+    shapes = [e[1] for e in events if e[0] == "allreduce"]
+    assert shapes == [(1, 6, 16, 2), (1, 6, 16, 2), (2, 6, 16, 2)]
+    for k in range(3):
+        call, handed, scanned = events[3 * k:3 * k + 3]
+        assert call[0] == "allreduce" and scanned == ("scan", handed[1], k)

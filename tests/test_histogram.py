@@ -669,3 +669,212 @@ def test_slot_totals_are_sums_of_the_weights_as_the_kernel_rounds_them():
         use_pallas=True, totals=True), np.float64)
     np.testing.assert_allclose(out[:, :8].sum(axis=2), np.repeat(
         out[:, 8:, 0], 8, axis=1), rtol=0, atol=2e-4)
+
+
+# ----------------------------------------------------------------------
+# the device's float32 ranking of a slot's features (PR 34): it chooses
+# which histogram rows the host looks at and decides nothing
+# ----------------------------------------------------------------------
+def _scored_case(kind, absent, f=6, nbin=16, seed=31):
+    """A float32 ``(f, nbin, 2)`` histogram and, with ``absent``, the
+    node's totals: a full node, a slot that holds no row, or a sibling
+    derived as parent minus built, whose cells read an ulp of either
+    sign where no row fell (feature 0's last bins, feature 1's first)."""
+    rng = np.random.default_rng(seed)
+    node = rng.random((f, nbin, 2))
+    node[..., 0] -= 0.5
+    node[0, nbin - 3:] = 0
+    node[1, :2] = 0
+    # every feature's bins add up to the node's totals (0.8, 9)
+    node[..., 1] *= 9.0 / node[..., 1].sum(axis=1, keepdims=True)
+    node[:, 5, 0] += 0.8 - node[..., 0].sum(axis=1)
+    if kind == "empty":
+        node[:] = 0
+    hist = node.astype(np.float32)
+    if kind == "sibling":
+        built = (37 * rng.random((f, nbin, 2))).astype(np.float32)
+        parent = (built.astype(np.float64) + node).astype(np.float32)
+        # the parent's cells were added up in another order
+        parent = np.nextafter(parent, np.where(
+            rng.random(parent.shape) < 0.5, -np.inf, np.inf).astype(
+                np.float32))
+        hist = parent - built
+        assert (hist[0, nbin - 3:] != 0).any() and hist[..., 1].min() < 0
+    if not absent:
+        return hist, None
+    # features 2.. are absent from a share of the rows; nobody is absent
+    # from features 0 and 1 (a residue under the floor reads as none)
+    total = hist[0].sum(axis=0, dtype=np.float64)
+    hist[2:] *= rng.uniform(0.3, 0.9, (f - 2, 1, 1)).astype(np.float32)
+    return hist, total.astype(np.float32)
+
+
+def _both_ways(hist, total, lam):
+    """Float64 gains with the absent rows sent left and sent right."""
+    sums = np.cumsum(hist.astype(np.float64), axis=1)
+    mass = histogram.missing_mass(hist, total)
+    tot = (sums[:, -1] + mass)[:, None]
+    out = []
+    for add in (mass[:, None], 0.0):
+        left = sums[:, :-1] + add
+        right = tot - left
+        out.append(left[..., 0] ** 2 / (left[..., 1] + lam)
+                   + right[..., 0] ** 2 / (right[..., 1] + lam)
+                   - tot[..., 0] ** 2 / (tot[..., 1] + lam))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["node", "empty", "sibling"])
+@pytest.mark.parametrize("min_child_weight", [None, 1.0, 1e9],
+                         ids=["any-child", "mcw-1", "bars-every-cut"])
+@pytest.mark.parametrize("absent", [False, True], ids=["dense", "nan"])
+def test_device_scoring_equals_split_candidates(kind, min_child_weight,
+                                                absent):
+    """``split_candidates_device`` (float32, traceable) against the
+    host's float64: the gain to 1e-5 of the node's scale, the same
+    candidates barred, the same default direction wherever the two
+    directions differ by more than that."""
+    import jax
+
+    lam = 1.0
+    hist, total = _scored_case(kind, absent)
+    want, want_left = histogram.split_candidates(hist, lam, min_child_weight,
+                                                 total)
+    got, got_left = jax.jit(
+        lambda h, t: histogram.split_candidates_device(
+            h[..., 0], h[..., 1], lam, min_child_weight,
+            None if t is None else (t[0], t[1])))(hist, total)
+    # the last bin rides along, is no cut and reads -inf
+    assert (np.asarray(got)[:, -1] == -np.inf).all()
+    got = np.asarray(got)[:, :-1]
+    got_left = None if got_left is None else np.asarray(got_left)[:, :-1]
+    assert got.dtype == np.float32 and got.shape == want.shape == (6, 15)
+    barred = want == -np.inf
+    np.testing.assert_array_equal(got == -np.inf, barred)
+    assert barred.all() == (min_child_weight == 1e9) or kind == "empty"
+    if min_child_weight == 1.0 and kind != "empty":
+        assert barred.any() and not barred.all()
+    g_abs = np.abs(hist[..., 0].astype(np.float64)).sum(axis=1).max()
+    scale = g_abs ** 2 / (hist[0, :, 1].sum(dtype=np.float64) + lam)
+    np.testing.assert_allclose(got[~barred], want[~barred], rtol=0,
+                               atol=1e-5 * scale)
+    if not absent:
+        assert got_left is None and want_left is None
+        return
+    left, right = _both_ways(hist, total, lam)
+    clear = ~barred & (np.abs(left - right) > 1e-5 * scale)
+    np.testing.assert_array_equal(np.asarray(got_left)[clear],
+                                  want_left[clear])
+    # nobody is absent from features 0 and 1: a tie, which goes left
+    assert np.asarray(got_left)[:2].all() and want_left[:2].all()
+    if kind == "node" and not barred.all():     # both ways are taken
+        assert clear[2:].any() and not want_left[clear].all()
+
+
+def _tied_level(absent, slots=3, f=12, nbin=16, seed=37):
+    """An assembled level ``(2, slots, f [+ 1], nbin)`` whose features
+    come on few levels (empty bins: consecutive cuts tie exactly) and
+    in copies (features 4 and 9 repeat feature 2: whole features tie);
+    slot 1 holds no row."""
+    rng = np.random.default_rng(seed)
+    hist = np.zeros((slots, f + absent, nbin, 2), np.float32)
+    for s in (0, 2):
+        for j in range(f):
+            at = rng.choice(nbin, size=5, replace=False)
+            g, h = rng.standard_normal(5) * 3, rng.uniform(1.5, 4, 5)
+            # every feature's bins add up to the node's totals (0, 12)
+            hist[s, j, at, 0] = g - g.mean()
+            hist[s, j, at, 1] = h * 12 / h.sum()
+        hist[s, 4] = hist[s, 9] = hist[s, 2]
+        # the best gain by far, twice: features 2's copies aside, 6 == 7
+        hist[s, 6] = 0
+        hist[s, 6, [1, 2, 11, 12]] = [(-9, 3), (-9, 3), (9, 3), (9, 3)]
+        hist[s, 7] = hist[s, 6]
+        if absent:          # half of the rows skip features 6 and 7
+            hist[s, f, 0] = hist[s, 6].sum(axis=0)
+            hist[s, 6:8] *= np.float32(0.5)
+    return np.moveaxis(hist, -1, 0).copy(), hist
+
+
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("absent", [False, True], ids=["dense", "nan"])
+def test_shortlist_holds_the_float64_winner(k, absent):
+    """The host's ``best_split`` on a slot's shortlist rows is its
+    ``best_split`` on the whole histogram (of equals the first feature,
+    the first cut, left), for a shortlist of one and of eight, on
+    histograms with duplicate cuts, empty bins and repeated features;
+    the rows are the level's own, in feature order, the totals row
+    last."""
+    import jax
+
+    lam, mcw, f = 1.0, 1.0, 12
+    level, hist = _tied_level(absent)
+    feats, rows = jax.jit(lambda x: histogram.level_shortlist(
+        x, f, lam, mcw, absent, k))(level)
+    feats, rows = np.asarray(feats), np.moveaxis(np.asarray(rows), 0, -1)
+    assert feats.shape == (3, k) and rows.shape == (3, k + absent, 16, 2)
+    assert (np.diff(feats, axis=1) > 0).all()
+    for s in range(3):
+        np.testing.assert_array_equal(rows[s, :k], hist[s, feats[s]])
+        total = hist[s, f, 0] if absent else None
+        if absent:
+            np.testing.assert_array_equal(rows[s, k], hist[s, f])
+        gain, j, t, left = histogram.best_split(hist[s, :f], lam, mcw, total)
+        got = histogram.best_split(rows[s, :k], lam, mcw, total)
+        assert (got[0], int(feats[s, got[1]]), got[2], got[3]) == (
+            gain, j, t, left)
+        if s == 1:          # no row: every cut barred, the first of all
+            assert (gain, j, t) == (-np.inf, 0, 0)
+            assert feats[s].tolist() == list(range(k))
+        else:               # 6 and 7 tie, cuts 2..10 tie: the first
+            assert (j, t) == (6, 2) and gain > 0 and feats[s, 0] <= 6
+
+
+@pytest.mark.parametrize("absent", [False, True], ids=["dense", "nan"])
+@pytest.mark.parametrize("depth", [0, 3])
+def test_scan_of_a_level_returns_every_slot_and_the_hosts_level(depth,
+                                                                absent):
+    """``assemble_level`` + ``level_shortlist`` as the scan program runs
+    them: the level equals the host's (built slots as they are, siblings
+    as parent minus built, in float64) to a float32 rounding of the
+    parent's cell, zeros where the slot above was not split; and every
+    slot has its shortlist, node or not."""
+    import jax
+
+    from rabit_tpu.learn import boosting
+
+    f, nbin, p = 5, 8, 1 << max(depth - 1, 0)
+    rng = np.random.default_rng(41 + depth)
+    built = rng.random((p, f + absent, nbin, 2)).astype(np.float32)
+
+    @jax.jit
+    def scan(built, above=None, build=None):
+        level = histogram.assemble_level(built, above, build)
+        return (level,) + histogram.level_shortlist(level, f, 1.0, 1.0,
+                                                    absent)
+
+    if not depth:
+        level, feats, rows = scan(built)
+        np.testing.assert_array_equal(np.moveaxis(np.asarray(level), 0, -1),
+                                      built)
+        assert feats.shape == (1, f) and rows.shape == (2, 1, f + absent,
+                                                        nbin)
+        return
+    above = (built + 3 * rng.random(built.shape)).astype(np.float32)
+    build = np.array([1, 2, -1, 7], np.int32)
+    level, feats, rows = scan(built, np.moveaxis(above, -1, 0), build)
+    level = np.moveaxis(np.asarray(level), 0, -1)
+    host = boosting._assemble({depth - 1: above.astype(np.float64)}, depth,
+                              built.astype(np.float64), build, 2 * p)
+    assert level.shape == host.shape == (2 * p, f + absent, nbin, 2)
+    assert level.dtype == np.float32
+    np.testing.assert_allclose(level, host, rtol=0,
+                               atol=np.finfo(np.float32).eps * above.max())
+    for s in (1, 2, 7):
+        np.testing.assert_array_equal(level[s], built[s >> 1])
+    assert not level[4:6].any() and level[[0, 3, 6]].all()
+    assert feats.shape == (2 * p, f) and rows.shape[1:3] == (2 * p,
+                                                            f + absent)
+    np.testing.assert_array_equal(
+        np.asarray(feats), np.tile(np.arange(f), (2 * p, 1)))
+    np.testing.assert_array_equal(np.moveaxis(np.asarray(rows), 0, -1), level)
