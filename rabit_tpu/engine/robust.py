@@ -1088,34 +1088,33 @@ class PyRobustEngine(PySocketEngine):
             self._lazy_global = None
 
     def _commit_checkpoint(self) -> None:
-        with program.span("commit.apply"):
-            if self._pending_lazy is not None:
-                self._lazy_global = self._pending_lazy
-                self._pending_lazy = None
-                self._global = b""
-            else:
-                self._global = self._pending_global
-                self._lazy_global = None
-            self._has_checkpoint = True
-            self._version += 1
-            if self._has_pending_local:
-                self._local_store[self._rank] = (self._version,
-                                                 self._pending_local)
-                self._local = self._pending_local  # world-of-1 load path
-            self._cache.clear()
-            self._seq = 0
-            if self._obs_on:
-                self._metrics.counter("checkpoint.commits").inc()
-                # Live-plane gauge: the streamed frames carry it, so a
-                # /metrics scrape shows each rank's committed progress
-                # mid-run (the cmd=epoch poll only reports in elastic
-                # mode).
-                self._metrics.gauge("ckpt.committed_version").set(
-                    self._version)
-                self._trace.emit("checkpoint", phase="commit",
-                                 rank=self._rank, version=self._version)
-            if self._is_ckpt_writer():
-                self._persist_checkpoint()
+        if self._pending_lazy is not None:
+            self._lazy_global = self._pending_lazy
+            self._pending_lazy = None
+            self._global = b""
+        else:
+            self._global = self._pending_global
+            self._lazy_global = None
+        self._has_checkpoint = True
+        self._version += 1
+        if self._has_pending_local:
+            self._local_store[self._rank] = (self._version,
+                                             self._pending_local)
+            self._local = self._pending_local  # world-of-1 load path
+        self._cache.clear()
+        self._seq = 0
+        if self._obs_on:
+            self._metrics.counter("checkpoint.commits").inc()
+            # Live-plane gauge: the streamed frames carry it, so a
+            # /metrics scrape shows each rank's committed progress
+            # mid-run (the cmd=epoch poll only reports in elastic
+            # mode).
+            self._metrics.gauge("ckpt.committed_version").set(
+                self._version)
+            self._trace.emit("checkpoint", phase="commit",
+                             rank=self._rank, version=self._version)
+        if self._is_ckpt_writer():
+            self._persist_checkpoint()
 
     def _is_ckpt_writer(self) -> bool:
         return (self._ckpt_store is not None

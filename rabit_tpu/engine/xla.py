@@ -259,7 +259,9 @@ class XLAEngine(Engine):
                             self._drop_distributed_state()
                             self._degraded = True
                         if not (self._degraded or self._adopted_jax):
-                            self._build_proc_mesh()
+                            # the first touch of the chip
+                            with program.span("init.group.mesh"):
+                                self._build_proc_mesh()
         else:
             # No tracker: adopt whatever world JAX already lives in
             # (single process, or a pod slice launched by its own runtime).
@@ -325,7 +327,10 @@ class XLAEngine(Engine):
         # tracker, so version-span 0 contains no engine-internal
         # collectives and a worker relaunched before the first
         # checkpoint replays a span aligned with the survivors'.
-        coord = self._request_tracker_service("init")
+        with program.span("init.group.service"):
+            # answered once every rank has asked: the wait for the
+            # slowest rank (its imports, mostly) is here
+            coord = self._request_tracker_service("init")
         if os.environ.get("RABIT_XLA_DIE_FORMATION", "") == str(self._rank):
             # Fault-injection hook (XLA death matrix): die INSIDE the
             # formation window — tracker round + coordinator resolution
@@ -340,7 +345,10 @@ class XLAEngine(Engine):
                 f"rank {self._rank} dying in the formation window "
                 "(RABIT_XLA_DIE_FORMATION)")
             os._exit(254)
-        if not self._formation_barrier():
+        with program.span("init.group.barrier"):
+            # milliseconds: the service round has levelled the ranks
+            formed = self._formation_barrier()
+        if not formed:
             # Someone died (or the barrier timed out) before formation
             # could complete: entering the device-group registration now
             # would block unrecoverably (see protocol.CMD_FORMBAR) —
@@ -373,7 +381,8 @@ class XLAEngine(Engine):
                 form_timeout = 10
         else:
             form_timeout = min(10, self._init_timeout)
-        self._connect_distributed(coord, init_timeout=form_timeout)
+        with program.span("init.group.connect"):
+            self._connect_distributed(coord, init_timeout=form_timeout)
         self._we_initialized_jax = True
 
     def _formation_barrier(self) -> bool:
@@ -1032,6 +1041,7 @@ class XLAEngine(Engine):
         # blocking here to time them would serialize the data plane
         with program.span(kind + ".dispatch") as dispatch:
             out = fn(garr)
+            program.enqueued(out)
         self._path_counts["device_ops"] += 1
         if self._obs_on:
             dt = dispatch.seconds

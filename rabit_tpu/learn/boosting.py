@@ -32,6 +32,7 @@ import numpy as np
 import rabit_tpu
 from rabit_tpu import engine as _engine_mod
 from rabit_tpu.learn import histogram
+from rabit_tpu.learn.data import fetch
 from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils.checks import check
@@ -299,8 +300,7 @@ class _DeviceShard:
             self.prog = self._programs()
         self.margin = jnp.full((self.n,), self.model.base_score, jnp.float32)
         self.node = jnp.zeros((self.n,), jnp.int32)
-        with program.span("stage.margin"):
-            _replay(self, self.model.trees, self.max_depth)
+        _replay(self, self.model.trees, self.max_depth)
 
     def _programs(self) -> dict:
         """The job's programs, compiled for its shapes: ``grad``,
@@ -424,13 +424,15 @@ class _DeviceShard:
             keep = (jax.device_put(_keep_rows(
                 self.seed, round_idx, self.n, self.subsample)),)
         self.gh = self.prog["grad"](self.margin, self.labels, *keep)
+        program.enqueued(self.gh)
 
     def level(self, build):
         calls = histogram.level_calls(len(build), self.bins_t.shape[0],
                                       self.nbin, self.use_pallas)
-        return (self.prog["level"][len(build)](
-            self.bins_t, self.gh, self.node, np.asarray(build, np.int32)),
-            build, calls)
+        local = self.prog["level"][len(build)](
+            self.bins_t, self.gh, self.node, np.asarray(build, np.int32))
+        program.enqueued(local)
+        return local, build, calls
 
     def scan(self, reduced, build, depth: int):
         """The shortlist of every slot of the level at ``depth`` as
@@ -443,16 +445,19 @@ class _DeviceShard:
         below = (self.above, np.asarray(build, np.int32)) if depth else ()
         self.above, feats, rows = self.prog["scan"][1 << depth](
             reduced, *below)
+        program.enqueued(rows)
         return feats, rows
 
     def partition(self, tab: np.ndarray) -> None:
         tab = np.concatenate(
             [tab, np.zeros((self.half - len(tab), 4), np.int32)])
         self.node = self.prog["partition"](self.bins_t, self.node, tab)
+        program.enqueued(self.node)
 
     def leaf(self, vals: np.ndarray) -> None:
         self.margin, self.node = self.prog["leaf"](self.margin, self.node,
                                                    vals)
+        program.enqueued(self.node)
 
 
 def _reduce_level(local) -> np.ndarray:
@@ -463,7 +468,7 @@ def _reduce_level(local) -> np.ndarray:
     shape = local.shape
     program.count("gbdt.hist_bytes_fetched", local.nbytes)
     with program.span("gbdt.level.fetch"):
-        local = histogram._writable(local)
+        local = fetch(local, histogram._writable)
     return rabit_tpu.allreduce(local.reshape(-1), SUM).reshape(shape)
 
 
@@ -472,11 +477,12 @@ def _fetch_shortlist(feats, rows):
     features ``(slots, k)`` and, a slot, the float64 histogram
     ``(k [+ 1], nbin, 2)`` of those features alone (and the totals
     row), as ``_scan`` and ``_split`` take a node's."""
-    import jax
+    def convert(host):
+        feats, rows = host
+        program.count("gbdt.hist_bytes_fetched", feats.nbytes + rows.nbytes)
+        return feats, np.moveaxis(rows, 0, -1).astype(np.float64, order="C")
 
-    feats, rows = jax.device_get((feats, rows))
-    program.count("gbdt.hist_bytes_fetched", feats.nbytes + rows.nbytes)
-    return feats, np.moveaxis(rows, 0, -1).astype(np.float64, order="C")
+    return fetch((feats, rows), convert)
 
 
 def _assemble(level_of: dict, depth: int, built: np.ndarray, order,

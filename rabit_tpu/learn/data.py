@@ -112,6 +112,33 @@ class SparseMat:
         return out
 
 
+def fetch(result, convert=None):
+    """A device result on the host, in two spans inside the caller's
+    own fetch span: ``learn.fetch.wait`` until the device has written it
+    (the kernel's time as the host sees it), then ``learn.fetch.copy``
+    around the copy back and ``convert`` of the numpy arrays (the
+    host's time, with nothing in flight unless the loop runs ahead).
+    ``result`` is an array or a tuple of them.
+
+    The host sleeps twice here where ``np.asarray`` of an unfinished
+    array sleeps once, which costs a fetch some 40 microseconds on a
+    shared host (PERF.md section 6, PR 35): the price of knowing, in
+    every run and on every rank, which of the two a loop waits for."""
+    arrays = result if isinstance(result, tuple) else (result,)
+    # the transfer is asked for before the wait and follows the kernel
+    # by itself, as `np.asarray` of an unfinished array has it do: asked
+    # for after the wait it costs one more round trip to the runtime
+    for a in arrays:
+        a.copy_to_host_async()
+    with program.span("learn.fetch.wait"):
+        for a in arrays:
+            a.block_until_ready()
+    with program.span("learn.fetch.copy"):
+        host = (tuple(np.asarray(a) for a in arrays) if arrays is result
+                else np.asarray(result))
+        return host if convert is None else convert(host)
+
+
 def hash_features(findex: np.ndarray, fvalue: np.ndarray, d_out: int,
                   seed: int = 0) -> tuple[np.ndarray, np.ndarray]:
     """Signed feature hashing: map feature ids into ``[0, d_out)`` with a

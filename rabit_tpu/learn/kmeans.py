@@ -18,7 +18,8 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import rabit_tpu
-from rabit_tpu.learn.data import SparseMat, load_libsvm, save_matrix_txt
+from rabit_tpu.learn.data import (SparseMat, fetch, load_libsvm,
+                                 save_matrix_txt)
 from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils import compile_cache
@@ -809,32 +810,39 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
             # that space (zero columns are inert) and slice on fetch
             cent = jnp.pad(cent, ((0, 0), (0, x.shape[1] - feat_dim)))
 
-        def enqueue(cent, chain):
-            with program.span("learn.dispatch"):
+        def enqueue(cent, chain, span="learn.dispatch"):
+            with program.span(span):
                 if shard[0] == "dense":
-                    return device_iterations(cent, x, vcol, chain)
-                if shard[0] == "dense16":
-                    return device_iterations(
+                    cent = device_iterations(cent, x, vcol, chain)
+                elif shard[0] == "dense16":
+                    cent = device_iterations(
                         cent, x, vcol, chain, compute_dtype=compute_dtype,
                         block=_DENSE16_ROW_TILE)
-                fn = _ell_chain_fn(chain, k, feat_dim, d_pad, nnz_p)
-                return fn(cent, idx_g, val_g, dvalid)
+                else:
+                    fn = _ell_chain_fn(chain, k, feat_dim, d_pad, nnz_p)
+                    cent = fn(cent, idx_g, val_g, dvalid)
+                program.enqueued(cent)
+                return cent
 
         # The chain after this one is enqueued before this one's result
         # is fetched and committed: it needs only the centroids on the
         # device, so the device never waits for the host's fetch, commit
         # and dispatch, and the rate does not follow the host's load.
+        # The job's first hand-over is set-up's: where the process has
+        # not run this program yet it is traced and compiled there, so
+        # no `learn.step` holds a compile (a last chain that is shorter
+        # compiles where it is enqueued, under the chain before it).
         chain = min(device_chain, max_iter - it)
-        queued = None
+        queued = enqueue(cent, chain, "stage.compile") if chain else None
         while chain:
             version += 1
             it += chain
             ahead = min(device_chain, max_iter - it)
             with program.span("learn.step", version=version):
-                cent = enqueue(cent, chain) if queued is None else queued
+                cent = queued
                 queued = enqueue(cent, ahead) if ahead else None
                 with program.span("learn.fetch"):
-                    fetched = np.asarray(cent)
+                    fetched = fetch(cent)
                 # the iterations of the version the host now holds
                 program.count("learn.iterations", chain)
                 with program.span("learn.update"):
@@ -853,13 +861,25 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
 
     device_plane = _engine_mod.is_device_plane()
 
+    def enqueue_stats(span="learn.dispatch"):
+        with program.span(span):
+            local = shard_stats_device(model, shard)
+            program.enqueued(local)
+        return local
+
     # On the device plane the stats program of the next version is
     # enqueued before this version is committed: it needs the updated
     # centroids and nothing of the commit, whose host rounds then run
     # under the kernel.  It is local and collective-free; the next
     # version's allreduce is still issued after this commit returns.
+    # The job's first hand-over is set-up's, as in the chained loop: it
+    # compiles the stats program where the process has not run it yet.
+    # (A host engine calls for the stats from inside its allreduce, and
+    # skips the call in a replay: its first step holds the compile.)
     epoch = rabit_tpu.device_epoch()
     queued = None
+    if device_plane and version < max_iter:
+        queued = enqueue_stats("stage.compile")
     for it in range(version, max_iter):
         if rabit_tpu.device_epoch() != epoch:
             # the device plane was re-formed at a checkpoint boundary
@@ -876,22 +896,21 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
         with program.span("learn.step", version=it + 1):
             if device_plane:
                 if queued is None:
-                    with program.span("learn.dispatch"):
-                        local = shard_stats_device(model, shard)
+                    local = enqueue_stats()
                 else:
                     local, queued = queued, None
-                    program.count("learn.ahead")
+                    if it > version:     # the job's first: of no commit
+                        program.count("learn.ahead")
                 total = rabit_tpu.allreduce(local, SUM)
                 with program.span("learn.fetch"):
-                    stats = np.asarray(total)
+                    stats = fetch(total)
             else:
                 stats = np.zeros((k, feat_dim + 1), np.float32)
 
-                def lazy_stats(stats=stats, model=model):
-                    with program.span("learn.dispatch"):
-                        local = shard_stats_device(model, shard)
+                def lazy_stats(stats=stats):
+                    local = enqueue_stats()
                     with program.span("learn.fetch"):
-                        stats[...] = np.asarray(local)
+                        stats[...] = fetch(local)
 
                 stats = rabit_tpu.allreduce(stats, SUM,
                                             prepare_fun=lazy_stats)
@@ -906,8 +925,7 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
             if device_plane and it + 1 < max_iter:
                 # `learn.update` bound a new centroid array, which
                 # nothing writes from here on (the commit reads it)
-                with program.span("learn.dispatch"):
-                    queued = shard_stats_device(model, shard)
+                queued = enqueue_stats()
             rabit_tpu.checkpoint(model)
 
     if out_model and rabit_tpu.get_rank() == 0:

@@ -21,7 +21,7 @@ from typing import BinaryIO
 import numpy as np
 
 import rabit_tpu
-from rabit_tpu.learn.data import SparseMat, load_libsvm
+from rabit_tpu.learn.data import SparseMat, fetch, load_libsvm
 from rabit_tpu.learn.lbfgs import LBFGSSolver, ObjFunction
 from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, on_tpu
@@ -374,6 +374,7 @@ class LinearObjFunction(ObjFunction):
         that follows an accepted trial; the loss summed a tile."""
         with program.span("learn.dispatch"):
             margins, partial = self._shard.evaluate(w, base)
+            program.enqueued(partial)
         self._margins = (w, base, margins)
         program.count("lbfgs.nnz", self._shard.nnz)
         program.count("lbfgs.nnz_padded", self._shard.nnz_padded)
@@ -386,7 +387,8 @@ class LinearObjFunction(ObjFunction):
         self.prepare()
         partial = self._evaluate(*self._split(weight))
         with program.span("learn.fetch"):
-            sum_val = float(np.asarray(partial).astype(np.float64).sum())
+            sum_val = fetch(partial, lambda tiles: float(
+                tiles.astype(np.float64).sum()))
         nf = self.model.num_feature
         if rabit_tpu.get_rank() == 0 and self.reg_L2 != 0.0:
             sum_val += 0.5 * self.reg_L2 * float(weight[:nf] @ weight[:nf])
@@ -405,6 +407,7 @@ class LinearObjFunction(ObjFunction):
             self._evaluate(w, base)
         with program.span("learn.dispatch"):
             out = self._shard.gradient(self._margins[2])
+            program.enqueued(out[1])
         program.count("lbfgs.nnz", self._shard.nnz)
         program.count("lbfgs.nnz_padded", self._shard.nnz_padded)
         return out
@@ -428,10 +431,15 @@ class LinearObjFunction(ObjFunction):
         else:
             gw, gbias = self._dispatch_grad(w, base)
         nf = self.model.num_feature
-        with program.span("learn.fetch"):
+
+        def widen(host):
             out = np.empty(nf + 1, np.float64)
-            out[:nf] = np.asarray(gw)
-            out[nf] = np.asarray(gbias).astype(np.float64).sum()
+            out[:nf] = host[0]
+            out[nf] = host[1].astype(np.float64).sum()
+            return out
+
+        with program.span("learn.fetch"):
+            out = fetch((gw, gbias), widen)
         if rabit_tpu.get_rank() == 0 and self.reg_L2 != 0.0:
             out[:nf] += self.reg_L2 * weight[:nf]
         return out

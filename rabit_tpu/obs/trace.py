@@ -54,7 +54,7 @@ class EventTrace:
     def capacity(self) -> int:
         return self._buf.maxlen
 
-    def emit(self, name: str, *, ts: float | None = None,
+    def emit(self, name: str, /, *, ts: float | None = None,
              dur: float | None = None, **fields) -> None:
         """Append one event.  ``name`` is the event family ("op",
         "recovery", "checkpoint", ...); ``fields`` carry the structured

@@ -20,7 +20,9 @@ from rabit_tpu.obs import program
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 STAGE = {"stage.to_ell", "stage.clamp", "stage.put"}
-LOOP = {"learn.step", "learn.dispatch", "learn.fetch", "learn.update"}
+LOOP = {"learn.step", "learn.dispatch", "learn.fetch", "learn.fetch.wait",
+        "learn.fetch.copy", "learn.update"}
+COLUMNS = ("n", "total_s", "max_s", "self_s", "exposed_s")
 # what kmeans.run leaves on engine `empty`: its two calls before the
 # loop, staging, the loop, the commit as far as `empty` has layers
 KMEANS_SPANS = (STAGE | LOOP
@@ -33,6 +35,11 @@ def table():
     program.reset()
     yield program
     program.reset()
+
+
+def open_span() -> str:
+    """The innermost span open on this thread."""
+    return program._thread.state.top.name
 
 
 def span_names(stats: dict) -> set:
@@ -87,7 +94,7 @@ def test_stats_is_flat_json_and_reset_empties_it(table):
     with program.span("x", version=3, anything="goes"):
         program.count("x.k", 2)
     s = program.stats()
-    assert set(s) == {"x.n", "x.total_s", "x.max_s", "x.k"}
+    assert set(s) == {"x." + column for column in COLUMNS} | {"x.k"}
     assert all(isinstance(v, (int, float)) for v in s.values())
     assert json.loads(json.dumps(s)) == s
     program.reset()
@@ -107,6 +114,222 @@ def test_span_off_cost_is_microseconds(table):
         samples.append(time.perf_counter() - t0)
     assert statistics.median(samples) < 10e-6
     assert program.stats()["cost.n"] == 10000
+
+
+# ------------------------------------------- self and exposed seconds
+class Clock:
+    """The table's clock, moved by hand."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self):
+        return self.now
+
+
+@pytest.fixture
+def clock(table, monkeypatch):
+    """A clock the test moves and a thread state that starts on it."""
+    clock = Clock()
+    monkeypatch.setattr(program, "_perf", clock)
+    monkeypatch.setattr(program._thread, "state", program._State())
+    yield clock
+    program.enqueued(None)
+
+
+class Owed:
+    """A result the device owes until the test says it has landed."""
+
+    def __init__(self):
+        self.landed = False
+
+    def is_ready(self):
+        return self.landed
+
+
+class Deleted:
+    def is_ready(self):
+        raise RuntimeError("Array has been deleted.")
+
+
+class DeletedByHand:
+    """``Array.delete()``: jaxlib can crash in ``is_ready`` of one."""
+
+    def is_deleted(self):
+        return True
+
+    def is_ready(self):
+        raise AssertionError("asked a deleted array whether it is ready")
+
+
+def test_self_seconds_of_a_parent_and_its_children_add_up_to_its_total(clock):
+    with program.span("outer"):                     # 100 .. 110
+        clock.now = 102.0
+        with program.span("outer.a"):               # 102 .. 105
+            clock.now = 105.0
+        clock.now = 106.0
+        with program.span("outer.b"):               # 106 .. 109
+            clock.now = 107.0
+            with program.span("outer.b.deep"):      # 107 .. 108
+                clock.now = 108.0
+            clock.now = 109.0
+        clock.now = 110.0
+    s = program.stats()
+    assert s["outer.total_s"] == 10.0 and s["outer.self_s"] == 4.0
+    assert s["outer.a.self_s"] == s["outer.a.total_s"] == 3.0
+    assert s["outer.b.total_s"] == 3.0 and s["outer.b.self_s"] == 2.0
+    assert s["outer.b.deep.self_s"] == 1.0
+    assert sum(s[name + ".self_s"] for name in span_names(s)) == 10.0
+
+
+def test_an_interval_is_exposed_if_it_began_with_the_device_idle(clock):
+    """A step that hands a program over, waits for it and copies the
+    result back: the dispatch and the copy began with nothing in flight
+    and count under themselves and the step; the launch after the
+    dispatch and the wait began with the program owed and count
+    nothing, though it landed inside the wait."""
+    owed = Owed()
+    with program.span("learn.step"):                # 100 .. 109
+        clock.now = 101.0
+        with program.span("learn.dispatch"):        # 101 .. 102
+            program.enqueued(owed)
+            clock.now = 102.0
+        clock.now = 103.0                           # the launch
+        with program.span("learn.fetch.wait"):      # 103 .. 105
+            owed.landed = True
+            clock.now = 105.0
+        clock.now = 106.0
+        with program.span("learn.fetch.copy"):      # 106 .. 108
+            clock.now = 108.0
+        clock.now = 109.0
+    s = program.stats()
+    assert s["learn.dispatch.exposed_s"] == 1.0
+    assert s["learn.fetch.wait.exposed_s"] == 0.0
+    assert s["learn.fetch.copy.exposed_s"] == 2.0
+    # 100-101 and the dispatch, then everything after the wait
+    assert s["learn.step.exposed_s"] == 6.0
+    assert s["learn.step.self_s"] == 4.0
+    for name in span_names(s):
+        assert s[name + ".exposed_s"] <= s[name + ".total_s"]
+
+
+def test_a_result_still_owed_exposes_nothing_however_many_spans_pass(clock):
+    owed = Owed()
+    program.enqueued(owed)
+    with program.span("busy"):
+        for _ in range(3):
+            clock.now += 1.0
+            with program.span("busy.inner"):
+                clock.now += 1.0
+    s = program.stats()
+    assert s["busy.total_s"] == 6.0
+    assert s["busy.exposed_s"] == s["busy.inner.exposed_s"] == 0.0
+    owed.landed = True          # seen at the next boundary, not before
+    clock.now += 1.0
+    with program.span("after"):
+        clock.now += 2.0
+    assert program.stats()["after.exposed_s"] == 2.0
+
+
+@pytest.mark.parametrize("result", ["deleted", "deleted_by_hand", "collected",
+                                    "numpy", "none"])
+def test_a_result_that_is_gone_counts_as_landed(clock, result):
+    """A donated array raises from ``is_ready``, one deleted by hand is
+    not asked, a freed one is not there to ask (the table holds it
+    weakly), a host array was never owed."""
+    import gc
+
+    made = {"deleted": Deleted, "deleted_by_hand": DeletedByHand,
+            "collected": Owed,
+            "numpy": lambda: np.ones(3), "none": lambda: None}[result]()
+    program.enqueued(made)
+    if result == "collected":
+        with program.span("held"):
+            clock.now += 1.0
+        assert program.stats()["held.exposed_s"] == 0.0
+        del made
+        gc.collect()
+        clock.now += 1.0
+    with program.span("gone"):
+        clock.now += 2.0
+    assert program.stats()["gone.exposed_s"] == 2.0
+    assert program._owed is None
+
+
+def test_a_result_named_while_another_lands_is_not_forgotten(clock):
+    """The name of the newest result is the process's: a result that
+    another thread names while this thread finds the one before it
+    landed stays owed."""
+    newer = Owed()
+
+    class LandsAsAnotherIsNamed(Owed):
+        def is_ready(self):
+            program.enqueued(newer)     # as a second thread would, now
+            return True
+
+    first = LandsAsAnotherIsNamed()
+    program.enqueued(first)
+    with program.span("first.landed"):
+        clock.now += 1.0
+    assert program._owed() is newer
+    with program.span("newer.owed"):
+        clock.now += 1.0
+    assert program.stats()["newer.owed.exposed_s"] == 0.0
+
+
+def test_two_threads_keep_two_stacks_of_open_spans(table):
+    import threading
+
+    def helper():
+        with program.span("helper.work"):
+            with program.span("helper.work.inner"):
+                time.sleep(0.01)
+
+    with program.span("main.work"):
+        t = threading.Thread(target=helper)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    s = program.stats()
+    # the helper's spans are no children of what the main thread had open
+    assert s["main.work.self_s"] == s["main.work.total_s"] >= 0.01
+    assert s["helper.work.inner.self_s"] == s["helper.work.inner.total_s"]
+    assert s["helper.work.self_s"] == pytest.approx(
+        s["helper.work.total_s"] - s["helper.work.inner.total_s"], abs=1e-12)
+    assert program._thread.state.top is None
+
+
+def test_time_between_spans_after_the_first_step_is_under_no_span(clock):
+    with program.span("stage.put"):
+        clock.now += 1.0
+    clock.now += 5.0                    # before any step: nobody's
+    with program.span("learn.step"):
+        clock.now += 1.0
+    assert not any(k.startswith(program.NO_SPAN) for k in program.stats())
+    clock.now += 2.0
+    owed = Owed()
+    program.enqueued(owed)
+    with program.span("learn.step"):    # the 2 s began idle
+        clock.now += 1.0
+    clock.now += 3.0                    # these 3 s began with one owed
+    with program.span("learn.step"):
+        clock.now += 1.0
+    s = program.stats()
+    assert s[program.NO_SPAN + ".self_s"] == 5.0
+    assert s[program.NO_SPAN + ".exposed_s"] == 2.0
+    assert set(s) == {name + "." + column for column in COLUMNS
+                      for name in ("stage.put", "learn.step")} | {
+        program.NO_SPAN + ".self_s", program.NO_SPAN + ".exposed_s"}
+    program.reset()
+    assert program.stats() == {}
+    clock.now += 4.0
+    with program.span("learn.step"):
+        clock.now += 1.0
+    # a table that was emptied starts again at nothing (and the result
+    # is owed still)
+    assert program.stats() == {
+        "learn.step.n": 1, "learn.step.total_s": 1.0, "learn.step.max_s": 1.0,
+        "learn.step.self_s": 1.0, "learn.step.exposed_s": 0.0}
 
 
 # ----------------------------------------------------------- path_stats
@@ -168,7 +391,9 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
     program.reset()                     # the fixture's `init` span
     kmeans.run(blobs(), 4, 8, device_chain=chain)
     s = engine_mod.get_engine().path_stats
-    assert span_names(s) == KMEANS_SPANS
+    # the chained loop hands its first chain over before its first step
+    assert span_names(s) == KMEANS_SPANS | (
+        {"stage.compile"} if chain else set())
     versions = rabit_tpu.version_number()
     assert s["learn.versions"] == s["learn.step.n"] == versions
     assert s["learn.iterations"] == per_version * versions == 8
@@ -177,7 +402,7 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
         assert s[name + ".n"] == 1
     # every counter of the table has a reader (PERF.md section 3)
     assert {k for k in s if "." in k and k.split(".")[-1] not in
-            ("n", "total_s", "max_s")} <= {
+            COLUMNS} <= {
         "learn.iterations", "learn.versions", "learn.ahead",
         "learn.ahead_discarded", "allreduce.programs_built",
         "compile.seconds", "compile.misses", "compile.hits",
@@ -204,11 +429,12 @@ def test_chained_loop_enqueues_a_chain_ahead_of_its_fetch(
         table, empty_engine, monkeypatch, max_iter, stop_at, order):
     from rabit_tpu.learn import kmeans
 
-    seen = []
+    seen, under = [], []
     iterate, commit = kmeans.device_iterations, rabit_tpu.checkpoint
 
     def device_iterations(cent, x, valid, iters, **kw):
         seen.append(f"enqueue {iters}")
+        under.append(open_span())
         return iterate(cent, x, valid, iters, **kw)
 
     def checkpoint(model):
@@ -231,7 +457,11 @@ def test_chained_loop_enqueues_a_chain_ahead_of_its_fetch(
     assert s["learn.versions"] == s["learn.fetch.n"] == versions
     done = [int(e.split()[1]) for e in order if e.startswith("enqueue")]
     assert s["learn.iterations"] == sum(done[:versions])
-    assert s["learn.dispatch.n"] == len(done)
+    # the job's first hand-over, which compiles, is set-up's: no step
+    # holds it
+    assert under == ["stage.compile"] + ["learn.dispatch"] * (len(done) - 1)
+    assert s["stage.compile.n"] == 1
+    assert s["learn.dispatch.n"] == len(done) - 1
     if not stop_at:
         # the same centroids as the loop that goes through the host
         # after every iteration
@@ -240,6 +470,71 @@ def test_chained_loop_enqueues_a_chain_ahead_of_its_fetch(
         plain = kmeans.run(data, 4, max_iter)
         np.testing.assert_allclose(chained.centroids, plain.centroids,
                                    rtol=1e-4, atol=1e-5)
+
+
+# ---------------------- every learner's fetch split, exposed <= total
+def run_kmeans(monkeypatch, chain):
+    from rabit_tpu.learn import kmeans
+
+    kmeans.run(blobs(), 4, 8, device_chain=chain)
+    return "learn.fetch"
+
+
+def run_boosting(monkeypatch):
+    from rabit_tpu.learn import boosting
+
+    # shapes no other test's job has: the programs of a shape are kept
+    # for the process, and tests/perfbench times their compile
+    rng = np.random.default_rng(35)
+    X = rng.standard_normal((1300, 5)).astype(np.float32)
+    y = (X[:, 0] + 0.3 * rng.standard_normal(1300) > 0).astype(np.float32)
+    monkeypatch.setattr(boosting, "on_tpu", lambda: True)   # _DeviceShard
+    boosting.train(X, y, num_round=3, max_depth=3, nbin=16,
+                   min_child_weight=40.0, use_pallas=False)
+    assert program.stats()["gbdt.levels_device_scan"] > 0
+    return "gbdt.level.fetch"
+
+
+def run_lbfgs(monkeypatch):
+    from rabit_tpu.learn import LinearObjFunction
+
+    rng = np.random.default_rng(5)
+    idx = rng.integers(0, 300, (600, 6)).astype(np.int32)
+    val = (rng.integers(1, 9, (600, 6)) / 8.0).astype(np.float32)
+    obj = LinearObjFunction()
+    obj.load_arrays(idx, val, (rng.random(600) < 0.4).astype(np.float32), 300)
+    for name, value in (("reg_L1", 0.5), ("silent", 1),
+                        ("max_lbfgs_iter", 4)):
+        obj.set_param(name, str(value))
+    obj.lbfgs.run()
+    return "learn.fetch"
+
+
+@pytest.mark.parametrize("learner,runs_ahead", [
+    (lambda m: run_kmeans(m, 4), True),
+    (lambda m: run_kmeans(m, 0), False),
+    (run_boosting, False),
+    (run_lbfgs, False),
+], ids=["kmeans-chained", "kmeans-periter", "boosting-device", "lbfgs"])
+def test_every_fetch_is_a_wait_and_a_copy_and_exposed_fits_in_total(
+        table, empty_engine, monkeypatch, learner, runs_ahead):
+    program.reset()
+    outer = learner(monkeypatch)
+    s = program.stats()
+    fetches = s[outer + ".n"]
+    assert fetches >= s["learn.versions"] >= 2
+    assert s["learn.fetch.wait.n"] == s["learn.fetch.copy.n"] == fetches
+    inside = s["learn.fetch.wait.total_s"] + s["learn.fetch.copy.total_s"]
+    assert 0 < inside <= s[outer + ".total_s"]
+    for name in span_names(s):
+        assert 0 <= s[name + ".exposed_s"] <= s[name + ".total_s"] + 1e-9, name
+        assert 0 <= s[name + ".self_s"] <= s[name + ".total_s"] + 1e-9, name
+    # the host's copy and arithmetic between two programs began with
+    # nothing in flight wherever the loop runs nothing ahead
+    if not runs_ahead:
+        assert s["learn.step.exposed_s"] > 0
+        assert s["learn.fetch.copy.exposed_s"] > 0
+    assert s["learn.fetch.wait.exposed_s"] <= s["learn.fetch.wait.total_s"]
 
 
 # ------------------------------- the distributed loop, a commit ahead
@@ -262,7 +557,7 @@ class DevicePlane:
     def __init__(self, monkeypatch, base=0, in_commit=None, budget=None):
         from rabit_tpu.learn import kmeans
 
-        self.seen, self.staged, self.epoch = [], [], 0
+        self.seen, self.staged, self.epoch, self.under = [], [], 0, []
         in_commit = in_commit or {}
         stats, reduce, commit, stage = (
             kmeans.shard_stats_device, rabit_tpu.allreduce,
@@ -273,6 +568,7 @@ class DevicePlane:
             # this run() has updated the centroids of
             version = base + program.stats().get("learn.versions", 0) + 1
             self.seen.append(f"dispatch {version}")
+            self.under.append(open_span())
             return self.Queued(version, stats(model, shard))
 
         def allreduce(data, *a, **kw):
@@ -350,8 +646,8 @@ def test_distributed_loop_enqueues_the_stats_a_commit_ahead(
         want = version_order(1, 2, final=False) + version_order(3, 5, True)
         versions, ahead = 5, 3          # versions 2, 4 and 5
     else:
-        # the first version after load_checkpoint is dispatched in
-        # place, a fresh start or a resume; nothing after the last
+        # the first version after load_checkpoint is dispatched before
+        # the loop, a fresh start or a resume; nothing after the last
         first = 3 if case == "resume" else 1
         kmeans.run(data, 4, 5)
         want = version_order(first, 5, final=True)
@@ -369,7 +665,12 @@ def test_distributed_loop_enqueues_the_stats_a_commit_ahead(
     assert s.get("learn.ahead", 0) == ahead
     assert s.get("learn.ahead_discarded", 0) == (1 if case == "epoch" else 0)
     dispatched = sum(e.startswith("dispatch") for e in plane.seen)
-    assert s["learn.dispatch.n"] == dispatched
+    # the job's first hand-over, which compiles, is set-up's, and is
+    # ahead of no commit
+    assert plane.under == ["stage.compile"] + ["learn.dispatch"] * (
+        dispatched - 1)
+    assert s["stage.compile.n"] == 1
+    assert s["learn.dispatch.n"] == dispatched - 1
     assert dispatched == sum(
         e.startswith("allreduce") for e in plane.seen) + (
         case in ("leave", "epoch"))
@@ -411,7 +712,7 @@ def test_distributed_loop_a_commit_ahead_gives_the_host_arms_centroids(
 # -------------------------------------------------- the robust commit
 def test_world2_pyrobust_commit_has_its_rounds_once_a_commit(tmp_path):
     """Two processes under the tracker, telemetry on: every commit is
-    one barrier round, one apply and one acknowledgement, in the table
+    one barrier round and one acknowledgement, in the table
     and, with parent and version, in ``Engine.events()``."""
     from rabit_tpu.tracker.launch_local import launch
 
@@ -425,10 +726,10 @@ def test_world2_pyrobust_commit_has_its_rounds_once_a_commit(tmp_path):
         out = json.loads((tmp_path / f"rank{rank}.json").read_text())
         s = out["path_stats"]
         for name in ("commit", "commit.serialize", "commit.barrier",
-                     "commit.apply", "commit.ack"):
+                     "commit.ack"):
             assert s[name + ".n"] == commits, name
         inside = sum(s[f"commit.{c}.total_s"] for c in
-                     ("serialize", "barrier", "apply", "ack"))
+                     ("serialize", "barrier", "ack"))
         assert 0.5 * s["commit.total_s"] <= inside <= s["commit.total_s"]
         rounds = [e for e in out["spans"]
                   if e["kind"] in ("commit.barrier", "commit.ack")]
@@ -456,16 +757,20 @@ def test_with_rabit_obs_the_spans_are_events_and_histograms(table):
             by_kind.setdefault(e["kind"], []).append(e)
         # `init` closed around the engine's own init, before the
         # engine had telemetry to attach: the table alone has it
-        assert set(by_kind) == KMEANS_SPANS and table_now["init.n"] == 1
+        assert set(by_kind) == KMEANS_SPANS | {"stage.compile"}
+        assert table_now["init.n"] == 1
         for kind, evs in by_kind.items():
             assert len(evs) == table_now[kind + ".n"], kind
             assert all(e["dur"] >= 0 and e["rank"] == 0 for e in evs)
         steps = by_kind["learn.step"]
         assert [e["version"] for e in steps] == [1, 2]
         assert all("parent" not in e for e in steps)
-        for kind in ("learn.dispatch", "learn.fetch", "learn.update",
-                     "commit"):
+        for kind in ("learn.fetch", "learn.update", "commit"):
             assert [e["parent"] for e in by_kind[kind]] == ["learn.step"] * 2
+        # two chains: the first handed over before the loop, as set-up
+        assert [e["parent"] for e in by_kind["learn.dispatch"]] == [
+            "learn.step"]
+        assert ["parent" in e for e in by_kind["stage.compile"]] == [False]
         # a child inherits the version of the unit of work it is in
         assert [e["version"] for e in by_kind["learn.fetch"]] == [1, 2]
         assert [(e["parent"], e["version"])
@@ -499,6 +804,30 @@ class StubEngine:
 
     def event_trace(self):
         return self._t
+
+
+def test_with_rabit_obs_the_span_event_carries_self_and_exposed(clock):
+    stub = StubEngine()
+    program.attach(stub)
+    try:
+        with program.span("learn.step", version=4):
+            clock.now += 1.0
+            with program.span("learn.dispatch"):
+                program.enqueued(Owed())    # collected at once: landed
+                clock.now += 2.0
+            owed = Owed()
+            program.enqueued(owed)
+            with program.span("learn.fetch.wait"):
+                clock.now += 4.0
+    finally:
+        program.detach()
+    events = {e["kind"]: e for e in stub.event_trace().events()}
+    step = events["learn.step"]
+    assert (step["dur"], step["self"], step["exposed"]) == (7.0, 1.0, 3.0)
+    wait = events["learn.fetch.wait"]
+    assert (wait["dur"], wait["self"], wait["exposed"]) == (4.0, 4.0, 0.0)
+    assert wait["parent"] == "learn.step" and wait["version"] == 4
+    assert events["learn.dispatch"]["exposed"] == 2.0
 
 
 def test_a_span_open_across_detach_leaves_no_stale_nesting(table):
@@ -630,6 +959,45 @@ def test_span_trace_gives_idle_time_to_the_innermost_span():
     assert sum(got.values()) == 55
 
 
+def test_span_trace_puts_the_programs_exposed_seconds_beside_the_idle():
+    """What the table gained over the window, inclusive as it is, and
+    less its children's where the trace shows whose children they are."""
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    sys.path.insert(0, ROOT)
+    import span_trace
+
+    thread = [("learn.step", 0, 100), ("learn.dispatch", 5, 20),
+              ("learn.fetch", 20, 60), ("learn.fetch.wait", 21, 50),
+              ("learn.fetch.copy", 50, 59), ("commit", 60, 95),
+              ("lbfgs.eval", 100, 140), ("learn.dispatch", 101, 110),
+              # the step the end of the trace cut off: no parent event
+              ("learn.fetch", 150, 160), ("learn.fetch.copy", 151, 159)]
+    parents = span_trace.parents_of([thread])
+    assert parents["learn.step"] == {None}
+    assert parents["learn.fetch"] == {"learn.step", None}
+    assert parents["learn.fetch.copy"] == {"learn.fetch"}
+    assert parents["learn.dispatch"] == {"learn.step", "lbfgs.eval"}
+
+    def table(scale):
+        return {f"{name}.{col}": scale * value for name, row in {
+            "learn.step": (10, 50.0, 4.0, 9.0),
+            "learn.fetch": (10, 40.0, 2.0, 6.0),
+            "learn.fetch.wait": (10, 29.0, 29.0, 0.0),
+            "learn.fetch.copy": (10, 9.0, 9.0, 5.0),
+            "learn.dispatch": (20, 15.0, 15.0, 2.0),
+            "commit": (10, 3.5, 3.5, 0.5)}.items()
+            for col, value in zip(("n", "total_s", "self_s", "exposed_s"),
+                                  row)}
+
+    gained = span_trace.window_table(table(1), table(3), parents)
+    assert gained["learn.fetch"] == {
+        "n": 20, "total_s": 80.0, "self_s": 4.0, "exposed_s": 12.0,
+        "exposed_own_s": 2.0}                   # 12 - wait's 0 - copy's 10
+    assert gained["learn.fetch.copy"]["exposed_own_s"] == 10.0
+    # a child seen under two parents: whose seconds they were is unknown
+    assert "exposed_own_s" not in gained["learn.step"]
+
+
 def test_span_cost_compares_segments_with_their_neighbours():
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     sys.path.insert(0, ROOT)
@@ -659,7 +1027,7 @@ def test_alternating_switches_the_spans_off_and_on(table):
         def __call__(self):
             self.stamps.append(0.0)
 
-    on = (program.span, program.count)
+    on = (program.span, program.count, program.enqueued)
     commit = span_trace.alternating(Clock(), 2, program, on)
     try:
         seen = []
@@ -669,7 +1037,7 @@ def test_alternating_switches_the_spans_off_and_on(table):
             seen.append(program.stats().get("v.n", 0))
             commit()
     finally:
-        program.span, program.count = on
+        program.span, program.count, program.enqueued = on
     # commits 0-1 on, 2-3 off, 4-5 on, 6-7 off
     assert seen == [1, 2, 2, 2, 3, 4, 4, 4]
     assert program.stats()["v.k"] == 4

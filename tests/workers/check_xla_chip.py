@@ -71,6 +71,16 @@ def main() -> None:
     # the program's own spans saw the same calls (obs/program.py)
     assert eng.path_stats["allreduce.dispatch.n"] == ops, eng.path_stats
     assert eng.path_stats["allreduce.programs_built"] == ops
+    # forming the group ran its four phases once each, and they are
+    # most of it
+    phases = ["init.group." + p
+              for p in ("service", "barrier", "connect", "mesh")]
+    assert [eng.path_stats[p + ".n"] for p in phases] == [1] * 4
+    formed = eng.path_stats["init.group.total_s"]
+    inside = sum(eng.path_stats[p + ".total_s"] for p in phases)
+    assert 0.5 * formed <= inside <= formed, eng.path_stats
+    assert abs(eng.path_stats["init.group.self_s"]
+               - (formed - inside)) < 1e-6
     # under RABIT_DEVICE_IMPL=pallas_ring the two large payloads rode
     # the remote-DMA kernel (multi-process meshes only do on a TPU)
     ring = [eng._use_pallas_ring((n // 4,), "float32", rabit_tpu.SUM)

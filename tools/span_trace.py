@@ -8,7 +8,12 @@ own spans (``rabit_tpu/obs/program.py``) instead of the benchmark's
 three names around public calls.  ``reduce_file`` gives a span every
 idle interval it overlaps, so a parent holds its children's too; here
 each idle interval also goes to the innermost span open on the host,
-read off how the annotations nest in the trace.  Also checks the two
+read off how the annotations nest in the trace.  Beside both it prints
+what the program's own table gained over the same window
+(``exposed_s``, inclusive as the enclosing-span column is; and less its
+children's, where the trace shows a span's children under no other
+parent, as the innermost column is): the program's lower bound of the
+idle time against the trace's measurement of it.  Also checks the two
 clocks against each other: every ``rabit:allreduce.dispatch`` begins
 before the collective's program it enqueues starts on the device (after
 ``clock_shift``).
@@ -67,6 +72,42 @@ def self_intervals(threads: list[list[tuple]]) -> dict[str, list]:
             for name, spans in own.items()}
 
 
+def parents_of(threads: list[list[tuple]]) -> dict[str, set]:
+    """Per span name the names it was seen nested directly in, from the
+    same events (None: in nothing, as the outermost spans are and the
+    children of the step that was open when the trace stopped)."""
+    parents: dict[str, set] = {}
+    for events in threads:
+        open_: list[tuple] = []             # (name, end)
+        for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
+            while open_ and open_[-1][1] <= a:
+                open_.pop()
+            parents.setdefault(name, set()).add(
+                open_[-1][0] if open_ else None)
+            open_.append((name, b))
+    return parents
+
+
+def window_table(before: dict, after: dict, parents: dict) -> dict:
+    """What the program's table gained between two ``program.stats()``:
+    per span ``n``, ``total_s``, ``self_s``, ``exposed_s`` and, where
+    every child of the span was seen under it alone, ``exposed_own_s``:
+    its exposed seconds less its children's, the seconds exposed with
+    the span innermost."""
+    names = {k[:-len(".exposed_s")] for k in after
+             if k.endswith(".exposed_s")}
+    gained = {name: {col: after.get(f"{name}.{col}", 0)
+                     - before.get(f"{name}.{col}", 0)
+                     for col in ("n", "total_s", "self_s", "exposed_s")}
+              for name in names}
+    for name, row in gained.items():
+        children = [c for c, ps in parents.items() if name in ps]
+        if all(parents[c] - {None} == {name} for c in children):
+            row["exposed_own_s"] = row["exposed_s"] - sum(
+                gained.get(c, {}).get("exposed_s", 0.0) for c in children)
+    return gained
+
+
 def idle_intervals(profile) -> tuple[list, float]:
     """The intervals in which nothing ran on the device (one device
     plane a process), on the host's clock, and the shift applied."""
@@ -117,8 +158,9 @@ def reduce_trace(path: str, prefix: str) -> dict:
     reduced = tr.reduce_file(path, prefix)
     profile = jax.profiler.ProfileData.from_file(path)
     idle, shift = idle_intervals(profile)
-    inner = {name: tr._overlap(idle, cover) * 1e-9 for name, cover in
-             self_intervals(host_events(profile, prefix)).items()}
+    threads = host_events(profile, prefix)
+    inner = {name: tr._overlap(idle, cover) * 1e-9
+             for name, cover in self_intervals(threads).items()}
     idle_s = reduced["window_s"] - reduced["busy_s"]
     inner[NO_SPAN] = idle_s - sum(inner.values())
     return {"window_s": reduced["window_s"], "busy_s": reduced["busy_s"],
@@ -128,6 +170,8 @@ def reduce_trace(path: str, prefix: str) -> dict:
             "no_span_share_of_idle":
                 inner[NO_SPAN] / idle_s if idle_s > 0 else 0.0,
             "idle_by_enclosing_span": reduced["gaps"],
+            "parents": {name: sorted(ps, key=str)
+                        for name, ps in parents_of(threads).items()},
             "dispatch": dispatch_leads(profile, prefix, shift),
             "device_ops": sorted(
                 ([k, v[0]] for k, v in reduced["ops"].items()),
@@ -150,13 +194,13 @@ class NoSpan:
 
 def alternating(clock, every: int, program, on: tuple):
     """The commit wrapper of ``--cost-every``: after the n-th commit the
-    spans and counters are ``on`` if ``n // every`` is even and no-ops
-    if odd, on every rank alike."""
-    off = (NoSpan, lambda name, k=1: None)
+    spans, counters and ``enqueued`` are ``on`` if ``n // every`` is even
+    and no-ops if odd, on every rank alike."""
+    off = (NoSpan, lambda name, k=1: None, lambda result: None)
 
     def commit(*args, **kwargs):
         clock(*args, **kwargs)
-        program.span, program.count = (
+        program.span, program.count, program.enqueued = (
             off if (len(clock.stamps) // every) % 2 else on)
     return commit
 
@@ -229,6 +273,9 @@ def main(argv=None) -> int:
     data = learner.make_data(
         cfg, args.seed, rank, world,
         max(1, min(8, (os.cpu_count() or 1) // world)), args.rows, None)
+    # the adapter's eyes on its learner, opened as the harness opens
+    # them: the boosting adapters' job counts on them
+    undo = list(learner.watch(data, harness.Spans(annotate=False), False))
     trace_dir = os.path.join(out_dir, f"trace-{rank}")
     # one word all ranks of this launch map, and no other launch
     stop_path = os.path.join(out_dir, "stop-" + os.environ.get(
@@ -240,15 +287,25 @@ def main(argv=None) -> int:
         opts.host_tracer_level = 1
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
 
+    table = {}                          # the program's, at both ends
+
+    def open_window():
+        table["before"] = program.stats()
+        start_trace()
+
+    def close_window():
+        jax.profiler.stop_trace()
+        table["after"] = program.stats()
+
     commit = rabit_tpu.checkpoint
     warmup = int(traffic.get("warmup_versions", 2))
     traced = not args.cost_every
     clock = VersionClock(
         commit, rabit_tpu.version_number, args.seconds, warmup, rank == 0,
         StopWord(stop_path) if world > 1 else None,
-        on_open=start_trace if traced else None,
-        on_close=jax.profiler.stop_trace if traced else None)
-    spans = (program.span, program.count)
+        on_open=open_window if traced else None,
+        on_close=close_window if traced else None)
+    spans = (program.span, program.count, program.enqueued)
     rabit_tpu.checkpoint = clock if traced else alternating(
         clock, args.cost_every, program, spans)
     try:
@@ -258,11 +315,16 @@ def main(argv=None) -> int:
         pass
     finally:
         rabit_tpu.checkpoint = commit
-        program.span, program.count = spans
+        program.span, program.count, program.enqueued = spans
+        for owner, name, fn in reversed(undo):
+            setattr(owner, name, fn)
     if traced:
         result = reduce_trace(trace_reduce.find_xplane(trace_dir),
                               program.PREFIX)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        result["program_window"] = window_table(
+            table["before"], table["after"],
+            {name: set(ps) for name, ps in result["parents"].items()})
     else:
         result = span_cost(clock.counted(), warmup - 1, args.cost_every)
     result.update(workload=args.workload, rank=rank, seed=args.seed,
@@ -282,8 +344,16 @@ def main(argv=None) -> int:
           f"{result['idle_s']:.3f} s of {result['window_s']:.3f} s; "
           f"no span: {100 * result['no_span_share_of_idle']:.1f}% of idle; "
           f"dispatch {json.dumps(result['dispatch'])}")
+    program_s = result["program_window"]
+    print(f"  {'span':24s} {'idle, innermost':>16s} {'exposed, own':>14s}"
+          f" {'idle, enclosing':>16s} {'exposed_s':>11s}")
     for name, seconds in result["idle_by_innermost_span"].items():
-        print(f"  {name:24s} {seconds:9.4f} s")
+        row = program_s.get(name, {})
+        own = row.get("exposed_own_s")
+        print(f"  {name:24s} {seconds:16.4f} "
+              + (f"{own:14.4f}" if own is not None else f"{'-':>14s}")
+              + f" {result['idle_by_enclosing_span'].get(name, 0.0):16.4f}"
+              f" {row.get('exposed_s', 0.0):11.4f}")
     return 0
 
 
