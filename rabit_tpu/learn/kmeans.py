@@ -377,8 +377,40 @@ def centroid_update(cent, stats):
     counts = stats[:, -1:]
     new = jnp.where(counts > 0,
                     stats[:, :-1] / jnp.maximum(counts, 1.0), cent)
-    norm = jnp.linalg.norm(new, axis=1, keepdims=True)
-    return jnp.where(norm < 1e-6, new, new / jnp.maximum(norm, 1e-30))
+    return _unit_rows(new)
+
+
+def _unit_rows(m):
+    """Rows scaled to unit L2 norm by :meth:`KMeansModel.normalize`'s
+    rule (rows with ~zero norm are left unscaled), on the device."""
+    import jax.numpy as jnp
+
+    norm = jnp.linalg.norm(m, axis=1, keepdims=True)
+    return jnp.where(norm < 1e-6, m, m / jnp.maximum(norm, 1e-30))
+
+
+def _update_fn():
+    """Jitted: the distributed loop's centroid update on the device.
+
+    Takes the allreduced (k, d+1) stats and returns the new centroids,
+    float32, and the counts column: sums over counts, then the row
+    normalisation, as the host arm does them in numpy.  A cluster that
+    received no row divides by zero here; the loop reads the counts
+    beside the centroids and aborts before it commits such a version
+    (the reference's rule), so nothing keeps the previous centroid as
+    :func:`centroid_update` does for the chained loop."""
+    fn = _STEP_CACHE.get("update")
+    if fn is None:
+        import jax
+
+        @jax.jit
+        def kmeans_update(stats):
+            with jax.named_scope("kmeans/update"):
+                counts = stats[:, -1:]
+                return _unit_rows(stats[:, :-1] / counts), counts
+
+        fn = _STEP_CACHE["update"] = kmeans_update
+    return fn
 
 
 def _device_loop_fn(iters: int, use_pallas: bool, block: int | None,
@@ -550,23 +582,25 @@ def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
     return ("ell", feat_dim, device_ell(idx, val, valid, row_block))
 
 
-def shard_stats_device(model: KMeansModel, shard):
+def shard_stats_device(centroids, shard):
     """Per-iteration (k, d+1) stats for a staged shard, left on device
     (a ``jax.Array`` — feed it straight to the XLA engine's allreduce so
-    the reduction rides ICI)."""
+    the reduction rides ICI).  ``centroids`` is a (k, d) float32 array
+    of the host or of the device: the distributed loop hands over what
+    its update program left there."""
     kind, feat_dim, payload = shard
-    k, d = model.centroids.shape
+    k, d = centroids.shape
     if kind == "dense":
         fn = _dense_stats_fn(k, d, payload.shape[1])
-        return fn(model.centroids, payload)
+        return fn(centroids, payload)
     if kind == "dense16":
         x, v16 = payload
-        return _dense16_stats_fn(k, d, x.shape[1])(model.centroids, x, v16)
+        return _dense16_stats_fn(k, d, x.shape[1])(centroids, x, v16)
     if kind == "ell_fused":
-        return _ell_fused_stats(model.centroids, payload, d)
+        return _ell_fused_stats(centroids, payload, d)
     idx, val, valid = payload  # pre-blocked by device_ell: (nb, block, nnz)
     fn = _stats_fn(k, d, idx.shape[1], idx.shape[2])
-    return fn(model.centroids, idx, val, valid)
+    return fn(centroids, idx, val, valid)
 
 
 def _dense16_stats_fn(k: int, d: int, dp: int):
@@ -646,11 +680,6 @@ def _ell_fused_stats(centroids, payload, d: int):
     return jnp.concatenate([stats[:, :d], stats[:, -1:]], axis=1)
 
 
-def shard_stats(model: KMeansModel, shard) -> np.ndarray:
-    """Per-iteration (k, d+1) stats for a staged shard."""
-    return np.asarray(shard_stats_device(model, shard))
-
-
 def device_ell(idx, val, valid, row_block: int = DEFAULT_ROW_BLOCK):
     """Move ELL arrays to the accelerator once, pre-blocked.
 
@@ -701,15 +730,22 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     while the next chain already runs.
 
     The distributed loop on the device plane (XLA engine, several ranks)
-    likewise runs a step ahead of its commit: once version v's centroids
-    are updated, the stats program of v+1 (local, no collective) is
-    enqueued, and only then is v committed, so the commit's host rounds
-    run under the kernel.  Every version is still committed before the
-    next version's allreduce is issued, and a resumed job recomputes at
-    most the one uncommitted version, as before.  A result queued across
+    likewise runs a step ahead of its commit, and keeps the reduced
+    statistics on the chip: the allreduce's result goes straight into
+    the update program (:func:`_update_fn`: divide, normalise), the
+    stats program of v+1 (local, no collective) is enqueued on the
+    centroids that leaves on the device, and only then does the host
+    fetch the new centroids (and the counts, for the zero-sized-cluster
+    check) and commit them as fetched, so the fetch's copy and the
+    commit's host rounds run under the kernel.  Every version is still
+    committed before the next version's allreduce is issued, and a
+    resumed job recomputes at most the one uncommitted version, from
+    bit for bit the centroids the device held.  A result queued across
     a re-formation of the device plane (``rabit_tpu.device_epoch``
-    moved inside the commit) is dropped unread and dispatched afresh on
-    the re-staged shard.  Host engines keep the lazy path.
+    moved inside the commit) is dropped unread with the centroids it
+    was computed on, and dispatched afresh from the model on the
+    re-staged shard.  Host engines keep the lazy path and the numpy
+    update.
 
     ``hash_dim`` (power of two) clusters in SIGNED-HASHED feature space
     instead of the original one: every downstream stage — init,
@@ -861,25 +897,43 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
 
     device_plane = _engine_mod.is_device_plane()
 
-    def enqueue_stats(span="learn.dispatch"):
-        with program.span(span):
-            local = shard_stats_device(model, shard)
-            program.enqueued(local)
+    def enqueue_stats(centroids):
+        local = shard_stats_device(centroids, shard)
+        program.enqueued(local)
         return local
 
-    # On the device plane the stats program of the next version is
-    # enqueued before this version is committed: it needs the updated
-    # centroids and nothing of the commit, whose host rounds then run
-    # under the kernel.  It is local and collective-free; the next
-    # version's allreduce is still issued after this commit returns.
-    # The job's first hand-over is set-up's, as in the chained loop: it
-    # compiles the stats program where the process has not run it yet.
-    # (A host engine calls for the stats from inside its allreduce, and
-    # skips the call in a replay: its first step holds the compile.)
+    def on_device(centroids):
+        # committed to this rank's device, as the update program leaves
+        # its result: a jitted program is compiled anew for an operand
+        # that is committed where the one it first saw was not
+        import jax
+
+        return jax.device_put(centroids, jax.local_devices()[0])
+
+    # On the device plane the reduced statistics never leave the chip
+    # between two kernels: the update program takes this rank's replica
+    # of the allreduce's result, and the stats program of the next
+    # version is enqueued on the centroids it leaves on the device,
+    # before the host has waited for anything.  Only then does the host
+    # fetch the new centroids and the counts, bind the model to what it
+    # fetched (bit for bit what the device iterates on, so a resumed
+    # job continues from the state the undisturbed one had) and commit:
+    # the fetch's copy and the commit's host rounds run under a kernel
+    # that was queued before the previous one ended.  That kernel is
+    # local and collective-free; the next version's allreduce is still
+    # issued after this commit returns.
+    # The job's first hand-over of either program is set-up's, as in
+    # the chained loop: it compiles them where the process has not run
+    # them yet.  (A host engine calls for the stats from inside its
+    # allreduce, and skips the call in a replay: its first step holds
+    # the compile.)
     epoch = rabit_tpu.device_epoch()
     queued = None
     if device_plane and version < max_iter:
-        queued = enqueue_stats("stage.compile")
+        update = _update_fn()
+        with program.span("stage.compile"):
+            update(on_device(np.ones((k, feat_dim + 1), np.float32)))
+            queued = enqueue_stats(on_device(model.centroids))
     for it in range(version, max_iter):
         if rabit_tpu.device_epoch() != epoch:
             # the device plane was re-formed at a checkpoint boundary
@@ -887,7 +941,10 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
             # backends — re-upload the shard, then continue at full speed
             epoch = rabit_tpu.device_epoch()
             if queued is not None:
-                # so did the result queued ahead: dropped unread
+                # so did the result queued ahead, and the centroids it
+                # was computed on: dropped unread, and the loop starts
+                # again from the host's copy of the last committed
+                # version
                 queued = None
                 program.count("learn.ahead_discarded")
             shard = prepare_shard(idx, val, valid, feat_dim, row_block,
@@ -896,36 +953,49 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
         with program.span("learn.step", version=it + 1):
             if device_plane:
                 if queued is None:
-                    local = enqueue_stats()
+                    with program.span("learn.dispatch"):
+                        local = enqueue_stats(on_device(model.centroids))
                 else:
                     local, queued = queued, None
                     if it > version:     # the job's first: of no commit
                         program.count("learn.ahead")
                 total = rabit_tpu.allreduce(local, SUM)
+                with program.span("learn.update"):
+                    if not total.is_fully_addressable:
+                        # replicated over the process mesh: this rank's
+                        # replica, no copy and no wait
+                        total = total.addressable_shards[0].data
+                    cent, counts = update(on_device(total))
+                    program.enqueued(cent)
+                program.count("learn.device_updates")
+                if it + 1 < max_iter:
+                    with program.span("learn.dispatch"):
+                        queued = enqueue_stats(cent)
                 with program.span("learn.fetch"):
-                    stats = fetch(total)
+                    fetched, counts = fetch((cent, counts))
+                program.count("learn.iterations")
+                check(bool((counts != 0).all()), "get zero sized cluster")
+                model.centroids = fetched
             else:
                 stats = np.zeros((k, feat_dim + 1), np.float32)
 
                 def lazy_stats(stats=stats):
-                    local = enqueue_stats()
+                    with program.span("learn.dispatch"):
+                        local = enqueue_stats(model.centroids)
                     with program.span("learn.fetch"):
                         stats[...] = fetch(local)
 
                 stats = rabit_tpu.allreduce(stats, SUM,
                                             prepare_fun=lazy_stats)
-            program.count("learn.iterations")
-            with program.span("learn.update"):
-                counts = stats[:, -1:]
-                check(bool((counts != 0).all()), "get zero sized cluster")
-                model.centroids = (stats[:, :-1] / counts).astype(
-                    np.float32)
-                model.normalize()
+                program.count("learn.iterations")
+                with program.span("learn.update"):
+                    counts = stats[:, -1:]
+                    check(bool((counts != 0).all()),
+                          "get zero sized cluster")
+                    model.centroids = (stats[:, :-1] / counts).astype(
+                        np.float32)
+                    model.normalize()
             program.count("learn.versions")
-            if device_plane and it + 1 < max_iter:
-                # `learn.update` bound a new centroid array, which
-                # nothing writes from here on (the commit reads it)
-                queued = enqueue_stats()
             rabit_tpu.checkpoint(model)
 
     if out_model and rabit_tpu.get_rank() == 0:

@@ -274,6 +274,30 @@ def _ring(nbytes, topo):
         sharding=NamedSharding(mesh, P("dp")))).compile()
 
 
+def test_distributed_update_program_compiles_for_v5e(topo):
+    """The per-iteration loop's two device programs at the x4 cell's
+    shapes, as the loop hands them over (operands committed to one
+    chip): the update of the allreduced (64, 257) statistics, plain XLA
+    that leaves float32 centroids and the counts column, and the stats
+    program that takes those centroids with a rank's 24.1M rows."""
+    from rabit_tpu.learn import kmeans as km
+
+    (stats,) = _one_chip(topo, ((64, 257), jnp.float32))
+    update = km._update_fn().lower(stats).compile()
+    assert "tpu_custom_call" not in update.as_text()
+    cent, counts = update.output_shardings
+    assert cent == counts == stats.sharding
+    shapes = jax.eval_shape(km._update_fn(), stats)
+    assert [(o.shape, o.dtype) for o in shapes] == [
+        ((64, 256), jnp.float32), ((64, 1), jnp.float32)]
+    n = 23 << 20
+    step = km._dense16_stats_fn(64, 256, 256).lower(*_one_chip(
+        topo, ((64, 256), jnp.float32), ((n, 256), jnp.bfloat16),
+        ((n,), jnp.float32))).compile()
+    assert "tpu_custom_call" in step.as_text()
+    assert step.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("build", [
     _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
     functools.partial(_hist_level_chunked, 16), _hist_level_wide,

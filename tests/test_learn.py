@@ -422,7 +422,7 @@ def test_dense16_staging_matches_f32(empty_engine):
 
     exact = kmeans.prepare_shard(idx, val, valid, 16, row_block=64)
     assert exact[0] == "dense"
-    ref = np.asarray(kmeans.shard_stats_device(model, exact))
+    ref = np.asarray(kmeans.shard_stats_device(model.centroids, exact))
 
     half = kmeans.prepare_shard(idx, val, valid, 16, row_block=64,
                                 budget=0, compute_dtype="bfloat16")
@@ -432,7 +432,7 @@ def test_dense16_staging_matches_f32(empty_engine):
     # features staged at the lane-padded width so stats calls never
     # re-pad the array
     assert x.shape[1] == 128
-    got = np.asarray(kmeans.shard_stats_device(model, half))
+    got = np.asarray(kmeans.shard_stats_device(model.centroids, half))
     np.testing.assert_allclose(got, ref, rtol=3e-2, atol=3e-2)
     # padded tail must be inert: counts equal
     np.testing.assert_allclose(got[:, -1], ref[:, -1])
@@ -443,7 +443,7 @@ def test_dense16_staging_matches_f32(empty_engine):
     odd = kmeans.prepare_shard(idx3, val3, valid3, 16, row_block=96,
                                budget=0, compute_dtype="bfloat16")
     assert odd[0] == "dense16"
-    got3 = np.asarray(kmeans.shard_stats_device(model, odd))
+    got3 = np.asarray(kmeans.shard_stats_device(model.centroids, odd))
     np.testing.assert_allclose(got3, ref, rtol=3e-2, atol=3e-2)
 
 

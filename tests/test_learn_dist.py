@@ -80,23 +80,29 @@ def test_kmeans_app_on_xla_engine_death_reform(tmp_path, native_lib):
     """kmeans.run over the XLA engine with a mid-run death: the relaunch
     resumes from the checkpoint, the device plane re-forms at the next
     checkpoint boundary, and kmeans re-uploads its device shard (epoch
-    change) — final centroids still agree across all ranks."""
+    change) — final centroids still agree across all ranks, and are the
+    same job's without a death: the relaunch resumes from the committed
+    centroids, which are the device's own, bit for bit."""
     from rabit_tpu.tracker.launch_local import launch
 
     world = 3
     X = _blobs()
     pattern, _full = _shard_files(tmp_path, X, np.zeros(len(X)), world)
-    out = str(tmp_path / "cent_xla_reform")
-    code = launch(world, [sys.executable,
-                          "tests/workers/kmeans_run_xla.py",
-                          pattern, "3", "5", out],
-                  extra_env={"RABIT_INNER": "native",
-                             "RABIT_KMEANS_DIE": "1:2"},
-                  watchdog_sec=20)
-    assert code == 0
-    cent = np.load(out + ".npy")
+    cents = {}
+    for name, die in (("undisturbed", {}),
+                      ("reform", {"RABIT_KMEANS_DIE": "1:2"})):
+        out = str(tmp_path / ("cent_xla_" + name))
+        code = launch(world, [sys.executable,
+                              "tests/workers/kmeans_run_xla.py",
+                              pattern, "3", "5", out],
+                      extra_env={"RABIT_INNER": "native", **die},
+                      watchdog_sec=20)
+        assert code == 0
+        cents[name] = np.load(out + ".npy")
+    cent = cents["reform"]
     cn = cent / np.linalg.norm(cent, axis=1, keepdims=True)
     assert sorted(np.argmax(cn, axis=1)) == [0, 1, 2]
+    np.testing.assert_array_equal(cent, cents["undisturbed"])
 
 
 def test_kmeans_distributed_with_faults(tmp_path, native_lib):

@@ -209,7 +209,7 @@ def test_kmeans_ell_stats_fused_validation():
 
 def test_prepare_shard_ell_fused_path(monkeypatch):
     """On a (faked) TPU backend an over-budget shard takes the fused
-    path with slot/row padding, and shard_stats matches the scan path."""
+    path with slot/row padding, and its stats match the scan path."""
     import jax as _jax
 
     from rabit_tpu.learn import kmeans as km
@@ -250,7 +250,7 @@ def test_prepare_shard_ell_fused_path(monkeypatch):
         return orig(*a, **kw)
 
     monkeypatch.setattr(kk, "kmeans_ell_stats_fused", interp)
-    got = np.asarray(km.shard_stats_device(model, shard))
+    got = np.asarray(km.shard_stats_device(model.centroids, shard))
 
     dense = _ell_to_dense(idx, val, d)
     want = _xla_stats(model.centroids, dense, valid)

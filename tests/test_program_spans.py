@@ -404,7 +404,8 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
     assert {k for k in s if "." in k and k.split(".")[-1] not in
             COLUMNS} <= {
         "learn.iterations", "learn.versions", "learn.ahead",
-        "learn.ahead_discarded", "allreduce.programs_built",
+        "learn.ahead_discarded", "learn.device_updates",
+        "allreduce.programs_built",
         "compile.seconds", "compile.misses", "compile.hits",
         "stage.clamped"}
     assert s["stage.clamped"] == 0
@@ -548,6 +549,9 @@ class DevicePlane:
     result travels in a ``Queued`` that only the allreduce opens, so a
     result that was dropped unread shows as a dispatch no allreduce
     follows.  ``in_commit[v]`` runs inside commit v, after the real one.
+    ``given[v]`` is the centroid array version v's stats program was
+    handed (a copy), ``committed[v]`` the centroids commit v wrote;
+    ``spans`` holds every span's opening and closing, in order.
     """
 
     class Queued:
@@ -558,29 +562,44 @@ class DevicePlane:
         from rabit_tpu.learn import kmeans
 
         self.seen, self.staged, self.epoch, self.under = [], [], 0, []
+        self.given, self.committed, self.spans = {}, {}, []
         in_commit = in_commit or {}
         stats, reduce, commit, stage = (
             kmeans.shard_stats_device, rabit_tpu.allreduce,
             rabit_tpu.checkpoint, kmeans.prepare_shard)
+        enter, leave_ = program.span.__enter__, program.span.__exit__
+        spans = self.spans
 
-        def shard_stats_device(model, shard):
+        def span_enter(span):
+            spans.append(("open", span.name))
+            return enter(span)
+
+        def span_exit(span, *exc):
+            spans.append(("close", span.name))
+            return leave_(span, *exc)
+
+        def shard_stats_device(centroids, shard):
             # the version whose stats these are: one past the versions
             # this run() has updated the centroids of
-            version = base + program.stats().get("learn.versions", 0) + 1
+            version = base + program.stats().get(
+                "learn.device_updates", 0) + 1
             self.seen.append(f"dispatch {version}")
             self.under.append(open_span())
-            return self.Queued(version, stats(model, shard))
+            self.given[version] = np.array(centroids)
+            return self.Queued(version, stats(centroids, shard))
 
         def allreduce(data, *a, **kw):
             if not isinstance(data, self.Queued):
                 return reduce(data, *a, **kw)   # the feature-width one
-            self.seen.append(f"allreduce {data.version}")
-            return data.array                   # world 1: the sum is it
+            with program.span("allreduce"):
+                self.seen.append(f"allreduce {data.version}")
+                return data.array               # world 1: the sum is it
 
         def checkpoint(model):
             commit(model)
             version = rabit_tpu.version_number()
             self.seen.append(f"commit {version}")
+            self.committed[version] = np.array(model.centroids)
             in_commit.get(version, lambda: None)()
 
         def prepare_shard(*a, **kw):
@@ -596,9 +615,21 @@ class DevicePlane:
         monkeypatch.setattr(rabit_tpu, "allreduce", allreduce)
         monkeypatch.setattr(rabit_tpu, "checkpoint", checkpoint)
         monkeypatch.setattr(rabit_tpu, "device_epoch", lambda: self.epoch)
+        monkeypatch.setattr(program.span, "__enter__", span_enter)
+        monkeypatch.setattr(program.span, "__exit__", span_exit)
 
     def move_epoch(self):
         self.epoch += 1
+
+    def steps(self):
+        """The spans' openings and closings, a list a ``learn.step``."""
+        steps = []
+        for event in self.spans:
+            if event == ("open", "learn.step"):
+                steps.append([])
+            elif steps:
+                steps[-1].append(event)
+        return steps
 
 
 def leave():
@@ -662,6 +693,7 @@ def test_distributed_loop_enqueues_the_stats_a_commit_ahead(
     s = program.stats()
     assert s["learn.versions"] == s["learn.iterations"] == versions
     assert s["learn.step.n"] == s["learn.fetch.n"] == versions
+    assert s["learn.device_updates"] == s["learn.update.n"] == versions
     assert s.get("learn.ahead", 0) == ahead
     assert s.get("learn.ahead_discarded", 0) == (1 if case == "epoch" else 0)
     dispatched = sum(e.startswith("dispatch") for e in plane.seen)
@@ -677,36 +709,168 @@ def test_distributed_loop_enqueues_the_stats_a_commit_ahead(
     assert rabit_tpu.version_number() == (2 if case == "leave" else 5)
 
 
-@pytest.mark.parametrize("tier,dtype,budget", [
+TIERS = [
     ("dense", "float32", None),         # densified float32 blocks
     ("dense16", "bfloat16", 0),         # half-width rows
     ("ell", "float32", 0),              # blocked ELL, the scan path
-])
+]
+
+
+def run_device_arm(monkeypatch, tier, dtype, budget, iters=6, reform_in=None):
+    """``kmeans.run`` through the device-plane arm on ``blobs()``, the
+    shard in ``tier``, the epoch moved inside commit ``reform_in``;
+    returns the fixture and the model."""
+    from rabit_tpu.learn import kmeans
+
+    with monkeypatch.context() as patched:
+        plane = DevicePlane(patched, budget=budget, in_commit={
+            reform_in: lambda: plane.move_epoch()})
+        model = kmeans.run(blobs(), 4, iters, compute_dtype=dtype)
+    assert {s[0] for s in plane.staged} == {tier}
+    return plane, model
+
+
+def fresh_job():
+    rabit_tpu.finalize()
+    program.reset()
+    rabit_tpu.init(rabit_engine="empty")
+
+
+@pytest.mark.parametrize("tier,dtype,budget", TIERS)
 @pytest.mark.parametrize("epoch_moves", [False, True], ids=["", "reform"])
 def test_distributed_loop_a_commit_ahead_gives_the_host_arms_centroids(
         table, empty_engine, monkeypatch, tier, dtype, budget, epoch_moves):
     """The same iterations whichever arm runs them and whether or not a
-    queued result is dropped on the way: on the CPU a jitted call may
-    read its numpy operand in place, so centroids written after the
-    dispatch ahead would show here."""
+    queued result is dropped on the way.  The two arms are two float32
+    implementations of a division and a norm (numpy's, XLA's), so they
+    agree to a rounding; a run of the device arm whose epoch moved agrees
+    with its own undisturbed run exactly."""
     from rabit_tpu.learn import kmeans
 
-    data = blobs()
-    with monkeypatch.context() as patched:
-        plane = DevicePlane(
-            patched, budget=budget, in_commit={
-                3: lambda: plane.move_epoch()} if epoch_moves else None)
-        ahead = kmeans.run(data, 4, 6, compute_dtype=dtype)
-    assert [s[0] for s in plane.staged] == [tier] * (2 if epoch_moves else 1)
+    plane, ahead = run_device_arm(
+        monkeypatch, tier, dtype, budget, reform_in=3 if epoch_moves else None)
+    assert len(plane.staged) == (2 if epoch_moves else 1)
+    assert plane.epoch == int(epoch_moves)
     assert program.stats()["learn.ahead"] == (4 if epoch_moves else 5)
-    rabit_tpu.finalize()
-    rabit_tpu.init(rabit_engine="empty")
+    if epoch_moves:
+        fresh_job()
+        _, undisturbed = run_device_arm(monkeypatch, tier, dtype, budget)
+        np.testing.assert_array_equal(ahead.centroids, undisturbed.centroids)
+    fresh_job()
     stage = kmeans.prepare_shard
     if budget is not None:
         monkeypatch.setattr(kmeans, "prepare_shard", lambda *a, **kw: stage(
             *a, **{**kw, "budget": budget}))
-    plain = kmeans.run(data, 4, 6, compute_dtype=dtype)
-    np.testing.assert_array_equal(ahead.centroids, plain.centroids)
+    plain = kmeans.run(blobs(), 4, 6, compute_dtype=dtype)
+    np.testing.assert_allclose(ahead.centroids, plain.centroids, rtol=1e-6)
+
+
+@pytest.mark.parametrize("tier,dtype,budget", TIERS)
+def test_device_arm_hands_three_programs_over_before_it_waits(
+        table, empty_engine, monkeypatch, tier, dtype, budget):
+    """In a step of the device arm the allreduce, the update program
+    and the next version's stats program are all handed over before the
+    host waits for anything, and every version's centroids are computed
+    on the device."""
+    plane, _ = run_device_arm(monkeypatch, tier, dtype, budget, iters=5)
+    steps = plane.steps()
+    assert len(steps) == 5
+    for v, step in enumerate(steps, 1):
+        wait = step.index(("open", "learn.fetch.wait"))
+        before = step[:wait]
+        assert ("close", "allreduce") in before
+        assert ("close", "learn.update") in before
+        # the last version enqueues nothing after itself
+        assert (("close", "learn.dispatch") in before) == (v < 5), v
+        assert (before.index(("close", "allreduce"))
+                < before.index(("open", "learn.update")))
+        if v < 5:
+            assert (before.index(("close", "learn.update"))
+                    < before.index(("open", "learn.dispatch")))
+        # what is left of the step after the hand-overs: the fetch,
+        # then the commit under the kernel queued ahead
+        after = [name for kind, name in step[wait:] if kind == "open"]
+        assert after[:2] == ["learn.fetch.wait", "learn.fetch.copy"]
+        assert "commit" in after and "learn.dispatch" not in after
+    s = program.stats()
+    assert s["learn.device_updates"] == s["learn.versions"] == 5
+    assert s["learn.fetch.wait.n"] == 5
+
+
+@pytest.mark.parametrize("tier,dtype,budget", TIERS)
+def test_device_arm_commits_what_the_next_stats_program_was_given(
+        table, empty_engine, monkeypatch, tier, dtype, budget):
+    """The committed centroids of every version are, bit for bit, the
+    array the next version's stats program was handed: the host binds
+    what it fetched and recomputes nothing, so a resumed job continues
+    from the state the undisturbed one had."""
+    plane, model = run_device_arm(monkeypatch, tier, dtype, budget)
+    assert sorted(plane.committed) == [1, 2, 3, 4, 5, 6]
+    assert sorted(plane.given) == [1, 2, 3, 4, 5, 6]
+    for v in range(1, 6):
+        assert plane.committed[v].dtype == np.float32
+        np.testing.assert_array_equal(plane.committed[v], plane.given[v + 1])
+    np.testing.assert_array_equal(plane.committed[6], model.centroids)
+    # unit rows, by the model's own rule
+    np.testing.assert_allclose(
+        np.linalg.norm(model.centroids, axis=1), 1.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("tier,dtype,budget", TIERS)
+def test_device_arm_with_the_epoch_moved_in_a_commit_ends_where_it_would(
+        table, empty_engine, monkeypatch, tier, dtype, budget):
+    """The device plane re-formed inside commit 3: the result queued for
+    version 4 and the device centroids it was computed on are dropped,
+    the loop restarts from the host's copy of the committed version 3,
+    which is bit for bit what the device held, and every later version
+    is the undisturbed run's exactly."""
+    _, undisturbed = run_device_arm(monkeypatch, tier, dtype, budget)
+    fresh_job()
+    plane, moved = run_device_arm(monkeypatch, tier, dtype, budget,
+                                  reform_in=3)
+    s = program.stats()
+    assert s["learn.ahead_discarded"] == 1
+    assert s["learn.device_updates"] == s["learn.versions"] == 6
+    # version 4 was dispatched twice, the second time from the host's
+    # copy of commit 3 on the shard staged anew
+    assert plane.seen.count("dispatch 4") == 2
+    assert plane.seen.count("stage") == 2
+    np.testing.assert_array_equal(plane.given[4], plane.committed[3])
+    np.testing.assert_array_equal(moved.centroids, undisturbed.centroids)
+
+
+@pytest.mark.parametrize("tier,dtype,budget", TIERS)
+def test_device_arm_compiles_its_update_program_in_set_up(
+        table, empty_engine, monkeypatch, tier, dtype, budget):
+    """The update program's first hand-over is set-up's, beside the
+    job's first stats program: no step of the loop holds a compile, of
+    either program (an operand committed to the device where the first
+    one was not would compile the stats program a second time)."""
+    from rabit_tpu.learn import kmeans
+    from rabit_tpu.utils import compile_cache
+
+    assert compile_cache.count_compiles() is not None
+    kmeans._STEP_CACHE.clear()          # programs of an earlier test
+    under, count = [], program.count
+
+    def seen_count(name, k=1):
+        if name == "compile.misses" and k > 0:   # a request to compile
+            span = program._thread.state.top
+            while span._parent is not None:
+                span = span._parent
+            under.append(span.name)
+        count(name, k)
+
+    monkeypatch.setattr(program, "count", seen_count)
+    with monkeypatch.context() as patched:
+        DevicePlane(patched, budget=budget)
+        kmeans.run(blobs(n=2048 + 512, seed=37), 4, 4, compute_dtype=dtype)
+    # the update program and the stats program; staging's own besides,
+    # and no step's
+    assert under.count("stage.compile") >= 2
+    assert set(under) <= {"stage.compile", "stage.put"}
+    s = program.stats()
+    assert s["stage.compile.n"] == 1 and s["learn.step.n"] == 4
 
 
 # -------------------------------------------------- the robust commit

@@ -55,6 +55,10 @@ def main() -> int:
     # a re-formation dropped the result it had queued in the old epoch
     stats = engine.get_engine().path_stats
     assert stats.get("learn.ahead", 0) >= 1, stats
+    # every version's centroids were computed on the device, from the
+    # reduced statistics that never left it (in a degraded version too:
+    # the host transport's sum goes back to the device)
+    assert stats["learn.device_updates"] == stats["learn.versions"], stats
     if die and trial == 0 and rabit_tpu.device_epoch() > 0:
         assert stats.get("learn.ahead_discarded", 0) >= 1, stats
 
