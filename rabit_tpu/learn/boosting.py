@@ -8,8 +8,11 @@ module closes that loop with a compact binned GBDT so the histogram
 path is exercised end-to-end as a real app: logistic or squared loss,
 level-wise trees, split gain from second-order statistics.
 
-TPU-native notes: features are quantile-binned once, on the device,
-and stay there with every other per-row quantity; per-node histograms
+TPU-native notes: features are quantile-binned on the device (once
+under ``tree_method="hist"``; before every tree under ``"approx"``, on
+cuts sketched from that round's hessians, the float values resident
+beside the bins) and stay there with every other per-row quantity;
+per-node histograms
 come from the MXU one-hot contraction in :mod:`rabit_tpu.learn.histogram`
 with node membership folded into the grad/hess operand inside the
 kernel.  Below the root a level builds one child of every node split
@@ -48,11 +51,22 @@ class TreeNode:
     # learned default direction for missing values (XGBoost's
     # sparsity-aware split; rows absent at the feature go this way)
     default_left: bool = True
+    # the split as a float, as XGBoost's trees hold it: the cut at
+    # bin_threshold of the cuts the tree was grown on, left if value <
+    # split (bin <= threshold says the same of a value binned by those
+    # cuts).  A tree committed before the field existed reads 0.0
+    split: float = 0.0
 
 
 @dataclass
 class BoostedModel:
-    """A forest of binned trees + the quantile cuts that define bins."""
+    """A forest of binned trees and the quantile cuts their bins are
+    defined by.  Under ``tree_method="hist"`` one ``cuts`` for the
+    forest, the quantiles of rank 0's :func:`cut_sample`, and a row is
+    routed by its bin.  Under ``"approx"`` every tree was grown on cuts
+    of its own (``tree_cuts[k]``, the sketch of every rank's rows under
+    round k's hessians), ``cuts`` holds their shape and no cut, and a
+    row is routed by its value against the node's float ``split``."""
 
     cuts: np.ndarray = field(
         default_factory=lambda: np.zeros((0, 0), np.float32))
@@ -65,9 +79,15 @@ class BoostedModel:
     # re-issue that collective — an op the survivors don't issue in the
     # same span would break the robust engine's replay alignment.
     has_missing: bool = False
+    tree_method: str = "hist"
+    # (f, nbin - 1) float32 a tree under "approx" (28.6 KB at 28 x 255);
+    # a model committed before the field existed has none
+    tree_cuts: list = field(default_factory=list)
 
-    def _tree_margin(self, tree: list[TreeNode], bins: np.ndarray
-                     ) -> np.ndarray:
+    def _tree_margin(self, tree: list[TreeNode], bins: np.ndarray,
+                     by_value: bool = False) -> np.ndarray:
+        """One tree's leaf weight a row, routed by ``bins`` or, with
+        ``by_value``, by the float values in their place."""
         missing_code = self.cuts.shape[1] + 1
         node = np.zeros(bins.shape[0], np.int32)
         out = np.zeros(bins.shape[0], np.float32)
@@ -84,23 +104,28 @@ class BoostedModel:
                     live[rows] = False
                 else:
                     b = bins[rows, n.feature]
-                    go_left = np.where(b == missing_code,
-                                       getattr(n, "default_left", True),
-                                       b <= n.bin_threshold)
+                    absent, below = (np.isnan(b), b < n.split) if by_value \
+                        else (b == missing_code, b <= n.bin_threshold)
+                    go_left = np.where(
+                        absent, getattr(n, "default_left", True), below)
                     idx = np.flatnonzero(rows)
                     node[idx[go_left]] = n.left
                     node[idx[~go_left]] = n.right
         return out
 
-    def margin(self, bins: np.ndarray) -> np.ndarray:
+    def margin(self, bins: np.ndarray, by_value: bool = False
+               ) -> np.ndarray:
         out = np.full(bins.shape[0], self.base_score, np.float32)
         for tree in self.trees:
-            out += self.learning_rate * self._tree_margin(tree, bins)
+            out += self.learning_rate * self._tree_margin(tree, bins,
+                                                          by_value)
         return out
 
     def predict(self, values: np.ndarray) -> np.ndarray:
-        bins = apply_cuts(values, self.cuts)
-        m = self.margin(bins)
+        if self.tree_method == "approx":
+            m = self.margin(np.asarray(values, np.float32), by_value=True)
+        else:
+            m = self.margin(apply_cuts(values, self.cuts))
         if self.loss == "logistic":
             return 1.0 / (1.0 + np.exp(-m))
         return m
@@ -118,13 +143,18 @@ def _grad_hess(margin: np.ndarray, labels: np.ndarray, loss: str):
     return (margin - labels).astype(np.float32), np.ones_like(margin)
 
 
-# rows the quantile cuts are taken from (XGBoost's sketch is approximate
-# too): a shard up to this size gives every row
+TREE_METHODS = ("hist", "approx")
+
+# rows the quantile cuts of tree_method="hist" are taken from (XGBoost's
+# sketch is approximate too): a shard up to this size gives every row
 CUT_SAMPLE_ROWS = 1 << 20
 
 
 def cut_sample(values: np.ndarray) -> np.ndarray:
-    """The strided sample of a shard that defines its cuts."""
+    """The strided sample of a shard that defines its cuts under
+    ``tree_method="hist"``: every ``n // CUT_SAMPLE_ROWS``-th row, at
+    most ``CUT_SAMPLE_ROWS`` of them.  (``"approx"`` samples nothing:
+    its sketch covers every row.)"""
     return values[::max(1, values.shape[0] // CUT_SAMPLE_ROWS)][
         :CUT_SAMPLE_ROWS]
 
@@ -174,9 +204,21 @@ def _leaf_values(tree, slots, leaves, max_depth: int) -> np.ndarray:
     return vals
 
 
-def _replay(shard, trees, max_depth: int) -> None:
-    """Add committed trees to the shard's margin (a resume)."""
-    for tree in trees:
+def _fill_splits(tree: list[TreeNode], cuts: np.ndarray) -> None:
+    """Every split's float value from the cuts the tree was grown on."""
+    for node in tree:
+        if node.feature >= 0:
+            node.split = float(cuts[node.feature, node.bin_threshold])
+
+
+def _replay(shard, model, max_depth: int) -> None:
+    """Add committed trees to the shard's margin (a resume).  A tree
+    grown under ``"approx"`` routes the rows binned by its own cuts
+    again, which is the walk by ``value < split``: a bin is at or under
+    the threshold exactly where the value is under that cut."""
+    for k, tree in enumerate(model.trees):
+        if model.tree_method == "approx":
+            shard.rebin(model.tree_cuts[k])
         slots, leaves = [0], []
         for _depth in range(max_depth):
             if all(nid < 0 for nid in slots):
@@ -197,18 +239,45 @@ class _HostShard:
         self.max_depth, self.subsample, self.seed = max_depth, subsample, seed
         self.kw = {"use_pallas": use_pallas, "compute_dtype": compute_dtype}
         self.nbin = nbin
-        with program.span("stage.bin"):
-            self.bins = apply_cuts(values, model.cuts)
-        absent = int(np.count_nonzero(self.bins == nbin))   # the code
+        self.approx = model.tree_method == "approx"
+        if self.approx:
+            # binned anew every round: the values stay
+            self.values = np.asarray(values, np.float32)
+            self.bins = np.zeros(values.shape, np.int32)
+            absent = int(np.count_nonzero(np.isnan(self.values)))
+            self.max_bin = nbin * (absent > 0)              # the code
+        else:
+            with program.span("stage.bin"):
+                self.bins = apply_cuts(values, model.cuts)
+            absent = int(np.count_nonzero(self.bins == nbin))   # the code
+            self.max_bin = int(self.bins.max(initial=0))
         program.count("gbdt.entries", self.bins.size)
         program.count("gbdt.entries_missing", absent)
         self.any_nan = absent > 0
-        self.max_bin = int(self.bins.max(initial=0))
 
     def start(self, has_missing: bool) -> None:
         self.has_missing = has_missing
-        self.margin = self.model.margin(self.bins)
+        self.margin = self.model.margin(self.values, by_value=True) \
+            if self.approx else self.model.margin(self.bins)
         self.node = np.zeros(self.n, np.int32)
+
+    def sketch(self):
+        """This rank's slot of the merge's payload
+        (``histogram.sketch_program``) under the round's hessians."""
+        fn = histogram.sketch_program(
+            self.n, self.values.shape[1], self.nbin,
+            rabit_tpu.get_world_size(), rabit_tpu.get_rank())
+        return fn(np.ascontiguousarray(self.values.T),
+                  np.stack([self.grad, self.hess]))
+
+    def cuts_of(self, merged):
+        """The cuts of the merged summaries, as every rank computes
+        them."""
+        return histogram.cuts_program(
+            merged.shape[0], merged.shape[1], self.nbin)(merged)
+
+    def rebin(self, cuts) -> None:
+        self.bins = apply_cuts(self.values, np.asarray(cuts))
 
     def grad_hess(self, round_idx: int) -> None:
         self.grad, self.hess = _grad_hess(self.margin, self.labels,
@@ -287,7 +356,13 @@ class _DeviceShard:
         self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
         self.subsample, self.seed = subsample, seed
         self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
-        self.bins_t, seen = histogram.stage_bins(values, model.cuts, nbin)
+        self.approx = model.tree_method == "approx"
+        if self.approx:
+            self.values_t, self.bins_t, seen = histogram.stage_values(
+                values, nbin)
+        else:
+            self.bins_t, seen = histogram.stage_bins(values, model.cuts,
+                                                     nbin)
         with program.span("stage.put"):
             self.labels = jax.device_put(np.asarray(labels, np.float32))
         self.any_nan, self.max_bin = (int(v) for v in np.asarray(seen))
@@ -298,9 +373,20 @@ class _DeviceShard:
         self.has_missing = has_missing
         with program.span("stage.compile"):
             self.prog = self._programs()
+            if self.approx:
+                world = rabit_tpu.get_world_size()
+                self.prog = dict(
+                    self.prog,
+                    sketch=histogram.sketch_program(
+                        self.n, self.f, self.nbin, world,
+                        rabit_tpu.get_rank()),
+                    cuts=histogram.cuts_program(world, self.f, self.nbin),
+                    rebin=histogram.rebin_program(
+                        self.n, self.f, self.bins_t.shape[0],
+                        self.model.cuts.shape[1]))
         self.margin = jnp.full((self.n,), self.model.base_score, jnp.float32)
         self.node = jnp.zeros((self.n,), jnp.int32)
-        _replay(self, self.model.trees, self.max_depth)
+        _replay(self, self.model, self.max_depth)
 
     def _programs(self) -> dict:
         """The job's programs, compiled for its shapes: ``grad``,
@@ -426,6 +512,29 @@ class _DeviceShard:
         self.gh = self.prog["grad"](self.margin, self.labels, *keep)
         program.enqueued(self.gh)
 
+    def sketch(self):
+        payload = self.prog["sketch"](self.values_t, self.gh)
+        program.enqueued(payload)
+        return payload
+
+    def cuts_of(self, merged):
+        import jax.numpy as jnp
+
+        if not isinstance(merged, np.ndarray) \
+                and not merged.is_fully_addressable:
+            # the device plane's result, replicated over the processes
+            merged = merged.addressable_shards[0].data
+        cuts = self.prog["cuts"](jnp.asarray(merged))
+        program.enqueued(cuts)
+        return cuts
+
+    def rebin(self, cuts) -> None:
+        import jax.numpy as jnp
+
+        self.bins_t = self.prog["rebin"](self.bins_t, self.values_t,
+                                         jnp.asarray(cuts, jnp.float32))
+        program.enqueued(self.bins_t)
+
     def level(self, build):
         calls = histogram.level_calls(len(build), self.bins_t.shape[0],
                                       self.nbin, self.use_pallas)
@@ -470,6 +579,25 @@ def _reduce_level(local) -> np.ndarray:
     with program.span("gbdt.level.fetch"):
         local = fetch(local, histogram._writable)
     return rabit_tpu.allreduce(local.reshape(-1), SUM).reshape(shape)
+
+
+def _merge_summaries(payload, on_device: bool):
+    """One ``rabit_tpu.allreduce`` a round of the sketch's payload (this
+    rank's summary in its own slot, zeros in the others:
+    ``histogram.sketch_program``), issued at world 1 too: the sum is
+    every rank's summary side by side, the same bytes on every rank.
+    A sum of slots and not ``allreduce_custom`` or ``allgather``: it is
+    the one collective every engine carries on the device plane and
+    replays after a failure, adding zeros is exact and commutative where
+    a merge that prunes is neither, and where the engine hands a device
+    array back (``on_device``) the summary never crosses the host.
+    Through a host engine it is fetched first, as a level is."""
+    program.count("gbdt.summary_bytes_merged", payload.nbytes)
+    if on_device:
+        return rabit_tpu.allreduce(payload, SUM)
+    shape = payload.shape
+    host = fetch(payload, histogram._writable)
+    return rabit_tpu.allreduce(host.reshape(-1), SUM).reshape(shape)
 
 
 def _fetch_shortlist(feats, rows):
@@ -597,13 +725,38 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
           min_child_weight: float = 1e-3,
           subsample: float = 1.0, seed: int = 0,
           use_pallas: bool | None = None,
-          compute_dtype: str | None = None) -> BoostedModel:
+          compute_dtype: str | None = None,
+          tree_method: str = "hist") -> BoostedModel:
     """Train a distributed booster on this rank's row shard.
 
-    Deterministic across ranks: cuts come from rank 0 (the quantiles of
-    :func:`cut_sample` of its shard), every split decision is taken on
-    the allreduced histogram.  Resumes from the last committed round
-    after a failure (checkpoint per round).
+    Deterministic across ranks: every rank holds the same cuts and
+    every split decision is taken on the allreduced histogram.  Resumes
+    from the last committed round after a failure (checkpoint per
+    round).
+
+    ``tree_method`` is XGBoost's parameter.  Under ``"hist"`` the cuts
+    are made once: the unweighted quantiles of :func:`cut_sample` of
+    rank 0's shard, by numpy on the host, broadcast; the rows are
+    binned once.  Under ``"approx"`` (the approximate greedy algorithm
+    of the XGBoost paper, section 3.3) they are made before every tree
+    from every row of every rank, with the round's hessians as weights:
+    each rank sorts every feature's values with their weights
+    (``gbdt_sketch``) into a summary of ``histogram.summary_entries``
+    exact weighted quantiles a feature, the summaries cross ranks by
+    one ``rabit_tpu.allreduce`` a round (issued at world 1 too:
+    :func:`_merge_summaries`), every rank computes the same cuts from
+    the same bytes (``gbdt_cuts``; weighted rank error at most
+    ``histogram.sketch_eps``) and bins its rows by them again, over the
+    old bins (``gbdt_rebin``).  The tree is then grown as under
+    ``"hist"``, by the same programs.  The committed model holds each
+    tree's cuts and every split's float value, and routes by value.
+    Round k + 1's gradients and its sort of the rows are enqueued
+    before round k is committed; the collective, the cuts and the
+    binning follow the commit, so that nothing of round k + 1 is agreed
+    on before round k is safe.  A resumed rank replays the committed
+    trees by their own cuts, computes the gradients and the summary of
+    the round it died in again and joins that round's collective: the
+    cuts are a function of the committed forest and the rows.
 
     On an accelerator, and under the XLA engine's device plane, the
     rows live on the device for the whole job (``_DeviceShard``): they
@@ -657,18 +810,30 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     """
     check(0.0 < subsample <= 1.0, "subsample must be in (0, 1], got %s",
           subsample)
+    check(tree_method in TREE_METHODS, "tree_method must be one of %s, "
+          "got %r", TREE_METHODS, tree_method)
+    approx = tree_method == "approx"
     version, restored = rabit_tpu.load_checkpoint()
     if version == 0:
-        # rank 0's shard defines the cuts; other ranks just receive them
-        with program.span("stage.cuts"):
-            cuts = rabit_tpu.broadcast(
-                histogram.quantile_cuts(cut_sample(values), nbin)
-                if rabit_tpu.get_rank() == 0 else None, 0)
+        if approx:
+            # every tree brings its own cuts: none to agree on yet
+            cuts = np.zeros((values.shape[1], nbin - 1), np.float32)
+        else:
+            # rank 0's shard defines the cuts; other ranks just receive
+            # them
+            with program.span("stage.cuts"):
+                cuts = rabit_tpu.broadcast(
+                    histogram.quantile_cuts(cut_sample(values), nbin)
+                    if rabit_tpu.get_rank() == 0 else None, 0)
         model = BoostedModel(cuts=cuts, base_score=0.0,
                              learning_rate=learning_rate, loss=loss,
-                             has_missing=False)
+                             has_missing=False, tree_method=tree_method)
     else:
         model = restored
+        # (a forest committed before the field existed reads "hist")
+        check(model.tree_method == tree_method,
+              "boosting: the committed forest was grown with tree_method="
+              "%r, this job asks for %r", model.tree_method, tree_method)
         rabit_tpu.tracker_print(
             "[%d] restart iter=%d" % (rabit_tpu.get_rank(), version))
     device_arm = on_tpu() or _engine_mod.is_device_plane()
@@ -707,6 +872,18 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     level_of: dict = {}     # depth -> the level's histograms on the host
     epoch = rabit_tpu.device_epoch()
     ready = -1                      # the round whose (grad, hess) is made
+    payload = None                  # and, under approx, whose rows are sorted
+
+    def open_round(idx: int):
+        """The round's first programs: its gradients and, under
+        ``"approx"``, the sort of every feature under its hessians."""
+        with program.span("gbdt.grad"):
+            shard.grad_hess(idx)
+        if not approx:
+            return None
+        with program.span("gbdt.sketch"), program.span("learn.dispatch"):
+            return shard.sketch()
+
     for round_idx in range(version, num_round):
         with program.span("learn.step", version=round_idx + 1):
             if device_arm and rabit_tpu.device_epoch() != epoch:
@@ -715,8 +892,21 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 epoch = rabit_tpu.device_epoch()
                 shard, ready = stage(), -1
             if ready != round_idx:
-                with program.span("gbdt.grad"):
-                    shard.grad_hess(round_idx)
+                payload = open_round(round_idx)
+            if approx:
+                # the summaries cross ranks, every rank derives the
+                # same cuts and bins its rows by them, over the old bins
+                with program.span("gbdt.sketch"):
+                    with program.span("gbdt.sketch.merge"):
+                        merged = _merge_summaries(payload, device_scan)
+                    with program.span("gbdt.sketch.cuts"):
+                        cuts = shard.cuts_of(merged)
+                with program.span("gbdt.rebin"):
+                    shard.rebin(cuts)
+                program.count("gbdt.sketches")
+                program.count("gbdt.summary_entries",
+                              payload.shape[1] * payload.shape[2])
+                program.count("gbdt.rows_rebinned", shard.n)
             tree: list[TreeNode] = [TreeNode()]
             slots, leaves = [0], []
             # the level slot built for each slot of the level above (the
@@ -782,12 +972,18 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
             # their parents' histograms gave them
             with program.span("gbdt.leaf"):
                 shard.leaf(_leaf_values(tree, slots, leaves, max_depth))
+            if approx:
+                # long made by now: 28.6 KB at 28 x 255
+                with program.span("gbdt.sketch"), \
+                        program.span("gbdt.sketch.cuts"):
+                    cuts = fetch(cuts, np.array)
+                model.tree_cuts.append(cuts)
+            _fill_splits(tree, cuts if approx else model.cuts)
             model.trees.append(tree)
             if round_idx + 1 < num_round:
-                # the next round's first program is enqueued before the
-                # commit, whose host rounds then run beside it
-                with program.span("gbdt.grad"):
-                    shard.grad_hess(round_idx + 1)
+                # the next round's first programs are enqueued before
+                # the commit, whose host rounds then run beside them
+                payload = open_round(round_idx + 1)
                 ready = round_idx + 1
             program.count("learn.iterations")
             program.count("learn.versions")

@@ -133,6 +133,12 @@ STAGE_CHUNK_ROWS = 1 << 20
 STAGE_CHUNK_BYTES = 1 << 28
 
 
+def stage_chunk_rows(n: int, f: int) -> int:
+    """Rows of one chunk of an ``(n, f)`` float32 shard on its way to
+    the device."""
+    return max(1, min(n, STAGE_CHUNK_ROWS, STAGE_CHUNK_BYTES // (4 * f)))
+
+
 def staged_features(f: int, nbin: int) -> int:
     """Feature rows of the staged bins array: ``f`` rounded up to the
     fused kernel's feature group, so that a call pads nothing."""
@@ -199,7 +205,7 @@ def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
     bins_t = jnp.zeros((fpad, n), jnp.int32)
     seen = jnp.zeros((2,), jnp.int32)
     absent = jnp.zeros((f,), jnp.int32)
-    chunk = max(1, min(n, STAGE_CHUNK_ROWS, STAGE_CHUNK_BYTES // (4 * f)))
+    chunk = stage_chunk_rows(n, f)
     for lo in range(0, n, chunk):
         c = min(chunk, n - lo)
         with program.span("stage.compile"):
@@ -214,6 +220,299 @@ def stage_bins(values: np.ndarray, cuts: np.ndarray, nbin: int):
     program.count("gbdt.entries_missing",
                   int(np.asarray(absent).sum(dtype=np.int64)))
     return bins_t, seen
+
+
+# ----------------------------------------------------------------------
+# the weighted quantile sketch (``tree_method="approx"``)
+# ----------------------------------------------------------------------
+# XGBoost sizes its summaries by a factor of 8 over ``max_bin``: a cut's
+# weighted rank is within ``sketch_eps`` = 1 / (8 * nbin) of its target
+SKETCH_EPS_FACTOR = 8
+# entries of one rank's summary of one feature: its exact weighted
+# quantiles at this spacing.  Between two neighbours lies under 1 / K of
+# the rank's weight, which is all a merge does not know of the rank:
+# the cuts of a merged summary are within 2 / K = sketch_eps / 2 of
+# their targets whatever the number of ranks, and exact at world 1
+SUMMARY_FACTOR = 4 * SKETCH_EPS_FACTOR
+# weights are added up in float32, a block of this many at a time and
+# the blocks' totals after: no chain is longer than a block or than the
+# number of blocks (XLA's scans are trees besides), so that a prefix is
+# good to a few ulp of the total where a running float32 sum of 2^25
+# quarters would stall at 2^22
+SKETCH_SCAN_BLOCK = 4096
+
+
+def sketch_eps(nbin: int) -> float:
+    """The weighted rank error a cut may have, as a share of the
+    feature's summed weight."""
+    return 1.0 / (SKETCH_EPS_FACTOR * nbin)
+
+
+def summary_entries(nbin: int) -> int:
+    """Entries of a rank's summary of one feature."""
+    return SUMMARY_FACTOR * nbin
+
+
+def _prefix(w):
+    """Inclusive prefix sums of ``(n,)`` float32 weights, traceable."""
+    import jax.numpy as jnp
+
+    n = w.shape[0]
+    block = min(SKETCH_SCAN_BLOCK, n)
+    pad = -n % block
+    c = jnp.cumsum(jnp.pad(w, (0, pad)).reshape(-1, block), axis=1)
+    ends = c[:, -1]
+    return (c + (jnp.cumsum(ends) - ends)[:, None]).reshape(-1)[:n]
+
+
+def _order_keys(v):
+    """int32 keys whose order is the float32 values', traceable: NaN
+    (absent) last, -0.0 with 0.0.  The device sorts integers a tenth
+    faster than floats, and compiles the sort in two thirds of the
+    time."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(jnp.where(v == 0, 0.0, v), jnp.int32)
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jnp.where(jnp.isnan(v), jnp.int32(0x7FFFFFFF), keys)
+
+
+def _key_values(keys):
+    """The float32 values of :func:`_order_keys`' keys."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.bitcast_convert_type(
+        jnp.where(keys < 0, keys ^ jnp.int32(0x7FFFFFFF), keys), jnp.float32)
+
+
+def sketch_summary(values_t, weights, entries: int):
+    """One rank's summary ``(f, entries, 3)`` float32 of its rows,
+    traceable: for feature ``j`` the exact weighted quantiles of
+    ``values_t[j]`` (``(f, n)`` float32, NaN absent) under ``weights``
+    (``(n,)`` float32 >= 0: the round's hessians), entry ``k`` the
+    ``(value, rmin, rmax)`` of the first value, in order, at which the
+    summed weight reaches ``(k + 1) / entries`` of the feature's total:
+    ``rmin`` the weight of the rows below the value and ``rmax`` that of
+    the rows at or below it (a tie counts as the interval it spans), so
+    that ``rmax`` of the last entry is the total.  An absent value takes
+    no weight and no entry, a row of weight 0 (sampled out) no entry; a
+    feature without weight reads zeros.
+
+    A feature at a time: its values are sorted with their weights, as
+    integer keys of the same order (the device's work is the same
+    whatever either holds), the weights added up in float32
+    (:func:`_prefix`) and the entries found by bisection.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    n = values_t.shape[1]
+    share = jnp.arange(1, entries + 1, dtype=jnp.float32) / entries
+
+    def one(v):
+        keys, sw = jax.lax.sort(
+            (_order_keys(v), jnp.where(jnp.isnan(v), 0.0, weights)),
+            num_keys=1)
+        c = _prefix(sw)
+        total = c[-1]
+        at = jnp.minimum(jnp.searchsorted(c, share * total), n - 1)
+        key = keys[at]
+        first = jnp.searchsorted(keys, key, side="left")
+        last = jnp.searchsorted(keys, key, side="right") - 1
+        rmin = jnp.where(first > 0, c[jnp.maximum(first - 1, 0)], 0.0)
+        out = jnp.stack([_key_values(key), rmin, c[last]], axis=1)
+        return jnp.where(total > 0, out, 0.0)
+
+    return jax.lax.map(one, values_t)
+
+
+def sketch_cuts(summaries, nbin: int):
+    """``(f, nbin - 1)`` float32 cuts from every rank's summary
+    ``(ranks, f, entries, 3)`` (:func:`sketch_summary`), traceable: cut
+    ``i`` is the first value, among the summaries' own, at or below
+    which lies ``i / nbin`` of the weight of all ranks.  What a rank
+    holds at or below a value is, from its summary, exact where the
+    value is an entry of it and else the middle of what the entries on
+    either side allow, which is under ``1 / entries`` of the rank's
+    weight wide.  So a cut's weighted rank interval is within
+    ``2 / entries`` of its target (the spacing of the candidates and
+    the merge's doubt), and holds it at one rank.  Every rank computes
+    this from the same bytes and gets the same cuts; cuts may repeat; a
+    feature without weight reads zeros."""
+    import jax
+    import jax.numpy as jnp
+
+    ranks, f, entries, _ = summaries.shape
+
+    def one(summary):                       # (ranks, entries, 3)
+        cand = summary[:, :, 0].reshape(-1)
+
+        def held(s):                        # a rank's weight <= candidates
+            value, rmin, rmax = s[:, 0], s[:, 1], s[:, 2]
+            k = jnp.searchsorted(value, cand, side="right")
+            below = jnp.where(k > 0, rmax[jnp.maximum(k - 1, 0)], 0.0)
+            above = jnp.where(k < entries,
+                              rmin[jnp.minimum(k, entries - 1)], rmax[-1])
+            exact = (k > 0) & (value[jnp.maximum(k - 1, 0)] == cand)
+            return jnp.where(exact, below, 0.5 * (below + above))
+
+        upto = jnp.sum(jax.vmap(held)(summary), axis=0)
+        if ranks > 1:       # one rank's entries are in order as they are
+            cand, upto = jax.lax.sort((cand, upto), num_keys=1)
+        total = jnp.sum(summary[:, -1, 2])
+        want = total * (jnp.arange(1, nbin, dtype=jnp.float32) / nbin)
+        at = jnp.minimum(jnp.searchsorted(upto, want), cand.shape[0] - 1)
+        return jnp.where(total > 0, cand[at], 0.0)
+
+    return jax.vmap(one, in_axes=1)(summaries)
+
+
+def sketch_program(n: int, f: int, nbin: int, world: int = 1, rank: int = 0):
+    """Compiled ``gbdt_sketch``: from the resident values ``(f, n)`` and
+    the round's ``(2, n)`` (grad, hess) to what the merge's one
+    allreduce carries, ``(world, f, entries, 3)`` float32: this rank's
+    :func:`sketch_summary` under the hessians in its own slot and zeros
+    in the others, so that a sum over ranks is every rank's summary
+    side by side, exact and the same bytes everywhere."""
+    key = ("sketch", n, f, nbin, world, rank)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        entries = summary_entries(nbin)
+
+        def gbdt_sketch(values_t, gh):
+            with jax.named_scope("gbdt/sketch"):
+                mine = sketch_summary(values_t, gh[1], entries)
+                if world == 1:
+                    return mine[None]
+                return jnp.zeros((world,) + mine.shape, jnp.float32).at[
+                    rank].set(mine)
+
+        sds = jax.ShapeDtypeStruct
+        fn = _CACHE[key] = jax.jit(gbdt_sketch).lower(
+            sds((f, n), jnp.float32), sds((2, n), jnp.float32)).compile()
+    return fn
+
+
+def cuts_program(world: int, f: int, nbin: int):
+    """Compiled ``gbdt_cuts``: :func:`sketch_cuts` of the merged
+    summaries ``(world, f, entries, 3)``."""
+    key = ("cuts", world, f, nbin)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        def gbdt_cuts(summaries):
+            with jax.named_scope("gbdt/sketch"):
+                return sketch_cuts(summaries, nbin)
+
+        fn = _CACHE[key] = jax.jit(gbdt_cuts).lower(jax.ShapeDtypeStruct(
+            (world, f, summary_entries(nbin), 3), jnp.float32)).compile()
+    return fn
+
+
+def rebin(bins_t, values_t, cuts):
+    """The resident values ``(f, n)`` binned by ``(f, ncut)`` cuts into
+    the first ``f`` rows of the staged ``(fpad, n)`` array, traceable;
+    the rows of padding keep what they hold (zeros).  Equal to
+    :func:`apply_cuts` bit for bit, NaN included.  A feature at a time,
+    each row written where it lies: the whole array at once costs a
+    second one of its size."""
+    import jax
+    import jax.numpy as jnp
+
+    f, ncut = cuts.shape
+
+    def feature(j, bins_t):
+        v = jax.lax.dynamic_index_in_dim(values_t, j, keepdims=False)
+        c = jax.lax.dynamic_index_in_dim(cuts, j, keepdims=False)
+        # cuts <= value, counted: searchsorted(side="right"); a NaN is
+        # below every cut and takes the missing code
+        b = jnp.sum(c[:, None] <= v[None, :], axis=0, dtype=jnp.int32)
+        b = jnp.where(jnp.isnan(v), ncut + 1, b)
+        return jax.lax.dynamic_update_slice(bins_t, b[None], (j, 0))
+
+    return jax.lax.fori_loop(0, f, feature, bins_t)
+
+
+def rebin_program(n: int, f: int, fpad: int, ncut: int):
+    """Compiled ``gbdt_rebin``: :func:`rebin` written over the old bins
+    (the argument is donated: no second array of that size)."""
+    key = ("rebin", n, f, fpad, ncut)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        def gbdt_rebin(bins_t, values_t, cuts):
+            with jax.named_scope("gbdt/rebin"):
+                return rebin(bins_t, values_t, cuts)
+
+        sds = jax.ShapeDtypeStruct
+        fn = _CACHE[key] = jax.jit(gbdt_rebin, donate_argnums=(0,)).lower(
+            sds((fpad, n), jnp.int32), sds((f, n), jnp.float32),
+            sds((f, ncut), jnp.float32)).compile()
+    return fn
+
+
+def stage_values(values: np.ndarray, nbin: int):
+    """Keep a shard's float values on the device for a job that bins
+    them anew every round (``tree_method="approx"``): returns the
+    ``(f, n)`` float32 array :func:`sketch_program` and
+    :func:`rebin_program` read, the ``(fpad, n)`` int32 array the bins
+    will be written to (zeros until the first round's cuts are there)
+    and a (2,) int32 device array ``[any NaN, the missing code if so]``
+    as :func:`stage_bins` gives it.  The values cross a chunk of rows at
+    a time as there; counts the staged entries and the absent ones."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.obs import program
+
+    n, f = values.shape
+    chunk = stage_chunk_rows(n, f)
+
+    def compiled(c: int):
+        key = ("stage_values", n, c, f)
+        fn = _CACHE.get(key)
+        if fn is None:
+            def gbdt_stage(values_t, absent, vals, lo):
+                with jax.named_scope("gbdt/stage"):
+                    v = vals.T
+                    absent = absent + jnp.sum(jnp.isnan(v), axis=1,
+                                              dtype=jnp.int32)
+                    return jax.lax.dynamic_update_slice(
+                        values_t, v, (jnp.int32(0), lo)), absent
+
+            sds = jax.ShapeDtypeStruct
+            fn = _CACHE[key] = jax.jit(
+                gbdt_stage, donate_argnums=(0, 1)).lower(
+                    sds((f, n), jnp.float32), sds((f,), jnp.int32),
+                    sds((c, f), jnp.float32), sds((), jnp.int32)).compile()
+        return fn
+
+    values_t = jnp.zeros((f, n), jnp.float32)
+    absent = jnp.zeros((f,), jnp.int32)
+    for lo in range(0, n, chunk):
+        c = min(chunk, n - lo)
+        with program.span("stage.compile"):
+            fn = compiled(c)
+        with program.span("stage.put"):
+            vals = jax.device_put(
+                np.ascontiguousarray(values[lo:lo + c], np.float32))
+        with program.span("stage.bin"):
+            values_t, absent = fn(values_t, absent, vals, np.int32(lo))
+    missing = int(np.asarray(absent).sum(dtype=np.int64))
+    program.count("gbdt.entries", n * f)
+    program.count("gbdt.entries_missing", missing)
+    bins_t = jnp.zeros((staged_features(f, nbin), n), jnp.int32)
+    return values_t, bins_t, jnp.asarray(
+        [missing > 0, nbin * (missing > 0)], jnp.int32)
 
 
 def _level_xla(bins_t, gh, node, nslots: int, nbin: int, block: int = 4096):

@@ -164,6 +164,37 @@ def test_stage_slice_writer_updates_the_shard_in_place_on_v5e(topo):
     assert m.temp_size_in_bytes <= 1 << 20
 
 
+def test_approx_rebin_writes_over_the_old_bins_on_v5e(topo):
+    """``tree_method="approx"`` bins the resident values again every
+    round at the cell's shape (28 x 33.5M float32 into (32, n) int32):
+    the donated bins are the output and the program holds a feature's
+    temporaries beside them, not a second array of 4.29 GB."""
+    from rabit_tpu.learn import histogram
+
+    n, f = 32 << 20, 28
+    fpad = histogram.staged_features(f, 256)
+    m = jax.jit(histogram.rebin, donate_argnums=(0,)).lower(*_one_chip(
+        topo, ((fpad, n), jnp.int32), ((f, n), jnp.float32),
+        ((f, 255), jnp.float32))).compile().memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes == fpad * n * 4
+    assert m.temp_size_in_bytes <= 3 * n * 4, m
+
+
+def test_approx_cuts_program_of_four_ranks_compiles_for_v5e(topo):
+    """The cuts of four ranks' merged summaries (28 x 8,192 entries a
+    rank): kilobytes out of 11 MB, in about their own size.  (The sketch
+    itself holds a sort of 2^25 pairs, which this compiler takes a
+    minute and a half over: the chip's own runs compile it.)"""
+    from rabit_tpu.learn import histogram
+
+    entries = histogram.summary_entries(256)
+    m = jax.jit(lambda s: histogram.sketch_cuts(s, 256)).lower(*_one_chip(
+        topo, ((4, 28, entries, 3), jnp.float32))).compile(
+        ).memory_analysis()
+    assert m.output_size_in_bytes <= 28 * 256 * 4 + 4096
+    assert m.temp_size_in_bytes <= 8 * 4 * 28 * entries * 3 * 4, m
+
+
 def test_wide_level_scan_holds_a_level_and_hands_over_kilobytes_on_v5e(topo):
     """The wide boosting cell's deepest ``gbdt/scan`` as ``boosting.
     _DeviceShard`` builds it: 16 built slots of 968 features and the

@@ -867,3 +867,288 @@ def test_allreduce_sees_the_built_slots_once_a_level_before_any_scoring(
     for k in range(3):
         call, handed, scanned = events[3 * k:3 * k + 3]
         assert call[0] == "allreduce" and scanned == ("scan", handed[1], k)
+
+
+# ----------------------------------------------------------------------
+# tree_method="approx": cuts sketched from the hessians before every
+# tree, rows binned again every round, a model that routes by value
+# ----------------------------------------------------------------------
+def _splits(model):
+    return [[n.split for n in tree] for tree in model.trees]
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["dense", "nan"])
+@pytest.mark.parametrize("kw", [
+    {"loss": "logistic"}, {"loss": "squared"},
+    {"loss": "logistic", "subsample": 0.7, "seed": 3},
+], ids=["logistic", "squared", "subsample"])
+def test_approx_device_arm_builds_the_host_arms_forest_on_the_same_cuts(
+        arm, kw, missing):
+    X, y = _tabular(missing=missing)
+    X[:, 3] = np.round(X[:, 3] * 2) / 2                  # ties
+    models = []
+    for which in ("host", "device"):
+        arm(which)
+        models.append(boosting.train(
+            X, y, num_round=4, max_depth=4, nbin=16, use_pallas=False,
+            tree_method="approx", **kw))
+    host, device = models
+    assert host.tree_method == device.tree_method == "approx"
+    assert len(device.tree_cuts) == 4
+    for a, b in zip(host.tree_cuts, device.tree_cuts):
+        assert a.shape == (5, 15) and a.dtype == np.float32
+        np.testing.assert_array_equal(a, b)
+    # the cuts move with the hessians: no two rounds share theirs
+    # (squared loss: every hessian is 1 in every round, and they do)
+    moved = not np.array_equal(device.tree_cuts[0], device.tree_cuts[3])
+    assert moved == (kw["loss"] == "logistic")
+    assert _structure(host) == _structure(device)
+    assert _splits(host) == _splits(device)
+    np.testing.assert_allclose(_weights(device), _weights(host),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(device.predict(X), host.predict(X), atol=1e-5)
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_approx_cuts_are_weighted_quantiles_under_each_rounds_hessians(
+        arm, which):
+    """Tree k's cuts against an exact float64 weighted quantile under
+    the hessians of the forest without tree k."""
+    from rabit_tpu.learn import histogram
+
+    X, y = _tabular(n=2000)
+    arm(which)
+    nbin = 16
+    model = boosting.train(X, y, num_round=3, max_depth=3, nbin=nbin,
+                           use_pallas=False, tree_method="approx")
+    for k in range(3):
+        before = boosting.BoostedModel(
+            cuts=model.cuts, trees=model.trees[:k], tree_method="approx",
+            learning_rate=model.learning_rate)
+        p = before.predict(X).astype(np.float64)
+        h = p * (1 - p)
+        for j in range(X.shape[1]):
+            v = X[:, j].astype(np.float64)
+            for i, cut in enumerate(model.tree_cuts[k][j]):
+                want = (i + 1) / nbin * h.sum()
+                assert h[v < cut].sum() - 1e-4 * h.sum() <= want \
+                    <= h[v <= cut].sum() + 1e-4 * h.sum(), (k, j, i)
+    assert histogram.sketch_eps(nbin) > 1e-4
+
+
+def test_approx_at_round_0_picks_hists_splits_on_a_shard_that_is_its_sample(
+        arm):
+    """Logistic hessians are all 0.25 at margin 0: the first tree's
+    weighted sketch is an unweighted one, and on a shard that
+    ``cut_sample`` takes whole the two methods cut between the same
+    neighbours to within one row a cut (hist interpolates, approx takes
+    a value of the data)."""
+    from rabit_tpu.learn import histogram
+
+    X, y = _tabular(n=3000)
+    arm("device")
+    hist = boosting.train(X, y, num_round=1, max_depth=4, nbin=16,
+                          use_pallas=False)
+    arm("device")
+    approx = boosting.train(X, y, num_round=1, max_depth=4, nbin=16,
+                            use_pallas=False, tree_method="approx")
+    assert _structure(hist) == _structure(approx)
+    moved = np.count_nonzero(
+        histogram.apply_cuts(X, hist.cuts)
+        != histogram.apply_cuts(X, approx.tree_cuts[0]))
+    assert 0 < moved <= X.shape[1] * 15
+    np.testing.assert_allclose(_weights(approx), _weights(hist), atol=0.02)
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["dense", "nan"])
+@pytest.mark.parametrize("method", ["hist", "approx"])
+def test_predict_by_split_value_is_the_training_margin(arm, method, missing):
+    """Training routes a tree's rows by the bins of the cuts it was
+    grown on; the committed float split values route them the same
+    way, absent values included."""
+    from rabit_tpu.learn import histogram
+
+    X, y = _tabular(n=2000, missing=missing)
+    arm("device")
+    model = boosting.train(X, y, num_round=4, max_depth=4, nbin=16,
+                           use_pallas=False, tree_method=method)
+    by_bins = np.zeros(len(X), np.float32)
+    for k, tree in enumerate(model.trees):
+        cuts = model.tree_cuts[k] if method == "approx" else model.cuts
+        for node in tree:
+            if node.feature >= 0:
+                assert node.split == cuts[node.feature, node.bin_threshold]
+        by_bins += model.learning_rate * model._tree_margin(
+            tree, histogram.apply_cuts(X, cuts))
+    by_value = model.margin(X, by_value=True)
+    np.testing.assert_array_equal(by_value, by_bins)
+    np.testing.assert_allclose(model.predict(X),
+                               1 / (1 + np.exp(-by_bins)), rtol=1e-6)
+    if method == "hist":
+        assert model.tree_cuts == [] and model.tree_method == "hist"
+
+
+def test_a_checkpoint_from_before_the_split_values_still_loads_under_hist(
+        arm):
+    """A model pickled before ``TreeNode.split``, ``tree_method`` and
+    ``tree_cuts`` existed: it predicts as it did, and a job resumes
+    from it under ``hist``."""
+    import pickle
+
+    import rabit_tpu
+
+    X, y = _tabular(n=1500)
+    kw = dict(max_depth=3, nbin=16, use_pallas=False)
+    arm("host")
+    straight = boosting.train(X, y, num_round=5, **kw)
+    arm("host")
+    old = boosting.train(X, y, num_round=3, **kw)
+    for tree in old.trees:
+        for node in tree:
+            del node.__dict__["split"]
+    del old.__dict__["tree_method"], old.__dict__["tree_cuts"]
+    old = pickle.loads(pickle.dumps(old))
+    assert "split" not in old.trees[0][0].__dict__
+    np.testing.assert_array_equal(old.predict(X),
+                                  boosting.BoostedModel(
+        cuts=straight.cuts, trees=straight.trees[:3],
+        learning_rate=straight.learning_rate).predict(X))
+    # a fresh store whose third version is the old model: the job
+    # resumes from it and ends where the undisturbed one ends
+    arm("host")
+    for _ in range(3):
+        rabit_tpu.checkpoint(old)
+    resumed = boosting.train(X, y, num_round=5, **kw)
+    assert _structure(resumed) == _structure(straight)
+    np.testing.assert_array_equal(_weights(resumed), _weights(straight))
+    assert _splits(resumed)[3:] == _splits(straight)[3:]
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_resumed_approx_job_commits_the_same_later_trees_and_cuts(arm, which):
+    X, y = _tabular(n=1500, missing=True)
+    kw = dict(max_depth=3, nbin=16, use_pallas=False, tree_method="approx")
+    arm(which)
+    straight = boosting.train(X, y, num_round=6, **kw)
+    arm(which)
+    boosting.train(X, y, num_round=3, **kw)
+    # the same process keeps the committed forest (world 1, empty engine)
+    resumed = boosting.train(X, y, num_round=6, **kw)
+    assert _structure(resumed) == _structure(straight)
+    assert _splits(resumed) == _splits(straight)
+    for a, b in zip(resumed.tree_cuts, straight.tree_cuts):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(_weights(resumed), _weights(straight))
+    with pytest.raises(Exception, match="tree_method"):
+        boosting.train(X, y, num_round=7, max_depth=3, nbin=16)
+
+
+def test_tree_method_is_one_of_two(arm):
+    X, y = _tabular(n=200)
+    arm("host")
+    with pytest.raises(Exception, match="tree_method"):
+        boosting.train(X, y, num_round=1, tree_method="exact")
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_counters_and_collectives_of_approx_rounds(arm, which, monkeypatch):
+    """A round opens with one more allreduce than its levels (the
+    summaries, at world 1 too), and counts what it sketched, merged
+    and binned again."""
+    import rabit_tpu
+    from rabit_tpu.learn import histogram
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=1200)
+    arm(which)
+    calls, allreduce = [], rabit_tpu.allreduce
+
+    def counting(buf, op, *a, **kw):
+        calls.append(tuple(buf.shape))
+        return allreduce(buf, op, *a, **kw)
+
+    monkeypatch.setattr(rabit_tpu, "allreduce", counting)
+    before = program.stats()
+    boosting.train(X, y, num_round=3, max_depth=3, nbin=16,
+                   use_pallas=False, tree_method="approx")
+    after = program.stats()
+
+    def gained(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    entries = histogram.summary_entries(16)
+    payload = [c for c in calls if c in ((1, 5, entries, 3),
+                                         (5 * entries * 3,))]
+    assert len(payload) == 3                         # one a round
+    assert len(calls) == 1 + 3 * (1 + 3)             # has_missing, levels
+    assert gained("gbdt.sketches") == 3
+    assert gained("gbdt.rows_rebinned") == 3 * 1200
+    assert gained("gbdt.summary_entries") == 3 * 5 * entries
+    assert gained("gbdt.summary_bytes_merged") == 3 * 5 * entries * 3 * 4
+    for name in ("gbdt.sketch", "gbdt.sketch.merge", "gbdt.sketch.cuts",
+                 "gbdt.rebin"):
+        assert gained(name + ".n") >= 3, name
+    assert gained("stage.cuts.n") == 0               # nothing cut up front
+    assert gained("gbdt.levels") == 9
+
+
+def test_no_program_is_built_after_the_first_approx_round(arm, monkeypatch):
+    import rabit_tpu
+    from rabit_tpu.utils import compile_cache
+
+    X, y = _tabular(n=1200)
+    arm("device")
+    clock = compile_cache.count_compiles()
+    commit, asked = rabit_tpu.checkpoint, []
+
+    def counting(model):
+        took = clock.take()
+        asked.append(took["misses"] + took["hits"])
+        return commit(model)
+
+    monkeypatch.setattr(rabit_tpu, "checkpoint", counting)
+    boosting.train(X, y, num_round=5, max_depth=3, nbin=16,
+                   use_pallas=False, tree_method="approx")
+    assert asked[1:] == [0, 0, 0, 0], asked
+
+
+def _saved(tmp_path, name, world):
+    out = []
+    for rank in range(world):
+        with np.load(tmp_path / f"{name}-{rank}.npz") as z:
+            out.append((z["nodes"], z["cuts"]))
+    return out
+
+
+def test_approx_distributed_resume_and_xla_commit_the_undisturbed_forest(
+        tmp_path, native_lib):
+    """World 2 under ``approx``: a job whose rank 1 dies at version 2
+    and resumes, and a job over the XLA engine's device plane, each end
+    where the undisturbed host-engine job ends: the same cuts bit for
+    bit, and (the death) the same forest bit for bit."""
+    from rabit_tpu.tracker.launch_local import launch
+
+    X, y = _xor_data(n=400)
+    np.save(tmp_path / "X.npy", X)
+    np.save(tmp_path / "y.npy", y)
+    cmd = [sys.executable, "tests/workers/boosting_dist.py", str(tmp_path)]
+    env = {"BOOST_TREE_METHOD": "approx"}
+    assert launch(2, cmd, extra_env={
+        **env, "RABIT_ENGINE": "mock", "BOOST_SAVE": "calm"}) == 0
+    assert launch(2, cmd, extra_env={
+        **env, "RABIT_ENGINE": "mock", "RABIT_MOCK": "1,2,0,0",
+        "BOOST_SAVE": "died"}) == 0
+    assert launch(2, cmd, extra_env={
+        **env, "RABIT_ENGINE": "xla", "BOOST_SAVE": "xla"}) == 0
+    calm = _saved(tmp_path, "calm", 2)
+    assert calm[0][1].shape == (15, 2, 15)
+    for name in ("calm", "died", "xla"):
+        for nodes, cuts in _saved(tmp_path, name, 2):
+            np.testing.assert_array_equal(cuts, calm[0][1])
+            if name != "xla":
+                np.testing.assert_array_equal(nodes, calm[0][0])
+            else:
+                # another arm adds the histograms up in another order
+                np.testing.assert_array_equal(nodes[:, :6], calm[0][0][:, :6])
+                np.testing.assert_allclose(nodes, calm[0][0], rtol=1e-4,
+                                           atol=1e-5)

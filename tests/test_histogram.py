@@ -878,3 +878,155 @@ def test_scan_of_a_level_returns_every_slot_and_the_hosts_level(depth,
     np.testing.assert_array_equal(
         np.asarray(feats), np.tile(np.arange(f), (2 * p, 1)))
     np.testing.assert_array_equal(np.moveaxis(np.asarray(rows), 0, -1), level)
+
+
+# ----------------------------------------------------------------------
+# the weighted quantile sketch (tree_method="approx")
+# ----------------------------------------------------------------------
+def _rank_err(values, weights, cuts, nbin):
+    """The largest distance from (i + 1) / nbin to cut i's exact
+    weighted rank interval, float64, over features and cuts; a tie
+    spans an interval; a feature without weight has no rank."""
+    worst = 0.0
+    w = weights.astype(np.float64)
+    for j in range(values.shape[1]):
+        v = values[:, j].astype(np.float64)
+        present = ~np.isnan(v)
+        total = w[present].sum()
+        if total == 0:
+            continue
+        for i, cut in enumerate(cuts[j].astype(np.float64)):
+            below = w[present & (v < cut)].sum() / total
+            upto = w[present & (v <= cut)].sum() / total
+            want = (i + 1) / nbin
+            worst = max(worst, below - want, want - upto)
+    return worst
+
+
+def _sketch_rows(case: str, n: int = 6000, seed: int = 5):
+    """Seeded rows of six columns (continuous, ties on a grid of
+    halves, constant, a third absent, all absent, two values) and the
+    case's weights."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((n, 6)).astype(np.float32)
+    x[:, 1] = np.round(x[:, 1] * 2) / 2
+    x[:, 2] = 3.0
+    x[rng.random(n) < 0.33, 3] = np.nan
+    x[:, 4] = np.nan
+    x[:, 5] = np.where(x[:, 5] > 1.0, 1.0, -0.0)
+    w = {"uniform": np.full(n, 0.25),
+         "hessians": rng.random(n) * 0.25,
+         "zero-weights": np.where(rng.random(n) < 0.6, 0.0, rng.random(n)),
+         "heavy-tailed": rng.pareto(1.05, n),
+         "one-row-holds-half": np.where(np.arange(n) == 17, float(n), 1.0),
+         }[case].astype(np.float32)
+    return x, w
+
+
+def _summary(x, w, nbin):
+    import jax.numpy as jnp
+
+    return histogram.sketch_summary(
+        jnp.asarray(np.ascontiguousarray(x.T)), jnp.asarray(w),
+        histogram.summary_entries(nbin))
+
+
+@pytest.mark.parametrize("nbin", [16, 256])
+@pytest.mark.parametrize("case", ["uniform", "hessians", "zero-weights",
+                                  "heavy-tailed", "one-row-holds-half"])
+def test_sketch_cuts_are_within_eps_of_the_exact_weighted_quantiles(case,
+                                                                    nbin):
+    x, w = _sketch_rows(case)
+    cuts = np.asarray(histogram.sketch_cuts(_summary(x, w, nbin)[None], nbin))
+    assert cuts.shape == (6, nbin - 1) and cuts.dtype == np.float32
+    assert _rank_err(x, w, cuts, nbin) <= 1e-5 < histogram.sketch_eps(nbin)
+    assert (np.diff(cuts, axis=1) >= 0).all()           # may repeat
+    np.testing.assert_array_equal(cuts[2], 3.0)         # constant
+    np.testing.assert_array_equal(cuts[4], 0.0)         # all absent
+    assert set(np.unique(cuts[5])) <= {0.0, 1.0}
+    # every cut is a value of the data, of a row that has weight
+    for j in (0, 1, 3):
+        assert np.isin(cuts[j], x[w > 0, j]).all()
+
+
+def test_a_summary_holds_exact_ranks_and_its_total():
+    x, w = _sketch_rows("hessians")
+    s = np.asarray(_summary(x, w, 16)).astype(np.float64)
+    assert s.shape == (6, histogram.summary_entries(16), 3)
+    for j in (0, 1, 3, 5):
+        v = x[:, j].astype(np.float64)
+        present = ~np.isnan(v)
+        total = w[present].sum(dtype=np.float64)
+        np.testing.assert_allclose(s[j, -1, 2], total, rtol=1e-6)
+        for value, rmin, rmax in s[j, ::37]:
+            np.testing.assert_allclose(
+                rmin, w[present & (v < value)].sum(dtype=np.float64),
+                rtol=1e-5, atol=1e-6 * total)
+            np.testing.assert_allclose(
+                rmax, w[present & (v <= value)].sum(dtype=np.float64),
+                rtol=1e-5, atol=1e-6 * total)
+    assert not s[4].any()                               # no weight
+
+
+@pytest.mark.parametrize("case", ["hessians", "heavy-tailed"])
+@pytest.mark.parametrize("shards", [2, 3, 8])
+def test_merged_summaries_are_within_eps_on_every_shard_order(shards, case):
+    import jax.numpy as jnp
+
+    nbin = 16
+    x, w = _sketch_rows(case, n=6000)
+    # unequal shards: the first takes half of the rows
+    bounds = np.concatenate([[0], np.linspace(3000, 6000, shards).astype(int)])
+    parts = [_summary(x[a:b], w[a:b], nbin)
+             for a, b in zip(bounds, bounds[1:])]
+    cuts = np.asarray(histogram.sketch_cuts(jnp.stack(parts), nbin))
+    assert _rank_err(x, w, cuts, nbin) <= histogram.sketch_eps(nbin)
+    for order in (list(range(shards))[::-1],
+                  list(np.random.default_rng(1).permutation(shards))):
+        again = np.asarray(histogram.sketch_cuts(
+            jnp.stack([parts[k] for k in order]), nbin))
+        np.testing.assert_array_equal(again, cuts)
+    # one rank's merge is its own sketch
+    whole = np.asarray(histogram.sketch_cuts(
+        _summary(x, w, nbin)[None], nbin))
+    assert _rank_err(x, w, whole, nbin) <= 1e-5
+
+
+def test_sketch_payload_is_the_ranks_slot_and_zeros_elsewhere():
+    x, w = _sketch_rows("hessians", n=512)
+    gh = np.stack([np.zeros_like(w), w])
+    mine = np.asarray(histogram.sketch_program(512, 6, 16, 3, 1)(
+        np.ascontiguousarray(x.T), gh))
+    assert mine.shape == (3, 6, histogram.summary_entries(16), 3)
+    assert not mine[0].any() and not mine[2].any()
+    np.testing.assert_array_equal(mine[1], np.asarray(_summary(x, w, 16)))
+    alone = np.asarray(histogram.sketch_program(512, 6, 16)(
+        np.ascontiguousarray(x.T), gh))
+    np.testing.assert_array_equal(alone[0], mine[1])
+
+
+@pytest.mark.parametrize("nan", [False, True], ids=["dense", "nan"])
+def test_rebin_equals_apply_cuts_bit_for_bit_and_writes_in_place(nan):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(9)
+    n, f, nbin = 3000, 5, 16
+    x = rng.standard_normal((n, f)).astype(np.float32)
+    if nan:
+        x[rng.random((n, f)) < 0.2] = np.nan
+    values_t, bins_t, seen = histogram.stage_values(x, nbin)
+    np.testing.assert_array_equal(np.asarray(values_t), x.T)
+    assert bins_t.shape == (histogram.staged_features(f, nbin), n)
+    assert list(np.asarray(seen)) == [int(nan), nbin * int(nan)]
+    cuts = histogram.quantile_cuts(x, nbin)
+    fn = histogram.rebin_program(n, f, bins_t.shape[0], nbin - 1)
+    out = fn(bins_t, values_t, jnp.asarray(cuts))
+    assert bins_t.is_deleted()                          # donated
+    np.testing.assert_array_equal(np.asarray(out)[:f].T,
+                                  histogram.apply_cuts(x, cuts))
+    assert not np.asarray(out)[f:].any()
+    # again, on other cuts, over the same buffer
+    cuts2 = cuts + np.float32(0.1)
+    out2 = fn(out, values_t, jnp.asarray(cuts2))
+    np.testing.assert_array_equal(np.asarray(out2)[:f].T,
+                                  histogram.apply_cuts(x, cuts2))

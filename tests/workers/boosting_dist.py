@@ -85,10 +85,27 @@ def main() -> int:
     subsample = float(os.environ.get("BOOST_SUBSAMPLE", "1.0"))
     min_acc = float(os.environ.get("BOOST_MIN_ACC", "0.9"))
     watched = watch_built() if os.environ.get("BOOST_CHECK_BUILT") else None
+    tree_method = os.environ.get("BOOST_TREE_METHOD", "hist")
     model = boosting.train(Xs, ys, num_round=15, max_depth=3, nbin=16,
-                           subsample=subsample)
+                           subsample=subsample, tree_method=tree_method)
     if watched:
         check_built(model, *watched)
+    assert model.tree_method == tree_method
+    if tree_method == "approx":
+        # every tree on cuts of its own, the same on every rank
+        assert len(model.tree_cuts) == len(model.trees) == 15
+        mine = np.asarray(model.tree_cuts, np.float64)
+        for theirs in rabit_tpu.allgather(mine):
+            np.testing.assert_array_equal(theirs, mine)
+    if os.environ.get("BOOST_SAVE"):
+        # what the job committed, for the test to hold two jobs together
+        np.savez(os.path.join(data_dir, "%s-%d.npz" % (
+            os.environ["BOOST_SAVE"], rank)),
+            nodes=np.array([(t, n.feature, n.bin_threshold, n.default_left,
+                             n.left, n.right, n.value, n.split)
+                            for t, tree in enumerate(model.trees)
+                            for n in tree], np.float64),
+            cuts=np.asarray(getattr(model, "tree_cuts", []), np.float32))
 
     # identical predictions everywhere (same model on every rank);
     # with missing values this also pins the learned default directions
