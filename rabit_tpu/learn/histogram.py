@@ -303,7 +303,12 @@ def sketch_summary(values_t, weights, entries: int):
     A feature at a time: its values are sorted with their weights, as
     integer keys of the same order (the device's work is the same
     whatever either holds), the weights added up in float32
-    (:func:`_prefix`) and the entries found by bisection.
+    (:func:`_prefix`) and the entries found by bisection.  Rows of
+    equal value come out of the sort in no particular order (it is not
+    stable: a stable one carries a third operand, the row's index, and
+    compares it too), which nothing reads: an entry takes a tie as the
+    interval it spans, so the order inside one moves only the float32
+    rounding of the sums within it.
     """
     import jax
     import jax.numpy as jnp
@@ -314,7 +319,7 @@ def sketch_summary(values_t, weights, entries: int):
     def one(v):
         keys, sw = jax.lax.sort(
             (_order_keys(v), jnp.where(jnp.isnan(v), 0.0, weights)),
-            num_keys=1)
+            num_keys=1, is_stable=False)
         c = _prefix(sw)
         total = c[-1]
         at = jnp.minimum(jnp.searchsorted(c, share * total), n - 1)
@@ -360,7 +365,9 @@ def sketch_cuts(summaries, nbin: int):
 
         upto = jnp.sum(jax.vmap(held)(summary), axis=0)
         if ranks > 1:       # one rank's entries are in order as they are
-            cand, upto = jax.lax.sort((cand, upto), num_keys=1)
+            # equal candidates hold equal ``upto``: any order of them
+            cand, upto = jax.lax.sort((cand, upto), num_keys=1,
+                                      is_stable=False)
         total = jnp.sum(summary[:, -1, 2])
         want = total * (jnp.arange(1, nbin, dtype=jnp.float32) / nbin)
         at = jnp.minimum(jnp.searchsorted(upto, want), cand.shape[0] - 1)

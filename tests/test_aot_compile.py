@@ -182,9 +182,7 @@ def test_approx_rebin_writes_over_the_old_bins_on_v5e(topo):
 
 def test_approx_cuts_program_of_four_ranks_compiles_for_v5e(topo):
     """The cuts of four ranks' merged summaries (28 x 8,192 entries a
-    rank): kilobytes out of 11 MB, in about their own size.  (The sketch
-    itself holds a sort of 2^25 pairs, which this compiler takes a
-    minute and a half over: the chip's own runs compile it.)"""
+    rank): kilobytes out of 11 MB, in about their own size."""
     from rabit_tpu.learn import histogram
 
     entries = histogram.summary_entries(256)
@@ -193,6 +191,39 @@ def test_approx_cuts_program_of_four_ranks_compiles_for_v5e(topo):
         ).memory_analysis()
     assert m.output_size_in_bytes <= 28 * 256 * 4 + 4096
     assert m.temp_size_in_bytes <= 8 * 4 * 28 * entries * 3 * 4, m
+
+
+def test_approx_sketch_sorts_pairs_by_one_compare_on_v5e(topo):
+    """``gbdt_sketch`` at the cell's shape (28 features of 2^25 rows):
+    the chip's sort carries the key and the weight and compares the
+    keys once.  A stable sort is rewritten here to carry an iota of the
+    rows as a third operand and to compare it too (five operations),
+    and compiles for three times as long.  The program's temporaries
+    stay a feature's five arrays (keys and weights before and after the
+    sort, the prefix sums): buffer assignment gives the same figure with
+    and without the iota."""
+    import re
+
+    from rabit_tpu.learn import histogram
+
+    n, f = 32 << 20, 28
+    entries = histogram.summary_entries(256)
+
+    def gbdt_sketch(values_t, gh):
+        return histogram.sketch_summary(values_t, gh[1], entries)[None]
+
+    compiled = jax.jit(gbdt_sketch).lower(*_one_chip(
+        topo, ((f, n), jnp.float32), ((2, n), jnp.float32))).compile()
+    text = compiled.as_text()
+    (sort,) = [line for line in text.splitlines()
+               if re.search(r"\bsort\(", line)]
+    assert len(re.search(r"\bsort\(([^)]*)\)", sort).group(1).split(",")) == 2
+    assert "is_stable=true" not in sort
+    assert not re.search(rf"s32\[{n}\]\S* iota\(", text)
+    comparator = re.search(r"to_apply=(%[\w.]+)", sort).group(1)
+    body = text.split(f"\n{comparator} (")[1].split("\n}")[0]
+    assert body.count(" compare(") == 1 and " select(" not in body, body
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6 * n * 4
 
 
 def test_wide_level_scan_holds_a_level_and_hands_over_kilobytes_on_v5e(topo):

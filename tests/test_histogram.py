@@ -1005,6 +1005,45 @@ def test_sketch_payload_is_the_ranks_slot_and_zeros_elsewhere():
     np.testing.assert_array_equal(alone[0], mine[1])
 
 
+@pytest.mark.parametrize("case", ["uniform", "hessians", "zero-weights",
+                                  "heavy-tailed", "one-row-holds-half"])
+def test_a_summary_does_not_read_the_order_of_the_rows(case):
+    """The sketch's sort is not stable, so rows of equal value reach
+    the prefix sums in whatever order the sort leaves them: on the
+    columns that are mostly ties, the rows shuffled, every entry holds
+    the same value, its ranks move by float32 rounding alone, and the
+    cuts stay exact."""
+    nbin, tied = 16, [1, 2, 3, 5]   # halves, constant, a third absent, two
+    x, w = _sketch_rows(case)
+    x = x[:, tied]
+    s = np.asarray(_summary(x, w, nbin))
+    for seed in (0, 1):
+        order = np.random.default_rng(seed).permutation(len(x))
+        xs, ws = x[order], w[order]
+        again = np.asarray(_summary(xs, ws, nbin))
+        np.testing.assert_array_equal(again[:, :, 0], s[:, :, 0])
+        for mine, other in zip(s, again):
+            np.testing.assert_allclose(other[:, 1:], mine[:, 1:], rtol=1e-5,
+                                       atol=1e-6 * mine[-1, 2])
+        cuts = np.asarray(histogram.sketch_cuts(again[None], nbin))
+        assert _rank_err(xs, ws, cuts, nbin) <= 1e-5
+
+
+def test_the_sketch_sorts_two_operands_and_not_stably():
+    """What the per-feature sort carries is the key and the weight: a
+    stable sort would carry the row's index as a third operand on the
+    chip (and say ``is_stable=true`` on any backend)."""
+    import re
+
+    text = histogram.sketch_program(512, 6, 16).as_text()
+    sorts = [line for line in text.splitlines()
+             if re.search(r"\bsort\(", line)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\(([^)]*)\)", sorts[0]).group(1).split(",")
+    assert len(operands) == 2, sorts[0]
+    assert "is_stable=true" not in sorts[0]
+
+
 @pytest.mark.parametrize("nan", [False, True], ids=["dense", "nan"])
 def test_rebin_equals_apply_cuts_bit_for_bit_and_writes_in_place(nan):
     import jax.numpy as jnp
