@@ -83,6 +83,10 @@ class BoostedModel:
     # (f, nbin - 1) float32 a tree under "approx" (28.6 KB at 28 x 255);
     # a model committed before the field existed has none
     tree_cuts: list = field(default_factory=list)
+    # output groups (XGBoost's ``num_class``): a round appends this many
+    # trees, tree ``t * num_class + k`` is round t's for class k.  A
+    # model committed before the field existed reads 1
+    num_class: int = 1
 
     def _tree_margin(self, tree: list[TreeNode], bins: np.ndarray,
                      by_value: bool = False) -> np.ndarray:
@@ -115,11 +119,14 @@ class BoostedModel:
 
     def margin(self, bins: np.ndarray, by_value: bool = False
                ) -> np.ndarray:
-        out = np.full(bins.shape[0], self.base_score, np.float32)
-        for tree in self.trees:
-            out += self.learning_rate * self._tree_margin(tree, bins,
-                                                          by_value)
-        return out
+        """``(n,)`` margins, or ``(num_class, n)`` of a model of several
+        output groups."""
+        groups = self.num_class
+        out = np.full((groups, bins.shape[0]), self.base_score, np.float32)
+        for t, tree in enumerate(self.trees):
+            out[t % groups] += self.learning_rate * self._tree_margin(
+                tree, bins, by_value)
+        return out if groups > 1 else out[0]
 
     def predict(self, values: np.ndarray) -> np.ndarray:
         if self.tree_method == "approx":
@@ -128,6 +135,9 @@ class BoostedModel:
             m = self.margin(apply_cuts(values, self.cuts))
         if self.loss == "logistic":
             return 1.0 / (1.0 + np.exp(-m))
+        if self.loss == "softprob":
+            e = np.exp(m - m.max(axis=0))
+            return (e / e.sum(axis=0)).T                # (n, num_class)
         return m
 
 
@@ -136,6 +146,15 @@ apply_cuts = histogram.apply_cuts
 
 
 def _grad_hess(margin: np.ndarray, labels: np.ndarray, loss: str):
+    if loss == "softprob":
+        # XGBoost's SoftmaxMultiClassObj: every class from the same
+        # margins, the hessian doubled and floored
+        e = np.exp(margin - margin.max(axis=0))
+        p = e / e.sum(axis=0)
+        hit = labels[None, :] == np.arange(len(margin), dtype=np.float32)[
+            :, None]
+        return (p - hit).astype(np.float32), np.maximum(
+            2.0 * p * (1.0 - p), SOFTPROB_MIN_HESS).astype(np.float32)
     if loss == "logistic":
         p = 1.0 / (1.0 + np.exp(-margin))
         return (p - labels).astype(np.float32), (p * (1 - p)).astype(
@@ -144,6 +163,8 @@ def _grad_hess(margin: np.ndarray, labels: np.ndarray, loss: str):
 
 
 TREE_METHODS = ("hist", "approx")
+# XGBoost's floor under a softmax hessian (kRtEps)
+SOFTPROB_MIN_HESS = 1e-16
 
 # rows the quantile cuts of tree_method="hist" are taken from (XGBoost's
 # sketch is approximate too): a shard up to this size gives every row
@@ -204,6 +225,30 @@ def _leaf_values(tree, slots, leaves, max_depth: int) -> np.ndarray:
     return vals
 
 
+def _route_round(trees, slots: list[int], leaves: list[list[int]]):
+    """:func:`_route` of every tree of a round.  ``slots`` holds the
+    trees' level slots one tree after another (tree-major: slot ``s`` of
+    tree ``k`` at a level ``w`` wide is entry ``k * w + s``, so that the
+    children of entry ``e`` are entries ``2e`` and ``2e + 1`` as within
+    one tree) and ``leaves[k]`` tree k's leaves.  Returns the tables
+    ``(trees, w, 4)`` and the next level's slots."""
+    width = len(slots) // len(trees)
+    routed = [_route(tree, slots[k * width:(k + 1) * width], leaves[k])
+              for k, tree in enumerate(trees)]
+    return (np.stack([tab for tab, _nxt in routed]),
+            [nid for _tab, nxt in routed for nid in nxt])
+
+
+def _round_leaf_values(trees, slots, leaves, max_depth: int) -> np.ndarray:
+    """:func:`_leaf_values` of every tree of a round, ``(trees, 2 *
+    2^max_depth)``; ``slots`` and ``leaves`` as :func:`_route_round`
+    left them."""
+    width = len(slots) // len(trees)
+    return np.stack([
+        _leaf_values(tree, slots[k * width:(k + 1) * width], leaves[k],
+                     max_depth) for k, tree in enumerate(trees)])
+
+
 def _fill_splits(tree: list[TreeNode], cuts: np.ndarray) -> None:
     """Every split's float value from the cuts the tree was grown on."""
     for node in tree:
@@ -212,20 +257,23 @@ def _fill_splits(tree: list[TreeNode], cuts: np.ndarray) -> None:
 
 
 def _replay(shard, model, max_depth: int) -> None:
-    """Add committed trees to the shard's margin (a resume).  A tree
-    grown under ``"approx"`` routes the rows binned by its own cuts
-    again, which is the walk by ``value < split``: a bin is at or under
-    the threshold exactly where the value is under that cut."""
-    for k, tree in enumerate(model.trees):
+    """Add committed trees to the shard's margins (a resume), a round's
+    trees together as they were grown.  A tree grown under ``"approx"``
+    routes the rows binned by its own cuts again, which is the walk by
+    ``value < split``: a bin is at or under the threshold exactly where
+    the value is under that cut."""
+    groups = model.num_class
+    for t in range(0, len(model.trees), groups):
+        trees = model.trees[t:t + groups]
         if model.tree_method == "approx":
-            shard.rebin(model.tree_cuts[k])
-        slots, leaves = [0], []
+            shard.rebin(model.tree_cuts[t])
+        slots, leaves = [0] * groups, [[] for _ in trees]
         for _depth in range(max_depth):
             if all(nid < 0 for nid in slots):
                 break
-            tab, slots = _route(tree, slots, leaves)
-            shard.partition(tab)
-        shard.leaf(_leaf_values(tree, slots, leaves, max_depth))
+            tabs, slots = _route_round(trees, slots, leaves)
+            shard.partition(tabs)
+        shard.leaf(_round_leaf_values(trees, slots, leaves, max_depth))
 
 
 class _HostShard:
@@ -257,9 +305,12 @@ class _HostShard:
 
     def start(self, has_missing: bool) -> None:
         self.has_missing = has_missing
-        self.margin = self.model.margin(self.values, by_value=True) \
+        margin = self.model.margin(self.values, by_value=True) \
             if self.approx else self.model.margin(self.bins)
-        self.node = np.zeros(self.n, np.int32)
+        # a row a tree of the round: margins, gradients and node ids
+        self.trees = self.model.num_class
+        self.margin = margin.reshape(self.trees, self.n)
+        self.node = np.zeros((self.trees, self.n), np.int32)
 
     def sketch(self):
         """This rank's slot of the merge's payload
@@ -268,7 +319,7 @@ class _HostShard:
             self.n, self.values.shape[1], self.nbin,
             rabit_tpu.get_world_size(), rabit_tpu.get_rank())
         return fn(np.ascontiguousarray(self.values.T),
-                  np.stack([self.grad, self.hess]))
+                  np.stack([self.grad[0], self.hess[0]]))
 
     def cuts_of(self, merged):
         """The cuts of the merged summaries, as every rank computes
@@ -289,32 +340,47 @@ class _HostShard:
             self.grad = np.where(keep, self.grad, 0.0).astype(np.float32)
             self.hess = np.where(keep, self.hess, 0.0).astype(np.float32)
 
-    def level(self, build):
-        """The histograms of the level slots ``build`` names (-1: none),
-        which, and the kernel calls that took."""
+    def level(self, build, depth: int):
+        """The histograms of the level slots ``build`` names (-1: none;
+        tree-major, the level ``2^depth`` slots a tree), which, and the
+        kernel calls that took: a tree's slots by its own gradients and
+        node ids, one builder call a tree that builds any."""
+        width = 1 << depth
         order = [s for s in build if s >= 0]
-        calls = histogram.level_calls(len(order), self.bins.shape[1],
-                                      self.nbin, self.kw["use_pallas"])
-        return histogram.build_level_local(
-            self.bins, self.grad, self.hess, self.node, order, self.nbin,
-            totals=self.has_missing, **self.kw), order, calls
+        built, calls = [], 0
+        for k in range(self.trees):
+            mine = [s - k * width for s in order if s // width == k]
+            if not mine:
+                continue
+            calls += histogram.level_calls(len(mine), self.bins.shape[1],
+                                           self.nbin, self.kw["use_pallas"])
+            built.append(histogram.build_level_local(
+                self.bins, self.grad[k], self.hess[k], self.node[k], mine,
+                self.nbin, totals=self.has_missing, **self.kw))
+        if len(built) > 1:
+            import jax.numpy as jnp
 
-    def partition(self, tab: np.ndarray) -> None:
-        node = self.node
-        live = node >= 0
-        feat, thr, dleft, leaf = tab[np.where(live, node, 0)].T
-        b = self.bins[np.arange(self.n), feat]
-        left = np.where(b == self.model.cuts.shape[1] + 1, dleft != 0,
-                        b <= thr)           # absent: the default direction
-        self.node = np.where(live, np.where(leaf < 0, leaf,
-                                            2 * node + 1 - left),
-                             node).astype(np.int32)
+            built = [jnp.concatenate(built)]
+        return built[0], order, calls
+
+    def partition(self, tabs: np.ndarray) -> None:
+        for k, tab in enumerate(tabs):
+            node = self.node[k]
+            live = node >= 0
+            feat, thr, dleft, leaf = tab[np.where(live, node, 0)].T
+            b = self.bins[np.arange(self.n), feat]
+            left = np.where(b == self.model.cuts.shape[1] + 1, dleft != 0,
+                            b <= thr)       # absent: the default direction
+            self.node[k] = np.where(live, np.where(leaf < 0, leaf,
+                                                   2 * node + 1 - left),
+                                    node)
 
     def leaf(self, vals: np.ndarray) -> None:
         width = 1 << self.max_depth
-        self.margin += self.model.learning_rate * vals[
-            np.where(self.node >= 0, self.node, width - self.node - 1)]
-        self.node = np.zeros(self.n, np.int32)
+        for k, node in enumerate(self.node):
+            self.margin[k] += self.model.learning_rate * vals[k][
+                np.where(node >= 0, node, width - node - 1)]
+        self.node = np.zeros_like(self.node)
 
 
 _PROGRAMS: dict = {}
@@ -334,9 +400,68 @@ def _lookup(table, idx, width: int):
     return out
 
 
+def softprob_grad_program(n: int, num_class: int, sampled: bool = False):
+    """Compiled ``gbdt_grad_softmax``: from the ``(K, n)`` margins the
+    round before left and the ``(n,)`` float32 class ids (and, with
+    ``sampled``, the round's ``(n,)`` bool row sample) to the round's
+    ``(K, 2, n)`` float32 (grad, hess), every class at once: XGBoost's
+    ``SoftmaxMultiClassObj``.  What it needs is (3K + 1) * 4 bytes a
+    row."""
+    import jax
+    import jax.numpy as jnp
+
+    key = ("grad_softmax", n, num_class, sampled, jax.default_backend())
+    fn = _PROGRAMS.get(key)
+    if fn is None:
+        def gbdt_grad_softmax(margin, labels, *keep):
+            with jax.named_scope("gbdt/grad"):
+                e = jnp.exp(margin - jnp.max(margin, axis=0))
+                p = e / jnp.sum(e, axis=0)
+                hit = labels[None, :] == jnp.arange(
+                    num_class, dtype=jnp.float32)[:, None]
+                gh = jnp.stack([p - hit, jnp.maximum(
+                    2.0 * p * (1.0 - p), SOFTPROB_MIN_HESS)], axis=1)
+                return jnp.where(keep[0], gh, 0.0) if keep else gh
+
+        sds = jax.ShapeDtypeStruct
+        keep = (sds((n,), jnp.bool_),) if sampled else ()
+        fn = _PROGRAMS[key] = jax.jit(gbdt_grad_softmax).lower(
+            sds((num_class, n), jnp.float32), sds((n,), jnp.float32),
+            *keep).compile()
+    return fn
+
+
+def _forest(fn, trees: int, shared: int = 0, join=None):
+    """The program of a round's ``trees`` trees from ``fn``, the program
+    of one, traceable.  Its first ``shared`` arguments are the round's
+    (the staged bins); every other has a leading tree axis, of which
+    call ``k`` of ``fn`` takes entry ``k``: tree k's kernel calls see
+    its own ``(2, n)`` weights and its own node row, as one tree's do.
+    The calls' results are joined (``join``: stacked, a leading tree
+    axis again) and all of them are one program: one hand-over and one
+    wait a round's level, whatever ``trees``.  A round of one tree is
+    ``fn`` itself, its arrays without the axis."""
+    if trees == 1:
+        return fn
+    import jax
+    import jax.numpy as jnp
+
+    join = join or jnp.stack
+
+    def forest(*args):
+        outs = [fn(*args[:shared], *(a[k] for a in args[shared:]))
+                for k in range(trees)]
+        return jax.tree.map(lambda *parts: join(parts), *outs)
+
+    forest.__name__ = fn.__name__
+    return forest
+
+
 class _DeviceShard:
     """One rank's rows on the device for the whole job: staged bins,
-    labels, margin, (grad, hess) and the node of every row.  A round is
+    labels, margin, (grad, hess) and the node of every row (of a model
+    of several output groups each with a leading axis of that length:
+    ``(K, n)`` margins and node ids, ``(K, 2, n)`` gradients).  A round is
     a fixed set of programs, compiled before the first; the host sends
     back tables of a level's width.  With ``scan`` None it sees
     ``level``'s histograms whole.  With ``scan`` the job's
@@ -353,6 +478,9 @@ class _DeviceShard:
         self.scan_by, self.above = scan, None
         self.n, self.f = values.shape
         self.model, self.max_depth, self.nbin = model, max_depth, nbin
+        self.trees = model.num_class            # trees a round
+        # the leading axis of what is kept a tree of the round
+        self.lead = (self.trees,) if self.trees > 1 else ()
         self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
         self.subsample, self.seed = subsample, seed
         self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
@@ -384,8 +512,9 @@ class _DeviceShard:
                     rebin=histogram.rebin_program(
                         self.n, self.f, self.bins_t.shape[0],
                         self.model.cuts.shape[1]))
-        self.margin = jnp.full((self.n,), self.model.base_score, jnp.float32)
-        self.node = jnp.zeros((self.n,), jnp.int32)
+        self.margin = jnp.full(self.lead + (self.n,), self.model.base_score,
+                               jnp.float32)
+        self.node = jnp.zeros(self.lead + (self.n,), jnp.int32)
         _replay(self, self.model, self.max_depth)
 
     def _programs(self) -> dict:
@@ -395,7 +524,14 @@ class _DeviceShard:
         that stops early runs the same programs; one of more slots than
         ``histogram.slots_per_call`` holds several kernel calls),
         ``partition`` and ``leaf``; and, where the level's histograms
-        stay on the device, ``scan`` by the level's number of slots."""
+        stay on the device, ``scan`` by the level's number of slots.
+        Of a round of several trees each is the round's (:func:`_forest`):
+        a level's program holds every tree's kernel calls of that depth
+        and hands back their slots tree-major, which is the numbering
+        ``scan`` keeps (a slot's children are slots ``2s`` and ``2s + 1``
+        across trees as within one), so that it, ``histogram.
+        assemble_level`` and ``level_shortlist`` take a forest's level as
+        a tree's of that many slots."""
         import jax
         import jax.numpy as jnp
 
@@ -403,13 +539,14 @@ class _DeviceShard:
 
         n, f, nbin, depth = self.n, self.f, self.nbin, self.max_depth
         loss, rate = self.model.loss, self.model.learning_rate
+        trees, lead = self.trees, self.lead
         sampled, totals = self.subsample < 1.0, self.has_missing
         missing_code = self.model.cuts.shape[1] + 1
         use_pallas, cdt, scan_by = (self.use_pallas, self.compute_dtype,
                                     self.scan_by)
         key = (n, f, self.bins_t.shape[0], nbin, totals, depth, loss, rate,
                sampled, missing_code, use_pallas, cdt, hk.hist_fused_multi,
-               jax.default_backend(), scan_by)
+               jax.default_backend(), scan_by, trees)
         if key in _PROGRAMS:
             return _PROGRAMS[key]
         half, width = self.half, 1 << depth
@@ -468,33 +605,41 @@ class _DeviceShard:
                     level, f, *scan_by, totals)
 
         sds = jax.ShapeDtypeStruct
-        rows_f, rows_i = sds((n,), jnp.float32), sds((n,), jnp.int32)
-        bins, gh = sds(self.bins_t.shape, jnp.int32), sds((2, n), jnp.float32)
+        rows_f = sds(lead + (n,), jnp.float32)
+        rows_i = sds(lead + (n,), jnp.int32)
+        bins = sds(self.bins_t.shape, jnp.int32)
+        gh = sds(lead + (2, n), jnp.float32)
 
         def build(fn, *shapes, donate=()):
             return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
 
         keep = (sds((n,), jnp.bool_),) if sampled else ()
         prog = {
-            "grad": build(gbdt_grad, rows_f, rows_f, *keep),
-            "level": {p: build(level_of(p), bins, gh, rows_i,
-                               sds((p,), jnp.int32))
+            "grad": softprob_grad_program(n, trees, sampled)
+            if loss == "softprob" else build(
+                gbdt_grad, rows_f, sds((n,), jnp.float32), *keep),
+            "level": {p: build(_forest(level_of(p), trees, shared=1,
+                                       join=jnp.concatenate),
+                               bins, gh, rows_i, sds(lead + (p,), jnp.int32))
                       for p in [1] + [1 << d for d in range(1, depth - 1)]},
-            "partition": build(gbdt_partition, bins, rows_i,
-                               sds((half, 4), jnp.int32), donate=(1,)),
-            "leaf": build(gbdt_leaf, rows_f, rows_i,
-                          sds((2 * width,), jnp.float32), donate=(0, 1)),
+            "partition": build(_forest(gbdt_partition, trees, shared=1),
+                               bins, rows_i, sds(lead + (half, 4), jnp.int32),
+                               donate=(1,)),
+            "leaf": build(_forest(gbdt_leaf, trees), rows_f, rows_i,
+                          sds(lead + (2 * width,), jnp.float32),
+                          donate=(0, 1)),
         }
         if scan_by is not None:
             def hists(*shape):
                 return sds(shape, jnp.float32)
 
             # the root's level is what was built; below, a level of 2p
-            # slots comes from its p built slots and the p slots above
+            # slots (a tree) comes from its p built slots and the p
+            # slots above
             rows = f + totals
-            prog["scan"] = {1: build(gbdt_scan, hists(1, rows, nbin, 2))}
-            for p in (1 << d for d in range(depth - 1)):
-                prog["scan"][2 * p] = build(
+            prog["scan"] = {1: build(gbdt_scan, hists(trees, rows, nbin, 2))}
+            for p in (trees << d for d in range(depth - 1)):
+                prog["scan"][2 * p // trees] = build(
                     gbdt_scan, hists(p, rows, nbin, 2),
                     hists(2, p, rows, nbin), sds((p,), jnp.int32))
         _PROGRAMS[key] = prog
@@ -535,11 +680,20 @@ class _DeviceShard:
                                          jnp.asarray(cuts, jnp.float32))
         program.enqueued(self.bins_t)
 
-    def level(self, build):
-        calls = histogram.level_calls(len(build), self.bins_t.shape[0],
-                                      self.nbin, self.use_pallas)
-        local = self.prog["level"][len(build)](
-            self.bins_t, self.gh, self.node, np.asarray(build, np.int32))
+    def level(self, build, depth: int):
+        """One program over the slots ``build`` names (tree-major, -1:
+        none; as many a tree, built or not): the level's histograms on
+        the device, ``build``, and the kernel calls the program holds."""
+        per_tree = len(build) // self.trees
+        calls = self.trees * histogram.level_calls(
+            per_tree, self.bins_t.shape[0], self.nbin, self.use_pallas)
+        # each tree's kernel calls match its rows' node ids, which are
+        # slots of its own level of 2^depth
+        takes = np.asarray(build, np.int32)
+        takes = np.where(takes >= 0, takes & ((1 << depth) - 1), -1).astype(
+            np.int32).reshape(self.lead + (per_tree,))
+        local = self.prog["level"][per_tree](
+            self.bins_t, self.gh, self.node, takes)
         program.enqueued(local)
         return local, build, calls
 
@@ -557,15 +711,16 @@ class _DeviceShard:
         program.enqueued(rows)
         return feats, rows
 
-    def partition(self, tab: np.ndarray) -> None:
-        tab = np.concatenate(
-            [tab, np.zeros((self.half - len(tab), 4), np.int32)])
-        self.node = self.prog["partition"](self.bins_t, self.node, tab)
+    def partition(self, tabs: np.ndarray) -> None:
+        tabs = np.concatenate([tabs, np.zeros(
+            (self.trees, self.half - tabs.shape[1], 4), np.int32)], axis=1)
+        self.node = self.prog["partition"](
+            self.bins_t, self.node, tabs.reshape(self.lead + (self.half, 4)))
         program.enqueued(self.node)
 
     def leaf(self, vals: np.ndarray) -> None:
-        self.margin, self.node = self.prog["leaf"](self.margin, self.node,
-                                                   vals)
+        self.margin, self.node = self.prog["leaf"](
+            self.margin, self.node, vals.reshape(self.lead + vals.shape[1:]))
         program.enqueued(self.node)
 
 
@@ -726,7 +881,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
           subsample: float = 1.0, seed: int = 0,
           use_pallas: bool | None = None,
           compute_dtype: str | None = None,
-          tree_method: str = "hist") -> BoostedModel:
+          tree_method: str = "hist", num_class: int = 1) -> BoostedModel:
     """Train a distributed booster on this rank's row shard.
 
     Deterministic across ranks: every rank holds the same cuts and
@@ -789,6 +944,35 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     Elsewhere the same loop runs on numpy arrays (``_HostShard``) and
     builds the same trees.
 
+    ``loss="softprob"`` with ``num_class=K >= 2`` is XGBoost's
+    ``objective=multi:softprob`` under its default
+    ``multi_strategy=one_output_per_tree``: labels are class ids in
+    ``[0, K)``, the margins are ``(K, n)`` (``base_score`` 0.5 a class:
+    it cancels in the softmax and is kept because committed margins are
+    compared), and a round commits K trees, ``model.trees[t * K + k]``
+    round t's for class k.  Once a round, from the margins the round
+    before left, ``p = softmax(m)`` a row (the largest margin
+    subtracted first), ``g[k] = p[k] - [y = k]`` and ``h[k] = max(2 p[k]
+    (1 - p[k]), 1e-16)`` (XGBoost's ``SoftmaxMultiClassObj``; on the
+    device one program, ``gbdt_grad_softmax``); tree k is then fit to
+    ``(g[k], h[k])`` as the binary job's one tree is to its ``(g, h)``.
+    None of the K trees sees another's update, so they are grown
+    together, level by level: **a level is a forest's**.  Per depth one
+    program holds every tree's kernel calls (tree k's take ``gh[k]`` and
+    ``node[k]``), one ``rabit_tpu.allreduce`` carries K times the built
+    slots, one ``gbdt_scan`` ranks K times the level's slots, one fetch
+    brings their shortlists, ``_split`` decides each in float64 and one
+    program moves the rows of all K trees; one more a round adds the K
+    leaf weights a row.  That is six waits and six collectives a round
+    whatever K, where tree by tree it were 6K.  Slots are numbered
+    tree-major (:func:`_route_round`), so everything that takes a
+    level's slots takes a forest's unchanged, and the other objectives
+    are K = 1 of the same loop.  ``predict`` gives ``(n, K)``
+    probabilities.  The row sample of ``subsample`` is the round's,
+    shared by its K trees, as XGBoost's is.  ``tree_method="approx"``
+    with K > 1 is refused: each class would sketch under its own
+    hessians.
+
     ``subsample < 1`` draws a fresh per-round row sample (stochastic
     gradient boosting): sampled-out rows contribute no gradient mass to
     any histogram or leaf this round.  The draw is seeded by
@@ -813,6 +997,20 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     check(tree_method in TREE_METHODS, "tree_method must be one of %s, "
           "got %r", TREE_METHODS, tree_method)
     approx = tree_method == "approx"
+    check(num_class >= 1 and (num_class > 1) == (loss == "softprob"),
+          "boosting: loss=%r with num_class=%d; \"softprob\" takes "
+          "num_class >= 2 (XGBoost's multi:softprob), every other loss "
+          "one margin a row", loss, num_class)
+    check(not approx or num_class == 1,
+          "boosting: tree_method=\"approx\" with num_class=%d: each class "
+          "would sketch its cuts under its own hessians, K sketches and K "
+          "binnings a round, which this loop does not do; use "
+          "tree_method=\"hist\"", num_class)
+    if num_class > 1:
+        check(labels.min() >= 0 and labels.max() < num_class
+              and not np.any(labels % 1),
+              "boosting: softprob labels must be class ids in [0, %d)",
+              num_class)
     version, restored = rabit_tpu.load_checkpoint()
     if version == 0:
         if approx:
@@ -825,17 +1023,23 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 cuts = rabit_tpu.broadcast(
                     histogram.quantile_cuts(cut_sample(values), nbin)
                     if rabit_tpu.get_rank() == 0 else None, 0)
-        model = BoostedModel(cuts=cuts, base_score=0.0,
+        model = BoostedModel(cuts=cuts,
+                             base_score=0.5 if num_class > 1 else 0.0,
                              learning_rate=learning_rate, loss=loss,
-                             has_missing=False, tree_method=tree_method)
+                             has_missing=False, tree_method=tree_method,
+                             num_class=num_class)
     else:
         model = restored
         # (a forest committed before the field existed reads "hist")
         check(model.tree_method == tree_method,
               "boosting: the committed forest was grown with tree_method="
               "%r, this job asks for %r", model.tree_method, tree_method)
+        check(model.num_class == num_class,
+              "boosting: the committed forest has num_class=%d, this job "
+              "asks for %d", model.num_class, num_class)
         rabit_tpu.tracker_print(
             "[%d] restart iter=%d" % (rabit_tpu.get_rank(), version))
+    program.put("gbdt.classes", num_class)
     device_arm = on_tpu() or _engine_mod.is_device_plane()
     # where the engine hands a reduced device array back, a level's
     # histograms never cross to the host
@@ -907,21 +1111,25 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 program.count("gbdt.summary_entries",
                               payload.shape[1] * payload.shape[2])
                 program.count("gbdt.rows_rebinned", shard.n)
-            tree: list[TreeNode] = [TreeNode()]
-            slots, leaves = [0], []
-            # the level slot built for each slot of the level above (the
+            # the round's trees, one a class, grown together: their
+            # level slots tree-major (_route_round)
+            trees: list[list[TreeNode]] = [[TreeNode()]
+                                           for _ in range(num_class)]
+            slots, leaves = [0] * num_class, [[] for _ in trees]
+            # the level slot built for each slot of the level above (a
             # root for itself; -1: none)
-            build = [0]
+            build = list(range(num_class))
             for depth in range(max_depth):
                 if all(nid < 0 for nid in slots):
                     break
                 with program.span("gbdt.level", depth=depth):
-                    # the built slots' histograms in one fused bins pass
-                    # and ONE allreduce for the level (the per-node
-                    # XGBoost wire pattern, batched); every rank holds
-                    # the same reduced histograms, so builds the same
+                    # the built slots' histograms of every tree in one
+                    # program (a fused bins pass a tree) and ONE
+                    # allreduce for the level (the per-node XGBoost wire
+                    # pattern, batched); every rank holds the same
+                    # reduced histograms, so builds the same
                     with program.span("learn.dispatch"):
-                        local, order, calls = shard.level(build)
+                        local, order, calls = shard.level(build, depth)
                     if device_scan:
                         # reduced where they are, and ranked there: the
                         # host decides on the few rows a slot it fetches
@@ -938,15 +1146,17 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                             hists = _assemble(level_of, depth, built, order,
                                               len(slots))
                         # every slot is scanned, node or not: a round's
-                        # host work is then a full tree's whatever the
-                        # tree, as the device's is (static shapes), and
+                        # host work is then a full forest's whatever the
+                        # trees, as the device's is (static shapes), and
                         # a job's rounds take the same time
                         best = _scan_level(hists, reg_lambda,
                                            min_child_weight, has_missing)
                         build, default_left = [-1] * len(slots), 0
+                        width = len(slots) // num_class
                         for s, nid in enumerate(slots):
                             if nid < 0:
                                 continue
+                            tree = trees[s // width]
                             side = _split(
                                 tree[nid], tree, hists[s], reg_lambda,
                                 min_child_weight, has_missing, best[s],
@@ -954,13 +1164,14 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                             if side is not None:
                                 build[s] = 2 * s + side
                                 default_left += tree[nid].default_left
-                        tab, slots = _route(tree, slots, leaves)
+                        tabs, slots = _route_round(trees, slots, leaves)
                     with program.span("gbdt.partition"):
-                        shard.partition(tab)
+                        shard.partition(tabs)
                 live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
                 program.count("gbdt.levels_device_scan", int(device_scan))
-                program.count("gbdt.levels_chunked", int(calls > 1))
+                program.count("gbdt.levels_chunked",
+                              int(calls > num_class))
                 program.count("gbdt.kernel_calls", calls)
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
@@ -971,15 +1182,18 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
             # the nodes at the depth limit are leaves, with the weights
             # their parents' histograms gave them
             with program.span("gbdt.leaf"):
-                shard.leaf(_leaf_values(tree, slots, leaves, max_depth))
+                shard.leaf(_round_leaf_values(trees, slots, leaves,
+                                              max_depth))
             if approx:
                 # long made by now: 28.6 KB at 28 x 255
                 with program.span("gbdt.sketch"), \
                         program.span("gbdt.sketch.cuts"):
                     cuts = fetch(cuts, np.array)
                 model.tree_cuts.append(cuts)
-            _fill_splits(tree, cuts if approx else model.cuts)
-            model.trees.append(tree)
+            for tree in trees:
+                _fill_splits(tree, cuts if approx else model.cuts)
+            model.trees.extend(trees)
+            program.count("gbdt.trees", num_class)
             if round_idx + 1 < num_round:
                 # the next round's first programs are enqueued before
                 # the commit, whose host rounds then run beside them

@@ -802,7 +802,16 @@ def assemble_level(built, above, build):
     is, its sibling as parent minus built (the sum over ranks is
     linear: this IS the sibling's reduced histogram, to a float32
     rounding of the parent's cell), and zeros in both children of a
-    slot that was not split."""
+    slot that was not split.
+
+    The level of a round of several trees (a multi-class job's K trees,
+    grown together) is this same call on K times the slots, numbered
+    tree-major: slot ``s`` of tree ``k`` at a level ``w`` wide is entry
+    ``k * w + s``, whose children are entries ``2e`` and ``2e + 1`` of
+    the level below as within one tree, so that neither this function
+    nor :func:`level_shortlist` knows of trees, no array gains an axis,
+    and the subtraction stays within a tree because a parent and its
+    children are the same tree's."""
     import jax.numpy as jnp
 
     b = jnp.moveaxis(built, -1, 0)

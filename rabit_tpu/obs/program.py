@@ -243,6 +243,13 @@ def count(name: str, k: float = 1) -> None:
     _counters[name] = _counters.get(name, 0) + k
 
 
+def put(name: str, value: float) -> None:
+    """Set the counter ``name``: a size of the job (its number of
+    classes), which a second job of the process states again and does
+    not add to."""
+    _counters[name] = value
+
+
 def stats() -> dict:
     """The table, flat and JSON-serialisable: ``<span>.n``,
     ``<span>.total_s``, ``<span>.max_s``, ``<span>.self_s``,

@@ -253,6 +253,72 @@ def test_wide_level_scan_holds_a_level_and_hands_over_kilobytes_on_v5e(topo):
     assert m.temp_size_in_bytes <= 2 * level, m
 
 
+def test_forest_level_programs_of_seven_classes_compile_for_v5e(
+        topo, monkeypatch):
+    """The multi-class boosting cell's programs as ``boosting.
+    _DeviceShard`` builds them (8,388,608 rows of 54 columns staged as
+    (56, n), 7 classes, depth 6): the softmax gradient, a level program
+    a width holding every tree's kernel calls (7 a level, 14 at the
+    16-slot level), the row move and the leaf update of the (7, n) node
+    ids and margins in place, and the scans over 7 times the slots.
+    The shapes are handed a described device here (steering in the
+    test: the shard builds them from ``jax.ShapeDtypeStruct``)."""
+    from rabit_tpu.learn import boosting, histogram
+
+    n, f, k, nbin, depth = 8 << 20, 54, 7, 256, 6
+    real = jax.ShapeDtypeStruct
+    s = SingleDeviceSharding(topo.devices[0])
+    monkeypatch.setattr(jax, "ShapeDtypeStruct",
+                        lambda shape, dtype: real(shape, dtype, sharding=s))
+    monkeypatch.setattr(boosting, "_PROGRAMS", {})
+    shard = object.__new__(boosting._DeviceShard)
+    shard.model = boosting.BoostedModel(
+        cuts=np.zeros((f, nbin - 1), np.float32), base_score=0.5,
+        loss="softprob", num_class=k)
+    shard.n, shard.f, shard.nbin, shard.max_depth = n, f, nbin, depth
+    shard.half, shard.trees, shard.lead = 1 << (depth - 1), k, (k,)
+    shard.subsample, shard.seed = 1.0, 0
+    shard.use_pallas, shard.compute_dtype = True, None
+    shard.scan_by, shard.has_missing = (1.0, 1.0), False
+    fpad = histogram.staged_features(f, nbin)
+    shard.bins_t = real((fpad, n), jnp.int32)
+    assert fpad == 56 and histogram.slots_per_call(nbin, fpad) == 8
+    prog = shard._programs()
+
+    def fits(compiled):
+        m = compiled.memory_analysis()
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        assert need <= V5E_BYTES_LIMIT // 2, m
+        return m
+
+    assert sorted(prog["level"]) == [1, 2, 4, 8, 16]
+    for p, level in prog["level"].items():
+        calls = k * -(-p // 8)
+        assert level.as_text().count("tpu_custom_call") >= calls, p
+        m = fits(level)
+        assert m.output_size_in_bytes == k * p * f * nbin * 2 * 4
+        # beside the bins: the trees' (2, n) weight pairs, their bf16
+        # operands and a second call's slot codes
+        assert m.temp_size_in_bytes <= k * n * (8 + 4 + 4) + (8 << 20), m
+    grad = fits(prog["grad"])
+    assert grad.output_size_in_bytes == k * 2 * n * 4
+    # (7, n) is laid out in tiles of 8 rows: the donated array is the
+    # output, at 8 rows' size
+    move = fits(prog["partition"])
+    assert move.alias_size_in_bytes == move.output_size_in_bytes == 8 * n * 4
+    leaf = fits(prog["leaf"])
+    assert leaf.alias_size_in_bytes == 2 * 8 * n * 4
+    assert leaf.output_size_in_bytes <= 2 * 8 * n * 4 + 4096   # the tuple
+    assert sorted(prog["scan"]) == [1, 2, 4, 8, 16, 32]
+    widest = fits(prog["scan"][32])
+    # the level for the next depth and 8 shortlisted rows a slot
+    level = 2 * k * 32 * f * nbin * 4
+    short = 2 * k * 32 * histogram.SHORTLIST * nbin * 4
+    assert level + short <= widest.output_size_in_bytes \
+        <= level + short + (1 << 20)
+
+
 def _dense16_loop(topo):
     from rabit_tpu.learn import kmeans
 

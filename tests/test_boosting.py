@@ -365,8 +365,8 @@ def _spy_on_loop(monkeypatch):
         return side
 
     def seen_level(level):
-        def wrapper(self, build):
-            local, order, calls = level(self, build)
+        def wrapper(self, build, depth):
+            local, order, calls = level(self, build, depth)
             levels.append((list(build), np.asarray(local), list(order)))
             return local, order, calls
         return wrapper

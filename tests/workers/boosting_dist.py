@@ -3,6 +3,9 @@ model (split decisions are taken on the allreduced histogram), and the
 ensemble must fit the XOR function no single stump can.
 
 argv: <data_dir with X.npy / y.npy>
+
+``BOOST_NUM_CLASS`` > 1 runs the multi-class objective (``softprob``,
+labels class ids): a round commits that many trees.
 """
 import os
 import sys
@@ -33,9 +36,9 @@ def watch_built():
         weights.append({})
         return grad_hess(self, round_idx)
 
-    def seen_level(self, build):
+    def seen_level(self, build, depth):
         builds[-1].append(list(build))
-        return level(self, build)
+        return level(self, build, depth)
 
     def seen_split(node, tree, hist, *a):
         nid = next(i for i, other in enumerate(tree) if other is node)
@@ -86,11 +89,16 @@ def main() -> int:
     min_acc = float(os.environ.get("BOOST_MIN_ACC", "0.9"))
     watched = watch_built() if os.environ.get("BOOST_CHECK_BUILT") else None
     tree_method = os.environ.get("BOOST_TREE_METHOD", "hist")
-    model = boosting.train(Xs, ys, num_round=15, max_depth=3, nbin=16,
-                           subsample=subsample, tree_method=tree_method)
+    num_class = int(os.environ.get("BOOST_NUM_CLASS", "1"))
+    model = boosting.train(
+        Xs, ys, num_round=15, max_depth=3, nbin=16, subsample=subsample,
+        tree_method=tree_method, num_class=num_class,
+        loss="softprob" if num_class > 1 else "logistic")
     if watched:
         check_built(model, *watched)
     assert model.tree_method == tree_method
+    assert model.num_class == num_class
+    assert len(model.trees) == 15 * num_class
     if tree_method == "approx":
         # every tree on cuts of its own, the same on every rank
         assert len(model.tree_cuts) == len(model.trees) == 15
@@ -114,7 +122,11 @@ def main() -> int:
     for r in range(world):
         np.testing.assert_allclose(gathered[r], pred, rtol=1e-6)
 
-    acc = ((pred > 0.5) == (y > 0.5)).mean()
+    if num_class > 1:
+        assert pred.shape == (len(y), num_class)
+        acc = (pred.argmax(axis=1) == y).mean()
+    else:
+        acc = ((pred > 0.5) == (y > 0.5)).mean()
     assert acc > min_acc, acc
     rabit_tpu.tracker_print(
         f"boosting_dist rank {rank}/{world} acc={acc:.3f} OK")
