@@ -343,17 +343,20 @@ class _HostShard:
     def level(self, build, depth: int):
         """The histograms of the level slots ``build`` names (-1: none;
         tree-major, the level ``2^depth`` slots a tree), which, and the
-        kernel calls that took: a tree's slots by its own gradients and
-        node ids, one builder call a tree that builds any."""
+        kernel calls that took (``histogram.level_calls``' pair): a
+        tree's slots by its own gradients and node ids, one builder call
+        a tree that builds any."""
         width = 1 << depth
         order = [s for s in build if s >= 0]
-        built, calls = [], 0
+        built, calls = [], (0, 0)
         for k in range(self.trees):
             mine = [s - k * width for s in order if s // width == k]
             if not mine:
                 continue
-            calls += histogram.level_calls(len(mine), self.bins.shape[1],
-                                           self.nbin, self.kw["use_pallas"])
+            made, lane = histogram.level_calls(
+                len(mine), self.bins.shape[1], self.nbin,
+                self.kw["use_pallas"])
+            calls = calls[0] + made, calls[1] + lane
             built.append(histogram.build_level_local(
                 self.bins, self.grad[k], self.hess[k], self.node[k], mine,
                 self.nbin, totals=self.has_missing, **self.kw))
@@ -431,27 +434,25 @@ def softprob_grad_program(n: int, num_class: int, sampled: bool = False):
     return fn
 
 
-def _forest(fn, trees: int, shared: int = 0, join=None):
+def _forest(fn, trees: int, shared: int = 0):
     """The program of a round's ``trees`` trees from ``fn``, the program
-    of one, traceable.  Its first ``shared`` arguments are the round's
-    (the staged bins); every other has a leading tree axis, of which
-    call ``k`` of ``fn`` takes entry ``k``: tree k's kernel calls see
-    its own ``(2, n)`` weights and its own node row, as one tree's do.
-    The calls' results are joined (``join``: stacked, a leading tree
-    axis again) and all of them are one program: one hand-over and one
-    wait a round's level, whatever ``trees``.  A round of one tree is
+    of one, traceable (the row move, the leaf update; a level's
+    histograms are ``histogram.level_hist``'s, which takes the trees
+    together).  Its first ``shared`` arguments are the round's (the
+    staged bins); every other has a leading tree axis, of which call
+    ``k`` of ``fn`` takes entry ``k``.  The calls' results are stacked
+    (a leading tree axis again) and all of them are one program: one
+    hand-over and one wait, whatever ``trees``.  A round of one tree is
     ``fn`` itself, its arrays without the axis."""
     if trees == 1:
         return fn
     import jax
     import jax.numpy as jnp
 
-    join = join or jnp.stack
-
     def forest(*args):
         outs = [fn(*args[:shared], *(a[k] for a in args[shared:]))
                 for k in range(trees)]
-        return jax.tree.map(lambda *parts: join(parts), *outs)
+        return jax.tree.map(lambda *parts: jnp.stack(parts), *outs)
 
     forest.__name__ = fn.__name__
     return forest
@@ -521,17 +522,20 @@ class _DeviceShard:
         """The job's programs, compiled for its shapes: ``grad``,
         ``level`` (by its number of build slots: 1 at the root, then one
         a node of the level above; an empty slot holds no row, so a tree
-        that stops early runs the same programs; one of more slots than
-        ``histogram.slots_per_call`` holds several kernel calls),
-        ``partition`` and ``leaf``; and, where the level's histograms
-        stay on the device, ``scan`` by the level's number of slots.
-        Of a round of several trees each is the round's (:func:`_forest`):
-        a level's program holds every tree's kernel calls of that depth
-        and hands back their slots tree-major, which is the numbering
-        ``scan`` keeps (a slot's children are slots ``2s`` and ``2s + 1``
-        across trees as within one), so that it, ``histogram.
-        assemble_level`` and ``level_shortlist`` take a forest's level as
-        a tree's of that many slots."""
+        that stops early runs the same programs; its kernel calls are
+        those ``histogram.level_calls`` counts), ``partition`` and
+        ``leaf``; and, where the level's histograms stay on the device,
+        ``scan`` by the level's number of slots.  Of a round of several
+        trees each is the round's: a level's program builds every
+        tree's slots of that depth (``histogram.level_hist``: in one
+        kernel call where the level is wide enough for the lane-wide
+        body, a call a tree and more where it is not) and hands them
+        back tree-major, which is the numbering ``scan`` keeps (a slot's
+        children are slots ``2s`` and ``2s + 1`` across trees as within
+        one), so that it, ``histogram.assemble_level`` and
+        ``level_shortlist`` take a forest's level as a tree's of that
+        many slots; the row move and the leaf update are a tree's
+        program lifted over the trees (:func:`_forest`)."""
         import jax
         import jax.numpy as jnp
 
@@ -562,16 +566,23 @@ class _DeviceShard:
                 return jnp.where(keep[0], gh, 0.0) if keep else gh
 
         def level_of(nslots: int):
+            def slots_of(node, takes):
+                # build slot p takes the rows of level slot takes[p], a
+                # child of the node in slot p of the level above (the
+                # root: slot 0 of both); a row of the sibling, of an
+                # unsplit node or of a leaf is in no slot
+                above = node >> 1
+                return jnp.where(
+                    (node >= 0) & (node == _lookup(takes, above, nslots)),
+                    above, -1)
+
             def gbdt_level(bins_t, gh, node, takes):
+                # a round's trees at once: their kernel calls are
+                # level_hist's to share out
                 with jax.named_scope("gbdt/level"):
-                    # build slot p takes the rows of level slot takes[p],
-                    # a child of the node in slot p of the level above
-                    # (the root: slot 0 of both); a row of the sibling,
-                    # of an unsplit node or of a leaf is in no slot
-                    above = node >> 1
-                    slot = jnp.where(
-                        (node >= 0) & (node == _lookup(takes, above, nslots)),
-                        above, -1)
+                    slot = slots_of(node, takes) if trees == 1 else \
+                        jnp.stack([slots_of(node[k], takes[k])
+                                   for k in range(trees)])
                     return histogram.level_hist(
                         bins_t, gh, slot, nslots, f, nbin,
                         use_pallas=use_pallas, compute_dtype=cdt,
@@ -618,9 +629,8 @@ class _DeviceShard:
             "grad": softprob_grad_program(n, trees, sampled)
             if loss == "softprob" else build(
                 gbdt_grad, rows_f, sds((n,), jnp.float32), *keep),
-            "level": {p: build(_forest(level_of(p), trees, shared=1,
-                                       join=jnp.concatenate),
-                               bins, gh, rows_i, sds(lead + (p,), jnp.int32))
+            "level": {p: build(level_of(p), bins, gh, rows_i,
+                               sds(lead + (p,), jnp.int32))
                       for p in [1] + [1 << d for d in range(1, depth - 1)]},
             "partition": build(_forest(gbdt_partition, trees, shared=1),
                                bins, rows_i, sds(lead + (half, 4), jnp.int32),
@@ -685,8 +695,8 @@ class _DeviceShard:
         none; as many a tree, built or not): the level's histograms on
         the device, ``build``, and the kernel calls the program holds."""
         per_tree = len(build) // self.trees
-        calls = self.trees * histogram.level_calls(
-            per_tree, self.bins_t.shape[0], self.nbin, self.use_pallas)
+        calls = histogram.level_calls(per_tree, self.f, self.nbin,
+                                      self.use_pallas, self.trees)
         # each tree's kernel calls match its rows' node ids, which are
         # slots of its own level of 2^depth
         takes = np.asarray(build, np.int32)
@@ -1171,8 +1181,9 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 program.count("gbdt.levels")
                 program.count("gbdt.levels_device_scan", int(device_scan))
                 program.count("gbdt.levels_chunked",
-                              int(calls > num_class))
-                program.count("gbdt.kernel_calls", calls)
+                              int(calls[0] > num_class))
+                program.count("gbdt.kernel_calls", calls[0])
+                program.count("gbdt.kernel_calls_lane", calls[1])
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
                 program.count("gbdt.hists_derived", live if depth else 0)

@@ -585,22 +585,48 @@ def with_totals(hists, totals):
     return jnp.concatenate([hists, row.at[:, 0, 0].set(totals)], axis=1)
 
 
-def slots_per_call(nbin: int, f: int) -> int:
-    """Level slots one kernel call of :func:`level_hist` builds: half
-    the kernel's widest worthwhile call (a slot is a grad and a hess
-    channel)."""
-    from rabit_tpu.ops.histogram_kernel import max_channels
+def slots_per_call(nbin: int, f: int, nslots: int = 1, trees: int = 1) -> int:
+    """Level slots of a tree that one kernel call of :func:`level_hist`
+    builds at a level of ``trees`` trees of ``nslots`` slots (a slot is
+    a grad and a hess channel): ``ops.histogram_kernel.level_plan``'s,
+    which for a level under the lane-wide body's crossing (the root's,
+    say: the default) is half the two-level body's widest worthwhile
+    call."""
+    from rabit_tpu.ops.histogram_kernel import level_plan
 
-    return max(1, max_channels(nbin, f) // 2)
+    return level_plan(nbin, f, nslots, trees).slots
+
+
+def _level_chunks(nslots: int, f: int, nbin: int, trees: int):
+    """The kernel calls of a level, in the order of their channels:
+    ``(first tree, trees, first slot, slots, lane-wide)`` each.  A call
+    holds several trees only with all of their slots, so tree by tree
+    and slot by slot is tree-major, slot-major."""
+    from rabit_tpu.ops.histogram_kernel import level_plan
+
+    plan = level_plan(nbin, f, nslots, trees)
+    for t in range(0, trees, plan.trees):
+        for lo in range(0, nslots, plan.slots):
+            nt, ns = min(plan.trees, trees - t), min(plan.slots, nslots - lo)
+            yield t, nt, lo, ns, level_plan(nbin, f, ns, nt).lane
 
 
 def level_calls(nslots: int, f: int, nbin: int,
-                use_pallas: bool | None = None) -> int:
-    """Kernel calls :func:`level_hist` makes for a level of ``nslots``:
-    none on the XLA path."""
+                use_pallas: bool | None = None,
+                trees: int = 1) -> tuple[int, int]:
+    """``(kernel calls, those of them by the lane-wide body)`` that
+    :func:`level_hist` makes for a level of ``trees`` trees of
+    ``nslots`` slots: none on the XLA path.  The two-level body takes a
+    call a tree."""
     if use_pallas is None:
         use_pallas = on_tpu()
-    return -(-nslots // slots_per_call(nbin, f)) if use_pallas else 0
+    if not use_pallas:
+        return 0, 0
+    calls = lane_calls = 0
+    for _, nt, _, _, lane in _level_chunks(nslots, f, nbin, trees):
+        calls += 1 if lane else nt
+        lane_calls += lane
+    return calls, lane_calls
 
 
 def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
@@ -617,35 +643,53 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     rows have absent entries) the result is ``(nslots, f + 1, nbin,
     2)``: :func:`with_totals` of each slot's :func:`slot_totals`.
 
-    A level of more slots than :func:`slots_per_call` is built by
-    several kernel calls inside the same program, call ``k`` over the
-    slots from ``k * slots_per_call`` on (a row of another call's slots
-    matches none of this one's), and their results joined: a channel
-    sees the same rows in the same order either way, so the histograms
-    are those of one wide call bit for bit, at the kernel's time a
-    channel of a narrow one."""
+    A round of several trees passes ``(T, 2, n)`` weights and ``(T, n)``
+    node ids, each tree's slots its own: ``(T * nslots, ...)``,
+    tree-major, what the trees' levels one by one would give.
+
+    The level is built by the kernel calls ``ops.histogram_kernel.
+    level_plan`` names for its width, inside the same program
+    (:func:`level_calls`): a narrow level by the two-level body, a call
+    a tree, and a tree of more slots than :func:`slots_per_call` by
+    several, call ``k`` over the slots from ``k * slots_per_call`` on (a
+    row of another call's slots matches none of this one's); a wide
+    level by the lane-wide body, every tree in one call where its lanes
+    hold them.  Either way a channel is the float32 sum of the same
+    exact products of the same rounded weights; the bodies differ in
+    the order of those adds, so a level reads the same under both to
+    float32 rounding (1e-5 of a channel's absolute mass), not bit for
+    bit."""
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
 
     if use_pallas is None:
         use_pallas = on_tpu()
+    if gh.ndim == 2:
+        gh, node = gh[None], node[None]
+    trees = gh.shape[0]
     if use_pallas:
         cdt = compute_dtype or hk.DEFAULT_COMPUTE_DTYPE
-        per_call = slots_per_call(nbin, bins_t.shape[0])
-        outs = [hk.hist_fused_multi(bins_t, gh, nbin,
-                                    node_of_row=node - lo if lo else node,
-                                    nslots=min(per_call, nslots - lo),
-                                    compute_dtype=cdt)
-                for lo in range(0, nslots, per_call)]
+        outs = []
+        for t, nt, lo, ns, _ in _level_chunks(nslots, f, nbin, trees):
+            # one tree: the (2, n) and (n,) arguments a tree's call has
+            # always had
+            at = t if nt == 1 else slice(t, t + nt)
+            outs.append(hk.hist_fused_multi(
+                bins_t, gh[at], nbin,
+                node_of_row=node[at] - lo if lo else node[at],
+                nslots=ns, compute_dtype=cdt, features=f))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-        # (2 * nslots, fpad, nbin), slot-major
-        out = out.reshape(nslots, 2, -1, nbin).transpose(0, 2, 3, 1)[:, :f]
+        # (trees * nslots * 2, f, nbin), tree-major, slot-major
+        out = out.reshape(trees * nslots, 2, f, nbin).transpose(0, 2, 3, 1)
     else:
         cdt = jnp.float32
-        out = _level_xla(bins_t, gh, node, nslots, nbin)[:, :f]
+        out = jnp.concatenate([
+            _level_xla(bins_t, gh[t], node[t], nslots, nbin)[:, :f]
+            for t in range(trees)])
     if totals:
-        out = with_totals(out, slot_totals(gh, node, nslots, cdt))
+        out = with_totals(out, jnp.concatenate([
+            slot_totals(gh[t], node[t], nslots, cdt) for t in range(trees)]))
     return out
 
 

@@ -1,17 +1,18 @@
-"""Fused Pallas kernel for the XGBoost gradient-histogram pass.
+"""Fused Pallas kernels for the XGBoost gradient-histogram pass.
 
-Measured with chained difference timing (a data-dependent chain inside
-one program, long minus short — doc/benchmarks.md): the XLA one-hot
-formulation takes ~30 ms for 262k x 64 x 256 (N=2 output lanes leave
-the MXU ~2% occupied); this kernel runs the same histogram in ~0.8 ms
-(~37x) at ~100% MXU occupancy of its fpg-fold-inflated FLOPs, and
-generalizes to an (nw, n) weight matrix (any number of grad/hess/node
-channels) that builds every channel's histogram in ONE bins pass: the
-bin one-hots are built once per feature group and contracted against
-each weight row, so a GBDT tree level costs ~0.4 ms per channel
-instead of a 30 ms XLA pass per node.
+One sum, two tilings of it on the MXU, and a rule that picks between
+them from a call's static shapes (:func:`level_plan`).  Both build
+every channel's histogram in ONE bins pass, a tree level's node masks
+folded into the weights inside the kernel a row block at a time; the
+two-level body also takes a plain (nw, n) weight matrix of any
+channels.
 
-MXU structure (per feature group, per row block):
+**The two-level body** (``_hist_kernel``; narrow calls).  The plain
+one-hot product ``onehot(bins) @ w`` with w of N=2 channels leaves the
+MXU's lanes 2% occupied (XLA's takes ~30 ms for 262k x 64 x 256;
+chained difference timing, doc/benchmarks.md); this body runs the same
+histogram in ~0.8 ms (~37x) at ~100% MXU occupancy of its
+fpg-fold-inflated FLOPs.  Per feature group, per row block:
 
 * **Two-level bins.**  Split each bin index ``b`` into ``b = bh*lo+bl``
   (``hi`` x ``lo``, powers of two, e.g. 16x16 for 256 bins).  The
@@ -30,6 +31,23 @@ MXU structure (per feature group, per row block):
   raw per-group C products are accumulated in VMEM across row blocks;
   the cheap diagonal-block extraction runs in XLA afterwards.
 
+Its price is a product a feature group AND CHANNEL: on a v5e 10.3 ms +
+22.45 ms a channel over 33.6M rows of 28 features (1.67e-10 s a row,
+group and channel: the MXU's peak, of products seven eighths of which
+are thrown away), linear in the call's width.
+
+**The lane-wide body** (``_lane_kernel``; wide levels).  The plain
+product after all, for callers that fill its lanes: a level of 16
+slots is 32 channels, a round of seven trees 14 to 224.  Per feature
+``hist_f (classes, lanes) = onehot_f (classes, block) @ W (lanes,
+block)^T``, W the level's masked weights with the CHANNELS ON THE LANES
+(every tree, slot, grad and hess of the level a lane; zero rows up to
+a multiple of 128), one NT product a feature whatever the width and no
+diagonal to throw away: 3.44e-10 s a row and feature for up to 128
+lanes (0.324 s over the same 33.6M rows of 28 features at 2 channels
+as at 128), the MXU's own 4,096 cycles a feature and block.  The two
+cross at 14 channels (``_LANE_CROSSING``, with the measured table).
+
 Like the kmeans kernel the weight operand is rounded to a compute
 dtype (default bf16; one-hots are exact in bf16).  Summing n values
 each with independent ~2^-9 relative rounding error gives a relative
@@ -47,6 +65,7 @@ job, done here the TPU way.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +89,34 @@ _LINE_CHANNELS = 16
 # feature groups up to which the kernel's body holds a copy a group (28
 # to 64 features: the shapes measured above); past it the body loops
 _UNROLL_GROUPS = 8
+# The lane-wide body (``_lane_kernel``): the widest call in lanes, the
+# features of one looped step of its body, and the lanes a tree's
+# channels are padded to (the bf16 sublane tile, so that the trees'
+# rows of the weight operand join on tile boundaries).
+_LANE_WIDTH = 256
+_LANE_FEATURES = 8
+_LANE_TREE_ROWS = 16
+# The level width, in channels, from which the lane-wide body is the
+# cheaper one.  Measured on a v5e (tools/hist_kernel_check.py, builder's
+# chip run, PR 43; seconds a level, host clock around the call), 256
+# bins.  28 features staged as 32, 33.6M rows, one tree: the two-level
+# body 0.0603, 0.1058, 0.1956, 0.3754 at 2, 4, 8, 16 channels, then
+# calls of 16: 0.7487, 1.4957, 2.9890 at 32, 64, 128 (its line, above);
+# the lane-wide body 0.3237, 0.3232, 0.3236, 0.3235, 0.3236, 0.3247,
+# 0.3262 at the same widths: one price to 128 lanes, 3.44e-10 s a row
+# and feature, the MXU's own 4,096 cycles a feature and block of 2,048
+# (0.313 s).  The lines cross at 13.7 channels.  54 features staged as
+# 56, 8.4M rows: one tree 0.0274, 0.0467, 0.0865, 0.1652, 0.3273,
+# 0.6511, 1.2988 against 0.1556 to 0.1567 (crossing 15.0: the two-level
+# body pays for 56 features there, the lane-wide one for 54); seven
+# trees of 1, 2, 4, 8, 16 slots (14 to 224 channels) 0.1760, 0.3140,
+# 0.5890, 1.1390, 2.2740 in 7 to 14 calls against 0.1589, 0.1586,
+# 0.1585, 0.1585 in one call of 128 lanes and 0.3094 in one of 256.  A
+# tree's level is a power of two and a forest's a multiple of its trees,
+# so 14 sends 16 channels of one tree and 14 of seven to the lane-wide
+# body and leaves 8 and 12 to the two-level one, which is right at both
+# shapes.
+_LANE_CROSSING = 14
 # the weight operand's type where a caller names none (one-hots are
 # exact in it; every sum is accumulated in float32)
 DEFAULT_COMPUTE_DTYPE = jnp.bfloat16
@@ -87,17 +134,61 @@ def _next_pow2(v: int) -> int:
 
 
 def max_channels(nbin: int, f: int) -> int:
-    """The widest call worth issuing for this shape, in weight channels:
-    the smaller of what the (ngroups, nw, fpg*hi, fpg*lo) f32 VMEM
-    accumulator's budget holds and the width up to which a call's time
-    is linear in its channels (``_LINE_CHANNELS``: a 32-channel call
-    costs 0.2 s more than two of 16).  ``learn.histogram.level_hist``
-    builds a wider level in calls of this width, so wide-feature deep
-    levels chunk harder rather than failing the accumulator bound."""
+    """The widest call of the two-level body worth issuing for this
+    shape, in weight channels: the smaller of what the (ngroups, nw,
+    fpg*hi, fpg*lo) f32 VMEM accumulator's budget holds and the width
+    up to which a call's time is linear in its channels
+    (``_LINE_CHANNELS``: a 32-channel call costs 0.2 s more than two of
+    16).  Where a level is that body's (:func:`level_plan`: under the
+    lane-wide body's crossing, or too many features for its
+    accumulator), ``learn.histogram.level_hist`` builds a wider one in
+    calls of this width, so wide-feature deep levels chunk harder
+    rather than failing the accumulator bound."""
     hi, lo, fpg, ngroups = plan(nbin, f)
     per_channel = ngroups * fpg * hi * fpg * lo * 4
     return max(1, min(_LINE_CHANNELS,
                       (_VMEM_LIMIT_BYTES // 2) // per_channel))
+
+
+def lane_width(nbin: int, f: int) -> int:
+    """Lanes of the widest lane-wide call at this shape, a multiple of
+    128: what the ``(f, classes, lanes)`` f32 VMEM accumulator's budget
+    holds, up to ``_LANE_WIDTH``; 0 where 128 do not fit (968 features),
+    and the shape then has the two-level body alone."""
+    per_lane = f * _round_up(nbin, 16) * 4
+    return min(_LANE_WIDTH,
+               (_VMEM_LIMIT_BYTES // 2) // per_lane // 128 * 128)
+
+
+def lane_rows(nslots: int) -> int:
+    """Lanes a tree of ``nslots`` level slots takes in a lane-wide call:
+    its (grad, hess) channels padded to ``_LANE_TREE_ROWS``."""
+    return _round_up(2 * nslots, _LANE_TREE_ROWS)
+
+
+class LevelPlan(NamedTuple):
+    """How a tree level's channels go to the kernel: the body, and the
+    trees and the level slots of each that one call builds."""
+    lane: bool
+    trees: int
+    slots: int
+
+
+def level_plan(nbin: int, f: int, nslots: int, trees: int = 1) -> LevelPlan:
+    """The one rule, from shapes only: a level of ``trees`` trees of
+    ``nslots`` slots of (grad, hess) is ``2 * trees * nslots`` channels.
+    The two-level body costs a call's channels (calls of
+    :func:`max_channels`, a tree a call); the lane-wide body costs the
+    same for any width up to 128 lanes.  Under ``_LANE_CROSSING``
+    channels, and wherever :func:`lane_width` is 0, the level is the
+    two-level body's; from it on the lane-wide body's, as many trees a
+    call as its lanes hold at ``_LANE_TREE_ROWS`` lanes a tree or more
+    (or, of a tree wider than a call, ``lane_width // 2`` slots)."""
+    width = lane_width(nbin, f)
+    if width and 2 * trees * nslots >= _LANE_CROSSING:
+        slots = min(nslots, width // 2)
+        return LevelPlan(True, min(trees, width // lane_rows(slots)), slots)
+    return LevelPlan(False, 1, max(1, max_channels(nbin, f) // 2))
 
 
 def plan(nbin: int, f: int):
@@ -185,14 +276,123 @@ def _hist_kernel(bins_t_ref, w_ref, *rest,
         lax.fori_loop(0, ngroups, group, None)
 
 
+def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
+                 classes: int, trees: int, tree_rows: int):
+    """One row block, channels on the MXU's lanes: each feature's plain
+    one-hot ``(classes, block)`` against the level's masked weights
+    ``(lanes, block)``, one NT product a feature whatever the level's
+    width, added into the VMEM-resident ``(features, classes, lanes)``
+    output.
+
+    Tree ``t`` holds lanes ``t * tree_rows`` on, lane ``2 * s + c`` of
+    them being weight row ``c`` of the tree's rows at its node ``s``:
+    the masks are made here from the trees' ``(2, block)`` weights and
+    node ids, once a block.  The three rules of :func:`_hist_kernel`
+    hold: an absent entry's code matches no class (or one the caller
+    slices off), a row at node -1 no slot, a padded row weighs 0."""
+    i = pl.program_id(0)
+    block = w_ref.shape[2]
+    features, _, lanes = out_ref.shape
+    cdt = w_ref.dtype
+    prec = (lax.Precision.HIGHEST if cdt == jnp.float32
+            else lax.Precision.DEFAULT)
+    # selects in float32 (exact from the compute dtype and back)
+    w = w_ref[:].astype(jnp.float32)               # (trees, 2, block)
+    node = node_ref[:]                             # (trees, block) int32
+    row = lax.broadcasted_iota(jnp.int32, (tree_rows, block), 0)
+    slot = lax.shift_right_logical(row, 1)
+    hess = lax.bitwise_and(row, 1) == 1
+    parts = []
+    for t in range(trees):
+        gh = jnp.where(hess, w[t, 1:2, :], w[t, 0:1, :])
+        parts.append(jnp.where(node[t:t + 1, :] == slot, gh, 0.0))
+    if lanes > trees * tree_rows:
+        parts.append(jnp.zeros((lanes - trees * tree_rows, block),
+                               jnp.float32))
+    wm = jnp.concatenate(parts, axis=0).astype(cdt)        # (lanes, block)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    cls = lax.broadcasted_iota(jnp.int32, (classes, block), 0)
+
+    def some(start, count):
+        bt = bins_t_ref[pl.ds(start, _LANE_FEATURES), :]
+        for j in range(count):
+            onehot = (bt[j:j + 1, :] == cls).astype(cdt)
+            out_ref[start + j] += lax.dot_general(
+                onehot, wm, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+
+    def group(grp, carry):
+        some(pl.multiple_of(grp * _LANE_FEATURES, _LANE_FEATURES),
+             _LANE_FEATURES)
+        return carry
+
+    # one body of _LANE_FEATURES products, looped: a copy a feature is
+    # 28 to 54 of them, and PR 33 paid minutes for an unrolled wide body
+    whole, rest = divmod(features, _LANE_FEATURES)
+    if whole:
+        lax.fori_loop(0, whole, group, None)
+    if rest:
+        some(whole * _LANE_FEATURES, rest)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("nbin", "block", "interpret", "compute_dtype",
-                     "plan_override", "nslots"))
+                     "plan_override", "nslots", "lanes", "features"))
 def _hist_multi(bins_t, weights, node, nbin: int, block: int,
                 interpret: bool, compute_dtype,
-                plan_override=None, nslots: int = 0) -> jax.Array:
+                plan_override=None, nslots: int = 0,
+                lanes: int = 0, features: int = 0) -> jax.Array:
+    """Both bodies' calls, so that the device operation has one name
+    whichever body a call took: ``lanes`` > 0 is the lane-wide body over
+    ``(trees, 2, n)`` weights and ``(trees, n)`` node ids."""
     f, n = bins_t.shape
+    if lanes:
+        trees = weights.shape[0]
+        classes = _round_up(nbin, 16)
+        tree_rows = lane_rows(nslots)
+        fpad = _round_up(f, _LANE_FEATURES)
+        npad = _round_up(n, block)
+        cdt = jnp.dtype(compute_dtype)
+        # as below: the bins are neither copied nor padded to the block,
+        # and what the last block reads past them meets weight 0, node -1
+        operands = [
+            jnp.pad(bins_t.astype(jnp.int32), ((0, fpad - f), (0, 0))),
+            jnp.pad(weights.astype(cdt), ((0, 0), (0, 0), (0, npad - n))),
+            jnp.pad(node.astype(jnp.int32), ((0, 0), (0, npad - n)),
+                    constant_values=-1)]
+        raw = pl.pallas_call(
+            functools.partial(_lane_kernel, classes=classes, trees=trees,
+                              tree_rows=tree_rows),
+            grid=(npad // block,),
+            in_specs=[
+                pl.BlockSpec((fpad, block), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                # (trees, 2, n) as it is: folding the trees into the
+                # rows is a copy, and one XLA takes minutes to compile
+                pl.BlockSpec((trees, 2, block), lambda i: (0, 0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((trees, block), lambda i: (0, i),
+                             memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((features, classes, lanes),
+                                   lambda i: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((features, classes, lanes),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )(*operands)
+        # lane t * tree_rows + 2 * s + c -> channel (t, s, c); tiny, XLA
+        out = raw[:, :nbin, :trees * tree_rows].reshape(
+            features, nbin, trees, tree_rows)[..., :2 * nslots]
+        return out.transpose(2, 3, 0, 1).reshape(
+            trees * 2 * nslots, features, nbin)
     nw = weights.shape[0] * max(nslots, 1)
     if plan_override is None:
         hi, lo, fpg, ngroups = plan(nbin, f)
@@ -279,7 +479,8 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                      interpret: bool | None = None,
                      compute_dtype=DEFAULT_COMPUTE_DTYPE,
                      plan_override: tuple | None = None,
-                     node_of_row=None, nslots: int = 0) -> jax.Array:
+                     node_of_row=None, nslots: int = 0,
+                     features: int | None = None) -> jax.Array:
     """(nw, f, nbin) histograms of ``nw`` weight channels in one pass.
 
     ``bins_t`` is the TRANSPOSED (f, n) int32 bins array (the layout
@@ -287,7 +488,9 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
     boosting reuses it for every node, level and round).  ``weights``
     is (nw, n); each row gets its own (f, nbin) histogram.  Extra
     channels share the single bins read, so per-level node histograms
-    cost one HBM pass instead of one per node.
+    cost one HBM pass instead of one per node.  Of a staged array
+    padded to whole feature groups, ``features`` says how many leading
+    rows are features: the result has that many.
 
     A tree level passes ``node_of_row`` (n,) int32 and ``nslots``: the
     result is then ``(nslots * nw, f, nbin)``, slot-major, channel
@@ -295,21 +498,61 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
     (a row at any other node, -1 say, is in no histogram).  The masks
     are made inside the kernel from 4 bytes a row; no (channels, n)
     weight matrix is written to HBM.
+
+    A level of several trees passes ``(T, 2, n)`` weights and ``(T, n)``
+    node ids: ``(T * nslots * 2, f, nbin)``, tree-major, tree ``t``'s
+    channels those of a call on ``weights[t]`` and ``node_of_row[t]``.
+
+    A level of (grad, hess) pairs is built by the body
+    :func:`level_plan` names for its shape: the lane-wide one takes the
+    trees in one call (more than its lanes hold is a ``ValueError``),
+    the two-level one a call a tree.  Both round the weights to the
+    compute dtype once and add exact products in float32; the order of
+    those adds is each body's own, so they are held to float32
+    rounding, not to equality bit for bit (which the chip showed at the
+    boosting cells' shapes all the same: tools/hist_kernel_check.py).
     """
     if interpret is None:
         interpret = not on_tpu()
+    weights = jnp.asarray(weights)
     f, n = bins_t.shape
-    nw = weights.shape[0] * max(nslots, 1)
-    if not 1 <= nw <= _MAX_CHANNELS:
-        raise ValueError(f"nw={nw} out of range [1, {_MAX_CHANNELS}]")
+    features = f if features is None else features
     if block is None:
         block = default_block(n)
     block = min(block, _round_up(n, 128))
-    return _hist_multi(jnp.asarray(bins_t), jnp.asarray(weights),
+    cdt = jnp.dtype(compute_dtype).name
+    forest = weights.ndim == 3
+    if forest and not (nslots and weights.shape[1] == 2):
+        raise ValueError("a level of several trees takes (T, 2, n) weights "
+                         "with their node ids and nslots")
+    trees = weights.shape[0] if forest else 1
+    if nslots and weights.shape[-2] == 2 and plan_override is None:
+        plan = level_plan(nbin, features, nslots, trees)
+        if plan.lane:
+            if trees > plan.trees or nslots > plan.slots:
+                raise ValueError(
+                    f"{trees} trees of {nslots} slots out of range of a "
+                    f"lane-wide call: {plan.trees} trees of {plan.slots}")
+            node = jnp.asarray(node_of_row)
+            return _hist_multi(
+                jnp.asarray(bins_t), weights if forest else weights[None],
+                node if forest else node[None], nbin, block, interpret, cdt,
+                nslots=nslots, features=features,
+                lanes=_round_up(trees * lane_rows(nslots), 128))
+    if forest:
+        return jnp.concatenate([
+            hist_fused_multi(bins_t, weights[t], nbin, block, interpret,
+                             compute_dtype, node_of_row=node_of_row[t],
+                             nslots=nslots, features=features)
+            for t in range(trees)])
+    nw = weights.shape[0] * max(nslots, 1)
+    if not 1 <= nw <= _MAX_CHANNELS:
+        raise ValueError(f"nw={nw} out of range [1, {_MAX_CHANNELS}]")
+    return _hist_multi(jnp.asarray(bins_t), weights,
                        None if not nslots else jnp.asarray(node_of_row),
-                       nbin, block, interpret,
-                       jnp.dtype(compute_dtype).name,
-                       plan_override=plan_override, nslots=nslots)
+                       nbin, block, interpret, cdt,
+                       plan_override=plan_override,
+                       nslots=nslots)[:, :features]
 
 
 def hist_fused(bins, grad, hess, nbin: int, block: int | None = None,

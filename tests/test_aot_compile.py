@@ -100,16 +100,18 @@ def _hist_level_chunked(nslots, topo):
     from rabit_tpu.learn import histogram
 
     # the benchmark cell's widest level as level_hist builds it (16
-    # slots since a level builds one child of every split node): two
-    # calls of 8 slots in one program; beside the bf16 grad and hess the
-    # second call's slot codes are the one temporary of a row's length
+    # slots since a level builds one child of every split node): 32
+    # channels, one call of the lane-wide body (two calls of 8 slots of
+    # the two-level body before the rule); beside the bf16 grad and hess
+    # no temporary of a row's length
     n = 32 << 20
+    assert histogram.level_calls(nslots, 28, 256, True) == (1, 1)
     fn = jax.jit(lambda bins, gh, node: histogram.level_hist(
         bins, gh, node, nslots, 28, 256, use_pallas=True))
     compiled = fn.lower(*_one_chip(topo, ((32, n), jnp.int32),
                                    ((2, n), jnp.float32),
                                    ((n,), jnp.int32))).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= nslots // 8
+    assert compiled.as_text().count("tpu_custom_call") == 1
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= n * 2 * 2 + n * 4 + (2 << 20))
     return compiled
@@ -258,9 +260,10 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
     """The multi-class boosting cell's programs as ``boosting.
     _DeviceShard`` builds them (8,388,608 rows of 54 columns staged as
     (56, n), 7 classes, depth 6): the softmax gradient, a level program
-    a width holding every tree's kernel calls (7 a level, 14 at the
-    16-slot level), the row move and the leaf update of the (7, n) node
-    ids and margins in place, and the scans over 7 times the slots.
+    a width holding every tree's slots (one lane-wide kernel call for
+    the seven trees at every width), the row move and the
+    leaf update of the (7, n) node ids and margins in place, and the
+    scans over 7 times the slots.
     The shapes are handed a described device here (steering in the
     test: the shard builds them from ``jax.ShapeDtypeStruct``)."""
     from rabit_tpu.learn import boosting, histogram
@@ -294,8 +297,11 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
 
     assert sorted(prog["level"]) == [1, 2, 4, 8, 16]
     for p, level in prog["level"].items():
-        calls = k * -(-p // 8)
-        assert level.as_text().count("tpu_custom_call") >= calls, p
+        # 14 to 224 channels: one lane-wide call for the seven trees
+        # (256 lanes at 16 slots)
+        calls = histogram.level_calls(p, f, nbin, True, k)
+        assert calls == (1, 1)
+        assert level.as_text().count("tpu_custom_call") == calls[0], p
         m = fits(level)
         assert m.output_size_in_bytes == k * p * f * nbin * 2 * 4
         # beside the bins: the trees' (2, n) weight pairs, their bf16

@@ -545,16 +545,25 @@ def test_counters_of_one_full_depth_3_round(arm, which):
         "gbdt.nodes_split")] == [3, 8, 8, 7]
 
 
-@pytest.mark.parametrize("which,use_pallas,chunked", [
-    ("device", True, 1), ("device", False, 0), ("host", False, 0)],
-    ids=["device-kernel", "device-xla", "host-xla"])
-def test_chunked_levels_of_one_depth_6_round(arm, which, use_pallas,
-                                                  chunked):
-    """Of a depth-6 tree's six levels the last alone (16 build slots,
-    32 channels) is wider than the kernel's widest worthwhile call, and
-    only an arm that runs the kernel issues calls at all."""
+@pytest.mark.parametrize("which,use_pallas,crossing,chunked,calls,lane", [
+    ("device", True, None, 0, 6, 2), ("device", True, 1 << 30, 1, 7, 0),
+    ("host", True, None, 0, 6, 2),
+    ("device", False, None, 0, 0, 0), ("host", False, None, 0, 0, 0)],
+    ids=["device-kernel", "device-kernel-two-level", "host-kernel",
+         "device-xla", "host-xla"])
+def test_chunked_levels_of_one_depth_6_round(arm, monkeypatch, which,
+                                             use_pallas, crossing, chunked,
+                                             calls, lane):
+    """Of a depth-6 tree's six levels the last two (8 and 16 build
+    slots, 16 and 32 channels) are at or over the crossing of the
+    kernel's rule and one lane-wide call each; with the two-level body
+    alone the last is wider than its widest worthwhile call and is
+    chunked.  Only an arm that runs the kernel issues calls at all."""
     from rabit_tpu.obs import program
+    from rabit_tpu.ops import histogram_kernel as hk
 
+    if crossing:
+        monkeypatch.setattr(hk, "_LANE_CROSSING", crossing)
     X, y = _tabular(n=2000)
     arm(which)
     before = program.stats()
@@ -563,7 +572,8 @@ def test_chunked_levels_of_one_depth_6_round(arm, which, use_pallas,
     assert len(model.trees[0]) > 63                    # reaches depth 6
     after = program.stats()
     assert [after.get(k, 0) - before.get(k, 0) for k in (
-        "gbdt.levels", "gbdt.levels_chunked")] == [6, chunked]
+        "gbdt.levels", "gbdt.levels_chunked", "gbdt.kernel_calls",
+        "gbdt.kernel_calls_lane")] == [6, chunked, calls, lane]
 
 
 def test_device_arm_trains_a_forest_of_levels_wider_than_a_kernel_call(arm):

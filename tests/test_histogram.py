@@ -278,13 +278,37 @@ def _pallas_calls(jaxpr) -> int:
     return total
 
 
+@pytest.fixture
+def body(request, monkeypatch):
+    """``two-level``: the rule's crossing out of reach, so that every
+    level is the two-level body's, in calls of its widest; ``rule``:
+    the rule as it stands; ``lane``: every level the lane-wide body's."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    crossing = {"two-level": 1 << 30, "rule": hk._LANE_CROSSING,
+                "lane": 2}[request.param]
+    monkeypatch.setattr(hk, "_LANE_CROSSING", crossing)
+    return request.param
+
+
+def _mass(bins_t, gh, node, nslots, f, nbin):
+    import jax.numpy as jnp
+
+    return np.asarray(histogram.level_hist(
+        bins_t, jnp.abs(gh), node, nslots, f, nbin, use_pallas=False))
+
+
+@pytest.mark.parametrize("body", ["two-level", "rule"], indirect=True)
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("nslots", [1, 8, 9, 16, 17, 32, 64])
-def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype):
+def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype, body):
     """The calls of a chunked level give every channel the rows of the
-    one wide call in the same order: equal bit for bit (the direct call
-    takes at most 64 channels, so a 64-slot level is held against two),
-    and equal to the float32 XLA level to the operand's rounding."""
+    one wide call in the same order: equal bit for bit under the
+    two-level body (the direct call takes at most 64 channels, so a
+    64-slot level is held against two), under the rule (a wide level is
+    one lane-wide call, the direct calls lane-wide too) to the order of
+    the float32 adds; and equal to the float32 XLA level to the
+    operand's rounding."""
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
@@ -301,23 +325,29 @@ def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype):
         for lo in range(0, nslots, 32)])
     direct = np.asarray(direct.reshape(nslots, 2, -1, nbin)
                         .transpose(0, 2, 3, 1)[:, :f])
-    np.testing.assert_array_equal(got, direct)
+    mass = _mass(bins_t, gh, node, nslots, f, nbin)
+    if body == "two-level":
+        np.testing.assert_array_equal(got, direct)
+    else:
+        assert (np.abs(got - direct) <= 1e-5 * mass.sum(
+            axis=(1, 2), keepdims=True)).all()
     for s in empty:
         assert not got[s].any()
     want = np.asarray(histogram.level_hist(bins_t, gh, node, nslots, f, nbin,
                                            use_pallas=False))
     # a bin's sum of bf16-rounded weights is off by 2^-9 of its sum of |w|
     room = 2.0 ** -8 if dtype == "bfloat16" else 1e-5
-    mass = np.asarray(histogram.level_hist(
-        bins_t, jnp.abs(gh), node, nslots, f, nbin, use_pallas=False))
     assert (np.abs(got - want) <= room * mass + 1e-4).all()
 
 
+@pytest.mark.parametrize("body", ["two-level", "rule"], indirect=True)
 @pytest.mark.parametrize("nslots", [1, 8, 9, 16, 32, 64])
-def test_level_lowers_to_one_kernel_call_a_width(nslots):
-    """At or under the kernel's widest worthwhile call a level is one
-    ``pallas_call``; a wider one is ceil(2 * nslots / width) of them in
-    the same program."""
+def test_level_lowers_to_one_kernel_call_a_width(nslots, body):
+    """Under the two-level body a level at or under the kernel's widest
+    worthwhile call is one ``pallas_call`` and a wider one
+    ceil(2 * nslots / width) of them in the same program; under the rule
+    a level from the crossing on is one lane-wide call up to 256
+    lanes."""
     import jax
 
     from rabit_tpu.ops import histogram_kernel as hk
@@ -329,10 +359,11 @@ def test_level_lowers_to_one_kernel_call_a_width(nslots):
     jaxpr = jax.make_jaxpr(lambda b, w, nd: histogram.level_hist(
         b, w, nd, nslots, f, nbin, use_pallas=True))(bins_t, gh, node)
     calls = _pallas_calls(jaxpr.jaxpr)
-    assert calls == -(-2 * nslots // width)
-    assert calls == histogram.level_calls(nslots, bins_t.shape[0], nbin,
-                                          use_pallas=True)
-    assert histogram.level_calls(nslots, f, nbin, use_pallas=False) == 0
+    lane = body == "rule" and 2 * nslots >= hk._LANE_CROSSING
+    assert calls == (1 if lane else -(-2 * nslots // width))
+    assert histogram.level_calls(nslots, f, nbin, use_pallas=True) == (
+        calls, int(lane))
+    assert histogram.level_calls(nslots, f, nbin, use_pallas=False) == (0, 0)
 
 
 @pytest.mark.parametrize("nbin,f,want", [(256, 28, 16), (256, 32, 16),
@@ -349,19 +380,26 @@ def test_widest_call_is_the_smaller_of_the_line_and_the_vmem_bound(
     assert histogram.slots_per_call(nbin, f) == max(1, want // 2)
 
 
-def test_direct_caller_still_gets_64_channels_and_no_more():
+@pytest.mark.parametrize("body", ["two-level", "rule"], indirect=True)
+def test_direct_caller_still_gets_64_channels_and_no_more(body):
+    """The two-level body's widest direct call is 64 channels; the
+    lane-wide body's is what 256 lanes hold."""
     from rabit_tpu.ops import histogram_kernel as hk
 
     bins_t, gh, node, _ = _level_case(32, n=256)
     out = hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=32)
     assert out.shape[0] == 64
+    over = 33 if body == "two-level" else 129
     with pytest.raises(ValueError, match="out of range"):
-        hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=33)
+        hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=over)
 
 
-def test_build_level_local_takes_level_hists_chunks():
+@pytest.mark.parametrize("body,calls", [("two-level", (3, 0)),
+                                        ("rule", (1, 1))], indirect=["body"])
+def test_build_level_local_takes_level_hists_chunks(body, calls):
     """A ``node_ids`` list longer than a call's slots, of ids in no
-    order: ``build_level_local`` is ``level_hist`` on the ids' places."""
+    order: ``build_level_local`` is ``level_hist`` on the ids' places
+    (42 channels: three calls of the two-level body, one lane-wide)."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(33)
@@ -379,11 +417,205 @@ def test_build_level_local_takes_level_hists_chunks():
     want = np.asarray(histogram.level_hist(
         jnp.asarray(bins.T), jnp.stack([grad, hess]), jnp.asarray(place),
         len(ids), f, nbin, use_pallas=True))
-    assert histogram.level_calls(len(ids), f, nbin, use_pallas=True) == 3
+    assert histogram.level_calls(len(ids), f, nbin, use_pallas=True) == calls
     np.testing.assert_array_equal(got, want)
     exact = np.asarray(histogram.build_level_local(
         bins, grad, hess, node, ids, nbin, use_pallas=False))
     np.testing.assert_allclose(got, exact, rtol=0, atol=0.05)
+
+
+# ----------------------------------------------------------------------
+# the lane-wide body: channels on the MXU's lanes, a round's trees in
+# one call; and the rule that shares a level out between the bodies
+# ----------------------------------------------------------------------
+def _forest_case(trees, nslots, n, f, nbin, seed=43):
+    """A round's level: ``(fpad, n)`` bins with absent entries (code
+    ``nbin``), ``(T, 2, n)`` weights, ``(T, n)`` slots with rows at -1."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed + trees * nslots)
+    bins = rng.integers(0, nbin + 1, (n, f)).astype(np.int32)
+    gh = np.stack([rng.standard_normal((trees, n)),
+                   rng.random((trees, n))], axis=1).astype(np.float32)
+    node = rng.integers(-1, nslots, (trees, n)).astype(np.int32)
+    fpad = histogram.staged_features(f, nbin)
+    bins_t = jnp.zeros((fpad, n), jnp.int32).at[:f].set(bins.T)
+    return bins, bins_t, gh, node
+
+
+def _float64_level(bins, gh, node, nslots, nbin, dtype):
+    """``(T * nslots, f, nbin, 2)`` in float64 from the weights rounded
+    as the kernel rounds them; an absent entry in no bin, a row at -1
+    in no slot."""
+    import jax.numpy as jnp
+
+    w = np.asarray(jnp.asarray(gh).astype(dtype).astype(jnp.float32),
+                   np.float64)
+    trees, (n, f) = node.shape[0], bins.shape
+    out = np.zeros((trees, nslots, f, nbin + 1, 2))
+    for t in range(trees):
+        live = node[t] >= 0
+        for j in range(f):
+            for c in range(2):
+                np.add.at(out[t, :, j, :, c],
+                          (node[t][live], bins[live, j]), w[t, c][live])
+    return out[:, :, :, :nbin].reshape(trees * nslots, f, nbin, 2)
+
+
+@pytest.mark.parametrize("trees,nslots,n,dtype,totals", [
+    (7, 1, 2500, "bfloat16", False),        # 14 channels
+    (1, 16, 2500, "bfloat16", False),       # 32
+    (1, 16, 2048, "float32", True),         # 32, exact operands, totals
+    (4, 16, 4097, "bfloat16", True),        # 128: every lane, ragged
+    (1, 64, 2500, "bfloat16", False),       # 128, one tree
+    (7, 16, 1300, "bfloat16", False),       # 224: a call of 256 lanes
+    (7, 16, 700, "float32", False),
+    (3, 2, 900, "float32", True),           # trees narrower than a tile
+    (9, 16, 600, "bfloat16", False),        # 288: two calls, 8 + 1 trees
+    (1, 256, 2100, "bfloat16", False),      # 512: a tree over two calls
+], ids=lambda v: str(v))
+def test_lane_wide_level_is_the_float64_level_and_the_two_level_bodys(
+        monkeypatch, trees, nslots, n, dtype, totals):
+    """The lane-wide body in interpret mode against numpy in float64
+    and against the two-level body on the same level: absent entries,
+    rows at node -1, a ragged ``n``, both compute dtypes, with and
+    without the slots' totals."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    f, nbin = 5, 16
+    bins, bins_t, gh, node = _forest_case(trees, nslots, n, f, nbin)
+
+    def level(crossing):
+        monkeypatch.setattr(hk, "_LANE_CROSSING", crossing)
+        return np.asarray(histogram.level_hist(
+            bins_t, jnp.asarray(gh), jnp.asarray(node), nslots, f, nbin,
+            use_pallas=True, compute_dtype=dtype, totals=totals))
+
+    got, old = level(2), level(1 << 30)
+    assert histogram.level_calls(nslots, f, nbin, True, trees)[1] == 0
+    monkeypatch.setattr(hk, "_LANE_CROSSING", 2)
+    calls, lane = histogram.level_calls(nslots, f, nbin, True, trees)
+    assert calls == lane == -(-trees // max(1, 256 // max(16, 2 * nslots))) \
+        * -(-2 * nslots // 256)
+    assert got.shape == old.shape == (trees * nslots, f + totals, nbin, 2)
+    want = _float64_level(bins, gh, node, nslots, nbin, dtype)
+    mass = _float64_level(bins, np.abs(gh), node, nslots, nbin, dtype).sum(
+        axis=(1, 2), keepdims=True)
+    for hist in (got, old):
+        assert (np.abs(hist[:, :f] - want) <= 1e-5 * mass).all()
+    assert (np.abs(got - old) <= 1e-5 * np.concatenate(
+        [mass] * (1 + totals), axis=1)[:, :f + totals].max(
+            axis=1, keepdims=True)).all()
+    if totals:
+        # bin 0 of the extra row: the slot's (grad, hess) over all rows
+        sums = _float64_level(np.zeros((n, 1), np.int32), gh, node, nslots,
+                              nbin, dtype)[:, 0, 0]
+        np.testing.assert_allclose(got[:, f, 0], sums, rtol=1e-5, atol=1e-4)
+        assert not got[:, f, 1:].any()
+
+
+def test_a_forests_level_is_its_trees_levels_one_by_one():
+    """``(T, 2, n)`` weights and ``(T, n)`` slots give, tree-major, what
+    each tree's own ``level_hist`` gives: on the XLA path exactly, and
+    the entry itself refuses a forest without its node ids."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    f, nbin, trees, nslots = 5, 16, 3, 4
+    _, bins_t, gh, node = _forest_case(trees, nslots, 800, f, nbin)
+    gh, node = jnp.asarray(gh), jnp.asarray(node)
+    for kw in ({"use_pallas": False},
+               {"use_pallas": True, "compute_dtype": "float32"}):
+        whole = np.asarray(histogram.level_hist(
+            bins_t, gh, node, nslots, f, nbin, totals=True, **kw))
+        parts = np.concatenate([np.asarray(histogram.level_hist(
+            bins_t, gh[t], node[t], nslots, f, nbin, totals=True, **kw))
+            for t in range(trees)])
+        np.testing.assert_allclose(whole, parts, rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="node ids"):
+        hk.hist_fused_multi(bins_t, gh, nbin)
+
+
+# (f, trees) of the four boosting configurations, 256 bins, depth 6: the
+# build slots of a tree level by level are 1, 1, 2, 4, 8, 16
+@pytest.mark.parametrize("f,trees,want", [
+    # HIGGS and approx: 2 to 32 channels
+    (28, 1, [(1, 0), (1, 0), (1, 0), (1, 0), (1, 1), (1, 1)]),
+    # Covertype: 14 to 224 channels of seven trees
+    (54, 7, [(1, 1), (1, 1), (1, 1), (1, 1), (1, 1), (1, 1)]),
+    # Bosch: 128 lanes of 968 features do not fit; 3 slots a call
+    (968, 1, [(1, 0), (1, 0), (1, 0), (2, 0), (3, 0), (6, 0)]),
+], ids=["higgs-approx", "covtype", "bosch"])
+def test_the_rule_at_the_four_configurations_shapes(f, trees, want):
+    """Which body builds each level of a depth-6 round, in how many
+    calls: ``level_calls`` as ``(calls, lane-wide among them)``."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    levels = [1, 1, 2, 4, 8, 16]
+    got = [histogram.level_calls(s, f, 256, True, trees) for s in levels]
+    assert got == want
+    # the staged width reads the same rule
+    assert got == [histogram.level_calls(
+        s, histogram.staged_features(f, 256), 256, True, trees)
+        for s in levels]
+    for s, (calls, lane) in zip(levels, got):
+        plan = hk.level_plan(256, f, s, trees)
+        assert plan.lane == bool(lane)
+        assert histogram.slots_per_call(256, f, s, trees) == plan.slots
+        assert calls == -(-trees // plan.trees) * -(-s // plan.slots)
+    assert hk.lane_width(256, f) == (0 if f == 968 else 256)
+
+
+@pytest.mark.parametrize("nslots,trees,want", [
+    (6, 1, (False, 1, 8)), (7, 1, (True, 1, 7)), (64, 1, (True, 1, 64)),
+    (128, 1, (True, 1, 128)), (256, 1, (True, 1, 128)),
+    (1, 6, (False, 1, 8)), (1, 7, (True, 7, 1)), (1, 20, (True, 16, 1)),
+    (16, 9, (True, 8, 16)), (32, 7, (True, 4, 32))],
+    ids=lambda v: str(v))
+def test_level_plan_by_width_alone(nslots, trees, want):
+    """The rule reads the level's channels and nothing else of the job:
+    under the crossing the two-level body's calls, from it on the
+    lane-wide body's, a tree padded to 16 lanes, 256 lanes a call."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    assert hk._LANE_CROSSING == 14
+    assert tuple(hk.level_plan(256, 28, nslots, trees)) == want
+
+
+def test_the_chip_check_of_the_two_bodies_rehearsed(monkeypatch):
+    """``tools/hist_kernel_check.py`` at tiny shapes with the kernels
+    interpreted: every comparison it would make on the chip is made and
+    passes, a timing line a width follows, and off the chip ``main``
+    refuses to judge."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools",
+                        "hist_kernel_check.py")
+    spec = importlib.util.spec_from_file_location("hist_kernel_check", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.setattr(tool, "SLICE_ROWS", 2048)
+    monkeypatch.setattr(tool, "WIDTHS", (2, 32))
+    lines = []
+    assert tool.run({"one": (8, 5, 1 << 12, 1), "forest": (8, 6, 1 << 12, 7)},
+                    43, True, lines.append)
+    checks = [ln for ln in lines if "check" in ln]
+    assert len(checks) == 3 * (2 + 4) and all(ln["ok"] for ln in checks)
+    assert {ln["shape"] for ln in checks} == {
+        "one", "one-ragged", "forest", "forest-ragged"}
+    assert all(ln["rows_at_no_node"] > 0 for ln in checks
+               if ln["check"] == "lane_vs_two_level")
+    assert {"bodies_agree": True} in lines
+    timed = [ln for ln in lines if "timing" in ln]
+    assert [(ln["trees"], ln["channels"]) for ln in timed] == [
+        (1, 2), (1, 32), (1, 2), (1, 32),
+        (7, 14), (7, 28), (7, 56), (7, 112), (7, 224)]
+    monkeypatch.setattr("sys.argv", [path])
+    assert tool.main() == 2
 
 
 # ----------------------------------------------------------------------
