@@ -654,7 +654,10 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     several, call ``k`` over the slots from ``k * slots_per_call`` on (a
     row of another call's slots matches none of this one's); a wide
     level by the lane-wide body, every tree in one call where its lanes
-    hold them.  Either way a channel is the float32 sum of the same
+    hold them, a wide shard's features chunk by chunk inside that call
+    (968: the two widest levels of a depth-6 round one call each, 7
+    calls a round where the two-level body alone made 14).  Either way
+    a channel is the float32 sum of the same
     exact products of the same rounded weights; the bodies differ in
     the order of those adds, so a level reads the same under both to
     float32 rounding (1e-5 of a channel's absolute mass), not bit for

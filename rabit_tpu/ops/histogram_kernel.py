@@ -45,7 +45,13 @@ block)^T``, W the level's masked weights with the CHANNELS ON THE LANES
 a multiple of 128), one NT product a feature whatever the width and no
 diagonal to throw away: 3.44e-10 s a row and feature for up to 128
 lanes (0.324 s over the same 33.6M rows of 28 features at 2 channels
-as at 128), the MXU's own 4,096 cycles a feature and block.  The two
+as at 128), the MXU's own 4,096 cycles a feature and block.  Its f32
+accumulator is ``(features, classes, lanes)`` in VMEM; of a shard of
+more features than the budget holds (968 x 256 x 128 x 4 B is 127 MB)
+it is a chunk's, the chunks a second grid axis outside the row blocks
+(:func:`lane_chunk`): the bins are still read once a call, a feature's
+adds are the same adds in the same order, and a shard whose features
+fit one chunk has the one-axis call it always had.  The two bodies
 cross at 14 channels (``_LANE_CROSSING``, with the measured table).
 
 Like the kmeans kernel the weight operand is rounded to a compute
@@ -96,6 +102,14 @@ _UNROLL_GROUPS = 8
 _LANE_WIDTH = 256
 _LANE_FEATURES = 8
 _LANE_TREE_ROWS = 16
+# The lane-wide body's f32 accumulator, one grid step's: a third of the
+# VMEM limit, because the pipeline holds the output block twice and the
+# last third is the bins' blocks (twice too) and the body's one-hots.
+# Measured on a v5e at 968 features x 1,183,747 rows, 128 lanes
+# (builder's chip run, PR 44): chunks of 88, 168, 248 and 328 features
+# (11 to 43 MB) read 0.3922 to 0.3933 s a call alike and equal bit for
+# bit; 400 (twice 50 MB) do not fit.
+_LANE_ACC_BYTES = _VMEM_LIMIT_BYTES // 3
 # The level width, in channels, from which the lane-wide body is the
 # cheaper one.  Measured on a v5e (tools/hist_kernel_check.py, builder's
 # chip run, PR 43; seconds a level, host clock around the call), 256
@@ -115,7 +129,13 @@ _LANE_TREE_ROWS = 16
 # tree's level is a power of two and a forest's a multiple of its trees,
 # so 14 sends 16 channels of one tree and 14 of seven to the lane-wide
 # body and leaves 8 and 12 to the two-level one, which is right at both
-# shapes.
+# shapes.  968 features, 1,183,747 rows, 81% of the entries absent
+# (builder's chip run, PR 44): the two-level body 0.0721, 0.1220, 0.2363,
+# 0.4506, 0.8949, 1.7681, 3.5297 in 1, 1, 2, 3, 6, 11, 22 calls of at
+# most 6 channels; the lane-wide body 0.3928, 0.3925, 0.3925, 0.3921,
+# 0.3926, 0.3936, 0.3953, its features in chunks (3.43e-10 s a row and
+# feature again).  Both costs are linear in the features and the lines
+# cross at 13.8 channels: the same 14 serves.
 _LANE_CROSSING = 14
 # the weight operand's type where a caller names none (one-hots are
 # exact in it; every sum is accumulated in float32)
@@ -140,10 +160,10 @@ def max_channels(nbin: int, f: int) -> int:
     up to which a call's time is linear in its channels
     (``_LINE_CHANNELS``: a 32-channel call costs 0.2 s more than two of
     16).  Where a level is that body's (:func:`level_plan`: under the
-    lane-wide body's crossing, or too many features for its
-    accumulator), ``learn.histogram.level_hist`` builds a wider one in
-    calls of this width, so wide-feature deep levels chunk harder
-    rather than failing the accumulator bound."""
+    lane-wide body's crossing), ``learn.histogram.level_hist`` builds a
+    wider one in calls of this width, so a wide shard's levels (6
+    channels a call at 968 features) chunk harder rather than failing
+    the accumulator bound."""
     hi, lo, fpg, ngroups = plan(nbin, f)
     per_channel = ngroups * fpg * hi * fpg * lo * 4
     return max(1, min(_LINE_CHANNELS,
@@ -152,12 +172,26 @@ def max_channels(nbin: int, f: int) -> int:
 
 def lane_width(nbin: int, f: int) -> int:
     """Lanes of the widest lane-wide call at this shape, a multiple of
-    128: what the ``(f, classes, lanes)`` f32 VMEM accumulator's budget
-    holds, up to ``_LANE_WIDTH``; 0 where 128 do not fit (968 features),
-    and the shape then has the two-level body alone."""
-    per_lane = f * _round_up(nbin, 16) * 4
-    return min(_LANE_WIDTH,
-               (_VMEM_LIMIT_BYTES // 2) // per_lane // 128 * 128)
+    128 up to ``_LANE_WIDTH``: what the f32 VMEM accumulator's budget
+    holds of the features one grid step has.  A wide shard's features go
+    chunk by chunk (:func:`lane_chunk`), so the smallest chunk decides
+    and 968 features read what 28 do; 0 only where ``nbin`` is so large
+    that ``_LANE_FEATURES`` features of 128 lanes do not fit."""
+    per_lane = min(f, _LANE_FEATURES) * _round_up(nbin, 16) * 4
+    return min(_LANE_WIDTH, _LANE_ACC_BYTES // per_lane // 128 * 128)
+
+
+def lane_chunk(nbin: int, f: int, lanes: int) -> int:
+    """Features one grid step of a lane-wide call of ``lanes`` lanes
+    holds, in whole groups of ``_LANE_FEATURES``: ``f`` shared out
+    evenly over the fewest chunks whose ``(chunk, classes, lanes)`` f32
+    accumulator fits ``_LANE_ACC_BYTES``.  All of them where they fit
+    (28 to 56 features at 256 lanes: one chunk, no second grid axis);
+    968 features at 128 lanes go in 4 chunks of 248."""
+    groups = -(-f // _LANE_FEATURES)
+    per_group = _LANE_FEATURES * _round_up(nbin, 16) * lanes * 4
+    chunks = -(-groups // max(1, _LANE_ACC_BYTES // per_group))
+    return -(-groups // chunks) * _LANE_FEATURES
 
 
 def lane_rows(nslots: int) -> int:
@@ -179,11 +213,13 @@ def level_plan(nbin: int, f: int, nslots: int, trees: int = 1) -> LevelPlan:
     ``nslots`` slots of (grad, hess) is ``2 * trees * nslots`` channels.
     The two-level body costs a call's channels (calls of
     :func:`max_channels`, a tree a call); the lane-wide body costs the
-    same for any width up to 128 lanes.  Under ``_LANE_CROSSING``
-    channels, and wherever :func:`lane_width` is 0, the level is the
-    two-level body's; from it on the lane-wide body's, as many trees a
-    call as its lanes hold at ``_LANE_TREE_ROWS`` lanes a tree or more
-    (or, of a tree wider than a call, ``lane_width // 2`` slots)."""
+    same for any width up to 128 lanes.  Both are linear in the
+    features, so the crossing is one number of channels at 28 features
+    and at 968.  Under ``_LANE_CROSSING`` channels (and wherever
+    :func:`lane_width` is 0) the level is the two-level body's; from it
+    on the lane-wide body's, as many trees a call as its lanes hold at
+    ``_LANE_TREE_ROWS`` lanes a tree or more (or, of a tree wider than
+    a call, ``lane_width // 2`` slots)."""
     width = lane_width(nbin, f)
     if width and 2 * trees * nslots >= _LANE_CROSSING:
         slots = min(nslots, width // 2)
@@ -277,12 +313,16 @@ def _hist_kernel(bins_t_ref, w_ref, *rest,
 
 
 def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
-                 classes: int, trees: int, tree_rows: int):
+                 classes: int, trees: int, tree_rows: int, groups: int = 0):
     """One row block, channels on the MXU's lanes: each feature's plain
     one-hot ``(classes, block)`` against the level's masked weights
     ``(lanes, block)``, one NT product a feature whatever the level's
     width, added into the VMEM-resident ``(features, classes, lanes)``
-    output.
+    output.  A wide shard's call names its feature ``groups``: the
+    features are then a chunk's (grid axis 0, the row blocks inside it
+    on axis 1), the output block that chunk's, zeroed at its first row
+    block and written back when the chunk changes, and the last chunk's
+    loop ends with the shard's groups.
 
     Tree ``t`` holds lanes ``t * tree_rows`` on, lane ``2 * s + c`` of
     them being weight row ``c`` of the tree's rows at its node ``s``:
@@ -290,7 +330,7 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
     node ids, once a block.  The three rules of :func:`_hist_kernel`
     hold: an absent entry's code matches no class (or one the caller
     slices off), a row at node -1 no slot, a padded row weighs 0."""
-    i = pl.program_id(0)
+    i = pl.program_id(1 if groups else 0)
     block = w_ref.shape[2]
     features, _, lanes = out_ref.shape
     cdt = w_ref.dtype
@@ -333,7 +373,13 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
     # one body of _LANE_FEATURES products, looped: a copy a feature is
     # 28 to 54 of them, and PR 33 paid minutes for an unrolled wide body
     whole, rest = divmod(features, _LANE_FEATURES)
-    if whole:
+    if groups:
+        # the last chunk's loop ends with the shard's groups: what its
+        # blocks hold past them is never read (run whole, that loop cost
+        # 1.6% of a call at 968 features in chunks of 328; chip, PR 44)
+        lax.fori_loop(0, jnp.minimum(whole, groups - pl.program_id(0) * whole),
+                      group, None)
+    elif whole:
         lax.fori_loop(0, whole, group, None)
     if rest:
         some(whole * _LANE_FEATURES, rest)
@@ -365,31 +411,46 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
             jnp.pad(weights.astype(cdt), ((0, 0), (0, 0), (0, npad - n))),
             jnp.pad(node.astype(jnp.int32), ((0, 0), (0, npad - n)),
                     constant_values=-1)]
+        # a shard of more features than the accumulator's budget holds
+        # goes chunk by chunk on a grid axis outside the row blocks; the
+        # last chunk's blocks may reach past the staged rows and the
+        # features: never read, left zero and sliced off below
+        chunk = lane_chunk(nbin, features, lanes)
+        chunks = -(-features // chunk)
+        if chunks == 1:
+            grid, held, kept, groups = (npad // block,), fpad, features, 0
+        else:
+            grid, held, kept = (chunks, npad // block), chunk, chunk
+            groups = -(-features // _LANE_FEATURES)
+
+        def part(*at):      # of grid indices (chunk, row block) or (row block,)
+            return at[0] if len(at) > 1 else 0
+
         raw = pl.pallas_call(
             functools.partial(_lane_kernel, classes=classes, trees=trees,
-                              tree_rows=tree_rows),
-            grid=(npad // block,),
+                              tree_rows=tree_rows, groups=groups),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((fpad, block), lambda i: (0, i),
+                pl.BlockSpec((held, block), lambda *at: (part(*at), at[-1]),
                              memory_space=pltpu.VMEM),
                 # (trees, 2, n) as it is: folding the trees into the
                 # rows is a copy, and one XLA takes minutes to compile
-                pl.BlockSpec((trees, 2, block), lambda i: (0, 0, i),
+                pl.BlockSpec((trees, 2, block), lambda *at: (0, 0, at[-1]),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((trees, block), lambda i: (0, i),
+                pl.BlockSpec((trees, block), lambda *at: (0, at[-1]),
                              memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((features, classes, lanes),
-                                   lambda i: (0, 0, 0),
+            out_specs=pl.BlockSpec((kept, classes, lanes),
+                                   lambda *at: (part(*at), 0, 0),
                                    memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((features, classes, lanes),
+            out_shape=jax.ShapeDtypeStruct((chunks * kept, classes, lanes),
                                            jnp.float32),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
+                dimension_semantics=("arbitrary",) * len(grid),
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(*operands)
         # lane t * tree_rows + 2 * s + c -> channel (t, s, c); tiny, XLA
-        out = raw[:, :nbin, :trees * tree_rows].reshape(
+        out = raw[:features, :nbin, :trees * tree_rows].reshape(
             features, nbin, trees, tree_rows)[..., :2 * nslots]
         return out.transpose(2, 3, 0, 1).reshape(
             trees * 2 * nslots, features, nbin)

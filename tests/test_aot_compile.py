@@ -138,6 +138,32 @@ def _hist_level_wide(topo):
     return compiled
 
 
+def _hist_level_wide_lanes(nslots, topo):
+    from rabit_tpu.learn import histogram
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    # the wide boosting cell's two widest levels (8 and 16 built slots)
+    # as level_hist builds them since the lane-wide body takes a wide
+    # shard's features chunk by chunk: one call of 128 lanes, its
+    # (248, 256, 128) f32 accumulator a quarter of the features, on a
+    # grid of 4 chunks x 579 row blocks; looped bodies only (PR 33 paid
+    # 433 s for 121 unrolled groups).  The bins are still not copied:
+    # beside them the kernel's raw (992, 256, 128) output and its slice
+    n, f = 1183747, 968
+    assert hk.lane_chunk(256, f, 128) == 248
+    assert histogram.level_calls(nslots, f, 256, True) == (1, 1)
+    fn = jax.jit(lambda bins, gh, node: histogram.level_hist(
+        bins, gh, node, nslots, f, 256, use_pallas=True, totals=True))
+    compiled = fn.lower(*_one_chip(topo, ((f, n), jnp.int32),
+                                   ((2, n), jnp.float32),
+                                   ((n,), jnp.int32))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes <= 992 * 256 * 128 * 4 * 2, m
+    assert m.output_size_in_bytes == nslots * (f + 1) * 256 * 2 * 4
+    return compiled
+
+
 def _kmeans_ell_chain(topo):
     from rabit_tpu.learn import kmeans
 
@@ -435,6 +461,8 @@ def test_distributed_update_program_compiles_for_v5e(topo):
 @pytest.mark.parametrize("build", [
     _kmeans_dense, _hist_level, functools.partial(_hist_level_staged, 32),
     functools.partial(_hist_level_chunked, 16), _hist_level_wide,
+    functools.partial(_hist_level_wide_lanes, 8),
+    functools.partial(_hist_level_wide_lanes, 16),
     _kmeans_ell_chain, _dense16_loop,
     functools.partial(_lbfgs_product, "margin"),
     functools.partial(_lbfgs_product, "grad"),
@@ -447,6 +475,8 @@ def test_distributed_update_program_compiles_for_v5e(topo):
         "hist_fused_multi-32slots-28x256x33.6M",
         "hist_fused_multi-16slots-28x256x33.6M",
         "hist_fused_multi-3slots-968x256x1.18M-ragged",
+        "hist_fused_multi-8slots-lanes-968x256x1.18M-ragged",
+        "hist_fused_multi-16slots-lanes-968x256x1.18M-ragged",
         "kmeans_ell_chain-d512-4M", "dense16_loop-24M",
         "lbfgs_margin-16.8Mx39-1M", "lbfgs_grad-16.8Mx39-1M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])

@@ -266,16 +266,23 @@ def _level_case(nslots, n=2500, f=3, nbin=8, seed=21):
     return bins_t, jnp.asarray(gh), jnp.asarray(node), empty
 
 
-def _pallas_calls(jaxpr) -> int:
-    """``pallas_call`` equations in a jaxpr, nested programs included."""
-    total = 0
+def _grids(jaxpr) -> list:
+    """The grid of every ``pallas_call`` in a jaxpr, nested programs
+    included."""
+    grids = []
     for eqn in jaxpr.eqns:
-        total += eqn.primitive.name == "pallas_call"
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
         for v in eqn.params.values():
             inner = getattr(v, "jaxpr", v)
             if hasattr(inner, "eqns"):
-                total += _pallas_calls(inner)
-    return total
+                grids += _grids(inner)
+    return grids
+
+
+def _pallas_calls(jaxpr) -> int:
+    """``pallas_call`` equations in a jaxpr, nested programs included."""
+    return len(_grids(jaxpr))
 
 
 @pytest.fixture
@@ -462,29 +469,16 @@ def _float64_level(bins, gh, node, nslots, nbin, dtype):
     return out[:, :, :, :nbin].reshape(trees * nslots, f, nbin, 2)
 
 
-@pytest.mark.parametrize("trees,nslots,n,dtype,totals", [
-    (7, 1, 2500, "bfloat16", False),        # 14 channels
-    (1, 16, 2500, "bfloat16", False),       # 32
-    (1, 16, 2048, "float32", True),         # 32, exact operands, totals
-    (4, 16, 4097, "bfloat16", True),        # 128: every lane, ragged
-    (1, 64, 2500, "bfloat16", False),       # 128, one tree
-    (7, 16, 1300, "bfloat16", False),       # 224: a call of 256 lanes
-    (7, 16, 700, "float32", False),
-    (3, 2, 900, "float32", True),           # trees narrower than a tile
-    (9, 16, 600, "bfloat16", False),        # 288: two calls, 8 + 1 trees
-    (1, 256, 2100, "bfloat16", False),      # 512: a tree over two calls
-], ids=lambda v: str(v))
-def test_lane_wide_level_is_the_float64_level_and_the_two_level_bodys(
-        monkeypatch, trees, nslots, n, dtype, totals):
+def _lane_level_against_float64_and_two_level(monkeypatch, f, trees, nslots,
+                                              n, dtype, totals):
     """The lane-wide body in interpret mode against numpy in float64
-    and against the two-level body on the same level: absent entries,
-    rows at node -1, a ragged ``n``, both compute dtypes, with and
-    without the slots' totals."""
+    and against the two-level body on the same level of ``f`` features
+    of 16 bins; returns the lane-wide level."""
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
 
-    f, nbin = 5, 16
+    nbin = 16
     bins, bins_t, gh, node = _forest_case(trees, nslots, n, f, nbin)
 
     def level(crossing):
@@ -514,6 +508,87 @@ def test_lane_wide_level_is_the_float64_level_and_the_two_level_bodys(
                               nbin, dtype)[:, 0, 0]
         np.testing.assert_allclose(got[:, f, 0], sums, rtol=1e-5, atol=1e-4)
         assert not got[:, f, 1:].any()
+    return got
+
+
+@pytest.mark.parametrize("trees,nslots,n,dtype,totals", [
+    (7, 1, 2500, "bfloat16", False),        # 14 channels
+    (1, 16, 2500, "bfloat16", False),       # 32
+    (1, 16, 2048, "float32", True),         # 32, exact operands, totals
+    (4, 16, 4097, "bfloat16", True),        # 128: every lane, ragged
+    (1, 64, 2500, "bfloat16", False),       # 128, one tree
+    (7, 16, 1300, "bfloat16", False),       # 224: a call of 256 lanes
+    (7, 16, 700, "float32", False),
+    (3, 2, 900, "float32", True),           # trees narrower than a tile
+    (9, 16, 600, "bfloat16", False),        # 288: two calls, 8 + 1 trees
+    (1, 256, 2100, "bfloat16", False),      # 512: a tree over two calls
+], ids=lambda v: str(v))
+def test_lane_wide_level_is_the_float64_level_and_the_two_level_bodys(
+        monkeypatch, trees, nslots, n, dtype, totals):
+    """The lane-wide body in interpret mode against numpy in float64
+    and against the two-level body on the same level: absent entries,
+    rows at node -1, a ragged ``n``, both compute dtypes, with and
+    without the slots' totals."""
+    _lane_level_against_float64_and_two_level(
+        monkeypatch, 5, trees, nslots, n, dtype, totals)
+
+
+@pytest.fixture
+def small_chunks(request, monkeypatch):
+    """A lane-wide grid step holds ``request.param`` features, as if the
+    accumulator's budget held no more (the real budget holds hundreds:
+    minutes of interpreted kernel)."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    import jax
+
+    monkeypatch.setattr(hk, "lane_chunk",
+                        lambda nbin, f, lanes: request.param)
+    jax.clear_caches()      # the kernel's builder is traced once a shape
+    yield request.param
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("f,small_chunks,trees,nslots,n,dtype,totals", [
+    (19, 8, 1, 8, 2500, "bfloat16", False),    # 3 chunks, the last of 3
+    (19, 16, 1, 16, 4097, "bfloat16", True),   # 2: the second reads past
+    (21, 8, 1, 16, 2048, "float32", True),     # the 24 staged rows
+    (40, 16, 1, 8, 700, "bfloat16", False),    # 3 chunks of 16: 48 > 40
+    (24, 8, 3, 4, 900, "bfloat16", True),      # a forest, whole chunks
+    (33, 16, 7, 16, 600, "bfloat16", False),   # 256 lanes, 3 chunks
+    (9, 8, 1, 64, 4097, "bfloat16", True),     # 128 channels, 2 chunks
+], indirect=["small_chunks"], ids=lambda v: str(v))
+def test_lane_wide_level_chunked_over_its_features(
+        monkeypatch, f, small_chunks, trees, nslots, n, dtype, totals):
+    """A shard of more features than one grid step's accumulator holds:
+    the lane-wide call takes them chunk by chunk on a grid axis outside
+    the row blocks (a feature count that is no multiple of the chunk, a
+    last chunk that reads past the staged rows, a ragged ``n``, rows at
+    node -1, absent entries, totals) and reads what one chunk of all the
+    features reads, bit for bit: a feature's adds are the same adds in
+    the same order."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    got = _lane_level_against_float64_and_two_level(
+        monkeypatch, f, trees, nslots, n, dtype, totals)
+    _, bins_t, gh, node = _forest_case(trees, nslots, n, f, 16)
+    args = (bins_t, jnp.asarray(gh), jnp.asarray(node))
+
+    def level(b, w, nd):
+        return histogram.level_hist(b, w, nd, nslots, f, 16, use_pallas=True,
+                                    compute_dtype=dtype, totals=totals)
+
+    blocks = -(-n // 2048)
+    assert _grids(jax.make_jaxpr(level)(*args).jaxpr) == [
+        (-(-f // small_chunks), blocks)]
+    monkeypatch.undo()
+    jax.clear_caches()
+    monkeypatch.setattr(hk, "_LANE_CROSSING", 2)
+    assert _grids(jax.make_jaxpr(level)(*args).jaxpr) == [(blocks,)]
+    np.testing.assert_array_equal(got, np.asarray(level(*args)))
 
 
 def test_a_forests_level_is_its_trees_levels_one_by_one():
@@ -544,11 +619,14 @@ def test_a_forests_level_is_its_trees_levels_one_by_one():
 @pytest.mark.parametrize("f,trees,want", [
     # HIGGS and approx: 2 to 32 channels
     (28, 1, [(1, 0), (1, 0), (1, 0), (1, 0), (1, 1), (1, 1)]),
+    (32, 1, [(1, 0), (1, 0), (1, 0), (1, 0), (1, 1), (1, 1)]),
     # Covertype: 14 to 224 channels of seven trees
     (54, 7, [(1, 1), (1, 1), (1, 1), (1, 1), (1, 1), (1, 1)]),
-    # Bosch: 128 lanes of 968 features do not fit; 3 slots a call
-    (968, 1, [(1, 0), (1, 0), (1, 0), (2, 0), (3, 0), (6, 0)]),
-], ids=["higgs-approx", "covtype", "bosch"])
+    (56, 7, [(1, 1), (1, 1), (1, 1), (1, 1), (1, 1), (1, 1)]),
+    # Bosch: 3 slots a call of the two-level body, and the two widest
+    # levels one lane-wide call each, 7 calls a round where 14 were
+    (968, 1, [(1, 0), (1, 0), (1, 0), (2, 0), (1, 1), (1, 1)]),
+], ids=["higgs-approx", "higgs-staged", "covtype", "covtype-staged", "bosch"])
 def test_the_rule_at_the_four_configurations_shapes(f, trees, want):
     """Which body builds each level of a depth-6 round, in how many
     calls: ``level_calls`` as ``(calls, lane-wide among them)``."""
@@ -566,7 +644,30 @@ def test_the_rule_at_the_four_configurations_shapes(f, trees, want):
         assert plan.lane == bool(lane)
         assert histogram.slots_per_call(256, f, s, trees) == plan.slots
         assert calls == -(-trees // plan.trees) * -(-s // plan.slots)
-    assert hk.lane_width(256, f) == (0 if f == 968 else 256)
+    assert hk.lane_width(256, f) == 256
+    # the narrow shards' features are one grid step's, at either width of
+    # call; the wide one's go in four
+    chunks = [-(-f // hk.lane_chunk(256, f, lanes)) for lanes in (128, 256)]
+    assert chunks == ([4, 8] if f == 968 else [1, 1])
+
+
+@pytest.mark.parametrize("f,lanes,want", [
+    (28, 128, 32), (28, 256, 32), (56, 256, 56), (128, 256, 128),
+    (129, 256, 72), (264, 128, 264), (265, 128, 136), (968, 128, 248),
+    (968, 256, 128), (2000, 128, 256), (4000, 256, 128)],
+    ids=lambda v: str(v))
+def test_lane_chunk_is_what_the_accumulators_budget_holds(f, lanes, want):
+    """A grid step's features at 256 bins: whole groups of 8, at most
+    what a third of the VMEM limit holds of f32 accumulator (264 at 128
+    lanes, 128 at 256), shared out evenly over the fewest chunks."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    budget = hk._VMEM_LIMIT_BYTES // 3
+    chunk = hk.lane_chunk(256, f, lanes)
+    assert chunk == want and chunk % 8 == 0
+    assert chunk * 256 * lanes * 4 <= budget
+    most = budget // (8 * 256 * lanes * 4) * 8
+    assert -(-f // chunk) == -(-f // most)
 
 
 @pytest.mark.parametrize("nslots,trees,want", [
@@ -585,6 +686,22 @@ def test_level_plan_by_width_alone(nslots, trees, want):
     assert tuple(hk.level_plan(256, 28, nslots, trees)) == want
 
 
+@pytest.mark.parametrize("nslots,trees,want", [
+    (4, 1, (False, 1, 3)), (6, 1, (False, 1, 3)), (7, 1, (True, 1, 7)),
+    (8, 1, (True, 1, 8)), (16, 1, (True, 1, 16)), (1, 7, (True, 7, 1)),
+    (16, 7, (True, 7, 16)), (256, 1, (True, 1, 128))],
+    ids=lambda v: str(v))
+def test_level_plan_of_a_wide_shard_crosses_where_a_narrow_ones_does(
+        nslots, trees, want):
+    """At 968 features the same crossing in channels: both bodies' costs
+    are linear in the features.  Under it the two-level body's calls of
+    what its accumulator holds (3 slots), from it on one lane-wide call,
+    the features chunk by chunk inside it."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    assert tuple(hk.level_plan(256, 968, nslots, trees)) == want
+
+
 def test_the_chip_check_of_the_two_bodies_rehearsed(monkeypatch):
     """``tools/hist_kernel_check.py`` at tiny shapes with the kernels
     interpreted: every comparison it would make on the chip is made and
@@ -601,19 +718,31 @@ def test_the_chip_check_of_the_two_bodies_rehearsed(monkeypatch):
     monkeypatch.setattr(tool, "SLICE_ROWS", 2048)
     monkeypatch.setattr(tool, "WIDTHS", (2, 32))
     lines = []
-    assert tool.run({"one": (8, 5, 1 << 12, 1), "forest": (8, 6, 1 << 12, 7)},
+    monkeypatch.setattr(tool.hk, "lane_chunk", lambda nbin, f, lanes: 8)
+    tool.jax.clear_caches()
+    assert tool.run({"one": (8, 5, 1 << 12, 1, 0),
+                     "forest": (8, 6, 1 << 12, 7, 0),
+                     "wide": (16, 16, 3000, 1, 4)},
                     43, True, lines.append)
+    tool.jax.clear_caches()
     checks = [ln for ln in lines if "check" in ln]
-    assert len(checks) == 3 * (2 + 4) and all(ln["ok"] for ln in checks)
+    assert len(checks) == 3 * (2 + 4 + 3) and all(ln["ok"] for ln in checks)
     assert {ln["shape"] for ln in checks} == {
-        "one", "one-ragged", "forest", "forest-ragged"}
+        "one", "one-ragged", "forest", "forest-ragged", "wide",
+        "wide-ragged"}
+    wide = [ln for ln in checks if ln["shape"].startswith("wide")
+            and ln["check"] == "lane_vs_two_level"]
+    assert [(ln["slots"], ln["feature_chunks"]) for ln in wide] == [
+        (8, 2), (16, 2), (8, 2)]
+    # absent station by station, 81% of the entries
+    assert all(0.7 < ln["entries_absent"] < 0.9 for ln in wide)
     assert all(ln["rows_at_no_node"] > 0 for ln in checks
                if ln["check"] == "lane_vs_two_level")
     assert {"bodies_agree": True} in lines
     timed = [ln for ln in lines if "timing" in ln]
     assert [(ln["trees"], ln["channels"]) for ln in timed] == [
         (1, 2), (1, 32), (1, 2), (1, 32),
-        (7, 14), (7, 28), (7, 56), (7, 112), (7, 224)]
+        (7, 14), (7, 28), (7, 56), (7, 112), (7, 224), (1, 2), (1, 32)]
     monkeypatch.setattr("sys.argv", [path])
     assert tool.main() == 2
 

@@ -20,7 +20,12 @@ both bodies from the same inputs and prints
 * both against a float64 numpy histogram of the first 2^20 rows;
 * a ragged ``n`` (no multiple of the row block) with rows at node -1;
 * then, unless ``--no-timing``, seconds a call of each body at 2 to 128
-  channels of one tree at both row shapes, and the forest's levels.
+  channels of one tree at each row shape, and the forest's levels.
+
+``bosch`` is the wide boosting cell's shape (968 features, 1,183,747
+rows, ragged as they are, 81% of the entries absent station by station,
+8 and 16 slots): the lane-wide body there takes the features chunk by
+chunk on a second grid axis (``lane_chunk``).
 
 Not on any cell's path.  Run it through the chip tool:
 
@@ -51,26 +56,57 @@ from rabit_tpu.ops import histogram_kernel as hk  # noqa: E402
 NBIN = 256
 LIMIT = 1e-5
 SLICE_ROWS = 1 << 20
-# (name, staged feature rows, features, rows, trees): the cells' shapes
-SHAPES = {"higgs": (32, 28, 1 << 25, 1), "covtype": (56, 54, 1 << 23, 7)}
+# (name, staged feature rows, features, rows, trees, stations): the
+# cells' shapes; with stations, a row's entries are absent (code 256)
+# station by station, ``PRESENT`` of them present
+SHAPES = {"higgs": (32, 28, 1 << 25, 1, 0), "covtype": (56, 54, 1 << 23, 7, 0),
+          "bosch": (968, 968, 1183747, 1, 52)}
 WIDTHS = (2, 4, 8, 16, 32, 64, 128)
+PRESENT = 0.19
 
 
 CDT = jnp.dtype(hk.DEFAULT_COMPUTE_DTYPE).name
 
 
-@functools.partial(jax.jit, static_argnames=("fpad", "n"))
-def make_bins(seed, fpad: int, n: int):
-    """Made on the device in one fused pass (a hash of the place: no
-    generator's temporaries beside 4.3 GB of bins): uniform over 0..256,
-    256 being the absent code."""
-    x = (lax.broadcasted_iota(jnp.uint32, (fpad, n), 1)
-         * jnp.uint32(2654435761)
-         + lax.broadcasted_iota(jnp.uint32, (fpad, n), 0)
-         * jnp.uint32(40503) + seed.astype(jnp.uint32))
+def _mix(x):
     for shift, mult in ((15, 2246822519), (13, 3266489917), (16, 1)):
         x = (x ^ (x >> shift)) * jnp.uint32(mult)
-    return (x % jnp.uint32(NBIN + 1)).astype(jnp.int32)
+    return x
+
+
+def visiting_shares(stations: int) -> np.ndarray:
+    """A ladder from the rarest station (0.4% of the rows visit it) to
+    the commonest (99.5%), bent until ``PRESENT`` of all visits are
+    made: the wide cell's own recipe, stations of one width here."""
+    rank = np.arange(stations) / (stations - 1.0)
+    a, b = 0.02, 50.0
+    for _ in range(80):
+        bend = (a * b) ** 0.5
+        share = 0.004 * (0.995 / 0.004) ** (rank ** bend)
+        a, b = (a, bend) if share.mean() < PRESENT else (bend, b)
+    return share
+
+
+@functools.partial(jax.jit, static_argnames=("fpad", "n", "stations"))
+def make_bins(seed, fpad: int, n: int, stations: int = 0):
+    """Made on the device in one fused pass (a hash of the place: no
+    generator's temporaries beside 4.3 GB of bins): uniform over 0..256,
+    256 being the absent code; with ``stations``, uniform over 0..255
+    where the row visits the feature's station and 256 where not."""
+    row = lax.broadcasted_iota(jnp.uint32, (fpad, n), 1)
+    feat = lax.broadcasted_iota(jnp.uint32, (fpad, n), 0)
+    seed = seed.astype(jnp.uint32)
+    x = _mix(row * jnp.uint32(2654435761) + feat * jnp.uint32(40503) + seed)
+    if not stations:
+        return (x % jnp.uint32(NBIN + 1)).astype(jnp.int32)
+    station = np.arange(fpad) * stations // fpad
+    visit = _mix(row * jnp.uint32(2246822519) + seed
+                 + jnp.asarray(station, jnp.uint32)[:, None]
+                 * jnp.uint32(374761393))
+    bar = jnp.asarray(visiting_shares(stations)[station] * 2.0 ** 32,
+                      jnp.float32).astype(jnp.uint32)[:, None]
+    return jnp.where(visit < bar, x % jnp.uint32(NBIN),
+                     jnp.uint32(NBIN)).astype(jnp.int32)
 
 
 def make_rows(key, n: int, trees: int, nslots: int):
@@ -164,8 +200,9 @@ def first_block(bins_t, gh, node, nslots, f, at, mass):
     return lo
 
 
-def compare(name: str, key, fpad, f, n, trees, nslots, emit) -> bool:
-    bins_t = make_bins(key[-1], fpad, n)
+def compare(name: str, key, fpad, f, n, trees, nslots, emit,
+            stations: int = 0) -> bool:
+    bins_t = make_bins(key[-1], fpad, n, stations)
     gh, node = make_rows(key, n, trees, nslots)
     mass = np.asarray(masses(gh, node, nslots))
     a = np.asarray(lane(bins_t, gh, node, nslots, f))
@@ -176,6 +213,9 @@ def compare(name: str, key, fpad, f, n, trees, nslots, emit) -> bool:
             "channels": 2 * trees * nslots, "max_rel_to_mass": rel,
             "limit": LIMIT, "ok": rel <= LIMIT,
             "rows_at_no_node": int(np.asarray(jnp.sum(node < 0))),
+            "entries_absent": float(np.asarray(jnp.mean(bins_t[:f] == NBIN))),
+            "feature_chunks": -(-f // hk.lane_chunk(
+                NBIN, f, hk._round_up(trees * hk.lane_rows(nslots), 128))),
             "equal_bitwise": bool(np.array_equal(a, b))}
     if rel > LIMIT:
         ch, feat, cls = at
@@ -214,13 +254,14 @@ def seconds(fn, *args) -> float:
     return min(took)
 
 
-def timing(name: str, key, fpad, f, n, trees, emit) -> None:
+def timing(name: str, key, fpad, f, n, trees, emit,
+           stations: int = 0) -> None:
     """One tree at every width, then (``trees`` > 1) the forest's own
     levels; the two-level body as the rule's calls of its widest."""
     levels = [(1, w // 2) for w in WIDTHS]
     if trees > 1:
         levels += [(trees, s) for s in (1, 2, 4, 8, 16)]
-    bins_t = make_bins(key[-1], fpad, n)
+    bins_t = make_bins(key[-1], fpad, n, stations)
     for nt, nslots in levels:
         gh, node = make_rows(key, n, nt, nslots)
         emit({"timing": name, "rows": n, "features": f, "trees": nt,
@@ -234,22 +275,26 @@ def timing(name: str, key, fpad, f, n, trees, emit) -> None:
 def run(shapes: dict, seed: int, timed: bool, emit) -> bool:
     key = jax.random.PRNGKey(seed)
     ok = True
-    for name, (fpad, f, n, trees) in shapes.items():
-        for nslots in ((16,) if trees == 1 else (1, 8, 16)):
-            ok &= compare(name, key, fpad, f, n, trees, nslots, emit)
+    for name, (fpad, f, n, trees, stations) in shapes.items():
+        # a wide shard's two lane-wide levels; one tree's widest; a
+        # forest's narrowest, and two more
+        for nslots in ((8, 16) if stations else (16,) if trees == 1
+                       else (1, 8, 16)):
+            ok &= compare(name, key, fpad, f, n, trees, nslots, emit,
+                          stations)
         # ragged: the last block reads past the rows
         ok &= compare(name + "-ragged", key, fpad, f, n // 8 - 77, trees, 8,
-                      emit)
+                      emit, stations)
     emit({"bodies_agree": bool(ok)})
     if ok and timed:
-        for name, (fpad, f, n, trees) in shapes.items():
-            timing(name, key, fpad, f, n, trees, emit)
+        for name, (fpad, f, n, trees, stations) in shapes.items():
+            timing(name, key, fpad, f, n, trees, emit, stations)
     return ok
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shapes", default="higgs,covtype")
+    ap.add_argument("--shapes", default="higgs,covtype,bosch")
     ap.add_argument("--seed", type=int, default=43)
     ap.add_argument("--no-timing", action="store_true")
     args = ap.parse_args()
