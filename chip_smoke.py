@@ -358,7 +358,8 @@ def phase_gbdt(seed: int, rows: int) -> dict:
     labels = (values[:, 3] + 0.5 * values[:, 10]
               + 0.1 * rng.standard_normal(rows, dtype=np.float32)
               > 0).astype(np.float32)
-    bins, _cuts = histogram.quantize(values, GBDT_BINS)
+    bins = histogram.apply_cuts(
+        values, histogram.quantile_cuts(values, GBDT_BINS))
     grad = rng.standard_normal(rows, dtype=np.float32)
     hess = rng.random(rows, dtype=np.float32)
     node_of_row = rng.integers(0, GBDT_NODES, rows).astype(np.int32)

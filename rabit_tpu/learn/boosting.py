@@ -993,7 +993,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     histogram has ``nbin`` slots with and without them), every split
     learns a default direction from the absent rows' gradient mass, the
     node's totals less the feature's bins
-    (``histogram.split_gain_missing``), and prediction routes NaN the
+    (``histogram.split_candidates``), and prediction routes NaN the
     same way — XGBoost's sparsity-aware splits.
 
     ``use_pallas``/``compute_dtype`` pin the histogram path: on TPU the

@@ -1,4 +1,4 @@
-"""XGBoost-style gradient-histogram building + allreduce.
+"""XGBoost-style gradient-histogram building.
 
 The reference's historical raison d'être is the histogram allreduce
 inside XGBoost: each worker bins its feature shard, accumulates per
@@ -17,16 +17,15 @@ fixed shapes, no scatter (TPU scatters serialize; the one-hot contraction
 keeps the FLOPs on the matrix unit).  A tree level builds its node
 slots' histograms in one pass (:func:`level_hist`), node membership folded
 into the weights inside the kernel; the booster gives it one child of
-every split node and subtracts for the sibling.  The cross-worker step is one
-framework allreduce of the flat histogram, exactly the XGBoost wire
-pattern.
+every split node and subtracts for the sibling.  The cross-worker step is
+the booster's (``boosting.train``): one framework allreduce of the flat
+level, exactly the XGBoost wire pattern.
 """
 from __future__ import annotations
 
 import numpy as np
 
-import rabit_tpu
-from rabit_tpu.ops import SUM, on_tpu
+from rabit_tpu.ops import on_tpu
 
 _CACHE: dict = {}
 
@@ -39,9 +38,6 @@ def _writable(arr) -> "np.ndarray":
     if not arr.flags.writeable:
         arr = arr.copy()
     return arr
-
-DEFAULT_ROW_BLOCK = 8192
-DEFAULT_FEAT_BLOCK = 8
 
 
 # columns sorted together by :func:`quantile_cuts`: a cache line of a
@@ -896,24 +892,11 @@ def level_shortlist(level, f: int, reg_lambda, min_child_weight,
     return feats, jnp.take_along_axis(level, take[None, :, :, None], axis=2)
 
 
-def split_gain_missing(hist: np.ndarray, total, reg_lambda: float = 1.0):
-    """Sparsity-aware split gain of a (f, nbin, 2) histogram and the
-    node's (grad, hess) ``total``.  For every (feature, cut) the gain is
-    evaluated with the feature's missing mass sent left and sent right;
-    returns ``(gain, default_left)`` where gain is the better of the
-    two and default_left says which direction won (XGBoost's learned
-    default direction, one bool per candidate split)."""
-    return split_candidates(hist, reg_lambda, total=total)
-
-
-def quantize(values: np.ndarray, nbin: int):
-    """Quantile-bin each feature column; returns (bins, cuts)."""
-    cuts = quantile_cuts(values, nbin)
-    return apply_cuts(values, cuts), cuts
-
-
-def _builder(n: int, f: int, nbin: int, row_block: int, feat_block: int):
-    """Jitted histogram builder.
+def _builder(n: int, f: int, nbin: int):
+    """Jitted float32 builder of one node's (f, nbin, 2) histogram from
+    (n, f) bins and the node's (grad, hess): what
+    :func:`build_level_local` builds a level from, node by node, off
+    the chip (the host arm's trees on CPU).
 
     Formulation chosen by measurement on TPU: one (n, nbin) one-hot per
     feature contracted with the packed (n, 2) grad/hess operand on the
@@ -921,10 +904,10 @@ def _builder(n: int, f: int, nbin: int, row_block: int, feat_block: int):
     blocked einsum defeats XLA's fusion; per-feature matmuls stream at
     HBM bandwidth.  Features are processed ``feat_block`` at a time
     inside a ``lax.scan`` — unrolled within a chunk for speed, scanned
-    across chunks to bound compile time.  ``row_block`` is accepted for
-    API stability but the contraction is over all rows at once.
+    across chunks to bound compile time.
     """
-    key = (n, f, nbin, row_block, feat_block)
+    key = ("node", n, f, nbin)
+    feat_block = 8
     fn = _CACHE.get(key)
     if fn is None:
         import jax
@@ -960,54 +943,24 @@ def _builder(n: int, f: int, nbin: int, row_block: int, feat_block: int):
     return fn
 
 
-def build_local(bins, grad, hess, nbin: int,
-                row_block: int = DEFAULT_ROW_BLOCK,
-                feat_block: int = DEFAULT_FEAT_BLOCK,
-                use_pallas: bool | None = None,
-                compute_dtype=None) -> np.ndarray:
-    """Local (f, nbin, 2) histogram of (grad, hess) sums on device.
-
-    Measured on TPU with chained difference timing
-    (doc/benchmarks.md): the fused Pallas
-    kernel (:mod:`rabit_tpu.ops.histogram_kernel`) runs a single
-    histogram in ~0.8 ms vs ~30 ms for the XLA one-hot contraction
-    (~37x), so it is the default on TPU; off-TPU the XLA path is used
-    (``use_pallas=True`` forces interpret mode for tests).  Per-node
-    level builds share one bins pass — see :func:`build_level_local`.
-    ``compute_dtype`` bounds the kernel's weight rounding (default
-    bf16; one-hots are exact).
-    """
-    import jax.numpy as jnp
-
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if use_pallas:
-        from rabit_tpu.ops.histogram_kernel import hist_fused
-        kw = {} if compute_dtype is None else {"compute_dtype": compute_dtype}
-        return hist_fused(bins, grad, hess, nbin, **kw)
-    n, f = bins.shape
-    fn = _builder(n, f, nbin, row_block, feat_block)
-    return fn(jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess))
-
-
 def build_level_local(bins, grad, hess, node_of_row, node_ids,
                       nbin: int, bins_t=None, use_pallas: bool | None = None,
                       compute_dtype=None, totals: bool = False):
-    """(m, f, nbin, 2) per-node histograms for one tree level.
+    """(m, f, nbin, 2) per-node histograms for one tree level, of the
+    nodes ``node_ids`` names in any order.
 
     Level-wise boosting needs one histogram per live node; building
     them one at a time re-reads the (n, f) bins array per node.  On
-    TPU this routes every node through ONE fused-kernel bins pass
-    (measured ~25x over per-node XLA passes at 8 nodes,
-    doc/benchmarks.md):
-    :func:`rabit_tpu.ops.histogram_kernel.hist_fused_multi`, which
-    folds the node masks into the grad/hess channels itself (no (2m, n)
-    weight matrix), in as many kernel calls as :func:`level_hist`
-    makes of a level that wide.
-    ``bins_t`` optionally supplies the resident transposed (f, n)
-    device array so the transpose isn't redone per level.  Off-TPU,
-    falls back to the XLA builder per node.  ``totals`` as
-    :func:`level_hist` has it: (m, f + 1, nbin, 2).
+    TPU this is :func:`level_hist` on the ids' places: every node
+    through one fused-kernel bins pass a call, the node masks folded
+    into the grad/hess channels inside the kernel (no (2m, n) weight
+    matrix), in as many calls as a level that wide takes (what a call
+    costs by its width: ``ops.histogram_kernel._LANE_CROSSING``'s
+    table).  ``bins_t`` optionally supplies the resident transposed
+    (f, n) device array so the transpose isn't redone per level.
+    Off the chip (``use_pallas`` false) the level is built node by
+    node in float32 by :func:`_builder`: the host arm's trees on CPU.
+    ``totals`` as :func:`level_hist` has it: (m, f + 1, nbin, 2).
     """
     import jax.numpy as jnp
 
@@ -1030,81 +983,9 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
                           use_pallas=True, compute_dtype=compute_dtype,
                           totals=totals)
     g_np, h_np, nor_np = np.asarray(g), np.asarray(h), np.asarray(nor)
-    parts = [build_local(bins, g_np * (nor_np == v), h_np * (nor_np == v),
-                         nbin, use_pallas=False)
-             for v in np.asarray(node_ids)]
-    out = jnp.stack([jnp.asarray(p) for p in parts])
+    build, b = _builder(*bins.shape, nbin), jnp.asarray(bins)
+    out = jnp.stack([build(b, jnp.asarray(g_np * (nor_np == v)),
+                           jnp.asarray(h_np * (nor_np == v)))
+                     for v in np.asarray(node_ids)])
     return with_totals(out, slot_totals(gh, slot, m, jnp.float32)) \
         if totals else out
-
-
-def build_level_allreduce(bins, grad, hess, node_of_row, node_ids,
-                          nbin: int, **kw) -> np.ndarray:
-    """Global per-node level histograms: one local fused pass + ONE
-    framework Allreduce<Sum> for the whole level (vs one per node).
-
-    Under the XLA engine the payload stays a device array so the
-    reduction rides the device data plane (ICI) like the kmeans stats
-    matrix does; host engines take the fault-tolerant numpy path."""
-    from rabit_tpu import engine as _engine_mod
-
-    local = build_level_local(
-        bins, grad, hess, node_of_row, node_ids, nbin, **kw)
-    if not _engine_mod.is_device_plane():
-        local = _writable(local)  # fault-tolerant host path
-    shape = local.shape
-    out = rabit_tpu.allreduce(local.reshape(-1), SUM)
-    return np.asarray(out).reshape(shape)
-
-
-def build_allreduce(bins, grad, hess, nbin: int, **kw) -> np.ndarray:
-    """Global histogram: local build + framework Allreduce<Sum> of the
-    flat payload (the XGBoost per-split wire pattern).
-
-    Histogram sums deliberately stay opted IN to an armed lossy wire
-    codec (``rabit_wire_codec``, doc/performance.md): split decisions
-    compare aggregate (g, h) sums whose ordering survives one
-    quantization step, and the error-feedback stream compensates
-    across the repeated per-level allreduces — this is the bulk
-    traffic the codec exists for."""
-    local = _writable(build_local(bins, grad, hess, nbin, **kw))
-    shape = local.shape
-    out = rabit_tpu.allreduce(local.reshape(-1), SUM)
-    return out.reshape(shape)
-
-
-class HistogramHandle:
-    """Waitable result of :func:`build_allreduce_async`; ``wait()``
-    returns the reduced (f, nbin, 2) histogram."""
-
-    def __init__(self, handle, shape):
-        self._handle = handle
-        self._shape = shape
-
-    def wait(self) -> np.ndarray:
-        return np.asarray(self._handle.wait()).reshape(self._shape)
-
-
-def build_allreduce_async(bins, grad, hess, nbin: int, fuse: bool = False,
-                          **kw) -> HistogramHandle:
-    """Async :func:`build_allreduce`: the flat histogram rides an engine
-    handle so the caller overlaps independent compute (the next node's
-    local build, gain scans of already-reduced histograms) with the
-    wire.  ``fuse`` defaults to False — the single-call pattern
-    (issue, compute, wait) needs eager dispatch, since a bucketed op
-    only reaches the wire when its bucket flushes; pass ``fuse=True``
-    when issuing a back-to-back stream of per-node histograms so they
-    coalesce under ``rabit_bucket_bytes`` (doc/performance.md).
-    Host-path variant: the payload is pulled to numpy, so on the XLA
-    engine it routes through the inner host transport rather than ICI —
-    use :func:`build_level_allreduce` for the device-plane level
-    batch."""
-    local = _writable(build_local(bins, grad, hess, nbin, **kw))
-    handle = rabit_tpu.allreduce_async(local.reshape(-1), SUM, fuse=fuse)
-    return HistogramHandle(handle, local.shape)
-
-
-def split_gain(hist: np.ndarray, reg_lambda: float = 1.0) -> np.ndarray:
-    """Per (feature, cut) split gain from a (f, nbin, 2) histogram —
-    the standard XGBoost structure score, vectorized over all cuts."""
-    return split_candidates(hist, reg_lambda)[0]

@@ -3,9 +3,10 @@
 One sum, two tilings of it on the MXU, and a rule that picks between
 them from a call's static shapes (:func:`level_plan`).  Both build
 every channel's histogram in ONE bins pass, a tree level's node masks
-folded into the weights inside the kernel a row block at a time; the
-two-level body also takes a plain (nw, n) weight matrix of any
-channels.
+folded into the weights inside the kernel a row block at a time.  One
+operand layout and one way in: a tree level's ``(2, n)`` or ``(T, 2,
+n)`` weights, its node ids and ``nslots``, through
+``learn.histogram.level_hist`` to :func:`hist_fused_multi`.
 
 **The two-level body** (``_hist_kernel``; narrow calls).  The plain
 one-hot product ``onehot(bins) @ w`` with w of N=2 channels leaves the
@@ -83,13 +84,13 @@ from rabit_tpu.ops import on_tpu
 
 _VMEM_LIMIT_BYTES = 100 << 20
 _DEFAULT_BLOCK = 2048
-_MAX_CHANNELS = 64
-# The widest call that stays on the kernel's own line.  Measured on a
-# v5e at 32 x 33.6M rows, 256 bins (builders' chip runs, PR 27 and
-# PR 30): a call costs 10.3 ms + 22.45 ms a channel at 2, 4, 8, 16, 20,
-# 24 and 28 channels (0.3698 s at 16) and leaves that line at 32
-# (0.9444 s where two calls of 16 cost 0.7397 s) and 64 (1.6315 s, four
-# of 16 1.4794 s).  A tree level's channels are a power of two, so 16
+# The widest call that stays on the kernel's own line, and the widest
+# the two-level body takes.  Measured on a v5e at 32 x 33.6M rows, 256
+# bins (builders' chip runs, PR 27 and PR 30): a call costs 10.3 ms +
+# 22.45 ms a channel at 2, 4, 8, 16, 20, 24 and 28 channels (0.3698 s
+# at 16) and left that line at 32 (0.9444 s where two calls of 16 cost
+# 0.7397 s) and 64 (1.6315 s, four of 16 1.4794 s), which the body took
+# until PR 45.  A tree level's channels are a power of two, so 16
 # splits every wider level into equal calls of one shape.
 _LINE_CHANNELS = 16
 # feature groups up to which the kernel's body holds a copy a group (28
@@ -251,16 +252,14 @@ def plan(nbin: int, f: int):
     return hi, lo, fpg, ngroups
 
 
-def _hist_kernel(bins_t_ref, w_ref, *rest,
+def _hist_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
                  hi: int, lo: int, fpg: int, ngroups: int, nslots: int):
     """One row block: the one-hots of every feature group against every
     weight channel, added into the VMEM-resident output.
 
-    With ``nslots`` > 0 a node operand follows the weights and channel
-    ``s * nw + c`` is weight row ``c`` of the rows whose node is ``s``
-    (everything else weighs 0): the level's node masks are made here,
-    a row block at a time, and never exist in HBM."""
-    node_ref, out_ref = rest if nslots else (None, rest[0])
+    Channel ``s * nw + c`` is weight row ``c`` of the rows whose node is
+    ``s`` (everything else weighs 0): the level's node masks are made
+    here, a row block at a time, and never exist in HBM."""
     i = pl.program_id(0)
     block = w_ref.shape[1]
     w = w_ref[:]                                   # (nw, block) compute dtype
@@ -270,11 +269,10 @@ def _hist_kernel(bins_t_ref, w_ref, *rest,
     prec = (lax.Precision.HIGHEST if cdt == jnp.float32
             else lax.Precision.DEFAULT)
     rows = [w[c:c + 1, :] for c in range(w.shape[0])]
-    if nslots:
-        node = node_ref[:]                         # (1, block) int32
-        zero = jnp.zeros((), cdt)
-        rows = [jnp.where(node == s, r, zero)
-                for s in range(nslots) for r in rows]
+    node = node_ref[:]                             # (1, block) int32
+    zero = jnp.zeros((), cdt)
+    rows = [jnp.where(node == s, r, zero)
+            for s in range(nslots) for r in rows]
 
     @pl.when(i == 0)
     def _():
@@ -388,14 +386,14 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
 @functools.partial(
     jax.jit,
     static_argnames=("nbin", "block", "interpret", "compute_dtype",
-                     "plan_override", "nslots", "lanes", "features"))
+                     "nslots", "lanes", "features"))
 def _hist_multi(bins_t, weights, node, nbin: int, block: int,
-                interpret: bool, compute_dtype,
-                plan_override=None, nslots: int = 0,
+                interpret: bool, compute_dtype, nslots: int,
                 lanes: int = 0, features: int = 0) -> jax.Array:
     """Both bodies' calls, so that the device operation has one name
     whichever body a call took: ``lanes`` > 0 is the lane-wide body over
-    ``(trees, 2, n)`` weights and ``(trees, n)`` node ids."""
+    ``(trees, 2, n)`` weights and ``(trees, n)`` node ids, 0 the
+    two-level body over one tree's ``(2, n)`` and ``(n,)``."""
     f, n = bins_t.shape
     if lanes:
         trees = weights.shape[0]
@@ -454,19 +452,8 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
             features, nbin, trees, tree_rows)[..., :2 * nslots]
         return out.transpose(2, 3, 0, 1).reshape(
             trees * 2 * nslots, features, nbin)
-    nw = weights.shape[0] * max(nslots, 1)
-    if plan_override is None:
-        hi, lo, fpg, ngroups = plan(nbin, f)
-    else:
-        hi, lo, fpg = plan_override
-        if hi * lo < nbin:
-            raise ValueError(f"plan {plan_override}: hi*lo < nbin={nbin}")
-        if lo & (lo - 1):
-            # the kernel decomposes bins with shift/mask — a non-pow2 lo
-            # would silently scatter counts into wrong bins
-            raise ValueError(f"plan {plan_override}: lo must be a "
-                             "power of two")
-        ngroups = -(-f // fpg)
+    nw = weights.shape[0] * nslots
+    hi, lo, fpg, ngroups = plan(nbin, f)
     # The whole (ngroups, nw, fpg*hi, fpg*lo) f32 accumulator is one
     # VMEM-resident output block: validate the combined bound up front
     # (wide-feature many-node levels can exceed it) with a clear error
@@ -477,8 +464,8 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
             f"histogram accumulator needs {out_bytes >> 20} MB of VMEM "
             f"(ngroups={ngroups} x nw={nw} x {fpg * hi} x {fpg * lo} f32) "
             f"> {(_VMEM_LIMIT_BYTES // 2) >> 20} MB budget — chunk the "
-            "channels (build_level_local does) or the features across "
-            "calls")
+            "channels (learn.histogram.level_hist does) or the features "
+            "across calls")
     fpad = ngroups * fpg
     npad = _round_up(n, block)
     cdt = jnp.dtype(compute_dtype)
@@ -489,20 +476,18 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
     # whatever it finds there meets a weight of 0 and a node of -1,
     # which the (small) operands below are padded with
     bt = jnp.pad(bins_t.astype(jnp.int32), ((0, fpad - f), (0, 0)))
-    operands = [bt, jnp.pad(weights.astype(cdt), ((0, 0), (0, npad - n)))]
+    operands = [bt, jnp.pad(weights.astype(cdt), ((0, 0), (0, npad - n))),
+                # padded rows sit at node -1: in no slot
+                jnp.pad(node.astype(jnp.int32).reshape(1, n),
+                        ((0, 0), (0, npad - n)), constant_values=-1)]
     in_specs = [
         pl.BlockSpec((fpad, block), lambda i: (0, i),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((weights.shape[0], block), lambda i: (0, i),
                      memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block), lambda i: (0, i),
+                     memory_space=pltpu.VMEM),
     ]
-    if nslots:
-        # padded rows sit at node -1: in no slot
-        operands.append(jnp.pad(
-            node.astype(jnp.int32).reshape(1, n), ((0, 0), (0, npad - n)),
-            constant_values=-1))
-        in_specs.append(pl.BlockSpec((1, block), lambda i: (0, i),
-                                     memory_space=pltpu.VMEM))
 
     params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
@@ -539,39 +524,37 @@ def default_block(n: int) -> int:
 def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                      interpret: bool | None = None,
                      compute_dtype=DEFAULT_COMPUTE_DTYPE,
-                     plan_override: tuple | None = None,
                      node_of_row=None, nslots: int = 0,
                      features: int | None = None) -> jax.Array:
-    """(nw, f, nbin) histograms of ``nw`` weight channels in one pass.
+    """``(nslots * 2, f, nbin)`` histograms of a tree level in one pass
+    over the bins, slot-major: channel ``s * 2 + c`` is weight row ``c``
+    over the rows at node ``s``.
 
     ``bins_t`` is the TRANSPOSED (f, n) int32 bins array (the layout
     the kernel streams; keep it resident on device across calls —
-    boosting reuses it for every node, level and round).  ``weights``
-    is (nw, n); each row gets its own (f, nbin) histogram.  Extra
-    channels share the single bins read, so per-level node histograms
-    cost one HBM pass instead of one per node.  Of a staged array
-    padded to whole feature groups, ``features`` says how many leading
-    rows are features: the result has that many.
-
-    A tree level passes ``node_of_row`` (n,) int32 and ``nslots``: the
-    result is then ``(nslots * nw, f, nbin)``, slot-major, channel
-    ``s * nw + c`` being weight row ``c`` over the rows at node ``s``
-    (a row at any other node, -1 say, is in no histogram).  The masks
+    boosting reuses it for every node, level and round), ``weights`` the
+    tree's ``(2, n)`` (grad, hess), ``node_of_row`` its ``(n,)`` int32
+    slots (a row at no slot, -1 say, is in no histogram).  The masks
     are made inside the kernel from 4 bytes a row; no (channels, n)
-    weight matrix is written to HBM.
+    weight matrix is written to HBM.  Of a staged array padded to whole
+    feature groups, ``features`` says how many leading rows are
+    features: the result has that many.
 
     A level of several trees passes ``(T, 2, n)`` weights and ``(T, n)``
     node ids: ``(T * nslots * 2, f, nbin)``, tree-major, tree ``t``'s
     channels those of a call on ``weights[t]`` and ``node_of_row[t]``.
 
-    A level of (grad, hess) pairs is built by the body
-    :func:`level_plan` names for its shape: the lane-wide one takes the
-    trees in one call (more than its lanes hold is a ``ValueError``),
-    the two-level one a call a tree.  Both round the weights to the
-    compute dtype once and add exact products in float32; the order of
-    those adds is each body's own, so they are held to float32
-    rounding, not to equality bit for bit (which the chip showed at the
-    boosting cells' shapes all the same: tools/hist_kernel_check.py).
+    The body is the one :func:`level_plan` names for the shape, and a
+    call holds what that plan's call holds: the lane-wide body the
+    trees at once, the two-level body a tree of :func:`max_channels`
+    channels.  More, or no level at all (no node ids, weights that are
+    no (grad, hess) pairs), is a ``ValueError``:
+    ``learn.histogram.level_hist`` shares a level of any width out over
+    such calls.  Both bodies round the weights to the compute dtype once
+    and add exact products in float32; the order of those adds is each
+    body's own, so they are held to float32 rounding, not to equality
+    bit for bit (which the chip showed at the boosting cells' shapes all
+    the same: tools/hist_kernel_check.py).
     """
     if interpret is None:
         interpret = not on_tpu()
@@ -583,52 +566,38 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
     block = min(block, _round_up(n, 128))
     cdt = jnp.dtype(compute_dtype).name
     forest = weights.ndim == 3
-    if forest and not (nslots and weights.shape[1] == 2):
-        raise ValueError("a level of several trees takes (T, 2, n) weights "
-                         "with their node ids and nslots")
+    pairs = weights.ndim in (2, 3) and weights.shape[-2] == 2
+    if not pairs or node_of_row is None or nslots < 1:
+        raise ValueError(
+            "a tree level takes (2, n) weights, or (T, 2, n) of several "
+            "trees, with their node ids and nslots >= 1, not "
+            f"{weights.shape} with nslots={nslots}: call "
+            "learn.histogram.level_hist (or build_level_local)")
     trees = weights.shape[0] if forest else 1
-    if nslots and weights.shape[-2] == 2 and plan_override is None:
-        plan = level_plan(nbin, features, nslots, trees)
-        if plan.lane:
-            if trees > plan.trees or nslots > plan.slots:
-                raise ValueError(
-                    f"{trees} trees of {nslots} slots out of range of a "
-                    f"lane-wide call: {plan.trees} trees of {plan.slots}")
-            node = jnp.asarray(node_of_row)
-            return _hist_multi(
-                jnp.asarray(bins_t), weights if forest else weights[None],
-                node if forest else node[None], nbin, block, interpret, cdt,
-                nslots=nslots, features=features,
-                lanes=_round_up(trees * lane_rows(nslots), 128))
+    plan = level_plan(nbin, features, nslots, trees)
+    if plan.lane:
+        if trees > plan.trees or nslots > plan.slots:
+            raise ValueError(
+                f"{trees} trees of {nslots} slots out of range of a "
+                f"lane-wide call: {plan.trees} trees of {plan.slots}")
+        node = jnp.asarray(node_of_row)
+        return _hist_multi(
+            jnp.asarray(bins_t), weights if forest else weights[None],
+            node if forest else node[None], nbin, block, interpret, cdt,
+            nslots=nslots, features=features,
+            lanes=_round_up(trees * lane_rows(nslots), 128))
     if forest:
         return jnp.concatenate([
             hist_fused_multi(bins_t, weights[t], nbin, block, interpret,
                              compute_dtype, node_of_row=node_of_row[t],
                              nslots=nslots, features=features)
             for t in range(trees)])
-    nw = weights.shape[0] * max(nslots, 1)
-    if not 1 <= nw <= _MAX_CHANNELS:
-        raise ValueError(f"nw={nw} out of range [1, {_MAX_CHANNELS}]")
-    return _hist_multi(jnp.asarray(bins_t), weights,
-                       None if not nslots else jnp.asarray(node_of_row),
+    if nslots > plan.slots:
+        raise ValueError(
+            f"{nslots} slots out of range of a two-level call: "
+            f"{plan.slots} at {features} features of {nbin} bins "
+            "(max_channels); learn.histogram.level_hist chunks a wider "
+            "level")
+    return _hist_multi(jnp.asarray(bins_t), weights, jnp.asarray(node_of_row),
                        nbin, block, interpret, cdt,
-                       plan_override=plan_override,
                        nslots=nslots)[:, :features]
-
-
-def hist_fused(bins, grad, hess, nbin: int, block: int | None = None,
-               interpret: bool | None = None,
-               compute_dtype=DEFAULT_COMPUTE_DTYPE) -> jax.Array:
-    """(f, nbin, 2) gradient/hessian histogram of binned features.
-
-    ``bins`` is (n, f) int32 in [0, nbin); ``grad``/``hess`` are (n,)
-    weights.  Convenience wrapper over :func:`hist_fused_multi` with
-    two channels (transposes ``bins`` internally — callers with the
-    (f, n) layout at hand should call the multi variant directly).
-    """
-    bins = jnp.asarray(bins)
-    w = jnp.stack([jnp.asarray(grad), jnp.asarray(hess)])
-    out = hist_fused_multi(bins.T, w, nbin, block=block,
-                           interpret=interpret,
-                           compute_dtype=compute_dtype)
-    return out.transpose(1, 2, 0)

@@ -70,19 +70,25 @@ def _kmeans_dense(topo):
 
 
 def _hist_level(topo):
-    from rabit_tpu.ops.histogram_kernel import hist_fused_multi
+    from rabit_tpu.ops.histogram_kernel import hist_fused_multi, level_plan
 
-    # 8 nodes x (grad, hess) channels, 64 features, 256 bins, 262k rows
-    fn = jax.jit(functools.partial(hist_fused_multi, nbin=256,
+    # a level of 4 slots, 8 channels: under the rule's crossing, so the
+    # two-level body, its 8 feature groups unrolled (what the narrow
+    # levels of the HIGGS and Covertype cells run, and no other id
+    # compiles); 64 features, 256 bins, 262k rows
+    n = 1 << 18
+    assert level_plan(256, 64, 4) == (False, 1, 8)
+    fn = jax.jit(functools.partial(hist_fused_multi, nbin=256, nslots=4,
                                    interpret=False))
-    return fn.lower(*_one_chip(topo, ((64, 1 << 18), jnp.int32),
-                               ((16, 1 << 18), jnp.float32))).compile()
+    (bins, gh, node) = _one_chip(topo, ((64, n), jnp.int32),
+                                 ((2, n), jnp.float32), ((n,), jnp.int32))
+    return fn.lower(bins, gh, node_of_row=node).compile()
 
 
 def _hist_level_staged(nslots, topo):
     from rabit_tpu.ops.histogram_kernel import hist_fused_multi
 
-    # the widest direct call, 64 channels: node slots x (grad, hess)
+    # one lane-wide call of 128 lanes: 32 node slots x (grad, hess)
     # folded in inside the kernel, 28 features staged as (32, n) int32,
     # 256 bins, one chip's 33.6M rows; the bins are not copied (the
     # temporaries are the bf16 grad and hess)
@@ -471,7 +477,7 @@ def test_distributed_update_program_compiles_for_v5e(topo):
     # threshold (_VMEM_BUDGET_BYTES): the three fail at the parent commit
     functools.partial(_ring, 64 << 10), functools.partial(_ring, 4 << 20),
     functools.partial(_ring, 64 << 20),
-], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-8x64x256x262k",
+], ids=["kmeans_stats_fused-bf16-512k", "hist_fused_multi-4slots-64x256x262k",
         "hist_fused_multi-32slots-28x256x33.6M",
         "hist_fused_multi-16slots-28x256x33.6M",
         "hist_fused_multi-3slots-968x256x1.18M-ragged",

@@ -509,8 +509,7 @@ def test_empty_side_of_a_derived_histogram_does_not_win_the_argmax(
     hist[1, :4] = [(-6.0, 8.0), (-5.0, 7.0), (6.0, 8.0), (5.0 + 1e-7, 7.0)]
     # nobody is absent: the node's totals are either feature's bins
     total = hist[0].sum(axis=0) if has_missing else None
-    unmasked = (histogram.split_gain_missing(hist, total, lam)[0]
-                if has_missing else histogram.split_gain(hist, lam))
+    unmasked = histogram.split_candidates(hist, lam, total=total)[0]
     assert not unmasked[0, 2] < np.inf           # the trap
     gain, _ = histogram.split_candidates(hist, lam, 1e-3, total)
     assert gain[0, 2] == -np.inf and np.isfinite(gain[1]).all()

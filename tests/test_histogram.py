@@ -1,5 +1,5 @@
-"""Histogram builder tests: numeric parity with numpy and the
-allreduce wire pattern on the empty engine."""
+"""Histogram builder tests: numeric parity with numpy, the level's
+chunking over kernel calls, and the rule between the two bodies."""
 import numpy as np
 import pytest
 
@@ -18,21 +18,25 @@ def _np_hist(bins, grad, hess, nbin):
 
 
 @pytest.mark.parametrize("n,f,nbin", [(1000, 5, 16), (513, 3, 7)])
-def test_build_local_matches_numpy(n, f, nbin):
+def test_node_builder_matches_numpy(n, f, nbin):
+    # the float32 per-node builder, as the level's fallback off the chip
     rng = np.random.default_rng(0)
     bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
     grad = rng.standard_normal(n).astype(np.float32)
     hess = rng.random(n).astype(np.float32)
-    got = np.asarray(histogram.build_local(
-        bins, grad, hess, nbin, row_block=256, feat_block=2))
+    got = np.asarray(histogram.build_level_local(
+        bins, grad, hess, np.zeros(n, np.int32), [0], nbin,
+        use_pallas=False))
     want = _np_hist(bins, grad, hess, nbin)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    assert got.shape == (1, f, nbin, 2)
+    np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=1e-3)
 
 
 def test_quantize_bounds():
     rng = np.random.default_rng(1)
     vals = rng.standard_normal((500, 4)).astype(np.float32)
-    bins, cuts = histogram.quantize(vals, 32)
+    cuts = histogram.quantile_cuts(vals, 32)
+    bins = histogram.apply_cuts(vals, cuts)
     assert bins.min() >= 0 and bins.max() < 32
     assert cuts.shape == (4, 31)
     # roughly uniform occupancy from quantile cuts
@@ -40,53 +44,47 @@ def test_quantize_bounds():
     assert counts.min() > 0
 
 
-def test_build_allreduce_empty_engine(empty_engine):
-    rng = np.random.default_rng(2)
-    bins = rng.integers(0, 8, (300, 4)).astype(np.int32)
-    grad = rng.standard_normal(300).astype(np.float32)
-    hess = np.ones(300, np.float32)
-    got = histogram.build_allreduce(bins, grad, hess, 8,
-                                    row_block=128, feat_block=4)
-    want = _np_hist(bins, grad, hess, 8)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-    # hessian column of counts sums to n
-    assert got[:, :, 1].sum() == pytest.approx(4 * 300)
-
-
 @pytest.mark.parametrize("n,f,nbin", [(1000, 5, 16), (513, 3, 7),
                                       (300, 9, 256)])
 def test_pallas_kernel_matches_numpy(n, f, nbin):
+    from rabit_tpu.ops.histogram_kernel import hist_fused_multi
+
     rng = np.random.default_rng(3)
     bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
     grad = rng.standard_normal(n).astype(np.float32)
     hess = rng.random(n).astype(np.float32)
     want = _np_hist(bins, grad, hess, nbin)
-    # interpret-mode fused kernel: f32 exact path, bf16 default path
-    got = np.asarray(histogram.build_local(
-        bins, grad, hess, nbin, use_pallas=True, compute_dtype="float32"))
+    # interpret-mode fused kernel, every row a level of one slot: f32
+    # exact path, bf16 default path
+    level = dict(node_of_row=np.zeros(n, np.int32), nslots=1, interpret=True)
+    got = np.asarray(hist_fused_multi(
+        bins.T, np.stack([grad, hess]), nbin, compute_dtype="float32",
+        **level)).transpose(1, 2, 0)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-    got16 = np.asarray(histogram.build_local(
-        bins, grad, hess, nbin, use_pallas=True))
+    got16 = np.asarray(hist_fused_multi(
+        bins.T, np.stack([grad, hess]), nbin, **level)).transpose(1, 2, 0)
     np.testing.assert_allclose(got16, want, rtol=2e-2, atol=5e-2)
 
 
 def test_multi_channel_kernel_matches_per_node():
-    # per-node level histograms from the (nw, n) weight matrix must
-    # equal node-by-node builds
+    # a level's slots from the (2, n) weights and the node ids must equal
+    # node-by-node builds of the masked weights
     from rabit_tpu.ops.histogram_kernel import hist_fused_multi
 
     rng = np.random.default_rng(4)
-    n, f, nbin, m = 600, 4, 16, 3
+    n, f, nbin, m = 600, 4, 16, 4
     bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
     grad = rng.standard_normal(n).astype(np.float32)
+    hess = rng.random(n).astype(np.float32)
     node = rng.integers(0, m, n).astype(np.int32)
-    w = np.stack([grad * (node == v) for v in range(m)])
-    out = np.asarray(hist_fused_multi(bins.T, w, nbin, interpret=True,
-                                      compute_dtype="float32"))
-    assert out.shape == (m, f, nbin)
+    out = np.asarray(hist_fused_multi(
+        bins.T, np.stack([grad, hess]), nbin, interpret=True,
+        compute_dtype="float32", node_of_row=node, nslots=m))
+    assert out.shape == (2 * m, f, nbin)
     for v in range(m):
-        want = _np_hist(bins, w[v], np.ones(n, np.float32), nbin)[:, :, 0]
-        np.testing.assert_allclose(out[v], want, rtol=1e-4, atol=1e-3)
+        want = _np_hist(bins, grad * (node == v), hess * (node == v), nbin)
+        np.testing.assert_allclose(out[2 * v:2 * v + 2].transpose(1, 2, 0),
+                                   want, rtol=1e-4, atol=1e-3)
 
 
 def test_build_level_local_pallas_matches_fallback():
@@ -123,19 +121,6 @@ def test_build_level_chunks_past_channel_budget():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
-def test_build_level_allreduce_empty_engine(empty_engine):
-    rng = np.random.default_rng(6)
-    n, f, nbin = 200, 3, 8
-    bins = rng.integers(0, nbin, (n, f)).astype(np.int32)
-    grad = rng.standard_normal(n).astype(np.float32)
-    hess = np.ones(n, np.float32)
-    node = np.zeros(n, np.int32)
-    got = histogram.build_level_allreduce(bins, grad, hess, node, [0], nbin)
-    want = _np_hist(bins, grad, hess, nbin)
-    np.testing.assert_allclose(np.asarray(got[0]), want, rtol=1e-4,
-                               atol=1e-3)
-
-
 def test_split_gain_prefers_clean_split():
     # two clusters: negative gradients in low bins, positive in high bins
     nbin = 8
@@ -143,7 +128,7 @@ def test_split_gain_prefers_clean_split():
     hist[0, :4, 0] = -5.0
     hist[0, 4:, 0] = +5.0
     hist[0, :, 1] = 10.0
-    gain = histogram.split_gain(hist)
+    gain, _left = histogram.split_candidates(hist)
     assert gain.shape == (1, nbin - 1)
     assert gain.argmax() == 3  # the boundary between the clusters
 
@@ -236,12 +221,12 @@ def test_split_gain_of_a_node_of_millions_of_rows():
     rng = np.random.default_rng(13)
     hist[:, :162, 1] = 29000.0 + rng.random((2, 162)).astype(np.float32)
     hist[:, :162, 0] = rng.standard_normal((2, 162)) * 1e3
-    gain = histogram.split_gain(hist, 1.0)
+    gain, _left = histogram.split_candidates(hist, 1.0)
     assert np.isfinite(gain).all()
     assert abs(gain[:, 161:]).max() < 1e-6     # nothing on the right
     # the first bin's rows again, absent from both features
     total = hist[0].sum(axis=0, dtype=np.float64) + hist[0, 0]
-    gain_m, _left = histogram.split_gain_missing(hist, total, 1.0)
+    gain_m, _left = histogram.split_candidates(hist, 1.0, total=total)
     assert np.isfinite(gain_m).all()
 
 
@@ -310,12 +295,12 @@ def _mass(bins_t, gh, node, nslots, f, nbin):
 @pytest.mark.parametrize("nslots", [1, 8, 9, 16, 17, 32, 64])
 def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype, body):
     """The calls of a chunked level give every channel the rows of the
-    one wide call in the same order: equal bit for bit under the
-    two-level body (the direct call takes at most 64 channels, so a
-    64-slot level is held against two), under the rule (a wide level is
-    one lane-wide call, the direct calls lane-wide too) to the order of
-    the float32 adds; and equal to the float32 XLA level to the
-    operand's rounding."""
+    direct calls in the same order: equal bit for bit under the
+    two-level body (a direct call takes ``max_channels`` channels, 8
+    slots here, so a wider level is held against several), under the
+    rule (a wide level is one lane-wide call, the direct calls of 8
+    slots lane-wide too) to the order of the float32 adds; and equal to
+    the float32 XLA level to the operand's rounding."""
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
@@ -326,10 +311,12 @@ def test_chunked_level_equals_the_direct_call_exactly(nslots, dtype, body):
         bins_t, gh, node, nslots, f, nbin, use_pallas=True,
         compute_dtype=dtype))
     assert got.shape == (nslots, f, nbin, 2)
+    per = hk.max_channels(nbin, bins_t.shape[0]) // 2
+    assert per == 8
     direct = jnp.concatenate([
         hk.hist_fused_multi(bins_t, gh, nbin, node_of_row=node - lo,
-                            nslots=min(32, nslots - lo), compute_dtype=dtype)
-        for lo in range(0, nslots, 32)])
+                            nslots=min(per, nslots - lo), compute_dtype=dtype)
+        for lo in range(0, nslots, per)])
     direct = np.asarray(direct.reshape(nslots, 2, -1, nbin)
                         .transpose(0, 2, 3, 1)[:, :f])
     mass = _mass(bins_t, gh, node, nslots, f, nbin)
@@ -379,8 +366,7 @@ def test_level_lowers_to_one_kernel_call_a_width(nslots, body):
 def test_widest_call_is_the_smaller_of_the_line_and_the_vmem_bound(
         nbin, f, want):
     """Wide-feature shapes whose accumulator bound is under the line's
-    width keep that smaller bound; a direct caller may still ask the
-    kernel for its 64 channels."""
+    width keep that smaller bound."""
     from rabit_tpu.ops import histogram_kernel as hk
 
     assert hk.max_channels(nbin, f) == want
@@ -388,17 +374,19 @@ def test_widest_call_is_the_smaller_of_the_line_and_the_vmem_bound(
 
 
 @pytest.mark.parametrize("body", ["two-level", "rule"], indirect=True)
-def test_direct_caller_still_gets_64_channels_and_no_more(body):
-    """The two-level body's widest direct call is 64 channels; the
-    lane-wide body's is what 256 lanes hold."""
+def test_direct_caller_gets_what_the_plans_call_holds_and_no_more(body):
+    """The two-level body's widest direct call is ``max_channels``
+    channels (16: 8 slots), and the refusal names the caller that
+    chunks; the lane-wide body's is what 256 lanes hold."""
     from rabit_tpu.ops import histogram_kernel as hk
 
-    bins_t, gh, node, _ = _level_case(32, n=256)
-    out = hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=32)
-    assert out.shape[0] == 64
-    over = 33 if body == "two-level" else 129
-    with pytest.raises(ValueError, match="out of range"):
-        hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=over)
+    most = 8 if body == "two-level" else 128
+    bins_t, gh, node, _ = _level_case(most, n=256)
+    out = hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=most)
+    assert out.shape[0] == 2 * most
+    with pytest.raises(ValueError, match="out of range") as refusal:
+        hk.hist_fused_multi(bins_t, gh, 8, node_of_row=node, nslots=most + 1)
+    assert ("level_hist" in str(refusal.value)) == (body == "two-level")
 
 
 @pytest.mark.parametrize("body,calls", [("two-level", (3, 0)),
@@ -614,6 +602,68 @@ def test_a_forests_level_is_its_trees_levels_one_by_one():
         hk.hist_fused_multi(bins_t, gh, nbin)
 
 
+@pytest.mark.parametrize("weights,level", [
+    ((6, 300), {}),                                   # the per-node form
+    ((6, 300), {"node_of_row": (300,), "nslots": 3}),
+    ((2, 300), {}),
+    ((2, 300), {"node_of_row": (300,)}),
+    ((3, 2, 300), {"node_of_row": (3, 300)}),
+    ((3, 4, 300), {"node_of_row": (3, 300), "nslots": 2}),
+    ((300,), {"node_of_row": (300,), "nslots": 1}),
+], ids=["plain-matrix", "plain-matrix-with-nodes", "pairs-without-nodes",
+        "pairs-without-nslots", "forest-without-nslots", "forest-of-fours",
+        "one-row"])
+def test_a_call_without_node_ids_is_refused(weights, level):
+    """The kernel's entry takes a tree level and nothing else: (2, n) or
+    (T, 2, n) weights with node ids and ``nslots``.  The plain (nw, n)
+    matrix of before PR 26 and every half-given level is a ``ValueError``
+    that names what to call instead, before anything is traced."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    bins_t = np.zeros((3, 300), np.int32)
+    kw = dict(level)
+    if "node_of_row" in kw:
+        kw["node_of_row"] = np.zeros(kw["node_of_row"], np.int32)
+    with pytest.raises(ValueError, match="node ids.*level_hist"):
+        hk.hist_fused_multi(bins_t, np.ones(weights, np.float32), 8,
+                            interpret=True, **kw)
+
+
+def test_a_wide_shards_call_over_its_width_names_level_hist():
+    """968 features of 256 bins: the accumulator holds 6 channels, so a
+    direct two-level call of 4 slots is refused with the caller that
+    chunks, and ``level_hist`` builds the same 4 slots in two calls of 3
+    and 1 that read what direct calls of those widths read."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    f, nbin, nslots, n = 968, 256, 4, 256
+    assert hk.max_channels(nbin, f) == 6
+    assert hk.level_plan(nbin, f, nslots) == (False, 1, 3)
+    rng = np.random.default_rng(45)
+    bins_t = jnp.asarray(rng.integers(0, nbin + 1, (f, n)).astype(np.int32))
+    gh = jnp.asarray(rng.standard_normal((2, n)).astype(np.float32))
+    node = jnp.asarray(rng.integers(-1, nslots, n).astype(np.int32))
+    with pytest.raises(ValueError, match="3 at 968 features.*level_hist"):
+        hk.hist_fused_multi(bins_t, gh, nbin, node_of_row=node,
+                            nslots=nslots, interpret=True)
+    assert histogram.level_calls(nslots, f, nbin, True) == (2, 0)
+
+    def level(b, w, nd):
+        return histogram.level_hist(b, w, nd, nslots, f, nbin,
+                                    use_pallas=True)
+
+    assert _pallas_calls(jax.make_jaxpr(level)(bins_t, gh, node).jaxpr) == 2
+    got = np.asarray(level(bins_t, gh, node))
+    direct = np.concatenate([np.asarray(hk.hist_fused_multi(
+        bins_t, gh, nbin, node_of_row=node - lo, nslots=ns, interpret=True))
+        for lo, ns in ((0, 3), (3, 1))])
+    np.testing.assert_array_equal(
+        got, direct.reshape(nslots, 2, f, nbin).transpose(0, 2, 3, 1))
+
+
 # (f, trees) of the four boosting configurations, 256 bins, depth 6: the
 # build slots of a tree level by level are 1, 1, 2, 4, 8, 16
 @pytest.mark.parametrize("f,trees,want", [
@@ -792,9 +842,9 @@ def test_an_absent_entry_adds_to_no_bin(kw, nbin):
             want = _np_hist(bins[have][:, j:j + 1], gh[0, have], gh[1, have],
                             nbin)[0]
             np.testing.assert_allclose(got[s, j], want, rtol=0, atol=2e-4)
-    built = np.asarray(histogram.build_local(
-        bins, gh[0], gh[1], nbin, **kw, **(
-            {"compute_dtype": "float32"} if kw["use_pallas"] else {})))
+    built = np.asarray(histogram.build_level_local(
+        bins, gh[0], gh[1], np.zeros(n, np.int32), [0], nbin, **kw, **(
+            {"compute_dtype": "float32"} if kw["use_pallas"] else {})))[0]
     have = bins[:, 0] < nbin
     np.testing.assert_allclose(
         built[0], _np_hist(bins[have][:, :1], gh[0, have], gh[1, have],
