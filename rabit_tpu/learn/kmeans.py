@@ -92,7 +92,8 @@ def _dense16_budget() -> int:
     (compute_dtype="bfloat16": x stored (n, d) bf16 plus an f32 validity
     vector — the biggest-that-fits tier, whose iterations ride the fused
     dense kernel instead of the ELL one): 7/8 of what the local device
-    reports, the rest being headroom for centroids/stats/scratch.
+    reports, the rest being headroom for centroids/stats/scratch.  The
+    fused ELL tiers' budget is read off it (:func:`_stream_budget`).
 
     The size is measured, never assumed.  A TPU that reports no limit
     is an error (a guessed 16 GB would OOM a smaller chip and waste a
@@ -106,7 +107,7 @@ def _dense16_budget() -> int:
     limit = int(stats.get("bytes_limit", 0)) if stats else 0
     if limit <= 0:
         check(not on_tpu(),
-              "kmeans dense16 staging: device %s reports no memory limit "
+              "kmeans staging: device %s reports no memory limit "
               "(memory_stats() = %r); cannot size the tier",
               jax.local_devices()[0], stats)
         limit = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
@@ -116,6 +117,29 @@ def _dense16_budget() -> int:
 _DENSE16_ROW_TILE = 16384   # fused-kernel row block: stage an exact
 #                             multiple so its padding never copies
 _STAGE_CHUNK_ROWS = 1 << 20
+# A shard whose fused-ELL form does not fit the device is taken a chunk
+# of _STAGE_CHUNK_ROWS rows at a time (at 32 slots a row 134 MB of
+# indices and as much of values: the size at which the host link ran at
+# 14 GB/s, PERF.md section 6, PR 32).  Of the budget (_stream_budget)
+# this many chunks are kept free for the ones on their way: one the
+# kernel reads, one landed, one in transfer.
+_STREAM_RING = 3
+
+
+def _stream_budget() -> int:
+    """Memory budget of the fused ELL tiers: over it a shard streams,
+    and as many of its chunks as fit in it beside the ring stay
+    resident.  15/16 of what the device reports: the fused ELL kernel
+    keeps its working set in VMEM (a resident shard's peak is the shard
+    and 1.6 MB: PERF.md section 5), so of the eighth that
+    :func:`_dense16_budget` leaves the dense kernel half is enough for
+    programs and results.  Every chunk more that is resident is 273 MB
+    less over the host link an iteration: of a 72-chunk shard on a v5e
+    17 chunks stream at 15/16 where 21 would at 7/8, and the job reads
+    183M rows/s where it read 173M (PERF.md section 6, PR 46).  Read
+    off ``_dense16_budget``, which measures and which a test steers."""
+    eighth_off = _dense16_budget()
+    return eighth_off + eighth_off // 14     # 7/8 + 1/16 of the whole
 
 
 def _densify_fn(block: int, d: int, nnz: int):
@@ -253,7 +277,9 @@ def _stage_sliced(host, rows: int):
     indices: PERF.md section 6, PR 32).  A slice is awaited before the
     next is handed over, so the device holds the array and one slice
     and never more: the loop would otherwise run ahead of the device by
-    as many slices as it has."""
+    as many slices as it has.  (A shard that streams keeps to the same
+    rule every iteration: :class:`_ChunkStream` holds the host to its
+    ring.)"""
     import jax
 
     alloc, write = _stage_slice_fns()
@@ -512,13 +538,53 @@ def prepare_shard(idx, val, valid, feat_dim: int,
     caller has already taken it (:func:`run` does, under
     ``stage.clamp``); the fused ELL tier takes it itself otherwise.
 
+    A shard whose fused-ELL form is over the budget the device reports
+    (:func:`_stream_budget`) is the fifth outcome, ``"ell_stream"``:
+    the same kernel, a chunk of ``_STAGE_CHUNK_ROWS`` rows a call.  As
+    many whole chunks as the budget holds beside a ring of
+    ``_STREAM_RING`` are staged resident, each a device array triple of
+    its own, spread evenly over the shard; the rest stay in the
+    caller's host memory and cross the host link under the kernel every
+    iteration.  The payload is ``(resident, host, d_pad, nnz, stream)``:
+    ``resident`` maps a chunk's number to its grouped ``(idx, val,
+    valid)`` on the device, ``host`` lists every chunk's grouped triple
+    on the host (views of ``idx`` and ``val``, which are read and never
+    written; a copy only of a ragged last chunk, padded once, or where
+    the slots had to be padded), ``stream`` is the :class:`_ChunkStream`
+    that hands the others over.
+
     The span ``stage.put`` closes as this returns: the dense tiers'
     transfers may still be in flight, the fused ELL tier's slices have
-    landed (:func:`_stage_sliced` awaits each).
+    landed (:func:`_stage_sliced` awaits each), and so has the resident
+    part of a shard that streams (``stage.resident``, inside it).
     """
     with program.span("stage.put"):
         return _prepare_shard(idx, val, valid, feat_dim, row_block,
                               budget, compute_dtype, max_index)
+
+
+def _tier(n: int, nnz: int, feat_dim: int, budget: int,
+          compute_dtype: str) -> str:
+    """Which of :func:`prepare_shard`'s five outcomes a shard of ``n``
+    rows of ``nnz`` slots gets: its bytes in each form against the
+    budget that form is held to.  Nothing is staged."""
+    import jax.numpy as jnp
+
+    if n * (feat_dim + 1) * 4 <= budget:
+        return "dense"
+    if compute_dtype != "float32":
+        itemsize = jnp.dtype(compute_dtype).itemsize
+        dp = -(-feat_dim // 128) * 128   # staged at lane-padded width
+        if n * dp * itemsize + n * 4 <= _dense16_budget():
+            return "dense16"
+    if not on_tpu():
+        return "ell"
+    # slots padded to a power of two, rows to the kernel block: indices,
+    # values and validity fit, or what fits is resident and the rest
+    # streams
+    n_p = -(-n // _ELL_FUSED_BLOCK) * _ELL_FUSED_BLOCK
+    whole = n_p * (_next_pow2(nnz) * 8 + 4)
+    return "ell_stream" if whole > _stream_budget() else "ell_fused"
 
 
 def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
@@ -526,60 +592,261 @@ def _prepare_shard(idx, val, valid, feat_dim: int, row_block: int,
                    max_index: int | None):
     import jax
 
-    import jax.numpy as jnp
-
-    n = idx.shape[0]
+    n, nnz = idx.shape
     nb = n // row_block
-    if n * (feat_dim + 1) * 4 <= budget:
-        fn = _densify_fn(row_block, feat_dim, idx.shape[1])
+    tier = _tier(n, nnz, feat_dim, budget, compute_dtype)
+    if tier == "dense":
+        fn = _densify_fn(row_block, feat_dim, nnz)
         blocks = fn(idx.reshape(nb, row_block, -1),
                     val.reshape(nb, row_block, -1),
                     valid.reshape(nb, row_block))
-        return ("dense", feat_dim, blocks)
-    if compute_dtype != "float32":
-        itemsize = jnp.dtype(compute_dtype).itemsize
-        dp = -(-feat_dim // 128) * 128   # staged at lane-padded width
-        if n * dp * itemsize + n * 4 <= _dense16_budget():
-            x, v16 = _stage_dense16(idx, val, valid, feat_dim,
-                                    row_block, compute_dtype)
-            return ("dense16", feat_dim, (x, v16))
-    if on_tpu():
-        # pad slots to a power of two (index shifts), rows to the kernel
-        # block; pad slots carry (index=feat_dim, value=0) so they land
-        # in the sliced-away validity column with zero weight
-        nnz = idx.shape[1]
-        nnz_p = _next_pow2(nnz)
-        n_p = -(-n // _ELL_FUSED_BLOCK) * _ELL_FUSED_BLOCK
-        if max_index is None:
-            max_index = int(idx.max(initial=0))
-        if nnz_p != nnz or n_p != n:
-            idx = np.pad(idx, ((0, n_p - n), (0, nnz_p - nnz)),
-                         constant_values=feat_dim)
-            val = np.pad(val, ((0, n_p - n), (0, nnz_p - nnz)))
-            valid = np.pad(valid, (0, n_p - n))
-        # Exact-d padding when possible: slots at index feat_dim with a
-        # ZERO value (ELL pads) vanish through the val-weighted one-hot,
-        # so only clamped out-of-range features carrying real values
-        # force an extra sliced-away feature block (+hi columns = +20%
-        # MACs at d=512) to absorb them.  The mask (a quarter of the
-        # indices' bytes) is built only where the shard holds such an
-        # index at all; the pads above carry zeros and need no look.
-        contaminated = (max_index >= feat_dim
-                        and bool(np.any(val[idx >= feat_dim])))
-        d_base = feat_dim + 1 if contaminated else feat_dim
-        d_pad = -(-d_base // _ELL_FUSED_HI) * _ELL_FUSED_HI
-        # Stage GROUPED (n/G, G*nnz): a device array with a 32-wide
-        # minor dim is lane-padded to 128 (4x HBM — OOM at 50M rows);
-        # the grouped layout is what the kernel consumes anyway.
-        g = _ELL_FUSED_GROUP
-        idx_g = np.ascontiguousarray(idx.reshape(n_p // g, g * nnz_p))
-        val_g = np.ascontiguousarray(
-            val.reshape(n_p // g, g * nnz_p).astype(np.float32, copy=False))
-        rows = _STAGE_CHUNK_ROWS // g
-        return ("ell_fused", feat_dim,
-                (_stage_sliced(idx_g, rows), _stage_sliced(val_g, rows),
-                 jax.device_put(valid), d_pad, nnz_p))
-    return ("ell", feat_dim, device_ell(idx, val, valid, row_block))
+        return (tier, feat_dim, blocks)
+    if tier == "dense16":
+        return (tier, feat_dim, _stage_dense16(
+            idx, val, valid, feat_dim, row_block, compute_dtype))
+    if tier == "ell":
+        return (tier, feat_dim, device_ell(idx, val, valid, row_block))
+    # pad slots to a power of two (index shifts), rows to the kernel
+    # block; pad slots carry (index=feat_dim, value=0) so they land
+    # in the sliced-away validity column with zero weight
+    nnz_p = _next_pow2(nnz)
+    n_p = -(-n // _ELL_FUSED_BLOCK) * _ELL_FUSED_BLOCK
+    if max_index is None:
+        max_index = int(idx.max(initial=0))
+    # Exact-d padding when possible: slots at index feat_dim with a
+    # ZERO value (ELL pads) vanish through the val-weighted one-hot,
+    # so only clamped out-of-range features carrying real values
+    # force an extra sliced-away feature block (+hi columns = +20%
+    # MACs at d=512) to absorb them.  The mask (a quarter of the
+    # indices' bytes) is built only where the shard holds such an
+    # index at all; the pads below carry zeros and need no look.
+    contaminated = (max_index >= feat_dim
+                    and bool(np.any(val[idx >= feat_dim])))
+    d_base = feat_dim + 1 if contaminated else feat_dim
+    d_pad = -(-d_base // _ELL_FUSED_HI) * _ELL_FUSED_HI
+    if tier == "ell_stream":
+        return (tier, feat_dim,
+                _stage_stream(idx, val, valid, feat_dim, nnz_p, d_pad))
+    if nnz_p != nnz or n_p != n:
+        idx = np.pad(idx, ((0, n_p - n), (0, nnz_p - nnz)),
+                     constant_values=feat_dim)
+        val = np.pad(val, ((0, n_p - n), (0, nnz_p - nnz)))
+        valid = np.pad(valid, (0, n_p - n))
+    # Stage GROUPED (n/G, G*nnz): a device array with a 32-wide
+    # minor dim is lane-padded to 128 (4x HBM — OOM at 50M rows);
+    # the grouped layout is what the kernel consumes anyway.
+    g = _ELL_FUSED_GROUP
+    idx_g = np.ascontiguousarray(idx.reshape(n_p // g, g * nnz_p))
+    val_g = np.ascontiguousarray(
+        val.reshape(n_p // g, g * nnz_p).astype(np.float32, copy=False))
+    rows = _STAGE_CHUNK_ROWS // g
+    return (tier, feat_dim,
+            (_stage_sliced(idx_g, rows), _stage_sliced(val_g, rows),
+             jax.device_put(valid), d_pad, nnz_p))
+
+
+def _stage_stream(idx, val, valid, feat_dim: int, nnz_p: int, d_pad: int):
+    """The payload of the ``"ell_stream"`` tier (:func:`prepare_shard`):
+    the host's part, done once a job, then :func:`_stream_device`.
+
+    The shard is cut into chunks of ``_STAGE_CHUNK_ROWS`` rows (whole
+    kernel blocks), each grouped ``(rows/G, G*nnz)`` as the kernel reads
+    it.  A chunk of a contiguous shard is a view: only a ragged last
+    chunk is copied, padded to the one shape with inert rows (index
+    ``feat_dim``, value and validity 0), and a shard whose slots are no
+    power of two is padded whole, as the resident tier pads it."""
+    n, nnz = idx.shape
+    g = _ELL_FUSED_GROUP
+    rows = -(-_STAGE_CHUNK_ROWS // _ELL_FUSED_BLOCK) * _ELL_FUSED_BLOCK
+    if nnz_p != nnz:
+        idx = np.pad(idx, ((0, 0), (0, nnz_p - nnz)),
+                     constant_values=feat_dim)
+        val = np.pad(val, ((0, 0), (0, nnz_p - nnz)))
+    val = val.astype(np.float32, copy=False)
+    valid = valid.astype(np.float32, copy=False)
+    host = []
+    for lo in range(0, n, rows):
+        ci, cv, cvalid = idx[lo:lo + rows], val[lo:lo + rows], \
+            valid[lo:lo + rows]
+        short = rows - len(ci)
+        if short:
+            ci = np.pad(ci, ((0, short), (0, 0)), constant_values=feat_dim)
+            cv = np.pad(cv, ((0, short), (0, 0)))
+            cvalid = np.pad(cvalid, (0, short))
+        host.append((
+            np.ascontiguousarray(ci).reshape(rows // g, g * nnz_p),
+            np.ascontiguousarray(cv).reshape(rows // g, g * nnz_p),
+            np.ascontiguousarray(cvalid)))
+    program.count("stage.host_chunks", len(host))
+    return _stream_device(host, n, d_pad, nnz_p)
+
+
+def _stream_device(host, n: int, d_pad: int, nnz_p: int):
+    """The device's part of the ``"ell_stream"`` payload: the chunks the
+    budget holds are put resident, each awaited before the next (the
+    rule of :func:`_stage_sliced`), and a new ring is set up over the
+    others.  Run again after a re-formation of the device plane, on the
+    ``host`` chunks the job already has.
+
+    Chunk ``c`` of ``C`` streams where ``(c+1)*S // C`` exceeds
+    ``c*S // C``, ``S`` being the number that does not fit: evenly
+    spread, so that the resident chunks' kernel time lies between two
+    transfers all along a pass, and the last chunk (the one that may be
+    ragged) among them."""
+    import jax
+
+    device = jax.local_devices()[0]
+    rows = len(host[0][2])
+    chunk_bytes = sum(a.nbytes for a in host[0])
+    fit = (_stream_budget() - _STREAM_RING * chunk_bytes) // chunk_bytes
+    total = len(host)
+    streamed = total - max(0, min(total, fit))
+    order = [c for c in range(total)
+             if (c + 1) * streamed // total > c * streamed // total]
+    resident = {}
+    with program.span("stage.resident"):
+        for c in sorted(set(range(total)) - set(order)):
+            resident[c] = jax.block_until_ready(
+                jax.device_put(host[c], device))
+    program.put("stage.resident_rows", len(resident) * rows)
+    real = [min(rows, n - c * rows) for c in range(total)]
+    return (resident, host, d_pad, nnz_p,
+            _ChunkStream(host, order, real, device))
+
+
+class _ChunkStream:
+    """The chunks of a shard that are not resident, handed to the device
+    in their order, round after round, through a ring of
+    ``_STREAM_RING``.
+
+    A chunk is handed over (one ``jax.device_put`` of its triple, which
+    returns while the bytes move) as soon as fewer than the ring's depth
+    are on the device unconsumed: handed over and not yet read to the
+    end by the kernel call that takes them.  The transfers depend on no
+    centroid, so the ring is filled again across a pass's end, for the
+    pass to come.  When the ring is full the host waits (``stream.wait``)
+    for the oldest call that read a chunk: the device never holds more
+    than the resident part and the ring, however far ahead the host
+    could run.  ``stream.inflight_max`` is the most it held."""
+
+    def __init__(self, host, order, real_rows, device):
+        import collections
+
+        self.host, self.order, self.real_rows = host, order, real_rows
+        self.device = device
+        self.turn = 0                       # in `order`: the next to go
+        self.ahead = collections.deque()    # (chunk, triple) handed over
+        self.reading = collections.deque()  # results of calls on a chunk
+        self.inflight_max = 0
+
+    def _inflight(self) -> int:
+        # the device runs its calls in order: the finished ones are first
+        while self.reading and self.reading[0].is_ready():
+            self.reading.popleft()
+        return len(self.ahead) + len(self.reading)
+
+    def hand_over(self, wait: bool) -> bool:
+        """Hand the next chunk over if the ring has room, or once it
+        has, if ``wait``.  Says whether it did."""
+        import jax
+
+        if self._inflight() >= _STREAM_RING:
+            if not wait or not self.reading:
+                return False
+            with program.span("stream.wait"):
+                self.reading.popleft().block_until_ready()
+        c = self.order[self.turn]
+        self.turn = (self.turn + 1) % len(self.order)
+        with program.span("stream.put"):
+            self.ahead.append((c, jax.device_put(self.host[c], self.device)))
+        self.inflight_max = max(self.inflight_max, self._inflight())
+        program.put("stream.inflight_max", self.inflight_max)
+        return True
+
+    def take(self, c: int):
+        """Chunk ``c`` on the device, for the kernel call of a pass."""
+        if not self.ahead:
+            self.hand_over(wait=True)
+        got, triple = self.ahead.popleft()
+        check(got == c, "kmeans stream: chunk %d is next on the device, "
+              "the pass asked for %d", got, c)
+        program.count("stream.chunks")
+        program.count("stream.rows", self.real_rows[c])
+        program.count("stream.bytes", sum(a.nbytes for a in triple))
+        return triple
+
+    def taken(self, result) -> None:
+        """``result`` is what the call that read the last chunk taken
+        writes; then every chunk the ring has room for is handed over."""
+        self.reading.append(result)
+        while self.hand_over(wait=False):
+            pass
+
+    def refill(self) -> None:
+        """At a pass's end: the ring filled for the next pass, each
+        chunk as soon as a call of this one has let go of its own."""
+        while self.order and len(self.ahead) < _STREAM_RING \
+                and self.hand_over(wait=True):
+            pass
+
+
+def _ell_chunk_fn(k: int, d: int, d_pad: int, nnz: int):
+    """Jitted: the fused ELL kernel on one chunk of a shard, its (k, d+1)
+    statistics added to the running sum of the pass.  One shape, so one
+    program, whether the chunk is resident or was just handed over."""
+    key = ("ellchunk", k, d, d_pad, nnz, _ELL_FUSED_BLOCK)
+    fn = _STEP_CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        from rabit_tpu.ops.kmeans_kernel import kmeans_ell_stats_fused
+
+        block = _ELL_FUSED_BLOCK
+
+        @jax.jit
+        def kmeans_ell_chunk_stats(acc, cent, idx_g, val_g, valid):
+            with jax.named_scope("kmeans/assign_stats"):
+                cent_p = jnp.pad(cent, ((0, 0), (0, d_pad - d)))
+                stats = kmeans_ell_stats_fused(
+                    cent_p, idx_g, val_g, valid, d_pad, nnz=nnz,
+                    group=_ELL_FUSED_GROUP, hi=_ELL_FUSED_HI, block=block)
+                return acc + jnp.concatenate(
+                    [stats[:, :d], stats[:, -1:]], axis=1)
+
+        fn = _STEP_CACHE[key] = kmeans_ell_chunk_stats
+    return fn
+
+
+def _ell_stream_stats(centroids, payload, d: int):
+    """A pass over a shard that streams: the kernel chunk by chunk in the
+    shard's order, every call of one shape, each result added on the
+    device to the sum of those before it.  Which chunks are resident
+    changes where a call's rows come from and nothing it computes: the
+    same calls in the same order give the same bits under any budget.
+
+    The span ``learn.stream`` covers the pass's hand-overs.  The
+    counters ``stream.chunks``, ``stream.rows`` and ``stream.bytes``
+    count a chunk as the pass takes it (what is handed over ahead, for a
+    pass that has not run, is in none of them)."""
+    import jax
+
+    resident, host, d_pad, nnz, stream = payload
+    k = centroids.shape[0]
+    call = _ell_chunk_fn(k, d, d_pad, nnz)
+    # committed where the chunks are, like every operand of every call:
+    # one executable (see `on_device` in run())
+    cent = jax.device_put(centroids, stream.device)
+    acc = jax.device_put(np.zeros((k, d + 1), np.float32), stream.device)
+    with program.span("learn.stream"):
+        for c in range(len(host)):
+            chunk = resident.get(c)
+            acc = call(acc, cent, *(chunk or stream.take(c)))
+            program.enqueued(acc)
+            if chunk is None:
+                stream.taken(acc)
+        stream.refill()
+    return acc
 
 
 def shard_stats_device(centroids, shard):
@@ -598,6 +865,8 @@ def shard_stats_device(centroids, shard):
         return _dense16_stats_fn(k, d, x.shape[1])(centroids, x, v16)
     if kind == "ell_fused":
         return _ell_fused_stats(centroids, payload, d)
+    if kind == "ell_stream":
+        return _ell_stream_stats(centroids, payload, d)
     idx, val, valid = payload  # pre-blocked by device_ell: (nb, block, nnz)
     fn = _stats_fn(k, d, idx.shape[1], idx.shape[2])
     return fn(centroids, idx, val, valid)
@@ -727,7 +996,12 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     checkpoints (resume granularity coarsens to the chain length: one
     committed version per chain, so a resumed run must pass the same
     ``device_chain``).  The host fetches and commits a chain's result
-    while the next chain already runs.
+    while the next chain already runs.  A chain is one program over
+    rows that are on the device: a shard that streams (below) is refused
+    under ``device_chain > 1`` with a message that says so, and is not
+    quietly run an iteration a version, because what a committed
+    version counts would then follow the memory the device reports, and
+    a job resumed on another chip would misread its own.
 
     The distributed loop on the device plane (XLA engine, several ranks)
     likewise runs a step ahead of its commit, and keeps the reduced
@@ -763,6 +1037,16 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     array and every iteration rides the HBM-roofline fused kernel
     (similarity in bf16, accumulation in float32 — the bench.py
     numerics).
+
+    A shard too large for the device (its fused-ELL form over the
+    budget the device reports) runs all the same, through the
+    per-iteration loop: :func:`prepare_shard` stages as many chunks as
+    fit and every iteration streams the others from ``data``'s own
+    arrays under the kernel (``learn.stream``, ``stream.put``,
+    ``stream.wait``; the counters ``stream.*``).  A version's statistics
+    are the same bits whichever chunks are resident.  The host's rows
+    are read and never copied again; after a re-formation of the device
+    plane only the resident chunks are put again.
 
     Every call stages the whole shard, under three spans:
     ``stage.to_ell``; ``stage.clamp``, which times one range check of
@@ -817,7 +1101,15 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
     # matrix crosses the host boundary for the fault-tolerant allreduce
     shard = prepare_shard(idx, val, valid, feat_dim, row_block,
                           compute_dtype=compute_dtype, max_index=max_index)
+    rows = idx.shape[0]
 
+    check(not (device_chain > 1 and shard[0] == "ell_stream"
+               and not rabit_tpu.is_distributed()),
+          "kmeans: device_chain=%d chains iterations in one program over "
+          "rows that are on the device, and this shard (%d rows) is "
+          "larger than the device holds and streams from the host every "
+          "iteration: pass device_chain=0 (one committed version an "
+          "iteration)", device_chain, rows)
     if (device_chain > 1 and not rabit_tpu.is_distributed()
             and shard[0] in ("dense", "dense16", "ell_fused")):
         # Single-worker fast path: chain iterations device-resident
@@ -881,6 +1173,7 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                     fetched = fetch(cent)
                 # the iterations of the version the host now holds
                 program.count("learn.iterations", chain)
+                program.count("learn.rows", chain * rows)
                 with program.span("learn.update"):
                     model.centroids = fetched[:, :feat_dim]
                 program.count("learn.versions")
@@ -947,9 +1240,17 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                 # version
                 queued = None
                 program.count("learn.ahead_discarded")
-            shard = prepare_shard(idx, val, valid, feat_dim, row_block,
-                                  compute_dtype=compute_dtype,
-                                  max_index=max_index)
+            if shard[0] == "ell_stream":
+                # the host's chunks are the job's own: the resident
+                # ones are put again, the ring starts empty
+                with program.span("stage.put"):
+                    _resident, host, *widths, _stream = shard[2]
+                    shard = (shard[0], feat_dim,
+                             _stream_device(host, rows, *widths))
+            else:
+                shard = prepare_shard(idx, val, valid, feat_dim, row_block,
+                                      compute_dtype=compute_dtype,
+                                      max_index=max_index)
         with program.span("learn.step", version=it + 1):
             if device_plane:
                 if queued is None:
@@ -974,6 +1275,7 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                 with program.span("learn.fetch"):
                     fetched, counts = fetch((cent, counts))
                 program.count("learn.iterations")
+                program.count("learn.rows", rows)
                 check(bool((counts != 0).all()), "get zero sized cluster")
                 model.centroids = fetched
             else:
@@ -988,6 +1290,7 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                 stats = rabit_tpu.allreduce(stats, SUM,
                                             prepare_fun=lazy_stats)
                 program.count("learn.iterations")
+                program.count("learn.rows", rows)
                 with program.span("learn.update"):
                     counts = stats[:, -1:]
                     check(bool((counts != 0).all()),

@@ -183,6 +183,20 @@ def _kmeans_ell_chain(topo):
                                ((n,), jnp.float32))).compile()
 
 
+def _kmeans_ell_chunk(topo):
+    from rabit_tpu.learn import kmeans
+
+    # the call of a shard that streams (tier ell_stream): the kernel on
+    # one chunk of 2^20 rows, its statistics added to the pass's sum
+    n, nnz, g = kmeans._STAGE_CHUNK_ROWS, 32, kmeans._ELL_FUSED_GROUP
+    fn = kmeans._ell_chunk_fn(64, 512, 512, nnz)
+    return fn.lower(*_one_chip(topo, ((64, 513), jnp.float32),
+                               ((64, 512), jnp.float32),
+                               ((n // g, g * nnz), jnp.int32),
+                               ((n // g, g * nnz), jnp.float32),
+                               ((n,), jnp.float32))).compile()
+
+
 def test_stage_slice_writer_updates_the_shard_in_place_on_v5e(topo):
     """The fused ELL tier's staging at the sparse cell's shape (33.5M
     rows grouped (n/4, 128), slices of 2^20 rows): the donated array is
@@ -469,7 +483,7 @@ def test_distributed_update_program_compiles_for_v5e(topo):
     functools.partial(_hist_level_chunked, 16), _hist_level_wide,
     functools.partial(_hist_level_wide_lanes, 8),
     functools.partial(_hist_level_wide_lanes, 16),
-    _kmeans_ell_chain, _dense16_loop,
+    _kmeans_ell_chain, _kmeans_ell_chunk, _dense16_loop,
     functools.partial(_lbfgs_product, "margin"),
     functools.partial(_lbfgs_product, "grad"),
     _mesh_kmeans_step,
@@ -483,7 +497,8 @@ def test_distributed_update_program_compiles_for_v5e(topo):
         "hist_fused_multi-3slots-968x256x1.18M-ragged",
         "hist_fused_multi-8slots-lanes-968x256x1.18M-ragged",
         "hist_fused_multi-16slots-lanes-968x256x1.18M-ragged",
-        "kmeans_ell_chain-d512-4M", "dense16_loop-24M",
+        "kmeans_ell_chain-d512-4M", "kmeans_ell_chunk_stats-d512-1M",
+        "dense16_loop-24M",
         "lbfgs_margin-16.8Mx39-1M", "lbfgs_grad-16.8Mx39-1M", "mesh_kmeans_step",
         "ring-64KB", "ring-4MB", "ring-64MB"])
 def test_compiles_for_v5e(topo, build):

@@ -24,6 +24,17 @@ TIERS = {
 }
 
 
+V5E_BYTES = 16909336064      # what the chip tool's v5e reports
+V5E_BUDGET = V5E_BYTES - (V5E_BYTES >> 3)
+
+
+@pytest.fixture(autouse=True)
+def v5e_budget(monkeypatch):
+    """The fused ELL tier asks the device what it holds (over that a
+    shard streams), and a CPU passed off for a TPU reports nothing."""
+    monkeypatch.setattr(kmeans, "_dense16_budget", lambda: V5E_BUDGET)
+
+
 @pytest.fixture
 def table():
     program.reset()
@@ -280,3 +291,45 @@ def test_restaging_the_same_host_arrays_stages_the_same_shard(
     for a, b in zip(host_arrays(one), host_arrays(two), strict=True):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(a, b)
+
+
+# --------------------------- (f) the tier rule at the benchmark's shapes
+def kmeans_cells():
+    """(cell, rows, dim, nnz, compute_dtype, tier) of every cell of
+    BENCHMARK.json whose configuration is k-means', by its file; and
+    the two shapes of tools/big_kmeans.py."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    manifest = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    files = {c["name"]: c["file"] for c in manifest["configs"]}
+    out = [("big_kmeans-sparse", 50_000_000, 512, 32, "float32", "ell_fused"),
+           ("big_kmeans-dense", 24_117_248, 256, 32, "bfloat16", "dense16")]
+    for w in manifest["workloads"]:
+        cfg = json.load(open(os.path.join(root, files[w["config"]])))
+        if cfg["learner"].startswith("kmeans"):
+            out.append((w["name"], cfg["rows_per_chip"], cfg["dim"],
+                        cfg["nnz"], cfg["compute_dtype"], cfg["tier"]))
+    return out
+
+
+@pytest.mark.parametrize("cell,n,dim,nnz,dtype,tier", kmeans_cells(),
+                         ids=[c[0] for c in kmeans_cells()])
+def test_a_cells_shard_lands_in_the_tier_its_configuration_names(
+        monkeypatch, cell, n, dim, nnz, dtype, tier):
+    """At the budget a v5e reports, by the rule alone (nothing staged):
+    the shards that were resident before the streamed tier still are,
+    and the one that is over the budget streams."""
+    monkeypatch.setattr(kmeans, "on_tpu", lambda: True)
+    assert kmeans._tier(n, nnz, dim, kmeans.DENSIFY_BUDGET_BYTES,
+                        dtype) == tier
+    whole = n * (nnz * 8 + 4)
+    budget = kmeans._stream_budget()
+    assert abs(budget - V5E_BYTES * 15 // 16) < 16      # 15/16 of the chip
+    assert (whole > budget) == (tier == "ell_stream")
+    if tier == "ell_stream":
+        # what the rule keeps resident beside the ring
+        chunk = kmeans._STAGE_CHUNK_ROWS * (nnz * 8 + 4)
+        assert (budget - kmeans._STREAM_RING * chunk) // chunk == 55
+

@@ -226,6 +226,9 @@ def test_prepare_shard_ell_fused_path(monkeypatch):
     valid = np.ones(n, np.float32)
 
     monkeypatch.setattr(_jax, "default_backend", lambda: "tpu")
+    # the fused ELL tier asks what the device holds (over that a shard
+    # streams), and a CPU passed off for a TPU reports nothing: a v5e's
+    monkeypatch.setattr(km, "_dense16_budget", lambda: 14795669056)
     shard = km.prepare_shard(idx, val, valid, d, budget=0)
     assert shard[0] == "ell_fused"
     di, dv, dvl, d_pad, nnz_p = shard[2]

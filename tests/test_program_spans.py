@@ -397,13 +397,16 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
     versions = rabit_tpu.version_number()
     assert s["learn.versions"] == s["learn.step.n"] == versions
     assert s["learn.iterations"] == per_version * versions == 8
+    # a resident shard: every row of every iteration, none over the link
+    assert s["learn.rows"] % s["learn.iterations"] == 0 < s["learn.rows"]
+    assert not {k for k in s if k.startswith(("stream.", "learn.stream"))}
     assert s["commit.n"] == versions
     for name in STAGE:
         assert s[name + ".n"] == 1
     # every counter of the table has a reader (PERF.md section 3)
     assert {k for k in s if "." in k and k.split(".")[-1] not in
             COLUMNS} <= {
-        "learn.iterations", "learn.versions", "learn.ahead",
+        "learn.iterations", "learn.versions", "learn.rows", "learn.ahead",
         "learn.ahead_discarded", "learn.device_updates",
         "allreduce.programs_built",
         "compile.seconds", "compile.misses", "compile.hits",

@@ -14,6 +14,15 @@ Two shapes, mirroring the reference's workloads:
   dense    ~24M rows x 256 features, bf16, device-chained iterations
            (the bench.py path) — 12.3 GB resident, ~77% of HBM
 
+Above "biggest that fits" (PR 46): a sparse shard over the budget the
+chip reports is not refused any more.  `run()` keeps resident as many
+chunks of 2^20 rows as fit and streams the rest from host memory under
+the kernel every iteration (`prepare_shard`'s tier `ell_stream`), so
+`sparse --points 75497472` (19.3 GB of slots) runs here too, an
+iteration a commit; the benchmark's cell of that shape is
+`kmeans-sparse-stream-x1` (PERF.md section 4).  `--chain` needs the
+whole shard on the device and is refused for such a shard.
+
 Timing: sparse mode takes the median gap between the per-iteration
 checkpoint calls inside ONE run (in-run timestamps are immune to the
 multi-GB staging variance); dense mode difference-times two chained
