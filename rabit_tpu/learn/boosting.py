@@ -268,11 +268,11 @@ def _replay(shard, model, max_depth: int) -> None:
         if model.tree_method == "approx":
             shard.rebin(model.tree_cuts[t])
         slots, leaves = [0] * groups, [[] for _ in trees]
-        for _depth in range(max_depth):
+        for depth in range(max_depth):
             if all(nid < 0 for nid in slots):
                 break
             tabs, slots = _route_round(trees, slots, leaves)
-            shard.partition(tabs)
+            shard.partition(tabs, depth)
         shard.leaf(_round_leaf_values(trees, slots, leaves, max_depth))
 
 
@@ -366,7 +366,7 @@ class _HostShard:
             built = [jnp.concatenate(built)]
         return built[0], order, calls
 
-    def partition(self, tabs: np.ndarray) -> None:
+    def partition(self, tabs: np.ndarray, depth: int) -> None:
         for k, tab in enumerate(tabs):
             node = self.node[k]
             live = node >= 0
@@ -377,6 +377,11 @@ class _HostShard:
             self.node[k] = np.where(live, np.where(leaf < 0, leaf,
                                                    2 * node + 1 - left),
                                     node)
+
+    def partition_rows(self, depth: int) -> tuple[int, int]:
+        """A bin a row and tree, in feature rows, and the trees' bins
+        whole."""
+        return self.trees, self.trees * self.bins.shape[1]
 
     def leaf(self, vals: np.ndarray) -> None:
         width = 1 << self.max_depth
@@ -401,6 +406,150 @@ def _lookup(table, idx, width: int):
     for k in range(width):
         out = jnp.where(idx == k, table[k], out)
     return out
+
+
+# feature rows a one-row slice of the staged bins costs: the array is
+# laid out in tiles of 8 rows by 128 columns and a row comes with its
+# tile.  Timed on a v5e (tools/partition_check.py; the table is in
+# PERF.md section 5): a slice takes what 8.4 to 9.1 rows' bytes take at
+# the HBM's rate, at all three boosting shapes; at 8 * K * W = fpad a
+# round of one tree is 12% faster sliced and one of seven 11% faster
+# whole (it joins the seven trees' ids), a millisecond of a round of a
+# second either way
+_SLICE_ROWS = 8
+# level nodes a chain of the sliced move takes: the compiler fuses a
+# chain of 16 nodes' selects with their slices into one pass, and cuts a
+# chain of 32 in two with the nodes' scalars written out as arrays of n
+_MOVE_CHAIN_NODES = 16
+
+
+def _move_slices(trees: int, width: int, fpad: int) -> bool:
+    """Whether the row move of a level ``width`` nodes wide of ``trees``
+    trees reads the feature rows its splits name (:func:`_move_sliced`)
+    or passes over the staged ``(fpad, n)`` bins whole
+    (:func:`_move_whole`): from the shapes alone, whatever the learner.
+    It slices where its ``trees * width`` slices read less than the
+    array."""
+    return _SLICE_ROWS * trees * width < fpad
+
+
+def _node_numbers(tab):
+    """A level's tables ``([K,] W, 4)`` (:func:`_route`'s rows) as what
+    a row of node ``j`` is compared with and moved to, each
+    ``([K,] W)``: the split's feature row, its threshold, whether an
+    absent bin goes left, and where a row goes that goes left and that
+    does not: children ``2j`` and ``2j + 1``, or of a node that stays a
+    leaf its code both ways."""
+    import jax.numpy as jnp
+
+    feat, thr, dleft, leaf = (tab[..., c] for c in range(4))
+    first = 2 * jnp.arange(tab.shape[-2], dtype=jnp.int32)
+    return (feat, thr, dleft != 0, jnp.where(leaf < 0, leaf, first),
+            jnp.where(leaf < 0, leaf, first + 1))
+
+
+def _moved(b, node, j: int, thr, dleft, lo, hi, missing_code: int, out):
+    """``out`` with the rows of level node ``j`` moved on by their bin
+    ``b`` of its split feature (:func:`_node_numbers`, of that node)."""
+    import jax.numpy as jnp
+
+    left = jnp.where(b == missing_code, dleft, b <= thr)
+    return jnp.where(node == j, jnp.where(left, lo, hi), out)
+
+
+def _move_sliced(bins_t, node, tab, missing_code: int):
+    """A level's row move that reads the feature rows its splits name:
+    ``(K, n)`` node ids under ``(K, W, 4)`` tables (a round of one tree:
+    ``(n,)`` under ``(W, 4)``), of tree ``k`` and level node ``j`` the
+    one row of ``bins_t`` its split names, a dynamic slice of n bins,
+    compared under the one mask ``node[k] == j`` with the node's own
+    threshold, default direction and leaf code.  A table entry of no
+    node names row 0 and matches no row; a dead row (``node < 0``)
+    keeps its code, as would an id the level has no slot for."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    if node.ndim == 1:
+        return _move_sliced(bins_t, node[None], tab[None],
+                            missing_code)[0]
+    feat, *numbers = _node_numbers(tab)
+    width, outs = tab.shape[1], []
+    for k in range(node.shape[0]):
+        # a tree's ids and a feature's bins as arrays of one row: the
+        # compiler takes a chain over those in one pass
+        ids, out = node[k:k + 1], None
+        for first in range(0, width, _MOVE_CHAIN_NODES):
+            # a chain moves its own nodes' rows and keeps the others'
+            part = ids
+            for j in range(first, min(first + _MOVE_CHAIN_NODES, width)):
+                row = lax.dynamic_slice_in_dim(bins_t, feat[k, j], 1)
+                part = _moved(row, ids, j, *(v[k, j] for v in numbers),
+                              missing_code, part)
+            out = part if out is None else jnp.where(ids < first, out, part)
+        outs.append(out)
+    return jnp.concatenate(outs)
+
+
+def _move_whole(bins_t, node, tab, missing_code: int):
+    """The same move (:func:`_move_sliced`) by one pass over the whole
+    staged array for all K trees: each row's split feature from a chain
+    of W selects, its bin of that feature picked out of the ``fpad``
+    rows as they stream, then the W nodes' numbers as above, every step
+    on the trees' ids at once."""
+    import jax.numpy as jnp
+
+    feat_of, *numbers = _node_numbers(tab)
+    if node.ndim > 1:
+        # node j's numbers as a column, an entry a tree
+        feat_of, *numbers = (v.T[:, :, None] for v in (feat_of, *numbers))
+    width = tab.shape[-2]
+    feat = jnp.zeros_like(node)
+    for j in range(width):
+        feat = jnp.where(node == j, feat_of[j], feat)
+    rows_of = jnp.arange(bins_t.shape[0], dtype=jnp.int32)[:, None]
+    b = jnp.sum(jnp.where(feat[..., None, :] == rows_of, bins_t, 0), axis=-2)
+    out = node
+    for j in range(width):
+        out = _moved(b, node, j, *(v[j] for v in numbers), missing_code, out)
+    return out
+
+
+def _move(bins_t, node, tab, missing_code: int):
+    """A level's row move in the form its shapes give it
+    (:func:`_move_slices`): the node ids of the next level."""
+    trees = node.shape[0] if node.ndim > 1 else 1
+    slices = _move_slices(trees, tab.shape[-2], bins_t.shape[0])
+    return (_move_sliced if slices else _move_whole)(bins_t, node, tab,
+                                                     missing_code)
+
+
+def partition_program(n: int, fpad: int, trees: int, width: int,
+                      missing_code: int):
+    """Compiled ``gbdt_partition`` of one depth: from the staged
+    ``(fpad, n)`` bins, the round's node ids (``(trees, n)`` int32,
+    donated; ``(n,)`` of a round of one tree) and the level's tables
+    (``(trees, width, 4)``, a tree's ``(width, 4)``: :func:`_route`'s, as
+    wide as the level and no wider) to the next level's ids, every tree
+    of the round in the one program, in the form :func:`_move_slices`
+    gives these shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    key = ("partition", n, fpad, trees, width, missing_code,
+           jax.default_backend())
+    fn = _PROGRAMS.get(key)
+    if fn is None:
+        def gbdt_partition(bins_t, node, tab):
+            with jax.named_scope("gbdt/partition"):
+                return _move(bins_t, node, tab, missing_code)
+
+        sds = jax.ShapeDtypeStruct
+        lead = (trees,) if trees > 1 else ()
+        fn = _PROGRAMS[key] = jax.jit(
+            gbdt_partition, donate_argnums=(1,)).lower(
+            sds((fpad, n), jnp.int32), sds(lead + (n,), jnp.int32),
+            sds(lead + (width, 4), jnp.int32)).compile()
+    return fn
 
 
 def softprob_grad_program(n: int, num_class: int, sampled: bool = False):
@@ -434,24 +583,23 @@ def softprob_grad_program(n: int, num_class: int, sampled: bool = False):
     return fn
 
 
-def _forest(fn, trees: int, shared: int = 0):
+def _forest(fn, trees: int):
     """The program of a round's ``trees`` trees from ``fn``, the program
-    of one, traceable (the row move, the leaf update; a level's
-    histograms are ``histogram.level_hist``'s, which takes the trees
-    together).  Its first ``shared`` arguments are the round's (the
-    staged bins); every other has a leading tree axis, of which call
-    ``k`` of ``fn`` takes entry ``k``.  The calls' results are stacked
-    (a leading tree axis again) and all of them are one program: one
-    hand-over and one wait, whatever ``trees``.  A round of one tree is
-    ``fn`` itself, its arrays without the axis."""
+    of one, traceable: the leaf update (a level's histograms are
+    ``histogram.level_hist``'s and the row move
+    :func:`partition_program`'s, which both take the trees together).
+    Every argument has a leading tree axis, of which call ``k`` of
+    ``fn`` takes entry ``k``.  The calls' results are stacked (a leading
+    tree axis again) and all of them are one program: one hand-over and
+    one wait, whatever ``trees``.  A round of one tree is ``fn`` itself,
+    its arrays without the axis."""
     if trees == 1:
         return fn
     import jax
     import jax.numpy as jnp
 
     def forest(*args):
-        outs = [fn(*args[:shared], *(a[k] for a in args[shared:]))
-                for k in range(trees)]
+        outs = [fn(*(a[k] for a in args)) for k in range(trees)]
         return jax.tree.map(lambda *parts: jnp.stack(parts), *outs)
 
     forest.__name__ = fn.__name__
@@ -482,7 +630,6 @@ class _DeviceShard:
         self.trees = model.num_class            # trees a round
         # the leading axis of what is kept a tree of the round
         self.lead = (self.trees,) if self.trees > 1 else ()
-        self.half = 1 << max(max_depth - 1, 0)    # slots of the last level
         self.subsample, self.seed = subsample, seed
         self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
         self.approx = model.tree_method == "approx"
@@ -523,9 +670,11 @@ class _DeviceShard:
         ``level`` (by its number of build slots: 1 at the root, then one
         a node of the level above; an empty slot holds no row, so a tree
         that stops early runs the same programs; its kernel calls are
-        those ``histogram.level_calls`` counts), ``partition`` and
-        ``leaf``; and, where the level's histograms stay on the device,
-        ``scan`` by the level's number of slots.  Of a round of several
+        those ``histogram.level_calls`` counts), ``partition`` (by
+        depth: :func:`partition_program`, its tables the level's own
+        ``2^depth`` entries) and ``leaf``; and, where the level's
+        histograms stay on the device, ``scan`` by the level's number of
+        slots.  Of a round of several
         trees each is the round's: a level's program builds every
         tree's slots of that depth (``histogram.level_hist``: in one
         kernel call where the level is wide enough for the lane-wide
@@ -534,8 +683,10 @@ class _DeviceShard:
         children are slots ``2s`` and ``2s + 1`` across trees as within
         one), so that it, ``histogram.assemble_level`` and
         ``level_shortlist`` take a forest's level as a tree's of that
-        many slots; the row move and the leaf update are a tree's
-        program lifted over the trees (:func:`_forest`)."""
+        many slots; a depth's row move takes the trees' ids and tables
+        together and reads of the staged bins what that depth needs,
+        once for all of them (:func:`_move_slices`); the leaf update is
+        a tree's program lifted over the trees (:func:`_forest`)."""
         import jax
         import jax.numpy as jnp
 
@@ -553,7 +704,7 @@ class _DeviceShard:
                jax.default_backend(), scan_by, trees)
         if key in _PROGRAMS:
             return _PROGRAMS[key]
-        half, width = self.half, 1 << depth
+        width = 1 << depth
 
         def gbdt_grad(margin, labels, *keep):
             with jax.named_scope("gbdt/grad"):
@@ -589,20 +740,6 @@ class _DeviceShard:
                         totals=totals)
             return gbdt_level
 
-        def gbdt_partition(bins_t, node, tab):
-            with jax.named_scope("gbdt/partition"):
-                feat, thr, dleft, leaf = (
-                    _lookup(tab[:, c], node, half) for c in range(4))
-                # the bin of each row's own split feature: one pass over
-                # the staged array, no gather and no slice of it
-                rows_of = jnp.arange(bins_t.shape[0], dtype=jnp.int32)
-                b = jnp.sum(jnp.where(feat[None, :] == rows_of[:, None],
-                                      bins_t, 0), axis=0)
-                left = jnp.where(b == missing_code, dleft != 0, b <= thr)
-                child = 2 * node + 1 - left.astype(jnp.int32)
-                return jnp.where(node < 0, node,
-                                 jnp.where(leaf < 0, leaf, child))
-
         def gbdt_leaf(margin, node, vals):
             with jax.named_scope("gbdt/leaf"):
                 code = jnp.where(node >= 0, node, width - node - 1)
@@ -632,9 +769,9 @@ class _DeviceShard:
             "level": {p: build(level_of(p), bins, gh, rows_i,
                                sds(lead + (p,), jnp.int32))
                       for p in [1] + [1 << d for d in range(1, depth - 1)]},
-            "partition": build(_forest(gbdt_partition, trees, shared=1),
-                               bins, rows_i, sds(lead + (half, 4), jnp.int32),
-                               donate=(1,)),
+            "partition": {d: partition_program(
+                n, self.bins_t.shape[0], trees, 1 << d, missing_code)
+                for d in range(depth)},
             "leaf": build(_forest(gbdt_leaf, trees), rows_f, rows_i,
                           sds(lead + (2 * width,), jnp.float32),
                           donate=(0, 1)),
@@ -721,12 +858,17 @@ class _DeviceShard:
         program.enqueued(rows)
         return feats, rows
 
-    def partition(self, tabs: np.ndarray) -> None:
-        tabs = np.concatenate([tabs, np.zeros(
-            (self.trees, self.half - tabs.shape[1], 4), np.int32)], axis=1)
-        self.node = self.prog["partition"](
-            self.bins_t, self.node, tabs.reshape(self.lead + (self.half, 4)))
+    def partition(self, tabs: np.ndarray, depth: int) -> None:
+        self.node = self.prog["partition"][depth](
+            self.bins_t, self.node, tabs.reshape(self.lead + tabs.shape[1:]))
         program.enqueued(self.node)
+
+    def partition_rows(self, depth: int) -> tuple[int, int]:
+        """Feature rows of the staged bins the move at ``depth`` reads,
+        and what a pass over them whole a tree would."""
+        fpad = self.bins_t.shape[0]
+        sliced = _move_slices(self.trees, 1 << depth, fpad)
+        return (self.trees << depth if sliced else fpad), self.trees * fpad
 
     def leaf(self, vals: np.ndarray) -> None:
         self.margin, self.node = self.prog["leaf"](
@@ -1176,9 +1318,12 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                                 default_left += tree[nid].default_left
                         tabs, slots = _route_round(trees, slots, leaves)
                     with program.span("gbdt.partition"):
-                        shard.partition(tabs)
+                        shard.partition(tabs, depth)
                 live = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
+                read, whole = shard.partition_rows(depth)
+                program.count("gbdt.partition_rows_read", read)
+                program.count("gbdt.partition_rows_whole", whole)
                 program.count("gbdt.levels_device_scan", int(device_scan))
                 program.count("gbdt.levels_chunked",
                               int(calls[0] > num_class))

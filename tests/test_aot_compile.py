@@ -307,7 +307,7 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
     _DeviceShard`` builds them (8,388,608 rows of 54 columns staged as
     (56, n), 7 classes, depth 6): the softmax gradient, a level program
     a width holding every tree's slots (one lane-wide kernel call for
-    the seven trees at every width), the row move and the
+    the seven trees at every width), a row move a depth and the
     leaf update of the (7, n) node ids and margins in place, and the
     scans over 7 times the slots.
     The shapes are handed a described device here (steering in the
@@ -325,7 +325,7 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
         cuts=np.zeros((f, nbin - 1), np.float32), base_score=0.5,
         loss="softprob", num_class=k)
     shard.n, shard.f, shard.nbin, shard.max_depth = n, f, nbin, depth
-    shard.half, shard.trees, shard.lead = 1 << (depth - 1), k, (k,)
+    shard.trees, shard.lead = k, (k,)
     shard.subsample, shard.seed = 1.0, 0
     shard.use_pallas, shard.compute_dtype = True, None
     shard.scan_by, shard.has_missing = (1.0, 1.0), False
@@ -356,9 +356,11 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
     grad = fits(prog["grad"])
     assert grad.output_size_in_bytes == k * 2 * n * 4
     # (7, n) is laid out in tiles of 8 rows: the donated array is the
-    # output, at 8 rows' size
-    move = fits(prog["partition"])
-    assert move.alias_size_in_bytes == move.output_size_in_bytes == 8 * n * 4
+    # output, at 8 rows' size, of every depth's move
+    assert sorted(prog["partition"]) == list(range(depth))
+    for move in map(fits, prog["partition"].values()):
+        assert move.alias_size_in_bytes == move.output_size_in_bytes \
+            == 8 * n * 4
     leaf = fits(prog["leaf"])
     assert leaf.alias_size_in_bytes == 2 * 8 * n * 4
     assert leaf.output_size_in_bytes <= 2 * 8 * n * 4 + 4096   # the tuple
@@ -369,6 +371,52 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
     short = 2 * k * 32 * histogram.SHORTLIST * nbin * 4
     assert level + short <= widest.output_size_in_bytes \
         <= level + short + (1 << 20)
+
+
+@pytest.mark.parametrize("n,f,k,sliced", [
+    (8 << 20, 54, 7, []), (32 << 20, 28, 1, [0, 1]),
+    (1183747, 968, 1, [0, 1, 2, 3, 4, 5]), (1183747, 968, 7, [0, 1, 2, 3, 4]),
+], ids=["covtype-7x56x8m", "higgs-32x33m", "bosch-968x1183747",
+        "seven-trees-968x1183747"])
+def test_every_depths_row_move_compiles_for_v5e(topo, monkeypatch, n, f, k,
+                                                sliced):
+    """``boosting.partition_program`` of each of six depths at the three
+    boosting cells' shapes (and seven trees on the wide one's rows, the
+    one that slices several trees' rows): the donated node ids are the
+    output (a round of seven trees: in tiles of 8 rows; of one: n ids);
+    a depth that slices (``_move_slices``: where 8 rows a slice read
+    less than the staged array) holds one dynamic slice a tree and level
+    node and no temporary but the trees' ids; one that passes over the
+    array whole holds none, and the trees' split features and bins
+    beside the ids."""
+    from rabit_tpu.learn import boosting, histogram
+
+    nbin, depth = 256, 6
+    real = jax.ShapeDtypeStruct
+    s = SingleDeviceSharding(topo.devices[0])
+    monkeypatch.setattr(jax, "ShapeDtypeStruct",
+                        lambda shape, dtype: real(shape, dtype, sharding=s))
+    monkeypatch.setattr(boosting, "_PROGRAMS", {})
+    fpad = histogram.staged_features(f, nbin)
+    # (7, n) in tiles of 8 x 128, (n,) in tiles of 1,024
+    ids = (8 * 128 * -(-n // 128) if k > 1 else 1024 * -(-n // 1024)) * 4
+    assert [d for d in range(depth)
+            if boosting._move_slices(k, 1 << d, fpad)] == sliced
+    for d in range(depth):
+        move = boosting.partition_program(n, fpad, k, 1 << d, nbin)
+        m, text = move.memory_analysis(), move.as_text()
+        assert m.alias_size_in_bytes == m.output_size_in_bytes == ids, (d, m)
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        assert need <= V5E_BYTES_LIMIT // 2, (d, m)
+        assert m.argument_size_in_bytes >= fpad * n * 4
+        if d in sliced:
+            assert text.count(" dynamic-slice(") == k << d, d
+            assert m.temp_size_in_bytes <= 2 * ids + (1 << 20), (d, m)
+        else:
+            assert " dynamic-slice(" not in text, d
+            assert m.temp_size_in_bytes <= 2 * ids + (8 << 20), (d, m)
+        assert m.temp_size_in_bytes < fpad * n * 4 // 2
 
 
 def _dense16_loop(topo):

@@ -1161,3 +1161,177 @@ def test_approx_distributed_resume_and_xla_commit_the_undisturbed_forest(
                 np.testing.assert_array_equal(nodes[:, :6], calm[0][0][:, :6])
                 np.testing.assert_allclose(nodes, calm[0][0], rtol=1e-4,
                                            atol=1e-5)
+
+
+def _level_inputs(trees, depth, fpad, missing, n=257, nbin=16, seed=0):
+    """A level's move as the loop hands it over: ``(trees, n)`` node ids
+    with live rows in every slot, dead rows (``node < 0``: leaf codes of
+    levels above) among them, and ``_route``'s tables with splits, nodes
+    that stay leaves, slots of no node with no row in them (padding) and
+    one with rows in it (zeros all the same: an unsplit node)."""
+    rng = np.random.default_rng((seed, trees, depth, fpad, missing))
+    width = 1 << depth
+    bins = rng.integers(0, nbin, (n, fpad)).astype(np.int32)
+    if missing:
+        bins[rng.random((n, fpad)) < 0.3] = nbin            # the code
+    tabs = np.zeros((trees, width, 4), np.int32)
+    node = np.zeros((trees, n), np.int32)
+    for k in range(trees):
+        kind = rng.choice(4, width, p=[0.55, 0.2, 0.15, 0.1])
+        kind[0] = 0                                         # a split
+        leaves = 0
+        for s in range(width):
+            if kind[s] == 0:                                # a split
+                tabs[k, s] = (rng.integers(fpad), rng.integers(nbin),
+                              rng.integers(2), 0)
+            elif kind[s] == 1:                              # stays a leaf
+                leaves += 1
+                tabs[k, s, 3] = -leaves - 3
+        holds_rows = np.flatnonzero(kind != 2)              # 2: padding
+        node[k] = rng.choice(holds_rows, n)
+        node[k, rng.random(n) < 0.2] = -rng.integers(1, 4)  # dead rows
+    return bins, node, tabs
+
+
+def _formula_of_before(bins, node, tabs, missing_code):
+    """The row move as every arm made it before the device's was
+    compiled a depth, a tree at a time, in numpy."""
+    out = np.empty_like(node)
+    for k, tab in enumerate(tabs):
+        feat, thr, dleft, leaf = tab[np.clip(node[k], 0, len(tab) - 1)].T
+        b = bins[np.arange(bins.shape[0]), feat]
+        left = np.where(b == missing_code, dleft != 0, b <= thr)
+        child = 2 * node[k] + 1 - left.astype(np.int32)
+        out[k] = np.where(node[k] < 0, node[k],
+                          np.where(leaf < 0, leaf, child))
+    return out
+
+
+@pytest.mark.parametrize("form", ["sliced", "whole"])
+@pytest.mark.parametrize("missing", [True, False], ids=["nan", "dense"])
+@pytest.mark.parametrize("depth", range(6))
+@pytest.mark.parametrize("trees", [1, 7])
+def test_a_depths_move_equals_the_host_arms_and_the_formula_of_before(
+        trees, depth, missing, form):
+    """``partition_program`` of one depth, in the form its shapes give
+    it (a shard of few feature rows passes over them whole, one of many
+    slices the rows the level's splits name: no switch), against
+    ``_HostShard.partition`` and the formula written out: the same
+    int32 array, leaf codes, dead rows, unsplit and padding entries
+    included."""
+    import jax.numpy as jnp
+
+    nbin, width = 16, 1 << depth
+    fpad = 8 * trees * 32 + 8 if form == "sliced" else 7
+    assert boosting._move_slices(trees, width, fpad) == (form == "sliced")
+    bins, node, tabs = _level_inputs(trees, depth, fpad, missing, nbin=nbin)
+    n = bins.shape[0]
+    lead = (trees,) if trees > 1 else ()
+    got = boosting.partition_program(n, fpad, trees, width, nbin)(
+        jnp.asarray(np.ascontiguousarray(bins.T)),
+        jnp.asarray(node.reshape(lead + (n,))),
+        jnp.asarray(tabs.reshape(lead + (width, 4))))
+    assert got.dtype == jnp.int32 and got.shape == lead + (n,)
+    got = np.asarray(got).reshape(trees, n)
+    want = _formula_of_before(bins, node, tabs, nbin)
+    host = object.__new__(boosting._HostShard)
+    host.n, host.bins, host.node = n, bins, node.copy()
+    host.model = boosting.BoostedModel(
+        cuts=np.zeros((fpad, nbin - 1), np.float32))
+    host.partition(tabs, depth)
+    np.testing.assert_array_equal(host.node, want)
+    np.testing.assert_array_equal(got, want)
+    assert (want < 0).any() and (want >= 0).any()
+    assert missing == bool((bins == nbin).any())
+
+
+@pytest.mark.parametrize("kw,classes", [
+    ({"loss": "logistic"}, 1), ({"loss": "softprob", "num_class": 3}, 3),
+], ids=["one-tree", "three-trees"])
+def test_a_resumed_job_replays_onto_the_ids_and_margins_it_left(
+        arm, monkeypatch, kw, classes):
+    """``_replay`` moves the rows by the same programs a depth as the
+    round that grew the trees: before every leaf update the resumed
+    job's node ids, and after it its margins, are those of the job that
+    never stopped, bit for bit, and its last round grows the same
+    trees."""
+    X, y = _tabular(n=1500, missing=True)
+    if classes > 1:
+        y = (np.floor(3 * np.random.default_rng(1).random(len(y)))
+             ).astype(np.float32)
+    kw = dict(kw, max_depth=4, nbin=16, use_pallas=False)
+    seen = []
+    leaf = boosting._DeviceShard.leaf
+
+    def spy(self, vals):
+        ids = np.asarray(self.node)
+        leaf(self, vals)
+        seen.append((ids, np.asarray(self.margin)))
+
+    monkeypatch.setattr(boosting._DeviceShard, "leaf", spy)
+    arm("device")
+    straight = boosting.train(X, y, num_round=3, **kw)
+    never_stopped, seen[:] = list(seen), []
+    arm("device")
+    boosting.train(X, y, num_round=2, **kw)
+    del seen[:]
+    resumed = boosting.train(X, y, num_round=3, **kw)
+    assert _structure(resumed) == _structure(straight)
+    # two rounds replayed, one grown
+    assert len(seen) == len(never_stopped) == 3
+    for (ids, margin), (want_ids, want_margin) in zip(seen, never_stopped):
+        assert ids.shape == ((classes, 1500) if classes > 1 else (1500,))
+        assert (ids < 0).any() or (ids > 0).any()
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(margin, want_margin)
+
+
+@pytest.mark.parametrize("trees,fpad,sliced", [
+    (7, 56, []), (1, 32, [0, 1]), (1, 968, [0, 1, 2, 3, 4, 5]),
+    (7, 968, [0, 1, 2, 3, 4]),
+], ids=["covtype", "higgs", "bosch", "seven-trees-on-968"])
+def test_the_moves_form_follows_from_trees_level_nodes_and_feature_rows(
+        trees, fpad, sliced):
+    """The one rule (``_move_slices``): a level slices where its K * W
+    slices, a tile of 8 feature rows each, read less than the staged
+    array.  At the boosting cells' shapes: no depth of seven trees on 56
+    feature rows, the two narrowest of one tree on 32, every depth on
+    968."""
+    assert [d for d in range(6)
+            if boosting._move_slices(trees, 1 << d, fpad)] == sliced
+
+
+@pytest.mark.parametrize("which,kw,read,whole", [
+    ("device", {}, 1 + 2 + 32 + 32, 4 * 32),
+    ("device", {"loss": "softprob", "num_class": 3}, 3 + 3 * 32, 4 * 3 * 32),
+    ("host", {}, 4, 4 * 5),
+], ids=["device", "device-three-trees", "host"])
+def test_the_level_loop_counts_the_feature_rows_its_moves_read(
+        arm, which, kw, read, whole):
+    """``gbdt.partition_rows_read`` over ``gbdt.partition_rows_whole``,
+    a level of the loop at a time: on the device K * W rows where the
+    level slices (5 features staged as 32 rows: the two narrowest levels
+    of one tree, the root of three) and the staged rows once where it
+    does not, of K passes
+    over them; on the host a bin a row and tree of the K * f.  A
+    resume's replayed moves count nothing."""
+    from rabit_tpu.obs import program
+
+    X, y = _tabular(n=1200)
+    if kw:
+        y = np.floor(3 * np.random.default_rng(2).random(len(y))).astype(
+            np.float32)
+    kw = dict(kw, max_depth=4, nbin=16, use_pallas=False)
+    names = ("gbdt.partition_rows_read", "gbdt.partition_rows_whole",
+             "gbdt.levels")
+
+    def counted(rounds):
+        before = program.stats()
+        boosting.train(X, y, num_round=rounds, **kw)
+        after = program.stats()
+        return [after.get(k, 0) - before.get(k, 0) for k in names]
+
+    arm(which)
+    assert counted(1) == [read, whole, 4]
+    # the second job replays the first's round and grows one
+    assert counted(2) == [read, whole, 4]
