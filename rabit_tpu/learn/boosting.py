@@ -343,9 +343,10 @@ class _HostShard:
     def level(self, build, depth: int):
         """The histograms of the level slots ``build`` names (-1: none;
         tree-major, the level ``2^depth`` slots a tree), which, and the
-        kernel calls that took (``histogram.level_calls``' pair): a
-        tree's slots by its own gradients and node ids, one builder call
-        a tree that builds any."""
+        kernel calls that took (``histogram.level_calls``' pair, and the
+        features that shared a packed product: none, this arm has no
+        plan): a tree's slots by its own gradients and node ids, one
+        builder call a tree that builds any."""
         width = 1 << depth
         order = [s for s in build if s >= 0]
         built, calls = [], (0, 0)
@@ -364,7 +365,7 @@ class _HostShard:
             import jax.numpy as jnp
 
             built = [jnp.concatenate(built)]
-        return built[0], order, calls
+        return built[0], order, calls + (0,)
 
     def partition(self, tabs: np.ndarray, depth: int) -> None:
         for k, tab in enumerate(tabs):
@@ -633,12 +634,22 @@ class _DeviceShard:
         self.subsample, self.seed = subsample, seed
         self.use_pallas, self.compute_dtype = use_pallas, compute_dtype
         self.approx = model.tree_method == "approx"
+        # the features of a few codes that share a product of the
+        # lane-wide body: from the job's cuts, so none under "approx",
+        # whose cuts are every round's own, and none for a job none of
+        # whose levels takes the plan
+        self.pack = None
         if self.approx:
             self.values_t, self.bins_t, seen = histogram.stage_values(
                 values, nbin)
         else:
             self.bins_t, seen = histogram.stage_bins(values, model.cuts,
                                                      nbin)
+            from rabit_tpu.ops import histogram_kernel as hk
+
+            pack = hk.pack_plan(model.cuts)
+            if pack and any(map(self._level_packs, self._level_widths())):
+                self.pack = pack
         with program.span("stage.put"):
             self.labels = jax.device_put(np.asarray(labels, np.float32))
         self.any_nan, self.max_bin = (int(v) for v in np.asarray(seen))
@@ -660,10 +671,20 @@ class _DeviceShard:
                     rebin=histogram.rebin_program(
                         self.n, self.f, self.bins_t.shape[0],
                         self.model.cuts.shape[1]))
+        self.codes = (jnp.asarray(self.pack[1]),) if self.pack else ()
         self.margin = jnp.full(self.lead + (self.n,), self.model.base_score,
                                jnp.float32)
         self.node = jnp.zeros(self.lead + (self.n,), jnp.int32)
         _replay(self, self.model, self.max_depth)
+
+    def _level_widths(self) -> list[int]:
+        """Build slots a tree of the job's level programs: the root's
+        and one a node of the level above."""
+        return [1] + [1 << d for d in range(1, self.max_depth - 1)]
+
+    def _level_packs(self, per_tree: int) -> int:
+        return histogram.level_packs(per_tree, self.f, self.nbin,
+                                     self.use_pallas, self.trees)
 
     def _programs(self) -> dict:
         """The job's programs, compiled for its shapes: ``grad``,
@@ -681,7 +702,9 @@ class _DeviceShard:
         body, a call a tree and more where it is not) and hands them
         back tree-major, which is the numbering ``scan`` keeps (a slot's
         children are slots ``2s`` and ``2s + 1`` across trees as within
-        one), so that it, ``histogram.assemble_level`` and
+        one; with the job's ``pack`` plan, whose codes are the program's
+        last operand, the features of a few codes in one product of each
+        lane-wide call), so that it, ``histogram.assemble_level`` and
         ``level_shortlist`` take a forest's level as a tree's of that
         many slots; a depth's row move takes the trees' ids and tables
         together and reads of the staged bins what that depth needs,
@@ -699,9 +722,11 @@ class _DeviceShard:
         missing_code = self.model.cuts.shape[1] + 1
         use_pallas, cdt, scan_by = (self.use_pallas, self.compute_dtype,
                                     self.scan_by)
+        # the plan's indices and width are shapes; its codes an operand
+        plan = self.pack and self.pack[0]
         key = (n, f, self.bins_t.shape[0], nbin, totals, depth, loss, rate,
                sampled, missing_code, use_pallas, cdt, hk.hist_fused_multi,
-               jax.default_backend(), scan_by, trees)
+               jax.default_backend(), scan_by, trees, plan)
         if key in _PROGRAMS:
             return _PROGRAMS[key]
         width = 1 << depth
@@ -727,7 +752,7 @@ class _DeviceShard:
                     (node >= 0) & (node == _lookup(takes, above, nslots)),
                     above, -1)
 
-            def gbdt_level(bins_t, gh, node, takes):
+            def gbdt_level(bins_t, gh, node, takes, *codes):
                 # a round's trees at once: their kernel calls are
                 # level_hist's to share out
                 with jax.named_scope("gbdt/level"):
@@ -737,7 +762,8 @@ class _DeviceShard:
                     return histogram.level_hist(
                         bins_t, gh, slot, nslots, f, nbin,
                         use_pallas=use_pallas, compute_dtype=cdt,
-                        totals=totals)
+                        totals=totals,
+                        pack=(plan, codes[0]) if codes else None)
             return gbdt_level
 
         def gbdt_leaf(margin, node, vals):
@@ -762,13 +788,14 @@ class _DeviceShard:
             return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
 
         keep = (sds((n,), jnp.bool_),) if sampled else ()
+        codes = (sds(self.pack[1].shape, jnp.int32),) if plan else ()
         prog = {
             "grad": softprob_grad_program(n, trees, sampled)
             if loss == "softprob" else build(
                 gbdt_grad, rows_f, sds((n,), jnp.float32), *keep),
             "level": {p: build(level_of(p), bins, gh, rows_i,
-                               sds(lead + (p,), jnp.int32))
-                      for p in [1] + [1 << d for d in range(1, depth - 1)]},
+                               sds(lead + (p,), jnp.int32), *codes)
+                      for p in self._level_widths()},
             "partition": {d: partition_program(
                 n, self.bins_t.shape[0], trees, 1 << d, missing_code)
                 for d in range(depth)},
@@ -830,17 +857,23 @@ class _DeviceShard:
     def level(self, build, depth: int):
         """One program over the slots ``build`` names (tree-major, -1:
         none; as many a tree, built or not): the level's histograms on
-        the device, ``build``, and the kernel calls the program holds."""
+        the device, ``build``, and the kernel calls the program holds
+        (``histogram.level_calls``' pair, and the features that shared a
+        packed product in them: the plan's a call that takes it)."""
         per_tree = len(build) // self.trees
         calls = histogram.level_calls(per_tree, self.f, self.nbin,
                                       self.use_pallas, self.trees)
+        if self.pack:
+            calls += (len(self.pack[0].narrow) * self._level_packs(per_tree),)
+        else:
+            calls += (0,)
         # each tree's kernel calls match its rows' node ids, which are
         # slots of its own level of 2^depth
         takes = np.asarray(build, np.int32)
         takes = np.where(takes >= 0, takes & ((1 << depth) - 1), -1).astype(
             np.int32).reshape(self.lead + (per_tree,))
         local = self.prog["level"][per_tree](
-            self.bins_t, self.gh, self.node, takes)
+            self.bins_t, self.gh, self.node, takes, *self.codes)
         program.enqueued(local)
         return local, build, calls
 
@@ -1329,6 +1362,13 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                               int(calls[0] > num_class))
                 program.count("gbdt.kernel_calls", calls[0])
                 program.count("gbdt.kernel_calls_lane", calls[1])
+                # a feature's histograms a call, by the body that built
+                # them, and those that shared a packed product
+                program.count("gbdt.features_lane",
+                              calls[1] * values.shape[1])
+                program.count("gbdt.features_two_level",
+                              (calls[0] - calls[1]) * values.shape[1])
+                program.count("gbdt.features_packed", calls[2])
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
                 program.count("gbdt.hists_derived", live if depth else 0)

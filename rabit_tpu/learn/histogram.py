@@ -625,9 +625,24 @@ def level_calls(nslots: int, f: int, nbin: int,
     return calls, lane_calls
 
 
+def level_packs(nslots: int, f: int, nbin: int,
+                use_pallas: bool | None = None, trees: int = 1) -> int:
+    """Those of the level's lane-wide calls (:func:`level_calls`) that
+    take a job's ``ops.histogram_kernel.pack_plan`` and build its narrow
+    features in one shared product: the one-axis calls
+    (``lane_packs``)."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    return sum(use_pallas and lane
+               and hk.lane_packs(nbin, f, hk.call_lanes(nt, ns))
+               for _, nt, _, ns, lane in _level_chunks(nslots, f, nbin, trees))
+
+
 def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
                use_pallas: bool | None = None, compute_dtype=None,
-               totals: bool = False):
+               totals: bool = False, pack=None):
     """``(nslots, f, nbin, 2)`` histograms of one tree level, traceable:
     slot ``s`` holds the (grad, hess) sums of the rows whose ``node`` is
     ``s``; a slot with no row reads zeros, a row at no slot (node < 0)
@@ -657,7 +672,10 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
     exact products of the same rounded weights; the bodies differ in
     the order of those adds, so a level reads the same under both to
     float32 rounding (1e-5 of a channel's absolute mass), not bit for
-    bit."""
+    bit.  ``pack`` is the job's ``ops.histogram_kernel.pack_plan`` (the
+    plan and its codes, the codes traceable): a one-axis lane-wide call
+    then builds the features of a few codes in one shared product, and
+    the level reads the same bit for bit."""
     import jax.numpy as jnp
 
     from rabit_tpu.ops import histogram_kernel as hk
@@ -677,7 +695,7 @@ def level_hist(bins_t, gh, node, nslots: int, f: int, nbin: int,
             outs.append(hk.hist_fused_multi(
                 bins_t, gh[at], nbin,
                 node_of_row=node[at] - lo if lo else node[at],
-                nslots=ns, compute_dtype=cdt, features=f))
+                nslots=ns, compute_dtype=cdt, features=f, pack=pack))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
         # (trees * nslots * 2, f, nbin), tree-major, slot-major
         out = out.reshape(trees * nslots, 2, f, nbin).transpose(0, 2, 3, 1)

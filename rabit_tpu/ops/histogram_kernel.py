@@ -55,6 +55,27 @@ adds are the same adds in the same order, and a shard whose features
 fit one chunk has the one-axis call it always had.  The two bodies
 cross at 14 channels (``_LANE_CROSSING``, with the measured table).
 
+**The packed segment** (``_packed_product``; the lane-wide body's
+narrow features).  That body charges by the class: 256 rows of one-hot
+a feature whatever the feature holds, and a column of two values fills
+two of them.  Which codes a feature can take is written in its cuts
+(:func:`feature_codes`: 0 and the end of every run of equal cuts), so a
+second rule, from the cuts only (:func:`pack_plan`), names the features
+of at most ``_NARROW_CODES`` codes, and a one-axis lane-wide call builds
+them all in ONE product: feature ``k`` has rows ``[k * w, (k + 1) * w)``
+of a shared class axis, row ``k * w + i`` of the multi-hot operand being
+``bins == codes[k, i]``, no cross terms and nothing thrown away (an
+indicator column costs 4 rows where it cost 256).  The indices and
+``w`` are shapes of the program; the codes are a small runtime operand,
+so a job's cuts key no compile.  XLA scatters the segment's rows back to
+``(features, nbin, lanes)`` after the call, and everything downstream
+reads the layout it always read, bit for bit.  Its price is its rows':
+a call of 10 features and 44 narrow ones of 4 codes costs 11 products
+and 3 ms, 0.0352 s on 128 lanes over 8.4M rows where 54 products cost
+0.1585 (``_NARROW_CODES``, with the measured table).  A wide shard's
+chunked call and the two-level body build every feature's own product
+still.
+
 Like the kmeans kernel the weight operand is rounded to a compute
 dtype (default bf16; one-hots are exact in bf16).  Summing n values
 each with independent ~2^-9 relative rounding error gives a relative
@@ -138,6 +159,28 @@ _LANE_ACC_BYTES = _VMEM_LIMIT_BYTES // 3
 # feature again).  Both costs are linear in the features and the lines
 # cross at 13.8 channels: the same 14 serves.
 _LANE_CROSSING = 14
+# The most codes a feature may hold to be *narrow*: to share the packed
+# product of the lane-wide body (:func:`pack_plan`), in a segment as tall
+# as the next power of two.  Measured on a v5e (tools/hist_kernel_check.py
+# --cases packed, builder's chip run, PR 48; seconds a call, host clock
+# around the call with its scatter back), 8.4M rows x 54 features of 256
+# bins, seven trees, 10 features with a product of their own and 44 in
+# segments of w rows: 0.0339, 0.0350, 0.0367, 0.0407 at w = 2, 4, 8, 16
+# on 128 lanes and 0.0634, 0.0657, 0.0688, 0.0772 on 256, where the call
+# without a plan reads 0.1585 and 0.3095 and equals each of them bit for
+# bit.  That is 10 + 44 w / 256 products of the body's 2.88 ms (5.6 ms
+# on 256 lanes) and about 3 ms: a code costs a 256th of a product and
+# of its compares, so a segment pays as long as it is shorter than the
+# feature's own 256 rows.  What bounds the constant is the body's size,
+# 8 rows of the shared axis an unrolled tile (compiled in 2 to 3 s at
+# every w measured); past 16 nothing was measured.
+_NARROW_CODES = 16
+# the code that pads a narrow feature's list: no row holds it (the bins
+# end at ``nbin``, the absent code) and the scatter back drops it
+_NO_CODE = 1 << 30
+# rows of the shared class axis one product of the packed segment takes
+# (a wide feature's product is ``classes`` = 256 of them)
+_PACK_ROWS = 256
 # the weight operand's type where a caller names none (one-hots are
 # exact in it; every sum is accumulated in float32)
 DEFAULT_COMPUTE_DTYPE = jnp.bfloat16
@@ -201,6 +244,12 @@ def lane_rows(nslots: int) -> int:
     return _round_up(2 * nslots, _LANE_TREE_ROWS)
 
 
+def call_lanes(trees: int, nslots: int) -> int:
+    """Lanes of the lane-wide call that holds ``trees`` trees of
+    ``nslots`` slots: whole MXU widths of 128."""
+    return _round_up(trees * lane_rows(nslots), 128)
+
+
 class LevelPlan(NamedTuple):
     """How a tree level's channels go to the kernel: the body, and the
     trees and the level slots of each that one call builds."""
@@ -226,6 +275,64 @@ def level_plan(nbin: int, f: int, nslots: int, trees: int = 1) -> LevelPlan:
         slots = min(nslots, width // 2)
         return LevelPlan(True, min(trees, width // lane_rows(slots)), slots)
     return LevelPlan(False, 1, max(1, max_channels(nbin, f) // 2))
+
+
+class PackPlan(NamedTuple):
+    """Which features of a job share one product of the lane-wide body
+    (:func:`pack_plan`): their indices, ascending, and the rows of the
+    shared class axis each takes (a power of two)."""
+    narrow: tuple
+    width: int
+
+
+def feature_codes(cuts) -> list:
+    """For every feature of ``cuts (f, nbin - 1)`` the sorted codes its
+    present values can take under ``learn.histogram.apply_cuts`` (and
+    ``stage_bins``, which counts the cuts at or under a value): 0, and
+    for every cut the number of the feature's cuts at or under it, which
+    for sorted cuts is ``searchsorted(cuts[j], c, "right")``, the end of
+    the run of cuts equal to ``c``.  A value between two cuts takes the
+    lower one's code, so nothing else is reachable: at most
+    ``distinct cuts + 1`` codes (an indicator column's quantiles are
+    zeros and then ones: 2 to 4 codes of 256).  The absent code
+    (``nbin``) is no bin and in no list.  Host, numpy."""
+    import numpy as np
+
+    cuts = np.asarray(cuts, np.float32)
+    return [np.union1d([0], np.count_nonzero(
+        row[None, :] <= row[:, None], axis=1)).astype(np.int32)
+        for row in cuts]
+
+
+def pack_plan(cuts):
+    """The second rule, from the cuts only: ``(PackPlan, codes)`` of the
+    features whose reachable codes (:func:`feature_codes`) are at most
+    ``_NARROW_CODES`` (and at most half the bins: a segment as tall as
+    the feature's own product saves nothing), or None where there is
+    none.  ``codes`` is ``(narrow, width)`` int32, each narrow feature's
+    codes in ascending order and then ``_NO_CODE``: a runtime operand, so
+    that a job's cuts (a seed's) do not key a program, while the indices
+    and the width are shapes.  The cuts are broadcast, so every rank has the same plan."""
+    import numpy as np
+
+    held = feature_codes(cuts)
+    most = min(_NARROW_CODES, (np.shape(cuts)[1] + 1) // 2)
+    narrow = tuple(j for j, c in enumerate(held) if len(c) <= most)
+    if not narrow:
+        return None
+    width = _next_pow2(max(len(held[j]) for j in narrow))
+    codes = np.full((len(narrow), width), _NO_CODE, np.int32)
+    for k, j in enumerate(narrow):
+        codes[k, :len(held[j])] = held[j]
+    return PackPlan(narrow, width), codes
+
+
+def lane_packs(nbin: int, features: int, lanes: int) -> bool:
+    """Whether a lane-wide call of this shape takes a :class:`PackPlan`:
+    the one-axis call, every feature in one grid step.  A wide shard's
+    chunked call (:func:`lane_chunk`) builds every feature's own
+    product."""
+    return lane_chunk(nbin, features, lanes) >= features
 
 
 def plan(nbin: int, f: int):
@@ -310,8 +417,21 @@ def _hist_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
         lax.fori_loop(0, ngroups, group, None)
 
 
-def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
-                 classes: int, trees: int, tree_rows: int, groups: int = 0):
+def _runs(features) -> tuple:
+    """``(first, end)`` of every maximal run of consecutive indices in
+    the ascending ``features``."""
+    runs: list = []
+    for j in features:
+        if runs and runs[-1][1] == j:
+            runs[-1][1] = j + 1
+        else:
+            runs.append([j, j + 1])
+    return tuple(map(tuple, runs))
+
+
+def _lane_kernel(bins_t_ref, w_ref, node_ref, *rest,
+                 classes: int, trees: int, tree_rows: int, groups: int = 0,
+                 wide: tuple = (), narrow: tuple = (), width: int = 0):
     """One row block, channels on the MXU's lanes: each feature's plain
     one-hot ``(classes, block)`` against the level's masked weights
     ``(lanes, block)``, one NT product a feature whatever the level's
@@ -327,7 +447,25 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
     the masks are made here from the trees' ``(2, block)`` weights and
     node ids, once a block.  The three rules of :func:`_hist_kernel`
     hold: an absent entry's code matches no class (or one the caller
-    slices off), a row at node -1 no slot, a padded row weighs 0."""
+    slices off), a row at node -1 no slot, a padded row weighs 0.
+
+    With ``narrow`` (a :class:`PackPlan`'s, a one-axis call's) the refs
+    end ``codes, out, packed``: only the features of the runs ``wide``
+    build a product of their own (a narrow one's block of ``out`` stays
+    zero),
+    the same adds as without a plan, and the narrow ones share one.  Row
+    ``k * width + i`` of its ``(rows, block)`` multi-hot operand is
+    ``bins[narrow[k]] == codes[k, i]``, built a sublane tile of 8 rows
+    at a time: the bins of the tile's features (``8 // width`` of them,
+    joined by selects on the sublane index; one where ``width`` is 8 or
+    more) compared with the tile of the ``(rows, 1)`` code column, the
+    tiles joined on tile boundaries and cast once, as ``wm`` is.  No
+    relayout, and a bin's sum is the same products added in the same
+    order along the block as in a product of the feature's own."""
+    if narrow:
+        codes_ref, out_ref, packed_ref = rest
+    else:
+        out_ref, = rest
     i = pl.program_id(1 if groups else 0)
     block = w_ref.shape[2]
     features, _, lanes = out_ref.shape
@@ -352,16 +490,21 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
     @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
+        if narrow:
+            packed_ref[:] = jnp.zeros(packed_ref.shape, packed_ref.dtype)
 
     cls = lax.broadcasted_iota(jnp.int32, (classes, block), 0)
 
-    def some(start, count):
+    def product(operand):
+        return lax.dot_general(operand, wm, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+    def some(start, count, first=0):
         bt = bins_t_ref[pl.ds(start, _LANE_FEATURES), :]
-        for j in range(count):
-            onehot = (bt[j:j + 1, :] == cls).astype(cdt)
-            out_ref[start + j] += lax.dot_general(
-                onehot, wm, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=prec)
+        for j in range(first, count):
+            out_ref[start + j] += product(
+                (bt[j:j + 1, :] == cls).astype(cdt))
 
     def group(grp, carry):
         some(pl.multiple_of(grp * _LANE_FEATURES, _LANE_FEATURES),
@@ -370,7 +513,24 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
 
     # one body of _LANE_FEATURES products, looped: a copy a feature is
     # 28 to 54 of them, and PR 33 paid minutes for an unrolled wide body
-    whole, rest = divmod(features, _LANE_FEATURES)
+    whole, left = divmod(features, _LANE_FEATURES)
+    if narrow:
+        # a run of features with a product of their own: up to a group's
+        # boundary, then whole groups looped, then what is left
+        for first, end in wide:
+            start = first // _LANE_FEATURES * _LANE_FEATURES
+            head = min(end, _round_up(first, _LANE_FEATURES))
+            tail = max(head, end // _LANE_FEATURES * _LANE_FEATURES)
+            if first < head:
+                some(start, head - start, first - start)
+            if head < tail:
+                lax.fori_loop(head // _LANE_FEATURES, tail // _LANE_FEATURES,
+                              group, None)
+            if tail < end:
+                some(tail, end - tail)
+        _packed_product(bins_t_ref, codes_ref, packed_ref, product, narrow,
+                        width, cdt)
+        return
     if groups:
         # the last chunk's loop ends with the shard's groups: what its
         # blocks hold past them is never read (run whole, that loop cost
@@ -379,21 +539,63 @@ def _lane_kernel(bins_t_ref, w_ref, node_ref, out_ref, *,
                       group, None)
     elif whole:
         lax.fori_loop(0, whole, group, None)
-    if rest:
-        some(whole * _LANE_FEATURES, rest)
+    if left:
+        some(whole * _LANE_FEATURES, left)
+
+
+def _packed_product(bins_t_ref, codes_ref, packed_ref, product, narrow: tuple,
+                    width: int, cdt) -> None:
+    """The narrow features' one product of a row block (:func:`_lane_kernel`),
+    in pieces of ``_PACK_ROWS`` rows of the shared class axis."""
+    rows, block = packed_ref.shape[0], bins_t_ref.shape[1]
+    tile = 8                                    # int32 sublanes
+    sub = lax.broadcasted_iota(jnp.int32, (tile, block), 0)
+    used = len(narrow) * width
+
+    def bins_of(k):                             # (1, block)
+        j = narrow[min(k, len(narrow) - 1)]
+        return bins_t_ref[pl.ds(j, 1), :]
+
+    def tile_at(at):
+        """Rows ``[at, at + 8)`` of the multi-hot operand, float32."""
+        if at >= used:
+            return jnp.zeros((tile, block), jnp.float32)
+        if width >= tile:
+            held = bins_of(at // width)
+        else:
+            # rows [q * width, (q + 1) * width) of the tile are feature
+            # at // width + q's; past the last feature the codes match
+            # no row, whosever bins stand there
+            per = tile // width
+            held = bins_of(at // width + per - 1)
+            for q in range(per - 2, -1, -1):
+                held = jnp.where(sub < (q + 1) * width,
+                                 bins_of(at // width + q), held)
+        return (held == codes_ref[pl.ds(at, tile), :]).astype(jnp.float32)
+
+    for lo in range(0, rows, _PACK_ROWS):
+        hi = min(rows, lo + _PACK_ROWS)
+        multihot = jnp.concatenate(
+            [tile_at(at) for at in range(lo, hi, tile)], axis=0).astype(cdt)
+        packed_ref[pl.ds(lo, hi - lo), :] += product(multihot)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("nbin", "block", "interpret", "compute_dtype",
-                     "nslots", "lanes", "features"))
+                     "nslots", "lanes", "features", "pack"))
 def _hist_multi(bins_t, weights, node, nbin: int, block: int,
                 interpret: bool, compute_dtype, nslots: int,
-                lanes: int = 0, features: int = 0) -> jax.Array:
+                lanes: int = 0, features: int = 0, codes=None,
+                pack: PackPlan | None = None) -> jax.Array:
     """Both bodies' calls, so that the device operation has one name
     whichever body a call took: ``lanes`` > 0 is the lane-wide body over
     ``(trees, 2, n)`` weights and ``(trees, n)`` node ids, 0 the
-    two-level body over one tree's ``(2, n)`` and ``(n,)``."""
+    two-level body over one tree's ``(2, n)`` and ``(n,)``.  A one-axis
+    lane-wide call with ``pack`` and its ``codes`` (:func:`pack_plan`)
+    builds the narrow features in one shared product, and XLA scatters
+    its rows back to the ``(features, nbin)`` layout every caller
+    reads."""
     f, n = bins_t.shape
     if lanes:
         trees = weights.shape[0]
@@ -424,9 +626,31 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
         def part(*at):      # of grid indices (chunk, row block) or (row block,)
             return at[0] if len(at) > 1 else 0
 
+        out_specs = pl.BlockSpec((kept, classes, lanes),
+                                 lambda *at: (part(*at), 0, 0),
+                                 memory_space=pltpu.VMEM)
+        out_shape = jax.ShapeDtypeStruct((chunks * kept, classes, lanes),
+                                         jnp.float32)
+        packed = {}
+        if pack is not None:
+            # the shared class axis: a narrow feature's segment of
+            # ``width`` rows after another's, zero rows up to a multiple
+            # of 128; its codes a column, _NO_CODE on the rows of no code
+            used = len(pack.narrow) * pack.width
+            rows = _round_up(used, 128)
+            operands.append(jnp.pad(
+                codes.astype(jnp.int32).reshape(used, 1),
+                ((0, rows - used), (0, 0)), constant_values=_NO_CODE))
+            packed = dict(
+                narrow=pack.narrow, width=pack.width,
+                wide=_runs(sorted(set(range(features)) - set(pack.narrow))))
+            out_specs = [out_specs, pl.BlockSpec(
+                (rows, lanes), lambda i: (0, 0), memory_space=pltpu.VMEM)]
+            out_shape = [out_shape,
+                         jax.ShapeDtypeStruct((rows, lanes), jnp.float32)]
         raw = pl.pallas_call(
             functools.partial(_lane_kernel, classes=classes, trees=trees,
-                              tree_rows=tree_rows, groups=groups),
+                              tree_rows=tree_rows, groups=groups, **packed),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((held, block), lambda *at: (part(*at), at[-1]),
@@ -436,17 +660,25 @@ def _hist_multi(bins_t, weights, node, nbin: int, block: int,
                 pl.BlockSpec((trees, 2, block), lambda *at: (0, 0, at[-1]),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((trees, block), lambda *at: (0, at[-1]),
-                             memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((kept, classes, lanes),
-                                   lambda *at: (part(*at), 0, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((chunks * kept, classes, lanes),
-                                           jnp.float32),
+                             memory_space=pltpu.VMEM)] + [
+                pl.BlockSpec(op.shape, lambda i: (0, 0),
+                             memory_space=pltpu.VMEM)
+                for op in operands[3:]],
+            out_specs=out_specs,
+            out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * len(grid),
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(*operands)
+        if pack is not None:
+            # back to the layout everyone reads: segment row (k, i) is
+            # bin codes[k, i] of feature narrow[k], whose own block the
+            # kernel left zero; a padded code is out of range and dropped
+            raw, shared = raw
+            raw = raw.at[jnp.asarray(pack.narrow)[:, None], codes].set(
+                shared[:used].reshape(len(pack.narrow), pack.width, lanes),
+                mode="drop")
         # lane t * tree_rows + 2 * s + c -> channel (t, s, c); tiny, XLA
         out = raw[:features, :nbin, :trees * tree_rows].reshape(
             features, nbin, trees, tree_rows)[..., :2 * nslots]
@@ -525,7 +757,7 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                      interpret: bool | None = None,
                      compute_dtype=DEFAULT_COMPUTE_DTYPE,
                      node_of_row=None, nslots: int = 0,
-                     features: int | None = None) -> jax.Array:
+                     features: int | None = None, pack=None) -> jax.Array:
     """``(nslots * 2, f, nbin)`` histograms of a tree level in one pass
     over the bins, slot-major: channel ``s * 2 + c`` is weight row ``c``
     over the rows at node ``s``.
@@ -547,8 +779,10 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
     The body is the one :func:`level_plan` names for the shape, and a
     call holds what that plan's call holds: the lane-wide body the
     trees at once, the two-level body a tree of :func:`max_channels`
-    channels.  More, or no level at all (no node ids, weights that are
-    no (grad, hess) pairs), is a ``ValueError``:
+    channels.  ``pack`` is a job's :func:`pack_plan`, which a one-axis
+    lane-wide call takes and every other call leaves: the result is the
+    same, bit for bit.  More, or no level at all (no node ids, weights
+    that are no (grad, hess) pairs), is a ``ValueError``:
     ``learn.histogram.level_hist`` shares a level of any width out over
     such calls.  Both bodies round the weights to the compute dtype once
     and add exact products in float32; the order of those adds is each
@@ -581,11 +815,14 @@ def hist_fused_multi(bins_t, weights, nbin: int, block: int | None = None,
                 f"{trees} trees of {nslots} slots out of range of a "
                 f"lane-wide call: {plan.trees} trees of {plan.slots}")
         node = jnp.asarray(node_of_row)
+        lanes = call_lanes(trees, nslots)
+        packed = {}
+        if pack is not None and lane_packs(nbin, features, lanes):
+            packed = dict(pack=pack[0], codes=jnp.asarray(pack[1]))
         return _hist_multi(
             jnp.asarray(bins_t), weights if forest else weights[None],
             node if forest else node[None], nbin, block, interpret, cdt,
-            nslots=nslots, features=features,
-            lanes=_round_up(trees * lane_rows(nslots), 128))
+            nslots=nslots, features=features, lanes=lanes, **packed)
     if forest:
         return jnp.concatenate([
             hist_fused_multi(bins_t, weights[t], nbin, block, interpret,

@@ -301,18 +301,38 @@ def test_wide_level_scan_holds_a_level_and_hands_over_kilobytes_on_v5e(topo):
     assert m.temp_size_in_bytes <= 2 * level, m
 
 
+def _covtype_cuts(f=54, wide=10, nbin=256):
+    """Cuts of the multi-class cell's kind: ``wide`` continuous columns,
+    then indicator columns: all zeros (set in under 1/256 of the rows),
+    zeros then ones, and zeros, one interpolated quantile, ones."""
+    cuts = np.tile(np.linspace(-2, 2, nbin - 1, dtype=np.float32), (f, 1))
+    for j in range(wide, f):
+        ones = (0, 3, 40, 129, 250)[j % 5]
+        cuts[j] = 0.0
+        if ones:
+            cuts[j, -ones:] = 1.0
+            if j % 2:
+                cuts[j, -ones - 1] = 0.625
+    return cuts
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
 def test_forest_level_programs_of_seven_classes_compile_for_v5e(
-        topo, monkeypatch):
+        topo, monkeypatch, packed):
     """The multi-class boosting cell's programs as ``boosting.
     _DeviceShard`` builds them (8,388,608 rows of 54 columns staged as
     (56, n), 7 classes, depth 6): the softmax gradient, a level program
     a width holding every tree's slots (one lane-wide kernel call for
     the seven trees at every width), a row move a depth and the
     leaf update of the (7, n) node ids and margins in place, and the
-    scans over 7 times the slots.
+    scans over 7 times the slots.  ``packed``: with the pack plan of
+    cuts of the cell's kind (44 indicator columns of 2 to 4 codes share
+    one product: its codes the level programs' fifth operand, its
+    scatter back inside them, still one kernel call a level).
     The shapes are handed a described device here (steering in the
     test: the shard builds them from ``jax.ShapeDtypeStruct``)."""
     from rabit_tpu.learn import boosting, histogram
+    from rabit_tpu.ops import histogram_kernel as hk
 
     n, f, k, nbin, depth = 8 << 20, 54, 7, 256, 6
     real = jax.ShapeDtypeStruct
@@ -332,6 +352,11 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
     fpad = histogram.staged_features(f, nbin)
     shard.bins_t = real((fpad, n), jnp.int32)
     assert fpad == 56 and histogram.slots_per_call(nbin, fpad) == 8
+    shard.pack = hk.pack_plan(_covtype_cuts()) if packed else None
+    if packed:
+        plan, codes = shard.pack
+        assert plan == (tuple(range(10, 54)), 4) and codes.shape == (44, 4)
+        assert sorted({int((c < nbin).sum()) for c in codes}) == [2, 3, 4]
     prog = shard._programs()
 
     def fits(compiled):
@@ -348,7 +373,9 @@ def test_forest_level_programs_of_seven_classes_compile_for_v5e(
         calls = histogram.level_calls(p, f, nbin, True, k)
         assert calls == (1, 1)
         assert level.as_text().count("tpu_custom_call") == calls[0], p
+        assert histogram.level_packs(p, f, nbin, True, k) == 1
         m = fits(level)
+        assert m.argument_size_in_bytes >= packed * 44 * 4 * 4
         assert m.output_size_in_bytes == k * p * f * nbin * 2 * 4
         # beside the bins: the trees' (2, n) weight pairs, their bf16
         # operands and a second call's slot codes

@@ -5,6 +5,7 @@ a time and against the plain reference, the model of K output groups
 (margins, predict, an old checkpoint), and the jobs across ranks."""
 import os
 import pickle
+import re
 import sys
 
 import numpy as np
@@ -437,3 +438,146 @@ def test_world_three_ranks_commit_one_forest(tmp_path, engine):
     saved = _saved(tmp_path, engine, 3)
     for nodes in saved[1:]:
         np.testing.assert_array_equal(nodes, saved[0])
+
+
+# ----------------------------------------------------------------------
+# narrow features share a product of the lane-wide call (PR 48)
+# ----------------------------------------------------------------------
+def _indicator_rows(n=2400, seed=48):
+    """Three continuous columns and eleven indicator columns set in a
+    thousandth to nine tenths of the rows, the class from both kinds."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, 14)).astype(np.float32)
+    shares = np.geomspace(1e-3, 0.9, 11)
+    X[:, 3:] = rng.random((n, 11)) < shares
+    score = np.stack([np.sin(c + X[:, c % 3]) + X[:, 3 + c] * (1 + c % 2)
+                      for c in range(7)])
+    y = np.argmax(score + 0.3 * rng.standard_normal(score.shape),
+                  axis=0).astype(np.float32)
+    return X, y
+
+
+def _counted(names, job):
+    from rabit_tpu.obs import program
+
+    before = program.stats()
+    model = job()
+    after = program.stats()
+    return model, [after.get(k, 0) - before.get(k, 0) for k in names]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_forest_is_the_same_byte_for_byte_with_and_without_the_plan(
+        arm, monkeypatch, dtype):
+    """A job on rows with indicator columns commits the same forest with
+    the rule as with the rule answering "no feature is narrow": the
+    packed product adds the same numbers in the same order.  The
+    counters read 11 features of 14 packed in every lane-wide call with
+    it, none without."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    X, y = _indicator_rows()
+    kw = dict(num_round=2, max_depth=4, nbin=16, loss="softprob",
+              num_class=7, use_pallas=True, compute_dtype=dtype)
+    names = ("gbdt.features_packed", "gbdt.features_lane",
+             "gbdt.kernel_calls_lane", "gbdt.kernel_calls")
+    plans = []
+    rule = hk.pack_plan
+    monkeypatch.setattr(hk, "pack_plan",
+                        lambda cuts: plans.append(rule(cuts)) or plans[-1])
+    arm("device")
+    with_plan, counted = _counted(names, lambda: boosting.train(X, y, **kw))
+    plan, codes = plans[0]
+    assert plan.narrow == tuple(range(3, 14)) and plan.width == 4
+    assert codes.shape == (11, 4)
+    # two rounds of four levels, each one lane-wide call of seven trees
+    assert counted == [8 * 11, 8 * 14, 8, 8]
+
+    monkeypatch.setattr(hk, "pack_plan", lambda cuts: None)
+    arm("device")
+    without, counted = _counted(names, lambda: boosting.train(X, y, **kw))
+    assert counted == [0, 8 * 14, 8, 8]
+    assert pickle.dumps(with_plan) == pickle.dumps(without)
+    assert len(with_plan.trees) == 14
+    assert any(n.feature >= 3 for t in with_plan.trees for n in t)
+
+
+@pytest.mark.parametrize("which,kw,lane", [
+    ("device", {"loss": "softprob", "num_class": 7, "use_pallas": True}, 4),
+    ("device", {"tree_method": "approx", "use_pallas": True,
+                "max_depth": 5}, 1),
+    ("host", {"loss": "softprob", "num_class": 7, "use_pallas": True,
+              "max_depth": 5}, None),
+    ("device", {"loss": "softprob", "num_class": 7, "use_pallas": False}, 0),
+], ids=["continuous", "approx-on-indicator-rows", "host-arm", "xla-path"])
+def test_a_job_without_a_plan_counts_no_packed_feature(arm, which, kw, lane):
+    """A shard of continuous columns has no narrow feature; an ``approx``
+    job's cuts are every round's own, whatever the columns; the host arm
+    builds a tree a call and takes no plan; the XLA path has no kernel.
+    ``gbdt.features_lane`` and ``gbdt.features_two_level`` still count
+    what each body's calls built."""
+    names = ("gbdt.features_packed", "gbdt.features_lane",
+             "gbdt.kernel_calls_lane", "gbdt.features_two_level",
+             "gbdt.kernel_calls")
+    if "tree_method" in kw:
+        X, y = _indicator_rows()
+        y = (y > 2).astype(np.float32)
+    else:
+        X, y = _classes(n=1500, f=6, k=7)
+    kw = dict({"num_round": 1, "max_depth": 4, "nbin": 16}, **kw)
+    arm(which)
+    _, counted = _counted(names, lambda: boosting.train(X, y, **kw))
+    if lane is None:
+        # a call a tree that builds 7 slots or more of the widest level
+        lane = counted[2]
+        assert 0 < lane <= 7
+    assert counted[:3] == [0, lane * X.shape[1], lane]
+    # the other calls' features: the two-level body's (the whole of
+    # ``gbdt_packed_feature_pct`` is both bodies')
+    assert counted[3] == (counted[4] - lane) * X.shape[1]
+    assert (counted[4] > 0) == kw["use_pallas"]
+
+
+def _without_frames(text: str) -> str:
+    """A compiled program's text without the table of the stack frames
+    its operations name (the caller's lines are in it)."""
+    if "StackFrames" in text:
+        head, rest = text.split("\n", 1)
+        text = head + rest[rest.index("\n\n", rest.index("StackFrames")):]
+    return re.sub(r"stack_frame_id=\d+", "", text)
+
+
+def test_a_shard_without_a_plan_lowers_to_the_programs_of_before(
+        arm, monkeypatch):
+    """The level programs of a shard with no narrow feature, and of an
+    ``approx`` job on indicator rows, are those of a shard for which the
+    rule is never asked: the same lowered text, no operand more."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    def texts(X, y, **kw):
+        monkeypatch.setattr(boosting, "_PROGRAMS", {})
+        model = boosting.BoostedModel(
+            cuts=histogram.quantile_cuts(X, 16), base_score=0.5,
+            loss=kw.get("loss", "logistic"), num_class=kw.get("k", 1),
+            tree_method=kw.get("tree_method", "hist"))
+        shard = boosting._DeviceShard(X, y, model, 4, 16, 1.0, 0, True, None,
+                                      scan=(1.0, 1.0))
+        shard.start(False)
+        assert shard.pack is None and shard.codes == ()
+        return {p: _without_frames(fn.as_text())
+                for p, fn in shard.prog["level"].items()}
+
+    arm("device")
+    X, y = _classes(n=700, f=6, k=7)
+    Xi, yi = _indicator_rows(n=700)
+    got = [texts(X, y, loss="softprob", k=7),
+           texts(Xi, (yi > 2).astype(np.float32), tree_method="approx")]
+
+    def never(cuts):
+        raise AssertionError("the rule is not asked")
+
+    monkeypatch.setattr(hk, "pack_plan", lambda cuts: None)
+    assert got[0] == texts(X, y, loss="softprob", k=7)
+    monkeypatch.setattr(hk, "pack_plan", never)
+    assert got[1] == texts(Xi, (yi > 2).astype(np.float32),
+                           tree_method="approx")

@@ -797,6 +797,252 @@ def test_the_chip_check_of_the_two_bodies_rehearsed(monkeypatch):
     assert tool.main() == 2
 
 
+def test_the_chip_check_of_the_packed_body_rehearsed(monkeypatch):
+    """``tools/hist_kernel_check.py --cases packed`` at a tiny shape with
+    the kernels interpreted: the rule's own plan on cuts of indicator
+    columns (some of one reachable code) at 128 and 256 lanes and ragged,
+    plans of 2 to 16 codes by hand, each packed call equal to the
+    unpacked one bit for bit and to float64, then a timing line a width
+    of call."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools",
+                        "hist_kernel_check.py")
+    spec = importlib.util.spec_from_file_location("hist_kernel_check", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.setattr(tool, "SLICE_ROWS", 2048)
+    monkeypatch.setattr(tool, "CUT_ROWS", 4096)
+    lines = []
+    assert tool.run_packed((24, 20, 1 << 13, 7, 0), 43, True, lines.append)
+    rule = lines[0]
+    assert rule["narrow"] == 10 and rule["width"] in (2, 4)
+    assert min(rule["codes_a_feature"]) == 2    # 0, which no row holds, and 255
+    checks = [ln for ln in lines if "check" in ln]
+    assert len(checks) == 2 * (3 + 4) and all(ln["ok"] for ln in checks)
+    packed = [ln for ln in checks if ln["check"] == "packed_vs_unpacked"]
+    assert all(ln["equal_bitwise"] and ln["rows_at_no_node"] > 0
+               and ln["entries_absent"] > 0 for ln in packed)
+    assert [(ln["shape"], ln["lanes"]) for ln in packed] == [
+        ("rule", 128), ("rule", 256), ("rule-ragged", 128), ("width-2", 256),
+        ("width-4", 256), ("width-8", 256), ("width-16", 256)]
+    assert {"packed_equals_unpacked": True} in lines
+    timed = [ln for ln in lines if "timing" in ln]
+    assert [ln["lanes"] for ln in timed] == [128, 256]
+    assert all({"unpacked_s", "rule_s", "width_2_s", "width_16_s"} <= set(ln)
+               for ln in timed)
+
+
+# ----------------------------------------------------------------------
+# narrow features share one product of the lane-wide body (PR 48): the
+# rule from the cuts, and the packed call against the unpacked one
+# ----------------------------------------------------------------------
+def _packed_case(held, wide, n, trees, nslots, nbin=256, seed=48):
+    """A level over ``wide`` features of every bin and one narrow
+    feature a list of ``held`` (the codes it can take), absent entries
+    (code ``nbin``) in all of them and rows at node -1: the staged bins,
+    the plain ``(n, f)`` ones, weights, node ids and the plan with its
+    codes as ``pack_plan`` lays them out."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    rng = np.random.default_rng(seed + n + trees * nslots)
+    f = wide + len(held)
+    bins = rng.integers(0, nbin + 1, (n, f)).astype(np.int32)
+    width = hk._next_pow2(max(len(c) for c in held))
+    codes = np.full((len(held), width), hk._NO_CODE, np.int32)
+    # narrow features between the wide ones and after them
+    narrow = tuple(range(1, 1 + len(held))) if wide else tuple(range(f))
+    for k, (j, mine) in enumerate(zip(narrow, held)):
+        codes[k, :len(mine)] = sorted(mine)
+        bins[:, j] = np.asarray(sorted(mine) + [nbin])[
+            rng.integers(0, len(mine) + 1, n)]
+    gh = np.stack([rng.standard_normal((trees, n)),
+                   rng.random((trees, n))], axis=1).astype(np.float32)
+    node = rng.integers(-1, nslots, (trees, n)).astype(np.int32)
+    fpad = histogram.staged_features(f, nbin)
+    bins_t = jnp.zeros((fpad, n), jnp.int32).at[:f].set(bins.T)
+    return bins, bins_t, gh, node, (hk.PackPlan(narrow, width), codes)
+
+
+@pytest.mark.parametrize("held,wide,n,trees,nslots,dtype,totals", [
+    # a column of one code, of two, of w (4), codes that are not 0..w-1
+    ([[255]], 2, 2500, 7, 1, "bfloat16", False),
+    ([[0, 255], [7]], 2, 2500, 7, 1, "bfloat16", False),
+    ([[0, 85, 170, 255], [0, 200, 255], [3]], 2, 2500, 7, 1, "bfloat16",
+     False),
+    # 44 columns of at most 4 codes after 10 wide ones: 176 rows
+    ([[0, 255]] * 20 + [[0, 9, 255]] * 14 + [[0, 31, 200, 255]] * 10, 10,
+     1100, 7, 1, "bfloat16", False),
+    # ragged n, 256 lanes, totals
+    ([[0, 200, 255], [0, 255]], 3, 4097, 7, 16, "bfloat16", True),
+    # one tree, three; float32 operands
+    ([[0, 200, 255], [1, 2]], 2, 2100, 1, 16, "bfloat16", False),
+    ([[0, 200, 255], [1, 2]], 2, 2100, 3, 4, "float32", True),
+    ([[0, 200, 255], [1, 2]], 2, 700, 7, 16, "float32", False),
+    # segments of 8 and 16 rows (a feature a tile, two tiles), of 1 and 2
+    ([list(range(0, 256, 37)), [5, 6, 7, 8, 9]], 1, 2500, 7, 1, "bfloat16",
+     False),
+    ([list(range(0, 256, 17)), [0, 255]], 1, 2500, 7, 2, "bfloat16", False),
+    ([[255], [0], [17]], 0, 2500, 7, 1, "bfloat16", False),
+    ([[0, 255]] * 5, 1, 2500, 1, 8, "bfloat16", True),
+    # more rows than one product of the segment takes: 40 x 8 = 320
+    ([list(range(8))] * 40, 1, 600, 7, 1, "bfloat16", False),
+], ids=["one-code", "two-codes", "w-codes-not-0-to-w", "covtype-44-of-54",
+        "ragged-256-lanes-totals", "one-tree", "three-trees-f32",
+        "seven-trees-256-lanes-f32", "segments-of-8", "segments-of-16",
+        "segments-of-1-no-wide", "segments-of-2", "two-products"])
+def test_packed_level_equals_the_unpacked_one_bit_for_bit(
+        monkeypatch, held, wide, n, trees, nslots, dtype, totals):
+    """The lane-wide call with a pack plan in interpret mode against the
+    same call without one, bit for bit (a bin's sum is the same products
+    added in the same order along the block), and against numpy in
+    float64: absent entries, rows at node -1, codes no row holds."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    nbin = 256
+    bins, bins_t, gh, node, pack = _packed_case(held, wide, n, trees, nslots)
+    f = bins.shape[1]
+    monkeypatch.setattr(hk, "_LANE_CROSSING", 2)
+    assert histogram.level_calls(nslots, f, nbin, True, trees) == (1, 1)
+    assert histogram.level_packs(nslots, f, nbin, True, trees) == 1
+
+    def level(**kw):
+        return np.asarray(histogram.level_hist(
+            bins_t, jnp.asarray(gh), jnp.asarray(node), nslots, f, nbin,
+            use_pallas=True, compute_dtype=dtype, totals=totals, **kw))
+
+    got, plain = level(pack=pack), level()
+    assert got.shape == (trees * nslots, f + totals, nbin, 2)
+    np.testing.assert_array_equal(got, plain)
+    want = _float64_level(bins, gh, node, nslots, nbin, dtype)
+    mass = _float64_level(bins, np.abs(gh), node, nslots, nbin, dtype).sum(
+        axis=(1, 2), keepdims=True)
+    assert (np.abs(got[:, :f] - want) <= 1e-5 * mass).all()
+    # a narrow feature's mass sits on its codes alone
+    for k, j in enumerate(pack[0].narrow):
+        off = np.setdiff1d(np.arange(nbin), held[k])
+        assert not got[:, j, off].any() and got[:, j, held[k]].any()
+
+
+def test_a_chunked_lane_wide_call_leaves_the_plan(monkeypatch):
+    """A wide shard's call (features chunk by chunk on a second grid
+    axis) builds every feature's own product: the plan is left, the
+    level reads the same, and nothing counts as packed."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    held = [[0, 200, 255]] * 6
+    bins, bins_t, gh, node, pack = _packed_case(held, 13, 900, 1, 8)
+    f = bins.shape[1]
+    monkeypatch.setattr(hk, "lane_chunk", lambda nbin, f, lanes: 8)
+    jax.clear_caches()
+
+    def level(b, w, nd, **kw):
+        return histogram.level_hist(b, w, nd, 8, f, 256, use_pallas=True,
+                                    **kw)
+
+    args = (bins_t, jnp.asarray(gh), jnp.asarray(node))
+    assert not hk.lane_packs(256, f, 128)
+    assert histogram.level_packs(8, f, 256, True, 1) == 0
+    assert str(jax.make_jaxpr(level)(*args)) == str(jax.make_jaxpr(
+        lambda *a: level(*a, pack=pack))(*args))
+    np.testing.assert_array_equal(np.asarray(level(*args)),
+                                  np.asarray(level(*args, pack=pack)))
+    jax.clear_caches()
+
+
+def _cuts_of(kind, nbin=256, seed=7):
+    """Cuts of a few columns of one kind, as ``quantile_cuts`` makes
+    them from a sample."""
+    rng = np.random.default_rng(seed)
+    m = 5000
+    if kind == "continuous":
+        sample = rng.standard_normal((m, 3))
+    elif kind == "indicator":
+        sample = np.stack([rng.random(m) < p for p in
+                           (1e-4, 0.003, 0.02, 0.5, 0.97, 1.0)], axis=1)
+    elif kind == "levels":
+        sample = np.stack([rng.integers(0, k, m) * 0.25 for k in
+                           (3, 5, 9, 17, 33)], axis=1)
+    elif kind == "all-equal":
+        sample = np.full((m, 2), 3.5)
+    elif kind == "all-nan-column":
+        sample = np.stack([np.full(m, np.nan), rng.integers(0, 2, m)], axis=1)
+    else:                                       # duplicates: a heavy atom
+        sample = np.where(rng.random((m, 3)) < 0.7, 0.0,
+                          rng.standard_normal((m, 3)))
+    return histogram.quantile_cuts(sample.astype(np.float32), nbin)
+
+
+@pytest.mark.parametrize("kind", ["continuous", "indicator", "levels",
+                                  "all-equal", "all-nan-column",
+                                  "duplicates"])
+def test_reachable_codes_are_the_codes_apply_cuts_gives_around_every_cut(
+        kind):
+    """``feature_codes``: the codes values around every cut take under
+    ``apply_cuts`` and under the device's binning (the cut itself, a
+    value just under and just over it (no subnormal: the device flushes
+    those to zero), the midpoints, both infinities), no more and no
+    fewer; a NaN takes the absent code, which is in no list."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    nbin = 256
+    cuts = _cuts_of(kind)
+    held = hk.feature_codes(cuts)
+    around = []
+    for row in cuts:
+        mid = (row[1:] + row[:-1]) / 2
+        step = np.maximum(np.abs(row) * 1e-6, 1e-30)
+        around.append(np.concatenate([
+            row, row - step, row + step, mid,
+            [-np.inf, np.inf, 0.0, 1.0, np.nan]]).astype(np.float32))
+    values = np.stack(around, axis=1)                       # (draws, f)
+    bins = histogram.apply_cuts(values, cuts)
+    staged, _ = histogram.stage_bins(values, cuts, nbin)
+    np.testing.assert_array_equal(np.asarray(staged)[:len(cuts)].T, bins)
+    for j, mine in enumerate(held):
+        got = np.unique(bins[:, j])
+        assert got[-1] == nbin                  # the NaN's code
+        np.testing.assert_array_equal(got[:-1], mine)
+        assert len(mine) <= len(np.unique(cuts[j])) + 1
+    plan = hk.pack_plan(cuts)
+    assert hk._NARROW_CODES <= nbin // 2
+    narrow = [j for j, c in enumerate(held) if len(c) <= hk._NARROW_CODES]
+    if kind in ("continuous", "duplicates"):
+        # an atom of 70% leaves 77 cuts of its 255 distinct: not narrow
+        assert plan is None and not narrow
+    else:
+        assert plan[0].narrow == tuple(narrow)
+        assert plan[0].width == hk._next_pow2(
+            max(len(held[j]) for j in narrow))
+        assert plan[1].shape == (len(narrow), plan[0].width)
+        for k, j in enumerate(narrow):
+            assert plan[1][k].tolist() == held[j].tolist() + [hk._NO_CODE] * (
+                plan[0].width - len(held[j]))
+    if kind == "indicator":
+        # set in under 1/256 of the rows: every cut 0; in all: every cut 1
+        assert [c.tolist() for c in held][::5] == [[0, 255], [0, 255]]
+        assert max(len(c) for c in held) <= 4
+
+
+def test_the_plan_is_shapes_and_its_codes_an_operand():
+    """Two jobs whose cuts differ only in where the narrow features'
+    codes lie share a plan, so a compiled program; the codes differ."""
+    from rabit_tpu.ops import histogram_kernel as hk
+
+    a, b = _cuts_of("indicator", seed=1), _cuts_of("indicator", seed=2)
+    (plan_a, codes_a), (plan_b, codes_b) = hk.pack_plan(a), hk.pack_plan(b)
+    assert plan_a == plan_b and hash(plan_a) == hash(plan_b)
+    assert codes_a.dtype == np.int32 and not np.array_equal(codes_a, codes_b)
+
+
 # ----------------------------------------------------------------------
 # absent entries take no histogram slot: XGBoost's layout
 # ----------------------------------------------------------------------

@@ -22,6 +22,17 @@ both bodies from the same inputs and prints
 * then, unless ``--no-timing``, seconds a call of each body at 2 to 128
   channels of one tree at each row shape, and the forest's levels.
 
+``packed`` (PR 48) holds the lane-wide body with a ``pack_plan`` against
+the same body without one, BIT FOR BIT (a bin's sum is the same
+products added in the same order along the block): at the multi-class
+cell's shape with cuts of its kind (10 continuous columns, 44 indicator
+columns set in a ten-thousandth to nine tenths of the rows, so that
+some hold one reachable code, the rule ``hk.pack_plan`` deciding), at
+128 and 256 lanes, ragged, rows at node -1, absent entries, both
+against float64; then seconds a call of each, and of a packed call whose
+44 narrow features hold 2, 4, 8 and 16 codes (the table
+``_NARROW_CODES`` rests on).
+
 ``bosch`` is the wide boosting cell's shape (968 features, 1,183,747
 rows, ragged as they are, 81% of the entries absent station by station,
 8 and 16 slots): the lane-wide body there takes the features chunk by
@@ -30,6 +41,7 @@ chunk on a second grid axis (``lane_chunk``).
 Not on any cell's path.  Run it through the chip tool:
 
     chiprun --timeout 1800 -- python3 tools/hist_kernel_check.py
+    chiprun -- python3 tools/hist_kernel_check.py --cases packed
 
 It prints one JSON object a line and writes the same lines to
 ``chiprun_out/hist_kernel_check/report.jsonl``; exit code 1 if the
@@ -119,13 +131,15 @@ def make_rows(key, n: int, trees: int, nslots: int):
     return gh, jax.random.randint(kn, (trees, n), -1, nslots, jnp.int32)
 
 
-def lane(bins_t, gh, node, nslots: int, f: int):
-    """The lane-wide body whatever the rule says of the shape."""
+def lane(bins_t, gh, node, nslots: int, f: int, pack=None):
+    """The lane-wide body whatever the rule says of the shape; with
+    ``pack`` (a plan and its codes) the narrow features in one product."""
     trees, n = node.shape
+    packed = {} if pack is None else {"pack": pack[0],
+                                      "codes": jnp.asarray(pack[1])}
     return hk._hist_multi(bins_t, gh, node, NBIN, hk.default_block(n),
                           not hk.on_tpu(), CDT, nslots=nslots, features=f,
-                          lanes=hk._round_up(trees * hk.lane_rows(nslots),
-                                             128))
+                          lanes=hk.call_lanes(trees, nslots), **packed)
 
 
 def two_level(bins_t, gh, node, nslots: int, f: int):
@@ -272,6 +286,136 @@ def timing(name: str, key, fpad, f, n, trees, emit,
                   1, hk.max_channels(NBIN, f) // 2))})
 
 
+# the multi-class cell's columns: continuous ones first, then indicator
+# columns set in these shares of the rows (under 1/256: every cut is 0
+# and one code is reachable)
+PACKED_WIDE = 10
+PACKED_SHARES = np.geomspace(1e-4, 0.9, 44)
+PACKED_WIDTHS = (2, 4, 8, 16)
+CUT_ROWS = 1 << 16
+
+
+def indicator_cuts(seed: int, f: int) -> np.ndarray:
+    """Quantile cuts of a sample of the packed case's columns."""
+    from rabit_tpu.learn.histogram import quantile_cuts
+
+    rng = np.random.default_rng(seed)
+    sample = rng.standard_normal((CUT_ROWS, f)).astype(np.float32)
+    for j, share in enumerate(PACKED_SHARES[:f - PACKED_WIDE]):
+        sample[:, PACKED_WIDE + j] = rng.random(CUT_ROWS) < share
+    return quantile_cuts(sample, NBIN)
+
+
+def width_pack(f: int, width: int):
+    """A plan by hand: every column from ``PACKED_WIDE`` on holds
+    ``width`` codes spread over the bins."""
+    narrow = tuple(range(PACKED_WIDE, f))
+    codes = np.tile(np.linspace(0, NBIN - 1, width).astype(np.int32),
+                    (len(narrow), 1))
+    return hk.PackPlan(narrow, width), codes
+
+
+@functools.partial(jax.jit, static_argnames=("fpad", "n"))
+def coded_bins(seed, table, count, fpad: int, n: int):
+    """Bins a narrow feature of which holds its own codes and the absent
+    one, uniformly (``table (fpad, w + 1)``, ``count`` of them a
+    feature); a feature of ``count`` 0 holds 0..256 as ``make_bins``."""
+    row = lax.broadcasted_iota(jnp.uint32, (fpad, n), 1)
+    feat = lax.broadcasted_iota(jnp.uint32, (fpad, n), 0)
+    x = _mix(row * jnp.uint32(2654435761) + feat * jnp.uint32(40503)
+             + seed.astype(jnp.uint32))
+    pick = x % jnp.maximum(count, 1).astype(jnp.uint32)[:, None]
+    held = jnp.zeros((fpad, n), jnp.int32)
+    for i in range(table.shape[1]):
+        held = jnp.where(pick == i, table[:, i:i + 1], held)
+    return jnp.where(count[:, None] > 0, held,
+                     (x % jnp.uint32(NBIN + 1)).astype(jnp.int32))
+
+
+def pack_bins(seed, pack, fpad: int, n: int):
+    plan, codes = pack
+    table = np.full((fpad, plan.width + 1), NBIN, np.int32)
+    count = np.zeros((fpad,), np.int32)
+    for k, j in enumerate(plan.narrow):
+        mine = codes[k][codes[k] < NBIN]
+        table[j, :len(mine)] = mine
+        count[j] = len(mine) + 1                     # and the absent code
+    return coded_bins(seed, jnp.asarray(table), jnp.asarray(count), fpad, n)
+
+
+def compare_packed(name: str, key, pack, fpad, f, n, trees, nslots,
+                   emit) -> bool:
+    """The packed call against the unpacked one, bit for bit, and both
+    against float64 on the first 2^20 rows."""
+    bins_t = pack_bins(key[-1], pack, fpad, n)
+    gh, node = make_rows(key, n, trees, nslots)
+    a = np.asarray(lane(bins_t, gh, node, nslots, f, pack))
+    b = np.asarray(lane(bins_t, gh, node, nslots, f))
+    equal = bool(np.array_equal(a, b))
+    line = {"check": "packed_vs_unpacked", "shape": name, "rows": n,
+            "features": f, "narrow": len(pack[0].narrow),
+            "width": pack[0].width, "trees": trees, "slots": nslots,
+            "lanes": hk.call_lanes(trees, nslots), "equal_bitwise": equal,
+            "ok": equal,
+            "rows_at_no_node": int(np.asarray(jnp.sum(node < 0))),
+            "entries_absent": float(np.asarray(jnp.mean(bins_t[:f] == NBIN)))}
+    if not equal:
+        ch, feat, cls = (int(v[0]) for v in np.nonzero(a != b))
+        line["parts_at"] = {"channel": ch, "feature": feat, "bin": cls,
+                            "packed_reads": float(a[ch, feat, cls]),
+                            "unpacked_reads": float(b[ch, feat, cls])}
+    emit(line)
+    m = min(SLICE_ROWS, n)
+    sb, sg, sn = bins_t[:, :m], gh[:, :, :m], node[:, :m]
+    rel, at = worst(np.asarray(lane(sb, sg, sn, nslots, f, pack)),
+                    float64_hist(sb, sg, sn, nslots, f),
+                    np.asarray(masses(sg, sn, nslots)))
+    emit({"check": "packed_vs_float64", "shape": name, "rows": m,
+          "trees": trees, "slots": nslots, "max_rel_to_mass": rel, "at": at,
+          "ok": rel <= LIMIT})
+    return equal and rel <= LIMIT
+
+
+def run_packed(shape, seed: int, timed: bool, emit,
+               widths=PACKED_WIDTHS) -> bool:
+    """The rule's own plan at the multi-class cell's shape, then plans of
+    every width by hand; seconds a call of each beside the unpacked
+    one."""
+    fpad, f, n, trees, _ = shape
+    key = jax.random.PRNGKey(seed)
+    pack = hk.pack_plan(indicator_cuts(seed, f))
+    held = [int(np.count_nonzero(c < NBIN)) for c in pack[1]]
+    emit({"packed": "rule", "narrow": len(pack[0].narrow),
+          "width": pack[0].width, "codes_a_feature": held,
+          "narrow_codes": hk._NARROW_CODES})
+    ok = True
+    for nslots in (1, 16):                      # 128 and 256 lanes
+        ok &= compare_packed("rule", key, pack, fpad, f, n, trees, nslots,
+                             emit)
+    ok &= compare_packed("rule-ragged", key, pack, fpad, f, n // 8 - 77,
+                         trees, 8, emit)
+    packs = {w: width_pack(f, w) for w in widths}
+    for w, by_hand in packs.items():
+        ok &= compare_packed(f"width-{w}", key, by_hand, fpad, f,
+                             n // 8 - 77, trees, 16, emit)
+    emit({"packed_equals_unpacked": bool(ok)})
+    if ok and timed:
+        bins_t = pack_bins(key[-1], pack, fpad, n)
+        for nslots in (1, 16):
+            gh, node = make_rows(key, n, trees, nslots)
+            line = {"timing": "packed", "rows": n, "features": f,
+                    "trees": trees, "slots": nslots,
+                    "lanes": hk.call_lanes(trees, nslots),
+                    "unpacked_s": seconds(lane, bins_t, gh, node, nslots, f),
+                    "rule_s": seconds(lane, bins_t, gh, node, nslots, f,
+                                      pack)}
+            for w, by_hand in packs.items():
+                line[f"width_{w}_s"] = seconds(lane, bins_t, gh, node,
+                                               nslots, f, by_hand)
+            emit(line)
+    return ok
+
+
 def run(shapes: dict, seed: int, timed: bool, emit) -> bool:
     key = jax.random.PRNGKey(seed)
     ok = True
@@ -297,6 +441,9 @@ def main() -> int:
     ap.add_argument("--shapes", default="higgs,covtype,bosch")
     ap.add_argument("--seed", type=int, default=43)
     ap.add_argument("--no-timing", action="store_true")
+    ap.add_argument("--cases", default="bodies,packed",
+                    help="bodies: lane-wide against two-level; packed: "
+                    "the lane-wide body with a pack plan against without")
     args = ap.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -313,9 +460,15 @@ def main() -> int:
             report.flush()
 
         emit({"device": device.device_kind, "seed": args.seed,
-              "crossing": hk._LANE_CROSSING})
-        ok = run({name: SHAPES[name] for name in args.shapes.split(",")},
-                 args.seed, not args.no_timing, emit)
+              "crossing": hk._LANE_CROSSING, "cases": args.cases})
+        ok, cases = True, args.cases.split(",")
+        if "packed" in cases:
+            ok &= run_packed(SHAPES["covtype"], args.seed,
+                             not args.no_timing, emit)
+        if "bodies" in cases:
+            ok &= run({name: SHAPES[name]
+                       for name in args.shapes.split(",")},
+                      args.seed, not args.no_timing, emit)
     return 0 if ok else 1
 
 
