@@ -35,7 +35,7 @@ import numpy as np
 import rabit_tpu
 from rabit_tpu import engine as _engine_mod
 from rabit_tpu.learn import histogram
-from rabit_tpu.learn.data import fetch
+from rabit_tpu.learn.data import EllRows, SparseMat, ell_rows, fetch
 from rabit_tpu.obs import program
 from rabit_tpu.ops import MAX, SUM, on_tpu
 from rabit_tpu.utils.checks import check
@@ -87,12 +87,20 @@ class BoostedModel:
     # trees, tree ``t * num_class + k`` is round t's for class k.  A
     # model committed before the field existed reads 1
     num_class: int = 1
+    # a forest grown on sparse rows: its bins are a flat space, column
+    # ``j``'s cuts ``cuts[cut_ptr[j]:cut_ptr[j + 1]]`` of a 1-D ``cuts``
+    # (``histogram.FlatBins``), and a row is routed by its value against
+    # the node's float ``split``, an absent entry the default way
+    cut_ptr: np.ndarray | None = None
 
-    def _tree_margin(self, tree: list[TreeNode], bins: np.ndarray,
+    def _tree_margin(self, tree: list[TreeNode], bins,
                      by_value: bool = False) -> np.ndarray:
         """One tree's leaf weight a row, routed by ``bins`` or, with
-        ``by_value``, by the float values in their place."""
-        missing_code = self.cuts.shape[1] + 1
+        ``by_value``, by the float values in their place (NaN: absent),
+        or by the entries of sparse rows (``data.EllRows``)."""
+        sparse = isinstance(bins, EllRows)
+        held = bins.present() if sparse else None
+        missing_code = None if by_value or sparse else self.cuts.shape[1] + 1
         node = np.zeros(bins.shape[0], np.int32)
         out = np.zeros(bins.shape[0], np.float32)
         live = np.ones(bins.shape[0], bool)
@@ -107,9 +115,17 @@ class BoostedModel:
                     out[rows] = n.value
                     live[rows] = False
                 else:
-                    b = bins[rows, n.feature]
-                    absent, below = (np.isnan(b), b < n.split) if by_value \
-                        else (b == missing_code, b <= n.bin_threshold)
+                    if sparse:
+                        # the row's entry of the split's column, if any
+                        hit = held[rows] & (bins.indices[rows] == n.feature)
+                        b = bins.values[rows][np.arange(len(hit)),
+                                              hit.argmax(axis=1)]
+                        absent, below = ~hit.any(axis=1), b < n.split
+                    else:
+                        b = bins[rows, n.feature]
+                        absent, below = (np.isnan(b), b < n.split) \
+                            if by_value else (b == missing_code,
+                                              b <= n.bin_threshold)
                     go_left = np.where(
                         absent, getattr(n, "default_left", True), below)
                     idx = np.flatnonzero(rows)
@@ -117,8 +133,7 @@ class BoostedModel:
                     node[idx[~go_left]] = n.right
         return out
 
-    def margin(self, bins: np.ndarray, by_value: bool = False
-               ) -> np.ndarray:
+    def margin(self, bins, by_value: bool = False) -> np.ndarray:
         """``(n,)`` margins, or ``(num_class, n)`` of a model of several
         output groups."""
         groups = self.num_class
@@ -128,8 +143,14 @@ class BoostedModel:
                 tree, bins, by_value)
         return out if groups > 1 else out[0]
 
-    def predict(self, values: np.ndarray) -> np.ndarray:
-        if self.tree_method == "approx":
+    def predict(self, values) -> np.ndarray:
+        if self.cut_ptr is not None:
+            # sparse rows by their entries; the same rows dense, NaN
+            # where absent, by value: the same walk
+            m = self.margin(np.asarray(values, np.float32), by_value=True) \
+                if isinstance(values, np.ndarray) \
+                else self.margin(ell_rows(values))
+        elif self.tree_method == "approx":
             m = self.margin(np.asarray(values, np.float32), by_value=True)
         else:
             m = self.margin(apply_cuts(values, self.cuts))
@@ -175,9 +196,12 @@ def cut_sample(values: np.ndarray) -> np.ndarray:
     """The strided sample of a shard that defines its cuts under
     ``tree_method="hist"``: every ``n // CUT_SAMPLE_ROWS``-th row, at
     most ``CUT_SAMPLE_ROWS`` of them.  (``"approx"`` samples nothing:
-    its sketch covers every row.)"""
-    return values[::max(1, values.shape[0] // CUT_SAMPLE_ROWS)][
-        :CUT_SAMPLE_ROWS]
+    its sketch covers every row.)  Of sparse rows (``data.EllRows``)
+    the same rows."""
+    stride = slice(None, None, max(1, values.shape[0] // CUT_SAMPLE_ROWS))
+    if isinstance(values, EllRows):
+        return values.rows(stride).rows(slice(CUT_SAMPLE_ROWS))
+    return values[stride][:CUT_SAMPLE_ROWS]
 
 
 def _keep_rows(seed: int, round_idx: int, n: int, subsample: float):
@@ -249,8 +273,9 @@ def _round_leaf_values(trees, slots, leaves, max_depth: int) -> np.ndarray:
                      max_depth) for k, tree in enumerate(trees)])
 
 
-def _fill_splits(tree: list[TreeNode], cuts: np.ndarray) -> None:
-    """Every split's float value from the cuts the tree was grown on."""
+def _fill_splits(tree: list[TreeNode], cuts) -> None:
+    """Every split's float value from the cuts the tree was grown on
+    (``(f, nbin - 1)``, or a flat bin space's ``histogram.FlatBins``)."""
     for node in tree:
         if node.feature >= 0:
             node.split = float(cuts[node.feature, node.bin_threshold])
@@ -584,6 +609,56 @@ def softprob_grad_program(n: int, num_class: int, sampled: bool = False):
     return fn
 
 
+def _slots_of(node, takes, nslots: int):
+    """The build slot of every row of a tree's level, traceable: build
+    slot p takes the rows of level slot ``takes[p]``, a child of the
+    node in slot p of the level above (the root: slot 0 of both); a row
+    of the sibling, of an unsplit node or of a leaf is in no slot."""
+    import jax.numpy as jnp
+
+    above = node >> 1
+    return jnp.where((node >= 0) & (node == _lookup(takes, above, nslots)),
+                     above, -1)
+
+
+def _level_slots(node, takes, nslots: int):
+    """:func:`_slots_of` of a round's trees: ``(n,)`` node ids under
+    ``(nslots,)`` takes, or ``(K, n)`` under ``(K, nslots)``."""
+    import jax.numpy as jnp
+
+    if node.ndim == 1:
+        return _slots_of(node, takes, nslots)
+    return jnp.stack([_slots_of(node[k], takes[k], nslots)
+                      for k in range(node.shape[0])])
+
+
+def _move_entries(cells_t, node, tab):
+    """A level's row move of sparse rows: the split's column looked for
+    among the row's entries.  ``cells_t`` is the ``(width, n)`` int32
+    cells of the rows' entries (-1: none), ``node`` the ``(n,)`` node
+    ids (``(K, n)`` of a round of K trees) and ``tab`` the level's
+    ``([K,] W, 5)`` table, of node ``j`` the cells of its split's column
+    ``[lo, hi)``, the last cell that goes left, whether a row without an
+    entry there goes left, and the node's leaf code (< 0) where it
+    stays a leaf.  A row has at most one entry a column: it goes left if
+    that one lies at or under the cut, the default way if it has none.
+    Dead rows and ids the level has no slot for keep their code, as in
+    :func:`_move_sliced`."""
+    import jax.numpy as jnp
+
+    if node.ndim > 1:
+        return jnp.stack([_move_entries(cells_t, node[k], tab[k])
+                          for k in range(node.shape[0])])
+    width = tab.shape[0]
+    lo, cut, hi, dleft, leaf = (_lookup(tab[:, c], node, width)
+                                for c in range(5))
+    here = (cells_t >= lo) & (cells_t < hi)
+    left = jnp.where(jnp.any(here, axis=0),
+                     jnp.any(here & (cells_t <= cut), axis=0), dleft != 0)
+    return jnp.where((node >= 0) & (node < width),
+                     jnp.where(leaf < 0, leaf, 2 * node + 1 - left), node)
+
+
 def _forest(fn, trees: int):
     """The program of a round's ``trees`` trees from ``fn``, the program
     of one, traceable: the leaf update (a level's histograms are
@@ -607,6 +682,13 @@ def _forest(fn, trees: int):
     return forest
 
 
+def _build(fn, *shapes, donate=()):
+    """``fn`` compiled for ``shapes``."""
+    import jax
+
+    return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
+
+
 class _DeviceShard:
     """One rank's rows on the device for the whole job: staged bins,
     labels, margin, (grad, hess) and the node of every row (of a model
@@ -623,10 +705,7 @@ class _DeviceShard:
 
     def __init__(self, values, labels, model, max_depth, nbin, subsample,
                  seed, use_pallas, compute_dtype, scan=None):
-        import jax
-
         self.scan_by, self.above = scan, None
-        self.n, self.f = values.shape
         self.model, self.max_depth, self.nbin = model, max_depth, nbin
         self.trees = model.num_class            # trees a round
         # the leading axis of what is kept a tree of the round
@@ -639,15 +718,22 @@ class _DeviceShard:
         # whose cuts are every round's own, and none for a job none of
         # whose levels takes the plan
         self.pack = None
+        self._stage(values, labels)
+
+    def _stage(self, values, labels) -> None:
+        """The shard on the device, in this class's layout."""
+        import jax
+
+        self.n, self.f = values.shape
         if self.approx:
             self.values_t, self.bins_t, seen = histogram.stage_values(
-                values, nbin)
+                values, self.nbin)
         else:
-            self.bins_t, seen = histogram.stage_bins(values, model.cuts,
-                                                     nbin)
+            self.bins_t, seen = histogram.stage_bins(values, self.model.cuts,
+                                                     self.nbin)
             from rabit_tpu.ops import histogram_kernel as hk
 
-            pack = hk.pack_plan(model.cuts)
+            pack = hk.pack_plan(self.model.cuts)
             if pack and any(map(self._level_packs, self._level_widths())):
                 self.pack = pack
         with program.span("stage.put"):
@@ -686,6 +772,19 @@ class _DeviceShard:
         return histogram.level_packs(per_tree, self.f, self.nbin,
                                      self.use_pallas, self.trees)
 
+    @property
+    def sampled(self) -> bool:
+        """Whether the gradient program takes a mask of the rows kept."""
+        return self.subsample < 1.0
+
+    def _layout_key(self) -> tuple:
+        """What of the rows' layout the job's programs are compiled
+        for."""
+        from rabit_tpu.ops import histogram_kernel as hk
+
+        return (self.f, self.bins_t.shape[0], self.model.cuts.shape[1] + 1,
+                hk.hist_fused_multi, self.pack and self.pack[0])
+
     def _programs(self) -> dict:
         """The job's programs, compiled for its shapes: ``grad``,
         ``level`` (by its number of build slots: 1 at the root, then one
@@ -709,24 +808,18 @@ class _DeviceShard:
         many slots; a depth's row move takes the trees' ids and tables
         together and reads of the staged bins what that depth needs,
         once for all of them (:func:`_move_slices`); the leaf update is
-        a tree's program lifted over the trees (:func:`_forest`)."""
+        a tree's program lifted over the trees (:func:`_forest`).  What
+        depends on the rows' layout is the shard class's own:
+        ``_level_program``, ``_partition_program``, ``_scan_programs``."""
         import jax
         import jax.numpy as jnp
 
-        from rabit_tpu.ops import histogram_kernel as hk
-
-        n, f, nbin, depth = self.n, self.f, self.nbin, self.max_depth
+        n, depth = self.n, self.max_depth
         loss, rate = self.model.loss, self.model.learning_rate
-        trees, lead = self.trees, self.lead
-        sampled, totals = self.subsample < 1.0, self.has_missing
-        missing_code = self.model.cuts.shape[1] + 1
-        use_pallas, cdt, scan_by = (self.use_pallas, self.compute_dtype,
-                                    self.scan_by)
-        # the plan's indices and width are shapes; its codes an operand
-        plan = self.pack and self.pack[0]
-        key = (n, f, self.bins_t.shape[0], nbin, totals, depth, loss, rate,
-               sampled, missing_code, use_pallas, cdt, hk.hist_fused_multi,
-               jax.default_backend(), scan_by, trees, plan)
+        trees, lead, sampled = self.trees, self.lead, self.sampled
+        key = (n, self.nbin, self.has_missing, depth, loss, rate, sampled,
+               self.use_pallas, self.compute_dtype, jax.default_backend(),
+               self.scan_by, trees) + self._layout_key()
         if key in _PROGRAMS:
             return _PROGRAMS[key]
         width = 1 << depth
@@ -741,93 +834,113 @@ class _DeviceShard:
                 gh = jnp.stack([g, h])
                 return jnp.where(keep[0], gh, 0.0) if keep else gh
 
-        def level_of(nslots: int):
-            def slots_of(node, takes):
-                # build slot p takes the rows of level slot takes[p], a
-                # child of the node in slot p of the level above (the
-                # root: slot 0 of both); a row of the sibling, of an
-                # unsplit node or of a leaf is in no slot
-                above = node >> 1
-                return jnp.where(
-                    (node >= 0) & (node == _lookup(takes, above, nslots)),
-                    above, -1)
-
-            def gbdt_level(bins_t, gh, node, takes, *codes):
-                # a round's trees at once: their kernel calls are
-                # level_hist's to share out
-                with jax.named_scope("gbdt/level"):
-                    slot = slots_of(node, takes) if trees == 1 else \
-                        jnp.stack([slots_of(node[k], takes[k])
-                                   for k in range(trees)])
-                    return histogram.level_hist(
-                        bins_t, gh, slot, nslots, f, nbin,
-                        use_pallas=use_pallas, compute_dtype=cdt,
-                        totals=totals,
-                        pack=(plan, codes[0]) if codes else None)
-            return gbdt_level
-
         def gbdt_leaf(margin, node, vals):
             with jax.named_scope("gbdt/leaf"):
                 code = jnp.where(node >= 0, node, width - node - 1)
                 return (margin + rate * _lookup(vals, code, 2 * width),
                         jnp.zeros_like(node))
 
-        def gbdt_scan(built, above=None, build=None):
-            with jax.named_scope("gbdt/scan"):
-                level = histogram.assemble_level(built, above, build)
-                return (level,) + histogram.level_shortlist(
-                    level, f, *scan_by, totals)
-
         sds = jax.ShapeDtypeStruct
         rows_f = sds(lead + (n,), jnp.float32)
         rows_i = sds(lead + (n,), jnp.int32)
-        bins = sds(self.bins_t.shape, jnp.int32)
-        gh = sds(lead + (2, n), jnp.float32)
-
-        def build(fn, *shapes, donate=()):
-            return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
-
         keep = (sds((n,), jnp.bool_),) if sampled else ()
-        codes = (sds(self.pack[1].shape, jnp.int32),) if plan else ()
         prog = {
             "grad": softprob_grad_program(n, trees, sampled)
-            if loss == "softprob" else build(
+            if loss == "softprob" else _build(
                 gbdt_grad, rows_f, sds((n,), jnp.float32), *keep),
-            "level": {p: build(level_of(p), bins, gh, rows_i,
-                               sds(lead + (p,), jnp.int32), *codes)
+            "level": {p: self._level_program(p)
                       for p in self._level_widths()},
-            "partition": {d: partition_program(
-                n, self.bins_t.shape[0], trees, 1 << d, missing_code)
-                for d in range(depth)},
-            "leaf": build(_forest(gbdt_leaf, trees), rows_f, rows_i,
-                          sds(lead + (2 * width,), jnp.float32),
-                          donate=(0, 1)),
+            "partition": {d: self._partition_program(d)
+                          for d in range(depth)},
+            "leaf": _build(_forest(gbdt_leaf, trees), rows_f, rows_i,
+                           sds(lead + (2 * width,), jnp.float32),
+                           donate=(0, 1)),
         }
-        if scan_by is not None:
-            def hists(*shape):
-                return sds(shape, jnp.float32)
-
-            # the root's level is what was built; below, a level of 2p
-            # slots (a tree) comes from its p built slots and the p
-            # slots above
-            rows = f + totals
-            prog["scan"] = {1: build(gbdt_scan, hists(trees, rows, nbin, 2))}
-            for p in (trees << d for d in range(depth - 1)):
-                prog["scan"][2 * p // trees] = build(
-                    gbdt_scan, hists(p, rows, nbin, 2),
-                    hists(2, p, rows, nbin), sds((p,), jnp.int32))
+        if self.scan_by is not None:
+            prog["scan"] = self._scan_programs()
         _PROGRAMS[key] = prog
         return prog
 
+    def _level_program(self, nslots: int):
+        """The level program of ``nslots`` build slots a tree."""
+        import jax
+        import jax.numpy as jnp
+
+        f, nbin = self.f, self.nbin
+        use_pallas, cdt, totals = (self.use_pallas, self.compute_dtype,
+                                   self.has_missing)
+        # the plan's indices and width are shapes; its codes an operand
+        plan = self.pack and self.pack[0]
+
+        def gbdt_level(bins_t, gh, node, takes, *codes):
+            # a round's trees at once: their kernel calls are
+            # level_hist's to share out
+            with jax.named_scope("gbdt/level"):
+                return histogram.level_hist(
+                    bins_t, gh, _level_slots(node, takes, nslots), nslots,
+                    f, nbin,
+                    use_pallas=use_pallas, compute_dtype=cdt,
+                    totals=totals,
+                    pack=(plan, codes[0]) if codes else None)
+
+        sds = jax.ShapeDtypeStruct
+        codes = (sds(self.pack[1].shape, jnp.int32),) if plan else ()
+        return _build(gbdt_level, sds(self.bins_t.shape, jnp.int32),
+                      sds(self.lead + (2, self.n), jnp.float32),
+                      sds(self.lead + (self.n,), jnp.int32),
+                      sds(self.lead + (nslots,), jnp.int32), *codes)
+
+    def _partition_program(self, depth: int):
+        return partition_program(self.n, self.bins_t.shape[0], self.trees,
+                                 1 << depth, self.model.cuts.shape[1] + 1)
+
+    def _scan_spec(self):
+        """``gbdt_scan`` of this layout: how a level is ranked, and the
+        shapes a built slot and a slot of the level above have."""
+        f, totals, scan_by = self.f, self.has_missing, self.scan_by
+        rows = (f + totals, self.nbin)
+        return (lambda level: histogram.level_shortlist(
+            level, f, *scan_by, totals)), rows + (2,), rows
+
+    def _scan_programs(self) -> dict:
+        """``gbdt_scan`` by the level's slots a tree: the root's level
+        is what was built; below, a level of 2p slots (a tree) comes
+        from its p built slots and the p slots above."""
+        import jax
+        import jax.numpy as jnp
+
+        rank, slot, slot_above = self._scan_spec()
+        # a flat slot is assembled as a rectangle of one row
+        flat = len(slot) == len(slot_above)
+
+        def gbdt_scan(built, above=None, build=None):
+            with jax.named_scope("gbdt/scan"):
+                level = histogram.assemble_level(
+                    built[:, None] if flat else built, above, build)
+                return (level,) + rank(level)
+
+        def hists(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+        trees = self.trees
+        scan = {1: _build(gbdt_scan, hists(trees, *slot))}
+        for p in (trees << d for d in range(self.max_depth - 1)):
+            scan[2 * p // trees] = _build(
+                gbdt_scan, hists(p, *slot), hists(2, p, *slot_above),
+                jax.ShapeDtypeStruct((p,), jnp.int32))
+        return scan
+
+    def _keep(self, round_idx: int) -> np.ndarray:
+        return _keep_rows(self.seed, round_idx, self.n, self.subsample)
+
     def grad_hess(self, round_idx: int) -> None:
         keep = ()
-        if self.subsample < 1.0:
+        if self.sampled:
             # the one host array of length n a round: the sample is
             # numpy's, so that both arms draw the same rows
             import jax
 
-            keep = (jax.device_put(_keep_rows(
-                self.seed, round_idx, self.n, self.subsample)),)
+            keep = (jax.device_put(self._keep(round_idx)),)
         self.gh = self.prog["grad"](self.margin, self.labels, *keep)
         program.enqueued(self.gh)
 
@@ -867,15 +980,19 @@ class _DeviceShard:
             calls += (len(self.pack[0].narrow) * self._level_packs(per_tree),)
         else:
             calls += (0,)
-        # each tree's kernel calls match its rows' node ids, which are
-        # slots of its own level of 2^depth
-        takes = np.asarray(build, np.int32)
-        takes = np.where(takes >= 0, takes & ((1 << depth) - 1), -1).astype(
-            np.int32).reshape(self.lead + (per_tree,))
         local = self.prog["level"][per_tree](
-            self.bins_t, self.gh, self.node, takes, *self.codes)
+            self.bins_t, self.gh, self.node, self._takes(build, depth),
+            *self.codes)
         program.enqueued(local)
         return local, build, calls
+
+    def _takes(self, build, depth: int) -> np.ndarray:
+        """``build`` as the level programs take it: each tree's kernel
+        calls match its rows' node ids, which are slots of its own level
+        of ``2^depth``."""
+        takes = np.asarray(build, np.int32)
+        return np.where(takes >= 0, takes & ((1 << depth) - 1), -1).astype(
+            np.int32).reshape(self.lead + (len(build) // self.trees,))
 
     def scan(self, reduced, build, depth: int):
         """The shortlist of every slot of the level at ``depth`` as
@@ -907,6 +1024,131 @@ class _DeviceShard:
         self.margin, self.node = self.prog["leaf"](
             self.margin, self.node, vals.reshape(self.lead + vals.shape[1:]))
         program.enqueued(self.node)
+
+
+class _SparseShard(_DeviceShard):
+    """One rank's sparse rows (``data.EllRows``) as **entries**, a
+    present value its row and its cell of the job's flat bin space
+    (``histogram.FlatBins``), and never an array of rows by columns: the
+    ``(width, n)`` cells the row move searches for a split's column (an
+    absent entry goes the split's default way) and, on the kernel's
+    road, the same entries bucketed for ``ops.sparse_hist_kernel``
+    (``histogram.stage_entries``).  A level's histograms are ``(slots,
+    flat.size, 2)``, sized by the cuts the columns have; every other
+    per-row array, program and the loop's protocol are
+    :class:`_DeviceShard`'s, the rows padded to whole tiles with rows
+    that hold no entry and whose gradients are zeroed.  Off the chip the
+    same class runs the same programs with the level as XLA's
+    ``segment_sum`` over the entries: the host arm of a sparse job."""
+
+    def _stage(self, rows, labels) -> None:
+        import jax
+
+        self.rows, self.f = rows.shape
+        if self.use_pallas is None:
+            self.use_pallas = on_tpu()
+        self.flat = histogram.FlatBins(self.model.cut_ptr, self.model.cuts,
+                                       self.nbin)
+        self.entries = histogram.stage_entries(rows, self.flat,
+                                               self.use_pallas)
+        self.width, self.n = self.entries[0].shape
+        with program.span("stage.put"):
+            self.labels = jax.device_put(np.pad(
+                np.asarray(labels, np.float32), (0, self.n - self.rows)))
+        # an absent entry is the rule: the levels always carry totals
+        self.any_nan, self.max_bin = True, 0
+
+    @property
+    def sampled(self) -> bool:
+        return self.subsample < 1.0 or self.n != self.rows
+
+    def _keep(self, round_idx: int) -> np.ndarray:
+        keep = np.zeros(self.n, bool)           # a row of padding: never
+        keep[:self.rows] = True if self.subsample >= 1.0 else _keep_rows(
+            self.seed, round_idx, self.rows, self.subsample)
+        return keep
+
+    def _layout_key(self) -> tuple:
+        from rabit_tpu.ops import sparse_hist_kernel as sk
+
+        return ("sparse", self.width, self.flat.cut_ptr.tobytes(),
+                sk.hist_sparse)
+
+    def _entry_shapes(self) -> tuple:
+        import jax
+
+        return tuple(None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype) for a in self.entries)
+
+    def _level_program(self, nslots: int):
+        import jax
+        import jax.numpy as jnp
+
+        flat, use_pallas, cdt = self.flat, self.use_pallas, self.compute_dtype
+
+        def gbdt_level(cells_t, packed, fb, gh, node, takes):
+            with jax.named_scope("gbdt/level"):
+                return histogram.level_hist_flat(
+                    (cells_t, packed, fb), gh,
+                    _level_slots(node, takes, nslots), nslots, flat,
+                    use_pallas=use_pallas, compute_dtype=cdt)
+
+        sds = jax.ShapeDtypeStruct
+        return _build(gbdt_level, *self._entry_shapes(),
+                      sds(self.lead + (2, self.n), jnp.float32),
+                      sds(self.lead + (self.n,), jnp.int32),
+                      sds(self.lead + (nslots,), jnp.int32))
+
+    def _partition_program(self, depth: int):
+        import jax
+        import jax.numpy as jnp
+
+        def gbdt_partition(cells_t, node, tab):
+            with jax.named_scope("gbdt/partition"):
+                return _move_entries(cells_t, node, tab)
+
+        sds = jax.ShapeDtypeStruct
+        return _build(gbdt_partition, self._entry_shapes()[0],
+                      sds(self.lead + (self.n,), jnp.int32),
+                      sds(self.lead + (1 << depth, 5), jnp.int32),
+                      donate=(1,))
+
+    def _scan_spec(self):
+        """``gbdt_scan`` on the flat bin space: the level assembled as
+        the rectangle's is, of one row a slot; every column ranked and
+        the shortlist fetched as windows
+        (``histogram.level_shortlist_flat``)."""
+        flat, scan_by = self.flat, self.scan_by
+        return (lambda level: histogram.level_shortlist_flat(
+            level, flat, *scan_by)), (flat.size, 2), (1, flat.size)
+
+    def level(self, build, depth: int):
+        """As :meth:`_DeviceShard.level`; the kernel takes a call a tree
+        and 16 slots, none of them lane-wide or packed."""
+        from rabit_tpu.ops.sparse_hist_kernel import CALL_SLOTS
+
+        per_tree = len(build) // self.trees
+        local = self.prog["level"][per_tree](
+            *self.entries, self.gh, self.node, self._takes(build, depth))
+        program.enqueued(local)
+        calls = self.trees * -(-per_tree // CALL_SLOTS) \
+            if self.entries[1] is not None else 0
+        return local, build, (calls, 0, 0)
+
+    def partition(self, tabs: np.ndarray, depth: int) -> None:
+        # a split's (feature, threshold) as cells of the flat space
+        ptr = self.flat.ptr
+        feat, thr, dleft, leaf = np.moveaxis(tabs, -1, 0)
+        tab = np.stack([ptr[feat], ptr[feat] + thr, ptr[feat + 1], dleft,
+                        leaf], axis=-1).astype(np.int32)
+        self.node = self.prog["partition"][depth](
+            self.entries[0], self.node,
+            tab.reshape(self.lead + tab.shape[1:]))
+        program.enqueued(self.node)
+
+    def partition_rows(self, depth: int) -> tuple[int, int]:
+        """The move reads every entry row, once for all trees."""
+        return self.width, self.width
 
 
 def _reduce_level(local) -> np.ndarray:
@@ -977,14 +1219,15 @@ def _assemble(level_of: dict, depth: int, built: np.ndarray, order,
 
 
 def _scan(hist: np.ndarray, reg_lambda: float, min_child_weight: float,
-          has_missing: bool):
+          has_missing: bool, widths=None):
     """``histogram.best_split`` of a node's histogram as the loop holds
     it: with ``has_missing`` its last feature row is not a feature but
     holds the node's (grad, hess) totals in its bin 0
-    (``histogram.with_totals``)."""
+    (``histogram.with_totals``); ``widths`` as ``best_split`` has
+    them."""
     if has_missing:
         return histogram.best_split(hist[:-1], reg_lambda, min_child_weight,
-                                    hist[-1, 0])
+                                    hist[-1, 0], widths)
     return histogram.best_split(hist, reg_lambda, min_child_weight)
 
 
@@ -999,13 +1242,16 @@ _scan_pool = None
 
 
 def _scan_level(hists, reg_lambda: float, min_child_weight: float,
-                has_missing: bool) -> list:
-    """``_scan`` of every slot of a level, node or not."""
+                has_missing: bool, widths=None) -> list:
+    """``_scan`` of every slot of a level, node or not (``widths``: a
+    slot's rows')."""
     global _scan_pool
 
-    def scan(hist):
-        return _scan(hist, reg_lambda, min_child_weight, has_missing)
+    def scan(hist, *width):
+        return _scan(hist, reg_lambda, min_child_weight, has_missing, *width)
 
+    if widths is not None:
+        return [scan(hist, width) for hist, width in zip(hists, widths)]
     if len(hists) < 2 or hists[0].nbytes < _SCAN_PARALLEL_BYTES:
         return [scan(hist) for hist in hists]
     if _scan_pool is None:
@@ -1059,7 +1305,7 @@ def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
     return int(hr < hl)
 
 
-def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
+def train(values, labels: np.ndarray, num_round: int = 10,
           max_depth: int = 3, nbin: int = 32, learning_rate: float = 0.3,
           reg_lambda: float = 1.0, loss: str = "logistic",
           min_child_weight: float = 1e-3,
@@ -1171,6 +1417,29 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     (``histogram.split_candidates``), and prediction routes NaN the
     same way — XGBoost's sparsity-aware splits.
 
+    **Sparse rows.**  ``values`` may be sparse: a ``data.SparseMat``
+    (libsvm's CSR) or ``data.EllRows(indices, values, counts,
+    feat_dim)``, rows of ``(index, value)`` pairs up to an ELL width.  Semantics are XGBoost's for libsvm input: an absent
+    entry is missing, not zero, in no bin and never imputed, and the
+    job is the job its rows would be as a dense array with NaN for the
+    absent entries, the same forest.  What differs is the layout
+    (``_SparseShard``): the shard is held as its entries, a present
+    value its row and its cell of a flat bin space in which a column
+    has the bins its cuts define (``histogram.sparse_cuts``: the
+    distinct quantiles of its present values in :func:`cut_sample`, so
+    an indicator column has one cut and two cells where the rectangle
+    gives it ``nbin``); a level's histograms, the allreduce's payload
+    and the level kept for the subtraction are ``(slots, cells, 2)``;
+    ``gbdt_scan`` ranks the columns on that axis (a column's prefix sums
+    begin anew at its first cell) and hands the host the shortlist as
+    windows of ``nbin`` cells, on which the float64 decision is the one
+    above; the row move looks for the split's column among the row's
+    entries.  On the chip the level is ``ops.sparse_hist_kernel``; off
+    it, and under a host engine, the same class adds the same entries up
+    by XLA's ``segment_sum`` and the host ranks the reduced level in
+    float64 (``histogram.flat_shortlist``).  ``tree_method="approx"``
+    on sparse rows is refused (it would sketch present values only).
+
     ``use_pallas``/``compute_dtype`` pin the histogram path: on TPU the
     default is the fused Pallas kernel with bf16-rounded weights
     (fastest); reproducibility-sensitive callers can force the exact
@@ -1191,6 +1460,12 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
           "would sketch its cuts under its own hessians, K sketches and K "
           "binnings a round, which this loop does not do; use "
           "tree_method=\"hist\"", num_class)
+    sparse = isinstance(values, (EllRows, SparseMat))
+    if sparse:
+        values = ell_rows(values)
+        check(not approx, "boosting: tree_method=\"approx\" on sparse "
+              "rows, whose sketch would cover present values only, is not "
+              "built; use tree_method=\"hist\"")
     if num_class > 1:
         check(labels.min() >= 0 and labels.max() < num_class
               and not np.any(labels % 1),
@@ -1198,9 +1473,15 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
               num_class)
     version, restored = rabit_tpu.load_checkpoint()
     if version == 0:
+        cut_ptr = None
         if approx:
             # every tree brings its own cuts: none to agree on yet
             cuts = np.zeros((values.shape[1], nbin - 1), np.float32)
+        elif sparse:
+            with program.span("stage.sparse_cuts"):
+                cut_ptr, cuts = rabit_tpu.broadcast(
+                    histogram.sparse_cuts(cut_sample(values), nbin)
+                    if rabit_tpu.get_rank() == 0 else None, 0)
         else:
             # rank 0's shard defines the cuts; other ranks just receive
             # them
@@ -1208,7 +1489,7 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                 cuts = rabit_tpu.broadcast(
                     histogram.quantile_cuts(cut_sample(values), nbin)
                     if rabit_tpu.get_rank() == 0 else None, 0)
-        model = BoostedModel(cuts=cuts,
+        model = BoostedModel(cuts=cuts, cut_ptr=cut_ptr,
                              base_score=0.5 if num_class > 1 else 0.0,
                              learning_rate=learning_rate, loss=loss,
                              has_missing=False, tree_method=tree_method,
@@ -1222,6 +1503,10 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
         check(model.num_class == num_class,
               "boosting: the committed forest has num_class=%d, this job "
               "asks for %d", model.num_class, num_class)
+        check((getattr(model, "cut_ptr", None) is not None) == sparse,
+              "boosting: the committed forest was grown on %s rows, this "
+              "job's are %s", "dense" if sparse else "sparse",
+              "sparse" if sparse else "dense")
         rabit_tpu.tracker_print(
             "[%d] restart iter=%d" % (rabit_tpu.get_rank(), version))
     program.put("gbdt.classes", num_class)
@@ -1233,8 +1518,8 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
     def stage():
         args = (values, labels, model, max_depth, nbin, subsample, seed,
                 use_pallas, compute_dtype)
-        if device_arm:
-            shard = _DeviceShard(*args, scan=(
+        if sparse or device_arm:
+            shard = (_SparseShard if sparse else _DeviceShard)(*args, scan=(
                 reg_lambda, min_child_weight) if device_scan else None)
         else:
             shard = _HostShard(*args)
@@ -1330,12 +1615,19 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                         if not device_scan:
                             hists = _assemble(level_of, depth, built, order,
                                               len(slots))
+                            if sparse:
+                                # the shortlist the device would hand
+                                # over, ranked here
+                                feats, hists = histogram.flat_shortlist(
+                                    hists, shard.flat, reg_lambda,
+                                    min_child_weight)
                         # every slot is scanned, node or not: a round's
                         # host work is then a full forest's whatever the
                         # trees, as the device's is (static shapes), and
                         # a job's rounds take the same time
-                        best = _scan_level(hists, reg_lambda,
-                                           min_child_weight, has_missing)
+                        best = _scan_level(
+                            hists, reg_lambda, min_child_weight, has_missing,
+                            shard.flat.widths[feats] if sparse else None)
                         build, default_left = [-1] * len(slots), 0
                         width = len(slots) // num_class
                         for s, nid in enumerate(slots):
@@ -1368,6 +1660,8 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                               calls[1] * values.shape[1])
                 program.count("gbdt.features_two_level",
                               (calls[0] - calls[1]) * values.shape[1])
+                if sparse:
+                    program.count("gbdt.sparse.payload_bytes", local.nbytes)
                 program.count("gbdt.features_packed", calls[2])
                 program.count("gbdt.channels", 2 * len(order))
                 program.count("gbdt.channels_live", 2 * live)
@@ -1387,7 +1681,8 @@ def train(values: np.ndarray, labels: np.ndarray, num_round: int = 10,
                     cuts = fetch(cuts, np.array)
                 model.tree_cuts.append(cuts)
             for tree in trees:
-                _fill_splits(tree, cuts if approx else model.cuts)
+                _fill_splits(tree, cuts if approx else
+                             shard.flat if sparse else model.cuts)
             model.trees.extend(trees)
             program.count("gbdt.trees", num_class)
             if round_idx + 1 < num_round:

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import sys
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -110,6 +111,59 @@ class SparseMat:
         rows = np.repeat(np.arange(self.num_row), np.diff(self.indptr))
         np.add.at(out, (rows, self.findex), self.fvalue)
         return out
+
+
+class EllRows(NamedTuple):
+    """Sparse rows in ELL form, as a learner that keeps entries takes
+    them (``boosting.train``): row ``i`` holds the ``counts[i]`` entries
+    ``(indices[i, j], values[i, j])``, ``j < counts[i]``, of a matrix of
+    ``feat_dim`` columns.  Without ``counts`` a slot is an entry where
+    its index is a column's (``0 <= index < feat_dim``:
+    :meth:`SparseMat.to_ell` pads with ``feat_dim``).  An entry whose
+    value is NaN is no entry; one whose value is 0 is (libsvm's
+    ``3:0``)."""
+
+    indices: np.ndarray             # (n, width) int32
+    values: np.ndarray              # (n, width) float32
+    counts: np.ndarray | None       # (n,) or None
+    feat_dim: int
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        """``(rows, columns)`` of the matrix the entries are of."""
+        return self.indices.shape[0], self.feat_dim
+
+    def rows(self, at) -> "EllRows":
+        """The rows a slice names."""
+        return EllRows(self.indices[at], self.values[at],
+                       None if self.counts is None else self.counts[at],
+                       self.feat_dim)
+
+    def present(self) -> np.ndarray:
+        """``(n, width)`` bool: the slots that hold an entry."""
+        idx = self.indices
+        held = (idx >= 0) & (idx < self.feat_dim) & ~np.isnan(self.values)
+        if self.counts is not None:
+            held &= np.arange(idx.shape[1]) < np.asarray(self.counts)[:, None]
+        return held
+
+    def to_dense(self) -> np.ndarray:
+        """``(n, feat_dim)`` float32 with NaN for an absent entry (small
+        data and tests only)."""
+        out = np.full(self.shape, np.nan, np.float32)
+        held = self.present()
+        rows = np.broadcast_to(np.arange(self.shape[0])[:, None], held.shape)
+        out[rows[held], self.indices[held]] = self.values[held]
+        return out
+
+
+def ell_rows(rows) -> EllRows:
+    """``rows`` as :class:`EllRows`: one, or a :class:`SparseMat`."""
+    if isinstance(rows, EllRows):
+        return rows
+    idx, val, _labels, _valid = rows.to_ell()
+    return EllRows(idx[:rows.num_row], val[:rows.num_row], None,
+                   rows.feat_dim)
 
 
 def fetch(result, convert=None):

@@ -46,6 +46,27 @@ CUT_BATCH_COLS = 16
 _CUT_BLOCK_ROWS = 4096
 
 
+def _quantiles(take, cnt: np.ndarray, qs: np.ndarray) -> np.ndarray:
+    """``(columns, len(qs))`` quantiles ``qs`` of columns whose present
+    values are sorted: ``cnt`` of them a column, ``take(at)`` the values
+    at positions ``at`` (columns, len(qs)) of each.  numpy's own
+    arithmetic (``linear``: float32 neighbours, float64 weight); zeros
+    for a column with none."""
+    last = np.maximum(cnt - 1, 0)[:, None]
+    virtual = last * qs[None, :]                       # float64
+    # a quantile at or past the last present value takes it whole
+    # (numpy: both neighbours the last entry, weight virtual + 1)
+    above, below = virtual >= last, np.floor(virtual)
+    lo = np.where(above, last, below).astype(np.intp)
+    gamma = virtual - np.where(above, -1.0, below)
+    a = take(lo)
+    b = take(np.minimum(lo + 1, last))
+    diff = b - a                                       # float32
+    out = np.where(gamma >= 0.5, b - diff * (1 - gamma), a + diff * gamma)
+    out[cnt == 0] = 0.0                                # all absent
+    return out
+
+
 def quantile_cuts(values: np.ndarray, nbin: int) -> np.ndarray:
     """Per-column quantile cut points, shape (f, nbin - 1) — the
     host-side analogue of XGBoost's quantile sketch (per-shard; callers
@@ -80,21 +101,8 @@ def quantile_cuts(values: np.ndarray, nbin: int) -> np.ndarray:
                 r:r + _CUT_BLOCK_ROWS, j0:j1].T
         cols.sort(axis=1)                                  # NaNs last
         cnt = m - np.count_nonzero(np.isnan(cols), axis=1)
-        last = np.maximum(cnt - 1, 0)[:, None]
-        virtual = last * qs[None, :]                       # float64
-        # a quantile at or past the last present value takes it whole
-        # (numpy: both neighbours the last entry, weight virtual + 1)
-        above, below = virtual >= last, np.floor(virtual)
-        lo = np.where(above, last, below).astype(np.intp)
-        gamma = virtual - np.where(above, -1.0, below)
         rows = np.arange(j1 - j0)[:, None]
-        a = cols[rows, lo]
-        b = cols[rows, np.minimum(lo + 1, last)]
-        diff = b - a                                       # float32
-        out = np.where(gamma >= 0.5, b - diff * (1 - gamma),
-                       a + diff * gamma)
-        out[cnt == 0] = 0.0                                # all absent
-        cuts[j0:j1] = out
+        cuts[j0:j1] = _quantiles(lambda at: cols[rows, at], cnt, qs)
 
     with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
         list(pool.map(batch, range(0, f, CUT_BATCH_COLS)))
@@ -761,9 +769,21 @@ def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
     sums = np.cumsum(np.asarray(hist, np.float64), axis=1)
     gl, hl = sums[:, :-1, 0], sums[:, :-1, 1]
     gt, ht = sums[:, -1:, 0], sums[:, -1:, 1]
+    mass = None
     if total is not None:
         mass = _mass(sums[:, -1], np.asarray(total, np.float64))
-        gm, hm = mass[:, :1], mass[:, 1:]
+        mass = mass[:, :1], mass[:, 1:]
+    return _score_sides(gl, hl, gt, ht, mass, reg_lambda, min_child_weight)
+
+
+def _score_sides(gl, hl, gt, ht, mass, reg_lambda: float,
+                 min_child_weight: float | None):
+    """:func:`split_candidates` from the left sides' sums ``gl, hl``,
+    the present entries' sums ``gt, ht`` of each candidate's feature and
+    the features' missing ``mass`` ``(gm, hm)`` (None: no totals), all
+    of one shape or broadcastable to it."""
+    if mass is not None:
+        gm, hm = mass
         gt, ht = gt + gm, ht + hm
     parent = gt * gt / (ht + reg_lambda)
 
@@ -785,17 +805,24 @@ def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
 
     with np.errstate(divide="ignore", invalid="ignore"):
         gain_right = score(gl, hl)         # absent rows, if any, go right
-        if total is None:
+        if mass is None:
             return gain_right, None
         gain_left = score(gl + gm, hl + hm)    # absent rows go left
     return np.maximum(gain_left, gain_right), gain_left >= gain_right
 
 
 def best_split(hist: np.ndarray, reg_lambda: float,
-               min_child_weight: float | None, total=None):
+               min_child_weight: float | None, total=None, widths=None):
     """``(gain, feature, cut, default_left)`` of the best candidate of
-    :func:`split_candidates` (the first of equals, feature-major)."""
+    :func:`split_candidates` (the first of equals, feature-major).
+    ``widths`` are the bins each feature row holds where that is fewer
+    than the row has room for (a window of a flat bin space,
+    :func:`level_shortlist_flat`): a cut from a feature's last bin on is
+    none."""
     gain, left = split_candidates(hist, reg_lambda, min_child_weight, total)
+    if widths is not None:
+        gain[np.arange(gain.shape[1]) >= np.asarray(widths)[:, None] - 1] \
+            = -np.inf
     j, t = np.unravel_index(int(gain.argmax()), gain.shape)
     return (float(gain[j, t]), int(j), int(t),
             True if left is None else bool(left[j, t]))
@@ -827,13 +854,32 @@ def split_candidates_device(g, h, reg_lambda: float = 1.0,
     sg, sh = jnp.cumsum(g, axis=-1), jnp.cumsum(h, axis=-1)
     gt, ht = sg[..., -1:], sh[..., -1:]
     if total is not None:
-        tg, th = (t[..., None, None] for t in total)
+        total = tuple(t[..., None, None] for t in total)
+    return _rank_sides(
+        sg, sh, gt, ht, total,
+        lambda: jnp.arange(g.shape[-1]) == g.shape[-1] - 1,
+        reg_lambda, min_child_weight)
+
+
+def _rank_sides(sg, sh, gt, ht, total, no_cut, reg_lambda: float,
+                min_child_weight: float | None):
+    """:func:`split_candidates_device` from the left sides' sums ``sg,
+    sh``, the present entries' sums ``gt, ht`` of each candidate's
+    feature, the node's ``total`` (shaped to them; None: no totals) and
+    the candidates that are no cut (a function that gives the mask: it
+    is traced where the rectangle's ranking always traced it, so that
+    the dense jobs' ``gbdt_scan`` stays the program it was, operation
+    for operation: ``tests/test_boosting_programs_pinned.py``)."""
+    import jax.numpy as jnp
+
+    if total is not None:
+        tg, th = total
         gm, hm = tg - gt, th - ht
         none = jnp.abs(hm) <= MISSING_MASS_FLOOR * jnp.abs(th)
         gm, hm = jnp.where(none, 0.0, gm), jnp.where(none, 0.0, hm)
         gt, ht = gt + gm, ht + hm
     parent = gt * gt / (ht + reg_lambda)
-    no_cut = jnp.arange(g.shape[-1]) == g.shape[-1] - 1
+    no_cut = no_cut()
 
     def score(gl, hl):
         gr, hr = gt - gl, ht - hl
@@ -1007,3 +1053,373 @@ def build_level_local(bins, grad, hess, node_of_row, node_ids,
                      for v in np.asarray(node_ids)])
     return with_totals(out, slot_totals(gh, slot, m, jnp.float32)) \
         if totals else out
+
+
+# ----------------------------------------------------------------------
+# sparse rows: entries, and a histogram of each column's own bins
+# ----------------------------------------------------------------------
+# rows binned by one thread's call of :func:`bin_entries`
+BIN_CHUNK_ROWS = 1 << 16
+
+
+class FlatBins:
+    """The bin space of a job on sparse rows: a column's bins follow
+    the column before's, as many as its cuts and one (XGBoost's
+    ``HistogramCuts``: ``cut_ptr[j]`` is where column ``j``'s cuts begin
+    in ``cut_vals``, ``ptr[j]`` where its cells do).  An indicator
+    column is two cells where the rectangle gives it ``nbin``.  One more
+    cell, ``nbins``, holds a level slot's (grad, hess) totals, as the
+    rectangle's extra feature row does (:func:`with_totals`), and the
+    axis is padded to whole blocks of the kernel's (``size``): that is
+    what a level reduces and keeps."""
+
+    def __init__(self, cut_ptr, cut_vals, nbin: int):
+        from rabit_tpu.ops.sparse_hist_kernel import CELL_BLOCK, num_blocks
+
+        self.cut_ptr = np.asarray(cut_ptr, np.int64)
+        self.cut_vals = np.asarray(cut_vals, np.float32)
+        self.nbin, self.f = nbin, len(self.cut_ptr) - 1
+        self.widths = np.diff(self.cut_ptr) + 1         # bins a column
+        assert self.f == 0 or self.widths.max() <= nbin, (self.widths.max(),
+                                                           nbin)
+        self.ptr = self.cut_ptr + np.arange(self.f + 1)
+        self.nbins = int(self.ptr[-1])
+        self.cells = self.nbins + 1                     # and the totals'
+        self.size = num_blocks(self.cells) * CELL_BLOCK
+        col = np.full(self.size, self.f, np.int32)
+        col[:self.nbins] = np.repeat(np.arange(self.f, dtype=np.int32),
+                                     self.widths)
+        # every cell past the columns' is a column of its own to a scan
+        self.col_of_cell = col
+        at = np.arange(self.size)
+        self.first = (at >= self.nbins) | np.isin(at, self.ptr[:-1])
+        self.last = (at >= self.nbins) | np.isin(at, self.ptr[1:] - 1)
+
+    def __getitem__(self, at) -> float:
+        """``flat[feature, threshold]``: the cut a split at that bin of
+        that column holds, as the rectangle of cuts is indexed."""
+        feature, threshold = at
+        return float(self.cut_vals[self.cut_ptr[feature] + threshold])
+
+
+def _order_bits(v: np.ndarray) -> np.ndarray:
+    """uint64 keys whose order is the float32 values' (-0.0 with 0.0),
+    in the low 32 bits."""
+    bits = (np.asarray(v, np.float32) + np.float32(0.0)).view(np.uint32)
+    return np.where(bits >> 31, ~bits, bits | np.uint32(1 << 31)).astype(
+        np.uint64)
+
+
+def sparse_cuts(sample, nbin: int):
+    """``(cut_ptr, cut_vals)`` of a sample's entries (``data.EllRows``):
+    a column's cuts are the distinct values among the ``nbin - 1``
+    quantiles of its present entries, :func:`quantile_cuts` of the
+    column with NaN for the absent ones, bit for bit, each kept once (a
+    repeated cut bounds a bin no value can fall in).  A column of one
+    distinct value has one cut, and so has one without an entry (0.0, as
+    there)."""
+    f = sample.feat_dim
+    held = sample.present()
+    cols = sample.indices[held].astype(np.uint64)
+    keys = np.sort(cols << np.uint64(32) | _order_bits(sample.values[held]))
+    del cols
+    bits = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    flat = np.where(bits >> 31, bits & np.uint32(0x7FFFFFFF), ~bits).view(
+        np.float32)
+    cnt = np.bincount((keys >> np.uint64(32)).astype(np.int64), minlength=f)
+    start = (np.cumsum(cnt) - cnt)[:, None]
+    qs = np.linspace(0, 1, nbin + 1)[1:-1]
+    if flat.size == 0:
+        flat = np.zeros(1, np.float32)
+    rect = _quantiles(lambda at: flat[np.minimum(start + at, flat.size - 1)],
+                      cnt, qs).astype(np.float32)
+    keep = np.ones(rect.shape, bool)
+    keep[:, 1:] = rect[:, 1:] != rect[:, :-1]
+    cut_ptr = np.concatenate([[0], np.cumsum(keep.sum(axis=1))])
+    return cut_ptr.astype(np.int32), rect[keep]
+
+
+def bin_entries(rows, flat: FlatBins) -> np.ndarray:
+    """``(n, width)`` int32: the cell of every entry of ``rows``
+    (``data.EllRows``), -1 where a slot holds none.  An entry of column
+    ``j`` lies in cell ``ptr[j] +`` the number of the column's cuts at
+    or below its value (:func:`apply_cuts`' rule).  One ``searchsorted``
+    of (column, value) keys among the cuts', a block of rows a thread:
+    the device's gather is 8 ns an element and a binary search over
+    ragged cuts eight of them an entry."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    cut_keys = (np.repeat(np.arange(flat.f, dtype=np.uint64),
+                          np.diff(flat.cut_ptr)) << np.uint64(32)
+                | _order_bits(flat.cut_vals))
+    n = rows.indices.shape[0]
+    out = np.empty(rows.indices.shape, np.int32)
+
+    def block(lo: int) -> None:
+        part = rows.rows(slice(lo, lo + BIN_CHUNK_ROWS))
+        held = part.present()
+        cols = np.where(held, part.indices, 0).astype(np.uint64)
+        below = np.searchsorted(
+            cut_keys, cols << np.uint64(32) | _order_bits(part.values),
+            side="right")
+        out[lo:lo + BIN_CHUNK_ROWS] = np.where(
+            held, below + cols.astype(np.int64), -1)
+
+    with ThreadPoolExecutor(min(12, os.cpu_count() or 1)) as pool:
+        list(pool.map(block, range(0, n, BIN_CHUNK_ROWS)))
+    return out
+
+
+def level_hist_flat(entries, gh, node, nslots: int, flat: FlatBins,
+                    use_pallas: bool | None = None, compute_dtype=None):
+    """``(trees * nslots, flat.size, 2)`` histograms of one tree level
+    of sparse rows, traceable: :func:`level_hist` on the flat bin space,
+    a slot's totals in cell ``flat.nbins``.  ``entries`` are the shard's
+    as staged, ``(cells_t, packed, fb)``: the row move's ``(width, n)``
+    cells and, on the kernel's road, the bucketed slots and their blocks
+    (``ops.sparse_hist_kernel``); ``gh`` and ``node`` as there.  The
+    kernel takes 16 slots a call and a tree a call; its operand is
+    bfloat16 and a ``compute_dtype`` of float32 takes the XLA road,
+    which adds the weights as they are."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    cells_t, packed, fb = entries
+    if gh.ndim == 2:
+        gh, node = gh[None], node[None]
+    kernel = use_pallas and jnp.dtype(
+        compute_dtype or jnp.bfloat16) == jnp.bfloat16
+    outs = []
+    for t in range(gh.shape[0]):
+        if kernel:
+            parts = []
+            for lo in range(0, nslots, sk.CALL_SLOTS):
+                ns = min(sk.CALL_SLOTS, nslots - lo)
+                mine = node[t] if ns == nslots else jnp.where(
+                    (node[t] >= lo) & (node[t] < lo + ns), node[t] - lo, -1)
+                parts.append(sk.hist_sparse(
+                    packed, fb, gh[t], mine,
+                    tiles=cells_t.shape[1] // sk.ROW_TILE, nslots=ns,
+                    cells=flat.cells, interpret=not on_tpu()))
+            out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        else:
+            out = sk.hist_sparse_xla(cells_t, gh[t], node[t], nslots,
+                                     flat.cells)
+        outs.append(out.at[:, flat.nbins].set(slot_totals(
+            gh[t], node[t], nslots,
+            jnp.bfloat16 if kernel else jnp.float32)))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+def _segment_scan(x, starts, op, reverse: bool = False):
+    """Inclusive scan of ``x`` along its last axis by ``op`` that begins
+    anew at every cell ``starts`` marks (in a reverse scan: the cells a
+    segment ends at), traceable."""
+    import jax
+    import jax.numpy as jnp
+
+    def combine(a, b):
+        return a[0] | b[0], jnp.where(b[0], b[1], op(a[1], b[1]))
+
+    return jax.lax.associative_scan(
+        combine, (jnp.broadcast_to(starts, x.shape), x), reverse=reverse,
+        axis=x.ndim - 1)[1]
+
+
+def _over_columns(x, flat: FlatBins, op):
+    """Every cell's column's ``op`` over ``x`` (last axis: the flat
+    cells), traceable: a scan each way."""
+    import jax.numpy as jnp
+
+    first, last = jnp.asarray(flat.first), jnp.asarray(flat.last)
+    return (_segment_scan(x, first, op),
+            _segment_scan(x, last, op, reverse=True))
+
+
+def level_shortlist_flat(level, flat: FlatBins, reg_lambda,
+                         min_child_weight, k: int = SHORTLIST):
+    """:func:`level_shortlist` of a level on the flat bin space
+    (``(2, slots, 1, flat.size)``, :func:`assemble_level` of
+    :func:`level_hist_flat`'s), traceable: for every slot the ``min(k,
+    f)`` columns of highest best gain, in column order, ``(slots, k)``
+    int32, and their histogram rows as **windows** of ``nbin`` cells
+    from each column's first, zeros past its last, with the totals row
+    after them: ``(2, slots, k + 1, nbin)``, what the rectangle's
+    shortlist hands the host, so that the host decides on it as it does
+    there (given the columns' widths: ``best_split``).  A column's
+    prefix sums are a scan that begins anew at its first cell; its
+    present entries' sums that scan and the one from its last cell
+    back, less the cell."""
+    import jax
+    import jax.numpy as jnp
+
+    g, h = level[0, :, 0], level[1, :, 0]               # (slots, size)
+    total = (g[:, flat.nbins, None], h[:, flat.nbins, None])
+    inside = jnp.arange(flat.size) < flat.nbins
+    x = jnp.where(inside, level[:, :, 0], 0.0)
+    pre, suf = _over_columns(x, flat, jnp.add)
+    sums = pre + suf - x
+    gain, _left = _rank_sides(
+        pre[0], pre[1], sums[0], sums[1], total,
+        lambda: jnp.asarray(flat.last) | ~inside, reg_lambda,
+        min_child_weight)
+    best = jnp.maximum(*_over_columns(gain, flat, jnp.maximum))
+    heads = jnp.where(jnp.asarray(flat.first) & inside, best, -jnp.inf)
+    _best, at = jax.lax.top_k(heads, min(k, flat.f))
+    feats = jnp.sort(jnp.asarray(flat.col_of_cell)[at], axis=-1)
+    # the totals' cell is a column of one bin after the last
+    take = jnp.concatenate(
+        [feats, jnp.full(feats.shape[:1] + (1,), flat.f, jnp.int32)], axis=1)
+    ptr = jnp.asarray(np.append(flat.ptr[:-1], flat.nbins).astype(np.int32))
+    width = jnp.asarray(np.append(flat.widths, 1).astype(np.int32))
+    lane = jnp.arange(flat.nbin, dtype=jnp.int32)
+    held = lane < width[take][..., None]                # (slots, k + 1, nbin)
+    cell = jnp.where(held, ptr[take][..., None] + lane, flat.nbins)
+    rows = jnp.take_along_axis(level[:, :, 0][:, :, None, :], cell[None],
+                               axis=3)
+    return feats, jnp.where(held[None], rows, 0.0)
+
+
+def flat_shortlist(hists: np.ndarray, flat: FlatBins, reg_lambda: float,
+                   min_child_weight: float | None, k: int = SHORTLIST):
+    """:func:`level_shortlist_flat` on the host, in float64, of a
+    level's ``(slots, flat.size, 2)`` histograms: the columns ``(slots,
+    k)`` and their windows ``(slots, k + 1, nbin, 2)`` as
+    ``boosting._fetch_shortlist`` hands them on."""
+    k = min(k, flat.f)
+    starts, ends = flat.ptr[:-1], flat.ptr[1:] - 1
+    feats = np.zeros((len(hists), k), np.int32)
+    rows = np.zeros((len(hists), k + 1, flat.nbin, 2))
+    for s, hist in enumerate(np.asarray(hists, np.float64)):
+        x, total = hist[:flat.nbins], hist[flat.nbins]
+        run = np.cumsum(x, axis=0)
+        pre = run - np.repeat(run[starts] - x[starts], flat.widths, axis=0)
+        sums = pre[ends]
+        mass = np.repeat(_mass(sums, total), flat.widths, axis=0)
+        sums = np.repeat(sums, flat.widths, axis=0)
+        gain, _left = _score_sides(
+            pre[:, 0], pre[:, 1], sums[:, 0], sums[:, 1],
+            (mass[:, 0], mass[:, 1]), reg_lambda, min_child_weight)
+        gain[ends] = -np.inf
+        best = np.maximum.reduceat(gain, starts)
+        feats[s] = np.sort(np.argsort(-best, kind="stable")[:k])
+        for r, j in enumerate(feats[s]):
+            rows[s, r, :flat.widths[j]] = x[flat.ptr[j]:flat.ptr[j + 1]]
+        rows[s, k, 0] = total
+    return feats, rows
+
+
+def _place_rows_program(n: int, c: int, width: int):
+    """Compiled ``gbdt_sparse_place``: ``c`` rows of binned entries
+    written, transposed, into columns ``[lo, lo + c)`` of the staged
+    ``(width, n)`` cells in place."""
+    key = ("sparse_place", n, c, width)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        def gbdt_sparse_place(cells_t, part, lo):
+            with jax.named_scope("gbdt/bin"):
+                return jax.lax.dynamic_update_slice(
+                    cells_t, part.T, (jnp.int32(0), lo))
+
+        sds = jax.ShapeDtypeStruct
+        fn = _CACHE[key] = jax.jit(
+            gbdt_sparse_place, donate_argnums=(0,)).lower(
+                sds((width, n), jnp.int32), sds((c, width), jnp.int32),
+                sds((), jnp.int32)).compile()
+    return fn
+
+
+def _bucket_program(n: int, width: int, tiles: int, cells: int):
+    """Compiled ``gbdt_sparse_bucket``: ``ops.sparse_hist_kernel.
+    bucket_group`` of the ``tiles`` tiles of the staged ``(width, n)``
+    cells from row ``lo`` on."""
+    key = ("sparse_bucket", n, width, tiles, cells)
+    fn = _CACHE.get(key)
+    if fn is None:
+        import jax
+        import jax.numpy as jnp
+
+        from rabit_tpu.ops import sparse_hist_kernel as sk
+
+        def gbdt_sparse_bucket(cells_t, lo):
+            return sk.bucket_group(jax.lax.dynamic_slice_in_dim(
+                cells_t, lo, tiles * sk.ROW_TILE, axis=1), cells=cells)
+
+        sds = jax.ShapeDtypeStruct
+        fn = _CACHE[key] = jax.jit(gbdt_sparse_bucket).lower(
+            sds((width, n), jnp.int32), sds((), jnp.int32)).compile()
+    return fn
+
+
+def stage_entries(rows, flat: FlatBins, bucket: bool):
+    """Bin a shard of sparse rows (``data.EllRows``) and keep it on the
+    device as entries: returns ``(cells_t, packed, fb)``, the ``(width,
+    n)`` int32 cells of the rows' entries, row-major transposed (-1: no
+    entry; ``n`` the rows padded to whole tiles of the kernel's, a row
+    of padding holding none), which the row move searches and the XLA
+    road adds up, and with ``bucket`` the same entries once more,
+    re-ordered for the kernel (``ops.sparse_hist_kernel.bucket_group``,
+    ``GROUP_TILES`` tiles a call; None, None without).  That second copy
+    is what the kernel's road costs in HBM: ``capacity / (ROW_TILE *
+    width)`` of the first, 9% more than it at 32 entries a row over 24
+    blocks.  No array of rows by columns is made anywhere.
+
+    The entries are binned on the host, a chunk of ``STAGE_CHUNK_ROWS``
+    rows at a time on its threads (:func:`bin_entries`), and cross as
+    their cells, 4 bytes where index and value are 8.  Counts the
+    entries of the rows-by-columns matrix and the absent among them
+    (``gbdt.entries``, ``gbdt.entries_missing``, as :func:`stage_bins`),
+    the present ones, the slots staged for the kernel and the bins a
+    slot has and the rectangle would (``gbdt.sparse.*``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from rabit_tpu.obs import program
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+    from rabit_tpu.ops.sparse_linear_kernel import place
+
+    rows_n, width = rows.indices.shape
+    tiles = -(-max(rows_n, 1) // sk.ROW_TILE)
+    n = tiles * sk.ROW_TILE
+    cells_t = jnp.full((width, n), -1, jnp.int32)
+    present = 0
+    for lo in range(0, rows_n, STAGE_CHUNK_ROWS):
+        c = min(STAGE_CHUNK_ROWS, rows_n - lo)
+        with program.span("stage.sparse_bin"):
+            part = bin_entries(rows.rows(slice(lo, lo + c)), flat)
+            present += int(np.count_nonzero(part >= 0))
+        with program.span("stage.compile"):
+            fn = _place_rows_program(n, c, width)
+        with program.span("stage.put"):
+            part = jax.device_put(part)
+        cells_t = fn(cells_t, part, np.int32(lo))
+    packed = fb = None
+    slots = n * width
+    if bucket:
+        with program.span("stage.sparse_bucket"):
+            cap = sk.capacity(width, flat.cells)
+            slots = tiles * cap
+            packed = jnp.zeros((slots // sk.SUB, sk.SUB), jnp.int32)
+            fb = jnp.zeros((slots // sk.STEP, sk.SUBS), jnp.int32)
+            for t in range(0, tiles, sk.GROUP_TILES):
+                g = min(sk.GROUP_TILES, tiles - t)
+                part, part_fb, _real = _bucket_program(
+                    n, width, g, flat.cells)(cells_t,
+                                             np.int32(t * sk.ROW_TILE))
+                packed = place(packed, part, np.int32(t * cap // sk.SUB))
+                fb = place(fb, part_fb, np.int32(t * cap // sk.STEP))
+            jax.block_until_ready((packed, fb))
+    program.count("gbdt.entries", rows_n * flat.f)
+    program.count("gbdt.entries_missing", rows_n * flat.f - present)
+    program.count("gbdt.sparse.entries", present)
+    program.count("gbdt.sparse.slots", slots)
+    program.count("gbdt.sparse.bins", flat.nbins)
+    program.count("gbdt.sparse.bins_rect", flat.f * flat.nbin)
+    return cells_t, packed, fb

@@ -446,6 +446,135 @@ def test_every_depths_row_move_compiles_for_v5e(topo, monkeypatch, n, f, k,
         assert m.temp_size_in_bytes < fpad * n * 4 // 2
 
 
+def _allstate_flat(nbin=256):
+    """The sparse boosting cell's flat bin space: 15 columns of 255
+    cuts, 4,212 indicator columns of one."""
+    from rabit_tpu.learn import histogram
+
+    cuts = np.array([nbin - 1] * 15 + [1] * 4212)
+    cut_ptr = np.concatenate([[0], np.cumsum(cuts)])
+    return histogram.FlatBins(cut_ptr, np.zeros(cut_ptr[-1], np.float32),
+                              nbin)
+
+
+@pytest.mark.parametrize("nslots", [1, 2, 4, 8, 16])
+def test_sparse_level_kernel_compiles_for_v5e_at_every_width(topo, nslots):
+    """``ops.sparse_hist_kernel.hist_sparse`` at the sparse boosting
+    cell's shapes (2^25 rows of 32 slots bucketed over 24 blocks of 512
+    cells, a level of 1 to 16 build slots): Mosaic takes the two one-hot
+    products and the resident ``(24, 128, 128)`` accumulator; the
+    program's temporaries are the table of (grad, hess, slot) a row, in
+    float32 and in bfloat16, and little else (24 bytes a row)."""
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    flat, n, width = _allstate_flat(), 1 << 25, 32
+    assert flat.cells == 12265 and flat.size == 24 * sk.CELL_BLOCK
+    tiles, cap = n // sk.ROW_TILE, sk.capacity(width, flat.cells)
+    assert cap == 35 * sk.STEP
+    m = sk.hist_sparse.lower(*_one_chip(
+        topo, ((tiles * cap // sk.SUB, sk.SUB), jnp.int32),
+        ((tiles * cap // sk.STEP, sk.SUBS), jnp.int32),
+        ((2, n), jnp.float32), ((n,), jnp.int32)),
+        tiles=tiles, nslots=nslots, cells=flat.cells,
+        interpret=False).compile().memory_analysis()
+    assert m.argument_size_in_bytes >= tiles * cap * 4
+    assert m.output_size_in_bytes == nslots * flat.size * 2 * 4
+    assert m.temp_size_in_bytes <= 24 * n, m
+
+
+def test_sparse_staging_and_row_move_compile_for_v5e(topo, monkeypatch):
+    """The sparse shard's other programs at the cell's shapes: the
+    bucketing of a group of 32 tiles (a sort of 32 x 143,360 slots), the
+    row move of every depth (the (32, n) cells searched for the split's
+    column, the donated node ids the output, no temporary of the cells'
+    size) and the deepest ``gbdt/scan`` on the flat axis (16 built slots
+    of 12,288 cells, the 16 above: it hands over the level and a
+    shortlist of windows, 9 x 256 a slot)."""
+    from rabit_tpu.learn import boosting, histogram
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    flat, n, width = _allstate_flat(), 1 << 25, 32
+    s = SingleDeviceSharding(topo.devices[0])
+    real = jax.ShapeDtypeStruct
+    monkeypatch.setattr(jax, "ShapeDtypeStruct",
+                        lambda shape, dtype: real(shape, dtype, sharding=s))
+    monkeypatch.setattr(histogram, "_CACHE", {})
+    bucket = histogram._bucket_program(n, width, sk.GROUP_TILES, flat.cells)
+    m = bucket.memory_analysis()
+    group = sk.GROUP_TILES * sk.capacity(width, flat.cells) * 4
+    assert group <= m.output_size_in_bytes <= 1.1 * group
+    cells = real((width, n), jnp.int32, sharding=s)
+    for depth in range(6):
+        move = jax.jit(boosting._move_entries, donate_argnums=(1,)).lower(
+            cells, real((n,), jnp.int32, sharding=s),
+            real((1 << depth, 5), jnp.int32, sharding=s)).compile()
+        m = move.memory_analysis()
+        assert m.alias_size_in_bytes == m.output_size_in_bytes == n * 4
+        assert m.temp_size_in_bytes <= 16 * n * 4, (depth, m)
+
+    def gbdt_scan(built, above, build):
+        level = histogram.assemble_level(built[:, None], above, build)
+        return (level,) + histogram.level_shortlist_flat(level, flat, 1.0,
+                                                         1.0)
+
+    p = 16
+    m = jax.jit(gbdt_scan).lower(
+        real((p, flat.size, 2), jnp.float32, sharding=s),
+        real((2, p, 1, flat.size), jnp.float32, sharding=s),
+        real((p,), jnp.int32, sharding=s)).compile().memory_analysis()
+    level = 2 * 2 * p * flat.size * 4
+    k = histogram.SHORTLIST
+    assert level <= m.output_size_in_bytes <= level + (1 << 20)
+    assert m.output_size_in_bytes - level >= 2 * 2 * p * (k + 1) * 256 * 4
+    assert m.temp_size_in_bytes <= 64 * level, m
+
+
+def test_sparse_shards_programs_compile_for_v5e(topo, monkeypatch):
+    """Every program of a sparse boosting job as ``boosting._SparseShard``
+    builds them at the cell's shapes (2^25 rows, depth 6, the levels'
+    histograms kept on the device): the gradient, a level program a
+    width (the slots of a row, ``hist_sparse``, the totals), a row move
+    a depth, the leaf update and a scan a level.  A round's programs
+    need the entries' two copies and 1.1 GB beside them at the widest
+    level (the chip's peak reads 10.25 GB: PERF.md section 5)."""
+    from rabit_tpu.learn import boosting
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    flat, n, width = _allstate_flat(), 1 << 25, 32
+    s = SingleDeviceSharding(topo.devices[0])
+    real = jax.ShapeDtypeStruct
+    monkeypatch.setattr(jax, "ShapeDtypeStruct",
+                        lambda shape, dtype: real(shape, dtype, sharding=s))
+    monkeypatch.setattr(boosting, "_PROGRAMS", {})
+    slots = n // sk.ROW_TILE * sk.capacity(width, flat.cells)
+    shard = object.__new__(boosting._SparseShard)
+    shard.__dict__.update(
+        n=n, rows=n, f=flat.f, width=width, flat=flat, nbin=256,
+        max_depth=6, trees=1, lead=(), subsample=1.0, seed=0,
+        use_pallas=True, compute_dtype=None, scan_by=(1.0, 1.0),
+        has_missing=True, approx=False, pack=None,
+        model=boosting.BoostedModel(cuts=flat.cut_vals,
+                                    cut_ptr=flat.cut_ptr),
+        entries=(real((width, n), jnp.int32),
+                 real((slots // sk.SUB, sk.SUB), jnp.int32),
+                 real((slots // sk.STEP, sk.SUBS), jnp.int32)))
+    prog = shard._programs()
+    assert sorted(prog["level"]) == [1, 2, 4, 8, 16]
+    assert sorted(prog["partition"]) == list(range(6))
+    assert sorted(prog["scan"]) == [1, 2, 4, 8, 16, 32]
+    for p, level in prog["level"].items():
+        m = level.memory_analysis()
+        # the kernel's road reads the bucketed copy, not the move's
+        assert slots * 4 <= m.argument_size_in_bytes < (
+            width * n + slots) * 4, p
+        assert m.output_size_in_bytes == p * flat.size * 2 * 4, p
+        assert m.temp_size_in_bytes <= 5 << 28, (p, m)
+        assert "hist_sparse" in level.as_text()
+    for d, move in prog["partition"].items():
+        m = move.memory_analysis()
+        assert m.alias_size_in_bytes == m.output_size_in_bytes == n * 4, d
+
+
 def _dense16_loop(topo):
     from rabit_tpu.learn import kmeans
 

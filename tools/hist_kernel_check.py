@@ -38,10 +38,21 @@ rows, ragged as they are, 81% of the entries absent station by station,
 8 and 16 slots): the lane-wide body there takes the features chunk by
 chunk on a second grid axis (``lane_chunk``).
 
+``sparse`` (PR 49) holds the sparse-row kernel (``ops/
+sparse_hist_kernel.py``: entries bucketed by cell block, a level's
+histograms as two one-hot products an entry) against XLA's
+``segment_sum`` over the same entries at the sparse boosting cell's
+shape (2^24 rows of up to 32 entries over a flat bin space of 12,265
+cells: 15 columns of 256 bins in nearly every row, the rest indicator
+columns by a power law, ragged rows, rows at node -1), every level's
+width, and against float64 on the first 2^20 rows; then seconds a call
+of each width and of the bucketing.
+
 Not on any cell's path.  Run it through the chip tool:
 
     chiprun --timeout 1800 -- python3 tools/hist_kernel_check.py
     chiprun -- python3 tools/hist_kernel_check.py --cases packed
+    chiprun -- python3 tools/hist_kernel_check.py --cases sparse
 
 It prints one JSON object a line and writes the same lines to
 ``chiprun_out/hist_kernel_check/report.jsonl``; exit code 1 if the
@@ -416,6 +427,118 @@ def run_packed(shape, seed: int, timed: bool, emit,
     return ok
 
 
+# (rows, ELL width, columns of NBIN bins, indicator columns): the sparse
+# boosting cell's shard
+SPARSE_SHAPE = (1 << 24, 32, 15, 4212)
+SPARSE_WIDTHS = (1, 2, 4, 8, 16)
+
+
+def sparse_cells(key, n: int, width: int, wide: int, narrow: int):
+    """``(width, n)`` int32 cells on the device, -1 where a row has no
+    entry (6% of the slots, and every slot of the last 1,000 rows: a
+    ragged shard): slot ``j < wide`` an entry of column ``j``, any of
+    its NBIN bins; the others the one occupied cell of an indicator
+    column drawn by a power law."""
+    ks = jax.random.split(key, 4)
+    first = jnp.arange(wide, dtype=jnp.int32)[:, None] * NBIN \
+        + jax.random.randint(ks[0], (wide, n), 0, NBIN)
+    u = jax.random.uniform(ks[1], (width - wide, n))
+    rest = wide * NBIN + 2 * jnp.minimum(
+        (narrow * u ** 3).astype(jnp.int32), narrow - 1) + 1
+    cells = jnp.concatenate([first, rest])
+    held = jax.random.uniform(ks[2], (width, n)) > 0.06
+    held &= (jnp.arange(n) < n - 1000)[None, :]
+    return jnp.where(held, cells, -1)
+
+
+def run_sparse(shape, seed: int, timed: bool, emit,
+               widths=SPARSE_WIDTHS) -> bool:
+    from rabit_tpu.learn import histogram
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+    from rabit_tpu.ops.sparse_linear_kernel import place
+
+    n, width, wide, narrow = shape
+    cells = wide * NBIN + 2 * narrow + 1
+    tiles = n // sk.ROW_TILE
+    key = jax.random.PRNGKey(seed)
+    cells_t = sparse_cells(key, n, width, wide, narrow)
+    cap = sk.capacity(width, cells)
+    packed = jnp.zeros((tiles * cap // sk.SUB, sk.SUB), jnp.int32)
+    fb = jnp.zeros((tiles * cap // sk.STEP, sk.SUBS), jnp.int32)
+    t0 = time.perf_counter()
+    real = 0
+    for t in range(0, tiles, sk.GROUP_TILES):
+        g = min(sk.GROUP_TILES, tiles - t)
+        part, part_fb, count = histogram._bucket_program(
+            n, width, g, cells)(cells_t, np.int32(t * sk.ROW_TILE))
+        packed = place(packed, part, np.int32(t * cap // sk.SUB))
+        fb = place(fb, part_fb, np.int32(t * cap // sk.STEP))
+        real += int(count)
+    jax.block_until_ready((packed, fb))
+    emit({"sparse": "bucketed", "rows": n, "entries": real,
+          "slots": tiles * cap, "padding": tiles * cap / real - 1.0,
+          "seconds_with_compile": time.perf_counter() - t0})
+    ok = True
+    kernel = jax.jit(functools.partial(
+        sk.hist_sparse, tiles=tiles, cells=cells,
+        interpret=jax.default_backend() != "tpu"),
+        static_argnames=("nslots",))
+    xla = jax.jit(sk.hist_sparse_xla, static_argnums=(3, 4))
+    # the kernel's operand, as float32: by reduce_precision, because the
+    # chip's compiler drops a cast to bfloat16 and back (excess precision
+    # is allowed it) and the reference would then add unrounded weights,
+    # 2^-9 of each apart (found on the chip, PR 49: 3e-5 of a channel's
+    # mass at every width, the same with and without the compensated join)
+    grid = jnp.finfo(hk.DEFAULT_COMPUTE_DTYPE)
+    rounded = jax.jit(lambda gh: jax.lax.reduce_precision(
+        gh, grid.nexp, grid.nmant))
+    head = min(n, SLICE_ROWS) // sk.ROW_TILE * sk.ROW_TILE
+    for nslots in widths:
+        k1, k2 = jax.random.split(jax.random.fold_in(key, nslots))
+        gh = jax.random.normal(k1, (2, n), jnp.float32)
+        slot = jax.random.randint(k2, (n,), -1, nslots)   # -1: at no node
+        got = kernel(packed, fb, gh, slot, nslots=nslots)
+        mass = np.asarray(masses(gh[None], slot[None], nslots)).reshape(
+            nslots, 1, 2)
+        want = xla(cells_t, rounded(gh), slot, nslots, cells)
+        rel = np.abs(np.asarray(got, np.float64) - np.asarray(
+            want, np.float64)) / np.maximum(mass, 1e-30)
+        at = np.unravel_index(int(rel.argmax()), rel.shape)
+        line = {"check": "sparse_vs_segment_sum", "slots": nslots,
+                "worst": float(rel[at]), "at": [int(v) for v in at],
+                "ok": bool(rel[at] <= LIMIT)}
+        # float64 on the first rows: the others masked to node -1
+        part = jnp.where(jnp.arange(n) < head, slot, -1)
+        got = np.asarray(kernel(packed, fb, gh, part, nslots=nslots),
+                         np.float64)
+        c, w, s = (np.asarray(a[..., :head]) for a in (
+            cells_t, rounded(gh), part))
+        want = np.zeros((nslots, got.shape[1], 2))
+        live = (c >= 0) & (s >= 0)[None, :]
+        flat = (s[None, :] * got.shape[1] + c)[live]
+        for ch in range(2):
+            want[:, :, ch] = np.bincount(
+                flat, np.broadcast_to(w[ch].astype(np.float64),
+                                      c.shape)[live],
+                nslots * got.shape[1]).reshape(nslots, -1)
+        mass = np.asarray(masses(gh[None], part[None], nslots)).reshape(
+            nslots, 1, 2)
+        rel = float((np.abs(got - want) / np.maximum(mass, 1e-30)).max())
+        line.update(float64_worst=rel, float64_ok=bool(rel <= LIMIT))
+        ok &= line["ok"] and line["float64_ok"]
+        emit(line)
+        if timed:
+            emit({"timing": "hist_sparse", "slots": nslots,
+                  "channels": 2 * nslots,
+                  "seconds": seconds(functools.partial(
+                      kernel, nslots=nslots), packed, fb, gh, slot),
+                  "segment_sum_seconds": seconds(
+                      lambda *a: xla(*a, nslots, cells), cells_t, gh, slot)
+                  if nslots == widths[-1] else None})
+    emit({"sparse_agrees": bool(ok)})
+    return ok
+
+
 def run(shapes: dict, seed: int, timed: bool, emit) -> bool:
     key = jax.random.PRNGKey(seed)
     ok = True
@@ -443,7 +566,8 @@ def main() -> int:
     ap.add_argument("--no-timing", action="store_true")
     ap.add_argument("--cases", default="bodies,packed",
                     help="bodies: lane-wide against two-level; packed: "
-                    "the lane-wide body with a pack plan against without")
+                    "the lane-wide body with a pack plan against without; "
+                    "sparse: the sparse-row kernel against segment_sum")
     args = ap.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -462,6 +586,9 @@ def main() -> int:
         emit({"device": device.device_kind, "seed": args.seed,
               "crossing": hk._LANE_CROSSING, "cases": args.cases})
         ok, cases = True, args.cases.split(",")
+        if "sparse" in cases:
+            ok &= run_sparse(SPARSE_SHAPE, args.seed, not args.no_timing,
+                             emit)
         if "packed" in cases:
             ok &= run_packed(SHAPES["covtype"], args.seed,
                              not args.no_timing, emit)
