@@ -29,6 +29,7 @@ per-iteration commit structure.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -1184,13 +1185,14 @@ def _merge_summaries(payload, on_device: bool):
 
 def _fetch_shortlist(feats, rows):
     """A level's shortlist (``_DeviceShard.scan``) on the host: the
-    features ``(slots, k)`` and, a slot, the float64 histogram
-    ``(k [+ 1], nbin, 2)`` of those features alone (and the totals
-    row), as ``_scan`` and ``_split`` take a node's."""
+    features ``(slots, k)`` and, a slot, the histogram rows ``(k [+ 1],
+    nbin, 2)`` of those features alone (and the totals row), as
+    :func:`decide_level` takes a level's: the float32 rows as they
+    came, grad and hess apart, seen channel-last."""
     def convert(host):
         feats, rows = host
         program.count("gbdt.hist_bytes_fetched", feats.nbytes + rows.nbytes)
-        return feats, np.moveaxis(rows, 0, -1).astype(np.float64, order="C")
+        return feats, np.moveaxis(rows, 0, -1)
 
     return fetch((feats, rows), convert)
 
@@ -1218,91 +1220,160 @@ def _assemble(level_of: dict, depth: int, built: np.ndarray, order,
     return hists
 
 
-def _scan(hist: np.ndarray, reg_lambda: float, min_child_weight: float,
-          has_missing: bool, widths=None):
-    """``histogram.best_split`` of a node's histogram as the loop holds
-    it: with ``has_missing`` its last feature row is not a feature but
-    holds the node's (grad, hess) totals in its bin 0
-    (``histogram.with_totals``); ``widths`` as ``best_split`` has
-    them."""
-    if has_missing:
-        return histogram.best_split(hist[:-1], reg_lambda, min_child_weight,
-                                    hist[-1, 0], widths)
-    return histogram.best_split(hist, reg_lambda, min_child_weight)
-
-
-# a level's slots are scanned on a few threads where a slot's histogram
-# is this large (numpy's loops release the interpreter): at 968 features
-# a scan is 7 ms of float64 arithmetic and a round has 63 of them with
-# nothing in flight on the device; at 28 features it is microseconds
-# and a thread would cost more than it saves
+# a level is decided in chunks of slots of about this many bytes of
+# its histograms (numpy's temporaries, a dozen float64 grids a chunk,
+# then stay small: at Covertype's 224 slots of 8 rows one pass over the
+# level whole took half as long again as four over 64 slots each), and
+# the chunks of a level of _SCAN_PARALLEL_BYTES and more go to a few
+# threads (numpy's loops release the interpreter): at 968 features a
+# slot is 4 MB and 7 ms of float64 arithmetic with nothing in flight on
+# the device; the chunks of a shortlist's level are a fraction of a
+# millisecond each, and threads only made those slower
 _SCAN_THREADS = 4
-_SCAN_PARALLEL_BYTES = 1 << 20
+_SCAN_CHUNK_BYTES = 1 << 20
+_SCAN_PARALLEL_BYTES = 1 << 24
 _scan_pool = None
 
 
-def _scan_level(hists, reg_lambda: float, min_child_weight: float,
-                has_missing: bool, widths=None) -> list:
-    """``_scan`` of every slot of a level, node or not (``widths``: a
-    slot's rows')."""
+class LevelSplits(NamedTuple):
+    """What :func:`decide_level` decided, an entry a level slot."""
+
+    gain: np.ndarray            # float64; <= 1e-12: the slot stays a leaf
+    feature: np.ndarray
+    cut: np.ndarray             # the split's bin_threshold
+    default_left: np.ndarray    # bool: where the absent rows go
+    side: np.ndarray            # the child the next level builds (1: right)
+    value: np.ndarray           # float64 weights: the slot's as a leaf,
+    left: np.ndarray            # and its children's as a split
+    right: np.ndarray
+
+
+def decide_level(hists, reg_lambda: float, min_child_weight: float,
+                 has_missing: bool, features=None,
+                 widths=None) -> LevelSplits:
+    """Every slot of a level decided in one float64 pass, node or not:
+    ``hists`` are the level's ``(slots, rows, nbin, 2)`` histograms as
+    the loop holds them (the fetched shortlist's float32 rows seen
+    channel-last, or a host engine's float64 level whole); with
+    ``has_missing`` a slot's last row is not a feature but holds its
+    (grad, hess) totals in bin 0 (``histogram.with_totals``);
+    ``features`` ``(slots, rows)`` names each row's feature where the
+    rows are a shortlist and not every feature in order, and ``widths``
+    are the rows' as ``histogram.best_split`` has them.
+
+    The best candidate of each slot is ``histogram.best_splits``', and
+    :func:`_split` takes both sides' sums from the chosen feature's own
+    bins.  A split gives both children the weight their side's sums
+    give; a child that is split in turn gets its own.  The child whose
+    histogram the next level builds is the one with the smaller hessian
+    sum (ties: left), so that the other, derived as parent minus built,
+    is the larger and inherits the parent's accumulation error at most
+    doubled in relative size."""
     global _scan_pool
 
-    def scan(hist, *width):
-        return _scan(hist, reg_lambda, min_child_weight, has_missing, *width)
+    nslots = len(hists)
+    step = max(1, _SCAN_CHUNK_BYTES // max(1, hists[0].nbytes))
+    parts = [slice(at, at + step) for at in range(0, nslots, step)]
 
-    if widths is not None:
-        return [scan(hist, width) for hist, width in zip(hists, widths)]
-    if len(hists) < 2 or hists[0].nbytes < _SCAN_PARALLEL_BYTES:
-        return [scan(hist) for hist in hists]
-    if _scan_pool is None:
-        from concurrent.futures import ThreadPoolExecutor
+    def scan(part):
+        return histogram.best_splits(
+            hists[part], reg_lambda, min_child_weight, has_missing,
+            None if widths is None else widths[part])
 
-        _scan_pool = ThreadPoolExecutor(_SCAN_THREADS,
-                                        thread_name_prefix="gbdt-scan")
-    return list(_scan_pool.map(scan, hists))
+    if hists.nbytes < _SCAN_PARALLEL_BYTES or len(parts) < 2:
+        found = [scan(part) for part in parts]
+    else:
+        if _scan_pool is None:
+            from concurrent.futures import ThreadPoolExecutor
 
-
-def _split(node: TreeNode, tree: list[TreeNode], hist: np.ndarray,
-           reg_lambda: float, min_child_weight: float,
-           has_missing: bool, best=None, features=None) -> int | None:
-    """Choose ``node``'s split on its reduced histogram, or leave it a
-    leaf (None).  A split gives both children the weight their side's
-    sums give; a child that is split in turn gets its own.  Returns the
-    child whose histogram the next level builds, 0 left or 1 right: the
-    one with the smaller hessian sum (ties: left), so that the other,
-    derived as parent minus built, is the larger and inherits the
-    parent's accumulation error at most doubled in relative size.
-
-    With ``has_missing`` the histogram's last feature row is not a
-    feature: its bin 0 holds the node's (grad, hess) totals
-    (``histogram.with_totals``), and the rows absent from the chosen
-    feature, the totals less its bins, go the better way.  ``best`` is
-    the node's ``_scan`` where the caller has made it already, and
-    ``features`` the feature of each of the histogram's rows where they
-    are a shortlist and not every feature in order."""
-    gain, j, t, dl = best or _scan(hist, reg_lambda, min_child_weight,
-                                   has_missing)
-    if has_missing:
-        hist, total = hist[:-1], hist[-1, 0]
-    # both sides from the chosen feature's own bins, in float64
-    g_tot, h_tot = hist[j].sum(axis=0, dtype=np.float64)
-    gl, hl = hist[j, :t + 1].sum(axis=0, dtype=np.float64)
-    if has_missing:
-        gm, hm = histogram.missing_mass(hist[j:j + 1], total)[0]
-        g_tot, h_tot = g_tot + gm, h_tot + hm
-        if dl:
-            gl, hl = gl + gm, hl + hm
+            _scan_pool = ThreadPoolExecutor(_SCAN_THREADS,
+                                            thread_name_prefix="gbdt-scan")
+        found = list(_scan_pool.map(scan, parts))
+    gain, row, cut, default_left = (np.concatenate(x) for x in zip(*found))
+    slot = np.arange(nslots)
+    g_tot, h_tot, gl, hl = _split(
+        cut, default_left, np.asarray(hists[slot, row], np.float64),
+        np.asarray(hists[:, -1, 0], np.float64) if has_missing else None)
     gr, hr = g_tot - gl, h_tot - hl
-    if gain <= 1e-12:
-        node.value = float(-g_tot / (h_tot + reg_lambda))
-        return None
-    node.feature = int(j if features is None else features[j])
-    node.bin_threshold = int(t)
-    node.default_left, node.value = dl, 0.0
-    node.left, node.right = len(tree), len(tree) + 1
-    tree.append(TreeNode(value=float(-gl / (hl + reg_lambda))))
-    tree.append(TreeNode(value=float(-gr / (hr + reg_lambda))))
-    return int(hr < hl)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        # (a slot that holds no node may read 0 / 0: nobody reads it)
+        return LevelSplits(
+            gain, row if features is None else features[slot, row], cut,
+            default_left, (hr < hl).astype(np.int64),
+            -g_tot / (h_tot + reg_lambda), -gl / (hl + reg_lambda),
+            -gr / (hr + reg_lambda))
+
+
+def _split(cut, default_left, hist: np.ndarray, total=None):
+    """``(g_tot, h_tot, gl, hl)``, an entry a slot: the (grad, hess)
+    sums of each slot's node and of the left side of its split, from
+    the chosen feature's own bins ``hist`` ``(slots, nbin, 2)`` in
+    float64, added up in bin order as ``hist[s].sum(axis=0)`` adds
+    them.  With ``total`` ``(slots, 2)``, the nodes' totals, the rows
+    absent from the chosen feature, the totals less its bins
+    (``histogram.missing_mass``), are the node's too and go where
+    ``default_left`` says.  (The third argument is what the benchmark's
+    rehearsals get at to weigh a forest wrongly:
+    ``tests/perfbench/as_if_on_chip_gbdt.py``.)"""
+    run = np.cumsum(hist, axis=1, dtype=np.float64)
+    (g_tot, h_tot), (gl, hl) = run[:, -1].T, run[np.arange(len(run)), cut].T
+    if total is not None:
+        gm, hm = histogram.missing_mass(hist, total).T
+        g_tot, h_tot = g_tot + gm, h_tot + hm
+        gl, hl = (np.where(default_left, gl + gm, gl),
+                  np.where(default_left, hl + hm, hl))
+    return g_tot, h_tot, gl, hl
+
+
+def _level_tables(found: LevelSplits, split: np.ndarray, live: np.ndarray,
+                  leaves: list[list[int]]):
+    """How the rows of a level move on, from its decisions
+    (:func:`_route_round`'s tables, before the trees are written):
+    ``split`` marks the slots whose node is split and ``live`` those
+    that hold a node at all, tree-major, and ``leaves[k]`` are tree
+    k's leaves so far.  Returns the tables ``(trees, w, 4)`` and the
+    level slot built for each slot of this one (-1: none)."""
+    trees = len(leaves)
+    tab = np.zeros((len(live), 4), np.int32)
+    tab[:, 0] = np.where(split, found.feature, 0)
+    tab[:, 1] = np.where(split, found.cut, 0)
+    tab[:, 2] = split & found.default_left
+    # a node that stays a leaf takes the next code of its tree
+    leaf = (live & ~split).reshape(trees, -1)
+    code = np.cumsum(leaf, axis=1) + [[len(mine)] for mine in leaves]
+    tab[:, 3] = np.where(leaf, -code, 0).reshape(-1)
+    build = np.where(split, 2 * np.arange(len(live)) + found.side, -1)
+    return tab.reshape(trees, -1, 4), build.tolist()
+
+
+def _grow(trees, slots: list[int], leaves: list[list[int]],
+          found: LevelSplits, split: np.ndarray) -> list[int]:
+    """Write a level's decisions into the round's trees: the node in
+    slot ``s`` (``slots[s]``, tree-major, -1: none) becomes a leaf of
+    its tree, appended to ``leaves[k]``, or, where ``split[s]``, a split
+    with two new nodes.  Returns the next level's slots."""
+    width = len(slots) // len(trees)
+    nxt = [-1] * (2 * len(slots))
+    cols = [x.tolist() for x in (split, found.feature, found.cut,
+                                 found.default_left, found.value,
+                                 found.left, found.right)]
+    for s, (nid, is_split, feature, cut, default_left, value, left,
+            right) in enumerate(zip(slots, *cols)):
+        if nid < 0:
+            continue
+        k = s // width
+        tree, node = trees[k], trees[k][nid]
+        if not is_split:
+            node.value = value
+            leaves[k].append(nid)
+            continue
+        node.feature, node.bin_threshold = feature, cut
+        node.default_left, node.value = default_left, 0.0
+        node.left, node.right = len(tree), len(tree) + 1
+        nxt[2 * s], nxt[2 * s + 1] = node.left, node.right
+        tree.append(TreeNode(value=left))
+        tree.append(TreeNode(value=right))
+    return nxt
 
 
 def train(values, labels: np.ndarray, num_round: int = 10,
@@ -1354,9 +1425,13 @@ def train(values, labels: np.ndarray, num_round: int = 10,
     as parent minus built, a float32 difference) and ranks every slot's
     features by their best gain in float32, the fetch of each slot's
     shortlist (``histogram.SHORTLIST`` features, their histogram rows
-    and the totals row: kilobytes where the level is megabytes), the
-    float64 ``_split`` on those rows and one program that moves every
-    row to its child.  Cut, default direction, gain, the stopping rule,
+    and the totals row: kilobytes where the level is megabytes), one
+    float64 pass over those rows that decides every slot
+    (:func:`decide_level`) and one program that moves every row to its
+    child; the next level's programs and its allreduce are handed over
+    next, and the level's ``TreeNode`` s are written from the pass's
+    arrays while that kernel runs (:func:`_grow`).  Cut, default
+    direction, gain, the stopping rule,
     child weights and the side to build next are therefore float64 sums
     of fetched bins; float32 only chooses which rows the host looks at.
     The assembled level stays on the device as the next level's parent.
@@ -1392,8 +1467,8 @@ def train(values, labels: np.ndarray, num_round: int = 10,
     program holds every tree's kernel calls (tree k's take ``gh[k]`` and
     ``node[k]``), one ``rabit_tpu.allreduce`` carries K times the built
     slots, one ``gbdt_scan`` ranks K times the level's slots, one fetch
-    brings their shortlists, ``_split`` decides each in float64 and one
-    program moves the rows of all K trees; one more a round adds the K
+    brings their shortlists, one pass decides them all in float64 and
+    one program moves the rows of all K trees; one more a round adds the K
     leaf weights a row.  That is six waits and six collectives a round
     whatever K, where tree by tree it were 6K.  Slots are numbered
     tree-major (:func:`_route_round`), so everything that takes a
@@ -1558,6 +1633,25 @@ def train(values, labels: np.ndarray, num_round: int = 10,
         with program.span("gbdt.sketch"), program.span("learn.dispatch"):
             return shard.sketch()
 
+    def open_level(build, depth: int):
+        """A level's programs handed over: the histograms of the slots
+        ``build`` names, of every tree in one program (a fused bins pass
+        a tree), and ONE allreduce for the level (the per-node XGBoost
+        wire pattern, batched; every rank holds the same reduced
+        histograms, so builds the same).  Where the engine reduces them
+        where they are, they are ranked there too and the host will
+        decide on the few rows a slot it fetches: nothing here waits.
+        Returns the local histograms, the slots built, the kernel calls
+        (``shard.level``) and the level reduced: its shortlist on the
+        device, or the built slots on the host."""
+        with program.span("learn.dispatch"):
+            local, order, calls = shard.level(build, depth)
+        if not device_scan:
+            return local, order, calls, _reduce_level(local)
+        reduced = rabit_tpu.allreduce(local, SUM)
+        with program.span("learn.dispatch"):
+            return local, order, calls, shard.scan(reduced, order, depth)
+
     for round_idx in range(version, num_round):
         with program.span("learn.step", version=round_idx + 1):
             if device_arm and rabit_tpu.device_epoch() != epoch:
@@ -1588,64 +1682,49 @@ def train(values, labels: np.ndarray, num_round: int = 10,
             slots, leaves = [0] * num_class, [[] for _ in trees]
             # the level slot built for each slot of the level above (a
             # root for itself; -1: none)
-            build = list(range(num_class))
+            opened = open_level(list(range(num_class)), 0)
             for depth in range(max_depth):
-                if all(nid < 0 for nid in slots):
+                if opened is None:
                     break
                 with program.span("gbdt.level", depth=depth):
-                    # the built slots' histograms of every tree in one
-                    # program (a fused bins pass a tree) and ONE
-                    # allreduce for the level (the per-node XGBoost wire
-                    # pattern, batched); every rank holds the same
-                    # reduced histograms, so builds the same
-                    with program.span("learn.dispatch"):
-                        local, order, calls = shard.level(build, depth)
+                    local, order, calls, level = opened
                     if device_scan:
-                        # reduced where they are, and ranked there: the
-                        # host decides on the few rows a slot it fetches
-                        reduced = rabit_tpu.allreduce(local, SUM)
-                        with program.span("learn.dispatch"):
-                            short = shard.scan(reduced, order, depth)
                         with program.span("gbdt.level.fetch"):
-                            feats, hists = _fetch_shortlist(*short)
-                    else:
-                        built = _reduce_level(local)
-                        feats = [None] * len(slots)
+                            feats, hists = _fetch_shortlist(*level)
                     with program.span("gbdt.split"):
                         if not device_scan:
-                            hists = _assemble(level_of, depth, built, order,
-                                              len(slots))
+                            feats, hists = None, _assemble(
+                                level_of, depth, level, order, len(slots))
                             if sparse:
                                 # the shortlist the device would hand
                                 # over, ranked here
                                 feats, hists = histogram.flat_shortlist(
                                     hists, shard.flat, reg_lambda,
                                     min_child_weight)
-                        # every slot is scanned, node or not: a round's
+                        # every slot is decided, node or not: a round's
                         # host work is then a full forest's whatever the
                         # trees, as the device's is (static shapes), and
                         # a job's rounds take the same time
-                        best = _scan_level(
+                        found = decide_level(
                             hists, reg_lambda, min_child_weight, has_missing,
+                            feats,
                             shard.flat.widths[feats] if sparse else None)
-                        build, default_left = [-1] * len(slots), 0
-                        width = len(slots) // num_class
-                        for s, nid in enumerate(slots):
-                            if nid < 0:
-                                continue
-                            tree = trees[s // width]
-                            side = _split(
-                                tree[nid], tree, hists[s], reg_lambda,
-                                min_child_weight, has_missing, best[s],
-                                feats[s])
-                            if side is not None:
-                                build[s] = 2 * s + side
-                                default_left += tree[nid].default_left
-                        tabs, slots = _route_round(trees, slots, leaves)
+                        live = np.asarray(slots) >= 0
+                        split = live & (found.gain > 1e-12)
+                        tabs, build = _level_tables(found, split, live,
+                                                    leaves)
                     with program.span("gbdt.partition"):
                         shard.partition(tabs, depth)
-                live = sum(s >= 0 for s in order)
+                    # the next level's programs before this one's trees
+                    # are written: the host writes them under the kernel
+                    opened = open_level(build, depth + 1) \
+                        if depth + 1 < max_depth and split.any() else None
+                    with program.span("gbdt.trees"):
+                        slots = _grow(trees, slots, leaves, found, split)
+                built = sum(s >= 0 for s in order)
                 program.count("gbdt.levels")
+                program.count("gbdt.split_passes")
+                program.count("gbdt.split_slots", len(live))
                 read, whole = shard.partition_rows(depth)
                 program.count("gbdt.partition_rows_read", read)
                 program.count("gbdt.partition_rows_whole", whole)
@@ -1664,11 +1743,11 @@ def train(values, labels: np.ndarray, num_round: int = 10,
                     program.count("gbdt.sparse.payload_bytes", local.nbytes)
                 program.count("gbdt.features_packed", calls[2])
                 program.count("gbdt.channels", 2 * len(order))
-                program.count("gbdt.channels_live", 2 * live)
-                program.count("gbdt.hists_derived", live if depth else 0)
-                program.count("gbdt.nodes_split",
-                              sum(s >= 0 for s in build))
-                program.count("gbdt.splits_default_left", default_left)
+                program.count("gbdt.channels_live", 2 * built)
+                program.count("gbdt.hists_derived", built if depth else 0)
+                program.count("gbdt.nodes_split", int(split.sum()))
+                program.count("gbdt.splits_default_left",
+                              int(found.default_left[split].sum()))
             # the nodes at the depth limit are leaves, with the weights
             # their parents' histograms gave them
             with program.span("gbdt.leaf"):

@@ -737,9 +737,12 @@ def missing_mass(hist: np.ndarray, total) -> np.ndarray:
 
 
 def _mass(present: np.ndarray, total: np.ndarray) -> np.ndarray:
-    """:func:`missing_mass` from the features' own sums (f, 2)."""
+    """:func:`missing_mass` from the features' own sums (f, 2);
+    ``total`` the node's (2,), or a row a feature where the features are
+    those of several nodes side by side (:func:`best_splits`)."""
     mass = total - present
-    mass[np.abs(mass[:, 1]) <= MISSING_MASS_FLOOR * abs(total[1])] = 0.0
+    none = np.abs(mass[:, 1]) <= MISSING_MASS_FLOOR * np.abs(total[..., 1])
+    mass[none] = 0.0
     return mass
 
 
@@ -753,7 +756,10 @@ def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
     (:func:`missing_mass`): the gain is then the better of sending
     those rows left and right and ``default_left`` says which won
     (ties: left).  Without, every row is in a bin of every feature, the
-    totals are the bins' own and ``default_left`` is None.
+    totals are the bins' own and ``default_left`` is None.  A candidate
+    is scored from its own feature row and ``total`` alone, so the rows
+    may be those of several nodes side by side, ``total`` then ``(f,
+    2)``, each row's node's (:func:`best_splits`).
 
     A candidate that leaves a side's hessian sum under
     ``min_child_weight`` is not eligible and reads -inf (XGBoost's
@@ -765,15 +771,20 @@ def split_candidates(hist: np.ndarray, reg_lambda: float = 1.0,
     # own last entries: in float32 a node of millions of rows has sums
     # with an ulp of 0.5, a total summed in another order than the
     # prefix can then leave an empty right side at hr = -1, and
-    # hr + lambda = 0 is an infinite gain
-    sums = np.cumsum(np.asarray(hist, np.float64), axis=1)
-    gl, hl = sums[:, :-1, 0], sums[:, :-1, 1]
-    gt, ht = sums[:, -1:, 0], sums[:, -1:, 1]
+    # hr + lambda = 0 is an infinite gain.  Grad and hess are summed
+    # apart and widened as they are: where the caller holds them apart
+    # (a fetched shortlist seen channel-last) each sum runs along
+    # contiguous memory and no float64 copy of the histogram is made
+    hist = np.asarray(hist)
+    sg = np.cumsum(hist[..., 0], axis=1, dtype=np.float64)
+    sh = np.cumsum(hist[..., 1], axis=1, dtype=np.float64)
     mass = None
     if total is not None:
-        mass = _mass(sums[:, -1], np.asarray(total, np.float64))
+        mass = _mass(np.stack([sg[:, -1], sh[:, -1]], axis=1),
+                     np.asarray(total, np.float64))
         mass = mass[:, :1], mass[:, 1:]
-    return _score_sides(gl, hl, gt, ht, mass, reg_lambda, min_child_weight)
+    return _score_sides(sg[:, :-1], sh[:, :-1], sg[:, -1:], sh[:, -1:], mass,
+                        reg_lambda, min_child_weight)
 
 
 def _score_sides(gl, hl, gt, ht, mass, reg_lambda: float,
@@ -826,6 +837,40 @@ def best_split(hist: np.ndarray, reg_lambda: float,
     j, t = np.unravel_index(int(gain.argmax()), gain.shape)
     return (float(gain[j, t]), int(j), int(t),
             True if left is None else bool(left[j, t]))
+
+
+def best_splits(hists: np.ndarray, reg_lambda: float,
+                min_child_weight: float | None, totals: bool = False,
+                widths=None):
+    """:func:`best_split` of every slot of a level in one pass: ``(gain,
+    row, cut, default_left)``, an entry a slot, of ``(slots, rows, nbin,
+    2)`` histograms of any float type and layout (with ``totals`` a
+    slot's last row is no feature: its bin 0 holds the slot's (grad,
+    hess) totals, :func:`with_totals`; ``widths`` ``(slots, rows)`` as
+    :func:`best_split` has a slot's).  The slots' feature rows go
+    through :func:`split_candidates` side by side, one cumulative sum
+    and one scoring for the level, and each slot takes the first of its
+    own best candidates, feature-major: the same float64 operations a
+    candidate as slot by slot, so the same decisions to the last bit."""
+    hists = np.asarray(hists)
+    nslots, rows, nbin, _ = hists.shape
+    f = rows - totals
+    total = None
+    if totals:
+        total = np.repeat(np.asarray(hists[:, f, 0], np.float64), f, axis=0)
+    # grad and hess apart, a row a (slot, feature): a view of a
+    # shortlist as fetched, else the one copy of the pass
+    planes = np.moveaxis(hists[:, :f], -1, 0).reshape(2, nslots * f, nbin)
+    gain, left = split_candidates(np.moveaxis(planes, 0, -1), reg_lambda,
+                                  min_child_weight, total)
+    if widths is not None:
+        gain[np.arange(nbin - 1) >= np.reshape(widths, (-1, 1)) - 1] = -np.inf
+    gain = gain.reshape(nslots, f * (nbin - 1))
+    at = gain.argmax(axis=1)
+    slot = np.arange(nslots)
+    return (gain[slot, at], at // (nbin - 1), at % (nbin - 1),
+            np.ones(nslots, bool) if left is None
+            else left.reshape(gain.shape)[slot, at])
 
 
 # features a slot's shortlist holds (:func:`level_shortlist`): the host

@@ -4,6 +4,7 @@ import sys
 import numpy as np
 import pytest
 
+import boosting_oracle as oracle
 from rabit_tpu.learn import boosting
 
 
@@ -352,17 +353,24 @@ def test_no_host_array_of_length_n_inside_the_loop(arm, monkeypatch, which,
 def _spy_on_loop(monkeypatch):
     """Records what ``train``'s level loop asks and uses: every
     ``shard.level(build)`` call as ``(build, local, order)`` and every
-    ``_split`` call as ``(round, node id, histogram, child to build)``."""
-    levels, splits, rounds = [], [], [-1]
-    split = boosting._split
+    node a level decided as ``(round, node id, histogram, child to
+    build)`` (None: it stays a leaf), from ``decide_level``'s histograms
+    and the slots ``_grow`` writes."""
+    levels, splits, rounds, held = [], [], [-1], []
+    decide, grow = boosting.decide_level, boosting._grow
 
-    def seen_split(node, tree, hist, *a):
-        if len(tree) == 1:
+    def seen_decide(hists, *a):
+        held[:] = [np.array(hists, np.float64)]
+        return decide(hists, *a)
+
+    def seen_grow(trees, slots, leaves, found, split):
+        if len(trees[0]) == 1:
             rounds[0] += 1
-        nid = next(i for i, other in enumerate(tree) if other is node)
-        side = split(node, tree, hist, *a)
-        splits.append((rounds[0], nid, np.array(hist), side))
-        return side
+        splits.extend(
+            (rounds[0], nid, held[0][s],
+             int(found.side[s]) if split[s] else None)
+            for s, nid in enumerate(slots) if nid >= 0)
+        return grow(trees, slots, leaves, found, split)
 
     def seen_level(level):
         def wrapper(self, build, depth):
@@ -371,7 +379,8 @@ def _spy_on_loop(monkeypatch):
             return local, order, calls
         return wrapper
 
-    monkeypatch.setattr(boosting, "_split", seen_split)
+    monkeypatch.setattr(boosting, "decide_level", seen_decide)
+    monkeypatch.setattr(boosting, "_grow", seen_grow)
     for cls in (boosting._HostShard, boosting._DeviceShard):
         monkeypatch.setattr(cls, "level", seen_level(cls.level))
     return levels, splits
@@ -513,17 +522,16 @@ def test_empty_side_of_a_derived_histogram_does_not_win_the_argmax(
     assert not unmasked[0, 2] < np.inf           # the trap
     gain, _ = histogram.split_candidates(hist, lam, 1e-3, total)
     assert gain[0, 2] == -np.inf and np.isfinite(gain[1]).all()
-    if has_missing:                  # as the loop hands it to _split
+    if has_missing:                  # as the loop hands it to the pass
         hist = np.concatenate([hist, np.zeros((1, 4, 2))])
         hist[-1, 0] = total
-    tree = [boosting.TreeNode()]
-    side = boosting._split(tree[0], tree, hist, lam, 1e-3, has_missing)
-    assert (tree[0].feature, tree[0].bin_threshold) == (1, 1)
-    assert side == 0 and len(tree) == 3          # hl == hr: left is built
+    found = boosting.decide_level(hist[None], lam, 1e-3, has_missing)
+    assert (found.feature[0], found.cut[0]) == (1, 1)
+    # hl == hr: left is built
+    assert found.gain[0] > 1e-12 and found.side[0] == 0
     # a node with no eligible candidate at all stays a leaf
-    tree = [boosting.TreeNode()]
-    assert boosting._split(tree[0], tree, hist, lam, 16.0,
-                           has_missing) is None and len(tree) == 1
+    assert boosting.decide_level(hist[None], lam, 16.0,
+                                 has_missing).gain[0] <= 1e-12
 
 
 @pytest.mark.parametrize("which", ["host", "device"])
@@ -722,21 +730,23 @@ def test_every_slot_of_a_level_is_scanned_whatever_the_tree(arm, monkeypatch,
     """A round's host work is a full tree's, node or not in a slot (as
     the device's programs build every slot): 1 + 2 + 4 + 8 scans a
     depth-4 round, also where the tree stops early; and the forest is
-    the one a scan of the nodes alone commits."""
+    the one the decision made slot by slot commits."""
     X, y = _station_rows(n=1500)
     kw = dict(num_round=2, max_depth=4, nbin=16, min_child_weight=40.0,
               use_pallas=False)
     arm(which)
-    plain = boosting.train(X, y, **kw)
+    with monkeypatch.context() as patch:
+        patch.setattr(boosting, "decide_level", oracle.decide_level)
+        plain = boosting.train(X, y, **kw)
     assert max(len(t) for t in plain.trees) < 31       # stops early
-    scans, scan = [], boosting._scan
+    scans, decide = [], boosting.decide_level
 
-    def seen_scan(hist, lam, mcw, has_missing):
-        scans.append(hist.any())
-        return scan(hist, lam, mcw, has_missing)
+    def seen_decide(hists, *a):
+        scans.extend(np.asarray(hists).any(axis=(1, 2, 3)))
+        return decide(hists, *a)
 
     arm(which)
-    monkeypatch.setattr(boosting, "_scan", seen_scan)
+    monkeypatch.setattr(boosting, "decide_level", seen_decide)
     model = boosting.train(X, y, **kw)
     assert _structure(model) == _structure(plain)
     levels = [sum(1 for n in t if n.feature >= 0) > 0 for t in model.trees]
@@ -744,29 +754,59 @@ def test_every_slot_of_a_level_is_scanned_whatever_the_tree(arm, monkeypatch,
     assert 0 < sum(1 for live in scans if not live) < len(scans)
 
 
-@pytest.mark.parametrize("has_missing", [False, True], ids=["dense", "nan"])
-def test_a_level_of_large_slots_is_scanned_on_threads_to_the_same_result(
-        monkeypatch, has_missing):
-    """Slots of a megabyte or more (968 features) are scanned on a few
-    threads, smaller ones (28 features) one after the other: the list
-    is the same, slot for slot."""
-    rng = np.random.default_rng(73)
-    hists = rng.random((8, 40 + has_missing, 32, 2))
-    hists[:, :, :, 0] -= 0.5
-    if has_missing:
-        hists[:, -1] = 0
-        hists[:, -1, 0] = 2 * hists[:, 0].sum(axis=1)
-    assert hists[0].nbytes < boosting._SCAN_PARALLEL_BYTES
-    serial = boosting._scan_level(hists, 1.0, 1.0, has_missing)
-    monkeypatch.setattr(boosting, "_SCAN_PARALLEL_BYTES", 0)
-    threaded = boosting._scan_level(hists, 1.0, 1.0, has_missing)
-    assert boosting._scan_pool is not None
-    assert threaded == serial and len(serial) == 8
-    assert serial == [boosting._scan(h, 1.0, 1.0, has_missing)
-                      for h in hists]
-    # one slot is not worth a thread
-    assert boosting._scan_level(hists[:1], 1.0, 1.0, has_missing) \
-        == serial[:1]
+@pytest.mark.parametrize("which", ["host", "device"])
+@pytest.mark.parametrize("job", ["dense", "nan", "stations", "approx"])
+def test_the_loop_commits_the_forest_decided_slot_by_slot_in_the_order_it_had(
+        arm, monkeypatch, which, job):
+    """A level decided in one pass and its trees written after the next
+    level is handed over: the committed forest is the one the loop
+    committed when it decided a slot at a time (``boosting_oracle``),
+    node for node and to the last bit of every weight; one allreduce a
+    level, in the order it had; the tables handed to ``shard.partition``
+    are ``_route_round``'s of the trees."""
+    X, y = _station_rows(n=1500) if job == "stations" \
+        else _tabular(missing=job == "nan")
+    kw = dict(num_round=3, max_depth=4, nbin=16, use_pallas=False)
+    if job == "stations":
+        kw["min_child_weight"] = 40.0           # trees that stop early
+    if job == "approx":
+        kw["tree_method"] = "approx"
+    model = oracle.held_to_the_loop_of_then(arm, which, monkeypatch, X, y,
+                                            **kw)
+    assert model.has_missing == (job in ("nan", "stations"))
+    full = sum(len(t) == 31 for t in model.trees)
+    assert (full < 3) == (job == "stations")
+
+
+@pytest.mark.parametrize("kill,classes", [("1,2,2,0", 1), ("0,3,1,0", 1),
+                                          ("1,1,2,0", 3)])
+def test_a_rank_killed_inside_a_round_commits_the_undisturbed_forest(
+        tmp_path, native_lib, capfd, kill, classes):
+    """A rank dies between two levels of a round (at its second or third
+    collective of that version: a level's allreduce, issued before the
+    level above's trees are written) and resumes from the round's
+    checkpoint; the replay still sees the sequence the survivors saw.
+    Every rank commits the forest of the job nobody disturbed, bit for
+    bit."""
+    from rabit_tpu.tracker.launch_local import launch
+
+    X, y = _xor_data(n=400)
+    if classes > 1:
+        y = (y + (X[:, 0] > 0.5)).astype(np.float32)      # 0, 1, 2
+    np.save(tmp_path / "X.npy", X)
+    np.save(tmp_path / "y.npy", y)
+    cmd = [sys.executable, "tests/workers/boosting_dist.py", str(tmp_path)]
+    env = {"RABIT_ENGINE": "mock", "BOOST_NUM_CLASS": str(classes),
+           "BOOST_MIN_ACC": "0.8"}
+    assert launch(2, cmd, extra_env={**env, "BOOST_SAVE": "calm"}) == 0
+    assert launch(2, cmd, extra_env={**env, "RABIT_MOCK": kill,
+                                     "BOOST_SAVE": "died"}) == 0
+    said = "".join(capfd.readouterr())
+    assert "killed at version=%s seq=%s" % tuple(kill.split(",")[1:3]) in said
+    calm = _saved(tmp_path, "calm", 2)
+    assert len(calm[0][0]) >= 15 * classes * 3
+    for nodes, _cuts in calm + _saved(tmp_path, "died", 2):
+        np.testing.assert_array_equal(nodes, calm[0][0])
 
 
 # ----------------------------------------------------------------------

@@ -11,6 +11,7 @@ import sys
 import numpy as np
 import pytest
 
+import boosting_oracle as oracle
 import rabit_tpu
 from rabit_tpu.learn import boosting, histogram
 
@@ -129,16 +130,9 @@ def _grow_one(bins, grad, hess, nbin, max_depth, reg_lambda, mcw,
             bins, grad, hess, node, order, nbin, use_pallas=False,
             totals=has_missing))
         hists = boosting._assemble(level_of, depth, built, order, len(slots))
-        best = boosting._scan_level(hists, reg_lambda, mcw, has_missing)
-        build = [-1] * len(slots)
-        for s, nid in enumerate(slots):
-            if nid < 0:
-                continue
-            side = boosting._split(tree[nid], tree, hists[s], reg_lambda,
-                                   mcw, has_missing, best[s], None)
-            if side is not None:
-                build[s] = 2 * s + side
-        tab, slots = boosting._route(tree, slots, leaves)
+        tabs, build, slots, _ = oracle.grow_level(
+            [tree], slots, [leaves], hists, reg_lambda, mcw, has_missing)
+        tab = tabs[0]
         live = node >= 0
         feat, thr, dleft, leaf = tab[np.where(live, node, 0)].T
         b = bins[np.arange(n), feat]
@@ -148,6 +142,23 @@ def _grow_one(bins, grad, hess, nbin, max_depth, reg_lambda, mcw,
     vals = boosting._leaf_values(tree, slots, leaves, max_depth)
     width = 1 << max_depth
     return tree, vals[np.where(node >= 0, node, width - node - 1)]
+
+
+@pytest.mark.parametrize("which", ["host", "device"])
+@pytest.mark.parametrize("num_class,missing", [(3, False), (7, True)],
+                         ids=["3-dense", "7-nan"])
+def test_a_forests_level_decided_in_one_pass_commits_the_slot_by_slot_forest(
+        arm, monkeypatch, which, num_class, missing):
+    """K trees' slots in one pass, tree-major, and their trees written
+    after the next level is handed over: the forest of the loop that
+    decided a slot at a time, node for node and bit for bit, one
+    allreduce a level in the order it had, the row-move tables
+    ``_route_round``'s of the trees."""
+    X, y = _classes(k=num_class, missing=missing)
+    model = oracle.held_to_the_loop_of_then(
+        arm, which, monkeypatch, X, y, num_round=2, max_depth=4, nbin=16,
+        loss="softprob", num_class=num_class, use_pallas=False)
+    assert len(model.trees) == 2 * num_class
 
 
 def _one_tree_at_a_time(X, y, num_class, loss, num_round, max_depth, nbin,

@@ -111,6 +111,21 @@ def test_sparse_forest_against_the_plain_reference(arm, which, use_pallas):
     assert got["splits"] >= 10 and 0 < got["default_left"] < got["splits"]
 
 
+@pytest.mark.parametrize("which", ["host", "device"])
+def test_a_flat_level_decided_in_one_pass_commits_the_slot_by_slot_forest(
+        arm, monkeypatch, which):
+    """Windows of unequal widths and a totals row, every slot in one
+    pass: the forest of the loop that decided a slot at a time, node
+    for node and bit for bit, its hand-overs in the order they had."""
+    import boosting_oracle as oracle
+
+    rows, labels = _rows()
+    model = oracle.held_to_the_loop_of_then(
+        arm, which, monkeypatch, rows, labels, num_round=3,
+        use_pallas=False, **KW)
+    assert model.has_missing and model.cut_ptr is not None
+
+
 def test_reference_bins_are_the_programs(arm):
     rows, _labels = _rows(n=500)
     cut_ptr, cut_vals = histogram.sparse_cuts(rows, NBIN)

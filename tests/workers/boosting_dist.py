@@ -26,10 +26,12 @@ from rabit_tpu.learn import boosting
 
 def watch_built():
     """Record which level slots every ``shard.level`` call builds and
-    the hessian sum of every histogram ``_split`` scans, by round."""
-    builds, weights = [], []
-    shard, split = boosting._HostShard, boosting._split
+    the hessian sum of every node's histogram a level decides on, by
+    round."""
+    builds, weights, held = [], [], []
+    shard = boosting._HostShard
     grad_hess, level = shard.grad_hess, shard.level
+    decide, grow = boosting.decide_level, boosting._grow
 
     def seen_grad_hess(self, round_idx):
         builds.append([])
@@ -40,15 +42,20 @@ def watch_built():
         builds[-1].append(list(build))
         return level(self, build, depth)
 
-    def seen_split(node, tree, hist, *a):
-        nid = next(i for i, other in enumerate(tree) if other is node)
+    def seen_decide(hists, *a):
         # the last feature row: a feature nobody misses, or, with
         # missing values, the node's totals in its bin 0
-        weights[-1][nid] = float(hist[-1, :, 1].sum())
-        return split(node, tree, hist, *a)
+        held[:] = [np.asarray(hists)[:, -1, :, 1].sum(axis=1)]
+        return decide(hists, *a)
+
+    def seen_grow(trees, slots, *a):
+        assert len(trees) == 1, "one tree a round"
+        weights[-1].update((nid, float(held[0][s]))
+                           for s, nid in enumerate(slots) if nid >= 0)
+        return grow(trees, slots, *a)
 
     shard.grad_hess, shard.level = seen_grad_hess, seen_level
-    boosting._split = seen_split
+    boosting.decide_level, boosting._grow = seen_decide, seen_grow
     return builds, weights
 
 
