@@ -187,6 +187,7 @@ def fetch(result, convert=None):
     with program.span("learn.fetch.wait"):
         for a in arrays:
             a.block_until_ready()
+        program.waited()
     with program.span("learn.fetch.copy"):
         host = (tuple(np.asarray(a) for a in arrays) if arrays is result
                 else np.asarray(result))

@@ -755,6 +755,7 @@ class _ChunkStream:
                 return False
             with program.span("stream.wait"):
                 self.reading.popleft().block_until_ready()
+                program.waited()
         c = self.order[self.turn]
         self.turn = (self.turn + 1) % len(self.order)
         with program.span("stream.put"):
@@ -772,7 +773,6 @@ class _ChunkStream:
               "the pass asked for %d", got, c)
         program.count("stream.chunks")
         program.count("stream.rows", self.real_rows[c])
-        program.count("stream.bytes", sum(a.nbytes for a in triple))
         return triple
 
     def taken(self, result) -> None:
@@ -826,9 +826,9 @@ def _ell_stream_stats(centroids, payload, d: int):
     same calls in the same order give the same bits under any budget.
 
     The span ``learn.stream`` covers the pass's hand-overs.  The
-    counters ``stream.chunks``, ``stream.rows`` and ``stream.bytes``
-    count a chunk as the pass takes it (what is handed over ahead, for a
-    pass that has not run, is in none of them)."""
+    counters ``stream.chunks`` and ``stream.rows`` count a chunk as the
+    pass takes it (what is handed over ahead, for a pass that has not
+    run, is in neither)."""
     import jax
 
     resident, host, d_pad, nnz, stream = payload
@@ -841,7 +841,11 @@ def _ell_stream_stats(centroids, payload, d: int):
     with program.span("learn.stream"):
         for c in range(len(host)):
             chunk = resident.get(c)
-            acc = call(acc, cent, *(chunk or stream.take(c)))
+            # the call before's result stays alive across the hand-over,
+            # where the span table asks it whether the device still ran
+            # (a result that was let go of counts as landed)
+            before = acc
+            acc = call(before, cent, *(chunk or stream.take(c)))
             program.enqueued(acc)
             if chunk is None:
                 stream.taken(acc)
@@ -1260,14 +1264,19 @@ def run(data: SparseMat, num_cluster: int, max_iter: int,
                     local, queued = queued, None
                     if it > version:     # the job's first: of no commit
                         program.count("learn.ahead")
-                total = rabit_tpu.allreduce(local, SUM)
+                reduced = total = rabit_tpu.allreduce(local, SUM)
                 with program.span("learn.update"):
                     if not total.is_fully_addressable:
                         # replicated over the process mesh: this rank's
                         # replica, no copy and no wait
                         total = total.addressable_shards[0].data
                     cent, counts = update(on_device(total))
+                    # `reduced`, the array the engine named, is alive
+                    # here for the span table to ask whether the
+                    # all-reduce still ran (one let go of counts as
+                    # landed)
                     program.enqueued(cent)
+                del reduced
                 program.count("learn.device_updates")
                 if it + 1 < max_iter:
                     with program.span("learn.dispatch"):

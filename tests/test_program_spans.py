@@ -22,7 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STAGE = {"stage.to_ell", "stage.clamp", "stage.put"}
 LOOP = {"learn.step", "learn.dispatch", "learn.fetch", "learn.fetch.wait",
         "learn.fetch.copy", "learn.update"}
-COLUMNS = ("n", "total_s", "max_s", "self_s", "exposed_s")
+COLUMNS = ("n", "total_s", "max_s", "self_s", "exposed_s", "unsure_s")
 # what kmeans.run leaves on engine `empty`: its two calls before the
 # loop, staging, the loop, the commit as far as `empty` has layers
 KMEANS_SPANS = (STAGE | LOOP
@@ -184,16 +184,17 @@ def test_self_seconds_of_a_parent_and_its_children_add_up_to_its_total(clock):
 
 def test_an_interval_is_exposed_if_it_began_with_the_device_idle(clock):
     """A step that hands a program over, waits for it and copies the
-    result back: the dispatch and the copy began with nothing in flight
-    and count under themselves and the step; the launch after the
-    dispatch and the wait began with the program owed and count
-    nothing, though it landed inside the wait."""
+    result back: the dispatch up to its hand-over and the copy began
+    with nothing in flight and count under themselves and the step; the
+    launch after the dispatch and the wait began with the program owed
+    and are not exposed, though it landed inside the wait: the wait is
+    what the table is unsure of."""
     owed = Owed()
     with program.span("learn.step"):                # 100 .. 109
         clock.now = 101.0
         with program.span("learn.dispatch"):        # 101 .. 102
-            program.enqueued(owed)
             clock.now = 102.0
+            program.enqueued(owed)
         clock.now = 103.0                           # the launch
         with program.span("learn.fetch.wait"):      # 103 .. 105
             owed.landed = True
@@ -209,8 +210,11 @@ def test_an_interval_is_exposed_if_it_began_with_the_device_idle(clock):
     # 100-101 and the dispatch, then everything after the wait
     assert s["learn.step.exposed_s"] == 6.0
     assert s["learn.step.self_s"] == 4.0
+    assert s["learn.fetch.wait.unsure_s"] == s["learn.step.unsure_s"] == 2.0
+    assert s["learn.dispatch.unsure_s"] == s["learn.fetch.copy.unsure_s"] == 0
     for name in span_names(s):
-        assert s[name + ".exposed_s"] <= s[name + ".total_s"]
+        assert s[name + ".exposed_s"] + s[name + ".unsure_s"] \
+            <= s[name + ".total_s"]
 
 
 def test_a_result_still_owed_exposes_nothing_however_many_spans_pass(clock):
@@ -317,9 +321,10 @@ def test_time_between_spans_after_the_first_step_is_under_no_span(clock):
     s = program.stats()
     assert s[program.NO_SPAN + ".self_s"] == 5.0
     assert s[program.NO_SPAN + ".exposed_s"] == 2.0
+    assert s[program.NO_SPAN + ".unsure_s"] == 0.0
     assert set(s) == {name + "." + column for column in COLUMNS
                       for name in ("stage.put", "learn.step")} | {
-        program.NO_SPAN + ".self_s", program.NO_SPAN + ".exposed_s"}
+        program.NO_SPAN + "." + column for column in COLUMNS[3:]}
     program.reset()
     assert program.stats() == {}
     clock.now += 4.0
@@ -329,7 +334,188 @@ def test_time_between_spans_after_the_first_step_is_under_no_span(clock):
     # is owed still)
     assert program.stats() == {
         "learn.step.n": 1, "learn.step.total_s": 1.0, "learn.step.max_s": 1.0,
-        "learn.step.self_s": 1.0, "learn.step.exposed_s": 0.0}
+        "learn.step.self_s": 1.0, "learn.step.exposed_s": 0.0,
+        "learn.step.unsure_s": 0.0}
+
+
+# ------------------ a hand-over is a boundary; the band; a session's table
+def test_a_hand_over_inside_an_interval_that_began_idle_cuts_it(clock):
+    """Only the part of the step's own time before the hand-over is
+    exposed; what follows it began busy.  Before this rule the seven
+    seconds to the wait's enter counted whole."""
+    owed, second = Owed(), Owed()
+    with program.span("learn.step"):                # 100 .. 110
+        clock.now = 103.0
+        program.enqueued(owed)                      # found the device idle
+        clock.now = 105.0
+        program.enqueued(second)                    # found it busy
+        clock.now = 107.0
+        with program.span("learn.fetch.wait"):      # 107 .. 109
+            clock.now = 109.0
+            owed.landed = second.landed = True
+        clock.now = 110.0
+    s = program.stats()
+    assert s["learn.step.exposed_s"] == 3.0 + 1.0
+    assert s["learn.step.unsure_s"] == s["learn.fetch.wait.unsure_s"] == 2.0
+    assert s["learn.step.self_s"] == 8.0            # a hand-over is no span
+    assert s["learn.fetch.wait.exposed_s"] == 0.0
+    assert (s["learn.handovers"], s["learn.handovers_idle"]) == (2, 1)
+
+
+def test_an_interval_that_began_busy_and_ended_idle_is_unsure_alone(clock):
+    """A commit under a kernel that it outlasts: the second before the
+    kernel's end is nobody's, the serialisation inside which it ended is
+    the band's, what follows is exposed; no second is in two columns."""
+    owed = Owed()
+    program.enqueued(owed)
+    with program.span("commit"):                    # 100 .. 108
+        clock.now = 101.0
+        with program.span("commit.serialize"):      # 101 .. 106
+            clock.now = 106.0
+            owed.landed = True
+        clock.now = 108.0
+    s = program.stats()
+    assert (s["commit.serialize.exposed_s"],
+            s["commit.serialize.unsure_s"]) == (0.0, 5.0)
+    assert (s["commit.exposed_s"], s["commit.unsure_s"]) == (2.0, 5.0)
+    assert s["commit.self_s"] == 3.0 and s["commit.total_s"] == 8.0
+    assert "learn.handovers" not in s               # no step was open
+
+
+def test_a_wait_is_never_unsure_and_counts_what_it_came_back_to(clock):
+    """``block_until_ready`` says where the device's work ended: at the
+    wait's end, a notice ago.  The wait's seconds are nobody's; the
+    wait that comes back to an idle device is counted for the notice."""
+    first, second = Owed(), Owed()
+    with program.span("learn.step"):                # 100 .. 110
+        program.enqueued(first)
+        program.enqueued(second)
+        with program.span("learn.fetch.wait"):      # 100 .. 104
+            clock.now = 104.0
+            first.landed = True                     # the newer one runs on
+            program.waited()
+        with program.span("learn.fetch.wait"):      # 104 .. 107
+            clock.now = 107.0
+            second.landed = True
+            program.waited()
+        with program.span("learn.fetch.wait"):      # 107 .. 108: no wait,
+            clock.now = 108.0                       # the result was there
+            program.waited()
+        clock.now = 110.0
+    s = program.stats()
+    assert s["learn.fetch.wait.unsure_s"] == s["learn.step.unsure_s"] == 0.0
+    assert s["learn.fetch.wait.exposed_s"] == 1.0
+    assert s["learn.step.exposed_s"] == 3.0
+    assert (s["learn.waits"], s["learn.waits_idle"]) == (3, 2)
+    assert (s["learn.handovers"], s["learn.handovers_idle"]) == (2, 1)
+    before = program.stats()
+    program.waited()                                # no span open
+    assert program.stats() == before
+
+
+def test_a_hand_over_with_no_span_open_changes_no_spans_columns(clock):
+    with program.span("stage.put"):
+        clock.now += 1.0
+    before = program.stats()
+    clock.now += 1.0
+    program.enqueued(Owed())
+    clock.now += 1.0
+    program.enqueued(None)
+    assert program.stats() == before
+
+
+def test_a_process_without_jax_pays_no_import_for_a_span():
+    """Spans, hand-overs and waits in a process that never imported JAX:
+    none imports it, and there is no session's table."""
+    import subprocess
+
+    code = (
+        "import sys\n"
+        "from rabit_tpu.obs import program\n"
+        "class Owed:\n"
+        "    def is_ready(self): return True\n"
+        "owed = Owed()\n"
+        "with program.span('learn.step'):\n"
+        "    with program.span('learn.dispatch'):\n"
+        "        program.enqueued(owed)\n"
+        "    program.waited()\n"
+        "s = program.stats()\n"
+        "assert 'jax' not in sys.modules, 'imported'\n"
+        "assert s['learn.handovers'] == s['learn.waits'] == 1, s\n"
+        "assert not any(k.startswith(program.TRACED) for k in s), s\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
+                   timeout=120)
+
+
+class Session:
+    """What the table asks of ``jax.profiler.TraceAnnotation``, with a
+    session the test starts and stops."""
+    recording = False
+
+    def __init__(self, name, **fields):
+        pass
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.recording
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_the_table_of_a_session_holds_the_spans_with_both_ends_in_it(
+        clock, monkeypatch):
+    """Four steps of 10 s: before the session, across its start (as the
+    benchmark starts it, after the step's commit), inside it, and cut
+    by its stop.  ``traced/`` holds the third and the children of the
+    others that lie wholly inside."""
+    monkeypatch.setattr(program, "_annotation", Session)
+    monkeypatch.setattr(Session, "recording", False)
+    assert not any(k.startswith(program.TRACED) for k in program.stats())
+
+    def step(after_commit=None, landed_after=2.0):
+        with program.span("learn.step"):
+            owed = Owed()
+            with program.span("learn.dispatch"):
+                clock.now += 1.0
+                program.enqueued(owed)
+            clock.now += landed_after
+            owed.landed = True
+            clock.now += 5.0 - landed_after
+            with program.span("commit"):
+                clock.now += 3.0
+            if after_commit is not None:
+                monkeypatch.setattr(Session, "recording", after_commit)
+            with program.span("learn.update"):
+                clock.now += 1.0
+
+    step()
+    step(after_commit=True)
+    step()
+    step(after_commit=False)
+    s = program.stats()
+    assert s["learn.step.n"] == s["commit.n"] == 4
+    assert s["learn.handovers"] == s["learn.handovers_idle"] == 4
+    traced = {k[len(program.TRACED):]: v for k, v in s.items()
+              if k.startswith(program.TRACED)}
+    # one whole step; the update of the step the start cut, the dispatch
+    # and the commit of the step the stop cut
+    assert {k: v for k, v in traced.items() if k.endswith(".n")} == {
+        "learn.step.n": 1, "learn.dispatch.n": 2, "commit.n": 2,
+        "learn.update.n": 2}
+    assert traced["learn.step.total_s"] == traced["learn.step.max_s"] == 10.0
+    assert traced["learn.step.exposed_s"] == 1.0 + 3.0 + 1.0
+    assert traced["learn.step.unsure_s"] == 5.0     # dispatch to the commit
+    assert traced["learn.step.self_s"] == 5.0
+    assert traced["commit.exposed_s"] == 6.0 and traced["commit.unsure_s"] == 0
+    assert (traced["learn.handovers"], traced["learn.handovers_idle"]) == (2, 2)
+    for name in span_names(traced):
+        assert traced[name + ".total_s"] <= s[name + ".total_s"]
+    program.reset()
+    assert program.stats() == {}
 
 
 # ----------------------------------------------------------- path_stats
@@ -408,6 +594,8 @@ def test_kmeans_run_leaves_the_spans_of_its_layers(
             COLUMNS} <= {
         "learn.iterations", "learn.versions", "learn.rows", "learn.ahead",
         "learn.ahead_discarded", "learn.device_updates",
+        "learn.handovers", "learn.handovers_idle",
+        "learn.waits", "learn.waits_idle",
         "allreduce.programs_built",
         "compile.seconds", "compile.misses", "compile.hits",
         "stage.clamped"}
@@ -533,6 +721,13 @@ def test_every_fetch_is_a_wait_and_a_copy_and_exposed_fits_in_total(
     for name in span_names(s):
         assert 0 <= s[name + ".exposed_s"] <= s[name + ".total_s"] + 1e-9, name
         assert 0 <= s[name + ".self_s"] <= s[name + ".total_s"] + 1e-9, name
+        assert 0 <= s[name + ".unsure_s"] <= s[name + ".total_s"] \
+            - s[name + ".exposed_s"] + 1e-9, name
+    # every program the loop handed over inside a step was counted, and
+    # no session recorded
+    assert 0 <= s.get("learn.handovers_idle", 0) <= s["learn.handovers"]
+    assert s["learn.handovers"] >= s["learn.versions"] - 1
+    assert not any(k.startswith(program.TRACED) for k in s)
     # the host's copy and arithmetic between two programs began with
     # nothing in flight wherever the loop runs nothing ahead
     if not runs_ahead:
@@ -980,12 +1175,13 @@ def test_with_rabit_obs_the_span_event_carries_self_and_exposed(clock):
         with program.span("learn.step", version=4):
             clock.now += 1.0
             with program.span("learn.dispatch"):
-                program.enqueued(Owed())    # collected at once: landed
                 clock.now += 2.0
+                program.enqueued(Owed())    # collected at once: landed
             owed = Owed()
             program.enqueued(owed)
             with program.span("learn.fetch.wait"):
                 clock.now += 4.0
+                owed.landed = True
     finally:
         program.detach()
     events = {e["kind"]: e for e in stub.event_trace().events()}
@@ -995,6 +1191,9 @@ def test_with_rabit_obs_the_span_event_carries_self_and_exposed(clock):
     assert (wait["dur"], wait["self"], wait["exposed"]) == (4.0, 4.0, 0.0)
     assert wait["parent"] == "learn.step" and wait["version"] == 4
     assert events["learn.dispatch"]["exposed"] == 2.0
+    # the wait began with a result owed and ended with it landed
+    assert (wait["unsure"], step["unsure"]) == (4.0, 4.0)
+    assert events["learn.dispatch"]["unsure"] == 0.0
 
 
 def test_a_span_open_across_detach_leaves_no_stale_nesting(table):
@@ -1147,22 +1346,101 @@ def test_span_trace_puts_the_programs_exposed_seconds_beside_the_idle():
 
     def table(scale):
         return {f"{name}.{col}": scale * value for name, row in {
-            "learn.step": (10, 50.0, 4.0, 9.0),
-            "learn.fetch": (10, 40.0, 2.0, 6.0),
-            "learn.fetch.wait": (10, 29.0, 29.0, 0.0),
-            "learn.fetch.copy": (10, 9.0, 9.0, 5.0),
-            "learn.dispatch": (20, 15.0, 15.0, 2.0),
-            "commit": (10, 3.5, 3.5, 0.5)}.items()
-            for col, value in zip(("n", "total_s", "self_s", "exposed_s"),
-                                  row)}
+            "learn.step": (10, 50.0, 4.0, 9.0, 20.0),
+            "learn.fetch": (10, 40.0, 2.0, 6.0, 20.0),
+            "learn.fetch.wait": (10, 29.0, 29.0, 0.0, 20.0),
+            "learn.fetch.copy": (10, 9.0, 9.0, 5.0, 0.0),
+            "learn.dispatch": (20, 15.0, 15.0, 2.0, 0.0),
+            "commit": (10, 3.5, 3.5, 0.5, 0.0)}.items()
+            for col, value in zip(("n", "total_s", "self_s", "exposed_s",
+                                   "unsure_s"), row)}
 
     gained = span_trace.window_table(table(1), table(3), parents)
     assert gained["learn.fetch"] == {
         "n": 20, "total_s": 80.0, "self_s": 4.0, "exposed_s": 12.0,
+        "unsure_s": 40.0,
         "exposed_own_s": 2.0}                   # 12 - wait's 0 - copy's 10
     assert gained["learn.fetch.copy"]["exposed_own_s"] == 10.0
     # a child seen under two parents: whose seconds they were is unknown
     assert "exposed_own_s" not in gained["learn.step"]
+    # the table of a recorded session, from its own keys alone
+    session = {program.TRACED + k: v for k, v in table(2).items()}
+    assert span_trace.window_table({}, {**table(5), **session}, parents,
+                                   program.TRACED) == \
+        span_trace.window_table(table(1), table(3), parents)
+
+
+def test_span_trace_aligns_the_clocks_and_finds_the_launch_lag():
+    """Times in ms; the device's clock is 1.0 early and the fastest
+    launch takes 0.1.  A: handed over at 10 to an idle device, starts at
+    10.2; B: handed over at 11, queued behind A; C: a program with no
+    hand-over of its own in the trace; D: handed over at 30, idle."""
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    sys.path.insert(0, ROOT)
+    import span_trace
+
+    ms = 1e6
+    enqueues = [(10 * ms, 7), (11 * ms, 8), (30 * ms, 10)]
+    ran = [(9.2 * ms, 14 * ms, 7), (14 * ms, 19 * ms, 8),
+           (19 * ms, 19.5 * ms, 9), (29.1 * ms, 34 * ms, 10)]
+    # each program against the hand-over of its own run id: C's lies
+    # before the trace, and a hand-over whose program has not started
+    # when the trace stops pairs with nothing
+    shift, pairs = span_trace.clock_shift(enqueues + [(40 * ms, 11)], ran)
+    assert (round(shift / ms, 6), pairs) == (0.9, 3)
+    # no ids: in order, if hand-overs and programs are as many
+    bare = [(h, None) for h, _run in enqueues]
+    shift, pairs = span_trace.clock_shift(
+        bare, [(a, b, None) for a, b, _run in ran[:2] + ran[3:]])
+    assert (round(shift / ms, 6), pairs) == (0.9, 3)
+    assert span_trace.clock_shift(bare, ran) == (0.0, 0)
+    assert span_trace.clock_shift([], ran) == (0.0, 0)
+    ran = [(a, b) for a, b, _run in ran]
+    moved = [(a + shift, b + shift) for a, b in ran]
+    busy = [[moved[0][0], moved[2][1]], list(moved[3])]
+    idle = [[busy[0][1], busy[1][0]]]
+    # the first hand-over inside an idle interval ends it; the one that
+    # follows queues behind it, one made with the device busy is no
+    # launch, and nothing precedes the window's first program
+    assert [(h / ms, round(x / ms, 6)) for h, x in span_trace.launch_lags(
+        [10 * ms, 11 * ms, 29.5 * ms, 29.8 * ms], idle)] == [(29.5, 0.5)]
+    assert span_trace.launch_lags([11 * ms], idle) == []
+    # a wait the device's last operation ended in, one that ended with
+    # the device busy, one that began with it idle
+    waits = [[19 * ms, 21 * ms], [31 * ms, 33 * ms], [25 * ms, 26 * ms]]
+    assert [(b / ms, round(x / ms, 6)) for b, x in span_trace.notice_lags(
+        waits, busy)] == [(20.0, 0.6)]
+
+
+def test_span_trace_says_how_far_outside_its_bounds_a_row_lies():
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import span_trace
+
+    row = {"n": 10, "total_s": 9.0, "self_s": 1.0}
+    got = span_trace.verdicts({
+        "lag": {"notice_us_p95": 1000.0},
+        "program_window": {
+            "learn.step": {**row, "exposed_s": 1.0, "unsure_s": 2.0},
+            "commit": {**row, "exposed_s": 0.5, "unsure_s": 0.0},
+            "gbdt.split": {**row, "exposed_s": 0.25, "unsure_s": 0.0},
+            "learn.fetch.wait": {**row, "exposed_s": 0.0, "unsure_s": 0.0}},
+        "idle_by_enclosing_span": {"learn.step": 3.009, "commit": 0.25,
+                                   "gbdt.split": 0.75,
+                                   "learn.fetch.wait": 0.004},
+        # the split's annotations are a quarter of a second wider than
+        # the table's intervals
+        "seconds_by_span": {"learn.step": 8.9, "gbdt.split": 9.25},
+        "handovers_idle_by_enclosing_span": {"learn.step": 10},
+        "launch_s_by_enclosing_span": {"learn.step": 0.005},
+        "waits_idle_by_enclosing_span": {"learn.step": 5,
+                                         "learn.fetch.wait": 5}})
+    assert got["learn.step"]["upper_s"] == pytest.approx(3.010)
+    assert got["learn.step"]["outside_s"] == 0.0
+    assert got["commit"]["outside_s"] == -0.25      # under the lower bound
+    assert got["gbdt.split"]["outside_s"] == 0.25   # over the upper one
+    assert got["learn.fetch.wait"] == {
+        "idle_s": 0.004, "lower_s": 0.0, "upper_s": 0.005,
+        "handovers_idle": 0, "waits_idle": 5, "outside_s": 0.0}
 
 
 def test_span_cost_compares_segments_with_their_neighbours():
@@ -1194,17 +1472,19 @@ def test_alternating_switches_the_spans_off_and_on(table):
         def __call__(self):
             self.stamps.append(0.0)
 
-    on = (program.span, program.count, program.enqueued)
+    on = (program.span, program.count, program.enqueued, program.waited)
     commit = span_trace.alternating(Clock(), 2, program, on)
     try:
         seen = []
         for _ in range(8):
             with program.span("v"):
                 program.count("v.k")
+                program.waited()
             seen.append(program.stats().get("v.n", 0))
             commit()
     finally:
-        program.span, program.count, program.enqueued = on
+        (program.span, program.count, program.enqueued,
+         program.waited) = on
     # commits 0-1 on, 2-3 off, 4-5 on, 6-7 off
     assert seen == [1, 2, 2, 2, 3, 4, 4, 4]
     assert program.stats()["v.k"] == 4

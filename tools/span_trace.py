@@ -9,14 +9,32 @@ three names around public calls.  ``reduce_file`` gives a span every
 idle interval it overlaps, so a parent holds its children's too; here
 each idle interval also goes to the innermost span open on the host,
 read off how the annotations nest in the trace.  Beside both it prints
-what the program's own table gained over the same window
-(``exposed_s``, inclusive as the enclosing-span column is; and less its
-children's, where the trace shows a span's children under no other
-parent, as the innermost column is): the program's lower bound of the
-idle time against the trace's measurement of it.  Also checks the two
-clocks against each other: every ``rabit:allreduce.dispatch`` begins
-before the collective's program it enqueues starts on the device (after
-``clock_shift``).
+the program's own table of the recorded session (the ``traced/`` keys of
+``program.stats()``: the spans that began and ended inside the trace):
+``exposed_s`` and ``unsure_s``, inclusive as the enclosing-span column
+is, and ``exposed_s`` less its children's, where the trace shows a
+span's children under no other parent, as the innermost column is.  A
+row's verdict holds the trace's idle seconds under the span against the
+program's bounds of them: ``exposed_s`` below; above, ``exposed_s +
+unsure_s`` and what no column can see, (hand-overs under the span that
+found the device idle) ``x`` (the launch lag's 95th percentile) ``+``
+(waits under it that came back to an idle device) ``x`` (the notice
+lag's), and what the span's annotation is wider than the table's
+interval.  It says by how much a row lies outside.  The launch lag is
+the end of an idle interval of the device less the first hand-over made
+inside it (``rabit:enqueued``, the instant ``program.enqueued`` ran: the
+runtime's own ``DoEnqueueProgram`` follows on another thread, a third
+of a millisecond later, and stands in only where a trace has no such
+marks); the notice lag the end of a ``*.wait`` span less the end of the
+device's last operation inside it.
+
+The two clocks are aligned here (:func:`clock_shift`): a device program
+and the ``DoEnqueueProgram`` that handed it over carry one run id, so
+each program is held to its own hand-over, also where the trace holds
+more of the one than of the other (the boosting cells, where
+``perfbench.trace_reduce`` pairs by index and shifts nothing).  Every
+``rabit:allreduce.dispatch`` must begin before the collective's program
+it enqueues starts on the device.
 
 One chip:    python tools/span_trace.py --workload kmeans-dense-chain8-x1
 Four chips:  python -m rabit_tpu.tracker.launch_local -n 4 \\
@@ -36,6 +54,7 @@ A builder's tool, not part of the benchmark: it claims no metric.
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import os
 import shutil
@@ -88,17 +107,20 @@ def parents_of(threads: list[list[tuple]]) -> dict[str, set]:
     return parents
 
 
-def window_table(before: dict, after: dict, parents: dict) -> dict:
-    """What the program's table gained between two ``program.stats()``:
-    per span ``n``, ``total_s``, ``self_s``, ``exposed_s`` and, where
-    every child of the span was seen under it alone, ``exposed_own_s``:
-    its exposed seconds less its children's, the seconds exposed with
-    the span innermost."""
-    names = {k[:-len(".exposed_s")] for k in after
-             if k.endswith(".exposed_s")}
-    gained = {name: {col: after.get(f"{name}.{col}", 0)
-                     - before.get(f"{name}.{col}", 0)
-                     for col in ("n", "total_s", "self_s", "exposed_s")}
+def window_table(before: dict, after: dict, parents: dict,
+                 prefix: str = "") -> dict:
+    """What the program's table gained between two ``program.stats()``
+    (with ``prefix``, its table of the recorded session): per span
+    ``n``, ``total_s``, ``self_s``, ``exposed_s``, ``unsure_s`` and,
+    where every child of the span was seen under it alone,
+    ``exposed_own_s``: its exposed seconds less its children's, the
+    seconds exposed with the span innermost."""
+    names = {k[len(prefix):-len(".exposed_s")] for k in after
+             if k.startswith(prefix) and k.endswith(".exposed_s")}
+    gained = {name: {col: after.get(f"{prefix}{name}.{col}", 0)
+                     - before.get(f"{prefix}{name}.{col}", 0)
+                     for col in ("n", "total_s", "self_s", "exposed_s",
+                                 "unsure_s")}
               for name in names}
     for name, row in gained.items():
         children = [c for c, ps in parents.items() if name in ps]
@@ -108,23 +130,116 @@ def window_table(before: dict, after: dict, parents: dict) -> dict:
     return gained
 
 
-def idle_intervals(profile) -> tuple[list, float]:
-    """The intervals in which nothing ran on the device (one device
-    plane a process), on the host's clock, and the shift applied."""
+def _run_id(event):
+    return dict(event.stats).get("run_id")
+
+
+def handovers(profile) -> list[tuple]:
+    """``(when, run id)`` of every ``DoEnqueueProgram``: the runtime
+    handing a program to the device, on the host's clock."""
     from perfbench import trace_reduce as tr
 
-    (ops, module_starts), = tr.device_ops(profile).values()
-    shift = tr.clock_shift(profile, module_starts)
+    return sorted((float(e.start_ns), _run_id(e)) for plane in profile.planes
+                  if not tr.DEVICE_PLANE.match(plane.name)
+                  for line in plane.lines for e in line.events
+                  if e.name == tr.ENQUEUE)
+
+
+def programs(profile) -> list[tuple]:
+    """``(start, end, run id)`` of every program the device ran (one
+    device plane a process), on the device's clock."""
+    from perfbench import trace_reduce as tr
+
+    return sorted((float(e.start_ns), float(e.start_ns + e.duration_ns),
+                   _run_id(e))
+                  for plane in profile.planes
+                  if tr.DEVICE_PLANE.match(plane.name)
+                  for line in plane.lines if line.name == tr.MODULES_LINE
+                  for e in line.events)
+
+
+def clock_shift(enqueues: list, ran: list) -> tuple[float, int]:
+    """Nanoseconds to add to the device's times so that no program
+    starts before the runtime handed it over, and the number of pairs
+    the figure rests on.  A program and its hand-over carry one run id;
+    the programs handed over before the trace began and the hand-overs
+    whose programs had not started when it stopped pair with nothing.
+    Where the events carry no id they are matched in order, if they are
+    as many (``perfbench.trace_reduce.clock_shift``'s rule)."""
+    handed = {run: h for h, run in enqueues if run is not None}
+    pairs = [handed[run] - a for a, _b, run in ran if run in handed]
+    if not handed and len(enqueues) == len(ran):
+        pairs = [h - a for (h, _), (a, _b, _run) in zip(enqueues, ran)]
+    return (max(0.0, max(pairs)) if pairs else 0.0), len(pairs)
+
+
+def _inside(intervals: list, t: float) -> bool:
+    """Is ``t`` inside one of the disjoint, sorted ``intervals``?  Not
+    at its start: the program the clocks were aligned by starts at its
+    hand-over."""
+    i = bisect.bisect_left(intervals, [t, t]) - 1
+    return i >= 0 and intervals[i][0] < t < intervals[i][1]
+
+
+def launch_lags(stamps: list, idle: list) -> list[tuple]:
+    """``(hand-over, lag)`` of every hand-over that found the device
+    idle: of each idle interval the first hand-over made inside it (the
+    program that ends the interval is its own; those after it queue
+    behind), and the interval's end less it.  ``stamps`` sorted, both
+    on the host's clock."""
+    out = []
+    for a, b in idle:
+        i = bisect.bisect_right(stamps, a)
+        if i < len(stamps) and stamps[i] < b:
+            out.append((stamps[i], b - stamps[i]))
+    return out
+
+
+def notice_lags(waits: list, busy: list) -> list[tuple]:
+    """``(middle of the wait, lag)`` of every wait (``[start, end]`` of
+    a ``*.wait`` span) inside which the device went idle and stayed so:
+    the wait's end less the end of the device's last operation."""
+    out = []
+    for a, b in waits:
+        i = bisect.bisect_left(busy, [b, b]) - 1
+        if i >= 0 and a < busy[i][1] <= b:
+            out.append(((a + b) / 2, b - busy[i][1]))
+    return out
+
+
+def _percentiles(lags: list, unit: str) -> dict:
+    lag = sorted(x for _t, x in lags)
+    if not lag:
+        return {}
+    return {unit + "_us_median": lag[len(lag) // 2] * 1e-3,
+            unit + "_us_p95": lag[min(len(lag) - 1,
+                                      int(0.95 * len(lag)))] * 1e-3,
+            unit + "_us_max": lag[-1] * 1e-3}
+
+
+def device_intervals(profile, enqueues: list, ran: list) -> tuple:
+    """The intervals in which something ran on the device (one device
+    plane a process) and those in which nothing did, on the host's
+    clock; the shift applied and the pairs it rests on."""
+    from perfbench import trace_reduce as tr
+
+    (ops, _starts), = tr.device_ops(profile).values()
+    shift, pairs = clock_shift(enqueues, ran)
     busy = tr._union([[a + shift, b + shift] for _n, a, b in ops])
-    return tr._subtract([[busy[0][0], busy[-1][1]]], busy), shift
+    return (busy, tr._subtract([[busy[0][0], busy[-1][1]]], busy), shift,
+            pairs)
 
 
-def host_events(profile, prefix: str) -> list[list[tuple]]:
+def host_events(profile, prefix: str, mark: str = "") -> list[list[tuple]]:
+    """The spans of the program thread by thread, without its marks of
+    hand-overs; with ``mark``, those alone."""
     from perfbench.trace_reduce import DEVICE_PLANE
+    from rabit_tpu.obs.program import MARK
 
     return [[(e.name[len(prefix):], float(e.start_ns),
               float(e.start_ns + e.duration_ns))
-             for e in line.events if e.name.startswith(prefix)]
+             for e in line.events if e.name.startswith(prefix)
+             and (e.name[len(prefix):] == MARK) == bool(mark)]
             for plane in profile.planes if not DEVICE_PLANE.match(plane.name)
             for line in plane.lines]
 
@@ -154,28 +269,96 @@ def reduce_trace(path: str, prefix: str) -> dict:
     import jax
 
     from perfbench import trace_reduce as tr
+    from rabit_tpu.obs.program import MARK
 
     reduced = tr.reduce_file(path, prefix)
     profile = jax.profiler.ProfileData.from_file(path)
-    idle, shift = idle_intervals(profile)
+    handed, ran = handovers(profile), programs(profile)
+    busy, idle, shift, pairs = device_intervals(profile, handed, ran)
     threads = host_events(profile, prefix)
     inner = {name: tr._overlap(idle, cover) * 1e-9
              for name, cover in self_intervals(threads).items()}
     idle_s = reduced["window_s"] - reduced["busy_s"]
     inner[NO_SPAN] = idle_s - sum(inner.values())
+    covers = {name: tr._union([[a, b] for events in threads
+                               for n, a, b in events if n == name])
+              for name in {n for events in threads for n, _a, _b in events}}
+    enqueues = [h for h, _run in handed]
+    marks = sorted(a for events in host_events(profile, prefix, MARK)
+                   for _n, a, _b in events)
+    launches = launch_lags(marks or enqueues, idle)
+    notices = notice_lags(sorted(
+        [a, b] for events in threads for name, a, b in events
+        if name.endswith(".wait")), busy)
+    lag = {**_percentiles(launches, "launch"),
+           **_percentiles(notices, "notice")}
+    # a launch is idle time under whichever spans are open while it
+    # lasts: the hand-over's own and those the host goes on to
+    launching = tr._union([[m, m + lag["launch_us_p95"] * 1e3]
+                           for m, _x in launches])
+
+    def under(stamps):
+        return {name: sum(1 for t, _x in stamps if _inside(cover, t))
+                for name, cover in covers.items()}
+
     return {"window_s": reduced["window_s"], "busy_s": reduced["busy_s"],
             "idle_s": idle_s,
             "idle_by_innermost_span": dict(sorted(
                 inner.items(), key=lambda kv: -kv[1])),
             "no_span_share_of_idle":
                 inner[NO_SPAN] / idle_s if idle_s > 0 else 0.0,
-            "idle_by_enclosing_span": reduced["gaps"],
+            # with this tool's shift, which `reduced["gaps"]` lacks
+            # where hand-overs and programs are not as many
+            "idle_by_enclosing_span": {
+                name: tr._overlap(idle, cover) * 1e-9
+                for name, cover in covers.items()},
+            "seconds_by_span": {name: tr._length(cover) * 1e-9
+                                for name, cover in covers.items()},
+            "handovers_idle_by_enclosing_span": under(launches),
+            "launch_s_by_enclosing_span": {
+                name: tr._overlap(launching, cover) * 1e-9
+                for name, cover in covers.items()},
+            "waits_idle_by_enclosing_span": under(notices),
+            "lag": {"handovers": len(enqueues), "marks": len(marks),
+                    "programs": len(ran),
+                    "handovers_idle": len(launches),
+                    "waits_idle": len(notices),
+                    "clock_shift_us": shift * 1e-3, "shift_pairs": pairs,
+                    **lag},
             "parents": {name: sorted(ps, key=str)
                         for name, ps in parents_of(threads).items()},
             "dispatch": dispatch_leads(profile, prefix, shift),
             "device_ops": sorted(
                 ([k, v[0]] for k, v in reduced["ops"].items()),
                 key=lambda kv: -kv[1])[:8]}
+
+
+def verdicts(result: dict) -> dict:
+    """Per span of the session's table: the trace's idle seconds under
+    it, the program's bounds of them, and how far outside they lie
+    (0.0: inside; negative: the trace reads under ``exposed_s``;
+    positive: over the upper bound).  The upper bound holds what no
+    column sees (the launch after a hand-over to an idle device, as
+    far as the span was open while it lasted, and the notice after a
+    wait, each at its 95th percentile) and what the span's annotations
+    are wider than the table's intervals."""
+    notice_s = result["lag"].get("notice_us_p95", 0.0) * 1e-6
+    out = {}
+    for name, row in result["program_window"].items():
+        seen = result["idle_by_enclosing_span"].get(name, 0.0)
+        handed = result["handovers_idle_by_enclosing_span"].get(name, 0)
+        waited = result["waits_idle_by_enclosing_span"].get(name, 0)
+        wider = max(0.0, result["seconds_by_span"].get(name, 0.0)
+                    - row["total_s"])
+        upper = (row["exposed_s"] + row["unsure_s"]
+                 + result["launch_s_by_enclosing_span"].get(name, 0.0)
+                 + waited * notice_s + wider)
+        out[name] = {"idle_s": seen, "lower_s": row["exposed_s"],
+                     "upper_s": upper, "handovers_idle": handed,
+                     "waits_idle": waited,
+                     "outside_s": min(0.0, seen - row["exposed_s"])
+                     + max(0.0, seen - upper)}
+    return out
 
 
 class NoSpan:
@@ -194,14 +377,14 @@ class NoSpan:
 
 def alternating(clock, every: int, program, on: tuple):
     """The commit wrapper of ``--cost-every``: after the n-th commit the
-    spans, counters and ``enqueued`` are ``on`` if ``n // every`` is even
-    and no-ops if odd, on every rank alike."""
-    off = (NoSpan, lambda name, k=1: None, lambda result: None)
+    spans, counters, ``enqueued`` and ``waited`` are ``on`` if
+    ``n // every`` is even and no-ops if odd, on every rank alike."""
+    off = (NoSpan, lambda name, k=1: None, lambda result: None, lambda: None)
 
     def commit(*args, **kwargs):
         clock(*args, **kwargs)
-        program.span, program.count, program.enqueued = (
-            off if (len(clock.stamps) // every) % 2 else on)
+        (program.span, program.count, program.enqueued,
+         program.waited) = off if (len(clock.stamps) // every) % 2 else on
     return commit
 
 
@@ -274,7 +457,9 @@ def main(argv=None) -> int:
         cfg, args.seed, rank, world,
         max(1, min(8, (os.cpu_count() or 1) // world)), args.rows, None)
     # the adapter's eyes on its learner, opened as the harness opens
-    # them: the boosting adapters' job counts on them
+    # them, after it has described the job: the boosting adapters' and
+    # the streamed cell's jobs count on both
+    learner.describe(cfg, traffic, data)
     undo = list(learner.watch(data, harness.Spans(annotate=False), False))
     trace_dir = os.path.join(out_dir, f"trace-{rank}")
     # one word all ranks of this launch map, and no other launch
@@ -305,7 +490,7 @@ def main(argv=None) -> int:
         StopWord(stop_path) if world > 1 else None,
         on_open=open_window if traced else None,
         on_close=close_window if traced else None)
-    spans = (program.span, program.count, program.enqueued)
+    spans = (program.span, program.count, program.enqueued, program.waited)
     rabit_tpu.checkpoint = clock if traced else alternating(
         clock, args.cost_every, program, spans)
     try:
@@ -315,7 +500,8 @@ def main(argv=None) -> int:
         pass
     finally:
         rabit_tpu.checkpoint = commit
-        program.span, program.count, program.enqueued = spans
+        (program.span, program.count, program.enqueued,
+         program.waited) = spans
         for owner, name, fn in reversed(undo):
             setattr(owner, name, fn)
     if traced:
@@ -324,7 +510,17 @@ def main(argv=None) -> int:
         shutil.rmtree(trace_dir, ignore_errors=True)
         result["program_window"] = window_table(
             table["before"], table["after"],
-            {name: set(ps) for name, ps in result["parents"].items()})
+            {name: set(ps) for name, ps in result["parents"].items()},
+            program.TRACED)
+        result["verdicts"] = verdicts(result)
+        result["program_handovers"] = {
+            name: table["after"].get(program.TRACED + key, 0)
+            - table["before"].get(program.TRACED + key, 0)
+            for name, key in (
+                ("handovers", program.HANDOVERS),
+                ("handovers_idle", program.HANDOVERS + program.IDLE),
+                ("waits", program.WAITS),
+                ("waits_idle", program.WAITS + program.IDLE))}
     else:
         result = span_cost(clock.counted(), warmup - 1, args.cost_every)
     result.update(workload=args.workload, rank=rank, seed=args.seed,
@@ -343,17 +539,26 @@ def main(argv=None) -> int:
     print(f"span_trace {args.workload} rank {rank}: idle "
           f"{result['idle_s']:.3f} s of {result['window_s']:.3f} s; "
           f"no span: {100 * result['no_span_share_of_idle']:.1f}% of idle; "
-          f"dispatch {json.dumps(result['dispatch'])}")
-    program_s = result["program_window"]
+          f"lag {json.dumps(result['lag'])}; "
+          f"dispatch {json.dumps(result['dispatch'])}; the program "
+          f"counted {json.dumps(result['program_handovers'])}")
+    program_s, verdict = result["program_window"], result["verdicts"]
     print(f"  {'span':24s} {'idle, innermost':>16s} {'exposed, own':>14s}"
-          f" {'idle, enclosing':>16s} {'exposed_s':>11s}")
+          f" {'idle, enclosing':>16s} {'exposed_s':>11s} {'unsure_s':>11s}"
+          f" {'idle h/o':>8s} {'idle w':>6s} {'upper':>11s}  verdict")
     for name, seconds in result["idle_by_innermost_span"].items():
-        row = program_s.get(name, {})
+        row, v = program_s.get(name, {}), verdict.get(name)
         own = row.get("exposed_own_s")
         print(f"  {name:24s} {seconds:16.4f} "
               + (f"{own:14.4f}" if own is not None else f"{'-':>14s}")
               + f" {result['idle_by_enclosing_span'].get(name, 0.0):16.4f}"
-              f" {row.get('exposed_s', 0.0):11.4f}")
+              f" {row.get('exposed_s', 0.0):11.4f}"
+              f" {row.get('unsure_s', 0.0):11.4f}"
+              + (f" {'-':>8s} {'-':>6s} {'-':>11s}  -" if v is None else
+                 f" {v['handovers_idle']:8d} {v['waits_idle']:6d}"
+                 f" {v['upper_s']:11.4f}  "
+                 + ("inside" if not v["outside_s"] else
+                    f"{v['outside_s']:+.4f} s outside")))
     return 0
 
 
