@@ -1414,15 +1414,20 @@ def stage_entries(rows, flat: FlatBins, bucket: bool):
     ``GROUP_TILES`` tiles a call; None, None without).  That second copy
     is what the kernel's road costs in HBM: ``capacity / (ROW_TILE *
     width)`` of the first, 9% more than it at 32 entries a row over 24
-    blocks.  No array of rows by columns is made anywhere.
+    blocks.  The 9% is room for the worst case (every bucket a slot over
+    a whole sub-chunk); what the buckets leave of it, with the rows' own
+    empty slots, is each tile's tail, which ``bucket_group`` marks and
+    the kernel does not work.  No array of rows by columns is made
+    anywhere.
 
     The entries are binned on the host, a chunk of ``STAGE_CHUNK_ROWS``
     rows at a time on its threads (:func:`bin_entries`), and cross as
     their cells, 4 bytes where index and value are 8.  Counts the
     entries of the rows-by-columns matrix and the absent among them
     (``gbdt.entries``, ``gbdt.entries_missing``, as :func:`stage_bins`),
-    the present ones, the slots staged for the kernel and the bins a
-    slot has and the rectangle would (``gbdt.sparse.*``)."""
+    the present ones, the slots staged for the kernel, those of the
+    steps it works (``slots_worked``: all of them without ``bucket``) and
+    the bins a slot has and the rectangle would (``gbdt.sparse.*``)."""
     import jax
     import jax.numpy as jnp
 
@@ -1446,7 +1451,7 @@ def stage_entries(rows, flat: FlatBins, bucket: bool):
             part = jax.device_put(part)
         cells_t = fn(cells_t, part, np.int32(lo))
     packed = fb = None
-    slots = n * width
+    slots = worked = n * width
     if bucket:
         with program.span("stage.sparse_bucket"):
             cap = sk.capacity(width, flat.cells)
@@ -1461,10 +1466,12 @@ def stage_entries(rows, flat: FlatBins, bucket: bool):
                 packed = place(packed, part, np.int32(t * cap // sk.SUB))
                 fb = place(fb, part_fb, np.int32(t * cap // sk.STEP))
             jax.block_until_ready((packed, fb))
+            worked = int(sk.steps_worked(fb, cells=flat.cells)) * sk.STEP
     program.count("gbdt.entries", rows_n * flat.f)
     program.count("gbdt.entries_missing", rows_n * flat.f - present)
     program.count("gbdt.sparse.entries", present)
     program.count("gbdt.sparse.slots", slots)
+    program.count("gbdt.sparse.slots_worked", worked)
     program.count("gbdt.sparse.bins", flat.nbins)
     program.count("gbdt.sparse.bins_rect", flat.f * flat.nbin)
     return cells_t, packed, fb

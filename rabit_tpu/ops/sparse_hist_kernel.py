@@ -30,18 +30,30 @@ has three exact parts:
 
 A product is exact (a one-hot is exact in any type) and every sum a
 float32 accumulation, a tile's by plain adds and the tiles' by a
-compensated one (``_hist_kernel``).  What a slot costs is the two products, whatever
-the level's width; what the level's width costs is W's rows.
+compensated one (``_hist_kernel``).  What a slot costs is the two
+products, whatever the level's width; what the level's width costs is
+W's rows.
+
+A tile takes :func:`capacity` slots, room for the worst case, and its
+entries fill nine tenths of them: what is left of the rows' ELL slots
+and of the spare ones sorts behind the last bucket, and
+:func:`bucket_group` marks those sub-chunks in the blocks it stages
+(block ``num_blocks``: none).  The kernel's grid step is a tile, and it
+works the tile's slots a step of ``SUBS`` sub-chunks at a time in a loop
+that ends where the marks begin: the tail costs nothing (PR 52).
 
 Off the chip the same sums come from ``jax.ops.segment_sum`` over the
 entries as staged for the row move (:func:`hist_sparse_xla`), in
 float32 with the weights as they are.
 
-Timed on a v5e (my chip runs, PR 49; PERF.md sections 5 and 6): a call
-over 2^25 rows of 32 slots (1.17G slots staged, 1.01G entries) takes
-0.6965 s averaged over a round's six widths, 0.59 ns a slot (0.342 to
-0.383 s at 1 to 16 level slots over 2^24 rows); the compensated join is
-1.5% of it.
+Timed on a v5e (my chip runs, PRs 49 and 52; PERF.md sections 5 and 6):
+a call over 2^24 rows of 32 slots (587.2M slots staged, 504.5M entries,
+91.5% of the staged slots worked) takes 0.2978 s at 1 level slot, 0.3031
+at 4, 0.3319 at 16 (0.55 to 0.62 ns a worked slot), where the grid of
+before PR 52, a turn a step of every tile, tail and all, took 0.3417,
+0.3484, 0.3837; the compensated join is 1.5% of a call.  A branch around
+a grid step's work (``pl.when``) had given back half of what it skipped,
+and one around a sub-chunk's a quarter more than all of it.
 """
 from __future__ import annotations
 
@@ -54,7 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from rabit_tpu.ops.sparse_linear_kernel import (
-    FB_STEPS, STEP, SUB, SUBS, _NT, _VMEM_LIMIT_BYTES, _block_of, _onehot)
+    STEP, SUB, SUBS, _NT, _VMEM_LIMIT_BYTES, _onehot)
 
 R_HI, R_LO = 128, 32
 ROW_TILE = R_HI * R_LO            # rows a tile
@@ -71,7 +83,7 @@ def num_blocks(cells: int) -> int:
 
 def capacity(width: int, cells: int) -> int:
     """Slots a tile takes: every slot of its ELL rows and the most that
-    padding its buckets to whole sub-chunks can ask for, in whole grid
+    padding its buckets to whole sub-chunks can ask for, in whole
     steps."""
     slots = ROW_TILE * width + num_blocks(cells) * (SUB - 1)
     return -(-slots // STEP) * STEP
@@ -85,7 +97,11 @@ def bucket_group(cells_t, *, cells: int):
     """The entries of ``g`` whole tiles as staged for the row move,
     ``(width, g * ROW_TILE)`` int32 cells (-1: no entry), to ``(packed,
     fb, real)``: per tile, slots sorted by cell block, buckets padded to
-    whole sub-chunks, and the block of every sub-chunk."""
+    whole sub-chunks, and the block of every sub-chunk.  A sub-chunk
+    behind the tile's last bucket (the rows' ELL slack and the spare
+    slots no bucket took) holds no entry and names block ``num_blocks``,
+    which does not exist: those are the tile's tail, and the kernel does
+    nothing on a step of SUBS sub-chunks that starts with one."""
     with jax.named_scope("gbdt_sparse_stage"):
         width, rows = cells_t.shape
         g, nblk = rows // ROW_TILE, num_blocks(cells)
@@ -115,10 +131,17 @@ def bucket_group(cells_t, *, cells: int):
              jnp.concatenate([packed, jnp.full(spare_key.shape, PAD,
                                                jnp.int32)], axis=1)),
             dimension=1, num_keys=1)
-        fb_sub = key[:, ::SUB]
-        fb_sub = jnp.where(fb_sub >= nblk, 0, fb_sub)
         return (packed.reshape(g * cap // SUB, SUB),
-                fb_sub.reshape(g * cap // STEP, SUBS), jnp.sum(counts))
+                key[:, ::SUB].reshape(g * cap // STEP, SUBS),
+                jnp.sum(counts))
+
+
+@functools.partial(jax.jit, static_argnames=("cells",))
+def steps_worked(fb, *, cells: int):
+    """Steps (rows of ``fb``: ``SUBS`` sub-chunks) of the bucketed
+    entries the kernel works: those whose first sub-chunk names a block
+    that exists."""
+    return jnp.sum(fb[:, 0] < num_blocks(cells))
 
 
 # ----------------------------------------------------------------------
@@ -130,12 +153,18 @@ def _bits(p, shift: int, mask: int):
 
 def _hist_kernel(fb_ref, idx_ref, tab_ref, out_ref, tile_ref, lost_ref, *,
                  rows: int):
-    """One grid step: SUBS sub-chunks of one row tile.  ``tab_ref[0]``
-    is ``(3 * R_LO, R_HI)`` bfloat16, row ``q * R_LO + rlo`` the grad
-    (q = 0), hess (1) or level slot (2) of row ``rhi * R_LO + rlo``;
-    ``out_ref[block]`` is ``(C_HI, 128)`` float32, column ``slot * 8 +
-    channel * 4 + clo``, resident for the whole call.  ``rows`` of W's
-    128 are the call's (its slots' eight each); the rest are zeros.
+    """One grid step: one row tile, its live steps of SUBS sub-chunks in
+    a loop.  ``idx_ref`` is the tile's slots, a sub-chunk a row;
+    ``fb_ref`` (SMEM) the block of each of its sub-chunks and, behind
+    them, the number of its steps that hold an entry (``bucket_group``'s
+    marks are a tile's tail: the loop ends where it begins, and a step
+    of the tail costs nothing, not even a turn of the grid).
+    ``tab_ref[0]`` is ``(3 * R_LO, R_HI)`` bfloat16, row ``q * R_LO +
+    rlo`` the grad (q = 0), hess (1) or level slot (2) of row ``rhi *
+    R_LO + rlo``; ``out_ref[block]`` is ``(C_HI, 128)`` float32, column
+    ``slot * 8 + channel * 4 + clo``, resident for the whole call.
+    ``rows`` of W's 128 are the call's (its slots' eight each); the rest
+    are zeros.
 
     A cell most rows hold takes a third of a million adds over 2^25
     rows, and a float32 sum of 4e5 that has drifted by a few units
@@ -144,57 +173,62 @@ def _hist_kernel(fb_ref, idx_ref, tab_ref, out_ref, tile_ref, lost_ref, *,
     So a tile's sub-chunks add into the tile's own sums (``tile_ref``,
     small numbers, a few dozen adds a cell), and a tile's sums join the
     call's by a compensated add (Kahan: ``lost_ref`` keeps what the last
-    add rounded away): the result is good to an ulp whatever the rows."""
-    first, last = pl.program_id(1) == 0, \
-        pl.program_id(1) == pl.num_programs(1) - 1
+    add rounded away): the result is good to an ulp whatever the rows.
+    Every tile zeroes its sums and joins them, whatever it holds."""
+    nblk, subs = out_ref.shape[0], idx_ref.shape[0]
 
-    @pl.when((pl.program_id(0) == 0) & first)
+    @pl.when(pl.program_id(0) == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
         lost_ref[...] = jnp.zeros_like(lost_ref)
 
-    @pl.when(first)
-    def _():
-        tile_ref[...] = jnp.zeros_like(tile_ref)
+    tile_ref[...] = jnp.zeros_like(tile_ref)
 
-    cls = lax.broadcasted_iota(jnp.int32, (128, SUB), 0)
-    cls_lo = lax.broadcasted_iota(jnp.int32, (R_LO, SUB), 0)
-    q = lax.broadcasted_iota(jnp.int32, (rows, SUB), 0)
-    key_q = lax.shift_right_logical(q, 3) * C_LO + lax.bitwise_and(q, 3)
-    takes_hess = _bits(q, 2, 1) == 1
-    table = tab_ref[0]
-    blank = jnp.zeros((128 - rows, SUB), jnp.bfloat16)
-    for b in range(SUBS):
-        p = idx_ref[b:b + 1, :]
-        picked = jnp.dot(table, _onehot(cls, _bits(p, 14, 127)),
-                         preferred_element_type=jnp.float32)  # (96, SUB)
-        mine = cls_lo == _bits(p, 9, 31)
-        g, h, s = (jnp.sum(jnp.where(mine, picked[k * R_LO:(k + 1) * R_LO],
-                                     0.0), axis=0, keepdims=True)
-                   for k in range(3))
-        # a row at no slot reads -1 and a slot of padding is given it:
-        # neither matches a row of W
-        key = jnp.where(p >= PAD, -1,
-                        s.astype(jnp.int32) * C_LO + lax.bitwise_and(p, 3))
-        w = jnp.where(key_q == key, jnp.where(takes_hess, h, g),
-                      0.0).astype(jnp.bfloat16)
-        if rows < 128:
-            w = jnp.concatenate([w, blank], axis=0)
-        blk = _block_of(fb_ref, b)
-        tile_ref[blk] = tile_ref[blk] + lax.dot_general(
-            _onehot(cls, _bits(p, 2, 127)), w, _NT,
-            preferred_element_type=jnp.float32)
+    def step(s, carry):
+        cls = lax.broadcasted_iota(jnp.int32, (128, SUB), 0)
+        cls_lo = lax.broadcasted_iota(jnp.int32, (R_LO, SUB), 0)
+        q = lax.broadcasted_iota(jnp.int32, (rows, SUB), 0)
+        key_q = lax.shift_right_logical(q, 3) * C_LO + lax.bitwise_and(q, 3)
+        takes_hess = _bits(q, 2, 1) == 1
+        table = tab_ref[0]
+        blank = jnp.zeros((128 - rows, SUB), jnp.bfloat16)
+        chunk = idx_ref[pl.ds(pl.multiple_of(s * SUBS, SUBS), SUBS), :]
+        for b in range(SUBS):
+            p = chunk[b:b + 1, :]
+            picked = jnp.dot(table, _onehot(cls, _bits(p, 14, 127)),
+                             preferred_element_type=jnp.float32)  # (96, SUB)
+            mine = cls_lo == _bits(p, 9, 31)
+            g, h, at = (jnp.sum(
+                jnp.where(mine, picked[k * R_LO:(k + 1) * R_LO], 0.0),
+                axis=0, keepdims=True) for k in range(3))
+            # a row at no slot reads -1 and a slot of padding is given it:
+            # neither matches a row of W
+            key = jnp.where(
+                p >= PAD, -1,
+                at.astype(jnp.int32) * C_LO + lax.bitwise_and(p, 3))
+            w = jnp.where(key_q == key, jnp.where(takes_hess, h, g),
+                          0.0).astype(jnp.bfloat16)
+            if rows < 128:
+                w = jnp.concatenate([w, blank], axis=0)
+            # a sub-chunk of the tile's tail in a step that is part live
+            # adds its zeros to a block that exists
+            sub = s * SUBS + b
+            blk = jnp.minimum(fb_ref[sub // 128, sub % 128], nblk - 1)
+            tile_ref[blk] = tile_ref[blk] + lax.dot_general(
+                _onehot(cls, _bits(p, 2, 127)), w, _NT,
+                preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(last)
-    def _():
-        def join(blk, carry):
-            add = tile_ref[blk] - lost_ref[blk]
-            total = out_ref[blk] + add
-            lost_ref[blk] = (total - out_ref[blk]) - add
-            out_ref[blk] = total
-            return carry
+    lax.fori_loop(0, fb_ref[subs // 128, subs % 128], step, 0)
 
-        lax.fori_loop(0, out_ref.shape[0], join, 0)
+    def join(blk, carry):
+        add = tile_ref[blk] - lost_ref[blk]
+        total = out_ref[blk] + add
+        lost_ref[blk] = (total - out_ref[blk]) - add
+        out_ref[blk] = total
+        return carry
+
+    lax.fori_loop(0, nblk, join, 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -212,28 +246,31 @@ def hist_sparse(packed, fb, gh, slot, *, tiles: int, nslots: int,
         jnp.bfloat16)
     table = table.reshape(3, tiles, R_HI, R_LO).transpose(1, 0, 3, 2).reshape(
         tiles, 3 * R_LO, R_HI)
-    steps = fb.shape[0] // tiles
-    fb = fb.reshape(-1)
-    fb = jnp.pad(fb, (0, -fb.shape[0] % (8 * 128))).reshape(-1, 128)
+    # a tile's SMEM rows: its sub-chunks' blocks, then its live steps
+    subs = fb.shape[0] // tiles * SUBS
+    fb = fb.reshape(tiles, subs)
+    live = jnp.sum(fb[:, ::SUBS] < nblk, axis=1, dtype=jnp.int32)
+    fb_rows = -(-(subs + 1) // (8 * 128)) * 8
+    fb = jnp.pad(jnp.concatenate([fb, live[:, None]], axis=1),
+                 ((0, 0), (0, fb_rows * 128 - subs - 1)))
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, rows=rows), grid=(tiles, steps),
+        functools.partial(_hist_kernel, rows=rows), grid=(tiles,),
         in_specs=[
-            pl.BlockSpec((8, 128),
-                         lambda t, s: ((t * steps + s) // FB_STEPS, 0),
+            pl.BlockSpec((fb_rows, 128), lambda t: (t, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((SUBS, SUB), lambda t, s: (t * steps + s, 0),
+            pl.BlockSpec((subs, SUB), lambda t: (t, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 3 * R_LO, R_HI), lambda t, s: (t, 0, 0),
+            pl.BlockSpec((1, 3 * R_LO, R_HI), lambda t: (t, 0, 0),
                          memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((nblk, C_HI, 128), lambda t, s: (0, 0, 0),
+        out_specs=pl.BlockSpec((nblk, C_HI, 128), lambda t: (0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((nblk, C_HI, 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((nblk, C_HI, 128), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret, name="hist_sparse",
-    )(fb, packed, table)
+    )(fb.reshape(tiles * fb_rows, 128), packed, table)
     out = out[..., :8 * nslots].reshape(nblk, C_HI, nslots, 2, C_LO)
     return out.transpose(2, 0, 1, 4, 3).reshape(
         nslots, nblk * CELL_BLOCK, 2)

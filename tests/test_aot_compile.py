@@ -462,21 +462,27 @@ def test_sparse_level_kernel_compiles_for_v5e_at_every_width(topo, nslots):
     """``ops.sparse_hist_kernel.hist_sparse`` at the sparse boosting
     cell's shapes (2^25 rows of 32 slots bucketed over 24 blocks of 512
     cells, a level of 1 to 16 build slots): Mosaic takes the two one-hot
-    products and the resident ``(24, 128, 128)`` accumulator; the
-    program's temporaries are the table of (grad, hess, slot) a row, in
-    float32 and in bfloat16, and little else (24 bytes a row)."""
+    products, the resident ``(24, 128, 128)`` accumulator, a tile's 280
+    sub-chunks as one block and the loop over its live steps, whose trip
+    count is a scalar read from SMEM (PR 52: a ``while`` in what Mosaic
+    is given, and one branch, the call's first tile); the program's
+    temporaries are the table of (grad, hess, slot) a row, in float32
+    and in bfloat16, the tiles' SMEM rows (4 KB a tile) and little else
+    (24 bytes a row)."""
     from rabit_tpu.ops import sparse_hist_kernel as sk
 
     flat, n, width = _allstate_flat(), 1 << 25, 32
     assert flat.cells == 12265 and flat.size == 24 * sk.CELL_BLOCK
     tiles, cap = n // sk.ROW_TILE, sk.capacity(width, flat.cells)
     assert cap == 35 * sk.STEP
-    m = sk.hist_sparse.lower(*_one_chip(
+    traced = sk.hist_sparse.trace(*_one_chip(
         topo, ((tiles * cap // sk.SUB, sk.SUB), jnp.int32),
         ((tiles * cap // sk.STEP, sk.SUBS), jnp.int32),
         ((2, n), jnp.float32), ((n,), jnp.int32)),
-        tiles=tiles, nslots=nslots, cells=flat.cells,
-        interpret=False).compile().memory_analysis()
+        tiles=tiles, nslots=nslots, cells=flat.cells, interpret=False)
+    kernel = str(traced.jaxpr)
+    assert kernel.count(" cond[") == 1 and " while[" in kernel
+    m = traced.lower().compile().memory_analysis()
     assert m.argument_size_in_bytes >= tiles * cap * 4
     assert m.output_size_in_bytes == nslots * flat.size * 2 * 4
     assert m.temp_size_in_bytes <= 24 * n, m
@@ -484,7 +490,8 @@ def test_sparse_level_kernel_compiles_for_v5e_at_every_width(topo, nslots):
 
 def test_sparse_staging_and_row_move_compile_for_v5e(topo, monkeypatch):
     """The sparse shard's other programs at the cell's shapes: the
-    bucketing of a group of 32 tiles (a sort of 32 x 143,360 slots), the
+    bucketing of a group of 32 tiles (a sort of 32 x 143,360 slots) and
+    the count of the steps its marks leave the kernel, the
     row move of every depth (the (32, n) cells searched for the split's
     column, the donated node ids the output, no temporary of the cells'
     size) and the deepest ``gbdt/scan`` on the flat axis (16 built slots
@@ -503,6 +510,11 @@ def test_sparse_staging_and_row_move_compile_for_v5e(topo, monkeypatch):
     m = bucket.memory_analysis()
     group = sk.GROUP_TILES * sk.capacity(width, flat.cells) * 4
     assert group <= m.output_size_in_bytes <= 1.1 * group
+    # the count of the grid steps the kernel works, from the staged marks
+    steps = n // sk.ROW_TILE * sk.capacity(width, flat.cells) // sk.STEP
+    m = sk.steps_worked.lower(real((steps, sk.SUBS), jnp.int32, sharding=s),
+                              cells=flat.cells).compile().memory_analysis()
+    assert m.temp_size_in_bytes <= 4 * steps, m
     cells = real((width, n), jnp.int32, sharding=s)
     for depth in range(6):
         move = jax.jit(boosting._move_entries, donate_argnums=(1,)).lower(

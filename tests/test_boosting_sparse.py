@@ -396,6 +396,124 @@ def test_interpreted_kernel_against_segment_sum_at_every_level_width(nslots):
                                rtol=1e-5, atol=2e-4)
 
 
+# ---- (vi b) the dead tail of a tile: marked, and not worked -------------
+TAIL_WIDTH, TAIL_CELLS = 5, 1300                      # 3 blocks, 6 steps
+# entries a block of a tile and the steps they leave live (48
+# sub-chunks a tile: 40 of ELL slots, 8 spare)
+TILE_KINDS = {
+    "empty": ((0, 0, 0), 0),            # every step dead: the join still runs
+    "full": ((511, 513, 19456), 6),     # 1 + 2 + 38 sub-chunks: no step dead
+    "edge": ((2048, 1024, 1024), 1),    # 8 sub-chunks: one step, to its edge
+    "part": ((100, 3000, 700), 2),      # 1 + 6 + 2: the second step part dead
+    "ragged": (None, None),             # drawn
+}
+
+
+def _tile_cells(kind, rng):
+    """``(TAIL_WIDTH, ROW_TILE)`` cells of one tile with the kind's
+    entries a block, at slots drawn over the whole tile."""
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    slots, counts = TAIL_WIDTH * sk.ROW_TILE, TILE_KINDS[kind][0]
+    if counts is None:
+        c = rng.integers(0, TAIL_CELLS, slots)
+        c[rng.random(slots) < 0.35] = -1
+        return c.reshape(TAIL_WIDTH, sk.ROW_TILE).astype(np.int32)
+    c = np.full(slots, -1, np.int32)
+    at = rng.permutation(slots)[:sum(counts)]
+    c[at] = np.concatenate([
+        rng.integers(b * sk.CELL_BLOCK,
+                     min((b + 1) * sk.CELL_BLOCK, TAIL_CELLS), count)
+        for b, count in enumerate(counts)])
+    return c.reshape(TAIL_WIDTH, sk.ROW_TILE)
+
+
+def _uneven_group(kinds, seed=0):
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    rng = np.random.default_rng(seed)
+    c = np.concatenate([_tile_cells(k, rng) for k in kinds], axis=1)
+    packed, fb, real = sk.bucket_group(jnp.asarray(c), cells=TAIL_CELLS)
+    assert int(real) == np.count_nonzero(c >= 0)
+    return c, packed, fb, rng
+
+
+@pytest.mark.parametrize("kinds,nslots", [
+    (("ragged", "empty", "full", "edge", "part"), 1),
+    (("ragged", "empty", "full", "edge", "part"), 16),
+    (("full", "part", "empty"), 16),        # the call ends on a dead tile
+    (("empty", "edge", "ragged"), 1),       # and starts on one
+    (("empty", "empty"), 16),               # no step of the call is live
+    (("part",), 1), (("edge",), 16), (("full",), 4)],
+    ids=lambda v: "-".join(v) if isinstance(v, tuple) else str(v))
+def test_kernel_skips_a_tiles_dead_steps_and_sums_as_before(kinds, nslots):
+    """The kernel on the marked ``fb`` against itself on ``fb`` as the
+    parent staged it (block 0 where no entry is: every step then runs,
+    and the clamp never engages), bit for bit, and against
+    ``segment_sum``."""
+    import jax.numpy as jnp
+
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    c, packed, fb, rng = _uneven_group(kinds)
+    n, nblk = c.shape[1], sk.num_blocks(TAIL_CELLS)
+    live = np.asarray(fb)[:, 0].reshape(len(kinds), -1) < nblk
+    for kind, steps in zip(kinds, live.sum(axis=1)):
+        assert TILE_KINDS[kind][1] in (None, steps), kind
+    assert int(sk.steps_worked(fb, cells=TAIL_CELLS)) == live.sum()
+    gh = rng.standard_normal((2, n)).astype(np.float32)
+    slot = rng.integers(-1, nslots, n).astype(np.int32)
+    kw = dict(tiles=len(kinds), nslots=nslots, cells=TAIL_CELLS,
+              interpret=True)
+    got = np.asarray(sk.hist_sparse(packed, fb, jnp.asarray(gh),
+                                    jnp.asarray(slot), **kw))
+    unmarked = jnp.where(fb >= nblk, 0, fb)
+    assert bool((unmarked != fb).any())     # no tile fills its capacity
+    np.testing.assert_array_equal(got, np.asarray(sk.hist_sparse(
+        packed, unmarked, jnp.asarray(gh), jnp.asarray(slot), **kw)))
+    rounded = jnp.asarray(gh).astype(jnp.bfloat16).astype(jnp.float32)
+    np.testing.assert_allclose(got, np.asarray(sk.hist_sparse_xla(
+        jnp.asarray(c), rounded, jnp.asarray(slot), nslots, TAIL_CELLS)),
+        rtol=1e-5, atol=2e-4)
+    if "empty" in kinds and len(set(kinds)) == 1:
+        assert not got.any()
+
+
+def test_the_marks_are_a_tiles_tail_and_the_counter_is_their_steps():
+    """No live sub-chunk behind a dead one; a dead one holds padding
+    alone; and ``stage_entries`` counts the slots of the steps that
+    start live."""
+    from rabit_tpu.obs import program
+    from rabit_tpu.ops import sparse_hist_kernel as sk
+
+    kinds = ("part", "empty", "full", "edge", "ragged")
+    _c, packed, fb, _rng = _uneven_group(kinds)
+    nblk = sk.num_blocks(TAIL_CELLS)
+    dead = np.asarray(fb).reshape(len(kinds), -1) >= nblk
+    assert (dead[:, 1:] >= dead[:, :-1]).all()        # a tail
+    assert (np.asarray(fb)[dead.reshape(fb.shape)] == nblk).all()
+    padding = (np.asarray(packed) >= sk.PAD).all(axis=1).reshape(dead.shape)
+    assert (padding | ~dead).all() and dead[1].all() and not dead[0, :9].any()
+    assert dead[0, 9:].all() and dead[3, 8:].all() and not dead[2, :41].any()
+
+    rows, _labels = _rows(n=2 * sk.ROW_TILE + 100)
+    flat = histogram.FlatBins(*histogram.sparse_cuts(rows, NBIN), NBIN)
+    program.reset()
+    _cells_t, packed, fb = histogram.stage_entries(rows, flat, True)
+    stats = program.stats()
+    live = np.asarray(fb)[:, 0] < sk.num_blocks(flat.cells)
+    assert 0 < live.sum() < live.size                 # it engages here
+    assert stats["gbdt.sparse.slots_worked"] == live.sum() * sk.STEP
+    assert stats["gbdt.sparse.entries"] <= stats["gbdt.sparse.slots_worked"] \
+        < stats["gbdt.sparse.slots"] == packed.size
+    program.reset()
+    histogram.stage_entries(rows, flat, False)        # XLA's road: every slot
+    stats = program.stats()
+    assert stats["gbdt.sparse.slots_worked"] == stats["gbdt.sparse.slots"]
+
+
 @pytest.mark.parametrize("trees,depth", [(1, 0), (1, 3), (3, 2)])
 def test_row_move_by_entries_against_the_formula(trees, depth):
     import jax.numpy as jnp
@@ -473,9 +591,18 @@ def test_the_chip_check_of_the_sparse_kernel_rehearsed(monkeypatch):
                            widths=(1, 16))
     checks = [ln for ln in lines if "check" in ln]
     assert [ln["slots"] for ln in checks] == [1, 16]
-    assert all(ln["ok"] and ln["float64_ok"] for ln in checks)
+    assert all(ln["ok"] and ln["float64_ok"] and ln["skip_equal"]
+               for ln in checks)
     assert lines[0]["sparse"] == "bucketed" and lines[0]["padding"] > 0
-    assert [ln["channels"] for ln in lines if "timing" in ln] == [2, 32]
+    # uneven tiles: the second holds no entry, the third every slot
+    steps = lines[0]["steps_a_tile"]
+    assert {0, steps} < set(lines[0]["live_steps_a_tile"])
+    assert lines[0]["entries"] < lines[0]["worked"] < lines[0]["slots"]
+    timings = [ln for ln in lines if "timing" in ln]
+    assert [ln["channels"] for ln in timings] == [2, 32]
+    assert all(ln["seconds_every_step"] > 0
+               and ln["worked_share"] == lines[0]["worked_share"] < 1
+               for ln in timings)
     assert lines[-1] == {"sparse_agrees": True}
 
 

@@ -44,9 +44,15 @@ histograms as two one-hot products an entry) against XLA's
 ``segment_sum`` over the same entries at the sparse boosting cell's
 shape (2^24 rows of up to 32 entries over a flat bin space of 12,265
 cells: 15 columns of 256 bins in nearly every row, the rest indicator
-columns by a power law, ragged rows, rows at node -1), every level's
-width, and against float64 on the first 2^20 rows; then seconds a call
-of each width and of the bucketing.
+columns by a power law, ragged rows, rows at node -1, and uneven tiles:
+the second holds no entry, the third every slot), every level's width,
+and against float64 on the first 2^20 rows; then seconds a call of each
+width and of the bucketing.  Since PR 52 the kernel does nothing on the
+steps of a tile's dead tail (``bucket_group`` marks them, the kernel's
+loop over a tile's steps ends before them): at every width its sums must
+EQUAL BIT FOR BIT those it gives with the marks taken out (every step
+worked: the sums of before PR 52), and each timing line holds both
+calls' seconds beside the share of the staged slots worked.
 
 Not on any cell's path.  Run it through the chip tool:
 
@@ -436,9 +442,12 @@ SPARSE_WIDTHS = (1, 2, 4, 8, 16)
 def sparse_cells(key, n: int, width: int, wide: int, narrow: int):
     """``(width, n)`` int32 cells on the device, -1 where a row has no
     entry (6% of the slots, and every slot of the last 1,000 rows: a
-    ragged shard): slot ``j < wide`` an entry of column ``j``, any of
-    its NBIN bins; the others the one occupied cell of an indicator
-    column drawn by a power law."""
+    ragged shard; every slot of the second tile of the kernel's, and
+    none of the third: uneven tiles): slot ``j < wide`` an entry of
+    column ``j``, any of its NBIN bins; the others the one occupied cell
+    of an indicator column drawn by a power law."""
+    from rabit_tpu.ops.sparse_hist_kernel import ROW_TILE
+
     ks = jax.random.split(key, 4)
     first = jnp.arange(wide, dtype=jnp.int32)[:, None] * NBIN \
         + jax.random.randint(ks[0], (wide, n), 0, NBIN)
@@ -448,6 +457,8 @@ def sparse_cells(key, n: int, width: int, wide: int, narrow: int):
     cells = jnp.concatenate([first, rest])
     held = jax.random.uniform(ks[2], (width, n)) > 0.06
     held &= (jnp.arange(n) < n - 1000)[None, :]
+    tile = (jnp.arange(n) // ROW_TILE)[None, :]
+    held = (held | (tile == 2)) & (tile != 1)
     return jnp.where(held, cells, -1)
 
 
@@ -475,9 +486,22 @@ def run_sparse(shape, seed: int, timed: bool, emit,
         fb = place(fb, part_fb, np.int32(t * cap // sk.STEP))
         real += int(count)
     jax.block_until_ready((packed, fb))
+    took = time.perf_counter() - t0
+    nblk = sk.num_blocks(cells)
+    live_sub = np.asarray(fb) < nblk
+    live = live_sub[:, 0].reshape(tiles, -1)
+    worked = int(sk.steps_worked(fb, cells=cells)) * sk.STEP
+    assert worked == int(live.sum()) * sk.STEP
     emit({"sparse": "bucketed", "rows": n, "entries": real,
           "slots": tiles * cap, "padding": tiles * cap / real - 1.0,
-          "seconds_with_compile": time.perf_counter() - t0})
+          "worked": worked, "worked_share": worked / (tiles * cap),
+          "worked_over_entries": worked / real,
+          "steps_a_tile": live.shape[1],
+          "live_steps_a_tile": sorted(set(live.sum(axis=1).tolist())),
+          "live_sub_chunks": int(live_sub.sum()),
+          "seconds_with_compile": took})
+    # the marks taken out, as before PR 52: every step is then worked
+    unmarked = jnp.where(fb >= nblk, 0, fb)
     ok = True
     kernel = jax.jit(functools.partial(
         sk.hist_sparse, tiles=tiles, cells=cells,
@@ -506,7 +530,9 @@ def run_sparse(shape, seed: int, timed: bool, emit,
         at = np.unravel_index(int(rel.argmax()), rel.shape)
         line = {"check": "sparse_vs_segment_sum", "slots": nslots,
                 "worst": float(rel[at]), "at": [int(v) for v in at],
-                "ok": bool(rel[at] <= LIMIT)}
+                "ok": bool(rel[at] <= LIMIT),
+                "skip_equal": bool(jnp.array_equal(got, kernel(
+                    packed, unmarked, gh, slot, nslots=nslots)))}
         # float64 on the first rows: the others masked to node -1
         part = jnp.where(jnp.arange(n) < head, slot, -1)
         got = np.asarray(kernel(packed, fb, gh, part, nslots=nslots),
@@ -525,13 +551,16 @@ def run_sparse(shape, seed: int, timed: bool, emit,
             nslots, 1, 2)
         rel = float((np.abs(got - want) / np.maximum(mass, 1e-30)).max())
         line.update(float64_worst=rel, float64_ok=bool(rel <= LIMIT))
-        ok &= line["ok"] and line["float64_ok"]
+        ok &= line["ok"] and line["float64_ok"] and line["skip_equal"]
         emit(line)
         if timed:
             emit({"timing": "hist_sparse", "slots": nslots,
                   "channels": 2 * nslots,
                   "seconds": seconds(functools.partial(
                       kernel, nslots=nslots), packed, fb, gh, slot),
+                  "seconds_every_step": seconds(functools.partial(
+                      kernel, nslots=nslots), packed, unmarked, gh, slot),
+                  "worked_share": worked / (tiles * cap),
                   "segment_sum_seconds": seconds(
                       lambda *a: xla(*a, nslots, cells), cells_t, gh, slot)
                   if nslots == widths[-1] else None})
